@@ -8,6 +8,11 @@ ran).  The reference's closest analogue is its zero-copy RDMA into the
 receiver's buffer; this is the TPU-native equivalent
 (DESIGN.md section 7, tests/test_devpull.py).
 
+Both processes pin JAX to the CPU before first use: a chip belongs to one
+process at a time, so two runtimes on one host cannot both hold it.
+(Whether devpull runs between two processes that own DISJOINT chips is
+recorded in PERF.md, "Open questions".)
+
 Run:  python examples/device_pull.py  [--size 16M]
 """
 

@@ -8,8 +8,8 @@ Run:  python examples/pingpong.py [--tls tcp] [--max-size 1g] [--uvloop]
 
 ``--uvloop`` swaps in uvloop's event loop when the package is available
 (the reference's perf script runs under uvloop, reference pingpong.py:6,47
-— the asyncio scheduling overhead it removes is exactly the remaining gap
-BASELINE.md names on the pingpong headline).  Falls back to stock asyncio
+— the asyncio scheduling overhead it removes is the remaining gap on the
+pingpong headline).  Falls back to stock asyncio
 with a warning when uvloop isn't installed (it is not in this sandbox).
 """
 

@@ -1,5 +1,5 @@
 """Measure the five BASELINE.json configs + bench scenarios; prints a
-markdown table for BASELINE.md.  Run on the virtual CPU mesh by default
+markdown table.  Run on the virtual CPU mesh by default
 (STARWAY_BASELINE_REAL=1 to use the real backend for device rows)."""
 
 from __future__ import annotations

@@ -456,6 +456,8 @@ def dump_results(results, args: argparse.Namespace) -> None:
         print("No results collected.")
         return
     print("\n=== Benchmark Results ===")
+    if args.device:
+        print(f"device: {args.device}")
     for result in results:
         print(f"\n[{result.name}] {get_scenario(result.name).description}")
         for key, value in result.metrics.items():
@@ -474,6 +476,9 @@ def dump_results(results, args: argparse.Namespace) -> None:
         report = {
             "timestamp": time.time(),
             "transport": os.environ.get("STARWAY_TLS"),
+            # --payload device: the device the numbers came from (None for
+            # host payloads, which never touch jax).
+            "device": args.device,
             # §24: which swfast levers this run armed ([] = seed path).
             "levers": active_levers(),
             "scenarios": [r.to_dict(include_samples=args.store_trace) for r in results],
@@ -550,13 +555,18 @@ def main(argv: Sequence[str] | None = None) -> int:
             pass
         os.environ["STARWAY_METRICS_PATH"] = str(args.metrics)
         os.environ.setdefault("STARWAY_METRICS_INTERVAL", "0.25")
+    args.device = None
     if getattr(args, "payload", None) == "device":
-        # devpull is only advertised in the handshake once the jax backend
-        # is up (the handshake never initialises one); device-payload runs
-        # should measure the pull path, so bring it up before connecting.
-        import jax
+        # A device-payload run measures the chip: it fails without one,
+        # and its results name the device.  Bringing the backend up here
+        # also matters for the handshake: devpull is only advertised once
+        # the jax backend is up (the handshake never initialises one).
+        from .utils.chip import enable_compile_cache, require_accelerator
 
-        jax.devices()
+        enable_compile_cache()
+        args.device = require_accelerator()
+        print(f"[device] {args.device['count']} x {args.device['kind']} "
+              f"(platform {args.device['platform']})")
 
     if args.role == "server":
         asyncio.run(run_server(args))

@@ -92,9 +92,13 @@ def _make_payload(size: int, fill: int, kind: str):
 
 def _make_sink(size: int, kind: str):
     if kind == "device":
+        import jax
+
         from ..device import DeviceBuffer
 
-        return DeviceBuffer((size,), np.uint8)
+        # A named target: a sink with no device places through
+        # jax.device_put, not the PJRT path the device plane is.
+        return DeviceBuffer((size,), np.uint8, device=jax.local_devices()[0])
     return np.empty(size, dtype=np.uint8)
 
 

@@ -48,9 +48,9 @@ benchmark.md:114-126 for ``UCX_TLS``).  The TPU build mirrors that shape:
 
 ``STARWAY_DECODE_STREAM``
     "1" (default) = the decode-attention kernel's streaming variant
-    (double-buffered manual DMA, ops/pallas_decode.py); "0" = the
-    grid-pipelined variant — the escape hatch if the manual-DMA lowering
-    misbehaves on a backend it has not been measured on.
+    (double-buffered manual DMA, ops/pallas_decode.py) -- the one that
+    serves on the chip (chip_smoke.py phase c, TPU v5 lite); "0" = the
+    grid-pipelined variant, kept until ROADMAP D4 deletes it.
 
 ``STARWAY_SM_FORCE_ATOMICS``
     "1" = route the Python sm ring's cursor ops through the native lib's

@@ -86,8 +86,8 @@ class LlamaConfig:
     # count).  "dots" = save every no-batch-dim matmul output AND the
     # attention kernel's output (tagged "attn_out" in decoder_layer), so
     # the backward re-runs only the cheap elementwise chain (norms, rope,
-    # silu) — the remat knob for MFU-bound training (BASELINE.md's
-    # train_step_mfu >= 0.40 target) at O(S * D) extra saved bytes per
+    # silu) — the remat knob for MFU-bound training (the train_step_mfu
+    # >= 0.40 target, ROADMAP.md S7) at O(S * D) extra saved bytes per
     # layer.
     remat_policy: Optional[str] = None
     # Layer iteration: True scans one compiled body over the stacked layer
@@ -508,9 +508,12 @@ def default_attn(q, k, v, window: Optional[int] = None):
     DMA-elides out-of-window blocks in forward AND backward."""
     if jax.default_backend() == "tpu":
         from ..ops.pallas_attention import flash_attention
+        from ..parallel.sharding import per_head_shard
 
-        return flash_attention(q, k, v, causal=True, interpret=False,
-                               window=window)
+        return per_head_shard(
+            lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                            interpret=False, window=window),
+            (q, k, v))
     return blockwise_attention(q, k, v, causal=True, window=window)
 
 

@@ -44,9 +44,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.pallas_paged import paged_decode_attention
+from ..parallel.sharding import per_head_shard
 from .generate import _sample, cached_layer_scan, prefill
 from .llama import LlamaConfig, cfg_rope_tables, embed_tokens, matmul_w, rmsnorm
-from .serving import SlotServer, _bucket, make_chunk_scan_step
+from .serving import (SlotServer, _bucket, _on_weights_mesh,
+                      make_chunk_scan_step)
 
 
 def init_paged_pool(cfg: LlamaConfig, n_pages: int, page: int) -> dict:
@@ -83,7 +85,8 @@ def paged_decode_step(params, pool, table, token, pos, cfg: LlamaConfig,
         return c.at[pids, :, offs, :].set(u[:, :, 0, :])
 
     def attend(q, lc):
-        return paged_decode_attention(q, lc["k"], lc["v"], table, pos)
+        return per_head_shard(paged_decode_attention, (q, lc["k"], lc["v"]),
+                              (table, pos))
 
     h = embed_tokens(params, token, cfg)[:, None, :]
     h, out = cached_layer_scan(params, pool, h, cos_p, sin_p, cfg, write,
@@ -200,8 +203,8 @@ def _compiled_paged_prefix_admit(cfg: LlamaConfig, s_bucket: int, page: int,
             return c.at[pids_c, :, offs, :].set(u[0].transpose(1, 0, 2))
 
         def attend(q, lc):
-            return paged_decode_attention(q, lc["k"], lc["v"], row,
-                                          plen[None])
+            return per_head_shard(paged_decode_attention,
+                                  (q, lc["k"], lc["v"]), (row, plen[None]))
 
         from .llama import embed_tokens, head_logits
 
@@ -330,6 +333,7 @@ class PagedSlotServer(SlotServer):
             row[i] = self._free.pop()
 
     # --------------------------------------------------------- admission
+    @_on_weights_mesh
     def register_prefix(self, tokens) -> int:
         """Prefill a shared prefix ONCE into pool pages; requests with
         ``prefix=pid`` then REFERENCE its whole pages (zero copy, pages

@@ -23,8 +23,8 @@ a FIXED, small set of compiled programs:
 * **Decode runs in chunks.**  One compiled ``lax.scan`` advances ALL live
   slots ``chunk`` tokens (dead slots are masked: frozen cursor, writes
   land on a position that admission or the advancing cursor overwrites
-  before any read).  Per-token host round-trips — fatal on a tunneled
-  device — happen once per chunk, not once per token.
+  before any read).  Host round-trips happen once per chunk, not once
+  per token.
 * **Greedy continuous batching is BIT-IDENTICAL to standalone
   ``generate()``** for every request, whatever the interleaving: same
   prefill, same decode step, same masking — pinned by
@@ -271,6 +271,32 @@ def make_chunk_scan_step(decode_one, max_len: int, temperature: float,
     return step
 
 
+def _weights_mesh(params):
+    """The mesh the weights are sharded over, or None on one device."""
+    for leaf in jax.tree_util.tree_leaves(params):
+        sharding = getattr(leaf, "sharding", None)
+        if (isinstance(sharding, jax.sharding.NamedSharding)
+                and len(sharding.device_set) > 1):
+            return sharding.mesh
+    return None
+
+
+def _on_weights_mesh(method):
+    """Run a server method under the mesh its weights are sharded over:
+    the compiled programs trace their Pallas attention calls per head
+    shard of that mesh (parallel/sharding.py per_head_shard) -- the TPU's
+    compiler refuses a Mosaic kernel inside a GSPMD-partitioned program."""
+
+    @functools.wraps(method)
+    def on_mesh(self, *args, **kwargs):
+        if self.mesh is None:
+            return method(self, *args, **kwargs)
+        with jax.set_mesh(self.mesh):
+            return method(self, *args, **kwargs)
+
+    return on_mesh
+
+
 class SlotServer:
     """Continuous-batching front end over the compiled admit/decode programs.
 
@@ -318,6 +344,7 @@ class SlotServer:
             raise ValueError(f"need n_slots >= 1 and chunk >= 1, got "
                              f"{n_slots}/{chunk}")
         self.params = params
+        self.mesh = _weights_mesh(params)
         self.cfg = cfg
         self.n_slots = n_slots
         self.max_len = max_len
@@ -380,6 +407,7 @@ class SlotServer:
         """Subclass hook: a slot's request finished or was cancelled (the
         paged server returns its pages to the pool here)."""
 
+    @_on_weights_mesh
     def register_prefix(self, tokens) -> int:
         """Prefill a shared PREFIX (system prompt, few-shot preamble) once
         and return its id; requests submitted with ``prefix=pid`` reuse
@@ -567,6 +595,7 @@ class SlotServer:
                 if self.on_tokens is not None:
                     self.on_tokens(rid, [], True)
 
+    @_on_weights_mesh
     def step(self) -> dict:
         """Admit what fits, decode one chunk; returns {rid: tokens} for
         requests that finished during this step."""

@@ -25,8 +25,7 @@ Two variants share the same online-softmax block body:
   path); block 512 quarters the cell count.
 
 ``bench.py --kernels decode_tune`` sweeps both variants x block sizes on
-real hardware; the stream default is the structural bet until the chip
-confirms it.
+real hardware; which is faster there has not been measured this round.
 
 Same online-softmax algebra as ops/pallas_attention.py; layouts follow
 models/generate.py: ``q [B, Hq, 1, D]``, caches ``[B, Hkv, T, D]``.
@@ -58,7 +57,8 @@ def _softmax_block_update(q, k, v, k_start, pos, m_scr, l_scr, acc_scr, *,
     positions x n_rep query heads as the matmul rows, so each row masks
     by its own cursor.  ``None`` = all rows at ``pos``.
 
-    ``k_scale``/``v_scale`` ([block_k] f32, int8 cache): dequantization is
+    ``k_scale``/``v_scale`` ([1, block_k] f32 rows, int8 cache; see
+    :func:`decode_attention` on the scale layout): dequantization is
     folded into the existing algebra instead of widening the operands —
     k's scale multiplies the score COLUMNS (``(q . k_int8[c]) * s_k[c]``)
     and v's scale folds into the softmax weights before the ``p @ v``
@@ -68,7 +68,7 @@ def _softmax_block_update(q, k, v, k_start, pos, m_scr, l_scr, acc_scr, *,
         preferred_element_type=jnp.float32,
     )  # [rows, block_k]
     if k_scale is not None:
-        s = s * (k_scale[None, :] * sm_scale)
+        s = s * (k_scale * sm_scale)
     else:
         s = s * sm_scale
     kv_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -85,7 +85,7 @@ def _softmax_block_update(q, k, v, k_start, pos, m_scr, l_scr, acc_scr, *,
     l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
     pv_dtype = q.dtype
     if v_scale is not None:
-        p = p * v_scale[None, :]
+        p = p * v_scale
     acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
         p.astype(pv_dtype), v.astype(pv_dtype), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -191,10 +191,12 @@ def _decode_stream_kernel(pos_ref, q_ref, k_hbm, v_hbm, *refs,
         ]
         if quant:
             cps.append(pltpu.make_async_copy(
-                ks_hbm.at[bh, pl.ds(i * block_k, block_k)], ks_buf.at[slot],
+                ks_hbm.at[bh, :, pl.ds(i * block_k, block_k)],
+                ks_buf.at[slot],
                 sems.at[slot, 2]))
             cps.append(pltpu.make_async_copy(
-                vs_hbm.at[bh, pl.ds(i * block_k, block_k)], vs_buf.at[slot],
+                vs_hbm.at[bh, :, pl.ds(i * block_k, block_k)],
+                vs_buf.at[slot],
                 sems.at[slot, 3]))
         return cps
 
@@ -262,15 +264,19 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None,
     ``k_scale``/``v_scale`` ([B, Hkv, T] f32): int8-quantized caches
     (ops/quantize.py) — the kernel streams the int8 blocks (half the HBM
     bytes of bf16) and folds dequantization into the score/weight algebra;
-    both or neither must be given, matching the caches' int8 dtype.
+    both or neither must be given, matching the caches' int8 dtype.  They
+    reach the kernel as [B*Hkv, 1, T]: Mosaic blocks and DMAs the last
+    two dims in (8, 128) tiles unless a block spans the whole dim, so one
+    row OF a 2-D [B*Hkv, T] array is refused by the chip's compiler while
+    a (1, block_k) slab of a dim of size 1 is not.
 
     ``stream`` (default True; ``STARWAY_DECODE_STREAM=0`` flips the
-    default — the manual-DMA lowering's escape hatch on hardware this
-    kernel has not run on yet): the double-buffered single-cell kernel
+    default): the double-buffered single-cell kernel
     (:func:`_decode_stream_kernel`) — b*hkv grid cells total, per-cell
-    pipeline overhead independent of T.  ``stream=False`` keeps the
-    grid-pipelined kernel (one cell per kv block); ``bench.py --kernels
-    decode_tune`` sweeps both on-chip.
+    pipeline overhead independent of T; it is what serves on the chip
+    (chip_smoke.py phase c).  ``stream=False`` keeps the grid-pipelined
+    kernel (one cell per kv block) until ROADMAP D4 deletes it;
+    ``bench.py --kernels decode_tune`` sweeps both on-chip.
     """
     if stream is None:
         from ..config import decode_stream_enabled
@@ -316,9 +322,13 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None,
     scales = []
     if quant:
         for s in (k_scale, v_scale):
-            sf = s.astype(jnp.float32).reshape(b * hkv, t)
+            # [b*hkv, 1, T], not [b*hkv, T]: one row's scales are then a
+            # whole (1, block_k) slab of the last two dims, which Mosaic
+            # can block and DMA; a single row OF a 2-D f32 array is a
+            # slice below the (8, 128) tile and is refused.
+            sf = s.astype(jnp.float32).reshape(b * hkv, 1, t)
             if t_pad != t:
-                sf = jnp.pad(sf, ((0, 0), (0, t_pad - t)))
+                sf = jnp.pad(sf, ((0, 0), (0, 0), (0, t_pad - t)))
             scales.append(sf)
 
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
@@ -326,8 +336,8 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None,
     if stream:
         any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
         quant_scratch = [
-            pltpu.VMEM((2, block_k), jnp.float32),
-            pltpu.VMEM((2, block_k), jnp.float32),
+            pltpu.VMEM((2, 1, block_k), jnp.float32),
+            pltpu.VMEM((2, 1, block_k), jnp.float32),
         ] if quant else []
         out = pl.pallas_call(
             functools.partial(
@@ -377,7 +387,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None,
 
     def _scale_index(bh, ki, pos_ref):
         bh_, ki_, _ = _kv_index(bh, ki, pos_ref)
-        return (bh_, ki_)
+        return (bh_, 0, ki_)
 
     out = pl.pallas_call(
         functools.partial(_decode_kernel, sm_scale=sm_scale, block_k=block_k,
@@ -390,7 +400,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None,
                 pl.BlockSpec((1, rows, d), lambda bh, ki, pos_ref: (bh, 0, 0)),
                 pl.BlockSpec((1, block_k, d), _kv_index),
                 pl.BlockSpec((1, block_k, d), _kv_index),
-            ] + [pl.BlockSpec((1, block_k), _scale_index)] * (2 * quant),
+            ] + [pl.BlockSpec((1, 1, block_k), _scale_index)] * (2 * quant),
             out_specs=pl.BlockSpec((1, rows, d), lambda bh, ki, pos_ref: (bh, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((rows, 128), jnp.float32),

@@ -1,9 +1,9 @@
 """Symmetric int8 quantization for the KV cache.
 
 Single-token decode streams the whole KV cache through the core once per
-generated token — it is HBM-bandwidth-bound (BASELINE.md: the bf16 decode
-kernel runs at ~390 GB/s effective), so halving the cache's bytes is worth
-~2x on the decode step and doubles the context a chip can serve.  The
+generated token — it is HBM-bandwidth-bound, so halving the cache's bytes
+halves what the decode step must stream and doubles the context a chip
+can serve.  The
 scheme is the standard serving-stack one (per-token, per-head symmetric
 int8): each cached [head_dim] vector x is stored as
 

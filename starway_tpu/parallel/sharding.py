@@ -40,14 +40,34 @@ def shard_array(mesh: Mesh, x, *spec):
 
 
 def shard_map_fn(mesh: Mesh, fn, in_specs, out_specs):
-    """Version-tolerant shard_map wrapper (per-device SPMD view)."""
-    try:
-        from jax import shard_map as _sm  # jax >= 0.7 style
+    """``jax.shard_map`` over ``mesh`` (per-device SPMD view), unchecked."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm  # legacy
 
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+def per_head_shard(kernel, sharded, replicated=(), *, axis: str = "tp"):
+    """``kernel(*sharded, *replicated)``, run per shard of the head
+    dimension (dim 1 of every ``sharded`` operand and of the result) when
+    the ambient mesh (``jax.set_mesh``) has ``axis`` with more than one
+    device; called directly otherwise.
+
+    A Mosaic kernel cannot be partitioned by the compiler: traced under a
+    tensor-parallel GSPMD program it is refused ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map").
+    Heads are independent in attention and ``param_specs`` already shards
+    the q/k/v projections over ``axis`` by head, so each device runs the
+    kernel on the heads it holds and nothing moves.  Grouped-query
+    pairing survives the split while ``axis`` divides the kv heads (q
+    head h reads kv head h // n_rep)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.shape.get(axis, 1) == 1:
+        return kernel(*sharded, *replicated)
+
+    def heads(x):
+        return P(None, axis, *([None] * (x.ndim - 2)))
+
+    return jax.shard_map(
+        kernel,
+        in_specs=(*(heads(x) for x in sharded), *(P() for _ in replicated)),
+        out_specs=heads(sharded[0]), check_vma=False,
+    )(*sharded, *replicated)

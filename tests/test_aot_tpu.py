@@ -1,0 +1,362 @@
+"""Every Pallas entry point the serve and train paths reach, compiled by
+the TPU's own compiler for a DESCRIBED v5e (no chip attached).
+
+Interpret mode checks a kernel's numerics; ``lower(lowering_platforms=
+("tpu",))`` stops before Mosaic.  Neither sees what the chip's compiler
+refuses: a slice below the (8, 128) tile, a block shape it cannot lay
+out, a program that does not fit 16 GB.  These cases ask it, at
+published widths (llama3-8b: Hq 32, Hkv 8, D 128, d_ff 14336, vocab
+128256; llama2-7b: MHA, n_rep 1), a second or less per kernel.  Nothing
+runs, so they say nothing about results or speed -- chip_smoke.py does
+that on the chip.
+
+``jax.default_backend()`` still answers "cpu" here, so kernels are called
+with ``interpret=False`` and whole programs are traced with that one
+function patched to answer "tpu": the steering lives in this file, not in
+an option of the program.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+# llama3-8b attention geometry; MHA is llama2-7b's.
+HQ, HKV, D = 32, 8, 128
+MHA = 32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without a chip (each later one warns and
+    compiles again): keep it off around these tests."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _placed(args, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        args)
+
+
+def _compile(fn, *args, sharding, **jit_kw):
+    """Compile ``fn`` for ``args`` (pytrees of ShapeDtypeStructs), every
+    leaf placed by ``sharding``."""
+    return jax.jit(fn, **jit_kw).lower(*_placed(args, sharding)).compile()
+
+
+def _s(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# ------------------------------------------------------------------ kernels
+
+
+def _flash(hkv, *, window=None, grad=False, s=4096):
+    from starway_tpu.ops.pallas_attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False,
+                               window=window)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    kv = _s((1, hkv, s, D), BF16)
+    return (bwd if grad else fwd), (_s((1, HQ, s, D), BF16), kv, kv)
+
+
+def _ring_step(*, bwd=False, causal=True, t=2048):
+    """The per-step kernels ring and zigzag attention run on each shard
+    (parallel/ring_attention.py: _step_fwd / _step_bwd); zigzag's
+    provably-unmasked pair is the causal=False case."""
+    from starway_tpu.ops.pallas_attention import (flash_partial,
+                                                  flash_partial_bwd)
+
+    q, kv = _s((1, HQ, t, D), BF16), _s((1, HKV, t, D), BF16)
+    off = _s((), I32)
+    if not bwd:
+        return (lambda q, k, v, qo, ko: flash_partial(
+            q, k, v, qo, ko, causal=causal, interpret=False)), (
+                q, kv, kv, off, off)
+    stat = _s((1, HQ, t), F32)
+    return (lambda q, do, k, v, lse, dl, qo, ko: flash_partial_bwd(
+        q, do, k, v, lse, dl, qo, ko, causal=causal, interpret=False)), (
+            q, q, kv, kv, stat, stat, off, off)
+
+
+def _decode(hkv, *, c=1, int8=False, stream=True, window=None, b=8, t=2048):
+    from starway_tpu.ops.pallas_decode import decode_attention
+
+    cache = _s((b, hkv, t, D), I8 if int8 else BF16)
+    args = [_s((b, HQ, c, D), BF16), cache, cache, _s((b,), I32)]
+    if int8:
+        args += [_s((b, hkv, t), F32)] * 2
+
+    def fn(q, k, v, pos, ks=None, vs=None):
+        return decode_attention(q, k, v, pos, interpret=False, stream=stream,
+                                window=window, k_scale=ks, v_scale=vs)
+
+    return fn, tuple(args)
+
+
+def _paged(page, *, b=8, max_len=2048):
+    from starway_tpu.ops.pallas_paged import paged_decode_attention
+
+    max_pages = max_len // page
+    pool = _s((1 + b * max_pages, HKV, page, D), BF16)
+    return (lambda q, k, v, t, p: paged_decode_attention(
+        q, k, v, t, p, interpret=False)), (
+            _s((b, HQ, 1, D), BF16), pool, pool, _s((b, max_pages), I32),
+            _s((b,), I32))
+
+
+def _gemv(d_in, d_out, m=8):
+    from starway_tpu.ops.pallas_gemv import int8_matmul
+
+    return (lambda x, w, s: int8_matmul(x, w, s, interpret=False)), (
+        _s((m, d_in), BF16), _s((d_in, d_out), I8), _s((d_out,), F32))
+
+
+KERNELS = {
+    "flash_fwd": lambda: _flash(HKV),
+    "flash_fwd_windowed": lambda: _flash(HKV, window=1024),
+    "flash_bwd": lambda: _flash(HKV, grad=True),
+    "flash_bwd_windowed": lambda: _flash(HKV, grad=True, window=1024),
+    "flash_fwd_mha": lambda: _flash(MHA),
+    "flash_bwd_mha": lambda: _flash(MHA, grad=True),
+    "ring_step_fwd": lambda: _ring_step(),
+    "ring_step_fwd_unmasked": lambda: _ring_step(causal=False),
+    "ring_step_bwd": lambda: _ring_step(bwd=True),
+    "ring_step_bwd_unmasked": lambda: _ring_step(bwd=True, causal=False),
+    "decode_bf16": lambda: _decode(HKV),
+    "decode_bf16_c4": lambda: _decode(HKV, c=4),
+    "decode_bf16_windowed": lambda: _decode(HKV, window=1024),
+    "decode_bf16_grid": lambda: _decode(HKV, stream=False),
+    "decode_bf16_grid_c4": lambda: _decode(HKV, stream=False, c=4),
+    "decode_bf16_mha": lambda: _decode(MHA),
+    # Refused before PR 21: the [B*Hkv, T] scale operand was sliced one
+    # row at a time, below the (8, 128) tile.
+    "decode_int8": lambda: _decode(HKV, int8=True),
+    "decode_int8_c4": lambda: _decode(HKV, int8=True, c=4),
+    "decode_int8_windowed": lambda: _decode(HKV, int8=True, window=1024),
+    "decode_int8_grid": lambda: _decode(HKV, int8=True, stream=False),
+    "decode_int8_grid_c4": lambda: _decode(HKV, int8=True, stream=False, c=4),
+    "decode_int8_grid_windowed": lambda: _decode(
+        HKV, int8=True, stream=False, window=1024),
+    "decode_int8_mha": lambda: _decode(MHA, int8=True),
+    "paged_page16": lambda: _paged(16),
+    "paged_page64": lambda: _paged(64),
+    "paged_page128": lambda: _paged(128),
+    # Every llama3-8b matmul shape the W8A16 tree routes through the gemv.
+    "gemv_wq_wo": lambda: _gemv(4096, 4096),
+    "gemv_wk_wv": lambda: _gemv(4096, 1024),
+    "gemv_gate_up": lambda: _gemv(4096, 14336),
+    "gemv_down": lambda: _gemv(14336, 4096),
+    "gemv_lm_head": lambda: _gemv(4096, 128256),
+}
+
+
+@pytest.mark.parametrize("name", list(KERNELS))
+def test_kernel_compiles_for_v5e(topo, name):
+    fn, args = KERNELS[name]()
+    compiled = _compile(fn, *args,
+                        sharding=SingleDeviceSharding(topo.devices[0]))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ----------------------------------------------------------- whole programs
+
+
+def _llama3_8l(**kw):
+    from starway_tpu.models import LlamaConfig
+
+    return LlamaConfig.preset("llama3-8b", n_layers=8, **kw)
+
+
+def _param_shapes(cfg):
+    from starway_tpu.models import init_params
+
+    return jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
+
+
+def _as_tpu(monkeypatch):
+    """Programs pick their TPU branches from jax.default_backend()."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+N_SLOTS, MAX_LEN, CHUNK = 8, 2048, 8
+
+
+def _slot_state():
+    return (_s((N_SLOTS,), I32), _s((N_SLOTS,), I32), _s((N_SLOTS,), bool),
+            _s((N_SLOTS,), I32), jax.eval_shape(jax.random.PRNGKey, 0))
+
+
+def _chunk_program(cfg):
+    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.serving import _compiled_chunk
+
+    run = _compiled_chunk(cfg, N_SLOTS, MAX_LEN, CHUNK, 0.0, None, None, None)
+    cache = jax.eval_shape(lambda: init_cache(cfg, N_SLOTS, MAX_LEN))
+    return run, (_param_shapes(cfg), cache, *_slot_state())
+
+
+def _admit_program(cfg, bucket=2048):
+    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.serving import _compiled_admit
+
+    run = _compiled_admit(cfg, bucket, 0.0, None, None)
+    cache = jax.eval_shape(lambda: init_cache(cfg, N_SLOTS, MAX_LEN))
+    return run, (_param_shapes(cfg), cache, _s((1, bucket), I32), _s((), I32),
+                 _s((), I32), jax.eval_shape(jax.random.PRNGKey, 0))
+
+
+def _paged_chunk_program(cfg, page=64):
+    from starway_tpu.models.paged import _compiled_paged_chunk, init_paged_pool
+
+    max_pages = MAX_LEN // page
+    run = _compiled_paged_chunk(cfg, MAX_LEN, CHUNK, 0.0, None, None, None)
+    pool = jax.eval_shape(
+        lambda: init_paged_pool(cfg, 1 + N_SLOTS * max_pages, page))
+    return run, (_param_shapes(cfg), pool, _s((N_SLOTS, max_pages), I32),
+                 *_slot_state())
+
+
+def _memory_gb(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes) / 1e9
+
+
+def _compile_program(topo, build, cfg):
+    run, args = build(cfg)
+    # The programs are jitted already (donation included): lower them as
+    # the servers call them.
+    return run.lower(
+        *_placed(args, SingleDeviceSharding(topo.devices[0]))).compile()
+
+
+def test_slot_server_decode_chunk_compiles_for_v5e(topo, monkeypatch):
+    """The whole SlotServer decode-chunk program (models/serving.py) at
+    llama3-8b widths, 8 layers, 8 slots x 2048 -- what chip_smoke.py
+    phase c runs -- fits one 16 GB chip and holds the decode kernel."""
+    _as_tpu(monkeypatch)
+    compiled = _compile_program(topo, _chunk_program, _llama3_8l())
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _memory_gb(compiled) < 15.75
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name,build,kw", [
+    ("chunk_int8", _chunk_program, dict(kv_quant="int8")),
+    ("admit_2048", _admit_program, {}),
+    ("admit_2048_int8", _admit_program, dict(kv_quant="int8")),
+    ("paged_chunk", _paged_chunk_program, {}),
+])
+def test_serving_program_compiles_for_v5e(topo, monkeypatch, name, build, kw):
+    _as_tpu(monkeypatch)
+    compiled = _compile_program(topo, build, _llama3_8l(**kw))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _memory_gb(compiled) < 15.75
+
+
+# chip_smoke.py phase d: llama2-7b widths, depth and batch cut to what one
+# chip's 16 GB holds (chip_smoke.TRAIN).
+@pytest.mark.slow
+@pytest.mark.parametrize("attn", ["flash", "lax"])
+def test_train_loss_and_grad_compile_for_v5e(topo, monkeypatch, attn):
+    """The flash forward AND backward kernels under jax.grad, and the lax
+    reference they are held to, whose temporaries are what bound the cut
+    to depth 4 x batch 2."""
+    import chip_smoke
+
+    _as_tpu(monkeypatch)
+    cfg = chip_smoke.train_config()
+    batch = _s((chip_smoke.TRAIN["batch"], chip_smoke.TRAIN["seq"] + 1), I32)
+    fn = chip_smoke._loss_and_grad_norm(
+        cfg, None if attn == "flash" else chip_smoke._plain_attn())
+    compiled = fn.lower(*_placed((_param_shapes(cfg), batch),
+                                 SingleDeviceSharding(topo.devices[0]))
+                        ).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == (attn == "flash")
+    assert _memory_gb(compiled) < 15.75
+
+
+@pytest.mark.slow
+def test_trainer_programs_fit_v5e(topo, monkeypatch):
+    """Trainer.step_sync's two programs (grad, then adamw apply)."""
+    import optax
+
+    import chip_smoke
+    from starway_tpu.models.llama import apply_updates, loss_fn
+
+    _as_tpu(monkeypatch)
+    cfg, tx = chip_smoke.train_config(), optax.adamw(1e-3)
+    params = _param_shapes(cfg)
+    opt = jax.eval_shape(tx.init, params)
+    batch = _s((chip_smoke.TRAIN["batch"], chip_smoke.TRAIN["seq"] + 1), I32)
+    one = SingleDeviceSharding(topo.devices[0])
+    grad = _compile(lambda p, b: jax.value_and_grad(loss_fn)(p, b, cfg),
+                    params, batch, sharding=one)
+    apply = _compile(lambda p, o, g: apply_updates(tx, p, o, g), params, opt,
+                     params, sharding=one, donate_argnums=(0, 1))
+    # While the grad program runs the Trainer also holds the adamw state.
+    opt_gb = sum(x.size * x.dtype.itemsize
+                 for x in jax.tree_util.tree_leaves(opt)) / 1e9
+    assert _memory_gb(grad) + opt_gb < 15.75
+    assert _memory_gb(apply) < 15.75
+
+
+# chip_smoke.py --chips 4: the dp x tp x sp train step with ring attention
+# and the tp=2 SlotServer chunk, on the described 2x2 mesh.
+@pytest.mark.slow
+def test_mesh_train_step_compiles_for_v5e_2x2(topo, monkeypatch):
+    import chip_smoke
+
+    _as_tpu(monkeypatch)
+    step, args = chip_smoke.mesh_train_program(topo.devices)
+    compiled = step.lower(*args).compile()
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt and "collective-permute" in txt
+    assert _memory_gb(compiled) < 15.75
+
+
+@pytest.mark.slow
+def test_tp_slot_server_chunk_compiles_for_v5e_2x2(topo, monkeypatch):
+    import chip_smoke
+
+    _as_tpu(monkeypatch)
+    mesh, run, args = chip_smoke.tp_chunk_program(topo.devices[:2])
+    with jax.set_mesh(mesh):
+        compiled = run.lower(*args).compile()
+    txt = compiled.as_text()
+    # The decode kernel runs per head shard; only activations cross chips.
+    assert "tpu_custom_call" in txt and "all-reduce" in txt
+    assert _memory_gb(compiled) < 15.75

@@ -30,23 +30,24 @@ N = 1 << 20  # 1 MiB: comfortably above STARWAY_DEVPULL_MIN
 
 
 def _pull_available() -> bool:
-    """Whether this jax build ships the transfer API at all (jax 0.4.37,
-    for example, has no jax.experimental.transfer / start_transfer_server).
-    Without it the capability is never negotiated and payloads stage --
-    correct delivery, so only the tests asserting the PULL transport must
-    skip; fallback/ordering/truncation tests still run."""
-    jax.devices()  # backend up first: the probe never initialises one
+    """Whether devpull would be negotiated in this process.  Brings the
+    jax backend up first: the probe itself never initialises one."""
+    jax.devices()
     from starway_tpu.device import devpull_supported
 
     return devpull_supported()
 
 
-requires_pull = pytest.mark.skipif(
-    not _pull_available(),
-    reason="PJRT transfer API unavailable in this jax build "
-           "(devpull_supported() is False; payloads stage instead)",
-)
+@pytest.fixture
+def _needs_pull():
+    if not _pull_available():
+        pytest.skip("devpull_supported() is False here; payloads stage")
 
+
+# A fixture, not a module-level skipif: the probe brings the jax backend
+# up, and the spawned children of this file import it -- a
+# jax.distributed member must initialise BEFORE its backend exists.
+requires_pull = pytest.mark.usefixtures("_needs_pull")
 
 
 @pytest.fixture(autouse=True)

@@ -1,57 +1,81 @@
-"""On-chip kernel numerics, gated behind STARWAY_ONCHIP=1.
+"""chip_smoke.py, from the suite.
 
-The regular suite pins kernel numerics in CPU interpret mode
-(tests/test_pallas.py); this marker runs the hardware half of that
-contract -- scripts/kernel_bench.py --which check in a clean subprocess
-(the suite's conftest pins this process to the CPU platform, so the chip
-is only reachable from a child with an untouched environment).
+The suite itself is pinned to the CPU (conftest.py), so the chip is only
+reachable from a child with a clean environment: one process per chip, and
+this one never touches it.
+
+* Without a TPU the script must give up at the platform check: non-zero
+  exit, no ``"ok": true`` line, nothing built or compiled.  That runs
+  everywhere, in a second or two.
+* With a TPU attached, the whole script runs ONCE (minutes: marked slow)
+  and each phase's JSON line is checked as its own case.
 """
 
 import json
 import os
 import subprocess
 import sys
+import time
 from pathlib import Path
 
 import pytest
 
+REPO = Path(__file__).resolve().parent.parent
+SMOKE = REPO / "chip_smoke.py"
+PHASES = ("setup", "a_transport_inproc", "b_transport_sockets",
+          "c_served_model", "d_trainer")
 
-def _clean_env():
-    return {k: v for k, v in os.environ.items()
-            if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+
+def _json_lines(stdout: str) -> list:
+    return [json.loads(l) for l in stdout.splitlines() if l.startswith("{")]
 
 
-def _kernel_bench(which: str, timeout: int = 840):
+def test_chip_smoke_refuses_the_cpu():
+    t0 = time.perf_counter()
     out = subprocess.run(
-        [sys.executable, str(Path(__file__).parent.parent / "scripts" / "kernel_bench.py"),
-         "--which", which],
-        capture_output=True, text=True, timeout=timeout, env=_clean_env(),
-    )
-    rows = [json.loads(l) for l in out.stdout.splitlines() if l.startswith("{")]
-    return out, rows
+        [sys.executable, str(SMOKE)], capture_output=True, text=True,
+        timeout=120, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    took = time.perf_counter() - t0
+    assert out.returncode != 0
+    assert "no TPU" in out.stderr, out.stderr[-2000:]
+    # It gave up at the platform check: no phase line (not even setup, which
+    # follows the native build), no last line, and no time to have compiled.
+    assert _json_lines(out.stdout) == [], out.stdout
+    assert '"ok": true' not in out.stdout
+    assert took < 30, f"took {took:.1f}s to refuse the CPU"
 
 
-@pytest.mark.skipif(os.environ.get("STARWAY_ONCHIP") != "1",
-                    reason="on-chip numerics need a real TPU; enable with STARWAY_ONCHIP=1")
-def test_onchip_kernel_numerics():
-    out, rows = _kernel_bench("check")
-    assert out.returncode == 0, f"on-chip checks failed:\n{out.stdout}\n{out.stderr}"
-    # 3 base rows + 3 windowed rows (flash window fwd/bwd, windowed decode).
-    assert len(rows) == 6 and all(r["ok"] for r in rows), rows
+def _has_chip() -> bool:
+    """A TPU's device node, seen without touching JAX (v5e: /dev/vfio/<n>;
+    older generations: /dev/accel<n>)."""
+    dev = Path("/dev")
+    return any(dev.glob("accel*")) or any(
+        p.name.isdigit() for p in (dev / "vfio").glob("*"))
 
 
-@pytest.mark.skipif(os.environ.get("STARWAY_ONCHIP") != "1",
-                    reason="serving throughput needs a real TPU; enable with STARWAY_ONCHIP=1")
-def test_onchip_serve_throughput():
-    """End-to-end generate() tokens/s on the chip (VERDICT r2 next #4).
+@pytest.fixture(scope="module")
+def smoke_run():
+    if not _has_chip():
+        pytest.skip("no TPU attached to this machine")
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    return subprocess.run([sys.executable, str(SMOKE)], capture_output=True,
+                          text=True, timeout=1250, env=env, cwd=REPO)
 
-    The floor is deliberately loose (the 8L/d1024 bench model is
-    bandwidth-bound around ~150 us/token of weight traffic on a v5e, so
-    thousands of tok/s are available): it exists to catch the serving path
-    falling off a cliff — a lost jit cache, a host sync per token — not to
-    pin single-digit percentages.  BASELINE.md records the measured value."""
-    out, rows = _kernel_bench("serve", timeout=1200)
-    assert rows and "error" not in rows[-1], f"{rows}\n{out.stderr}"
-    row = rows[-1]
-    assert row["metric"] == "serve_llama_b1_tokens_per_s"
-    assert row["value"] > 100, row
+
+@pytest.mark.slow
+@pytest.mark.parametrize("phase", PHASES)
+def test_onchip_phase(smoke_run, phase):
+    lines = [l for l in _json_lines(smoke_run.stdout)
+             if l.get("phase") == phase]
+    assert len(lines) == 1 and lines[0]["ok"] is True, (
+        f"{phase}: {lines}\n{smoke_run.stdout[-3000:]}\n"
+        f"{smoke_run.stderr[-3000:]}")
+
+
+@pytest.mark.slow
+def test_onchip_last_line(smoke_run):
+    assert smoke_run.returncode == 0, smoke_run.stderr[-3000:]
+    last = json.loads(smoke_run.stdout.strip().splitlines()[-1])
+    assert last["ok"] is True and last["device"]["platform"] == "tpu"
+    assert set(last) == {"ok", "device"}

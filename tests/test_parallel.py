@@ -366,3 +366,58 @@ def test_windowed_model_sharded_attn():
         resolve_attn_fn(cfg, make_sharded_attn(mesh))
     with pytest.raises(ValueError, match="window=4"):
         resolve_attn_fn(cfg, make_sharded_attn(mesh, window=4))
+
+
+@pytest.mark.parametrize("case", ["decode_bf16", "decode_int8", "flash"])
+def test_per_head_shard_matches_unsharded(case):
+    """The Pallas attention calls run per tp shard of the head dimension
+    under an ambient mesh (parallel/sharding.py per_head_shard: the TPU's
+    compiler refuses a Mosaic kernel inside a partitioned program).  GQA
+    pairing must survive the split: q head h reads kv head h // n_rep."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from starway_tpu.models.generate import _attend_cached
+    from starway_tpu.ops.pallas_attention import flash_attention
+    from starway_tpu.ops.quantize import quantize_kv
+    from starway_tpu.parallel.sharding import per_head_shard
+
+    b, hq, hkv, t, d = 2, 8, 4, 256, 64
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(11), 3)
+    k = jax.random.normal(kk, (b, hkv, t, d), jnp.float32)
+    v = jax.random.normal(kv, (b, hkv, t, d), jnp.float32)
+    if case == "flash":
+        q = jax.random.normal(kq, (b, hq, t, d), jnp.float32)
+
+        def run(q, k, v):
+            return per_head_shard(
+                lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                                interpret=True), (q, k, v))
+        args = (q, k, v)
+    else:
+        q = jax.random.normal(kq, (b, hq, 1, d), jnp.float32)
+        pos = jnp.asarray([100, 37], jnp.int32)
+        scales = {}
+        if case == "decode_int8":
+            q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+            (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+            scales = dict(k_scale=ks, v_scale=vs)
+
+        def run(q, k, v, **sc):
+            return _attend_cached(q, k, v, pos, hq // hkv, use_pallas=True,
+                                  **sc)
+        args = (q, k, v)
+
+    want = jax.jit(run)(*args, **(scales if case != "flash" else {}))
+    mesh = make_mesh({"tp": 2}, jax.devices()[:2])
+    heads = NamedSharding(mesh, P(None, "tp"))
+    sharded = [jax.device_put(x, heads) for x in args]
+    kw = ({n: jax.device_put(s, heads) for n, s in scales.items()}
+          if case != "flash" else {})
+    with jax.set_mesh(mesh):
+        jitted = jax.jit(run)
+        got = jitted(*sharded, **kw)
+        assert "shard_map" in str(jitted.trace(*sharded, **kw).jaxpr)
+    assert got.sharding.spec[1] == "tp"
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=2e-6, rtol=2e-6)

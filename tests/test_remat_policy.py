@@ -9,8 +9,8 @@ pallas custom calls are counted by kernel name in the lowered StableHLO
 once regardless of depth).
 
 Reference intent: the reference has no remat machinery at all (its compute
-layer is torch); this pins the TPU-native lever that BASELINE.md's
-train_step_mfu >= 0.40 target rides on.
+layer is torch); this pins the TPU-native lever that the
+train_step_mfu >= 0.40 target (ROADMAP.md S7) rides on.
 
 Background (jax 0.9): a whole-layer jax.checkpoint whose policy saves the
 q/k/v projection dots makes partial-eval replay the flash custom_vjp's

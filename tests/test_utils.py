@@ -381,3 +381,51 @@ def test_probe_tag_dropped_on_wire_both_engines(monkeypatch):
             await s.aclose()
 
     asyncio.run(drive())
+
+
+# ------------------------------------------------- utils/chip.py (PR 21)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR set: no directory is set in code.  Unset:
+    one fixed path inside the checkout, never a temporary name."""
+    import jax
+
+    from starway_tpu.utils import chip
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert chip.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before[keys[0]]
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            first = chip.enable_compile_cache()
+            assert first == chip.enable_compile_cache()  # fixed, not per call
+            assert first == jax.config.jax_compilation_cache_dir
+            repo = str(chip.Path(chip.__file__).resolve().parents[2])
+            assert first == repo + "/.jax_cache"
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+
+
+def test_measuring_entry_points_refuse_the_cpu():
+    from starway_tpu.utils import chip
+
+    assert chip.device_info()["platform"] == "cpu"  # the suite's platform
+    with pytest.raises(SystemExit, match="no accelerator"):
+        chip.require_accelerator()
+
+
+def test_peaks_are_keyed_by_device_kind():
+    from starway_tpu.utils import chip
+
+    assert chip.peaks("TPU v5 lite")["bf16_flops"] == 197e12
+    with pytest.raises(SystemExit, match="no published peaks"):
+        chip.peaks("cpu")
