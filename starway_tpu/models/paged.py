@@ -47,7 +47,7 @@ from ..ops.pallas_paged import paged_decode_attention
 from ..parallel.sharding import per_head_shard
 from .generate import _sample, cached_layer_scan, prefill
 from .llama import LlamaConfig, cfg_rope_tables, embed_tokens, matmul_w, rmsnorm
-from .serving import (SlotServer, _bucket, _on_weights_mesh,
+from .serving import (SlotServer, _bucket, _named_jit, _on_weights_mesh,
                       make_chunk_scan_step)
 
 
@@ -117,7 +117,8 @@ def _compiled_paged_admit(cfg: LlamaConfig, p_bucket: int, page: int,
         tok = _sample(logits, key, temperature, top_k, top_p)[0]
         return pool, tok
 
-    return jax.jit(run, donate_argnums=(1,))
+    return _named_jit(run, f"serve_paged_admit_{p_bucket}",
+                      donate_argnums=(1,))
 
 
 @functools.cache
@@ -139,7 +140,7 @@ def _compiled_paged_chunk(cfg: LlamaConfig, max_len: int, chunk: int,
             length=chunk)
         return pool, token, pos, live, remaining, key, toks, mask
 
-    return jax.jit(run, donate_argnums=(1,))
+    return _named_jit(run, "serve_paged_decode_chunk", donate_argnums=(1,))
 
 
 @functools.cache
@@ -168,7 +169,8 @@ def _compiled_paged_prefix_write(cfg: LlamaConfig, p_bucket: int, page: int,
                                           small[name].dtype))
         return pool, tails["k"], tails["v"]
 
-    return jax.jit(run, donate_argnums=(1,))
+    return _named_jit(run, f"serve_paged_prefix_register_{p_bucket}",
+                      donate_argnums=(1,))
 
 
 @functools.cache
@@ -184,6 +186,7 @@ def _compiled_paged_prefix_admit(cfg: LlamaConfig, s_bucket: int, page: int,
     traced argument, so prefixes of any length share the program."""
     rope = cfg_rope_tables(cfg, max_pages * page)
     cos, sin = rope
+    name = f"serve_paged_prefix_admit_{s_bucket}"
 
     def run(params, pool, tail_k, tail_v, row, suffix, s_len, plen, key):
         pool = dict(pool)
@@ -224,8 +227,8 @@ def _compiled_paged_prefix_admit(cfg: LlamaConfig, s_bucket: int, page: int,
             return run(params, pool, None, None, row, suffix, s_len, plen,
                        key)
 
-        return jax.jit(run_no_tail, donate_argnums=(1,))
-    return jax.jit(run, donate_argnums=(1,))
+        return _named_jit(run_no_tail, name, donate_argnums=(1,))
+    return _named_jit(run, name, donate_argnums=(1,))
 
 
 class PagedSlotServer(SlotServer):

@@ -23,7 +23,13 @@ type   direction tag              payload
 0xA2   C -> S    REQUEST | cid    [nonce, max_new, n, prompt x n]
 0xA3   S -> C    TOKENS | nonce   [nonce, status, count, tokens x count]
                                   status: 0 = streaming, 1 = done,
-                                  2 = aborted (rejected or cancelled)
+                                  2 = aborted (rejected or cancelled).
+                                  A done frame carries a TRAILER after
+                                  its tokens: the server's timing of the
+                                  request, ``TIMING_WORDS`` int32 values
+                                  (microsecond durations and a count,
+                                  below).  A reader that stops at
+                                  ``3 + count`` words never sees it
 0xA4   C -> S    CANCEL | cid     [nonce] — abort that request; its slot
                                   frees on the next decode step
 ====== ========= ================ =======================================
@@ -34,6 +40,29 @@ its endpoint, so the request tag carries the server-assigned client_id
 token stream needs no client id in its tag — it rides the requesting
 client's own connection — so the low bits carry the client-chosen nonce,
 letting one client run many concurrent generates.
+
+The timing trailer (the stream's ``Server-Timing``; DESIGN.md §13) --
+each duration in microseconds on the server's clock, clipped to int32:
+
+================ =====================================================
+word             from -> to
+================ =====================================================
+recv_submit      REQUEST receive completed -> ``SlotServer.submit`` (the
+                 bridge's queue: a ``step()`` was running in the executor)
+submit_admit0    submit -> the request's admission begins (the
+                 scheduler's queue: no slot was free, or a chunk ran)
+admit0_first     admission begins -> its first token is a host int
+first_post       first token -> its TOKENS send is posted (the bridge
+                 sends after ``step()`` returns: the rest of the chunk)
+recv_done_post   REQUEST receive completed -> this frame is posted
+steps            ``step()`` calls the request lived through (a count)
+================ =====================================================
+
+``RemoteGenerateSession.generate`` reads the trailer only when the
+received length says it is there (an older server sends none), stamps
+its own ``t_send`` / ``t_first_rx`` / ``t_done_rx``, fills
+``handle.timing`` and appends one client row to
+``serving.request_log()`` of its process.
 
 The per-chunk TOKENS messages for one request are FIFO on one
 connection (the engine preserves per-connection send order), so the
@@ -48,13 +77,15 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from collections import deque
 from typing import Optional
 
 import numpy as np
 
+from .. import perf
 from ..api import Client, Server
-from .serving import SlotServer
+from .serving import SlotServer, log_request
 
 logger = logging.getLogger("starway.serve_remote")
 
@@ -68,6 +99,27 @@ TYPE_MASK = 0xFF << TAG_TYPE_SHIFT
 STATUS_STREAMING, STATUS_DONE, STATUS_ABORTED = 0, 1, 2
 FULL_MASK = (1 << 64) - 1
 _ID_MASK = (1 << 32) - 1
+
+#: The done frame's trailer, in wire order (the table in the module
+#: docstring).  All but ``steps`` are microseconds.
+TIMING_WORDS = ("recv_submit", "submit_admit0", "admit0_first",
+                "first_post", "recv_done_post", "steps")
+_now = time.perf_counter   # the clock of serving.request_log()
+_I32_MAX = (1 << 31) - 1
+
+
+def _timing_trailer(row: dict) -> list:
+    """The trailer words of a finished request's server row; a stamp the
+    row lacks makes its word 0."""
+    def us(a: str, b: str) -> int:
+        t0, t1 = row.get(a), row.get(b)
+        if t0 is None or t1 is None:
+            return 0
+        return max(0, min(_I32_MAX, round((t1 - t0) * 1e6)))
+
+    return [us("t_recv", "t_submit"), us("t_submit", "t_admit0"),
+            us("t_admit0", "t_first"), us("t_first", "t_first_post"),
+            us("t_recv", "t_done_post"), min(_I32_MAX, row.get("steps", 0))]
 
 
 def _wire(words) -> np.ndarray:
@@ -106,8 +158,9 @@ class RemoteSlotServer:
         self._eps: dict[int, object] = {}      # client_id -> endpoint
         self._next_cid = 1
         self._rid_route: dict[int, tuple] = {}  # rid -> (cid, nonce)
+        self._rid_row: dict[int, dict] = {}     # rid -> its request_log() row
         self._emissions: list = []              # (rid, tokens, done)
-        self._requests: deque = deque()         # (sender_tag, payload copy)
+        self._requests: deque = deque()   # (sender_tag, payload copy, t_recv)
         self._unassigned: deque = deque()       # cids awaiting their ASSIGN
         self._dead_cids: deque = deque()        # send-failed clients to drop
         self._stopping = False
@@ -171,7 +224,7 @@ class RemoteSlotServer:
     def _post_request_recv(self) -> None:
         self._post_typed_recv(
             TAG_REQUEST, 3 + self.max_prompt_tokens,
-            lambda stag, words: self._requests.append((stag, words)))
+            lambda stag, words: self._requests.append((stag, words, _now())))
 
     def _post_cancel_recv(self) -> None:
         def on_msg(stag, words):
@@ -196,7 +249,7 @@ class RemoteSlotServer:
                     # Decoding for a peer that will never read the
                     # stream is wasted chip time: free the slot too.
                     self.slot.cancel(rid)
-                    del self._rid_route[rid]
+                    self._unroute(rid)
             for k in [k for k in self._pre_cancels if k[0] == cid]:
                 self._pre_cancels.pop(k, None)  # free the stash budget
 
@@ -206,7 +259,7 @@ class RemoteSlotServer:
             for rid, (rcid, rnonce) in list(self._rid_route.items()):
                 if rcid == cid and rnonce == nonce:
                     self.slot.cancel(rid)
-                    del self._rid_route[rid]
+                    self._unroute(rid)
                     # Closure marker so a still-listening generate()
                     # terminates instead of awaiting forever.
                     self._send_chunk(cid, nonce, [], STATUS_ABORTED)
@@ -242,7 +295,7 @@ class RemoteSlotServer:
     def _drain_requests(self) -> int:
         n = 0
         while self._requests:
-            stag, arr = self._requests.popleft()
+            stag, arr, t_recv = self._requests.popleft()
             cid = stag & _ID_MASK
             if cid not in self._eps:
                 # No endpoint to reply over; the sender is gone or buggy.
@@ -272,11 +325,20 @@ class RemoteSlotServer:
                 self._send_chunk(cid, nonce, [], STATUS_ABORTED)
                 continue
             self._rid_route[rid] = (cid, nonce)
+            row = self.slot.open_row(rid)
+            if row is not None:
+                row.update(route=f"{cid}:{nonce}", t_recv=t_recv,
+                           t_first_post=None, t_done_post=None)
+                self._rid_row[rid] = row
             n += 1
         return n
 
+    def _unroute(self, rid: int) -> None:
+        del self._rid_route[rid]
+        self._rid_row.pop(rid, None)
+
     def _send_chunk(self, cid: int, nonce: int, tokens: list,
-                    status) -> None:
+                    status, trailer=()) -> None:
         ep = self._eps.get(cid)
         if ep is None:
             return
@@ -288,20 +350,29 @@ class RemoteSlotServer:
             self._dead_cids.append(cid)
 
         self.server.send(
-            ep, _wire([nonce, int(status), len(tokens), *tokens]),
+            ep, _wire([nonce, int(status), len(tokens), *tokens, *trailer]),
             TAG_TOKENS | nonce, lambda: None, failed)
 
     def _flush_emissions(self) -> None:
         emissions, self._emissions = self._emissions, []
-        for rid, tokens, done in emissions:
-            route = self._rid_route.get(rid)
-            if route is None:
-                continue  # cancelled mid-step; stream already closed
-            cid, nonce = route
-            self._send_chunk(cid, nonce, tokens,
-                             STATUS_DONE if done else STATUS_STREAMING)
-            if done:
-                del self._rid_route[rid]
+        if not emissions:
+            return
+        with perf.stage_span(self.slot.stage_scope, "bridge.emit"):
+            for rid, tokens, done in emissions:
+                route = self._rid_route.get(rid)
+                if route is None:
+                    continue  # cancelled mid-step; stream already closed
+                cid, nonce = route
+                row = self._rid_row.get(rid, {})
+                if tokens and row.get("t_first_post") is None:
+                    row["t_first_post"] = _now()
+                if not done:
+                    self._send_chunk(cid, nonce, tokens, STATUS_STREAMING)
+                    continue
+                row["t_done_post"] = _now()
+                self._send_chunk(cid, nonce, tokens, STATUS_DONE,
+                                 _timing_trailer(row))
+                self._unroute(rid)
 
     async def serve(self, *, idle_sleep: float = 0.002) -> None:
         """Drive until :meth:`stop` AND all in-flight work has drained.
@@ -314,10 +385,13 @@ class RemoteSlotServer:
         loop = asyncio.get_running_loop()
         while not (self._stopping and not self.slot.busy
                    and not self._requests):
-            self._drop_dead_clients()
-            self._drain_cancels()
-            self._flush_assigns()
-            self._drain_requests()
+            if (self._requests or self._cancels or self._unassigned
+                    or self._dead_cids):
+                with perf.stage_span(self.slot.stage_scope, "bridge.drain"):
+                    self._drop_dead_clients()
+                    self._drain_cancels()
+                    self._flush_assigns()
+                    self._drain_requests()
             if self.slot.busy:
                 await loop.run_in_executor(None, self.slot.step)
                 self._flush_emissions()
@@ -338,6 +412,7 @@ class RemoteSlotServer:
 
     async def aclose(self) -> None:
         self._closed = True
+        self.slot.close()   # its serve.* / bridge.* spans outlive it
         await self.server.aclose()
 
 
@@ -354,9 +429,15 @@ class RemoteGenerateSession:
 
     class Handle:
         """Out-param for generate(): carries the request nonce so the
-        caller can cancel() a stream it no longer wants."""
+        caller can cancel() a stream it no longer wants, and, once the
+        stream has ended, ``timing``: the request's client row of
+        ``serving.request_log()`` (``t_send`` / ``t_first_rx`` /
+        ``t_done_rx`` on this process's ``time.perf_counter``, and
+        ``server_us``, the done frame's trailer by ``TIMING_WORDS`` name,
+        None from a server that sent none)."""
 
         nonce: Optional[int] = None
+        timing: Optional[dict] = None
 
     def __init__(self, client: Client):
         self.client = client
@@ -407,24 +488,45 @@ class RemoteGenerateSession:
         req = _wire(np.concatenate([
             np.asarray([nonce, int(max_new_tokens), len(prompt)], np.int32),
             prompt]))
+        row = {"side": "client", "route": f"{self.client_id}:{nonce}",
+               "n_prompt": len(prompt), "n_out": 0, "t_send": _now(),
+               "t_first_rx": None, "t_done_rx": None, "status": "running",
+               "server_us": None}
         await self.client.asend(req, TAG_REQUEST | self.client_id)
         out: list = []
-        while True:
-            buf = _recv_buf(3 + max_chunk_tokens)
-            await self.client.arecv(buf, TAG_TOKENS | nonce, FULL_MASK)
-            words = buf.view(np.int32)
-            count, status = int(words[2]), int(words[1])
-            chunk = [int(t) for t in words[3:3 + count]]
-            out.extend(chunk)
-            if chunk and on_tokens is not None:
-                on_tokens(chunk)
-            if status == STATUS_ABORTED:
-                raise ValueError(
-                    "request rejected or cancelled by the server "
-                    f"(after {len(out)} tokens); rejections mean "
-                    "prompt/max_new exceeded the server's max_len")
-            if status == STATUS_DONE:
-                return np.asarray(out, np.int32)
+        try:
+            while True:
+                buf = _recv_buf(3 + max_chunk_tokens + len(TIMING_WORDS))
+                _stag, length = await self.client.arecv(
+                    buf, TAG_TOKENS | nonce, FULL_MASK)
+                words = buf.view(np.int32)
+                count, status = int(words[2]), int(words[1])
+                chunk = [int(t) for t in words[3:3 + count]]
+                if chunk and row["t_first_rx"] is None:
+                    row["t_first_rx"] = _now()
+                out.extend(chunk)
+                if chunk and on_tokens is not None:
+                    on_tokens(chunk)
+                if status == STATUS_ABORTED:
+                    row["status"] = "aborted"
+                    raise ValueError(
+                        "request rejected or cancelled by the server "
+                        f"(after {len(out)} tokens); rejections mean "
+                        "prompt/max_new exceeded the server's max_len")
+                if status == STATUS_DONE:
+                    row["status"] = "done"
+                    end = 3 + count + len(TIMING_WORDS)
+                    if length // 4 >= end:   # an older server sends none
+                        row["server_us"] = dict(zip(
+                            TIMING_WORDS, map(int, words[3 + count:end])))
+                    return np.asarray(out, np.int32)
+        finally:
+            if row["status"] == "running":   # the transport failed, or
+                row["status"] = "failed"     # the caller gave up
+            row.update(t_done_rx=_now(), n_out=len(out))
+            log_request(row)
+            if handle is not None:
+                handle.timing = row
 
     async def cancel(self, handle: "Handle") -> None:
         """Abort the stream identified by ``handle`` server-side: its

@@ -54,6 +54,8 @@ same rule as ragged ``generate()``.
 from __future__ import annotations
 
 import functools
+import itertools
+import time
 from collections import deque
 from typing import Optional
 
@@ -63,9 +65,75 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import perf
+from ..core import swtrace
 from .generate import (_sample, decode_step, init_cache, init_rolling_cache,
                        prefill)
 from .llama import LlamaConfig, cfg_rope_tables
+
+# ----------------------------------------------------------- the serve logs
+#
+# The serve scope (DESIGN.md §13): two bounded, always-on logs of what the
+# scheduler did, kept at module level so they outlive the server that wrote
+# them (a caller that frees the server to make room on the chip still reads
+# them).  One clock, ``time.perf_counter`` -- the call swtrace's ring stamps
+# with, CLOCK_MONOTONIC on Linux, so the stamps compare with
+# ``time.monotonic`` windows and with the engines' trace events.  One dict
+# per request (stamped where its state changes, never per token) and one per
+# ``step()``; the fields are listed at :func:`request_log` / :func:`step_log`.
+
+LOG_ROWS = 4096
+_request_log: deque = deque(maxlen=LOG_ROWS)
+_step_log: deque = deque(maxlen=LOG_ROWS)
+_server_ids = itertools.count(1)
+_now = time.perf_counter
+
+
+def request_log() -> list:
+    """The last ``LOG_ROWS`` requests of this process, oldest first, as
+    copies.  A SERVER row (``side: "server"``, written by
+    :class:`SlotServer`) is identified by ``(server, rid)`` and carries
+    ``n_prompt``, ``bucket`` (the admit program's prompt bucket, 0 on the
+    rolling path), ``n_out``, ``step0`` (this server's ``step()`` count at
+    its admission), ``steps`` (``step()`` calls it lived through) and the
+    stamps ``t_submit``, ``t_admit0`` (its admission begins),
+    ``t_first`` (its first token is a host int), ``t_done``; ``status`` is
+    ``queued`` / ``running`` until it ends as ``done`` / ``cancelled`` /
+    ``rejected`` (a rejected row has no rid).  Behind the transport bridge
+    (models/remote_serving.py) the same row also carries ``route``
+    (``"<client id>:<nonce>"``), ``t_recv`` (the REQUEST receive
+    completed), ``t_first_post`` and ``t_done_post`` (the TOKENS sends
+    were posted).  A CLIENT row (``side: "client"``, written by
+    ``RemoteGenerateSession.generate``) carries ``route``, ``t_send``,
+    ``t_first_rx``, ``t_done_rx``, ``n_out``, ``status`` and ``server_us``
+    (the done frame's timing trailer, None from a server without one)."""
+    return [dict(row) for row in list(_request_log)]
+
+
+def step_log() -> list:
+    """The last ``LOG_ROWS`` ``SlotServer.step()`` calls of this process,
+    oldest first: ``server``, ``n_slots``, ``t0``, ``t1``, ``queued`` and
+    ``live`` (queue depth and occupied slots when the decode chunk was
+    dispatched), ``admits`` and ``admit_s`` (admissions of this step and
+    their seconds), ``dispatch_s`` (inside ``_run_chunk``), ``wait_s`` (the
+    chunk's result copied to the host: the wait the loop always had),
+    ``harvest_s`` (from there until the step returns, ``on_tokens`` calls
+    included).  The four durations leave out the Python between them, so
+    they sum to at most ``t1 - t0``."""
+    return [dict(row) for row in list(_step_log)]
+
+
+def log_request(row: dict) -> None:
+    """Append one row to :func:`request_log` (the client side's entry)."""
+    _request_log.append(row)
+
+
+def _named_jit(fn, name: str, **jit_kwargs):
+    """``jax.jit`` under a name of the program's own: the profiler's ``XLA
+    Modules`` line then reads ``jit_<name>(...)`` instead of ``jit_run``, so
+    a trace reduction finds each serving program after a refactor."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kwargs)
 
 
 def _bucket(n: int, buckets) -> int:
@@ -106,7 +174,7 @@ def _compiled_admit(cfg: LlamaConfig, p_bucket: int, temperature: float,
         return _write_slot_and_sample(cache, small, logits, slot, key,
                                       temperature, top_k, top_p)
 
-    return jax.jit(run, donate_argnums=(1,))
+    return _named_jit(run, f"serve_admit_{p_bucket}", donate_argnums=(1,))
 
 
 @functools.cache
@@ -119,7 +187,7 @@ def _compiled_prefix_register(cfg: LlamaConfig, p_bucket: int):
         return prefill(params, cfg, prompt, p_bucket,
                        logit_positions=length[None] - 1)
 
-    return jax.jit(run)
+    return _named_jit(run, f"serve_prefix_register_{p_bucket}")
 
 
 @functools.cache
@@ -176,7 +244,8 @@ def _compiled_prefix_admit(cfg: LlamaConfig, p_bucket: int, s_bucket: int,
         return _write_slot_and_sample(cache, rows, last, slot, key,
                                       temperature, top_k, top_p)
 
-    return jax.jit(run, donate_argnums=(1,))
+    return _named_jit(run, f"serve_prefix_admit_{p_bucket}_{s_bucket}",
+                      donate_argnums=(1,))
 
 
 @functools.cache
@@ -189,7 +258,7 @@ def _compiled_rolling_admit(cfg: LlamaConfig, temperature: float,
         return _write_slot_and_sample(cache, small, logits, slot, key,
                                       temperature, top_k, top_p)
 
-    return jax.jit(run, donate_argnums=(0,))
+    return _named_jit(run, "serve_rolling_admit", donate_argnums=(0,))
 
 
 # Chunk-width denominations for rolling admission: covering the prompt
@@ -237,7 +306,7 @@ def _compiled_chunk(cfg: LlamaConfig, n_slots: int, max_len: int, chunk: int,
             length=chunk)
         return cache, token, pos, live, remaining, key, toks, mask
 
-    return jax.jit(run, donate_argnums=(1,))
+    return _named_jit(run, "serve_decode_chunk", donate_argnums=(1,))
 
 
 def make_chunk_scan_step(decode_one, max_len: int, temperature: float,
@@ -392,6 +461,15 @@ class SlotServer:
         # (models/remote_serving.py) rides this to stream tokens over the
         # wire without waiting for full completion.
         self.on_tokens = on_tokens
+        # The serve scope (DESIGN.md §13): this server's rows of the module
+        # logs while their requests are open, its phase accumulator, and --
+        # only when swtrace is armed -- a ring of its own in the registry,
+        # so the trace CLI draws serve.* spans beside the engines' ops.
+        self.server_id = next(_server_ids)
+        self._rows: dict[int, dict] = {}
+        self._n_steps = 0
+        self.stage_scope = perf.StageScope(ring=swtrace.worker_ring())
+        swtrace.register_worker(self)
         self._post_init()
         self._next_pid = 0
 
@@ -406,6 +484,28 @@ class SlotServer:
     def _on_slot_freed(self, slot: int) -> None:
         """Subclass hook: a slot's request finished or was cancelled (the
         paged server returns its pages to the pool here)."""
+
+    # ------------------------------------------------------ observability
+    @property
+    def trace_label(self) -> str:
+        return f"serve-{self.server_id}"
+
+    def trace_events(self) -> list:
+        """Snapshot of this server's swtrace ring ([] when tracing off)."""
+        ring = self.stage_scope.ring
+        return ring.snapshot() if ring is not None else []
+
+    def open_row(self, rid: int) -> Optional[dict]:
+        """The live :func:`request_log` row of a request that has not
+        ended yet, or None: a front end (the transport bridge) adds its
+        own stamps to it."""
+        return self._rows.get(rid)
+
+    def close(self) -> None:
+        """Hand the trace ring to swtrace's retired list, so its serve.*
+        spans outlive this object (a no-op with tracing off).  The logs
+        and the device state need no closing."""
+        swtrace.retire(self)
 
     @_on_weights_mesh
     def register_prefix(self, tokens) -> int:
@@ -461,6 +561,29 @@ class SlotServer:
         SUFFIX continuing it (the generated text continues
         ``prefix_tokens + prompt``)."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
+        row = {"side": "server", "server": self.server_id, "rid": None,
+               "n_prompt": len(prompt), "bucket": None, "n_out": 0,
+               "step0": None, "steps": 0, "t_submit": _now(), "t_admit0": None,
+               "t_first": None, "t_done": None, "status": "queued"}
+        try:
+            self._check_request(prompt, max_new_tokens, prefix)
+        except (ValueError, KeyError):
+            row.update(status="rejected", t_done=row["t_submit"])
+            _request_log.append(row)
+            raise
+        rid = self._next_rid
+        self._next_rid += 1
+        row["rid"] = rid
+        self._rows[rid] = row
+        _request_log.append(row)
+        self._pending.append((rid, prompt, int(max_new_tokens), prefix))
+        return rid
+
+    def _check_request(self, prompt: np.ndarray, max_new_tokens: int,
+                       prefix: Optional[int]) -> None:
+        """Everything submit() refuses, raised before the request gets an
+        id: un-bucketable and un-placeable requests fail NOW, not at
+        admission time after they have left the queue."""
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
         if len(prompt) < 1:
@@ -474,8 +597,6 @@ class SlotServer:
             raise ValueError(
                 f"prefix ({plen}) + prompt ({len(prompt)}) + max_new "
                 f"({max_new_tokens}) exceeds max_len={self.max_len}")
-        # Reject un-bucketable/un-placeable requests NOW, not at admission
-        # time after the request has left the queue.
         if prefix is not None:
             sb = _bucket(len(prompt), self.buckets)
             if plen + sb > self.max_len:
@@ -485,10 +606,6 @@ class SlotServer:
                     f"the suffix ingest writes bucket-wide")
         elif not self.rolling:
             _bucket(len(prompt), self.buckets)
-        rid = self._next_rid
-        self._next_rid += 1
-        self._pending.append((rid, prompt, int(max_new_tokens), prefix))
-        return rid
 
     # ------------------------------------------------------------- engine
     def _admit(self, slot: int, rid: int, prompt: np.ndarray,
@@ -538,6 +655,10 @@ class SlotServer:
         paged): record the first token, fire the streaming hook, and set
         the slot's cursor/liveness/budget."""
         tok_host = int(tok)
+        row = self._rows.get(rid)
+        if row is not None:
+            row.update(t_first=_now(), status="running",
+                       step0=self._n_steps)
         self._slot_rid[slot] = rid
         self._collected[rid] = [tok_host]
         if self.on_tokens is not None:
@@ -567,16 +688,27 @@ class SlotServer:
         for i, (qrid, *_rest) in enumerate(self._pending):
             if qrid == rid:
                 del self._pending[i]
+                self._close_row(rid, "cancelled", 0)
                 return True
         for slot, srid in self._slot_rid.items():
             if srid == rid:
                 self.live = self.live.at[slot].set(False)
                 self.remaining = self.remaining.at[slot].set(0)
                 del self._slot_rid[slot]
-                self._collected.pop(rid, None)
+                self._close_row(rid, "cancelled",
+                                len(self._collected.pop(rid, ())))
                 self._on_slot_freed(slot)
                 return True
         return False
+
+    def _close_row(self, rid: int, status: str, n_out: int) -> None:
+        """A request's last stamp: its row leaves this server (the log
+        keeps it)."""
+        row = self._rows.pop(rid, None)
+        if row is not None:
+            step0 = row["step0"]
+            row.update(t_done=_now(), status=status, n_out=n_out,
+                       steps=0 if step0 is None else self._n_steps - step0 + 1)
 
     def _harvest_dead(self, finished: dict) -> None:
         live = np.asarray(self.live)
@@ -590,6 +722,7 @@ class SlotServer:
                     continue
                 finished[rid] = np.asarray(self._collected.pop(rid),
                                            np.int32)
+                self._close_row(rid, "done", len(finished[rid]))
                 self._slot_rid.pop(slot, None)
                 self._on_slot_freed(slot)
                 if self.on_tokens is not None:
@@ -600,37 +733,66 @@ class SlotServer:
         """Admit what fits, decode one chunk; returns {rid: tokens} for
         requests that finished during this step."""
         finished: dict = {}
-        self._harvest_dead(finished)  # 1-token/instant-eos admissions
-        free = [s for s in range(self.n_slots) if s not in self._slot_rid]
-        while free and self._pending:
-            rid, prompt, max_new, prefix = self._pending.popleft()
-            try:
-                self._admit(free.pop(0), rid, prompt, max_new, prefix)
-            except RuntimeError:
-                # Transient resource exhaustion (the paged server's pool):
-                # the request STAYS QUEUED — in-flight work frees capacity
-                # and a later step admits it (the class docstring's
-                # "callers keep it queued / retry" contract).
-                self._pending.appendleft((rid, prompt, max_new, prefix))
-                break
-        self._harvest_dead(finished)
-        if not self._slot_rid:
-            return finished
-
-        self.key, sub = jax.random.split(self.key)
-        toks, mask = self._run_chunk(sub)
-        toks = np.asarray(toks)
-        mask = np.asarray(mask)
-        # Snapshot: an on_tokens callback may legally cancel() a request
-        # (its own or another), which mutates _slot_rid/_collected.
-        for slot, rid in list(self._slot_rid.items()):
-            if rid not in self._collected:
-                continue  # cancelled by an earlier callback this step
-            new = [int(t) for t, m in zip(toks[:, slot], mask[:, slot]) if m]
-            self._collected[rid].extend(new)
-            if self.on_tokens is not None and new:
-                self.on_tokens(rid, new, False)
-        self._harvest_dead(finished)
+        scope = self.stage_scope
+        self._n_steps += 1
+        admits, admit_s = 0, 0.0
+        dispatch_s = wait_s = harvest_s = 0.0
+        with perf.stage_span(scope, "serve.step") as whole:
+            self._harvest_dead(finished)  # 1-token/instant-eos admissions
+            free = [s for s in range(self.n_slots)
+                    if s not in self._slot_rid]
+            while free and self._pending:
+                rid, prompt, max_new, prefix = self._pending.popleft()
+                row = self._rows.get(rid)
+                span = perf.stage_span(scope, "serve.admit", rid)
+                try:
+                    with span:
+                        if row is not None:
+                            row["t_admit0"] = span.t0
+                            row["bucket"] = (_bucket(len(prompt), self.buckets)
+                                             if self.buckets else 0)
+                        self._admit(free.pop(0), rid, prompt, max_new, prefix)
+                except RuntimeError:
+                    # Transient resource exhaustion (the paged server's
+                    # pool): the request STAYS QUEUED — in-flight work
+                    # frees capacity and a later step admits it (the class
+                    # docstring's "callers keep it queued / retry"
+                    # contract).
+                    self._pending.appendleft((rid, prompt, max_new, prefix))
+                    break
+                admits += 1
+                admit_s += span.seconds
+            self._harvest_dead(finished)
+            queued, live = len(self._pending), len(self._slot_rid)
+            if live:
+                self.key, sub = jax.random.split(self.key)
+                with perf.stage_span(scope, "serve.chunk_dispatch") as span:
+                    toks, mask = self._run_chunk(sub)
+                dispatch_s = span.seconds
+                with perf.stage_span(scope, "serve.chunk_wait") as span:
+                    toks = np.asarray(toks)
+                    mask = np.asarray(mask)
+                wait_s = span.seconds
+                with perf.stage_span(scope, "serve.harvest") as span:
+                    # Snapshot: an on_tokens callback may legally cancel()
+                    # a request (its own or another), which mutates
+                    # _slot_rid/_collected.
+                    for slot, rid in list(self._slot_rid.items()):
+                        if rid not in self._collected:
+                            continue  # cancelled by an earlier callback
+                        new = [int(t) for t, m
+                               in zip(toks[:, slot], mask[:, slot]) if m]
+                        self._collected[rid].extend(new)
+                        if self.on_tokens is not None and new:
+                            self.on_tokens(rid, new, False)
+                    self._harvest_dead(finished)
+                harvest_s = span.seconds
+        _step_log.append({
+            "server": self.server_id, "n_slots": self.n_slots,
+            "t0": whole.t0, "t1": whole.t0 + whole.seconds,
+            "queued": queued, "live": live, "admits": admits,
+            "admit_s": admit_s, "dispatch_s": dispatch_s, "wait_s": wait_s,
+            "harvest_s": harvest_s})
         return finished
 
     @property

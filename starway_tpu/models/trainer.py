@@ -1,7 +1,9 @@
 """Minimal training harness for the model family.
 
 Wires the pieces the framework already provides into one loop: the jitted
-(optionally sharded) train step, telemetry (utils.OpTimer), checkpointing
+(optionally sharded) train step, telemetry (a perf.StageScope fed by
+perf.stage_span, so each phase is also an ``sw:`` span in a profiler
+trace), checkpointing
 (utils.checkpoint), and -- when a DP-boundary port is supplied -- averaged
 gradient exchange with a peer host over the async P2P fabric
 (parallel/dp_exchange.py; the examples/dp_training_2proc.py pattern as a
@@ -15,7 +17,7 @@ from typing import Any, Callable, Optional
 
 import jax
 
-from ..utils import OpTimer
+from .. import perf
 from .llama import LlamaConfig, apply_updates, loss_fn, make_train_step
 
 
@@ -67,7 +69,7 @@ class Trainer:
         self.cfg = cfg
         self.tx = tx
         self.state = TrainState(params=params, opt_state=tx.init(params))
-        self.timer = OpTimer()
+        self.timer = perf.StageScope()
         self.dp_port = dp_port
         self.dp_base_tag = dp_base_tag
         self.with_moe_stats = with_moe_stats
@@ -135,7 +137,7 @@ class Trainer:
     def step_sync(self, batch) -> float:
         """One local step (no DP exchange)."""
         if self._accum_step is not None:
-            with self.timer.span("accum_step"):
+            with perf.stage_span(self.timer, "accum_step"):
                 out = self._accum_step(self.state.params,
                                        self.state.opt_state, batch)
                 if self.with_moe_stats:
@@ -146,15 +148,15 @@ class Trainer:
             self.state.step += 1
             return float(loss)
         if self._fsdp_step is not None:
-            with self.timer.span("fsdp_step"):
+            with perf.stage_span(self.timer, "fsdp_step"):
                 self.state.params, self.state.opt_state, loss = self._fsdp_step(
                     self.state.params, self.state.opt_state, batch)
             self.state.step += 1
             return float(loss)
-        with self.timer.span("grad"):
+        with perf.stage_span(self.timer, "grad"):
             loss, grads = self._unpack_grad(
                 self._grad_fn(self.state.params, batch))
-        with self.timer.span("apply"):
+        with perf.stage_span(self.timer, "apply"):
             self.state.params, self.state.opt_state = self._apply_fn(
                 self.state.params, self.state.opt_state, grads
             )
@@ -175,10 +177,10 @@ class Trainer:
 
         from ..parallel.dp_exchange import recv_pytree, send_pytree
 
-        with self.timer.span("grad"):
+        with perf.stage_span(self.timer, "grad"):
             loss, grads = self._unpack_grad(
                 self._grad_fn(self.state.params, batch))
-        with self.timer.span("dp_exchange"):
+        with perf.stage_span(self.timer, "dp_exchange"):
             base = self.dp_base_tag + (self.state.step % 1024) * 256
             send_task = asyncio.ensure_future(
                 send_pytree(self.dp_port, grads, base_tag=base)
@@ -186,7 +188,7 @@ class Trainer:
             peer = await recv_pytree(self.dp_port, like=grads, base_tag=base)
             await send_task
             grads = jax.tree_util.tree_map(lambda a, b: (a + b) / 2, grads, peer)
-        with self.timer.span("apply"):
+        with perf.stage_span(self.timer, "apply"):
             self.state.params, self.state.opt_state = self._apply_fn(
                 self.state.params, self.state.opt_state, grads
             )
@@ -211,4 +213,7 @@ class Trainer:
                                 step=int(got["step"]))
 
     def telemetry(self) -> dict:
-        return self.timer.summary()
+        """``{phase: {"count", "seconds", ...}}`` of the step phases so far
+        (``grad``, ``apply``, ``fsdp_step``, ``accum_step``,
+        ``dp_exchange``)."""
+        return self.timer.snapshot()

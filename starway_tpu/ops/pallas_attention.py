@@ -256,6 +256,7 @@ def _fwd_impl(q, k, v, cfg: _Cfg, save_lse: bool):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=cfg.interpret,
+        name="sw_flash_fwd",
     )(qf, kf, vf)
     if save_lse:
         o, lse = out
@@ -465,6 +466,7 @@ def _run_bwd_passes(qf, dof, kf, vf, lse8, delta8, offs, *, b, hq, hkv,
             jax.ShapeDtypeStruct((b * hkv, kv_pad, d), dkv_dtype),
         ],
         interpret=interpret,
+        name="sw_flash_bwd_dkv",
     )(offs, qf, dof, kf, vf, lse8, delta8)
 
     # ---- pass B: dQ (q-stationary, sweeps kv blocks) ----
@@ -508,6 +510,7 @@ def _run_bwd_passes(qf, dof, kf, vf, lse8, delta8, offs, *, b, hq, hkv,
         grid_spec=grid_b,
         out_shape=jax.ShapeDtypeStruct((b * hq, s_pad, d), dq_dtype),
         interpret=interpret,
+        name="sw_flash_bwd_dq",
     )(offs, qf, dof, kf, vf, lse8, delta8)
     return dq, dk, dv
 
@@ -721,6 +724,7 @@ def flash_partial(q, k, v, q_offset, kv_offset, *, causal: bool = True,
             jax.ShapeDtypeStruct((b * hq, s_pad, 8), jnp.float32),
         ],
         interpret=bool(interpret),
+        name="sw_flash_partial",
     )(offs, qf, kf, vf)
     o = o.reshape(b, hq, s_pad, d)[:, :, :s, :]
     m = m8[:, :, 0].reshape(b, hq, s_pad)[:, :, :s]

@@ -366,6 +366,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None,
             ),
             out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), q.dtype),
             interpret=interpret,
+            name="sw_decode_attn_stream",
         )(pos_arr, qf, kf, vf, *scales)
         return out.reshape(b, hkv, rows, d)[:, :, :n_rows, :].reshape(
             b, hq, n_q, d)
@@ -410,6 +411,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None,
         ),
         out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), q.dtype),
         interpret=interpret,
+        name="sw_decode_attn",
     )(pos_arr, qf, kf, vf, *scales)
     return out.reshape(b, hkv, rows, d)[:, :, :n_rows, :].reshape(
         b, hq, n_q, d)

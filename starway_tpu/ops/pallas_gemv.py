@@ -85,5 +85,6 @@ def int8_matmul(x, wq, scale, *, block_f: "int | None" = None,
         out_specs=pl.BlockSpec((m_pad, block_f), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m_pad, f_pad), out_dtype),
         interpret=interpret,
+        name="sw_gemv_int8",
     )(x, wq, scale2)
     return out[:m, :f]

@@ -162,6 +162,7 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, *, sm_scale=None,
         ),
         out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), q.dtype),
         interpret=interpret,
+        name="sw_paged_decode_attn",
     )(meta, qf, k_pool, v_pool)
     return out.reshape(b, hkv, rows, d)[:, :, :n_rows, :].reshape(
         b, hq, n_q, d)

@@ -26,6 +26,7 @@ peer's matcher consumes and drops them (core/matching.py, sw_engine.cpp).
 from __future__ import annotations
 
 import threading
+import time
 
 # ------------------------------------------------------ per-stage telemetry
 #
@@ -80,6 +81,58 @@ class StageScope:
     def reset(self) -> None:
         with self._lock:
             self._stages.clear()
+
+
+_annotation = None  # jax.profiler.TraceAnnotation, bound at first use
+
+
+class stage_span:
+    """One host phase of a layer ABOVE the engines (the serve scope of
+    DESIGN.md §13: ``serve.step``, ``serve.admit``, ``bridge.emit`` ...),
+    entered as a context manager and recorded three ways at once:
+
+    * ``jax.profiler.TraceAnnotation("sw:<name>")`` -- the phase lands in
+      ``/host:CPU`` of the profiler's xplane, on the clock of the device's
+      programs, whenever ANY profiler session runs (a flag check when none
+      does);
+    * ``scope.record(name, seconds)`` -- the owner's :class:`StageScope`;
+    * with a ring on the scope (``swtrace.active()`` when the owner was
+      built), an ``EV_STAGE`` event whose tag is ``tag`` (the request id
+      for per-request phases), so ``python -m starway_tpu.trace`` draws it.
+
+    ``t0`` / ``seconds`` stay readable after the block: the serve logs
+    take their durations from the same two clock reads.  jax is imported
+    at first use only -- the engines import this module and stay jax-free.
+    """
+
+    __slots__ = ("scope", "name", "tag", "t0", "seconds", "_note")
+
+    def __init__(self, scope: StageScope, name: str, tag: int = 0):
+        self.scope, self.name, self.tag = scope, name, tag
+        self.t0 = self.seconds = 0.0
+
+    def __enter__(self):
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        self._note = _annotation("sw:" + self.name)
+        self._note.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = time.perf_counter() - self.t0
+        self._note.__exit__(*exc)
+        scope = self.scope
+        scope.record(self.name, self.seconds)
+        if scope.ring is not None:
+            from .core import swtrace
+
+            scope.ring.rec(swtrace.EV_STAGE, self.tag, 0, 0, self.name,
+                           self.seconds)
+        return False
 
 
 def percentile(sorted_vals, q: float) -> float:

@@ -28,7 +28,9 @@ is the worker-wide track: posted receives are fan-in and have no conn
 until matched.  Op lifecycles render as complete ("X") spans --
 ``send_post``..``send_done``, ``recv_post``..``recv_done``,
 ``flush_post``..``flush_done``, with ``op_fail`` closing whichever op it
-matches -- stage spans (``stage_span`` events from perf.record_stage) as
+matches -- stage spans (``stage_span`` events from perf.record_stage, and
+the serve scope's ``serve.*`` / ``bridge.*`` phases from perf.stage_span,
+whose ``args.tag`` is the request id) as
 "X" spans of their measured duration, and everything unpaired (matches,
 E2E ordinals, connection churn) as instants.
 """
@@ -160,7 +162,7 @@ def chrome_events(label: str, events: Iterable, pid: int,
             out.append({"ph": "X", "name": reason or "stage",
                         "ts": ts - dur * 1e6, "dur": max(0.0, dur * 1e6),
                         "pid": pid, "tid": tid_of(conn), "cat": "stage",
-                        "args": {"nbytes": nbytes}})
+                        "args": {"nbytes": nbytes, "tag": tag}})
         else:  # recv_match, conn churn, e2e, clock, anything future
             if e2e_out is not None and ev == swtrace.EV_E2E:
                 tcid, _, direction = reason.rpartition(":")

@@ -1,6 +1,5 @@
-"""Utilities: tracing/telemetry helpers, checkpointing, data batching."""
+"""Utilities: checkpointing, data batching, chip helpers."""
 
 from .data import TokenBatcher, load_tokens
-from .trace import OpTimer, trace_span, profile_to
 
-__all__ = ["OpTimer", "trace_span", "profile_to", "TokenBatcher", "load_tokens"]
+__all__ = ["TokenBatcher", "load_tokens"]

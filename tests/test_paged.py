@@ -99,7 +99,7 @@ def test_paged_kernel_mosaic_lowers_for_tpu():
         .trace(q, kp, kp, table, pos)
         .lower(lowering_platforms=("tpu",)).as_text())
     assert re.findall(r'kernel_name = "(\w+)"', txt) == [
-        "_paged_stream_kernel"]
+        "sw_paged_decode_attn"]
 
 
 def test_paged_kernel_refuses_int8():

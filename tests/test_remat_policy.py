@@ -63,27 +63,27 @@ def test_dots_remat_never_reruns_flash_forward():
     """THE pin: scanned layers + "dots" remat lower to exactly one forward
     kernel call site — identical to the no-remat lowering."""
     calls = _kernel_calls(_tiny_cfg(remat=True, remat_policy="dots"))
-    assert calls == ["_fwd_kernel", "_bwd_dkv_kernel", "_bwd_dq_kernel"]
+    assert calls == ["sw_flash_fwd", "sw_flash_bwd_dkv", "sw_flash_bwd_dq"]
 
 
 def test_no_remat_baseline_call_sites():
     calls = _kernel_calls(_tiny_cfg())
-    assert calls == ["_fwd_kernel", "_bwd_dkv_kernel", "_bwd_dq_kernel"]
+    assert calls == ["sw_flash_fwd", "sw_flash_bwd_dkv", "sw_flash_bwd_dq"]
 
 
 def test_full_remat_replays_flash_forward():
     """Full-layer remat pays one extra forward kernel per layer body —
     the documented memory-for-flops trade (llama.py remat_policy=None)."""
     calls = _kernel_calls(_tiny_cfg(remat=True, remat_policy=None))
-    assert calls.count("_fwd_kernel") == 2
+    assert calls.count("sw_flash_fwd") == 2
 
 
 def test_dots_remat_unrolled_never_reruns_flash_forward():
     """scan_layers=False: one forward call site per layer, no recompute."""
     cfg = _tiny_cfg(remat=True, remat_policy="dots", scan_layers=False)
     calls = _kernel_calls(cfg)
-    assert calls.count("_fwd_kernel") == cfg.n_layers
-    assert calls.count("_bwd_dq_kernel") == cfg.n_layers
+    assert calls.count("sw_flash_fwd") == cfg.n_layers
+    assert calls.count("sw_flash_bwd_dq") == cfg.n_layers
 
 
 def test_dots_remat_backward_has_no_matmul_recompute():

@@ -12,6 +12,7 @@ client processes against one serving process.
 import asyncio
 import multiprocessing as mp
 import os
+import time
 
 import numpy as np
 import pytest
@@ -402,7 +403,7 @@ async def test_remote_cancel_overtaking_request(cfg, params, port):
         # processes cancels first — the CANCEL overtakes the REQUEST.
         nonce = 0
         bridge._requests.append((session.client_id, np.asarray(
-            [nonce, 30, 4, 4, 2, 8, 1], np.int32)))
+            [nonce, 30, 4, 4, 2, 8, 1], np.int32), time.perf_counter()))
         bridge._cancels.append((session.client_id, nonce))
         session._nonce = 1  # nonce 0 is taken by the hand-crafted request
         task = asyncio.create_task(_await_aborted(session, nonce))
@@ -427,3 +428,176 @@ async def _await_aborted(session, nonce):
     buf = _recv_buf(8)
     await session.client.arecv(buf, TAG_TOKENS | nonce, FULL_MASK)
     return int(buf.view(np.int32)[1])
+
+
+# ------------------------------------------- the done frame's timing trailer
+
+
+async def _bridge_and_session(cfg, params, port):
+    from starway_tpu.models.remote_serving import (RemoteGenerateSession,
+                                                   RemoteSlotServer)
+
+    slot = SlotServer(params, cfg, n_slots=1, max_len=64, chunk=3)
+    bridge = RemoteSlotServer(slot)
+    bridge.server.listen(ADDR, port)
+    serve_task = asyncio.create_task(bridge.serve())
+    session = await RemoteGenerateSession.aconnect(ADDR, port)
+
+    async def finish():
+        bridge.stop()
+        await serve_task
+        await session.aclose()
+        await bridge.aclose()
+
+    return slot, bridge, session, finish
+
+
+async def test_remote_timing_trailer_roundtrip(cfg, params, transport, port):
+    """The server's timing of a request rides its done frame back: over
+    every transport, ``handle.timing`` is filled, the trailer's words are
+    the durations of the server's own row, and both rows (one route) sit
+    in request_log().  Two requests on one slot: the second one queued."""
+    from starway_tpu.models import serving
+    from starway_tpu.models.remote_serving import (TIMING_WORDS,
+                                                   RemoteGenerateSession)
+
+    slot, _bridge, session, finish = await _bridge_and_session(
+        cfg, params, port)
+    try:
+        handles = [RemoteGenerateSession.Handle() for _ in range(2)]
+        reqs = [([4, 2, 8, 1], 10), ([6, 6, 3], 5)]
+        outs = await asyncio.gather(*(
+            session.generate(p, m, handle=h)
+            for (p, m), h in zip(reqs, handles)))
+    finally:
+        await finish()
+    log = serving.request_log()
+    for h, (prompt, max_new), out in zip(handles, reqs, outs):
+        np.testing.assert_array_equal(out, _oracle(params, cfg, prompt,
+                                                   max_new))
+        timing = h.timing
+        route = f"{session.client_id}:{h.nonce}"
+        assert timing["route"] == route and timing["status"] == "done"
+        assert timing["n_out"] == max_new
+        assert timing["t_send"] <= timing["t_first_rx"] <= timing["t_done_rx"]
+        us = timing["server_us"]
+        assert tuple(us) == TIMING_WORDS and all(v >= 0 for v in us.values())
+        srow, = [r for r in log if r["side"] == "server"
+                 and r["server"] == slot.server_id and r.get("route") == route]
+        assert srow["status"] == "done"
+        assert (srow["t_recv"] <= srow["t_submit"] <= srow["t_admit0"]
+                <= srow["t_first"] <= srow["t_first_post"]
+                <= srow["t_done_post"])
+        for word, (a, b) in {
+                "recv_submit": ("t_recv", "t_submit"),
+                "submit_admit0": ("t_submit", "t_admit0"),
+                "admit0_first": ("t_admit0", "t_first"),
+                "first_post": ("t_first", "t_first_post"),
+                "recv_done_post": ("t_recv", "t_done_post")}.items():
+            assert us[word] == round((srow[b] - srow[a]) * 1e6)
+        assert us["steps"] == srow["steps"] >= 1
+        # One process here, so one clock: the server's phases up to the
+        # first send lie inside the client's time to first token.
+        server_ttft = sum(us[w] for w in TIMING_WORDS[:4])
+        client_ttft = (timing["t_first_rx"] - timing["t_send"]) * 1e6
+        assert 0 < server_ttft <= client_ttft + 50
+        crow, = [r for r in log if r["side"] == "client"
+                 and r["route"] == route and r["t_send"] == timing["t_send"]]
+        assert crow["server_us"] == us
+    # The second request waited for the only slot: its queue time is real.
+    first, second = (h.timing["server_us"] for h in handles)
+    assert second["submit_admit0"] > first["submit_admit0"]
+    assert second["submit_admit0"] >= first["admit0_first"]
+
+
+async def test_remote_old_server_without_trailer(cfg, params, port,
+                                                 monkeypatch):
+    """A server from before the trailer sends [nonce, status, count,
+    tokens]: the client parses it as ever and reports no server timing."""
+    from starway_tpu.models import remote_serving
+    from starway_tpu.models.remote_serving import RemoteGenerateSession
+
+    monkeypatch.setattr(remote_serving, "_timing_trailer", lambda row: [])
+    _slot, _bridge, session, finish = await _bridge_and_session(
+        cfg, params, port)
+    try:
+        handle = RemoteGenerateSession.Handle()
+        out = await session.generate([4, 2, 8, 1], 7, handle=handle)
+    finally:
+        await finish()
+    np.testing.assert_array_equal(out, _oracle(params, cfg, [4, 2, 8, 1], 7))
+    assert handle.timing["server_us"] is None
+    assert handle.timing["status"] == "done" and handle.timing["n_out"] == 7
+
+
+async def test_remote_old_client_ignores_trailer(cfg, params, port):
+    """A client from before the trailer posts 3 + max_chunk words and reads
+    words[3:3 + count]: the trailer behind the tokens changes nothing."""
+    from starway_tpu.models.remote_serving import (FULL_MASK, STATUS_DONE,
+                                                   TAG_REQUEST, TAG_TOKENS,
+                                                   _recv_buf, _wire)
+
+    _slot, _bridge, session, finish = await _bridge_and_session(
+        cfg, params, port)
+    try:
+        prompt, nonce, out = [4, 2, 8, 1], 77, []
+        await session.client.asend(
+            _wire([nonce, 7, len(prompt), *prompt]),
+            TAG_REQUEST | session.client_id)
+        while True:
+            buf = _recv_buf(3 + 16)
+            await session.client.arecv(buf, TAG_TOKENS | nonce, FULL_MASK)
+            words = buf.view(np.int32)
+            out.extend(int(t) for t in words[3:3 + int(words[2])])
+            if int(words[1]) == STATUS_DONE:
+                break
+    finally:
+        await finish()
+    np.testing.assert_array_equal(out, _oracle(params, cfg, prompt, 7))
+
+
+async def test_remote_aborted_stream_fills_handle_timing(cfg, params, port):
+    """A rejected request still ends with a client row: status aborted, no
+    server timing (only a done frame carries the trailer)."""
+    from starway_tpu.models.remote_serving import RemoteGenerateSession
+
+    _slot, _bridge, session, finish = await _bridge_and_session(
+        cfg, params, port)
+    try:
+        handle = RemoteGenerateSession.Handle()
+        with pytest.raises(ValueError):
+            await session.generate([1, 2, 3], 4096, handle=handle)
+    finally:
+        await finish()
+    assert handle.timing["status"] == "aborted"
+    assert handle.timing["server_us"] is None and handle.timing["n_out"] == 0
+    assert handle.timing["t_first_rx"] is None
+
+
+async def test_remote_bridge_spans_in_trace(cfg, params, port, monkeypatch):
+    """STARWAY_TRACE=1: the bridge's drain and emit phases land in the
+    served SlotServer's ring beside its serve.* spans, and aclose() retires
+    that ring so the export still finds it."""
+    from starway_tpu import trace
+    from starway_tpu.core import swtrace
+
+    monkeypatch.delenv("STARWAY_TLS", raising=False)
+    monkeypatch.delenv("STARWAY_NATIVE", raising=False)
+    monkeypatch.setenv("STARWAY_TRACE", "1")
+    swtrace.reset()
+    try:
+        slot, _bridge, session, finish = await _bridge_and_session(
+            cfg, params, port)
+        try:
+            await session.generate([4, 2, 8, 1], 5)
+        finally:
+            await finish()
+        dumps = [d for d in swtrace.dump_all()
+                 if d["worker"] == slot.trace_label]
+        names = {e["name"] for e in trace.to_chrome(dumps)["traceEvents"]
+                 if e.get("cat") == "stage"}
+    finally:
+        swtrace.reset()
+    assert {"bridge.drain", "bridge.emit", "serve.step",
+            "serve.admit"} <= names
+    assert set(slot.stage_scope.snapshot()) >= names

@@ -399,3 +399,232 @@ def test_cancel_own_request_from_admit_callback(cfg, params):
     np.testing.assert_array_equal(done[r1], _oracle(params, cfg,
                                                     [9, 1, 5], 6))
     assert not srv.busy and not srv._slot_rid
+
+
+# ---------------------------------------------------------- the serve scope
+#
+# DESIGN.md §13: request_log() / step_log(), the serve.* phases on the
+# swtrace spine, the stable program names.
+
+
+def _rows_of(srv):
+    from starway_tpu.models import serving
+
+    return {r["rid"]: r for r in serving.request_log()
+            if r["side"] == "server" and r["server"] == srv.server_id}
+
+
+def _finished(srv, cfg, params):
+    rid = srv.submit([5, 1, 7, 2, 9], 6)
+    done = srv.run()
+    return rid, "done", len(done[rid]), True
+
+
+def _one_token(srv, cfg, params):
+    rid = srv.submit([3, 8, 6], 1)
+    done = srv.run()
+    return rid, "done", 1, True
+
+
+def _eos_at_admission(srv, cfg, params):
+    prompt = [5, 1, 7, 2, 9]
+    srv.eos_id = int(_oracle(params, cfg, prompt, 1)[0])
+    rid = srv.submit(prompt, 8)
+    done = srv.run()
+    assert list(done[rid]) == [srv.eos_id]
+    return rid, "done", 1, True
+
+
+def _cancelled_while_queued(srv, cfg, params):
+    srv.submit([4, 2, 8, 1], 12)        # takes the only slot
+    rid = srv.submit([6, 6, 3], 7)      # waits behind it
+    srv.step()
+    assert srv.cancel(rid)
+    srv.run()
+    return rid, "cancelled", 0, False
+
+
+def _cancelled_in_slot(srv, cfg, params):
+    rid = srv.submit([4, 2, 8, 1], 20)
+    srv.step()                          # admitted, one chunk decoded
+    assert srv.cancel(rid)
+    srv.run()
+    return rid, "cancelled", 1 + 3, True
+
+
+@pytest.mark.parametrize("scenario", [
+    _finished, _one_token, _eos_at_admission, _cancelled_while_queued,
+    _cancelled_in_slot], ids=lambda f: f.__name__.lstrip("_"))
+def test_request_log_stamps(cfg, params, scenario):
+    """Every way a request ends leaves one complete row: the stamps it
+    reached are ordered, the ones it never reached stay None, and the
+    server forgets the row (the module's log keeps it)."""
+    srv = SlotServer(params, cfg, n_slots=1, max_len=64, chunk=3)
+    rid, status, n_out, admitted = scenario(srv, cfg, params)
+    row = _rows_of(srv)[rid]
+    assert row["status"] == status and row["n_out"] == n_out
+    assert row["n_prompt"] in (3, 4, 5)
+    if admitted:
+        assert (row["t_submit"] <= row["t_admit0"] <= row["t_first"]
+                <= row["t_done"])
+        assert row["bucket"] == 32 and row["steps"] >= 1
+    else:
+        assert row["t_admit0"] is None and row["t_first"] is None
+        assert row["t_submit"] <= row["t_done"] and row["steps"] == 0
+    assert not srv._rows          # nothing open is left behind
+
+
+def test_request_log_rejected_request(cfg, params):
+    from starway_tpu.models import serving
+
+    srv = SlotServer(params, cfg, n_slots=1, max_len=64)
+    with pytest.raises(ValueError):
+        srv.submit(list(range(1, 70)), 4)
+    row = [r for r in serving.request_log()
+           if r["side"] == "server" and r["server"] == srv.server_id][-1]
+    assert row["status"] == "rejected" and row["rid"] is None
+    assert row["n_prompt"] == 69 and row["t_done"] == row["t_submit"]
+
+
+def test_request_log_paged_server(cfg, params):
+    """The paged server overrides _admit and _run_chunk; the stamps sit in
+    the paths it inherits, so its rows are as complete -- including a
+    request its pool made wait."""
+    from starway_tpu.models import PagedSlotServer
+
+    srv = PagedSlotServer(params, cfg, n_slots=2, max_len=64, page=16,
+                          n_pages=2, chunk=4)
+    rids = [srv.submit([7, 3, 9, 1, 4], 6), srv.submit([2, 5, 8], 5)]
+    done = srv.run()
+    rows = _rows_of(srv)
+    for rid in rids:
+        row = rows[rid]
+        assert row["status"] == "done" and row["n_out"] == len(done[rid])
+        assert row["bucket"] == 16
+        assert (row["t_submit"] <= row["t_admit0"] <= row["t_first"]
+                <= row["t_done"])
+    # One usable page: the second request's admission was refused until the
+    # first one's page came back, and its stamp is the attempt that held.
+    assert rows[rids[1]]["t_admit0"] >= rows[rids[0]]["t_done"]
+    assert not srv._rows
+
+
+def test_step_log_rows_add_up(cfg, params):
+    from starway_tpu.models import serving
+
+    srv = SlotServer(params, cfg, n_slots=2, max_len=64, chunk=4)
+    for prompt, n in [([5, 1, 7], 9), ([3, 8, 6, 2], 5), ([9, 9], 7)]:
+        srv.submit(prompt, n)
+    srv.run()
+    steps = [r for r in serving.step_log() if r["server"] == srv.server_id]
+    assert len(steps) == srv._n_steps >= 3
+    assert sum(r["admits"] for r in steps) == 3
+    assert steps[0]["queued"] == 1 and steps[0]["live"] == 2
+    for r in steps:
+        parts = (r["admit_s"] + r["dispatch_s"] + r["wait_s"]
+                 + r["harvest_s"])
+        assert 0.0 <= parts <= r["t1"] - r["t0"]
+        assert r["n_slots"] == 2 and 0 <= r["live"] <= 2
+        assert (r["admit_s"] > 0) == (r["admits"] > 0)
+    assert all(a["t1"] <= b["t0"] for a, b in zip(steps, steps[1:]))
+    # The server's own accumulator saw the same phases.
+    snap = srv.stage_scope.snapshot()
+    assert snap["serve.step"]["count"] == len(steps)
+    assert snap["serve.admit"]["count"] == 3
+    assert {"serve.chunk_dispatch", "serve.chunk_wait",
+            "serve.harvest"} <= set(snap)
+
+
+def test_serve_logs_stay_bounded(cfg, params):
+    from starway_tpu.models import serving
+
+    srv = SlotServer(params, cfg, n_slots=1, max_len=64)
+    for _ in range(serving.LOG_ROWS + 10):
+        with pytest.raises(ValueError):
+            srv.submit([], 1)           # a rejected row each
+    assert len(serving.request_log()) == serving.LOG_ROWS
+    serving._step_log.extend({"server": 0} for _ in range(serving.LOG_ROWS + 1))
+    assert len(serving.step_log()) == serving.LOG_ROWS
+    serving._step_log.clear()
+
+
+def test_serve_clock_is_the_monotonic_clock():
+    """The logs stamp with time.perf_counter, swtrace's clock; a caller
+    windows them with time.monotonic.  On Linux both are CLOCK_MONOTONIC."""
+    import time
+
+    perf, mono = (time.get_clock_info(n)
+                  for n in ("perf_counter", "monotonic"))
+    assert perf.implementation == mono.implementation
+    assert perf.monotonic and abs(time.perf_counter() - time.monotonic()) < 0.01
+
+
+def test_serve_spans_in_chrome_export(cfg, params, monkeypatch):
+    """STARWAY_TRACE=1: the server registers a ring of its own and the
+    trace CLI's export draws the serve.* phases, admissions carrying the
+    rid; with the variable unset it has no ring at all."""
+    from starway_tpu import trace
+    from starway_tpu.core import swtrace
+
+    monkeypatch.delenv("STARWAY_TRACE", raising=False)
+    monkeypatch.delenv("STARWAY_FLIGHT_DIR", raising=False)
+    assert SlotServer(params, cfg, n_slots=1,
+                      max_len=64).stage_scope.ring is None
+
+    monkeypatch.setenv("STARWAY_TRACE", "1")
+    swtrace.reset()
+    try:
+        srv = SlotServer(params, cfg, n_slots=1, max_len=64, chunk=3)
+        rids = [srv.submit([5, 1, 7], 4), srv.submit([3, 8], 5)]
+        srv.run()
+        live = [d for d in swtrace.dump_all() if d["worker"] == srv.trace_label]
+        assert len(live) == 1
+        srv.close()                    # retired: the events outlive it
+        label = srv.trace_label
+        del srv
+        dumps = [d for d in swtrace.dump_all() if d["worker"] == label]
+        assert len(dumps) == 1 and dumps[0]["events"] == live[0]["events"]
+        events = trace.to_chrome(dumps)["traceEvents"]
+    finally:
+        swtrace.reset()
+    spans = [e for e in events if e.get("cat") == "stage"]
+    names = {e["name"] for e in spans}
+    assert {"serve.step", "serve.admit", "serve.chunk_dispatch",
+            "serve.chunk_wait", "serve.harvest"} <= names
+    admits = [e for e in spans if e["name"] == "serve.admit"]
+    assert [e["args"]["tag"] for e in admits] == rids
+    assert all(e["ph"] == "X" and e["dur"] > 0 for e in admits)
+
+
+def test_serving_program_names_are_stable(cfg, params):
+    """The profiler names a program after the jitted function: each
+    serving program carries a name of its own (a trace reduction takes a
+    program's device time by name), not ``run``."""
+    from starway_tpu.models import paged, serving
+
+    sampling = (0.0, None, None)
+    progs = {
+        "serve_admit_32": serving._compiled_admit(cfg, 32, *sampling),
+        "serve_prefix_register_64": serving._compiled_prefix_register(cfg, 64),
+        "serve_prefix_admit_64_32": serving._compiled_prefix_admit(
+            cfg, 64, 32, 128, *sampling),
+        "serve_rolling_admit": serving._compiled_rolling_admit(cfg, *sampling),
+        "serve_decode_chunk": serving._compiled_chunk(
+            cfg, 2, 64, 4, *sampling, None),
+        "serve_paged_admit_32": paged._compiled_paged_admit(
+            cfg, 32, 16, *sampling),
+        "serve_paged_decode_chunk": paged._compiled_paged_chunk(
+            cfg, 64, 4, *sampling, None),
+        "serve_paged_prefix_register_32": paged._compiled_paged_prefix_write(
+            cfg, 32, 16, 1),
+        "serve_paged_prefix_admit_32": paged._compiled_paged_prefix_admit(
+            cfg, 32, 16, 4, False, *sampling),
+    }
+    for name, prog in progs.items():
+        assert prog.__name__ == name
+    # ... and that is the name the compiled module carries.
+    srv = SlotServer(params, cfg, n_slots=2, max_len=64, chunk=4)
+    lowered = progs["serve_decode_chunk"].lower(
+        params, srv.cache, srv.token, srv.pos, srv.live, srv.remaining,
+        srv.key)
+    assert "jit_serve_decode_chunk" in lowered.as_text()[:400]

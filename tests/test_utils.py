@@ -1,4 +1,4 @@
-"""Checkpoint round-trip, perf-model calibration, trace timer."""
+"""Checkpoint round-trip, perf-model calibration."""
 
 import numpy as np
 import pytest
@@ -6,7 +6,6 @@ import pytest
 import jax.numpy as jnp
 
 from starway_tpu import perf
-from starway_tpu.utils import OpTimer
 from starway_tpu.utils.checkpoint import restore_pytree, save_pytree
 
 
@@ -260,15 +259,6 @@ def test_autocalibrate_dcn_standin_two_processes(monkeypatch,
     assert ep_d["calibrated"] is True
     assert "per-endpoint" in ep_d["source"]
     assert ep_d["seconds"] > 0
-
-
-def test_op_timer_summary():
-    t = OpTimer()
-    for _ in range(10):
-        with t.span("op"):
-            pass
-    s = t.summary()["op"]
-    assert s["count"] == 10 and s["p50_us"] >= 0
 
 
 def _conn_is_sm(conn) -> bool:
