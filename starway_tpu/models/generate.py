@@ -6,9 +6,13 @@ generation (``lax.scan`` over steps; no retracing, no dynamic shapes -- the
 XLA-friendly decode loop).
 
 The cache layout is scan-stacked like the parameters: ``k/v
-[n_layers, B, Hkv, max_len, head_dim]``, updated in place with
-``dynamic_update_slice`` (donate the cache under jit for in-place HBM
-updates).
+[n_layers, B, Hkv, max_len, head_dim]``.  The stacked arrays ride the layer
+scan's CARRY (:func:`cached_layer_scan`): they are never a scan input or
+output and never sliced per layer, so one buffer serves the whole
+generation (donate the cache under jit).  On the chip the new entries are
+written in place by ``ops/pallas_decode.py::kv_write`` and attention reads
+the stacked array through a layer index; the decode step moves no cache
+bytes but the ones attention reads.
 """
 
 from __future__ import annotations
@@ -58,40 +62,55 @@ def init_rolling_cache(cfg: LlamaConfig, batch: int) -> dict:
 
 
 def _attend_cached(q, k_cache, v_cache, pos, n_rep, use_pallas=None,
-                   window=None, k_scale=None, v_scale=None):
+                   window=None, k_scale=None, v_scale=None, layer=None):
     """q: [B, Hq, C, D] — C consecutive query positions per row (C=1 is
     single-token decode; C>1 the speculative chunk verify, whose entries
-    are already written: write-then-attend).  caches: [B, Hkv, T, D];
-    row b's queries sit at ``pos[b] .. pos[b] + C - 1`` (``pos`` scalar
-    or per-row [B]) and mask key positions above themselves; ``window``
-    restricts to the last ``window`` positions (sliding-window models).
-    ``k_scale``/``v_scale`` ([B, Hkv, T] f32): the caches are
-    int8-quantized (ops/quantize.py) — the kernel streams them at half
-    width; the lax path dequantizes up front.
+    are already written: write-then-attend).  caches: the stacked [L, B,
+    Hkv, T, D] with ``layer`` the (traced) layer index, or one layer's
+    [B, Hkv, T, D] with ``layer=None``; row b's queries sit at ``pos[b]
+    .. pos[b] + C - 1`` (``pos`` scalar or per-row [B]) and mask key
+    positions above themselves; ``window`` restricts to the last
+    ``window`` positions (sliding-window models).  ``k_scale``/``v_scale``
+    (the caches' shape less D, f32): the caches are int8-quantized
+    (ops/quantize.py) — the kernel streams them at half width; the lax
+    path dequantizes up front.
 
     On TPU the pallas decode kernel (ops/pallas_decode.py) streams the
     grouped cache once instead of materialising ``repeat_kv`` — an
     ``n_rep``× HBM-bandwidth saving on the bandwidth-bound decode step
     (and only ~window bytes of it under a sliding window); C>1 just adds
-    matmul rows to the same stream.
+    matmul rows to the same stream.  It takes the stacked cache and the
+    layer index as they are: no layer is sliced out.
     """
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
+    if layer is None:  # one layer's caches: a stack of one
+        k_cache, v_cache, k_scale, v_scale = (
+            None if a is None else a[None]
+            for a in (k_cache, v_cache, k_scale, v_scale))
+        layer = 0
     if use_pallas:
         from ..ops.pallas_decode import decode_attention
         from ..parallel.sharding import per_head_shard
 
         scales = () if k_scale is None else (k_scale, v_scale)
 
-        def kernel(q, k, v, *rest):  # rest = (*scales, pos)
-            ks, vs = rest[:-1] or (None, None)
-            return decode_attention(q, k, v, rest[-1], window=window,
-                                    k_scale=ks, v_scale=vs)
+        def kernel(q, k, v, *rest):  # rest = (*scales, pos, layer)
+            ks, vs = rest[:-2] or (None, None)
+            return decode_attention(q, k, v, rest[-2], layer=rest[-1],
+                                    window=window, k_scale=ks, v_scale=vs)
 
-        # Heads shard q, the caches and their scales alike; pos (a scalar,
-        # or one cursor per batch row) is the same on every shard.
-        return per_head_shard(kernel, (q, k_cache, v_cache, *scales),
-                              (jnp.asarray(pos, jnp.int32),))
+        # Heads (dim 1 of q, dim 2 of the stacked caches and scales) shard
+        # alike; pos (a scalar, or one cursor per batch row) and the layer
+        # index are the same on every shard.
+        return per_head_shard(
+            kernel, (q, k_cache, v_cache, *scales),
+            (jnp.asarray(pos, jnp.int32), jnp.asarray(layer, jnp.int32)),
+            head_dims=(1,) + (2,) * (2 + len(scales)))
+    k_cache, v_cache, k_scale, v_scale = (
+        None if a is None
+        else lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+        for a in (k_cache, v_cache, k_scale, v_scale))
     if k_scale is not None:
         from ..ops.quantize import dequantize_kv
 
@@ -110,6 +129,47 @@ def _attend_cached(q, k_cache, v_cache, pos, n_rep, use_pallas=None,
     s = jnp.where(keep, s, NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+
+
+def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
+                  use_pallas=None) -> dict:
+    """The C new positions of one layer into the stacked cache, every leaf
+    (k, v and, int8, their scales): ``cache[name][layer, rows[b], :,
+    pos[b] + c] = new[name][b, :, c]``.  ``new[name]``: [B, Hkv, C(, D)];
+    ``pos``: scalar or per-row [B]; ``rows`` (default ``arange(B)``): the
+    cache row each batch row owns — the paged pool passes page ids, with
+    ``pos`` the offsets inside them.  A start above ``T - C`` is clamped,
+    as ``lax.dynamic_update_slice`` does.
+
+    On TPU this is ``ops/pallas_decode.py::kv_write``: in place, a tile a
+    row.  Either XLA form (a scatter, or ``dynamic_update_slice`` per
+    row) makes the chip's compiler re-lay the scan's carry for the write
+    and copy the whole stacked cache back for the kernel, every layer."""
+    from ..ops.pallas_decode import kv_write, kv_write_lax
+
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    B = new["k"].shape[0]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+    rows = jnp.arange(B) if rows is None else rows
+    layer = jnp.asarray(layer, jnp.int32)
+    out = dict(cache)
+    groups = [("k", "v")] + [("k_scale", "v_scale")] * ("k_scale" in cache)
+    for names in groups:  # same-shaped leaves share one kernel call
+        n = len(names)
+        leaves = tuple(cache[name] for name in names)
+        updates = tuple(new[name] for name in names)
+        if use_pallas:
+            from ..parallel.sharding import per_head_shard
+
+            done = per_head_shard(
+                lambda *a: kv_write(a[:n], a[n:2 * n], *a[2 * n:]),
+                leaves + updates, (layer, rows, pos),
+                head_dims=(2,) * n + (1,) * n, out_head_dims=(2,) * n)
+        else:
+            done = kv_write_lax(leaves, updates, layer, rows, pos)
+        out.update(zip(names, done))
+    return out
 
 
 def decode_step(params: dict, cache: dict, token, pos, cfg: LlamaConfig,
@@ -148,32 +208,27 @@ def decode_step(params: dict, cache: dict, token, pos, cfg: LlamaConfig,
         # [B, 1, 1, hd/2]: one rotation angle per row, broadcast over heads.
         cos_p = cos[pos][:, None, None, :]
         sin_p = sin[pos][:, None, None, :]
-
-        def write(c, u):
-            return jax.vmap(
-                lambda cr, ur, p: lax.dynamic_update_slice_in_dim(
-                    cr, ur, p, axis=1))(c, u, slot)
     else:
         cos_p = lax.dynamic_slice_in_dim(cos, pos, 1, axis=0)
         sin_p = lax.dynamic_slice_in_dim(sin, pos, 1, axis=0)
 
-        def write(c, u):
-            return lax.dynamic_update_slice_in_dim(c, u, slot, axis=2)
-
     h = embed_tokens(params, token, cfg)[:, None, :]  # [B, 1, D]
 
-    def attend(q, lc):
-        ksc, vsc = lc.get("k_scale"), lc.get("v_scale")
+    def write(cache, new, layer):
+        return _write_cached(cache, new, layer, slot)
+
+    def attend(q, cache, layer):
+        ksc, vsc = cache.get("k_scale"), cache.get("v_scale")
         if rolling:
             # Warm slots are exactly the window (we just overwrote the
             # oldest); cold-start slots (> pos) are masked by the clamped
             # position.  No window re-mask: absolute order is irrelevant.
-            return _attend_cached(q, lc["k"], lc["v"],
+            return _attend_cached(q, cache["k"], cache["v"],
                                   jnp.minimum(pos, T - 1), n_rep,
-                                  k_scale=ksc, v_scale=vsc)
-        return _attend_cached(q, lc["k"], lc["v"], pos, n_rep,
+                                  k_scale=ksc, v_scale=vsc, layer=layer)
+        return _attend_cached(q, cache["k"], cache["v"], pos, n_rep,
                               window=cfg.sliding_window,
-                              k_scale=ksc, v_scale=vsc)
+                              k_scale=ksc, v_scale=vsc, layer=layer)
 
     h, out = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg, write,
                                attend)
@@ -185,47 +240,48 @@ def decode_step(params: dict, cache: dict, token, pos, cfg: LlamaConfig,
 def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
                       write, attend):
     """The ONE per-layer body of every cached decode path — decode_step's
-    C=1 and the speculative chunk verify's C>1
-    (models/speculative.py:chunk_decode_step) run exactly this: qkv
-    projection, RoPE, quantize-on-write when the cache is int8, ``write``
-    at the caller's cursor(s), ``attend(q, layer_cache)``, FFN (dense or
-    MoE).  Sharing it is what keeps the pinned chunk==stepwise parity a
-    tautology instead of a maintenance contract.
+    C=1, the speculative chunk verify's C>1
+    (models/speculative.py:chunk_decode_step) and the paged pool's
+    (models/paged.py) run exactly this: qkv projection, RoPE,
+    quantize-on-write when the cache is int8, ``write`` at the caller's
+    cursor(s), ``attend``, FFN (dense or MoE).  Sharing it is what keeps
+    the pinned chunk==stepwise parity a tautology instead of a
+    maintenance contract.
 
-    h: [B, C, D] embedded inputs; ``write(c, u)`` places a [B, Hkv, C(,D)]
-    update (values and, int8, scales — the T axis sits at the same index
-    once the trailing D dim is dropped); ``attend`` returns [B, Hq, C, hd].
-    Returns ``(h [B, C, D], new cache dict)``.
+    The stacked cache arrays (``k``, ``v`` and, int8, ``k_scale`` /
+    ``v_scale``: [L, B, Hkv, T(, D)]) ride the scan's CARRY beside ``h``;
+    ``xs`` is the stacked layer weights and the layer index.  As scan
+    inputs and outputs they could not share a buffer: every layer would
+    be sliced out, updated as a slice and stored into a second stacked
+    array, and the caller's step scan would copy that array into its own
+    carry — half the device time of a serving step (PERF.md, PR 25).
+
+    h: [B, C, D] embedded inputs; ``write(cache, new, layer) -> cache``
+    places ``new`` (the same keys, [B, Hkv, C(, D)] each) at the caller's
+    cursor(s) of layer ``layer`` (:func:`_write_cached`);
+    ``attend(q, cache, layer)`` returns [B, Hq, C, hd] (write-then-attend:
+    it sees the entries just written).  Returns ``(h [B, C, D], cache)``.
     """
     B, C = h.shape[0], h.shape[1]
     hd = cfg.head_dim
     quant = "k_scale" in cache  # int8 cache (init_cache's format marker)
 
     def layer(carry, xs):
-        h, = carry
-        if quant:
-            lp, kc, vc, ksc, vsc = xs
-        else:
-            lp, kc, vc = xs
-            ksc = vsc = None
+        h, cache = carry
+        lp, li = xs
         x = rmsnorm(h, lp["attn_norm"], cfg.norm_eps)
         q, k, v = qkv_proj(x, lp, cfg)
         q = apply_rope(q, cos_p, sin_p)
         k = apply_rope(k, cos_p, sin_p)
+        new = {"k": k, "v": v}
         if quant:
             from ..ops.quantize import quantize_kv
 
             # Quantize-on-write: the cache never holds a wide entry.
-            k, k_s = quantize_kv(k)
-            v, v_s = quantize_kv(v)
-            ksc = write(ksc, k_s)
-            vsc = write(vsc, v_s)
-        kc = write(kc, k)
-        vc = write(vc, v)
-        layer_cache = {"k": kc, "v": vc}
-        if quant:
-            layer_cache["k_scale"], layer_cache["v_scale"] = ksc, vsc
-        o = attend(q, layer_cache)
+            new["k"], new["k_scale"] = quantize_kv(k)
+            new["v"], new["v_scale"] = quantize_kv(v)
+        cache = write(cache, new, li)
+        o = attend(q, cache, li)
         o = o.transpose(0, 2, 1, 3).reshape(B, C, cfg.n_heads * hd)
         h = h + matmul_w(o, lp["wo"])
 
@@ -242,16 +298,13 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
         else:
             gate = mlp_gate_act(matmul_w(x, lp["w_gate"]), cfg).astype(x.dtype)
             h = h + matmul_w(gate * matmul_w(x, lp["w_up"]), lp["w_down"])
-        return (h,), (kc, vc) + ((ksc, vsc) if quant else ())
+        return (h, cache), None
 
-    xs = (params["layers"], cache["k"], cache["v"])
-    if quant:
-        xs += (cache["k_scale"], cache["v_scale"])
-    (h,), new = lax.scan(layer, (h,), xs)
-    out = {"k": new[0], "v": new[1]}
-    if quant:
-        out["k_scale"], out["v_scale"] = new[2], new[3]
-    return h, out
+    n_layers = cache["k"].shape[0]
+    (h, cache), _ = lax.scan(
+        layer, (h, dict(cache)),
+        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
+    return h, cache
 
 
 def prefill(params: dict, cfg: LlamaConfig, prompt,

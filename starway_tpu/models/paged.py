@@ -45,7 +45,7 @@ from jax import lax
 
 from ..ops.pallas_paged import paged_decode_attention
 from ..parallel.sharding import per_head_shard
-from .generate import _sample, cached_layer_scan, prefill
+from .generate import _sample, _write_cached, cached_layer_scan, prefill
 from .llama import LlamaConfig, cfg_rope_tables, embed_tokens, matmul_w, rmsnorm
 from .serving import (SlotServer, _bucket, _named_jit, _on_weights_mesh,
                       make_chunk_scan_step)
@@ -58,6 +58,16 @@ def init_paged_pool(cfg: LlamaConfig, n_pages: int, page: int) -> dict:
     shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page, hd)
     return {"k": jnp.zeros(shape, cfg.compute_dtype),
             "v": jnp.zeros(shape, cfg.compute_dtype)}
+
+
+def _paged_attend(q, pool, layer, table, pos):
+    """The paged kernel over the stacked pool ``[L, n_pages, Hkv, page,
+    D]`` (heads at dim 2), per head shard under a tp mesh."""
+    return per_head_shard(
+        lambda q, k, v, table, pos, layer: paged_decode_attention(
+            q, k, v, table, pos, layer=layer),
+        (q, pool["k"], pool["v"]),
+        (table, pos, jnp.asarray(layer, jnp.int32)), head_dims=(1, 2, 2))
 
 
 def paged_decode_step(params, pool, table, token, pos, cfg: LlamaConfig,
@@ -78,15 +88,15 @@ def paged_decode_step(params, pool, table, token, pos, cfg: LlamaConfig,
     cos_p = cos[pos][:, None, None, :]
     sin_p = sin[pos][:, None, None, :]
 
-    def write(c, u):
-        # c [n_pages, Hkv, page, D] (one layer's pool slice in the scan);
-        # u [B, Hkv, 1, D].  Distinct slots own distinct pages (allocator
-        # invariant), so the scatter indices never collide.
-        return c.at[pids, :, offs, :].set(u[:, :, 0, :])
+    def write(pool, new, layer):
+        # The pool's "row" is a page and the cursor an offset inside it.
+        # Distinct LIVE slots own distinct pages (allocator invariant), so
+        # their tiles never collide; dead slots' zeroed table rows may
+        # collide on trash page 0, whose contents are never read.
+        return _write_cached(pool, new, layer, offs, rows=pids)
 
-    def attend(q, lc):
-        return per_head_shard(paged_decode_attention, (q, lc["k"], lc["v"]),
-                              (table, pos))
+    def attend(q, pool, layer):
+        return _paged_attend(q, pool, layer, table, pos)
 
     h = embed_tokens(params, token, cfg)[:, None, :]
     h, out = cached_layer_scan(params, pool, h, cos_p, sin_p, cfg, write,
@@ -201,13 +211,18 @@ def _compiled_paged_prefix_admit(cfg: LlamaConfig, s_bucket: int, page: int,
         cos_p = cos[spos][None, None, :, :]
         sin_p = sin[spos][None, None, :, :]
 
-        def write(c, u):
-            # u [1, Hkv, s_bucket, D] -> scatter rows at (pid, :, off).
-            return c.at[pids_c, :, offs, :].set(u[0].transpose(1, 0, 2))
+        def write(pool, new, layer):
+            # [1, Hkv, s_bucket, D] -> one row a TOKEN at (pid, :, off).
+            # Neighbouring tokens share a page tile, which the in-place
+            # kernel's rows must not (they would race): this write stays
+            # the XLA scatter, whatever the backend (PERF.md section 7).
+            tokens = {name: jnp.moveaxis(u[0], 1, 0)[:, :, None]
+                      for name, u in new.items()}
+            return _write_cached(pool, tokens, layer, offs, rows=pids_c,
+                                 use_pallas=False)
 
-        def attend(q, lc):
-            return per_head_shard(paged_decode_attention,
-                                  (q, lc["k"], lc["v"]), (row, plen[None]))
+        def attend(q, pool, layer):
+            return _paged_attend(q, pool, layer, row, plen[None])
 
         from .llama import embed_tokens, head_logits
 

@@ -40,7 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .generate import _filter_logits, _sample, cached_layer_scan, prefill
+from .generate import (_attend_cached, _filter_logits, _sample, _write_cached,
+                       cached_layer_scan, prefill)
 from .llama import (LlamaConfig, cfg_rope_tables, embed_tokens, matmul_w,
                     rmsnorm)
 
@@ -90,24 +91,19 @@ def chunk_decode_step(params, cache, tokens, pos, cfg: LlamaConfig, rope):
     cos_p = cos[pos_bc][:, None]  # [B, 1, C, hd/2]
     sin_p = sin[pos_bc][:, None]
 
-    def write(c, u):
-        """C contiguous entries at each row's cursor; same per-leaf axis
-        invariant as decode_step (T axis at index 1 per row)."""
-        return jax.vmap(
-            lambda cr, ur, p: lax.dynamic_update_slice_in_dim(
-                cr, ur, p, axis=1))(c, u, pos_b)
+    def write(cache, new, layer):
+        # C contiguous entries at each row's cursor.
+        return _write_cached(cache, new, layer, pos_b)
 
-    def attend(q, layer_cache):
+    def attend(q, cache, layer):
         # The SAME grouped-stream attention decode_step uses, at C query
         # positions: on TPU the pallas kernel packs C x n_rep rows into
         # one per-(batch, kv head) matmul over the narrow (int8-capable)
         # cache stream — the verify costs one decode step's bytes.
-        from .generate import _attend_cached
-
-        return _attend_cached(q, layer_cache["k"], layer_cache["v"], pos_b,
-                              n_rep, window=cfg.sliding_window,
-                              k_scale=layer_cache.get("k_scale"),
-                              v_scale=layer_cache.get("v_scale"))
+        return _attend_cached(q, cache["k"], cache["v"], pos_b, n_rep,
+                              window=cfg.sliding_window,
+                              k_scale=cache.get("k_scale"),
+                              v_scale=cache.get("v_scale"), layer=layer)
 
     h = embed_tokens(params, tokens, cfg)  # [B, C, D]
     h, out = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg, write,

@@ -28,7 +28,18 @@ Two variants share the same online-softmax block body:
 real hardware; which is faster there has not been measured this round.
 
 Same online-softmax algebra as ops/pallas_attention.py; layouts follow
-models/generate.py: ``q [B, Hq, 1, D]``, caches ``[B, Hkv, T, D]``.
+models/generate.py: ``q [B, Hq, 1, D]``, caches ``[B, Hkv, T, D]`` — or the
+whole scan-stacked cache ``[L, B, Hkv, T, D]`` with a traced ``layer``.
+
+The stacked form is what serves.  A decode program that slices one layer
+out of the stacked cache, updates the slice and stores it into a second
+stacked array moves the whole cache four to six times a step (measured,
+PR 23: half the device time of a 16-layer, 24 x 2048 serving step).  So
+both kernels take the layer as a second prefetched scalar and index HBM
+by it, and :func:`kv_write` (``sw_kv_write``) puts the new entries into
+the SAME buffer (``input_output_aliases``): the cache is only ever an
+operand of these custom calls, never sliced, padded or scattered into by
+XLA, so it can ride a ``lax.scan`` carry in place (models/generate.py).
 """
 
 from __future__ import annotations
@@ -104,9 +115,17 @@ def _row_offsets(rows: int, n_q: int):
         jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), n_q)
 
 
-def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *refs, sm_scale: float,
-                   block_k: int, hkv: int, window: "int | None",
-                   quant: bool = False, n_q: int = 1):
+def _head_row(scales, h):
+    """Row ``h`` of a ``[Hkv, block_k]`` f32 scale block as ``[1, block_k]``
+    (a masked sublane sum: one row OF a tile is not a slice Mosaic takes
+    at a traced index, and the block is a few KiB)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, scales.shape, 0)
+    return jnp.sum(jnp.where(rows == h, scales, 0.0), axis=0, keepdims=True)
+
+
+def _decode_kernel(pos_ref, layer_ref, q_ref, k_ref, v_ref, *refs,
+                   sm_scale: float, block_k: int, hkv: int,
+                   window: "int | None", quant: bool = False, n_q: int = 1):
     if quant:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -133,13 +152,15 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *refs, sm_scale: float,
         # pos + n_q - 1] (the union of every query's band).
         live = live & (k_start + block_k - 1 > pos - window)
 
+    h = jax.lax.rem(pl.program_id(0), hkv)
+
     @pl.when(live)
     def _body():
         _softmax_block_update(
-            q_ref[0], k_ref[0], v_ref[0], k_start, pos, m_scr, l_scr,
+            q_ref[0], k_ref[...], v_ref[...], k_start, pos, m_scr, l_scr,
             acc_scr, sm_scale=sm_scale, window=window,
-            k_scale=None if ks_ref is None else ks_ref[0],
-            v_scale=None if vs_ref is None else vs_ref[0],
+            k_scale=None if ks_ref is None else _head_row(ks_ref[...], h),
+            v_scale=None if vs_ref is None else _head_row(vs_ref[...], h),
             row_off=_row_offsets(q_ref.shape[1], n_q))
 
     @pl.when(ki == n_k - 1)
@@ -147,7 +168,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, *refs, sm_scale: float,
         o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
 
 
-def _decode_stream_kernel(pos_ref, q_ref, k_hbm, v_hbm, *refs,
+def _decode_stream_kernel(pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *refs,
                           sm_scale: float, block_k: int, hkv: int,
                           window: "int | None", n_blocks: int,
                           quant: bool = False, n_q: int = 1):
@@ -162,9 +183,15 @@ def _decode_stream_kernel(pos_ref, q_ref, k_hbm, v_hbm, *refs,
     stream (~cache bytes / HBM bandwidth) and the (tiny) grouped-GQA
     matmuls.
 
-    ``quant``: two extra HBM inputs (per-token f32 scales) and two extra
-    scratch buffers ride the same double-buffered pipeline; the int8 cache
-    blocks halve the DMA bytes (the scales add 1/(2*D) back).
+    ``k_hbm``/``v_hbm`` are the whole stacked caches ``[L, B, Hkv, T, D]``
+    left in HBM; ``layer_ref`` (second prefetched scalar) picks the layer
+    in the DMA's source address, so no layer is ever sliced out.
+
+    ``quant``: two extra HBM inputs (per-token f32 scales ``[L, B, Hkv,
+    T]``) and two extra scratch buffers ride the same double-buffered
+    pipeline; the int8 cache blocks halve the DMA bytes.  A cell fetches
+    its batch row's ``[Hkv, block_k]`` scale block whole (one head's row
+    of it is a slice below the (8, 128) tile) and keeps its own row.
     """
     if quant:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems, m_scr,
@@ -173,7 +200,10 @@ def _decode_stream_kernel(pos_ref, q_ref, k_hbm, v_hbm, *refs,
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
         o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = refs
     bh = pl.program_id(0)
-    pos = pos_ref[bh // hkv]
+    b = bh // hkv
+    h = jax.lax.rem(bh, hkv)
+    layer = layer_ref[0]
+    pos = pos_ref[b]
     hi = (pos + n_q - 1) // block_k  # last live block (queries span n_q)
     if window is None:
         lo = jnp.int32(0)
@@ -181,22 +211,19 @@ def _decode_stream_kernel(pos_ref, q_ref, k_hbm, v_hbm, *refs,
         lo = jnp.maximum(pos - window + 1, 0) // block_k
 
     def copies(i, slot):
+        blk = pl.ds(i * block_k, block_k)
         cps = [
             pltpu.make_async_copy(
-                k_hbm.at[bh, pl.ds(i * block_k, block_k)], k_buf.at[slot],
-                sems.at[slot, 0]),
+                k_hbm.at[layer, b, h, blk], k_buf.at[slot], sems.at[slot, 0]),
             pltpu.make_async_copy(
-                v_hbm.at[bh, pl.ds(i * block_k, block_k)], v_buf.at[slot],
-                sems.at[slot, 1]),
+                v_hbm.at[layer, b, h, blk], v_buf.at[slot], sems.at[slot, 1]),
         ]
         if quant:
             cps.append(pltpu.make_async_copy(
-                ks_hbm.at[bh, :, pl.ds(i * block_k, block_k)],
-                ks_buf.at[slot],
+                ks_hbm.at[layer, b, :, blk], ks_buf.at[slot],
                 sems.at[slot, 2]))
             cps.append(pltpu.make_async_copy(
-                vs_hbm.at[bh, :, pl.ds(i * block_k, block_k)],
-                vs_buf.at[slot],
+                vs_hbm.at[layer, b, :, blk], vs_buf.at[slot],
                 sems.at[slot, 3]))
         return cps
 
@@ -229,8 +256,8 @@ def _decode_stream_kernel(pos_ref, q_ref, k_hbm, v_hbm, *refs,
             _softmax_block_update(
                 q, k_buf[slot], v_buf[slot], i * block_k, pos, m_scr, l_scr,
                 acc_scr, sm_scale=sm_scale, window=window,
-                k_scale=None if not quant else ks_buf[slot],
-                v_scale=None if not quant else vs_buf[slot],
+                k_scale=None if not quant else _head_row(ks_buf[slot], h),
+                v_scale=None if not quant else _head_row(vs_buf[slot], h),
                 row_off=_row_offsets(q.shape[0], n_q))
 
         return 0
@@ -239,7 +266,21 @@ def _decode_stream_kernel(pos_ref, q_ref, k_hbm, v_hbm, *refs,
     o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
 
 
-def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None,
+def _pick_block(t: int, block_k: int, quant: bool) -> "int | None":
+    """A kv block that DIVIDES the cache length, so that the cache is
+    streamed where it lies: the largest multiple of 128 up to ``block_k``
+    that divides ``t``, else (bf16 only: the scales' positions lie on the
+    128 lanes) a moderate length of whole sublane tiles as one block.
+    None: this length cannot be tiled."""
+    if t % 128 == 0:
+        return max(c for c in range(128, max(block_k, 128) + 1, 128)
+                   if t % c == 0)
+    if not quant and t % 8 == 0 and t <= 4096:
+        return t
+    return None
+
+
+def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
                      block_k: int = 512, interpret=None, window=None,
                      stream: "bool | None" = None, k_scale=None,
                      v_scale=None):
@@ -251,24 +292,36 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None,
     models/speculative.py packs C positions x n_rep grouped heads as the
     rows of the SAME per-(batch, kv head) matmul, so the cache still
     streams exactly once, narrow and int8-capable).  k_cache/v_cache:
-    [B, Hkv, T, D]; pos: scalar int or per-row [B] int (ragged batches)
-    — row b's queries sit at ``pos[b] .. pos[b] + C - 1``, key positions
-    above each query are masked, and row b's DMA stops at its last
-    query's block.  Write-then-attend callers must have the C entries in
-    the cache already.  ``window`` (static): sliding-window attention
-    over the last ``window`` positions — blocks entirely below the
-    window are DMA-elided too, so a windowed decode streams ~window
-    bytes of cache regardless of T.  Returns [B, Hq, C, D].  Numerically
-    matches models/generate.py:_attend_cached (softmax in f32).
+    the scan-stacked caches ``[L, B, Hkv, T, D]`` with ``layer`` a scalar
+    int (traced inside the layer scan: it reaches the kernel as a
+    prefetched scalar and indexes HBM, so no layer is sliced out and the
+    stacked arrays are never copied), or one layer's ``[B, Hkv, T, D]``
+    with ``layer=None`` (a stack of one).  pos: scalar int or per-row [B]
+    int (ragged batches) — row b's queries sit at ``pos[b] .. pos[b] + C
+    - 1``, key positions above each query are masked, and row b's DMA
+    stops at its last query's block.  Write-then-attend callers must have
+    the C entries in the cache already (:func:`kv_write`).  ``window``
+    (static): sliding-window attention over the last ``window`` positions
+    — blocks entirely below the window are DMA-elided too, so a windowed
+    decode streams ~window bytes of cache regardless of T.  Returns [B,
+    Hq, C, D].  Numerically matches models/generate.py:_attend_cached
+    (softmax in f32).
 
-    ``k_scale``/``v_scale`` ([B, Hkv, T] f32): int8-quantized caches
-    (ops/quantize.py) — the kernel streams the int8 blocks (half the HBM
-    bytes of bf16) and folds dequantization into the score/weight algebra;
-    both or neither must be given, matching the caches' int8 dtype.  They
-    reach the kernel as [B*Hkv, 1, T]: Mosaic blocks and DMAs the last
-    two dims in (8, 128) tiles unless a block spans the whole dim, so one
-    row OF a 2-D [B*Hkv, T] array is refused by the chip's compiler while
-    a (1, block_k) slab of a dim of size 1 is not.
+    ``k_scale``/``v_scale`` ([L, B, Hkv, T] f32, or [B, Hkv, T] with
+    ``layer=None``): int8-quantized caches (ops/quantize.py) — the kernel
+    streams the int8 blocks (half the HBM bytes of bf16) and folds
+    dequantization into the score/weight algebra; both or neither must be
+    given, matching the caches' int8 dtype.  They stay in that layout: a
+    cell reads its batch row's ``[Hkv, block_k]`` block (Mosaic blocks
+    and DMAs the last two dims in (8, 128) tiles unless a block spans the
+    whole dim, so one head's row of it is refused by the chip's compiler)
+    and keeps its head's row.
+
+    The kv block is chosen to divide T (:func:`_pick_block`), so nothing
+    is padded.  A length no block divides (not a multiple of 128; for
+    bf16 up to 4096, of 8) costs what every length cost before: that
+    layer is sliced out and padded, a copy of it a call.  Allocate
+    multiples of 128.
 
     ``stream`` (default True; ``STARWAY_DECODE_STREAM=0`` flips the
     default): the double-buffered single-cell kernel
@@ -293,8 +346,14 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None,
                 f"{name} dtype {c.dtype} inconsistent with "
                 f"{'present' if quant else 'absent'} scales (int8 caches "
                 f"carry per-token scales; see ops/quantize.py)")
+    scales = ([s.astype(jnp.float32) for s in (k_scale, v_scale)]
+              if quant else [])
+    if layer is None:
+        k_cache, v_cache = k_cache[None], v_cache[None]
+        scales = [s[None] for s in scales]
+        layer = 0
     b, hq, n_q, d = q.shape
-    hkv, t = k_cache.shape[1], k_cache.shape[2]
+    hkv, t = k_cache.shape[2], k_cache.shape[3]
     n_rep = hq // hkv
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
@@ -312,106 +371,240 @@ def decode_attention(q, k_cache, v_cache, pos, *, sm_scale=None,
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - n_rows), (0, 0)))
     qf = qg.reshape(b * hkv, rows, d)
 
-    block_k = min(block_k, _round_up(t, 128))
-    t_pad = _round_up(t, block_k)
-    kf = k_cache.reshape(b * hkv, t, d)
-    vf = v_cache.reshape(b * hkv, t, d)
-    if t_pad != t:
-        kf = jnp.pad(kf, ((0, 0), (0, t_pad - t), (0, 0)))
-        vf = jnp.pad(vf, ((0, 0), (0, t_pad - t), (0, 0)))
-    scales = []
-    if quant:
-        for s in (k_scale, v_scale):
-            # [b*hkv, 1, T], not [b*hkv, T]: one row's scales are then a
-            # whole (1, block_k) slab of the last two dims, which Mosaic
-            # can block and DMA; a single row OF a 2-D f32 array is a
-            # slice below the (8, 128) tile and is refused.
-            sf = s.astype(jnp.float32).reshape(b * hkv, 1, t)
-            if t_pad != t:
-                sf = jnp.pad(sf, ((0, 0), (0, 0), (0, t_pad - t)))
-            scales.append(sf)
+    if _pick_block(t, block_k, quant) is None:
+        def one_padded(a):
+            a = jax.lax.dynamic_index_in_dim(a, layer, 0)
+            grow = [(0, 0)] * a.ndim
+            grow[3] = (0, _round_up(t, 128) - t)
+            return jnp.pad(a, grow)
 
+        k_cache, v_cache = one_padded(k_cache), one_padded(v_cache)
+        scales = [one_padded(s) for s in scales]
+        layer, t = 0, k_cache.shape[3]
+    block_k = _pick_block(t, block_k, quant)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
+    q_spec = pl.BlockSpec((1, rows, d), lambda bh, *_: (bh, 0, 0))
+    softmax_scratch = [
+        pltpu.VMEM((rows, 128), jnp.float32),
+        pltpu.VMEM((rows, 128), jnp.float32),
+        pltpu.VMEM((rows, d), jnp.float32),
+    ]
+    common = dict(sm_scale=sm_scale, block_k=block_k, hkv=hkv,
+                  window=None if window is None else int(window),
+                  quant=quant, n_q=n_q)
 
     if stream:
         any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
-        quant_scratch = [
-            pltpu.VMEM((2, 1, block_k), jnp.float32),
-            pltpu.VMEM((2, 1, block_k), jnp.float32),
-        ] if quant else []
+        quant_scratch = [pltpu.VMEM((2, hkv, block_k), jnp.float32)] * (
+            2 * quant)
         out = pl.pallas_call(
-            functools.partial(
-                _decode_stream_kernel, sm_scale=sm_scale, block_k=block_k,
-                hkv=hkv, window=None if window is None else int(window),
-                n_blocks=t_pad // block_k, quant=quant, n_q=n_q),
+            functools.partial(_decode_stream_kernel,
+                              n_blocks=t // block_k, **common),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
+                num_scalar_prefetch=2,
                 grid=(b * hkv,),
-                in_specs=[
-                    pl.BlockSpec((1, rows, d), lambda bh, pos_ref: (bh, 0, 0)),
-                    any_spec,
-                    any_spec,
-                ] + [any_spec] * (2 * quant),
-                out_specs=pl.BlockSpec((1, rows, d),
-                                       lambda bh, pos_ref: (bh, 0, 0)),
+                in_specs=[q_spec] + [any_spec] * (2 + 2 * quant),
+                out_specs=q_spec,
                 scratch_shapes=[
-                    pltpu.VMEM((2, block_k, d), kf.dtype),
-                    pltpu.VMEM((2, block_k, d), vf.dtype),
+                    pltpu.VMEM((2, block_k, d), k_cache.dtype),
+                    pltpu.VMEM((2, block_k, d), v_cache.dtype),
                 ] + quant_scratch + [
                     pltpu.SemaphoreType.DMA((2, 4 if quant else 2)),
-                    pltpu.VMEM((rows, 128), jnp.float32),
-                    pltpu.VMEM((rows, 128), jnp.float32),
-                    pltpu.VMEM((rows, d), jnp.float32),
-                ],
+                ] + softmax_scratch,
             ),
             out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), q.dtype),
             interpret=interpret,
             name="sw_decode_attn_stream",
-        )(pos_arr, qf, kf, vf, *scales)
+        )(pos_arr, layer_arr, qf, k_cache, v_cache, *scales)
         return out.reshape(b, hkv, rows, d)[:, :, :n_rows, :].reshape(
             b, hq, n_q, d)
-
-    grid = (b * hkv, t_pad // block_k)
 
     # Clamp the K/V block index into the live range: the kernel body is
     # skipped outside it (pl.when), and a repeated block index makes the
     # Pallas pipeline elide the HBM copy entirely -- so a decode at pos
     # streams only the blocks holding (pos - window, pos + n_q - 1], not
-    # the whole padded cache.  (pl.when alone skips compute, not DMA.)
-    def _kv_index(bh, ki, pos_ref):
+    # the whole cache.  (pl.when alone skips compute, not DMA.)
+    def _live_block(bh, ki, pos_ref):
         p = pos_ref[bh // hkv]
         hi = (p + n_q - 1) // block_k
         if window is None:
-            return (bh, jnp.minimum(ki, hi), 0)
+            return jnp.minimum(ki, hi)
         lo = jnp.maximum(p - window + 1, 0) // block_k
-        return (bh, jnp.clip(ki, lo, hi), 0)
+        return jnp.clip(ki, lo, hi)
 
-    def _scale_index(bh, ki, pos_ref):
-        bh_, ki_, _ = _kv_index(bh, ki, pos_ref)
-        return (bh_, 0, ki_)
+    def _kv_index(bh, ki, pos_ref, layer_ref):
+        return (layer_ref[0], bh // hkv, jax.lax.rem(bh, hkv),
+                _live_block(bh, ki, pos_ref), 0)
 
+    def _scale_index(bh, ki, pos_ref, layer_ref):
+        return (layer_ref[0], bh // hkv, 0, _live_block(bh, ki, pos_ref))
+
+    kv_spec = pl.BlockSpec((None, None, None, block_k, d), _kv_index)
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, sm_scale=sm_scale, block_k=block_k,
-                          hkv=hkv, window=None if window is None else int(window),
-                          quant=quant, n_q=n_q),
+        functools.partial(_decode_kernel, **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, rows, d), lambda bh, ki, pos_ref: (bh, 0, 0)),
-                pl.BlockSpec((1, block_k, d), _kv_index),
-                pl.BlockSpec((1, block_k, d), _kv_index),
-            ] + [pl.BlockSpec((1, 1, block_k), _scale_index)] * (2 * quant),
-            out_specs=pl.BlockSpec((1, rows, d), lambda bh, ki, pos_ref: (bh, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, d), jnp.float32),
-            ],
+            num_scalar_prefetch=2,
+            grid=(b * hkv, t // block_k),
+            in_specs=[q_spec, kv_spec, kv_spec] + [
+                pl.BlockSpec((None, None, hkv, block_k), _scale_index)
+            ] * (2 * quant),
+            out_specs=q_spec,
+            scratch_shapes=softmax_scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), q.dtype),
         interpret=interpret,
         name="sw_decode_attn",
-    )(pos_arr, qf, kf, vf, *scales)
+    )(pos_arr, layer_arr, qf, k_cache, v_cache, *scales)
     return out.reshape(b, hkv, rows, d)[:, :, :n_rows, :].reshape(
         b, hq, n_q, d)
+
+
+# ------------------------------------------------------ the in-place write
+
+# VMEM one call's read-modify-write buffers may take; the update blocks
+# ride the Pallas pipeline double-buffered on top (C > 1), all inside the
+# compiler's default scoped limit of 16 MiB.
+_WRITE_VMEM_BYTES = 3 << 20
+
+
+def _kv_write_kernel(layer_ref, row_ref, start_ref, lo_ref, hi_ref, *refs,
+                     n_arr: int, group: int, width: int, align: int):
+    """One grid cell per GROUP of rows.  For each row and each array: DMA
+    the tile-aligned window ``[Hkv, width(, D)]`` that holds the row's new
+    positions out of HBM, replace positions ``lo .. hi - 1`` of it by the
+    update (a select), DMA it back to where it came from.  All of a
+    group's reads are in flight together, then all of its writes: a
+    decode step's 24 rows cost two DMA latencies, not 48."""
+    updates = refs[:n_arr]
+    src = refs[n_arr:2 * n_arr]        # the caches, in HBM
+    dst = refs[2 * n_arr:3 * n_arr]    # the same buffers (aliased outputs)
+    bufs = refs[3 * n_arr:4 * n_arr]   # VMEM [group, Hkv, width(, D)]
+    sems = refs[4 * n_arr]
+    layer = layer_ref[0]
+    base = pl.program_id(0) * group
+
+    def window(ref, i):
+        return ref.at[(layer, row_ref[i], slice(None),
+                       pl.ds(pl.multiple_of(start_ref[i], align), width))
+                      + (slice(None),) * (len(ref.shape) - 4)]
+
+    def each(make):
+        return [make(a, j) for a in range(n_arr) for j in range(group)]
+
+    reads = each(lambda a, j: pltpu.make_async_copy(
+        window(src[a], base + j), bufs[a].at[j], sems.at[a, j]))
+    for cp in reads:
+        cp.start()
+    for cp in reads:
+        cp.wait()
+    for j in range(group):
+        at = jax.lax.broadcasted_iota(jnp.int32, bufs[0].shape[1:], 1)
+        new = (at >= lo_ref[base + j]) & (at < hi_ref[base + j])
+        for a in range(n_arr):
+            bufs[a][j] = jnp.where(new, updates[a][j], bufs[a][j])
+    writes = each(lambda a, j: pltpu.make_async_copy(
+        bufs[a].at[j], window(dst[a], base + j), sems.at[a, j]))
+    for cp in writes:
+        cp.start()
+    for cp in writes:
+        cp.wait()
+
+
+def kv_write_lax(caches, updates, layer, rows, pos):
+    """:func:`kv_write` in plain lax (one scatter an array): what runs
+    where Pallas does not (the CPU), where a cache length has no whole
+    tiles, and what the kernel is tested against.  Same clamp."""
+    c = updates[0].shape[2]
+    t = caches[0].shape[3]
+    pos = jnp.clip(jnp.asarray(pos, jnp.int32), 0, t - c)
+    at = pos[:, None] + jnp.arange(c)[None, :]                  # [N, C]
+    rows = jnp.asarray(rows, jnp.int32)[:, None]
+    # Advanced indices (row, position) lead the update: [N, C, Hkv(, D)].
+    return tuple(
+        x.at[layer, rows, :, at].set(jnp.moveaxis(u, 2, 1))
+        for x, u in zip(caches, updates))
+
+
+def kv_write(caches, updates, layer, rows, pos, *, interpret=None):
+    """``cache[layer, rows[n], :, pos[n] + c] = update[n, :, c]`` for every
+    ``(cache, update)`` pair, IN PLACE: each output aliases its cache
+    operand, only the new entries' tiles move, and the stacked array's
+    layout is left alone.  This is the write half of write-then-attend
+    (:func:`decode_attention` is the read half); the XLA forms of it
+    (scatter, ``dynamic_update_slice`` per row) make the chip's compiler
+    re-lay the scan's carry and copy the whole cache every layer.
+
+    caches: same-shaped stacked arrays ``[L, R, Hkv, T, D]`` (k and v) or
+    ``[L, R, Hkv, T]`` (their int8 scales); updates: ``[N, Hkv, C, D]`` /
+    ``[N, Hkv, C]``, the C new consecutive positions of N rows; layer:
+    scalar; rows, pos: ``[N]`` ints — the dense cache has ``rows =
+    arange(B)``, the paged pool ``rows = page ids, pos = offsets``.  As
+    with ``lax.dynamic_update_slice`` a start above ``T - C`` is clamped.
+    The N windows of one call must not overlap (two rows in one tile
+    would race, each writing back what it read).
+
+    DMAs move whole tiles, so a row's window is the aligned span of
+    ``width`` positions around ``pos .. pos + C - 1`` (at C = 1: 16 for
+    bf16, 32 for int8, 128 lanes for the scales).  A cache length with no
+    whole tiles takes :func:`kv_write_lax`.  Returns the updated caches,
+    as a tuple."""
+    c0, u0 = caches[0], updates[0]
+    hkv, t = c0.shape[2:4]
+    n, c = u0.shape[0], u0.shape[2]
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    # T is the second-minor (sublane) dim of k/v and the minor (lane) dim
+    # of the scales.
+    align = 128 if c0.ndim == 4 else 32 // c0.dtype.itemsize
+    if t % align:
+        return kv_write_lax(caches, updates, layer, rows, pos)
+    tail = c0.shape[4:]
+    row_bytes = sum(x.dtype.itemsize for x in caches) * hkv * (
+        tail[0] if tail else 1)  # of one position, all arrays
+    c_max = max(_WRITE_VMEM_BYTES // row_bytes - align, align)
+    pos = jnp.clip(jnp.asarray(pos, jnp.int32), 0, t - c)
+    if c > c_max:  # a long chunk (a prefix admit's suffix): in pieces
+        for at in range(0, c, c_max):
+            caches = kv_write(
+                caches, [u[:, :, at:at + c_max] for u in updates], layer,
+                rows, pos + at, interpret=interpret)
+        return tuple(caches)
+
+    width = min(_round_up(align - 1 + c, align), t)
+    start = jnp.minimum(pos // align * align, t - width)
+    lo = pos - start
+    if c > 1:
+        # The update, placed in its window by XLA (a gather over a few
+        # KiB): in the kernel a shift by a traced count is not a select.
+        src = jnp.clip(jnp.arange(width)[None, :] - lo[:, None], 0, c - 1)
+        updates = [jnp.take_along_axis(
+            u, src.reshape((n, 1, width) + (1,) * (u.ndim - 3)), axis=2)
+            for u in updates]
+    group = max(g for g in range(1, n + 1)
+                if n % g == 0 and (g == 1 or g * width * row_bytes
+                                   <= _WRITE_VMEM_BYTES))
+    n_arr = len(caches)
+    ublock = (group, hkv, updates[0].shape[2]) + tail
+    any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    out = pl.pallas_call(
+        functools.partial(_kv_write_kernel, n_arr=n_arr, group=group,
+                          width=width, align=align),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(n // group,),
+            in_specs=[pl.BlockSpec(ublock, lambda g, *_: (g,) + (0,) * (
+                len(ublock) - 1))] * n_arr + [any_spec] * n_arr,
+            out_specs=[any_spec] * n_arr,
+            scratch_shapes=[
+                pltpu.VMEM((group, hkv, width) + tail, x.dtype)
+                for x in caches
+            ] + [pltpu.SemaphoreType.DMA((n_arr, group))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in caches],
+        input_output_aliases={5 + n_arr + a: a for a in range(n_arr)},
+        interpret=interpret,
+        name="sw_kv_write",
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.asarray(rows, jnp.int32), start, lo, lo + c, *updates, *caches)
+    return tuple(out)

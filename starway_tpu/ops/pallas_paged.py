@@ -43,18 +43,21 @@ from .pallas_attention import _round_up
 from .pallas_decode import _row_offsets, _softmax_block_update
 
 
-def _paged_stream_kernel(meta_ref, q_ref, k_pool, v_pool, o_ref, k_buf,
-                         v_buf, sems, m_scr, l_scr, acc_scr, *,
+def _paged_stream_kernel(meta_ref, layer_ref, q_ref, k_pool, v_pool, o_ref,
+                         k_buf, v_buf, sems, m_scr, l_scr, acc_scr, *,
                          sm_scale: float, page: int, hkv: int,
                          max_pages: int, n_q: int):
     """One grid cell per (slot, kv head); fori_loop over the slot's pages
     with double-buffered DMA through the block table.
 
     ``meta_ref`` (scalar prefetch, SMEM): ``[n_slots, 1 + max_pages]`` —
-    column 0 is the slot's cursor, columns 1.. its page ids."""
+    column 0 is the slot's cursor, columns 1.. its page ids.
+    ``layer_ref``: the layer of the stacked pools ``[L, n_pages, Hkv,
+    page, D]`` to read, in the DMA's source address."""
     bh = pl.program_id(0)
     b = bh // hkv
     h = jax.lax.rem(bh, hkv)
+    layer = layer_ref[0]
     pos = meta_ref[b, 0]
     hi = (pos + n_q - 1) // page  # last live page (queries span n_q)
 
@@ -62,9 +65,9 @@ def _paged_stream_kernel(meta_ref, q_ref, k_pool, v_pool, o_ref, k_buf,
         pid = meta_ref[b, 1 + i]
         return [
             pltpu.make_async_copy(
-                k_pool.at[pid, h], k_buf.at[slot], sems.at[slot, 0]),
+                k_pool.at[layer, pid, h], k_buf.at[slot], sems.at[slot, 0]),
             pltpu.make_async_copy(
-                v_pool.at[pid, h], v_buf.at[slot], sems.at[slot, 1]),
+                v_pool.at[layer, pid, h], v_buf.at[slot], sems.at[slot, 1]),
         ]
 
     m_scr[:] = jnp.full_like(m_scr, NEG_BIG)
@@ -99,13 +102,16 @@ def _paged_stream_kernel(meta_ref, q_ref, k_pool, v_pool, o_ref, k_buf,
     o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
 
 
-def paged_decode_attention(q, k_pool, v_pool, table, pos, *, sm_scale=None,
-                           interpret=None):
+def paged_decode_attention(q, k_pool, v_pool, table, pos, *, layer=None,
+                           sm_scale=None, interpret=None):
     """Decode attention over a paged KV pool.
 
     q: ``[B, Hq, C, D]`` (C consecutive query positions per slot, like
-    the dense kernel — C=1 is plain decode).  k_pool/v_pool:
-    ``[n_pages, Hkv, page, D]``; table: ``[B, max_pages] int32`` (page i
+    the dense kernel — C=1 is plain decode).  k_pool/v_pool: the stacked
+    pools ``[L, n_pages, Hkv, page, D]`` with ``layer`` a (traced) scalar
+    that indexes HBM in the kernel, so no layer is sliced out of the scan's
+    carry; or one layer's ``[n_pages, Hkv, page, D]`` with
+    ``layer=None``.  table: ``[B, max_pages] int32`` (page i
     of slot b holds positions ``i*page .. (i+1)*page - 1``; ids past the
     cursor may be anything — they are never fetched); pos: scalar or
     ``[B]`` cursors.  Returns ``[B, Hq, C, D]``, numerically matching
@@ -116,8 +122,10 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, *, sm_scale=None,
         raise NotImplementedError(
             "int8 paged pools are not wired yet; serve int8 caches "
             "through the dense kernel (ops/pallas_decode.py)")
+    if layer is None:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     b, hq, n_q, d = q.shape
-    n_pages_total, hkv, page, _ = k_pool.shape
+    hkv, page = k_pool.shape[2:4]
     max_pages = table.shape[1]
     n_rep = hq // hkv
     if sm_scale is None:
@@ -142,15 +150,14 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, *, sm_scale=None,
             _paged_stream_kernel, sm_scale=sm_scale, page=page, hkv=hkv,
             max_pages=max_pages, n_q=n_q),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b * hkv,),
             in_specs=[
-                pl.BlockSpec((1, rows, d), lambda bh, meta_ref: (bh, 0, 0)),
+                pl.BlockSpec((1, rows, d), lambda bh, *_: (bh, 0, 0)),
                 any_spec,
                 any_spec,
             ],
-            out_specs=pl.BlockSpec((1, rows, d),
-                                   lambda bh, meta_ref: (bh, 0, 0)),
+            out_specs=pl.BlockSpec((1, rows, d), lambda bh, *_: (bh, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, page, d), k_pool.dtype),
                 pltpu.VMEM((2, page, d), v_pool.dtype),
@@ -163,7 +170,7 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, *, sm_scale=None,
         out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), q.dtype),
         interpret=interpret,
         name="sw_paged_decode_attn",
-    )(meta, qf, k_pool, v_pool)
+    )(meta, jnp.asarray(layer, jnp.int32).reshape(1), qf, k_pool, v_pool)
     return out.reshape(b, hkv, rows, d)[:, :, :n_rows, :].reshape(
         b, hq, n_q, d)
 

@@ -45,11 +45,17 @@ def shard_map_fn(mesh: Mesh, fn, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)
 
 
-def per_head_shard(kernel, sharded, replicated=(), *, axis: str = "tp"):
+def per_head_shard(kernel, sharded, replicated=(), *, axis: str = "tp",
+                   head_dims=None, out_head_dims=None):
     """``kernel(*sharded, *replicated)``, run per shard of the head
-    dimension (dim 1 of every ``sharded`` operand and of the result) when
-    the ambient mesh (``jax.set_mesh``) has ``axis`` with more than one
-    device; called directly otherwise.
+    dimension when the ambient mesh (``jax.set_mesh``) has ``axis`` with
+    more than one device; called directly otherwise.
+
+    ``head_dims``: which dim of each ``sharded`` operand counts heads —
+    1 for ``[B, H, ...]`` activations and per-layer caches (the default
+    for all), 2 for the scan-stacked caches ``[L, B, Hkv, T, D]``.
+    ``out_head_dims``: the same for the result — an int, or a tuple when
+    the kernel returns a tuple; default the first operand's.
 
     A Mosaic kernel cannot be partitioned by the compiler: traced under a
     tensor-parallel GSPMD program it is refused ("Mosaic kernels cannot be
@@ -62,12 +68,18 @@ def per_head_shard(kernel, sharded, replicated=(), *, axis: str = "tp"):
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.shape.get(axis, 1) == 1:
         return kernel(*sharded, *replicated)
+    if head_dims is None:
+        head_dims = (1,) * len(sharded)
+    if out_head_dims is None:
+        out_head_dims = head_dims[0]
 
-    def heads(x):
-        return P(None, axis, *([None] * (x.ndim - 2)))
+    def heads(dim):
+        return P(*([None] * dim), axis)
 
     return jax.shard_map(
         kernel,
-        in_specs=(*(heads(x) for x in sharded), *(P() for _ in replicated)),
-        out_specs=heads(sharded[0]), check_vma=False,
+        in_specs=(*(heads(d) for d in head_dims), *(P() for _ in replicated)),
+        out_specs=(heads(out_head_dims) if isinstance(out_head_dims, int)
+                   else tuple(heads(d) for d in out_head_dims)),
+        check_vma=False,
     )(*sharded, *replicated)

@@ -214,18 +214,18 @@ def _as_tpu(monkeypatch):
 N_SLOTS, MAX_LEN, CHUNK = 8, 2048, 8
 
 
-def _slot_state():
-    return (_s((N_SLOTS,), I32), _s((N_SLOTS,), I32), _s((N_SLOTS,), bool),
-            _s((N_SLOTS,), I32), jax.eval_shape(jax.random.PRNGKey, 0))
+def _slot_state(n_slots=N_SLOTS):
+    return (_s((n_slots,), I32), _s((n_slots,), I32), _s((n_slots,), bool),
+            _s((n_slots,), I32), jax.eval_shape(jax.random.PRNGKey, 0))
 
 
-def _chunk_program(cfg):
+def _chunk_program(cfg, n_slots=N_SLOTS):
     from starway_tpu.models.generate import init_cache
     from starway_tpu.models.serving import _compiled_chunk
 
-    run = _compiled_chunk(cfg, N_SLOTS, MAX_LEN, CHUNK, 0.0, None, None, None)
-    cache = jax.eval_shape(lambda: init_cache(cfg, N_SLOTS, MAX_LEN))
-    return run, (_param_shapes(cfg), cache, *_slot_state())
+    run = _compiled_chunk(cfg, n_slots, MAX_LEN, CHUNK, 0.0, None, None, None)
+    cache = jax.eval_shape(lambda: init_cache(cfg, n_slots, MAX_LEN))
+    return run, (_param_shapes(cfg), cache, *_slot_state(n_slots))
 
 
 def _admit_program(cfg, bucket=2048):
@@ -255,8 +255,8 @@ def _memory_gb(compiled):
             + m.temp_size_in_bytes - m.alias_size_in_bytes) / 1e9
 
 
-def _compile_program(topo, build, cfg):
-    run, args = build(cfg)
+def _compile_program(topo, build, cfg, **kw):
+    run, args = build(cfg, **kw)
     # The programs are jitted already (donation included): lower them as
     # the servers call them.
     return run.lower(
@@ -271,6 +271,75 @@ def test_slot_server_decode_chunk_compiles_for_v5e(topo, monkeypatch):
     compiled = _compile_program(topo, _chunk_program, _llama3_8l())
     assert "tpu_custom_call" in compiled.as_text()
     assert _memory_gb(compiled) < 15.75
+
+
+# The benchmark's serving cells (benchmark/configs/mistral7b.json):
+# Mistral-7B widths (the attention geometry above, d_ff 14336, vocab
+# 32000), 16 layers, 24 slots x 2048, chunk 8.  Shapes only.
+CELL_SLOTS = 24
+
+
+def _mistral7b_16l(**kw):
+    from starway_tpu.models import LlamaConfig
+
+    return LlamaConfig(vocab_size=32000, d_model=4096, n_layers=16,
+                       n_heads=HQ, n_kv_heads=HKV, d_ff=14336,
+                       rope_theta=1e6, dtype="bfloat16", **kw)
+
+
+def _cache_moves(text, n_layers, n_slots):
+    """Instructions of the compiled program whose result has the stacked
+    k/v cache's shape or one layer's, and that are not the carry handed
+    on as it is (parameters, tuples, the loops, bitcasts) or a kernel
+    that takes it in place: copies, scatters, dynamic slices and updates,
+    and the fusions XLA makes of them, by whatever name."""
+    import re
+
+    layer = f"{n_slots},{HKV},{MAX_LEN},{D}]"
+    shaped = re.compile(
+        rf"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[(?:{n_layers},|1,)?"
+        + re.escape(layer) + r"\S* ([\w\-]+)\(")
+    passes = {"parameter", "get-tuple-element", "bitcast", "custom-call"}
+    return [(m.group(1), m.group(2)) for m in map(shaped.match,
+                                                  text.splitlines())
+            if m and m.group(2) not in passes]
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_decode_chunk_moves_no_cache_for_v5e(topo, monkeypatch, kv):
+    """The decode chunk at the serving cells' geometry: the stacked cache
+    rides the scans' carries and only kernels touch it.  (a) Temporaries
+    stay under half the cache (the parent held a second whole cache: 4.23
+    GB against 3.22); (b) no instruction produces a cache-shaped or
+    layer-shaped array by copying, slicing or scattering (the parent:
+    dynamic-slice and dynamic-update-slice fusions a layer, two whole
+    copies a step; the XLA-only in-place forms re-lay the carry and copy
+    it every layer, which no CPU test shows)."""
+    _as_tpu(monkeypatch)
+    cfg = _mistral7b_16l(**({"kv_quant": "int8"} if kv == "int8" else {}))
+    compiled = _compile_program(topo, _chunk_program, cfg,
+                                n_slots=CELL_SLOTS)
+    text = compiled.as_text()
+    assert "sw_kv_write" in text and "sw_decode_attn_stream" in text
+    from starway_tpu.models.generate import init_cache
+
+    cache_bytes = sum(  # 3.22 GB bf16; 1.66 GB int8 with its f32 scales
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(
+            jax.eval_shape(lambda: init_cache(cfg, CELL_SLOTS, MAX_LEN))))
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 2
+    assert _cache_moves(text, 16, CELL_SLOTS) == []
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_decode_chunk_fits_32_slots_on_v5e(topo, monkeypatch, kv):
+    """32 slots x 2048 at the cells' widths: refused by the chip's
+    compiler in PR 23 (16.49 of 15.75 GiB, the cache counted twice)."""
+    _as_tpu(monkeypatch)
+    cfg = _mistral7b_16l(**({"kv_quant": "int8"} if kv == "int8" else {}))
+    compiled = _compile_program(topo, _chunk_program, cfg, n_slots=32)
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes) < 15.75 * 2**30
 
 
 @pytest.mark.slow

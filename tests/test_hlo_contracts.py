@@ -162,6 +162,76 @@ def test_single_chip_decode_has_no_collectives_or_host_io(cfg):
         assert _ops(txt, op) == 0, f"decode step contains {op}"
 
 
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+@pytest.mark.parametrize("path", ["dense", "int8", "rolling", "chunk",
+                                  "paged"])
+def test_layer_scan_carries_the_cache(cfg, path):
+    """The cached decode step's layer scan holds the stacked cache in its
+    CARRY and nowhere else: as ``xs`` every layer is sliced out of it, as
+    ``ys`` every layer is stored into a second stacked array, and the two
+    cannot share a buffer (PERF.md, PR 25: half a serving step's device
+    time).  Read off the jaxpr, so it holds wherever the program is
+    compiled; every path through ``cached_layer_scan`` is held to it."""
+    from starway_tpu.models.generate import (decode_step, init_cache,
+                                             init_rolling_cache)
+    from starway_tpu.models.llama import cfg_rope_tables
+    from starway_tpu.models.paged import init_paged_pool, paged_decode_step
+    from starway_tpu.models.speculative import chunk_decode_step
+
+    B, T = 2, 64
+    if path == "int8":
+        cfg = LlamaConfig.preset("debug", kv_quant="int8")
+    if path == "rolling":
+        cfg = LlamaConfig.preset("debug", sliding_window=T)
+    rope = cfg_rope_tables(cfg, 2 * T)
+    params = _abstract_params(cfg)
+    tok = jax.ShapeDtypeStruct((B,), jnp.int32)
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32)
+    if path == "paged":
+        cache = jax.eval_shape(lambda: init_paged_pool(cfg, 9, 16))
+        table = jax.ShapeDtypeStruct((B, 4), jnp.int32)
+        jaxpr = jax.make_jaxpr(lambda p, c, tb, t, q: paged_decode_step(
+            p, c, tb, t, q, cfg, rope))(params, cache, table, tok, pos)
+    elif path == "chunk":
+        cache = jax.eval_shape(lambda: init_cache(cfg, B, T))
+        toks = jax.ShapeDtypeStruct((B, 4), jnp.int32)
+        jaxpr = jax.make_jaxpr(lambda p, c, t, q: chunk_decode_step(
+            p, c, t, q, cfg, rope))(params, cache, toks, pos)
+    else:
+        rolling = path == "rolling"
+        cache = jax.eval_shape(
+            lambda: init_rolling_cache(cfg, B) if rolling
+            else init_cache(cfg, B, T))
+        jaxpr = jax.make_jaxpr(lambda p, c, t, q: decode_step(
+            p, c, t, q, cfg, rope, rolling=rolling))(params, cache, tok, pos)
+
+    stacked = {leaf.shape for leaf in jax.tree_util.tree_leaves(cache)}
+    per_layer = {shape[1:] for shape in stacked}
+    layer_scans = [e for e in _scans(jaxpr.jaxpr)
+                   if e.params["length"] == cfg.n_layers]
+    assert len(layer_scans) == 1
+    scan, = layer_scans
+    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    carry = [v.aval.shape for v in scan.invars[n_consts:n_consts + n_carry]]
+    xs = [v.aval.shape for v in scan.invars[n_consts + n_carry:]]
+    ys = [v.aval.shape for v in scan.outvars[n_carry:]]
+    assert stacked <= set(carry)
+    assert not stacked & set(xs) and not stacked & set(ys)
+    # ... and the body neither takes nor returns one layer of it.
+    body = scan.params["jaxpr"].jaxpr
+    body_xs = [v.aval.shape for v in body.invars[n_consts + n_carry:]]
+    body_ys = [v.aval.shape for v in body.outvars[n_carry:]]
+    assert not per_layer & set(body_xs) and not per_layer & set(body_ys)
+
+
 def test_tp_train_step_collective_count_scales_with_layers(cfg):
     """The scanned tp train step's all-reduce count is depth-INDEPENDENT
     (collectives live inside the scan body, compiled once) — a count

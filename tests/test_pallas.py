@@ -299,3 +299,104 @@ def test_window_validation():
             decode_attention(xq, x, x, 0, window=bad, interpret=True)
         with pytest.raises(ValueError, match=">= 1"):
             LlamaConfig.preset("debug", sliding_window=bad)
+
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
+@pytest.mark.parametrize("n_q", [1, 4], ids=["c1", "c4"])
+@pytest.mark.parametrize("window", [None, 96], ids=["full", "window"])
+@pytest.mark.parametrize("per_row", [True, False], ids=["rows", "scalar"])
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_decode_attention_reads_stacked_cache_by_layer(int8, per_row, window,
+                                                       n_q, layer):
+    """``decode_attention(stacked, layer=i)`` is bit-equal to the per-layer
+    call on ``stacked[i]``: the layer only moves the DMA's source address.
+    The layer is traced (as inside the layer scan), T = 256 makes two
+    blocks of 128, and both kernel variants read the same stack."""
+    from starway_tpu.ops.pallas_decode import decode_attention
+    from starway_tpu.ops.quantize import quantize_kv
+
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    L, B, Hq, Hkv, T, D = 3, 2, 8, 2, 256, 64
+    q = jax.random.normal(ks[0], (B, Hq, n_q, D)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (L, B, Hkv, T, D)).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (L, B, Hkv, T, D)).astype(jnp.bfloat16)
+    scales = {}
+    if int8:
+        k, scales["k_scale"] = quantize_kv(k)
+        v, scales["v_scale"] = quantize_kv(v)
+    pos = jnp.asarray([125, 250], jnp.int32) if per_row else 130
+    for stream in (True, False):
+        kw = dict(window=window, block_k=128, interpret=True, stream=stream)
+        stacked = jax.jit(lambda li: decode_attention(
+            q, k, v, pos, layer=li, **scales, **kw))(jnp.int32(layer))
+        one = decode_attention(
+            q, k[layer], v[layer], pos,
+            **{n: s[layer] for n, s in scales.items()}, **kw)
+        assert stacked.shape == (B, Hq, n_q, D)
+        np.testing.assert_array_equal(np.asarray(stacked, np.float32),
+                                      np.asarray(one, np.float32))
+
+
+@pytest.mark.parametrize("n_new", [1, 4], ids=["c1", "c4"])
+@pytest.mark.parametrize("leaf", ["bf16", "int8", "scales"])
+def test_kv_write_in_place_matches_dynamic_update_slice(leaf, n_new):
+    """``kv_write`` (the aliased read-modify-write of one tile a row) puts
+    exactly what ``lax.dynamic_update_slice`` puts, at a tile's first row,
+    its last row, the row after it and the cache's last position (where a
+    C = 4 start is clamped), into the asked layer and rows only: every
+    other entry of the stacked array stays bit-equal.  ``kv_write_lax``,
+    what the CPU path runs, is held to the same."""
+    from starway_tpu.ops.pallas_decode import kv_write, kv_write_lax
+
+    dtype, tile = {"bf16": (jnp.bfloat16, 16), "int8": (jnp.int8, 32),
+                   "scales": (jnp.float32, 128)}[leaf]
+    L, R, Hkv, T, D = 3, 6, 2, 2 * tile, 64
+    tail = () if leaf == "scales" else (D,)
+    ks = jax.random.split(jax.random.PRNGKey(13), 4)
+
+    def draw(key, shape):
+        return (jax.random.normal(key, shape) * 40).astype(dtype)
+
+    caches = (draw(ks[0], (L, R, Hkv, T) + tail),
+              draw(ks[1], (L, R, Hkv, T) + tail))
+    pos = jnp.asarray([0, tile - 1, tile, T - 1], jnp.int32)
+    rows = jnp.asarray([4, 0, 5, 2], jnp.int32)  # not the identity
+    updates = (draw(ks[2], (4, Hkv, n_new) + tail),
+               draw(ks[3], (4, Hkv, n_new) + tail))
+    layer = 1
+
+    def reference(c, u):
+        for n in range(4):
+            c = jax.lax.dynamic_update_slice(
+                c, u[n][None, None],
+                (layer, rows[n], 0, pos[n]) + (0,) * len(tail))
+        return c
+
+    want = [reference(c, u) for c, u in zip(caches, updates)]
+    got = jax.jit(lambda c, u, li: kv_write(
+        c, u, li, rows, pos, interpret=True))(caches, updates,
+                                             jnp.int32(layer))
+    lax_got = kv_write_lax(caches, updates, jnp.int32(layer), rows, pos)
+    for w, g, lg, c in zip(want, got, lax_got, caches):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        np.testing.assert_array_equal(np.asarray(lg), np.asarray(w))
+        assert (np.asarray(w) != np.asarray(c)).any()
+
+
+def test_kv_write_long_chunk_goes_in_pieces(monkeypatch):
+    """A chunk whose window would not fit the kernel's VMEM budget (a
+    prefix admit's long suffix) is written a piece at a time, to the same
+    result."""
+    from starway_tpu.ops import pallas_decode
+    from starway_tpu.ops.pallas_decode import kv_write, kv_write_lax
+
+    monkeypatch.setattr(pallas_decode, "_WRITE_VMEM_BYTES", 32 << 10)
+    L, R, Hkv, T, D, C = 2, 1, 2, 512, 64, 300  # 256 B a position: 112 a piece
+    ks = jax.random.split(jax.random.PRNGKey(19), 2)
+    cache = jax.random.normal(ks[0], (L, R, Hkv, T, D)).astype(jnp.bfloat16)
+    update = jax.random.normal(ks[1], (R, Hkv, C, D)).astype(jnp.bfloat16)
+    rows, pos = jnp.zeros((1,), jnp.int32), jnp.asarray([37], jnp.int32)
+    got, = kv_write((cache,), (update,), 1, rows, pos, interpret=True)
+    want, = kv_write_lax((cache,), (update,), 1, rows, pos)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))

@@ -421,3 +421,57 @@ def test_per_head_shard_matches_unsharded(case):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=2e-6, rtol=2e-6)
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_per_head_shard_stacked_cache(kv):
+    """Write-then-attend on the scan-stacked cache ``[L, B, Hkv, T, D]``
+    under a tp mesh: the caches' heads are dim 2 there (dim 1 of q and of
+    the new entries), which ``per_head_shard`` is told, not left to guess.
+    The in-place write returns the stacked cache still sharded by head,
+    and both kernels agree with the unsharded run."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from starway_tpu.models.generate import _attend_cached, _write_cached
+    from starway_tpu.ops.quantize import quantize_kv
+
+    n_layers, b, hq, hkv, t, d = 3, 2, 8, 4, 256, 64
+    keys = jax.random.split(jax.random.PRNGKey(17), 5)
+    q = jax.random.normal(keys[0], (b, hq, 1, d)).astype(jnp.bfloat16)
+    cache = {"k": jax.random.normal(keys[1], (n_layers, b, hkv, t, d)),
+             "v": jax.random.normal(keys[2], (n_layers, b, hkv, t, d))}
+    new = {"k": jax.random.normal(keys[3], (b, hkv, 1, d)),
+           "v": jax.random.normal(keys[4], (b, hkv, 1, d))}
+    cache, new = ({n: a.astype(jnp.bfloat16) for n, a in tree.items()}
+                  for tree in (cache, new))
+    if kv == "int8":
+        for tree in (cache, new):
+            tree["k"], tree["k_scale"] = quantize_kv(tree["k"])
+            tree["v"], tree["v_scale"] = quantize_kv(tree["v"])
+    pos = jnp.asarray([100, 37], jnp.int32)
+
+    def run(q, cache, new, layer):
+        cache = _write_cached(cache, new, layer, pos, use_pallas=True)
+        out = _attend_cached(q, cache["k"], cache["v"], pos, hq // hkv,
+                             use_pallas=True, k_scale=cache.get("k_scale"),
+                             v_scale=cache.get("v_scale"), layer=layer)
+        return out, cache
+
+    layer = jnp.int32(1)
+    want, want_cache = jax.jit(run)(q, cache, new, layer)
+    mesh = make_mesh({"tp": 2}, jax.devices()[:2])
+    at = lambda dim: NamedSharding(mesh, P(*[None] * dim, "tp"))
+    put = lambda tree, dim: {n: jax.device_put(a, at(dim))
+                             for n, a in tree.items()}
+    with jax.set_mesh(mesh):
+        got, got_cache = jax.jit(run)(jax.device_put(q, at(1)),
+                                      put(cache, 2), put(new, 1), layer)
+    assert got.sharding.spec[1] == "tp"
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    for name, leaf in got_cache.items():
+        assert leaf.sharding.spec[2] == "tp"
+        np.testing.assert_array_equal(np.asarray(leaf, np.float32),
+                                      np.asarray(want_cache[name],
+                                                 np.float32))
+        assert (np.asarray(leaf) != np.asarray(cache[name])).any()
+
+
