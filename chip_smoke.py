@@ -36,6 +36,7 @@ import functools
 import gc
 import json
 import logging
+import math
 import os
 import socket
 import subprocess
@@ -66,6 +67,13 @@ REQUESTS = ((5, 16), (12, 24), (200, 32), (230, 48), (1100, 64), (1500, 64),
             (29, 20))
 RERUN = 2  # the request re-run through standalone generate()
 
+# Phase e: Kimi-K2-Instruct's block at its published widths (64 heads over a
+# 512 + 64 latent row, 7168 wide, 384-wide router, 8 experts a token), one
+# dense and one routed layer, 12 of the 384 experts held (one chip of 32),
+# an eighth of the vocabulary: 2.9 GB of bf16 weights.
+LATENT = {"n_layers": 2, "held": 12, "vocab": 20480, "n_slots": 8,
+          "max_len": 2048, "chunk": 8}
+
 # Phase d: llama2-7b at its published widths (vocab 32000 leaves room that
 # llama3-8b's 128256-row embedding and head do not); depth cut 32 -> 4 and
 # batch 2 x 2048 tokens are what the chip's compiler fits beside bf16
@@ -83,6 +91,16 @@ TRAIN = {"preset": "llama2-7b", "n_layers": 4, "batch": 2, "seq": 2048,
 TOL_BF16 = 2e-2
 TOL_LOGITS = 2.0 ** -5
 TOL_LOGITS_INT8 = 2.0 ** -4
+# A routed model (phase e): where two experts' scores lie within a bf16
+# rounding of each other, the two sides of a comparison choose differently
+# and that token's logits move by a tenth of their range or more (0.16-0.28
+# of max |logit| in a cell's run, PERF.md section 2, PR 26), so one token
+# says little.  Held: the MEAN gap (0.0013-0.0033 read for bf16, 0.017 for
+# int8 arithmetic), no single token further than half the range (a wrong
+# token reads about 1), and at most 2% of positions past TOL_LOGITS.
+TOL_ROUTED_MEAN = 0.008
+TOL_FLIP = 0.5
+FLIPPED_ROWS = 0.02
 
 
 class SmokeFailure(Exception):
@@ -494,11 +512,27 @@ def make_requests(cfg, requests=REQUESTS) -> list:
             for n, m in requests]
 
 
-def _plain_attn():
+def _plain_attn(cfg=None):
     """The plain lax path: no Pallas on any backend."""
     from starway_tpu.ops.attention import blockwise_attention
 
-    return functools.partial(blockwise_attention, causal=True)
+    scale = cfg.latent.sm_scale if cfg is not None and cfg.latent else None
+    return functools.partial(blockwise_attention, causal=True, sm_scale=scale)
+
+
+@contextlib.contextmanager
+def _lax_experts():
+    """Programs traced inside run the routed experts' grouped matmul in
+    its lax form (the plain side of a comparison; the steering lives
+    here, not in an option of the program)."""
+    from starway_tpu.models import moe
+
+    kernel = moe.routed_experts
+    moe.routed_experts = functools.partial(kernel, use_pallas=False)
+    try:
+        yield
+    finally:
+        moe.routed_experts = kernel
 
 
 def make_reference(cfg, max_len: int, n_new: int):
@@ -517,16 +551,17 @@ def make_reference(cfg, max_len: int, n_new: int):
 
     from starway_tpu.models import forward
 
-    plain = _plain_attn()
+    plain = _plain_attn(cfg)
 
     @jax.jit
     def gaps(params, padded, at, chosen):
-        logits = forward(params, padded, cfg, plain)[0][at]  # [n, V] f32
+        with _lax_experts():
+            logits = forward(params, padded, cfg, plain)[0][at]  # [n, V] f32
         top = logits.max(-1)
         got = jnp.take_along_axis(logits, chosen[:, None], -1)[:, 0]
         return (top - got) / jnp.abs(logits).max(-1), jnp.isfinite(logits).all()
 
-    def worst_gap(params, prompt, new) -> float:
+    def token_gaps(params, prompt, new):
         padded = np.zeros((1, max_len), np.int32)
         padded[0, :len(prompt)] = prompt
         padded[0, len(prompt):len(prompt) + len(new)] = new
@@ -538,9 +573,9 @@ def make_reference(cfg, max_len: int, n_new: int):
         g, finite = gaps(params, jnp.asarray(padded), jnp.asarray(at),
                          jnp.asarray(chosen))
         check(bool(finite), "the reference's logits are not finite")
-        return float(np.asarray(g)[:len(new)].max())
+        return np.asarray(g)[:len(new)]
 
-    return worst_gap
+    return token_gaps
 
 
 async def _serve_over_wire(slot, reqs, tls, native: bool) -> tuple:
@@ -588,23 +623,34 @@ async def _serve_over_wire(slot, reqs, tls, native: bool) -> tuple:
                   "stream_chunks": chunks}
 
 
-def _check_streams(name, params, reqs, outs, worst_gap, cfg, tol) -> dict:
+def _check_streams(name, params, reqs, outs, token_gaps, cfg, tol,
+                   mean_tol=None) -> dict:
+    """``tol``: half the widest gap any one token may have; ``mean_tol``
+    (routed models): the mean gap over all tokens."""
     import numpy as np
 
-    worst = 0.0
+    worst, total = 0.0, 0.0
     for i, ((prompt, max_new), out) in enumerate(zip(reqs, outs)):
         check(len(out) == max_new,
               f"{name}: request {i} returned {len(out)} of {max_new} tokens")
         check(((out >= 0) & (out < cfg.vocab_size)).all(),
               f"{name}: request {i} returned a token outside the vocabulary")
-        gap = worst_gap(params, prompt, np.asarray(out))
+        gaps = token_gaps(params, prompt, np.asarray(out))
+        gap = float(gaps.max())
         check(gap <= 2 * tol,
               f"{name}: request {i} chose a token {gap:.4f} of max|logit| "
               f"below the plain reference's best (allowed {2 * tol})")
-        worst = max(worst, gap)
-    return {"requests": len(reqs), "tokens": int(sum(len(o) for o in outs)),
-            "worst_gap_to_reference": round(worst, 5),
-            "gap_allowed": 2 * tol}
+        worst, total = max(worst, gap), total + float(gaps.sum())
+    tokens = int(sum(len(o) for o in outs))
+    got = {"requests": len(reqs), "tokens": tokens,
+           "worst_gap_to_reference": round(worst, 5), "gap_allowed": 2 * tol}
+    if mean_tol is not None:
+        check(total / tokens <= mean_tol,
+              f"{name}: mean gap {total / tokens:.5f} to the plain "
+              f"reference (allowed {mean_tol})")
+        got.update(mean_gap_to_reference=round(total / tokens, 6),
+                   mean_gap_allowed=mean_tol)
+    return got
 
 
 def _first_difference(a, b) -> "int | None":
@@ -614,11 +660,14 @@ def _first_difference(a, b) -> "int | None":
     return int(diff[0]) if len(diff) else None
 
 
-def _rel_err(a, r) -> float:
+def _rel_err(a, r, skip: float = 0.0) -> float:
+    """max |a - r| / max |r|; with ``skip``, over all but that share of
+    the rows (the positions a routed model's flipped experts moved)."""
     import jax.numpy as jnp
 
     a, r = a.astype(jnp.float32), r.astype(jnp.float32)
-    return float(jnp.max(jnp.abs(a - r)) / (jnp.max(jnp.abs(r)) + 1e-9))
+    rows = jnp.max(jnp.abs(a - r), -1).reshape(-1)
+    return float(jnp.quantile(rows, 1.0 - skip) / (jnp.max(jnp.abs(r)) + 1e-9))
 
 
 def _pallas_vs_lax_logits(params, cfg, tokens) -> dict:
@@ -631,10 +680,12 @@ def _pallas_vs_lax_logits(params, cfg, tokens) -> dict:
     from starway_tpu.models.generate import decode_step
 
     s = tokens.shape[1]
-    plain = jax.jit(lambda p, t: forward(p, t, cfg, _plain_attn()))(
-        params, tokens)
+    with _lax_experts():
+        plain = jax.jit(lambda p, t: forward(p, t, cfg, _plain_attn(cfg)))(
+            params, tokens)
     flash = jax.jit(lambda p, t: forward(p, t, cfg))(params, tokens)
-    err_prefill = _rel_err(flash, plain)
+    skip = FLIPPED_ROWS if cfg.routed is not None else 0.0
+    err_prefill = _rel_err(flash, plain, skip)
     _logits, cache = jax.jit(lambda p, t: prefill(p, cfg, t, s))(
         params, tokens[:, :s - 1])
     step, _cache = jax.jit(
@@ -643,7 +694,7 @@ def _pallas_vs_lax_logits(params, cfg, tokens) -> dict:
     err_decode = _rel_err(step, plain[:, s - 1])
     check(err_prefill < TOL_LOGITS,
           f"flash prefill logits differ from the lax path by {err_prefill}")
-    check(err_decode < TOL_LOGITS,
+    check(err_decode < (TOL_FLIP if skip else TOL_LOGITS),
           f"cached decode logits differ from the lax path by {err_decode}")
     return {"tokens": s, "prefill_rel_err": round(err_prefill, 5),
             "decode_rel_err": round(err_decode, 5), "allowed": TOL_LOGITS}
@@ -662,8 +713,8 @@ async def phase_serve(dev, requests=REQUESTS) -> dict:
         params = init_params(jax.random.PRNGKey(SEED), cfg)
     jax.block_until_ready(params)
     reqs = make_requests(cfg, requests)
-    worst_gap = make_reference(cfg, SERVE["max_len"],
-                               max(m for _p, m in reqs))
+    token_gaps = make_reference(cfg, SERVE["max_len"],
+                                max(m for _p, m in reqs))
     int8 = dataclasses.replace(cfg, kv_quant="int8")
     kw = dict(n_slots=SERVE["n_slots"], max_len=SERVE["max_len"],
               chunk=SERVE["chunk"])
@@ -688,7 +739,7 @@ async def phase_serve(dev, requests=REQUESTS) -> dict:
         slot = make()
         outs, how = await _serve_over_wire(slot, reqs, tls, native)
         out[name] = {**how, **_check_streams(name, params, reqs, outs,
-                                             worst_gap, vcfg, tol)}
+                                             token_gaps, vcfg, tol)}
         streams[name] = outs
         del slot
         gc.collect()
@@ -699,7 +750,7 @@ async def phase_serve(dev, requests=REQUESTS) -> dict:
     prompt, max_new = reqs[RERUN]
     alone = np.asarray(generate(params, cfg, jnp.asarray(prompt[None]),
                                 max_new)[0, len(prompt):])
-    gap = worst_gap(params, prompt, alone)
+    gap = float(token_gaps(params, prompt, alone).max())
     check(gap <= 2 * TOL_LOGITS,
           f"generate() chose a token {gap:.4f} of max|logit| below the "
           f"plain reference's best")
@@ -711,6 +762,81 @@ async def phase_serve(dev, requests=REQUESTS) -> dict:
     out["first_difference_from_slot_server"] = {
         name: [_first_difference(a, b) for a, b in zip(outs, dense)]
         for name, outs in streams.items() if name != "slot_server"}
+    longest = max(reqs, key=lambda r: len(r[0]))[0]
+    out["pallas_vs_lax"] = _pallas_vs_lax_logits(
+        params, cfg, jnp.asarray(longest[None, :min(len(longest), 1024)]))
+    return out
+
+
+# ------------------------------------------- latent attention, routed experts
+
+
+def latent_config(**overrides):
+    """Kimi-K2-Instruct's block at its published widths, as one chip of the
+    32 that share each layer holds it (LATENT)."""
+    from starway_tpu.models.llama import LatentAttn, LlamaConfig, RoutedFFN
+
+    mscale = 0.1 * math.log(32.0) + 1.0
+    kw = dict(
+        vocab_size=LATENT["vocab"], d_model=7168, n_layers=LATENT["n_layers"],
+        n_heads=64, n_kv_heads=64, d_ff=18432, rope_theta=50000.0,
+        norm_eps=1e-6, rope_scaling=("yarn", 32.0, 4096, 1, 1, 1.0, True),
+        latent=LatentAttn(q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+                          v_dim=128, sm_scale=192 ** -0.5 * mscale * mscale),
+        routed=RoutedFFN(n_experts=384, top_k=8, d_expert=2048,
+                         n_held=LATENT["held"], n_shared=1, scale=2.827,
+                         first_dense=1))
+    kw.update(overrides)
+    return LlamaConfig(**kw)
+
+
+def phase_latent_moe(dev, requests=REQUESTS) -> dict:
+    """The latent cache, the absorbed decode kernel and the dropless
+    grouped matmul through ``SlotServer`` and ``generate()``, against the
+    plain lax forward (expanded attention, lax experts) at logit level."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from starway_tpu.models import SlotServer, generate, init_params, serving
+
+    cfg = latent_config()
+    with jax.default_device(dev):
+        params = init_params(jax.random.PRNGKey(SEED), cfg)
+    jax.block_until_ready(params)
+    reqs = make_requests(cfg, requests)
+    token_gaps = make_reference(cfg, LATENT["max_len"],
+                                max(m for _p, m in reqs))
+    out: dict = {"weights_gb": round(sum(
+        x.nbytes for x in jax.tree_util.tree_leaves(params)) / 1e9, 2)}
+    slot = SlotServer(params, cfg, n_slots=LATENT["n_slots"],
+                      max_len=LATENT["max_len"], chunk=LATENT["chunk"])
+    rids = [slot.submit(p, m) for p, m in reqs]
+    done = slot.run()
+    out["slot_server"] = _check_streams(
+        "latent slot_server", params, reqs, [done[r] for r in rids],
+        token_gaps, cfg, TOL_FLIP / 2, mean_tol=TOL_ROUTED_MEAN)
+    rows = [r for r in serving.step_log()
+            if r["server"] == slot.server_id and "moe_assign" in r]
+    # A chunk's count may be 0: near the end one request decodes beside
+    # seven freed slots whose pending tokens no longer change.
+    check(rows and any(r["moe_assign"] > 0 for r in rows)
+          and all(0 <= r["moe_touched"] <= LATENT["held"] for r in rows),
+          "the routed model's step_log rows carry no pair counts")
+    out["held_experts_touched_a_step"] = round(
+        sum(r["moe_touched"] for r in rows) / len(rows), 2)
+    del slot
+    gc.collect()
+    prompt, max_new = reqs[RERUN]
+    alone = np.asarray(generate(params, cfg, jnp.asarray(prompt[None]),
+                                max_new)[0, len(prompt):])
+    gaps = token_gaps(params, prompt, alone)
+    check(gaps.max() <= TOL_FLIP and gaps.mean() <= TOL_ROUTED_MEAN,
+          f"generate() chose tokens {gaps.max():.4f} (mean {gaps.mean():.5f}) "
+          f"of max|logit| below the plain reference's best")
+    out["generate_rerun"] = {"request": RERUN,
+                             "worst_gap_to_reference": round(float(gaps.max()), 5),
+                             "mean_gap_to_reference": round(float(gaps.mean()), 6)}
     longest = max(reqs, key=lambda r: len(r[0]))[0]
     out["pallas_vs_lax"] = _pallas_vs_lax_logits(
         params, cfg, jnp.asarray(longest[None, :min(len(longest), 1024)]))
@@ -1014,8 +1140,8 @@ def phase_tp_serve(devices) -> dict:
     _mesh, p_sh = _param_shardings(cfg, {"tp": len(devices)}, devices)
     sharded = jax.device_put(params, p_sh)
     reqs = make_requests(cfg, TP_REQUESTS)
-    worst_gap = make_reference(cfg, SERVE["max_len"],
-                               max(m for _p, m in reqs))
+    token_gaps = make_reference(cfg, SERVE["max_len"],
+                                max(m for _p, m in reqs))
     out, streams = {}, {}
     for name, weights in (("one_chip", params), ("tp2", sharded)):
         srv = SlotServer(weights, cfg, n_slots=SERVE["n_slots"],
@@ -1024,7 +1150,7 @@ def phase_tp_serve(devices) -> dict:
         done = srv.run()
         streams[name] = [np.asarray(done[r]) for r in rids]
         out[name] = _check_streams(name, params, reqs, streams[name],
-                                   worst_gap, cfg, TOL_LOGITS)
+                                   token_gaps, cfg, TOL_LOGITS)
         del srv
         gc.collect()
     wq = sharded["layers"]["wq"]
@@ -1214,6 +1340,13 @@ def run(chips: int) -> dict:
                cut="llama2-7b depth 32 -> 4, batch 2 x 2048: what fits "
                    "16 GB beside bf16 adamw state; widths as published") as d:
         d.update(phase_train(dev))
+    gc.collect()
+    with phase("e_latent_moe", clock, model=LATENT,
+               requests=[list(r) for r in REQUESTS],
+               cut="Kimi-K2-Instruct depth 61 -> 2 (one dense, one routed "
+                   "layer), 12 of 384 experts held, vocabulary 163840 -> "
+                   "20480; widths as published") as d:
+        d.update(phase_latent_moe(dev))
     return info
 
 

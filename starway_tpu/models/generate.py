@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from .llama import (LlamaConfig, apply_rope, cfg_rope_tables, embed_tokens,
-                    forward, matmul_w, mlp_gate_act, qkv_proj, rmsnorm)
+                    ffn_block, forward, layer_segments, matmul_w, qkv_proj,
+                    rmsnorm, scan_segment)
 from ..ops.attention import NEG_BIG, repeat_kv
 
 
@@ -36,7 +37,17 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
     ``k_scale/v_scale [n_layers, B, Hkv, max_len]`` (ops/quantize.py) —
     half the HBM bytes on the bandwidth-bound decode stream.  The scale
     keys' presence IS the format marker every consumer dispatches on.
+
+    Latent attention (``cfg.latent``, models/mla.py) caches ONE row a token
+    for all heads: ``ckv [n_layers, B, 1, max_len, cache_width]`` (``kv_rank
+    + rope_dim`` values in whole lane tiles), the same five axes with a
+    single "head", so slot writes, padding and ``kv_write`` treat it as
+    they treat ``k``.
     """
+    if cfg.latent is not None:
+        return {"ckv": jnp.zeros(
+            (cfg.n_layers, batch, 1, max_len, cfg.latent.cache_width),
+            cfg.compute_dtype)}
     hd = cfg.head_dim
     shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, hd)
     if cfg.kv_quant == "int8":
@@ -131,6 +142,34 @@ def _attend_cached(q, k_cache, v_cache, pos, n_rep, use_pallas=None,
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
 
+def cache_len(cache: dict) -> int:
+    """Positions a cache holds: the T axis sits at index 3 of every leaf."""
+    return next(iter(cache.values())).shape[3]
+
+
+def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig,
+                 use_pallas=None):
+    """``attend`` of :func:`cached_layer_scan` over a whole-length cache of
+    either kind, queries at ``pos[b] ..``: grouped k/v
+    (:func:`_attend_cached`, windowed and int8-aware), or the latent rows
+    of ``cfg.latent`` (absorbed queries in, ``P c_kv`` out;
+    ops/pallas_decode.py::mla_decode_attention on TPU)."""
+    if "ckv" in cache:
+        from ..ops.pallas_decode import (mla_decode_attention,
+                                         mla_decode_attention_lax)
+
+        if use_pallas is None:
+            use_pallas = jax.default_backend() == "tpu"
+        fn = mla_decode_attention if use_pallas else mla_decode_attention_lax
+        return fn(q, cache["ckv"], pos, rank=cfg.latent.kv_rank,
+                  sm_scale=cfg.latent.sm_scale, layer=layer)
+    return _attend_cached(q, cache["k"], cache["v"], pos,
+                          cfg.n_heads // cfg.n_kv_heads,
+                          use_pallas=use_pallas, window=cfg.sliding_window,
+                          k_scale=cache.get("k_scale"),
+                          v_scale=cache.get("v_scale"), layer=layer)
+
+
 def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
                   use_pallas=None) -> dict:
     """The C new positions of one layer into the stacked cache, every leaf
@@ -149,12 +188,13 @@ def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
 
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    B = new["k"].shape[0]
+    B = next(iter(new.values())).shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     rows = jnp.arange(B) if rows is None else rows
     layer = jnp.asarray(layer, jnp.int32)
     out = dict(cache)
-    groups = [("k", "v")] + [("k_scale", "v_scale")] * ("k_scale" in cache)
+    groups = ([("ckv",)] if "ckv" in cache else
+              [("k", "v")] + [("k_scale", "v_scale")] * ("k_scale" in cache))
     for names in groups:  # same-shaped leaves share one kernel call
         n = len(names)
         leaves = tuple(cache[name] for name in names)
@@ -174,10 +214,18 @@ def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
 
 def decode_step(params: dict, cache: dict, token, pos, cfg: LlamaConfig,
                 rope=None, rolling: bool = False):
-    """One token in, next-token logits out.  token: [B] int32; pos: the
-    ABSOLUTE position of ``token`` — a scalar (aligned batch) or a per-row
-    [B] vector (ragged batch: every row sits at its own cursor).  Returns
-    (logits [B, V], updated cache).
+    """One token in, next-token logits out: ``(logits [B, V], updated
+    cache)`` of :func:`decode_step_counted`, which documents the rest."""
+    return decode_step_counted(params, cache, token, pos, cfg, rope,
+                               rolling)[:2]
+
+
+def decode_step_counted(params: dict, cache: dict, token, pos,
+                        cfg: LlamaConfig, rope=None, rolling: bool = False):
+    """token: [B] int32; pos: the ABSOLUTE position of ``token`` — a
+    scalar (aligned batch) or a per-row [B] vector (ragged batch: every
+    row sits at its own cursor).  Returns (logits [B, V], updated cache,
+    the third value of :func:`cached_layer_scan`).
 
     ``rolling``: the cache is a circular window of exactly
     ``cfg.sliding_window`` slots (``init_rolling_cache``) — writes go to
@@ -185,10 +233,7 @@ def decode_step(params: dict, cache: dict, token, pos, cfg: LlamaConfig,
     re-mask (the residents ARE the window; keys carry their absolute RoPE,
     and attention is permutation-invariant over keys, so slot order never
     matters).  Cache memory is O(window) for any generation length."""
-    B = token.shape[0]
-    hd = cfg.head_dim
-    n_rep = cfg.n_heads // cfg.n_kv_heads
-    T = cache["k"].shape[3]
+    T = cache_len(cache)
     if rolling:
         if cfg.sliding_window is None or T != cfg.sliding_window:
             raise ValueError(
@@ -218,23 +263,22 @@ def decode_step(params: dict, cache: dict, token, pos, cfg: LlamaConfig,
         return _write_cached(cache, new, layer, slot)
 
     def attend(q, cache, layer):
-        ksc, vsc = cache.get("k_scale"), cache.get("v_scale")
         if rolling:
             # Warm slots are exactly the window (we just overwrote the
             # oldest); cold-start slots (> pos) are masked by the clamped
             # position.  No window re-mask: absolute order is irrelevant.
             return _attend_cached(q, cache["k"], cache["v"],
-                                  jnp.minimum(pos, T - 1), n_rep,
-                                  k_scale=ksc, v_scale=vsc, layer=layer)
-        return _attend_cached(q, cache["k"], cache["v"], pos, n_rep,
-                              window=cfg.sliding_window,
-                              k_scale=ksc, v_scale=vsc, layer=layer)
+                                  jnp.minimum(pos, T - 1),
+                                  cfg.n_heads // cfg.n_kv_heads,
+                                  k_scale=cache.get("k_scale"),
+                                  v_scale=cache.get("v_scale"), layer=layer)
+        return attend_cache(q, cache, pos, layer, cfg)
 
-    h, out = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg, write,
-                               attend)
+    h, out, counts = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg,
+                                       write, attend)
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
     logits = matmul_w(h[:, 0, :], params["lm_head"]).astype(jnp.float32)
-    return logits, out
+    return logits, out, counts
 
 
 def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
@@ -242,69 +286,73 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
     """The ONE per-layer body of every cached decode path — decode_step's
     C=1, the speculative chunk verify's C>1
     (models/speculative.py:chunk_decode_step) and the paged pool's
-    (models/paged.py) run exactly this: qkv projection, RoPE,
-    quantize-on-write when the cache is int8, ``write`` at the caller's
-    cursor(s), ``attend``, FFN (dense or MoE).  Sharing it is what keeps
-    the pinned chunk==stepwise parity a tautology instead of a
+    (models/paged.py) run exactly this: the attention kind's projection
+    and RoPE, quantize-on-write when the cache is int8, ``write`` at the
+    caller's cursor(s), ``attend``, the FFN kind
+    (:func:`~starway_tpu.models.llama.ffn_block`).  Sharing it is what
+    keeps the pinned chunk==stepwise parity a tautology instead of a
     maintenance contract.
 
     The stacked cache arrays (``k``, ``v`` and, int8, ``k_scale`` /
-    ``v_scale``: [L, B, Hkv, T(, D)]) ride the scan's CARRY beside ``h``;
-    ``xs`` is the stacked layer weights and the layer index.  As scan
-    inputs and outputs they could not share a buffer: every layer would
-    be sliced out, updated as a slice and stored into a second stacked
-    array, and the caller's step scan would copy that array into its own
-    carry — half the device time of a serving step (PERF.md, PR 25).
+    ``v_scale``: [L, B, Hkv, T(, D)]; latent attention: ``ckv`` [L, B, 1,
+    T, W]) ride the scan's CARRY beside ``h``, through every segment's
+    scan in turn; ``xs`` is a segment's stacked layer weights and the
+    layers' indices in the whole cache.  As scan inputs and outputs they
+    could not share a buffer: every layer would be sliced out, updated as
+    a slice and stored into a second stacked array, and the caller's step
+    scan would copy that array into its own carry — half the device time
+    of a serving step (PERF.md, PR 25).
 
-    h: [B, C, D] embedded inputs; ``write(cache, new, layer) -> cache``
-    places ``new`` (the same keys, [B, Hkv, C(, D)] each) at the caller's
-    cursor(s) of layer ``layer`` (:func:`_write_cached`);
-    ``attend(q, cache, layer)`` returns [B, Hq, C, hd] (write-then-attend:
-    it sees the entries just written).  Returns ``(h [B, C, D], cache)``.
+    What a cache kind provides: its leaves all have the layer at axis 0,
+    the row at 1 and the position at 3; ``write(cache, new, layer) ->
+    cache`` places ``new`` (the same keys, [B, Hkv, C(, D)] each) at the
+    caller's cursor(s) of layer ``layer`` (:func:`_write_cached`);
+    ``attend(q, cache, layer)`` returns [B, Hq, C, hd] (latent: absorbed
+    queries in, ``P c_kv`` [B, H, C, kv_rank] out; write-then-attend: it
+    sees the entries just written; :func:`attend_cache`).  Returns ``(h
+    [B, C, D], cache, counts)``: the pairs each held expert of each routed
+    layer got, ``[routed layers, n_held]`` int32 (None for a model with no
+    routed layer).
     """
     B, C = h.shape[0], h.shape[1]
-    hd = cfg.head_dim
     quant = "k_scale" in cache  # int8 cache (init_cache's format marker)
 
-    def layer(carry, xs):
+    def layer(carry, lp, li):
         h, cache = carry
-        lp, li = xs
         x = rmsnorm(h, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = qkv_proj(x, lp, cfg)
-        q = apply_rope(q, cos_p, sin_p)
-        k = apply_rope(k, cos_p, sin_p)
-        new = {"k": k, "v": v}
-        if quant:
-            from ..ops.quantize import quantize_kv
+        if "wq_a" in lp:
+            from .mla import expand_values, project_absorbed
 
-            # Quantize-on-write: the cache never holds a wide entry.
-            new["k"], new["k_scale"] = quantize_kv(k)
-            new["v"], new["v_scale"] = quantize_kv(v)
-        cache = write(cache, new, li)
-        o = attend(q, cache, li)
-        o = o.transpose(0, 2, 1, 3).reshape(B, C, cfg.n_heads * hd)
-        h = h + matmul_w(o, lp["wo"])
-
-        x = rmsnorm(h, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.n_experts > 0:
-            from .moe import switch_moe
-
-            y, _ = switch_moe(
-                x, lp["moe"]["router"], lp["moe"]["w_in"], lp["moe"]["w_out"],
-                capacity_factor=cfg.moe_capacity_factor, k=cfg.moe_top_k,
-                w_gate=lp["moe"].get("w_gate"),
-            )
-            h = h + y
+            q, rows = project_absorbed(x, lp, cfg, cos_p, sin_p)
+            cache = write(cache, {"ckv": rows}, li)
+            o = expand_values(attend(q, cache, li), lp, cfg)
         else:
-            gate = mlp_gate_act(matmul_w(x, lp["w_gate"]), cfg).astype(x.dtype)
-            h = h + matmul_w(gate * matmul_w(x, lp["w_up"]), lp["w_down"])
-        return (h, cache), None
+            q, k, v = qkv_proj(x, lp, cfg)
+            q = apply_rope(q, cos_p, sin_p)
+            k = apply_rope(k, cos_p, sin_p)
+            new = {"k": k, "v": v}
+            if quant:
+                from ..ops.quantize import quantize_kv
 
-    n_layers = cache["k"].shape[0]
-    (h, cache), _ = lax.scan(
-        layer, (h, dict(cache)),
-        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
-    return h, cache
+                # Quantize-on-write: the cache never holds a wide entry.
+                new["k"], new["k_scale"] = quantize_kv(k)
+                new["v"], new["v_scale"] = quantize_kv(v)
+            cache = write(cache, new, li)
+            o = attend(q, cache, li)
+        o = o.transpose(0, 2, 1, 3).reshape(B, C, -1)
+        h = h + matmul_w(o, lp["wo"])
+        y, _aux, stats = ffn_block(rmsnorm(h, lp["mlp_norm"], cfg.norm_eps),
+                                   lp, cfg)
+        return (h + y, cache), (stats if "routed" in lp else None)
+
+    carry, counts = (h, dict(cache)), []
+    for seg, first in layer_segments(params["layers"]):
+        n = jax.tree_util.tree_leaves(seg)[0].shape[0]
+        carry, ys = scan_segment(
+            layer, carry, seg, first + jnp.arange(n, dtype=jnp.int32))
+        if ys is not None:
+            counts.append(ys)
+    return (*carry, jnp.concatenate(counts) if counts else None)
 
 
 def prefill(params: dict, cfg: LlamaConfig, prompt,
@@ -313,8 +361,8 @@ def prefill(params: dict, cfg: LlamaConfig, prompt,
     """One parallel forward pass over the whole prompt -> the decode state.
 
     Returns ``(next_logits [B, V], cache)`` where the cache holds the
-    post-RoPE grouped k/v of positions ``0..P-1`` (zero-padded to
-    ``max_len``).  This is the flash-attention path over the prompt — one
+    post-RoPE grouped k/v (latent attention: the latent rows) of positions
+    ``0..P-1`` (zero-padded to ``max_len``).  This is the flash-attention path over the prompt — one
     MXU-shaped dispatch instead of P bandwidth-bound cached decode steps,
     and bit-identical to stepping the prompt through ``decode_step``
     (pinned by tests/test_generate.py::test_prefill_matches_stepwise).
@@ -328,16 +376,16 @@ def prefill(params: dict, cfg: LlamaConfig, prompt,
         max_len = P
     elif max_len < P:
         raise ValueError(f"max_len={max_len} is smaller than the prompt ({P})")
-    logits, _aux, (ks, vs) = forward(
+    logits, _aux, kv = forward(
         params, prompt, cfg, attn_fn, return_aux=True, return_kv=True,
         last_only=logit_positions is None, logit_positions=logit_positions,
     )
-    cache = {"k": ks, "v": vs}
+    cache = dict(kv)
     if cfg.kv_quant == "int8":
         from ..ops.quantize import quantize_kv
 
-        cache["k"], cache["k_scale"] = quantize_kv(ks)
-        cache["v"], cache["v_scale"] = quantize_kv(vs)
+        cache["k"], cache["k_scale"] = quantize_kv(kv["k"])
+        cache["v"], cache["v_scale"] = quantize_kv(kv["v"])
     pad = max_len - P
     if pad:
         # Every leaf's T axis sits at index 3 (the scale arrays only drop
@@ -489,9 +537,9 @@ def _compiled_prefill_chunk(cfg: LlamaConfig):
             kc, vc = cache["k"][li], cache["v"][li]
             ksc = cache["k_scale"][li] if quant else None
             vsc = cache["v_scale"][li] if quant else None
-            h, _aux, k, v, _stats = decoder_layer(lp, h, cfg, cos_c, sin_c,
-                                                  chunk_attn(kc, vc, ksc,
-                                                             vsc))
+            h, _aux, kv, _stats = decoder_layer(lp, h, cfg, cos_c, sin_c,
+                                                chunk_attn(kc, vc, ksc, vsc))
+            k, v = kv["k"], kv["v"]
             if quant:
                 from ..ops.quantize import quantize_kv
 

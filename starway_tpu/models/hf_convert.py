@@ -1,6 +1,6 @@
 """HuggingFace Llama-family checkpoint -> starway-tpu parameter tree.
 
-Bridges the ecosystem's weights into this framework — six served
+Bridges the ecosystem's weights into this framework — seven served
 families: ``transformers.LlamaForCausalLM``, ``MistralForCausalLM``
 (sliding-window attention -> ``LlamaConfig.sliding_window``),
 ``Qwen2ForCausalLM`` (q/k/v projection biases ->
@@ -8,9 +8,13 @@ families: ``transformers.LlamaForCausalLM``, ``MistralForCausalLM``
 (SwiGLU top-2 MoE experts -> ``cfg.moe_swiglu``, dropless conversion
 capacity), ``GemmaForCausalLM`` (GeGLU -> ``cfg.mlp_act``, the
 (1 + w) RMSNorm convention folded into the converted weights,
-sqrt(d_model)-scaled embeddings -> ``cfg.scaled_embed``), and
+sqrt(d_model)-scaled embeddings -> ``cfg.scaled_embed``),
 ``Phi3ForCausalLM`` (fused ``qkv_proj``/``gate_up_proj`` row-sliced into
-separate projections at conversion) — all into the
+separate projections at conversion), and the DeepSeek-V3 block of
+``deepseek_v3`` / ``kimi_k2`` (latent attention -> ``cfg.latent``,
+sigmoid-routed experts beside a shared one after leading dense layers ->
+``cfg.routed`` and a tuple of layer segments; the published interleaved
+rope columns permuted into split halves) — all into the
 stacked-layer pytree ``models/llama.py`` trains and serves;
 ``config_from_hf`` derives the matching :class:`LlamaConfig`, including
 modern variants with decoupled ``head_dim`` and linear/llama3
@@ -52,6 +56,8 @@ def config_from_hf(hf_config: Any, **overrides) -> LlamaConfig:
         raise NotImplementedError(
             "MLP biases are not represented in this parameter tree")
     model_type = getattr(hf_config, "model_type", "")
+    if model_type in ("deepseek_v3", "kimi_k2"):
+        return _latent_moe_config(hf_config, **overrides)
     if model_type in ("gemma2", "gemma3", "gemma3_text"):
         # Must precede the activation check, or these fall into the
         # generic hidden_act error with a misleading message.
@@ -156,6 +162,143 @@ def config_from_hf(hf_config: Any, **overrides) -> LlamaConfig:
         )
     kw.update(overrides)
     return LlamaConfig(**kw)
+
+
+def _latent_moe_config(hf_config: Any, held_experts=None,
+                       **overrides) -> LlamaConfig:
+    """The DeepSeek-V3 block (``deepseek_v3``, ``kimi_k2``): latent
+    attention and sigmoid-routed experts beside shared ones after
+    ``first_k_dense_replace`` dense layers.  ``held_experts = (first,
+    count)``: the share of the routed experts this holder computes (all of
+    them by default)."""
+    import math
+
+    from .llama import LatentAttn, RoutedFFN
+
+    c = hf_config
+    if getattr(c, "attention_bias", False):
+        raise NotImplementedError("attention_bias on a latent-attention "
+                                  "config is not represented in this tree")
+    if getattr(c, "hidden_act", "silu") not in ("silu", "swish"):
+        raise NotImplementedError(f"hidden_act={c.hidden_act!r}")
+    if getattr(c, "scoring_func", "sigmoid") != "sigmoid" or (
+            getattr(c, "n_group", 1) != 1 or getattr(c, "topk_group", 1) != 1):
+        raise NotImplementedError(
+            "the routed FFN scores by sigmoid and selects over all experts "
+            "(n_group = topk_group = 1, Kimi-K2); softmax scoring and the "
+            "group-limited selection of DeepSeek-V3 are not implemented")
+    if not getattr(c, "norm_topk_prob", True) or getattr(
+            c, "moe_layer_freq", 1) != 1 or not getattr(c, "q_lora_rank", None):
+        raise NotImplementedError(
+            "needs norm_topk_prob, moe_layer_freq 1 and a low-rank q")
+    scaling = _rope_scaling_from_hf(
+        getattr(c, "rope_scaling", None),
+        getattr(c, "max_position_embeddings", None))
+    rs = getattr(c, "rope_scaling", None) or {}
+    mscale = 1.0
+    if rs.get("mscale_all_dim"):  # the scores carry its square
+        mscale = 0.1 * rs["mscale_all_dim"] * math.log(rs["factor"]) + 1.0
+    first, count = held_experts or (0, c.n_routed_experts)
+    kw = dict(
+        vocab_size=c.vocab_size, d_model=c.hidden_size,
+        n_layers=c.num_hidden_layers, n_heads=c.num_attention_heads,
+        n_kv_heads=c.num_attention_heads, d_ff=c.intermediate_size,
+        rope_theta=float(c.rope_theta), norm_eps=float(c.rms_norm_eps),
+        rope_scaling=scaling,
+        latent=LatentAttn(
+            q_rank=c.q_lora_rank, kv_rank=c.kv_lora_rank,
+            nope_dim=c.qk_nope_head_dim, rope_dim=c.qk_rope_head_dim,
+            v_dim=c.v_head_dim,
+            sm_scale=(c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
+            * mscale * mscale),
+        routed=RoutedFFN(
+            n_experts=c.n_routed_experts, top_k=c.num_experts_per_tok,
+            d_expert=c.moe_intermediate_size, n_held=count, first_held=first,
+            n_shared=c.n_shared_experts,
+            scale=float(c.routed_scaling_factor),
+            first_dense=c.first_k_dense_replace))
+    kw.update(overrides)
+    return LlamaConfig(**kw)
+
+
+def rope_rows_to_halves(w: np.ndarray, starts, width: int) -> np.ndarray:
+    """Rows ``start .. start + width`` (for every start in ``starts``) of
+    an HF ``[out, in]`` projection from the published INTERLEAVED rope
+    order (pairs (0,1), (2,3), ...) into this package's split halves
+    (evens, then odds): the permutation the published
+    ``apply_rotary_pos_emb`` makes at run time.  One gather for all of a
+    matrix's heads."""
+    order = np.concatenate([np.arange(0, width, 2), np.arange(1, width, 2)])
+    rows = np.arange(w.shape[0])
+    for start in np.atleast_1d(starts):
+        rows[start:start + width] = start + order
+    return w[rows]
+
+
+def _latent_moe_params(get, cfg: LlamaConfig, dt) -> tuple:
+    """The segments of a DeepSeek-V3 / Kimi-K2 state dict: the leading
+    dense layers, then the routed ones with the HELD experts stacked.  The
+    checkpoints' block-quantised fp8 weights are not handled: dequantise
+    first."""
+    import jax.numpy as jnp
+
+    la, r, H = cfg.latent, cfg.routed, cfg.n_heads
+    qk = la.nope_dim + la.rope_dim
+
+    def wq_b(i):
+        return rope_rows_to_halves(
+            _np(get(f"layers.{i}.self_attn.q_b_proj.weight")),
+            np.arange(H) * qk + la.nope_dim, la.rope_dim).T
+
+    def wkv_a(i):
+        return rope_rows_to_halves(
+            _np(get(f"layers.{i}.self_attn.kv_a_proj_with_mqa.weight")),
+            la.kv_rank, la.rope_dim).T
+
+    def mlp(name, i):
+        return {ours: _t(get(f"layers.{i}.{name}.{theirs}.weight"))
+                for ours, theirs in (("w_gate", "gate_proj"),
+                                     ("w_up", "up_proj"),
+                                     ("w_down", "down_proj"))}
+
+    def layer(i, routed: bool) -> dict:
+        at = f"layers.{i}.self_attn."
+        out = {
+            "attn_norm": _np(get(f"layers.{i}.input_layernorm.weight")),
+            "mlp_norm": _np(get(f"layers.{i}.post_attention_layernorm.weight")),
+            "wq_a": _t(get(at + "q_a_proj.weight")),
+            "q_norm": _np(get(at + "q_a_layernorm.weight")),
+            "wq_b": wq_b(i), "wkv_a": wkv_a(i),
+            "kv_norm": _np(get(at + "kv_a_layernorm.weight")),
+            "wkv_b": _t(get(at + "kv_b_proj.weight")),
+            "wo": _t(get(at + "o_proj.weight")),
+        }
+        if not routed:
+            out.update(mlp("mlp", i))
+            return out
+        held = [mlp(f"mlp.experts.{e}", i)
+                for e in range(r.first_held, r.first_held + r.n_held)]
+        out["routed"] = {
+            "router": _t(get(f"layers.{i}.mlp.gate.weight")),
+            "bias": _np(get(f"layers.{i}.mlp.gate.e_score_correction_bias")),
+            **{n: np.stack([h[n] for h in held]) for n in held[0]},
+            "shared": mlp("mlp.shared_experts", i)}
+        return out
+
+    def stacked(lo, hi, routed):
+        import jax
+
+        rows = [layer(i, routed) for i in range(lo, hi)]
+        return jax.tree_util.tree_map(
+            lambda *xs: jnp.asarray(np.stack(xs), dt), *rows)
+
+    segs = [stacked(lo, hi, routed) for lo, hi, routed in (
+        (0, r.first_dense, False), (r.first_dense, cfg.n_layers, True))
+        if hi > lo]
+    for seg in segs:  # the selection bias stays float32 (the router's type)
+        if "routed" in seg:
+            seg["routed"]["bias"] = seg["routed"]["bias"].astype(jnp.float32)
+    return tuple(segs)
 
 
 def _rope_scaling_from_hf(scaling, max_position_embeddings=None,
@@ -310,6 +453,14 @@ def params_from_hf(model_or_state: Any, cfg: LlamaConfig, dtype=None, *,
     def get(name):
         return state[prefix + name]
 
+    if cfg.latent is not None:
+        if quantize != "none":
+            raise NotImplementedError("the W8A16 tree has no latent leaves")
+        segs = _latent_moe_params(get, cfg, dt)
+        return {"embed": jnp.asarray(_np(get("embed_tokens.weight")), dt),
+                "layers": segs[0] if len(segs) == 1 else segs,
+                "final_norm": jnp.asarray(_np(get("norm.weight")), dt),
+                "lm_head": jnp.asarray(_t(state["lm_head.weight"]), dt)}
     L = cfg.n_layers
     stack = lambda fn: jnp.asarray(np.stack([fn(i) for i in range(L)]), dt)
     fused = prefix + "layers.0.self_attn.qkv_proj.weight" in state

@@ -35,6 +35,49 @@ from ..ops.attention import blockwise_attention
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentAttn:
+    """Attention kind: multi-head latent attention (models/mla.py).  Low-rank
+    q and kv projections, heads split into a no-rope and a rope part, one
+    rope key head shared by all heads; the cache holds ``kv_rank +
+    rope_dim`` values a token a layer."""
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    # The scores' whole multiplier: (nope_dim + rope_dim) ** -0.5, times
+    # the square of YaRN's mscale where the checkpoint has one.
+    sm_scale: float
+
+    @property
+    def cache_width(self) -> int:
+        """Values a cached row is ALLOCATED: ``kv_rank + rope_dim`` rounded
+        up to whole 128-lane tiles, zeros above.  The chip lays the array
+        out so whatever is asked for (576 -> 640), and its compiler takes
+        no DMA of part of a tile: the padding is made explicit."""
+        return -(-(self.kv_rank + self.rope_dim) // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedFFN:
+    """FFN kind: sigmoid-routed, dropless experts beside a shared expert
+    (models/moe.py::routed_ffn).  The router is ``n_experts`` wide and
+    every token takes ``top_k``; THIS holder computes experts
+    ``first_held .. first_held + n_held - 1`` (one chip's share of an
+    expert-parallel deployment; all of them when ``n_held ==
+    n_experts``).  The first ``first_dense`` layers are dense SwiGLU of
+    width ``d_ff`` and form a segment of their own."""
+    n_experts: int
+    top_k: int
+    d_expert: int
+    n_held: int
+    first_held: int = 0
+    n_shared: int = 1
+    scale: float = 1.0
+    first_dense: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
     d_model: int = 4096
@@ -105,6 +148,13 @@ class LlamaConfig:
     #    (long wavelengths scaled, short kept, smooth band between).
     # None = unscaled.  Applied inside rope_tables via cfg_rope_tables.
     rope_scaling: Optional[tuple] = None
+    # The kinds of a block, where they are not the defaults (grouped-query
+    # attention; one dense or capacity-MoE FFN for every layer).  A model
+    # is a sequence of homogeneous SEGMENTS of layers, each a stacked tree
+    # that one scan walks (layer_segments); what a layer is follows from
+    # its leaves, what it needs beyond them from these.
+    latent: Optional[LatentAttn] = None
+    routed: Optional[RoutedFFN] = None
 
     def __post_init__(self):
         if self.sliding_window is not None and self.sliding_window < 1:
@@ -175,6 +225,11 @@ class LlamaConfig:
         return self.d_model // self.n_heads
 
     @property
+    def rope_dim(self) -> int:
+        """Width of the rotated part of a head: the tables' width."""
+        return self.latent.rope_dim if self.latent else self.head_dim
+
+    @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
@@ -200,6 +255,103 @@ class LlamaConfig:
 # ------------------------------------------------------------------ params
 
 
+def layer_segments(layers) -> list:
+    """``params["layers"]`` as ``[(stacked tree, index of its first
+    layer)]``: one dict is a model of one segment, a tuple of dicts one
+    segment each (a leading dense layer, then the expert layers)."""
+    out, at = [], 0
+    for seg in (layers if isinstance(layers, (tuple, list)) else (layers,)):
+        out.append((seg, at))
+        at += jax.tree_util.tree_leaves(seg)[0].shape[0]
+    return out
+
+
+# Leaves a kernel indexes by layer itself: a scan leaves them whole.
+_WHOLE_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def scan_segment(body, carry, seg, *xs):
+    """``lax.scan`` of ``body(carry, lp, *x) -> (carry, y)`` over the
+    layers of one stacked segment (and ``xs`` beside them).  A routed
+    segment's expert weights are NOT scanned over: as ``xs`` every layer's
+    ``[G, K, N]`` would be sliced out and copied for the grouped matmul's
+    custom call, every step (a third of a Kimi-K2 decode step's device
+    time: PERF.md, PR 26).  They stay whole in the body's closure and the
+    layer's ``routed`` tree gets them with ``layer``, its index, which
+    reaches the kernel as a prefetched scalar."""
+    if "routed" not in seg:
+        return lax.scan(lambda c, x: body(c, *x), carry, (seg, *xs))
+    whole = {n: seg["routed"][n] for n in _WHOLE_LEAVES}
+    rest = {**seg, "routed": {k: v for k, v in seg["routed"].items()
+                              if k not in whole}}
+    n = jax.tree_util.tree_leaves(rest)[0].shape[0]
+
+    def step(carry, x):
+        lp, i, *more = x
+        lp = {**lp, "routed": {**lp["routed"], **whole, "layer": i}}
+        return body(carry, lp, *more)
+
+    return lax.scan(step, carry, (rest, jnp.arange(n, dtype=jnp.int32), *xs))
+
+
+def _init_block_params(key, cfg: LlamaConfig) -> tuple:
+    """The segments of a model whose blocks are not the default kinds."""
+    dt = cfg.compute_dtype
+    D, H = cfg.d_model, cfg.n_heads
+
+    def norm(k, shape, scale):
+        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
+
+    def mlp(k, L, lead, F):
+        ks = jax.random.split(k, 3)
+        return {"w_gate": norm(ks[0], (L, *lead, D, F), D**-0.5),
+                "w_up": norm(ks[1], (L, *lead, D, F), D**-0.5),
+                "w_down": norm(ks[2], (L, *lead, F, D), F**-0.5)}
+
+    def segment(k, L, routed: bool):
+        ks = jax.random.split(k, 8)
+        seg = {"attn_norm": jnp.ones((L, D), dt),
+               "mlp_norm": jnp.ones((L, D), dt)}
+        la = cfg.latent
+        if la is not None:
+            seg.update(
+                wq_a=norm(ks[0], (L, D, la.q_rank), D**-0.5),
+                q_norm=jnp.ones((L, la.q_rank), dt),
+                wq_b=norm(ks[1], (L, la.q_rank, H * (la.nope_dim + la.rope_dim)),
+                          la.q_rank**-0.5),
+                wkv_a=norm(ks[2], (L, D, la.kv_rank + la.rope_dim), D**-0.5),
+                kv_norm=jnp.ones((L, la.kv_rank), dt),
+                wkv_b=norm(ks[3], (L, la.kv_rank, H * (la.nope_dim + la.v_dim)),
+                           la.kv_rank**-0.5),
+                wo=norm(ks[4], (L, H * la.v_dim, D), (H * la.v_dim)**-0.5))
+        else:
+            hd, Hkv = cfg.head_dim, cfg.n_kv_heads
+            seg.update(wq=norm(ks[0], (L, D, H * hd), D**-0.5),
+                       wk=norm(ks[1], (L, D, Hkv * hd), D**-0.5),
+                       wv=norm(ks[2], (L, D, Hkv * hd), D**-0.5),
+                       wo=norm(ks[4], (L, H * hd, D), (H * hd)**-0.5))
+        if routed:
+            r = cfg.routed
+            seg["routed"] = {
+                "router": norm(ks[5], (L, D, r.n_experts), D**-0.5),
+                # Small and not zero: the selection path is worked.  Small
+                # against the scores' spacing at the top-k threshold, or
+                # the experts' popularity is the seed's (PERF.md, PR 26).
+                "bias": 0.005 * jax.random.normal(ks[6], (L, r.n_experts),
+                                                  jnp.float32),
+                **mlp(ks[7], L, (r.n_held,), r.d_expert),
+                "shared": mlp(jax.random.fold_in(ks[7], 1), L, (),
+                              r.n_shared * r.d_expert)}
+        else:
+            seg.update(mlp(ks[7], L, (), cfg.d_ff))
+        return seg
+
+    n_dense = cfg.routed.first_dense if cfg.routed else cfg.n_layers
+    plan = [(n_dense, False), (cfg.n_layers - n_dense, True)]
+    return tuple(segment(jax.random.fold_in(key, 31 + i), n, routed)
+                 for i, (n, routed) in enumerate(plan) if n)
+
+
 def init_params(key, cfg: LlamaConfig) -> dict:
     """Stacked-layer parameter pytree.  Weights init: scaled normal."""
     dt = cfg.compute_dtype
@@ -211,6 +363,12 @@ def init_params(key, cfg: LlamaConfig) -> dict:
 
     L, D, F = cfg.n_layers, cfg.d_model, cfg.d_ff
     Hq, Hkv = cfg.n_heads, cfg.n_kv_heads
+    if cfg.latent is not None or cfg.routed is not None:
+        segs = _init_block_params(jax.random.fold_in(key, 23), cfg)
+        return {"embed": norm(keys[0], (cfg.vocab_size, D), 0.02),
+                "layers": segs[0] if len(segs) == 1 else segs,
+                "final_norm": jnp.ones((D,), dt),
+                "lm_head": norm(keys[8], (D, cfg.vocab_size), D**-0.5)}
     layers = {
         "wq": norm(keys[1], (L, D, Hq * hd), D**-0.5),
         "wk": norm(keys[2], (L, D, Hkv * hd), D**-0.5),
@@ -250,6 +408,11 @@ def param_specs(cfg: LlamaConfig) -> dict:
     over the tp-sharded dim, so XLA inserts the reduce-scatter/all-reduce
     pattern over ICI automatically.  Embedding/lm_head shard the vocab dim.
     """
+    if cfg.latent is not None or cfg.routed is not None:
+        raise NotImplementedError(
+            "latent attention and the routed FFN have no sharding rules "
+            "yet: they serve on one chip as one holder of an expert-"
+            "parallel deployment (ROADMAP M2)")
     layers = {
         "wq": P(None, None, "tp"),
         "wk": P(None, None, "tp"),
@@ -421,7 +584,7 @@ def cfg_rope_tables(cfg: "LlamaConfig", seq_len: int):
     """:func:`rope_tables` keyed entirely off a config — THE way model
     code builds tables (forgetting ``cfg.rope_scaling`` at one of the
     many call sites would silently mis-rotate positions)."""
-    return rope_tables(seq_len, cfg.head_dim, cfg.rope_theta,
+    return rope_tables(seq_len, cfg.rope_dim, cfg.rope_theta,
                        cfg.rope_scaling)
 
 
@@ -501,20 +664,24 @@ def _remat_wrap(layer, cfg: "LlamaConfig"):
     return jax.checkpoint(layer)
 
 
-def default_attn(q, k, v, window: Optional[int] = None):
+def default_attn(q, k, v, window: Optional[int] = None,
+                 sm_scale: Optional[float] = None):
     """Causal attention: the hand-tiled pallas kernel on TPU, the lax
     blockwise scan elsewhere (bit-compatible algebra, same GQA handling).
     ``window``: sliding-window causal — the flash kernel masks, skips, and
-    DMA-elides out-of-window blocks in forward AND backward."""
+    DMA-elides out-of-window blocks in forward AND backward.  ``sm_scale``:
+    the scores' multiplier where it is not ``head_dim ** -0.5``."""
     if jax.default_backend() == "tpu":
         from ..ops.pallas_attention import flash_attention
         from ..parallel.sharding import per_head_shard
 
         return per_head_shard(
             lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                            interpret=False, window=window),
+                                            interpret=False, window=window,
+                                            sm_scale=sm_scale),
             (q, k, v))
-    return blockwise_attention(q, k, v, causal=True, window=window)
+    return blockwise_attention(q, k, v, causal=True, window=window,
+                               sm_scale=sm_scale)
 
 
 def resolve_attn_fn(cfg: LlamaConfig, attn_fn: Optional[Callable]) -> Callable:
@@ -531,6 +698,8 @@ def resolve_attn_fn(cfg: LlamaConfig, attn_fn: Optional[Callable]) -> Callable:
     windows.
     """
     if attn_fn is None:
+        if cfg.latent is not None:
+            return partial(default_attn, sm_scale=cfg.latent.sm_scale)
         if cfg.sliding_window is not None:
             return partial(default_attn, window=cfg.sliding_window)
         return default_attn
@@ -594,17 +763,61 @@ def qkv_proj(x, lp, cfg: "LlamaConfig"):
             v.reshape(B, S, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3))
 
 
+def ffn_block(x, lp, cfg: "LlamaConfig", moe_fn: Optional[Callable] = None):
+    """The FFN of one block on the normed ``x [B, S, D]``, by the kind its
+    leaves name: ``routed`` (sigmoid-routed dropless experts and a shared
+    one), ``moe`` (capacity-buffer Switch/Mixtral) or dense gated MLP.
+    Returns ``(y, aux, stats)``: the MoE balance term (0 elsewhere) and,
+    routed, the pairs each held expert got ``[n_held]``; capacity MoE,
+    the router-health dict of a ``with_stats`` ``moe_fn``; else None.  The
+    ONE FFN site of the scan forward and the cached decode layer scan."""
+    aux, stats = jnp.zeros((), jnp.float32), None
+    if "routed" in lp:
+        from .moe import routed_ffn
+
+        y, stats = routed_ffn(x, lp["routed"], cfg.routed,
+                              act=partial(mlp_gate_act, cfg=cfg))
+    elif "moe" in lp:
+        if moe_fn is not None:
+            # SwiGLU expert trees carry w_gate; pass it only when
+            # present so 4-arg moe_fns (Switch-style) keep working.
+            kw = ({"w_gate": lp["moe"]["w_gate"]}
+                  if "w_gate" in lp["moe"] else {})
+            out = moe_fn(
+                x, lp["moe"]["router"], lp["moe"]["w_in"],
+                lp["moe"]["w_out"], **kw)
+            y, aux = out[0], out[1]
+            if len(out) > 2:  # with_stats moe_fn: router-health metrics
+                stats = out[2]
+        else:
+            from .moe import switch_moe
+
+            y, aux = switch_moe(
+                x, lp["moe"]["router"], lp["moe"]["w_in"],
+                lp["moe"]["w_out"],
+                capacity_factor=cfg.moe_capacity_factor,
+                k=cfg.moe_top_k, w_gate=lp["moe"].get("w_gate"),
+            )
+    else:
+        g = checkpoint_name(matmul_w(x, lp["w_gate"]), "mlp_gate")
+        u = checkpoint_name(matmul_w(x, lp["w_up"]), "mlp_up")
+        gate = mlp_gate_act(g, cfg).astype(x.dtype)
+        y = matmul_w(gate * u, lp["w_down"])
+    return y, aux, stats
+
+
 def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
                   attn_fn: Callable, moe_fn: Optional[Callable] = None):
     """One pre-norm decoder block on ``h [B, S, D]`` with layer params
-    ``lp`` (one slice of the stacked tree).  Returns
-    ``(h, aux, k, v, stats)`` — aux is the MoE balance term (0 for dense),
-    k/v the post-RoPE grouped heads (the KV-cache prefix), stats the MoE
-    router-health dict when ``moe_fn`` returns one (``with_stats=True``
-    builders), else None.  Shared by the scan forward, and the
-    pipeline-parallel stage body (models/pp_llama.py)."""
+    ``lp`` (one slice of a stacked segment).  Returns
+    ``(h, aux, kv, stats)`` — aux is the MoE balance term (0 for dense),
+    kv what the cache holds of these positions, under the cache's own
+    keys (``k`` / ``v``: the post-RoPE grouped heads; latent attention:
+    ``ckv``, models/mla.py), stats the capacity MoE's router-health dict
+    when ``moe_fn`` returns one (``with_stats=True`` builders), else None.
+    Shared by the scan forward, and the pipeline-parallel stage body
+    (models/pp_llama.py)."""
     B, S, _ = h.shape
-    hd = cfg.head_dim
     # "dots" remat is CHUNKED: two checkpointed regions around an
     # un-checkpointed attention call.  A whole-layer jax.checkpoint with a
     # dots-saveable policy silently replays the flash forward kernel in the
@@ -618,48 +831,26 @@ def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
 
     def pre(h, lp):
         x = rmsnorm(h, lp["attn_norm"], cfg.norm_eps)
+        if "wq_a" in lp:
+            from .mla import project_expanded
+
+            q, k, v, rows = project_expanded(x, lp, cfg, cos, sin)
+            return q, k, v, {"ckv": rows}
         q, k, v = qkv_proj(x, lp, cfg)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         # kv stays in grouped (narrow) form; attention impls expand it, so
         # the ring rotates 1/n_rep of the bytes over ICI.
-        return q, k, v
+        return q, k, v, {"k": k, "v": v}
 
     def post(h, o, lp):
-        o = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_heads * hd)
+        o = o.transpose(0, 2, 1, 3).reshape(B, S, -1)
         h = h + matmul_w(o, lp["wo"])
-
-        x = rmsnorm(h, lp["mlp_norm"], cfg.norm_eps)
-        stats = None
-        if cfg.n_experts > 0:
-            if moe_fn is not None:
-                # SwiGLU expert trees carry w_gate; pass it only when
-                # present so 4-arg moe_fns (Switch-style) keep working.
-                kw = ({"w_gate": lp["moe"]["w_gate"]}
-                      if "w_gate" in lp["moe"] else {})
-                out = moe_fn(
-                    x, lp["moe"]["router"], lp["moe"]["w_in"],
-                    lp["moe"]["w_out"], **kw)
-                y, aux = out[0], out[1]
-                if len(out) > 2:  # with_stats moe_fn: router-health metrics
-                    stats = out[2]
-            else:
-                from .moe import switch_moe
-
-                y, aux = switch_moe(
-                    x, lp["moe"]["router"], lp["moe"]["w_in"],
-                    lp["moe"]["w_out"],
-                    capacity_factor=cfg.moe_capacity_factor,
-                    k=cfg.moe_top_k, w_gate=lp["moe"].get("w_gate"),
-                )
-            h = h + y
-        else:
-            g = checkpoint_name(matmul_w(x, lp["w_gate"]), "mlp_gate")
-            u = checkpoint_name(matmul_w(x, lp["w_up"]), "mlp_up")
-            gate = mlp_gate_act(g, cfg).astype(x.dtype)
-            h = h + matmul_w(gate * u, lp["w_down"])
-            aux = jnp.zeros((), jnp.float32)
-        return h, aux, stats
+        y, aux, stats = ffn_block(rmsnorm(h, lp["mlp_norm"], cfg.norm_eps),
+                                  lp, cfg, moe_fn)
+        if "routed" in lp:
+            stats = None  # the held experts' pair counts: the decode path's
+        return h + y, aux, stats
 
     if chunked:
         # pre: boundary outputs (q, k, v) are saved by construction; the
@@ -680,13 +871,13 @@ def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
                 jax.checkpoint_policies.save_only_these_names(
                     "mlp_gate", "mlp_up")))
 
-    q, k, v = pre(h, lp)
+    q, k, v, kv = pre(h, lp)
     o = attn_fn(q, k, v)  # [B, H, S, Dh]
     # Tag kept for user-supplied whole-model remat policies; the flash
     # kernel additionally tags o and lse internally (pallas_attention).
     o = checkpoint_name(o, "attn_out")
     h, aux, stats = post(h, o, lp)
-    return h, aux, k, v, stats
+    return h, aux, kv, stats
 
 
 def forward(params: dict, tokens, cfg: LlamaConfig,
@@ -696,10 +887,13 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
             return_moe_stats: bool = False):
     """Next-token logits ``[B, S, V]`` for token ids ``[B, S]``.
 
-    ``return_kv`` additionally returns the post-RoPE grouped k/v of every
-    layer, scan-stacked ``[n_layers, B, Hkv, S, Dh]`` -- the KV-cache prefix
-    for :func:`~starway_tpu.models.generate.prefill` (one flash-attention
-    pass over the whole prompt instead of S cached decode steps).
+    ``return_kv`` additionally returns what the cache holds of every
+    layer, under the cache's keys and stacked over ALL layers (``k`` /
+    ``v [n_layers, B, Hkv, S, Dh]``, the post-RoPE grouped heads; latent
+    attention: ``ckv [n_layers, B, 1, S, cache_width]``) -- the
+    KV-cache prefix for :func:`~starway_tpu.models.generate.prefill` (one
+    flash-attention pass over the whole prompt instead of S cached decode
+    steps).
     ``last_only`` applies the final norm + lm_head to the last position only
     (``[B, 1, V]``), skipping the ``[B, S, V]`` logit tensor a prefill never
     reads; ``logit_positions`` ([B] ints) is its ragged analog — logits for
@@ -738,30 +932,36 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
 
     def layer(carry, lp):
         h, aux = carry
-        h, layer_aux, k, v, stats = decoder_layer(lp, h, cfg, cos, sin,
-                                                  attn_fn, moe_fn=moe_fn)
+        h, layer_aux, kv, stats = decoder_layer(lp, h, cfg, cos, sin,
+                                                attn_fn, moe_fn=moe_fn)
         if return_moe_stats and stats is None:
             raise ValueError("return_moe_stats=True but moe_fn returned no "
                              "stats (build it with with_stats=True)")
-        return (h, aux + layer_aux), ((k, v) if return_kv else None,
+        return (h, aux + layer_aux), (kv if return_kv else None,
                                       stats if return_moe_stats else None)
 
     body = _remat_wrap(layer, cfg)
-    if cfg.scan_layers:
-        (h, aux), (kv, moe_stats) = lax.scan(
-            body, (h, jnp.zeros((), jnp.float32)), params["layers"])
-    else:
-        # Unrolled: same body, Python loop over layer slices; per-layer
-        # outputs are stacked to match the scan's [n_layers, ...] layout.
-        carry = (h, jnp.zeros((), jnp.float32))
-        ys = []
-        for i in range(cfg.n_layers):
-            lp = jax.tree_util.tree_map(lambda x: x[i], params["layers"])
-            carry, y = body(carry, lp)
-            ys.append(y)
-        h, aux = carry
-        kv, moe_stats = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *ys)
+    carry = (h, jnp.zeros((), jnp.float32))
+    outs = []
+    # One scan a segment: the segments differ in their trees (a leading
+    # dense layer before the expert layers), the body reads a layer's kind
+    # off its leaves.
+    for seg, _first in layer_segments(params["layers"]):
+        if cfg.scan_layers:
+            carry, ys = scan_segment(body, carry, seg)
+        else:
+            # Unrolled: same body, Python loop over layer slices; per-layer
+            # outputs are stacked to match the scan's [n_layers, ...] layout.
+            ys = []
+            for i in range(jax.tree_util.tree_leaves(seg)[0].shape[0]):
+                lp = jax.tree_util.tree_map(lambda x: x[i], seg)
+                carry, y = body(carry, lp)
+                ys.append(y)
+            ys = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ys)
+        outs.append(ys)
+    h, aux = carry
+    kv, moe_stats = (outs[0] if len(outs) == 1 else jax.tree_util.tree_map(
+        lambda *xs: jnp.concatenate(xs), *outs))
     if last_only:
         h = h[:, -1:]
     elif logit_positions is not None:

@@ -26,6 +26,8 @@ reduces to GShard's aux for k>=2 with first-choice fractions).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -70,7 +72,8 @@ def require_dropless(cfg, context: str) -> None:
     the rule every shape-sensitive entry point shares: ragged
     generation, continuous batching, and the speculative chunk verify
     all rely on routing being shape-invariant, which only droplessness
-    guarantees."""
+    guarantees.  The routed FFN (``cfg.routed``, :func:`routed_ffn`) has
+    no capacity to exceed and passes: its ``n_experts`` here is 0."""
     if cfg.n_experts > 0 and cfg.moe_capacity_factor < cfg.n_experts:
         raise ValueError(
             f"{context} needs dense FFNs or provably-dropless MoE: expert "
@@ -333,3 +336,113 @@ def make_sharded_moe(mesh, *, ep_axis: str = "ep", dp_axis: str = "dp",
         return mapped(x, router_w, w_in, w_out, w_gate)
 
     return fn
+
+
+# --------------------------------------------------- routed (dropless) FFN
+#
+# The DeepSeek-V3 / Kimi-K2 expert layer as ONE chip of an expert-parallel
+# deployment runs it: the router scores all ``n_experts``, every token
+# takes its ``top_k``, and this chip computes the part of the result that
+# the experts it HOLDS give (plus the shared expert, which every chip
+# holds).  No capacity: the (token, choice) pairs that landed here are
+# sorted by expert and go through a grouped matmul (ops/pallas_gmm.py)
+# that reads only the experts that got a token.
+
+
+def sigmoid_route(xt, router_w, bias, top_k: int, scale: float):
+    """``xt [T, D]`` -> ``(experts [T, k] int32, gates [T, k] f32)``.
+    Scores are sigmoids in f32; the SELECTION adds ``bias`` (the
+    checkpoint's ``e_score_correction_bias``), the gates are the chosen
+    scores without it, divided by their sum and scaled."""
+    s = jax.nn.sigmoid(jnp.dot(xt, router_w,
+                               preferred_element_type=jnp.float32))
+    _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    g = jnp.take_along_axis(s, idx, axis=-1)
+    g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20) * scale
+    return idx.astype(jnp.int32), g
+
+
+def group_rows(local, n_held: int, tile_m: int):
+    """The grouped matmul's row layout for ``local [M]`` (each pair's
+    expert as an index into the held ones; anything outside ``[0,
+    n_held)`` is not held here).  Held pairs are laid out expert by
+    expert, each expert's rows padded to whole tiles of ``tile_m``.
+    Returns ``(src [M_pad]`` the pair whose input each row holds, M for an
+    empty row; ``row [M]`` each pair's row, M_pad where it is not held;
+    ``tile_expert [M_pad / tile_m]``; ``n_live`` tiles that hold a pair;
+    ``sizes [n_held]`` pairs an expert)."""
+    m = local.shape[0]
+    m_pad = -(-m // tile_m) * tile_m + n_held * tile_m
+    held = (local >= 0) & (local < n_held)
+    key = jnp.where(held, local, n_held)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:n_held]
+    padded = -(-sizes // tile_m) * tile_m
+    ends_p = jnp.cumsum(padded)
+    starts = jnp.cumsum(sizes) - sizes
+    skey = key[order]
+    e = jnp.minimum(skey, n_held - 1)
+    dest = jnp.where(skey < n_held,
+                     (ends_p - padded)[e] + jnp.arange(m) - starts[e], m_pad)
+    src = jnp.full((m_pad,), m, jnp.int32).at[dest].set(
+        order.astype(jnp.int32), mode="drop")
+    row = jnp.zeros((m,), jnp.int32).at[order].set(dest.astype(jnp.int32))
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(ends_p, jnp.arange(m_pad // tile_m) * tile_m,
+                         side="right"), n_held - 1).astype(jnp.int32)
+    return src, row, tile_expert, ends_p[-1] // tile_m, sizes
+
+
+def routed_experts(xt, experts, gates, w, first: int, use_pallas=None):
+    """The held experts' part of the layer for ``xt [T, D]``: ``experts`` /
+    ``gates`` ``[T, k]`` from the router over ALL experts, ``w`` the held
+    experts' stacked SwiGLU weights (``w_gate`` / ``w_up [G, D, F]``,
+    ``w_down [G, F, D]``), which are experts ``first .. first + G - 1``;
+    with ``w["layer"]`` (a traced scalar) the three are every layer's
+    ``[L, G, ...]`` and the kernel picks the layer
+    (:func:`~starway_tpu.models.llama.scan_segment`).  Returns ``(y [T,
+    D], sizes [G])``: pairs that chose an expert held elsewhere add
+    nothing here."""
+    from ..ops.pallas_gmm import gmm, gmm_lax
+
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    t, d = xt.shape
+    k = experts.shape[1]
+    layer = w.get("layer")
+    g = w["w_gate"].shape[0 if layer is None else 1]
+    # Row tiles: a decode step's few pairs an expert want small tiles, a
+    # prompt's hundreds the MXU's height.
+    tile_m = 16 if t * k <= 4096 else 128
+    src, row, tile_expert, n_live, sizes = group_rows(
+        experts.reshape(-1) - first, g, tile_m)
+    x_rows = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)])[
+        jnp.minimum(src // k, t)]
+    run = functools.partial(gmm if use_pallas else gmm_lax, tile_m=tile_m,
+                            layer=layer)
+    hidden = run(x_rows, w["w_gate"], tile_expert, n_live, w2=w["w_up"])
+    out = run(hidden, w["w_down"], tile_expert, n_live)
+    held = (row < out.shape[0]).reshape(t, k)
+    picked = out[jnp.minimum(row, out.shape[0] - 1)].reshape(t, k, d)
+    # Rows no live tile wrote are never summed: a held pair's row is live.
+    y = jnp.sum(jnp.where(held[..., None], picked, 0).astype(jnp.float32)
+                * gates[..., None], axis=1)
+    return y.astype(xt.dtype), sizes
+
+
+def routed_ffn(x, rp, routed, act=jax.nn.silu):
+    """One routed FFN layer on ``x [B, S, D]``: router over all experts,
+    the held experts' share of the routed result, plus the shared expert.
+    ``rp``: ``router [D, E]``, ``bias [E]``, ``w_gate`` / ``w_up`` /
+    ``w_down`` (held experts, stacked) and ``shared`` (one dense SwiGLU).
+    ``routed``: the configuration's :class:`~.llama.RoutedFFN`.  Returns
+    ``(y, sizes [G])``."""
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    experts, gates = sigmoid_route(xt, rp["router"], rp["bias"],
+                                   routed.top_k, routed.scale)
+    y, sizes = routed_experts(xt, experts, gates, rp, routed.first_held)
+    sh = rp["shared"]
+    gate = act((xt @ sh["w_gate"]).astype(jnp.float32)).astype(xt.dtype)
+    y = y + (gate * (xt @ sh["w_up"])) @ sh["w_down"]
+    return y.reshape(b, s, d), sizes

@@ -99,8 +99,8 @@ def paged_decode_step(params, pool, table, token, pos, cfg: LlamaConfig,
         return _paged_attend(q, pool, layer, table, pos)
 
     h = embed_tokens(params, token, cfg)[:, None, :]
-    h, out = cached_layer_scan(params, pool, h, cos_p, sin_p, cfg, write,
-                               attend)
+    h, out, _ = cached_layer_scan(params, pool, h, cos_p, sin_p, cfg, write,
+                                  attend)
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
     logits = matmul_w(h[:, 0, :], params["lm_head"]).astype(jnp.float32)
     return logits, out
@@ -227,8 +227,8 @@ def _compiled_paged_prefix_admit(cfg: LlamaConfig, s_bucket: int, page: int,
         from .llama import embed_tokens, head_logits
 
         h = embed_tokens(params, suffix[0], cfg)[None]  # [1, s_bucket, D]
-        h, pool = cached_layer_scan(params, pool, h, cos_p, sin_p, cfg,
-                                    write, attend)
+        h, pool, _ = cached_layer_scan(params, pool, h, cos_p, sin_p, cfg,
+                                       write, attend)
         logits = head_logits(h[:, s_len - 1][:, None], params["final_norm"],
                              params["lm_head"],
                              cfg.norm_eps)[:, 0]
@@ -281,6 +281,10 @@ class PagedSlotServer(SlotServer):
             raise NotImplementedError(
                 "int8 paged pools are not wired yet; use the dense "
                 "SlotServer for kv_quant='int8'")
+        if cfg.latent is not None:
+            raise NotImplementedError(
+                "the page pool has no latent page kind yet (ROADMAP M3); "
+                "latent-attention models serve through the dense SlotServer")
         if max_len % page:
             raise ValueError(f"page ({page}) must divide max_len "
                              f"({max_len})")

@@ -252,8 +252,8 @@ def make_pp_llama_train(mesh, cfg: LlamaConfig, *, axis_name: str = "pp",
 
         def body(carry, lp):
             hh, aux = carry
-            hh, a, _k, _v, _stats = decoder_layer(lp, hh, cfg, cos, sin,
-                                                  attn, moe_fn=moe_fn)
+            hh, a, _kv, _stats = decoder_layer(lp, hh, cfg, cos, sin,
+                                               attn, moe_fn=moe_fn)
             return (hh, aux + a), None
 
         (h, aux), _ = lax.scan(body, (h, jnp.zeros((), jnp.float32)), local)

@@ -67,8 +67,8 @@ from jax import lax
 
 from .. import perf
 from ..core import swtrace
-from .generate import (_sample, decode_step, init_cache, init_rolling_cache,
-                       prefill)
+from .generate import (_sample, cache_len, decode_step_counted, init_cache,
+                       init_rolling_cache, prefill)
 from .llama import LlamaConfig, cfg_rope_tables
 
 # ----------------------------------------------------------- the serve logs
@@ -119,8 +119,22 @@ def step_log() -> list:
     chunk's result copied to the host: the wait the loop always had),
     ``harvest_s`` (from there until the step returns, ``on_tokens`` calls
     included).  The four durations leave out the Python between them, so
-    they sum to at most ``t1 - t0``."""
+    they sum to at most ``t1 - t0``.  A model with a routed FFN
+    (``cfg.routed``) adds, for the chunk's decode steps and every slot's
+    row in them: ``moe_assign`` ((token, choice) pairs that landed on held
+    experts, all routed layers), ``moe_touched`` (held experts with at
+    least one pair, mean over layers and steps) and ``moe_max`` (the most
+    pairs one expert got in one layer of one step); other models' rows
+    carry none of the three."""
     return [dict(row) for row in list(_step_log)]
+
+
+def _moe_fields(pairs: np.ndarray) -> dict:
+    """A routed model's :func:`step_log` fields from the chunk's pair
+    counts ``[steps, routed layers, held experts]``."""
+    return {"moe_assign": int(pairs.sum()),
+            "moe_touched": float((pairs > 0).sum(-1).mean()),
+            "moe_max": int(pairs.max())}
 
 
 def log_request(row: dict) -> None:
@@ -298,13 +312,16 @@ def _compiled_chunk(cfg: LlamaConfig, n_slots: int, max_len: int, chunk: int,
 
     def run(params, cache, token, pos, live, remaining, key):
         step = make_chunk_scan_step(
-            lambda cache, token, pos: decode_step(
+            lambda cache, token, pos: decode_step_counted(
                 params, cache, token, pos, cfg, rope, rolling=rolling),
             max_len, temperature, top_k, top_p, eos_id)
-        (cache, token, pos, live, remaining, key), (toks, mask) = lax.scan(
-            step, (cache, token, pos, live, remaining, key), None,
-            length=chunk)
-        return cache, token, pos, live, remaining, key, toks, mask
+        # ``pairs``: what each held expert of each routed layer got, a
+        # step ([chunk, routed layers, held]; None for a model with no
+        # routed layer): a third output, fetched with the tokens.
+        (cache, token, pos, live, remaining, key), (toks, mask, pairs) = (
+            lax.scan(step, (cache, token, pos, live, remaining, key), None,
+                     length=chunk))
+        return cache, token, pos, live, remaining, key, toks, mask, pairs
 
     return _named_jit(run, "serve_decode_chunk", donate_argnums=(1,))
 
@@ -314,11 +331,12 @@ def make_chunk_scan_step(decode_one, max_len: int, temperature: float,
     """THE per-step body of every chunked serving loop — dense and paged
     (models/paged.py) scan exactly this, so the liveness/eos/budget/
     emission semantics cannot drift between cache layouts.
-    ``decode_one(cache, token, pos) -> (logits, cache)``."""
+    ``decode_one(cache, token, pos) -> (logits, cache)``; what it returns
+    beyond the two is emitted per step after ``(tokens, mask)``."""
 
     def step(carry, _):
         cache, token, pos, live, remaining, key = carry
-        logits, cache = decode_one(cache, token, pos)
+        logits, cache, *extra = decode_one(cache, token, pos)
         key, sub = jax.random.split(key)
         nxt = _sample(logits, sub, temperature, top_k, top_p)
         emit_live = live & (remaining > 0)
@@ -335,7 +353,8 @@ def make_chunk_scan_step(decode_one, max_len: int, temperature: float,
         # page).
         pos = pos + emit_live.astype(jnp.int32)
         token = jnp.where(emit_live, nxt, token)
-        return (cache, token, pos, live, remaining, key), (nxt, emit_live)
+        return (cache, token, pos, live, remaining, key), (nxt, emit_live,
+                                                           *extra)
 
     return step
 
@@ -448,6 +467,7 @@ class SlotServer:
         self.pos = jnp.zeros((n_slots,), jnp.int32)
         self.live = jnp.zeros((n_slots,), bool)
         self.remaining = jnp.zeros((n_slots,), jnp.int32)
+        self._pairs = None  # the last chunk's third output (_run_chunk)
 
         self._next_rid = 0
         self._pending: deque = deque()
@@ -622,7 +642,7 @@ class SlotServer:
             padded = np.zeros((1, sb), np.int32)
             padded[0, :len(prompt)] = prompt
             admit = _compiled_prefix_admit(
-                self.cfg, small["k"].shape[3], sb, self.max_len,
+                self.cfg, cache_len(small), sb, self.max_len,
                 *self.sampling)
             self.cache, tok = admit(
                 self.params, self.cache, small,
@@ -737,12 +757,26 @@ class SlotServer:
         self._n_steps += 1
         admits, admit_s = 0, 0.0
         dispatch_s = wait_s = harvest_s = 0.0
+        moe: dict = {}
         with perf.stage_span(scope, "serve.step") as whole:
             self._harvest_dead(finished)  # 1-token/instant-eos admissions
             free = [s for s in range(self.n_slots)
                     if s not in self._slot_rid]
+            # WHICH requests a step admits is the queue's order (its first
+            # ``len(free)``); among them the one with most tokens to
+            # produce goes first.  Each admission stalls every request
+            # admitted before it, and a stall costs a request's time per
+            # token as its share of the tokens it is spread over: after a
+            # cold fill of many slots the first-admitted would otherwise
+            # carry the whole fill on however few tokens they asked for
+            # (PERF.md, PR 26).  Chosen one at a time, from the queue as
+            # it stands: an ``on_tokens`` callback may cancel() a request
+            # that still waits.
             while free and self._pending:
-                rid, prompt, max_new, prefix = self._pending.popleft()
+                at = max(range(min(len(free), len(self._pending))),
+                         key=lambda i: (self._pending[i][2], -i))
+                rid, prompt, max_new, prefix = self._pending[at]
+                del self._pending[at]
                 row = self._rows.get(rid)
                 span = perf.stage_span(scope, "serve.admit", rid)
                 try:
@@ -754,11 +788,11 @@ class SlotServer:
                         self._admit(free.pop(0), rid, prompt, max_new, prefix)
                 except RuntimeError:
                     # Transient resource exhaustion (the paged server's
-                    # pool): the request STAYS QUEUED — in-flight work
-                    # frees capacity and a later step admits it (the class
-                    # docstring's "callers keep it queued / retry"
-                    # contract).
-                    self._pending.appendleft((rid, prompt, max_new, prefix))
+                    # pool): the request STAYS QUEUED, where it was —
+                    # in-flight work frees capacity and a later step
+                    # admits it (the class docstring's "callers keep it
+                    # queued / retry" contract).
+                    self._pending.insert(at, (rid, prompt, max_new, prefix))
                     break
                 admits += 1
                 admit_s += span.seconds
@@ -770,9 +804,11 @@ class SlotServer:
                     toks, mask = self._run_chunk(sub)
                 dispatch_s = span.seconds
                 with perf.stage_span(scope, "serve.chunk_wait") as span:
-                    toks = np.asarray(toks)
-                    mask = np.asarray(mask)
+                    toks, mask, pairs = jax.device_get(
+                        (toks, mask, self._pairs))
                 wait_s = span.seconds
+                if pairs is not None:  # a routed model's
+                    moe = _moe_fields(pairs)
                 with perf.stage_span(scope, "serve.harvest") as span:
                     # Snapshot: an on_tokens callback may legally cancel()
                     # a request (its own or another), which mutates
@@ -792,7 +828,7 @@ class SlotServer:
             "t0": whole.t0, "t1": whole.t0 + whole.seconds,
             "queued": queued, "live": live, "admits": admits,
             "admit_s": admit_s, "dispatch_s": dispatch_s, "wait_s": wait_s,
-            "harvest_s": harvest_s})
+            "harvest_s": harvest_s, **moe})
         return finished
 
     @property
@@ -807,8 +843,9 @@ class SlotServer:
                               self.chunk, *self.sampling, self.eos_id,
                               rolling=self.rolling)
         (self.cache, self.token, self.pos, self.live, self.remaining,
-         _key, toks, mask) = run(self.params, self.cache, self.token,
-                                 self.pos, self.live, self.remaining, sub)
+         _key, toks, mask, self._pairs) = run(
+             self.params, self.cache, self.token, self.pos, self.live,
+             self.remaining, sub)
         return toks, mask
 
     def run(self) -> dict:

@@ -40,8 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .generate import (_attend_cached, _filter_logits, _sample, _write_cached,
-                       cached_layer_scan, prefill)
+from .generate import (_filter_logits, _sample, _write_cached, attend_cache,
+                       cache_len, cached_layer_scan, prefill)
 from .llama import (LlamaConfig, cfg_rope_tables, embed_tokens, matmul_w,
                     rmsnorm)
 
@@ -69,7 +69,7 @@ def chunk_decode_step(params, cache, tokens, pos, cfg: LlamaConfig, rope):
     slots change nothing.
     """
     B, C = tokens.shape
-    T_cache = cache["k"].shape[3]
+    T_cache = cache_len(cache)
     if cfg.sliding_window is not None and T_cache == cfg.sliding_window:
         # Mirrors decode_step's rolling-cache shape check, inverted: a
         # cache of exactly sliding_window slots is a rolling cache
@@ -83,7 +83,6 @@ def chunk_decode_step(params, cache, tokens, pos, cfg: LlamaConfig, rope):
             f"(init_cache with max_len != sliding_window — positions past "
             f"the window are masked anyway, so max_len = window + C costs "
             f"nothing) for chunk verify / multi-token ingestion")
-    n_rep = cfg.n_heads // cfg.n_kv_heads
     cos, sin = rope
     pos = jnp.asarray(pos, jnp.int32)
     pos_b = pos if pos.ndim == 1 else jnp.broadcast_to(pos, (B,))
@@ -100,14 +99,11 @@ def chunk_decode_step(params, cache, tokens, pos, cfg: LlamaConfig, rope):
         # positions: on TPU the pallas kernel packs C x n_rep rows into
         # one per-(batch, kv head) matmul over the narrow (int8-capable)
         # cache stream — the verify costs one decode step's bytes.
-        return _attend_cached(q, cache["k"], cache["v"], pos_b, n_rep,
-                              window=cfg.sliding_window,
-                              k_scale=cache.get("k_scale"),
-                              v_scale=cache.get("v_scale"), layer=layer)
+        return attend_cache(q, cache, pos_b, layer, cfg)
 
     h = embed_tokens(params, tokens, cfg)  # [B, C, D]
-    h, out = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg, write,
-                               attend)
+    h, out, _ = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg, write,
+                                  attend)
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
     logits = matmul_w(h, params["lm_head"]).astype(jnp.float32)  # [B, C, V]
     return logits, out

@@ -146,7 +146,7 @@ def blockwise_attention(
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     kb = k.reshape(b, h, nblocks, block_k, d).transpose(2, 0, 1, 3, 4)
-    vb = v.reshape(b, h, nblocks, block_k, d).transpose(2, 0, 1, 3, 4)
+    vb = v.reshape(b, h, nblocks, block_k, v.shape[3]).transpose(2, 0, 1, 3, 4)
     offs = jnp.arange(nblocks) * block_k
 
     def step(carry, blk):
@@ -159,7 +159,11 @@ def blockwise_attention(
         )
         return merge_partials(carry, part), None
 
-    (o, m, l), _ = jax.lax.scan(step, zero_partial(q), (kb, vb, offs))
+    # The accumulator follows the VALUES' width (latent attention's are
+    # narrower than its keys).
+    _, m0, l0 = zero_partial(q)
+    o0 = jnp.zeros(q.shape[:3] + v.shape[3:], jnp.float32)
+    (o, m, l), _ = jax.lax.scan(step, (o0, m0, l0), (kb, vb, offs))
     return finalize_partial(o, m, l, out_dtype=q.dtype)
 
 

@@ -195,6 +195,7 @@ def _fwd_impl(q, k, v, cfg: _Cfg, save_lse: bool):
     hkv = k.shape[1]
     n_rep = hq // hkv
     kv_len = k.shape[2]
+    dv = v.shape[3]  # the values may be narrower than q/k (latent attention)
 
     block_q = min(cfg.block_q, _round_up(s, 8))
     block_k = min(cfg.block_k, _round_up(kv_len, 8))
@@ -208,7 +209,7 @@ def _fwd_impl(q, k, v, cfg: _Cfg, save_lse: bool):
 
     qf = q.reshape(b * hq, s_pad, d)
     kf = k.reshape(b * hkv, kv_pad, d)
-    vf = v.reshape(b * hkv, kv_pad, d)
+    vf = v.reshape(b * hkv, kv_pad, dv)
 
     def kv_head(bh):  # q-head flat index -> kv-head flat index
         return (bh // hq) * hkv + (bh % hq) // n_rep
@@ -228,8 +229,8 @@ def _fwd_impl(q, k, v, cfg: _Cfg, save_lse: bool):
         return (kv_head(bh), j, 0)
 
     grid = (b * hq, s_pad // block_q, kv_pad // block_k)
-    out_shapes = [jax.ShapeDtypeStruct((b * hq, s_pad, d), q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))]
+    out_shapes = [jax.ShapeDtypeStruct((b * hq, s_pad, dv), q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, dv), lambda bh, i, j: (bh, i, 0))]
     if save_lse:
         # Lane dim 8 (not 1): keeps the block tiling legal; col 0 is the
         # value, the rest redundant broadcast (tiny: S*8 f32 per head).
@@ -246,22 +247,22 @@ def _fwd_impl(q, k, v, cfg: _Cfg, save_lse: bool):
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_index),
-            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_k, dv), kv_index),
         ],
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=cfg.interpret,
         name="sw_flash_fwd",
     )(qf, kf, vf)
     if save_lse:
         o, lse = out
-        return o.reshape(b, hq, s_pad, d)[:, :, :s, :], lse[:, :s]
-    return out[0].reshape(b, hq, s_pad, d)[:, :, :s, :]
+        return o.reshape(b, hq, s_pad, dv)[:, :, :s, :], lse[:, :s]
+    return out[0].reshape(b, hq, s_pad, dv)[:, :, :s, :]
 
 
 # ---------------------------------------------------------------------------
@@ -825,7 +826,10 @@ def flash_attention(
     (grouped).
 
     Pads S to the block size internally; padded keys are masked, padded
-    query rows are sliced off the output.  ``window`` (requires
+    query rows are sliced off the output.  ``v`` may be narrower than q/k
+    (latent attention's 192-wide keys over 128-wide values): the forward
+    follows v's width; the backward kernels assume one width and are not
+    reached by such a call's serving path.  ``window`` (requires
     ``causal``): sliding-window attention — kv blocks outside
     ``(q - window, q]`` are masked, compute-skipped, AND DMA-elided in
     both the forward and the two backward passes, so a windowed pass

@@ -460,6 +460,117 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
         b, hq, n_q, d)
 
 
+# ------------------------------------------------- latent (MLA) attention
+
+
+def _mla_stream_kernel(pos_ref, layer_ref, q_ref, c_hbm, o_ref, c_buf, sems,
+                       m_scr, l_scr, acc_scr, *, sm_scale: float,
+                       block_k: int, n_blocks: int, rank: int, n_q: int):
+    """One grid cell per batch row: the row's latent entries stream through
+    VMEM once (double-buffered, as :func:`_decode_stream_kernel`) and each
+    block is used twice: whole as the keys of all the heads' absorbed
+    queries, and its first ``rank`` columns as their values."""
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    pos = pos_ref[b]
+    hi = (pos + n_q - 1) // block_k
+
+    def copy(i, slot):
+        return pltpu.make_async_copy(
+            c_hbm.at[layer, b, 0, pl.ds(i * block_k, block_k)],
+            c_buf.at[slot], sems.at[slot])
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_BIG)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    copy(0, 0).start()
+    q = q_ref[0]
+
+    def body(i, _):
+        @pl.when(i <= hi)
+        def _live():
+            slot = jax.lax.rem(i, 2)
+
+            @pl.when(i + 1 <= hi)
+            def _prefetch():
+                copy(i + 1, jax.lax.rem(i + 1, 2)).start()
+
+            copy(i, slot).wait()
+            c = c_buf[slot]
+            _softmax_block_update(
+                q, c, c[:, :rank], i * block_k, pos, m_scr, l_scr, acc_scr,
+                sm_scale=sm_scale, window=None,
+                row_off=_row_offsets(q.shape[0], n_q))
+
+        return 0
+
+    jax.lax.fori_loop(0, n_blocks, body, 0)
+    o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+def mla_decode_attention_lax(q, latent, pos, *, rank: int, sm_scale: float,
+                             layer=0):
+    """:func:`mla_decode_attention` in plain lax (softmax in f32): what runs
+    where Pallas does not, and what the kernel is tested against."""
+    c = jax.lax.dynamic_index_in_dim(latent, layer, 0, keepdims=False)[:, 0]
+    s = jnp.einsum("bhcw,btw->bhct", q, c,
+                   preferred_element_type=jnp.float32) * sm_scale
+    qp = (jnp.asarray(pos, jnp.int32).reshape(-1)[:, None, None, None]
+          + jnp.arange(q.shape[2])[None, None, :, None])
+    s = jnp.where(jnp.arange(c.shape[1])[None, None, None, :] <= qp, s, NEG_BIG)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhct,btr->bhcr", p.astype(c.dtype), c[..., :rank])
+
+
+def mla_decode_attention(q, latent, pos, *, rank: int, sm_scale: float,
+                         layer=0, block_k: int = 512, interpret=None):
+    """Cached decode attention over a LATENT cache (multi-head latent
+    attention, absorbed form): every head shares one cached row a
+    position, ``[c_kv (rank) | k_pe]``, whose first ``rank`` values are
+    also the value.  q: ``[B, H, C, W]`` absorbed queries ``[q_nope W_UK |
+    q_pe]`` at positions ``pos[b] .. pos[b] + C - 1``; latent: the stacked
+    cache ``[L, B, 1, T, W]`` with ``layer`` a (traced) scalar, never
+    sliced; pos: scalar or ``[B]``.  Returns ``[B, H, C, rank]`` (the
+    caller applies ``W_UV``).  The H x C queries of a row are the rows of
+    one matmul against each block, so the cache is read once for all heads
+    and once for both uses.  T must be a multiple of 128
+    (:func:`_pick_block`); other lengths take the lax form."""
+    b, h, n_q, w = q.shape
+    t = latent.shape[3]
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    block_k = _pick_block(t, block_k, True)
+    if block_k is None:
+        return mla_decode_attention_lax(q, latent, pos, rank=rank,
+                                        sm_scale=sm_scale, layer=layer)
+    rows = h * n_q  # row r = head * C + ci (_row_offsets' layout)
+    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    out = pl.pallas_call(
+        functools.partial(_mla_stream_kernel, sm_scale=float(sm_scale),
+                          block_k=block_k, n_blocks=t // block_k, rank=rank,
+                          n_q=n_q),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, rows, w), lambda i, *_: (i, 0, 0)),
+                      pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+            out_specs=pl.BlockSpec((1, rows, rank), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, block_k, w), latent.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, rank), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, rows, rank), q.dtype),
+        interpret=interpret,
+        name="sw_mla_decode_attn",
+    )(pos_arr, jnp.asarray(layer, jnp.int32).reshape(1),
+      q.reshape(b, rows, w), latent)
+    return out.reshape(b, h, n_q, rank)
+
+
 # ------------------------------------------------------ the in-place write
 
 # VMEM one call's read-modify-write buffers may take; the update blocks

@@ -1,0 +1,136 @@
+"""Pallas TPU grouped matmul for a dropless mixture-of-experts FFN.
+
+The rows of ``x [M, K]`` are (token, choice) pairs laid out expert by
+expert, each expert's rows padded up to whole row tiles, so that a row
+tile belongs to exactly ONE expert (models/moe.py::group_rows builds the
+layout).  The kernel walks ``(row tile, column block)``; a row tile's
+expert reaches it as a prefetched scalar and picks the weight block in the
+DMA's source address, so only the experts that got a token are read, once
+a row tile, and nothing is padded to a capacity.  Row tiles past the last
+live one repeat the last live tile's block indices and skip their body:
+they move no bytes and do no work, so a step's cost follows the pairs that
+really landed here, not the worst case the static shape allows.
+
+Two forms under one name (``sw_moe_gmm``): ``x @ w[e]`` and, with a second
+weight, the gated pair ``silu(x @ w[e]) * (x @ w2[e])`` of a SwiGLU
+expert's first half, which reads the row tile once for both.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# The double-buffered weight blocks of the gated form at K = 7168 are more
+# than the compiler's default scoped limit (16 MiB) leaves room for.
+_VMEM_LIMIT_BYTES = 64 << 20
+_BLOCK_BYTES = 4 << 20   # one weight block [K, tn]
+
+
+def _gmm_kernel(tile_expert_ref, n_live_ref, layer_ref, x_ref, *refs,
+                gated: bool):
+    if gated:
+        w_ref, w2_ref, o_ref = refs
+    else:
+        w_ref, o_ref = refs
+
+    @pl.when(pl.program_id(0) < n_live_ref[0])
+    def _body():
+        x = x_ref[...]
+        y = jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
+        if gated:
+            y = jax.nn.silu(y) * jnp.dot(x, w2_ref[...],
+                                         preferred_element_type=jnp.float32)
+        o_ref[...] = y.astype(o_ref.dtype)
+
+
+def column_block(k: int, n: int, itemsize: int) -> int:
+    """Columns of one weight block: the widest multiple of 128 that divides
+    ``n`` and keeps ``[k, tn]`` under ``_BLOCK_BYTES`` (``n`` itself where
+    it has no such divisor: small test shapes)."""
+    fits = [c for c in range(128, n + 1, 128)
+            if n % c == 0 and k * c * itemsize <= _BLOCK_BYTES]
+    return max(fits) if fits else n
+
+
+def gmm_lax(x, w, tile_expert, n_live, tile_m: int, w2=None, layer=None):
+    """:func:`gmm` in plain lax: what runs where Pallas does not, and what
+    the kernel is tested against.  Rows of dead tiles come out zero."""
+    if layer is not None:
+        w, w2 = (None if a is None else jax.lax.dynamic_index_in_dim(
+            a, layer, 0, keepdims=False) for a in (w, w2))
+    sizes = jnp.zeros((w.shape[0],), jnp.int32).at[tile_expert].add(
+        jnp.where(jnp.arange(tile_expert.shape[0]) < n_live, tile_m, 0))
+    y = jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32)
+    if w2 is not None:
+        y = jax.nn.silu(y) * jax.lax.ragged_dot(
+            x, w2, sizes, preferred_element_type=jnp.float32)
+    return y.astype(x.dtype)
+
+
+def gmm(x, w, tile_expert, n_live, *, tile_m: int, w2=None, layer=None,
+        interpret=None):
+    """``out[r] = x[r] @ w[tile_expert[r // tile_m]]`` for the rows of the
+    first ``n_live`` row tiles; rows of later tiles are NOT written (the
+    caller never reads them).  With ``w2``: ``silu(x @ w[e]) * (x @ w2[e])``.
+
+    x: ``[M, K]``, M a multiple of ``tile_m``, rows grouped by expert in
+    whole tiles; w (and w2): ``[G, K, N]``, or every layer's stacked ``[L,
+    G, K, N]`` with ``layer`` a (traced) scalar: it reaches the kernel as a
+    prefetched scalar and indexes HBM, so a layer scan never slices its
+    experts out (352 MB a matrix a layer at Kimi-K2's widths, copied every
+    decode step otherwise: PERF.md, PR 26); tile_expert: ``[M // tile_m]``
+    int32, non-decreasing over the live tiles; n_live: scalar int32.
+    Returns ``[M, N]`` in x's dtype (f32 accumulation inside)."""
+    if layer is None:
+        w, w2, layer = w[None], None if w2 is None else w2[None], 0
+    m, k = x.shape
+    n = w.shape[3]
+    if m % tile_m:
+        raise ValueError(f"rows {m} are not whole tiles of {tile_m}")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    tn = column_block(k, n, w.dtype.itemsize)
+    n_col = n // tn
+    n_live = jnp.asarray(n_live, jnp.int32).reshape(1)
+
+    def live(i, j, n_live_ref):
+        """(row tile, column block) whose blocks step (i, j) holds: its
+        own while live, the last live step's after."""
+        on = i < n_live_ref[0]
+        last = jnp.maximum(n_live_ref[0] - 1, 0)
+        return jnp.where(on, i, last), jnp.where(on, j, n_col - 1)
+
+    def x_index(i, j, te, nl, la):
+        return (live(i, j, nl)[0], 0)
+
+    def w_index(i, j, te, nl, la):
+        ii, jj = live(i, j, nl)
+        return (la[0], te[ii], 0, jj)
+
+    def o_index(i, j, te, nl, la):
+        return live(i, j, nl)
+
+    w_spec = pl.BlockSpec((None, None, k, tn), w_index)
+    weights = (w,) if w2 is None else (w, w2)
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, gated=w2 is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(m // tile_m, n_col),
+            in_specs=[pl.BlockSpec((tile_m, k), x_index)]
+            + [w_spec] * len(weights),
+            out_specs=pl.BlockSpec((tile_m, tn), o_index),
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="sw_moe_gmm",
+    )(jnp.asarray(tile_expert, jnp.int32), n_live,
+      jnp.asarray(layer, jnp.int32).reshape(1), x, *weights)

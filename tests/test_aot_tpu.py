@@ -144,6 +144,55 @@ def _gemv(d_in, d_out, m=8):
         _s((m, d_in), BF16), _s((d_in, d_out), I8), _s((d_out,), F32))
 
 
+# Kimi-K2's published widths: 64 heads of 128 + 64 over a 512 + 64 latent
+# row, 7168 wide, experts 2048 wide, 12 held.
+K2_H, K2_RANK, K2_ROPE, K2_D, K2_FE, K2_HELD = 64, 512, 64, 7168, 2048, 12
+
+
+def _flash_latent(s=4096):
+    """Expanded latent attention: 192-wide q/k over 128-wide values."""
+    from starway_tpu.ops.pallas_attention import flash_attention
+
+    qk = _s((1, K2_H, s, 128 + K2_ROPE), BF16)
+    return (lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=False, sm_scale=0.13)), (
+            qk, qk, _s((1, K2_H, s, 128), BF16))
+
+
+def _mla_decode(c=1, b=16, t=5120, layers=2):
+    from starway_tpu.ops.pallas_decode import mla_decode_attention
+
+    w = 640  # 512 + 64 in whole lane tiles (LatentAttn.cache_width)
+    return (lambda q, latent, pos, layer: mla_decode_attention(
+        q, latent, pos, rank=K2_RANK, sm_scale=0.13, layer=layer,
+        interpret=False)), (
+            _s((b, K2_H, c, w), BF16), _s((layers, b, 1, t, w), BF16),
+            _s((b,), I32), _s((), I32))
+
+
+def _latent_write(b=16, t=5120, layers=2):
+    from starway_tpu.ops.pallas_decode import kv_write
+
+    w = 640  # 512 + 64 in whole lane tiles (LatentAttn.cache_width)
+    return (lambda cache, new, layer, rows, pos: kv_write(
+        (cache,), (new,), layer, rows, pos, interpret=False)), (
+            _s((layers, b, 1, t, w), BF16), _s((b, 1, 1, w), BF16),
+            _s((), I32), _s((b,), I32), _s((b,), I32))
+
+
+def _gmm(pairs, tile_m, k, n, gated):
+    from starway_tpu.ops.pallas_gmm import gmm
+
+    m = pairs + K2_HELD * tile_m
+    w = _s((K2_HELD, k, n), BF16)
+    args = (_s((m, k), BF16), w, _s((m // tile_m,), I32), _s((), I32))
+    if gated:
+        return (lambda x, w, te, nl, w2: gmm(
+            x, w, te, nl, tile_m=tile_m, w2=w2, interpret=False)), args + (w,)
+    return (lambda x, w, te, nl: gmm(
+        x, w, te, nl, tile_m=tile_m, interpret=False)), args
+
+
 KERNELS = {
     "flash_fwd": lambda: _flash(HKV),
     "flash_fwd_windowed": lambda: _flash(HKV, window=1024),
@@ -180,6 +229,15 @@ KERNELS = {
     "gemv_gate_up": lambda: _gemv(4096, 14336),
     "gemv_down": lambda: _gemv(14336, 4096),
     "gemv_lm_head": lambda: _gemv(4096, 128256),
+    "flash_fwd_latent": lambda: _flash_latent(),
+    "mla_decode": lambda: _mla_decode(),
+    "mla_decode_c4": lambda: _mla_decode(c=4),
+    "latent_write": lambda: _latent_write(),
+    # 128 slots x 8 choices a decode step; a 4096-token admit.
+    "gmm_gated_decode": lambda: _gmm(1024, 16, K2_D, K2_FE, True),
+    "gmm_down_decode": lambda: _gmm(1024, 16, K2_FE, K2_D, False),
+    "gmm_gated_admit": lambda: _gmm(32768, 128, K2_D, K2_FE, True),
+    "gmm_down_admit": lambda: _gmm(32768, 128, K2_FE, K2_D, False),
 }
 
 

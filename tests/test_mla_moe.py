@@ -1,0 +1,381 @@
+"""Latent attention (MLA) and the sigmoid-routed dropless expert layer (the
+DeepSeek-V3 / Kimi-K2 block) against the benchmark's plain reference
+(benchmark/configs/kimi-k2_reference.py: float32, HIGHEST, expanded
+attention, every held expert on every token), at tiny widths with seeded
+weights; and the kernels in interpret mode against their lax forms."""
+
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark.harness import spec as S
+from benchmark.harness import weights_mla_moe as W
+
+TINY = {
+    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 4,
+    "q_lora_rank": 24, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "intermediate_size": 96,
+    "moe_intermediate_size": 32, "n_routed_experts": 16,
+    "n_routed_experts_published": 16, "num_experts_per_tok": 4,
+    "n_shared_experts": 1, "routed_scaling_factor": 2.827,
+    "first_k_dense_replace": 1, "vocab_size": 128, "num_hidden_layers": 3,
+    "rms_norm_eps": 1e-6, "rope_theta": 50000,
+    "rope_scaling": {"beta_fast": 1, "beta_slow": 1, "factor": 32, "mscale": 1,
+                     "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 16, "type": "yarn"},
+    "torch_dtype": "float32",
+}
+SEED = 1234
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return S.load_reference("kimi-k2")
+
+
+@pytest.fixture(scope="module")
+def runner():
+    return S.load_runner("serve_mla_moe")
+
+
+@pytest.fixture(autouse=True)
+def _highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _model(runner, config=TINY, seed=SEED):
+    return (runner.program_tree(W.make_model(seed, W.dims(config))),
+            runner.model_config(config))
+
+
+def _tokens(n, s, seed=0):
+    return np.random.default_rng(seed).integers(
+        1, TINY["vocab_size"], (n, s)).astype(np.int32)
+
+
+def test_forward_matches_reference(ref, runner):
+    from starway_tpu.models import forward
+
+    params, cfg = _model(runner)
+    toks = _tokens(2, 24)
+    got = forward(params, jnp.asarray(toks), cfg)
+    want = ref.full_logits(TINY, SEED, toks)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def test_prefill_then_cached_decode_matches_reference(ref, runner):
+    """Expanded prefill fills the latent cache; absorbed decode steps read
+    it: every step's logits equal the reference's full forward."""
+    from starway_tpu.models.generate import decode_step, prefill
+
+    params, cfg = _model(runner)
+    toks = _tokens(2, 20, seed=1)
+    want = np.asarray(ref.full_logits(TINY, SEED, toks))
+    p0 = 9
+    logits, cache = prefill(params, cfg, jnp.asarray(toks[:, :p0]), 32)
+    assert set(cache) == {"ckv"} and cache["ckv"].shape == (3, 2, 1, 32, 128)
+    np.testing.assert_allclose(logits, want[:, p0 - 1], rtol=2e-4, atol=2e-4)
+    for p in range(p0, toks.shape[1]):
+        logits, cache = decode_step(params, cache, jnp.asarray(toks[:, p]),
+                                    jnp.full((2,), p, jnp.int32), cfg)
+        np.testing.assert_allclose(logits, want[:, p], rtol=2e-4, atol=2e-4)
+
+
+def test_generate_greedy_is_the_references_argmax(ref, runner):
+    from starway_tpu.models import generate
+
+    params, cfg = _model(runner)
+    prompt = _tokens(2, 7, seed=2)
+    out = np.asarray(generate(params, cfg, jnp.asarray(prompt), 9))
+    want = np.asarray(ref.full_logits(TINY, SEED, out))
+    np.testing.assert_array_equal(out[:, 7:], want[:, 6:-1].argmax(-1))
+
+
+def test_slot_server_ragged_slots_match_reference(ref, runner):
+    """Continuous batching with ragged prompts, more requests than slots:
+    every served token is the reference's best at its position (gap 0 up
+    to float32 rounding), teacher-forced: the comparison ``correct`` makes."""
+    from starway_tpu.models import SlotServer, serving
+
+    params, cfg = _model(runner)
+    srv = SlotServer(params, cfg, n_slots=3, max_len=64, chunk=4)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 128, n).astype(np.int32)
+               for n in (5, 17, 9, 30, 3)]
+    wants = [6, 11, 4, 9, 13]
+    rids = [srv.submit(p, m) for p, m in zip(prompts, wants)]
+    done = srv.run()
+    sample = [(p, done[r]) for p, r in zip(prompts, rids)]
+    assert [len(done[r]) for r in rids] == wants
+    got = ref.served_gaps(TINY, SEED, sample, 64, 16)
+    assert got["finite"] and got["gap_max"] < 1e-5, got
+    rows = [r for r in serving.step_log() if r["server"] == srv.server_id]
+    decoded = [r for r in rows if "moe_assign" in r]
+    assert decoded and all(
+        0 < r["moe_touched"] <= 16 and 0 < r["moe_max"] <= 3
+        # every pair lands on a held expert when all are held: 3 slots x
+        # 4 choices x 2 routed layers x 4 steps
+        and r["moe_assign"] == 3 * 4 * 2 * 4 for r in decoded)
+
+
+def test_dense_model_step_log_has_no_moe_fields():
+    from starway_tpu.models import LlamaConfig, SlotServer, init_params, serving
+
+    cfg = LlamaConfig.preset("debug", n_layers=1)
+    srv = SlotServer(init_params(jax.random.PRNGKey(0), cfg), cfg, n_slots=2,
+                     max_len=32, chunk=2)
+    srv.submit(np.arange(1, 5), 3)
+    srv.run()
+    rows = [r for r in serving.step_log() if r["server"] == srv.server_id]
+    assert rows and not any("moe_assign" in r for r in rows)
+
+
+def test_absorbed_equals_expanded(runner):
+    """The two forms of latent attention are one bilinear form regrouped."""
+    from starway_tpu.models import mla
+    from starway_tpu.models.llama import cfg_rope_tables, default_attn
+    from starway_tpu.ops.pallas_decode import mla_decode_attention_lax
+
+    params, cfg = _model(runner)
+    lp = jax.tree_util.tree_map(lambda a: a[0], params["layers"][1])
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 12, 64))
+    cos, sin = cfg_rope_tables(cfg, 12)
+    q, k, v, rows = mla.project_expanded(x, lp, cfg, cos, sin)
+    want = default_attn(q, k, v, sm_scale=cfg.latent.sm_scale)
+    qa, rows_a = mla.project_absorbed(x, lp, cfg, cos, sin)
+    np.testing.assert_allclose(rows_a, rows, rtol=1e-6, atol=1e-6)
+    o_lat = mla_decode_attention_lax(qa, rows[None], jnp.zeros((2,), jnp.int32),
+                                     rank=32, sm_scale=cfg.latent.sm_scale)
+    got = mla.expand_values(o_lat, lp, cfg)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_router_bias_changes_the_choice_not_the_gate():
+    from starway_tpu.models.moe import sigmoid_route
+
+    x = jnp.eye(4, dtype=jnp.float32)[:1] * 2.0          # one token
+    w = jnp.asarray([[2.0, 1.0, 0.5, -1.0], [0] * 4, [0] * 4, [0] * 4]) / 2.0
+    s = jax.nn.sigmoid(jnp.asarray([2.0, 1.0, 0.5, -1.0]))
+    idx, g = sigmoid_route(x, w, jnp.zeros(4), 2, 2.5)
+    assert sorted(idx[0].tolist()) == [0, 1]
+    # A bias lifts expert 3 over expert 1: it is chosen, and its gate is
+    # its own (small) score over the chosen scores' sum, bias left out.
+    idx, g = sigmoid_route(x, w, jnp.asarray([0.0, 0.0, 0.0, 0.6]), 2, 2.5)
+    assert idx[0].tolist() == [0, 3]
+    np.testing.assert_allclose(
+        g[0], 2.5 * np.asarray([s[0], s[3]]) / (s[0] + s[3]), rtol=1e-6)
+
+
+@pytest.mark.parametrize("share", range(4))
+def test_share_holds_its_experts_numbers(share):
+    """Share ``i`` of four holds experts 4i..4i+3 of the uncut layer."""
+    whole = W.layer_weights(W.base_key(SEED), 1, W.dims(TINY), True)["routed"]
+    part = W.layer_weights(
+        W.base_key(SEED), 1,
+        W.dims(dict(TINY, n_routed_experts=4, expert_share=share)),
+        True)["routed"]
+    for n in ("w_gate", "w_up", "w_down"):
+        np.testing.assert_array_equal(part[n], whole[n][4 * share:4 * share + 4])
+    np.testing.assert_array_equal(part["router"], whole["router"])
+
+
+def test_shares_add_up_to_the_uncut_layer(ref, runner):
+    """model-configs guide, section 4: the routed parts that the shares
+    give, with the shared expert counted once, add up to what the uncut
+    reference gives for the whole layer.  Program and reference alike."""
+    from starway_tpu.models.llama import ffn_block
+
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 9, 64))
+    whole = W.layer_weights(W.base_key(SEED), 1, W.dims(TINY), True)
+    want = (ref.routed_part(x.reshape(-1, 64), whole["routed"], W.dims(TINY))
+            + ref._swiglu(x.reshape(-1, 64), whole["routed"]["shared"], None))
+    total_prog = total_ref = 0.0
+    pairs = 0
+    for share in range(4):
+        config = dict(TINY, n_routed_experts=4, expert_share=share)
+        d, cfg = W.dims(config), runner.model_config(config)
+        w = W.layer_weights(W.base_key(SEED), 1, d, True)
+        total_ref += ref.routed_part(x.reshape(-1, 64), w["routed"], d)
+        y, _aux, sizes = ffn_block(x, w, cfg)
+        shared = ref._swiglu(x.reshape(-1, 64), w["routed"]["shared"], None)
+        total_prog += y.reshape(-1, 64) - shared
+        pairs += int(sizes.sum())
+    assert pairs == 2 * 9 * 4   # every (token, choice) pair landed once
+    np.testing.assert_allclose(total_ref + shared, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(total_prog + shared, want, rtol=1e-4, atol=1e-4)
+
+
+def test_mla_decode_kernel_matches_lax():
+    from starway_tpu.ops.pallas_decode import (mla_decode_attention,
+                                               mla_decode_attention_lax)
+
+    k = jax.random.split(jax.random.PRNGKey(11), 2)
+    L, B, H, T, r, w = 2, 3, 8, 256, 128, 160
+    latent = jax.random.normal(k[0], (L, B, 1, T, w), jnp.float32)
+    pos = jnp.asarray([0, 129, 255], jnp.int32)
+    for c in (1, 2):
+        q = jax.random.normal(k[1], (B, H, c, w), jnp.float32)
+        p = jnp.minimum(pos, T - c)
+        got = mla_decode_attention(q, latent, p, rank=r, sm_scale=0.11,
+                                   layer=1, block_k=128, interpret=True)
+        want = mla_decode_attention_lax(q, latent, p, rank=r, sm_scale=0.11,
+                                        layer=1)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("gated", [False, True])
+def test_gmm_kernel_matches_lax(gated):
+    from starway_tpu.models.moe import group_rows
+    from starway_tpu.ops.pallas_gmm import gmm, gmm_lax
+
+    rng = np.random.default_rng(13)
+    G, K, N, tm = 5, 64, 256, 8
+    local = jnp.asarray(rng.integers(-3, G + 2, 90), jnp.int32)  # some not held
+    src, row, tile_expert, n_live, sizes = group_rows(local, G, tm)
+    assert int(sizes.sum()) == int(((local >= 0) & (local < G)).sum())
+    x = jnp.asarray(rng.normal(size=(src.shape[0], K)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(G, K, N)), jnp.float32)
+    w2 = jnp.asarray(rng.normal(size=(G, K, N)), jnp.float32) if gated else None
+    got = gmm(x, w, tile_expert, n_live, tile_m=tm, w2=w2, interpret=True)
+    want = gmm_lax(x, w, tile_expert, n_live, tm, w2=w2)
+    live = int(n_live) * tm
+    np.testing.assert_allclose(got[:live], want[:live], rtol=1e-4, atol=1e-4)
+    # Each held pair's row lies in its expert's tiles.
+    e_of_row = np.repeat(np.asarray(tile_expert), tm)
+    held = np.asarray((local >= 0) & (local < G))
+    assert (e_of_row[np.asarray(row)[held]] == np.asarray(local)[held]).all()
+    assert (np.asarray(row)[~held] == src.shape[0]).all()
+
+
+def test_routed_experts_pallas_path_matches_lax(runner):
+    from starway_tpu.models.moe import routed_experts, sigmoid_route
+
+    w = W.layer_weights(W.base_key(SEED), 1, W.dims(
+        dict(TINY, n_routed_experts=4, expert_share=2)), True)["routed"]
+    x = jax.random.normal(jax.random.PRNGKey(17), (21, 64))
+    idx, g = sigmoid_route(x, w["router"], w["bias"], 4, 2.827)
+    a, sa = routed_experts(x, idx, g, w, 8, use_pallas=True)
+    b, sb = routed_experts(x, idx, g, w, 8, use_pallas=False)
+    np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(sa, sb)
+
+
+def _hf_state(params, cfg):
+    """A program tree written out under the published checkpoint's keys
+    ([out, in] matrices, interleaved rope rows): the inverse of the map
+    ``hf_convert`` applies."""
+    la, r, H = cfg.latent, cfg.routed, cfg.n_heads
+    order = np.concatenate([np.arange(0, la.rope_dim, 2),
+                            np.arange(1, la.rope_dim, 2)])
+    back = np.argsort(order)
+
+    def interleave(w, start):
+        w = w.copy()
+        w[start:start + la.rope_dim] = w[start + back]
+        return w
+
+    state = {"model.embed_tokens.weight": np.asarray(params["embed"]),
+             "model.norm.weight": np.asarray(params["final_norm"]),
+             "lm_head.weight": np.asarray(params["lm_head"]).T}
+    i = 0
+    for seg in params["layers"]:
+        for j in range(seg["wo"].shape[0]):
+            lp = jax.tree_util.tree_map(lambda a: np.asarray(a[j]), seg)
+            pre = f"model.layers.{i}."
+            wq_b = lp["wq_b"].T
+            for h in range(H):
+                wq_b = interleave(wq_b, h * (la.nope_dim + la.rope_dim) + la.nope_dim)
+            state.update({
+                pre + "input_layernorm.weight": lp["attn_norm"],
+                pre + "post_attention_layernorm.weight": lp["mlp_norm"],
+                pre + "self_attn.q_a_proj.weight": lp["wq_a"].T,
+                pre + "self_attn.q_a_layernorm.weight": lp["q_norm"],
+                pre + "self_attn.q_b_proj.weight": wq_b,
+                pre + "self_attn.kv_a_proj_with_mqa.weight":
+                    interleave(lp["wkv_a"].T, la.kv_rank),
+                pre + "self_attn.kv_a_layernorm.weight": lp["kv_norm"],
+                pre + "self_attn.kv_b_proj.weight": lp["wkv_b"].T,
+                pre + "self_attn.o_proj.weight": lp["wo"].T})
+
+            def mlp(name, w):
+                for ours, theirs in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                                     ("w_down", "down_proj")):
+                    state[pre + f"{name}.{theirs}.weight"] = w[ours].T
+
+            if "routed" in lp:
+                rp = lp["routed"]
+                state[pre + "mlp.gate.weight"] = rp["router"].T
+                state[pre + "mlp.gate.e_score_correction_bias"] = rp["bias"]
+                mlp("mlp.shared_experts", rp["shared"])
+                for e in range(r.n_held):
+                    mlp(f"mlp.experts.{r.first_held + e}",
+                        {n: rp[n][e] for n in ("w_gate", "w_up", "w_down")})
+            else:
+                mlp("mlp", lp)
+            i += 1
+    return state
+
+
+def test_hf_key_map_round_trip(runner):
+    """``kimi_k2`` keys -> the segmented tree and back, the held share's
+    experts only, with the rope rows' interleaved-to-halves permutation."""
+    from types import SimpleNamespace
+
+    from starway_tpu.models import config_from_hf, params_from_hf
+    from starway_tpu.models.hf_convert import rope_rows_to_halves
+
+    config = dict(TINY, n_routed_experts=4, expert_share=2)
+    params, cfg = _model(runner, config)
+    hf = SimpleNamespace(**dict(
+        TINY, model_type="kimi_k2", scoring_func="sigmoid", n_group=1,
+        topk_group=1, norm_topk_prob=True, moe_layer_freq=1,
+        max_position_embeddings=64, hidden_act="silu"))
+    got_cfg = config_from_hf(hf, held_experts=(8, 4), dtype="float32")
+    assert got_cfg == cfg
+    back = params_from_hf(_hf_state(params, cfg), got_cfg)
+    assert jax.tree_util.tree_structure(back) == jax.tree_util.tree_structure(params)
+    for a, b in zip(jax.tree_util.tree_leaves(back),
+                    jax.tree_util.tree_leaves(params)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+    # What the permutation is for: the published rotation of interleaved
+    # pairs, then put into halves, is this package's split-half rotation
+    # of the permuted rows.
+    x = np.arange(8.0)[:, None] + 1
+    c, s = np.cos(0.3 * np.arange(4)), np.sin(0.3 * np.arange(4))
+    pairs = np.stack([x[0::2, 0] * c - x[1::2, 0] * s,
+                      x[0::2, 0] * s + x[1::2, 0] * c])        # [2, 4]
+    halves = rope_rows_to_halves(x, 0, 8)[:, 0]
+    ours = np.concatenate([halves[:4] * c - halves[4:] * s,
+                           halves[:4] * s + halves[4:] * c])
+    np.testing.assert_allclose(ours, pairs.reshape(-1))
+
+
+def test_paged_server_refuses_a_latent_model(runner):
+    from starway_tpu.models import PagedSlotServer
+
+    params, cfg = _model(runner)
+    with pytest.raises(NotImplementedError, match="latent page kind"):
+        PagedSlotServer(params, cfg, n_slots=2, max_len=64, page=16)
+
+
+def test_prefix_admission_reads_the_latent_cache(ref, runner):
+    """A registered prefix and a suffix ingested through the chunk step
+    (C > 1 absorbed queries) serve what the whole prompt serves."""
+    from starway_tpu.models import SlotServer
+
+    params, cfg = _model(runner)
+    rng = np.random.default_rng(23)
+    prefix = rng.integers(1, 128, 20).astype(np.int32)
+    suffix = rng.integers(1, 128, 7).astype(np.int32)
+    srv = SlotServer(params, cfg, n_slots=2, max_len=96, chunk=4)
+    pid = srv.register_prefix(prefix)
+    rid = srv.submit(suffix, 6, prefix=pid)
+    got = srv.run()[rid]
+    gaps = ref.served_gaps(TINY, SEED, [(np.concatenate([prefix, suffix]), got)],
+                           96, 8)
+    assert gaps["gap_max"] < 1e-5, gaps

@@ -159,8 +159,9 @@ def test_unrolled_return_kv_matches_scanned():
     cfg_s = _tiny_cfg(dtype="float32")
     cfg_u = _tiny_cfg(dtype="float32", scan_layers=False)
     params = init_params(jax.random.PRNGKey(5), cfg_s)
-    _, (k_s, v_s) = forward(params, tokens, cfg_s, return_kv=True)
-    _, (k_u, v_u) = forward(params, tokens, cfg_u, return_kv=True)
+    _, kv_s = forward(params, tokens, cfg_s, return_kv=True)
+    _, kv_u = forward(params, tokens, cfg_u, return_kv=True)
+    (k_s, v_s), (k_u, v_u) = (kv_s["k"], kv_s["v"]), (kv_u["k"], kv_u["v"])
     np.testing.assert_allclose(np.asarray(k_s), np.asarray(k_u),
                                atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(v_s), np.asarray(v_u),

@@ -474,6 +474,28 @@ def test_request_log_stamps(cfg, params, scenario):
     assert not srv._rows          # nothing open is left behind
 
 
+def test_a_step_admits_the_queues_first_the_longest_budget_first(cfg, params):
+    """WHO is admitted is the queue's order; among those a step admits,
+    the request with most tokens to produce goes first (each admission
+    stalls those before it: PERF.md, PR 26).  Every request's tokens are
+    what it would have got alone."""
+    srv = SlotServer(params, cfg, n_slots=2, max_len=64, chunk=3)
+    a = srv.submit([4, 2, 8], 3)
+    b = srv.submit([7, 7], 9)
+    c = srv.submit([5, 1], 30)      # the longest, and not of this step's two
+    d = srv.submit([9, 1, 5], 9)    # a tie with b: the earlier goes first
+    done = srv.step()
+    rows = _rows_of(srv)
+    assert rows[b]["t_admit0"] < rows[a]["t_admit0"]
+    assert rows[c]["t_admit0"] is None and rows[d]["t_admit0"] is None
+    done.update(srv.run())
+    rows = _rows_of(srv)
+    assert rows[c]["t_admit0"] < rows[d]["t_admit0"]
+    for rid, prompt, n in ((a, [4, 2, 8], 3), (b, [7, 7], 9),
+                           (c, [5, 1], 30), (d, [9, 1, 5], 9)):
+        np.testing.assert_array_equal(done[rid], _oracle(params, cfg, prompt, n))
+
+
 def test_request_log_rejected_request(cfg, params):
     from starway_tpu.models import serving
 
