@@ -463,17 +463,20 @@ class PagedSlotServer(SlotServer):
         self._finish_admit(slot, rid, tok, plen + len(suffix), max_new)
 
     # ------------------------------------------------------------ decode
-    def _run_chunk(self, sub):
+    def _launch_chunk(self, sub):
         # Lazy growth: every live slot needs pages covering its cursor's
-        # reach this chunk (writes go through table[pos // page]).
-        live = np.asarray(self.live)
-        pos = np.asarray(self.pos)
-        for slot in range(self.n_slots):
-            if live[slot]:
+        # reach this chunk (writes go through table[pos // page]).  From
+        # the host's own copy of live/pos (the last chunk's, and this
+        # step's admissions): a request whose first token will turn out to
+        # be its eos gets its pages too, and returns them when it is
+        # harvested.
+        for slot in sorted(self._slot_rid):
+            if self._live_host[slot]:
                 # The chunk writes positions pos .. pos+chunk-1 (reads
                 # only written positions), so the last page touched is
                 # (pos+chunk-1) // page.
-                reach = min(int(pos[slot]) + self.chunk, self.max_len)
+                reach = min(int(self._pos_host[slot]) + self.chunk,
+                            self.max_len)
                 self._alloc_to(slot, -(-reach // self.page))
         run = _compiled_paged_chunk(self.cfg, self.max_len, self.chunk,
                                     *self.sampling, self.eos_id)

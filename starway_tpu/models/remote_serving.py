@@ -51,7 +51,8 @@ recv_submit      REQUEST receive completed -> ``SlotServer.submit`` (the
                  bridge's queue: a ``step()`` was running in the executor)
 submit_admit0    submit -> the request's admission begins (the
                  scheduler's queue: no slot was free, or a chunk ran)
-admit0_first     admission begins -> its first token is a host int
+admit0_first     admission begins -> its first token is on the host
+                 (with those of every request its step admitted)
 first_post       first token -> its TOKENS send is posted (the bridge
                  sends after ``step()`` returns: the rest of the chunk)
 recv_done_post   REQUEST receive completed -> this frame is posted

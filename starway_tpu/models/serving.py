@@ -25,6 +25,13 @@ a FIXED, small set of compiled programs:
   land on a position that admission or the advancing cursor overwrites
   before any read).  Host round-trips happen once per chunk, not once
   per token.
+* **A step queues, then fetches.**  ``step()`` launches every admission
+  it makes and then the chunk, back to back, with no device value read in
+  between: an admit program's sampled token stays on the device, where
+  ``serve_seat`` files it and the slot's cursor, budget and liveness into
+  the slot state.  Two blocking reads follow, however many requests were
+  admitted: the step's first tokens (ready when the last admit program
+  has run, the chunk already queued behind it) and the chunk's result.
 * **Greedy continuous batching is BIT-IDENTICAL to standalone
   ``generate()``** for every request, whatever the interleaving: same
   prefill, same decode step, same masking — pinned by
@@ -97,7 +104,8 @@ def request_log() -> list:
     rolling path), ``n_out``, ``step0`` (this server's ``step()`` count at
     its admission), ``steps`` (``step()`` calls it lived through) and the
     stamps ``t_submit``, ``t_admit0`` (its admission begins),
-    ``t_first`` (its first token is a host int), ``t_done``; ``status`` is
+    ``t_first`` (its first token is on the host: one instant for all a
+    step admits), ``t_done``; ``status`` is
     ``queued`` / ``running`` until it ends as ``done`` / ``cancelled`` /
     ``rejected`` (a rejected row has no rid).  Behind the transport bridge
     (models/remote_serving.py) the same row also carries ``route``
@@ -114,18 +122,24 @@ def step_log() -> list:
     """The last ``LOG_ROWS`` ``SlotServer.step()`` calls of this process,
     oldest first: ``server``, ``n_slots``, ``t0``, ``t1``, ``queued`` and
     ``live`` (queue depth and occupied slots when the decode chunk was
-    dispatched), ``admits`` and ``admit_s`` (admissions of this step and
-    their seconds), ``dispatch_s`` (inside ``_run_chunk``), ``wait_s`` (the
-    chunk's result copied to the host: the wait the loop always had),
-    ``harvest_s`` (from there until the step returns, ``on_tokens`` calls
-    included).  The four durations leave out the Python between them, so
-    they sum to at most ``t1 - t0``.  A model with a routed FFN
-    (``cfg.routed``) adds, for the chunk's decode steps and every slot's
-    row in them: ``moe_assign`` ((token, choice) pairs that landed on held
-    experts, all routed layers), ``moe_touched`` (held experts with at
-    least one pair, mean over layers and steps) and ``moe_max`` (the most
-    pairs one expert got in one layer of one step); other models' rows
-    carry none of the three."""
+    dispatched), ``admits`` (admissions of this step) and ``admit_s`` (the
+    seconds in which every lane stalls for them: from ``t0``, when the
+    device is empty and the first admission begins, until the step's first
+    tokens are on the host; 0 in a step that admits nothing),
+    ``dispatch_s`` (launching the chunk program; in a step that admits it
+    lies inside ``admit_s``, the chunk being queued behind the admissions
+    before their tokens are fetched), ``wait_s`` (the chunk's result
+    copied to the host: the wait the loop always had), ``harvest_s`` (from
+    there until the step returns, those ``on_tokens`` calls included) and
+    ``fetches`` (blocking device-to-host reads the step made: at most two,
+    the first tokens and the chunk's result, however many it admitted).
+    The durations leave out the Python between them.  A model with a
+    routed FFN (``cfg.routed``) adds, for the chunk's decode steps and
+    every slot's row in them: ``moe_assign`` ((token, choice) pairs that
+    landed on held experts, all routed layers), ``moe_touched`` (held
+    experts with at least one pair, mean over layers and steps) and
+    ``moe_max`` (the most pairs one expert got in one layer of one step);
+    other models' rows carry none of the three."""
     return [dict(row) for row in list(_step_log)]
 
 
@@ -171,6 +185,22 @@ def _write_slot_and_sample(cache, small, logits, slot, key, temperature,
     }
     tok = _sample(logits, key, temperature, top_k, top_p)[0]
     return cache, tok
+
+
+def _seat(token, pos, live, remaining, tok, seat):
+    """An admission's slot state, set on the device from the admit
+    program's own sampled token ``tok``: ``seat`` is ``[slot, cursor,
+    max_new, eos id or -1]``.  One shared program behind every admit
+    program (dense, prefix, rolling, paged), so the host never reads a
+    first token in order to seat its request."""
+    slot, cursor, max_new, eos = seat[0], seat[1], seat[2], seat[3]
+    done = (max_new == 1) | (tok == eos)
+    return (token.at[slot].set(tok), pos.at[slot].set(cursor),
+            live.at[slot].set(~done), remaining.at[slot].set(max_new - 1))
+
+
+# Not ``serve_admit*``: a trace reduction takes those for the prefills.
+_seat = _named_jit(_seat, "serve_seat")
 
 
 @functools.cache
@@ -397,6 +427,16 @@ class SlotServer:
     ``run()`` loops until everything queued has finished.  Generated
     tokens INCLUDE the terminating eos (when ``eos_id`` fires).
 
+    STREAMING: ``on_tokens(rid, tokens, done)`` fires inside ``step()``.
+    A step launches its admissions and its chunk first and reads the
+    device afterwards, so the first tokens of everything it admitted
+    arrive together, in admission order, once the last admit program has
+    run; each request's chunk tokens follow when the chunk has, and
+    ``([], True)`` exactly once when the request finishes.  A request
+    ``cancel()``led from a first-token callback has by then a chunk in
+    flight: that chunk decodes its slot once more and the tokens are
+    thrown away, never delivered.
+
     PREFIX CACHING: ``register_prefix(tokens)`` prefills a shared prefix
     (system prompt, few-shot preamble) once; ``submit(suffix,
     prefix=pid)`` requests then admit by copying the prefix's cache rows
@@ -467,19 +507,25 @@ class SlotServer:
         self.pos = jnp.zeros((n_slots,), jnp.int32)
         self.live = jnp.zeros((n_slots,), bool)
         self.remaining = jnp.zeros((n_slots,), jnp.int32)
-        self._pairs = None  # the last chunk's third output (_run_chunk)
+        self._pairs = None  # the last chunk's third output (_launch_chunk)
+        # What the host knows of ``live`` and ``pos`` without asking the
+        # device: the last chunk's final values (fetched with its tokens),
+        # then each admission's cursor and whether it can outlive its first
+        # token.  Only the entries of occupied slots mean anything.
+        self._live_host = np.zeros((n_slots,), bool)
+        self._pos_host = np.zeros((n_slots,), np.int32)
+        # Admitted, first token still on the device: (slot, rid).
+        self._firsts: list = []
+        self._step: dict = {}  # the step_log() row of the step under way
 
         self._next_rid = 0
         self._pending: deque = deque()
         self._slot_rid: dict[int, int] = {}
         self._collected: dict[int, list] = {}
         self._prefixes: dict[int, tuple] = {}  # pid -> (small, plen)
-        # Streaming hook: ``on_tokens(rid, tokens, done)`` fires inside
-        # step() — once per request per step with that step's new tokens
-        # (done=False), and exactly once with ``([], True)`` when the
-        # request finishes.  The transport bridge
-        # (models/remote_serving.py) rides this to stream tokens over the
-        # wire without waiting for full completion.
+        # Streaming hook (the class docstring says when it fires).  The
+        # transport bridge (models/remote_serving.py) rides this to stream
+        # tokens over the wire without waiting for full completion.
         self.on_tokens = on_tokens
         # The serve scope (DESIGN.md §13): this server's rows of the module
         # logs while their requests are open, its phase accumulator, and --
@@ -672,28 +718,57 @@ class SlotServer:
     def _finish_admit(self, slot: int, rid: int, tok, cursor: int,
                       max_new: int) -> None:
         """Shared tail of every admission path (dense, prefix, rolling,
-        paged): record the first token, fire the streaming hook, and set
-        the slot's cursor/liveness/budget."""
-        tok_host = int(tok)
+        paged): seat the request in its slot, on the device, from the admit
+        program's own token ``tok``, and note that its first token is owed.
+        Never reads the device: ``step()`` fetches the first tokens of all
+        it admitted at once (:meth:`_hand_out_firsts`), and ``on_tokens``
+        fires there."""
+        eos = -1 if self.eos_id is None else self.eos_id
+        self.token, self.pos, self.live, self.remaining = _seat(
+            self.token, self.pos, self.live, self.remaining, tok,
+            jnp.asarray([slot, cursor, max_new, eos], jnp.int32))
         row = self._rows.get(rid)
         if row is not None:
-            row.update(t_first=_now(), status="running",
-                       step0=self._n_steps)
+            row.update(status="running", step0=self._n_steps)
         self._slot_rid[slot] = rid
-        self._collected[rid] = [tok_host]
-        if self.on_tokens is not None:
-            self.on_tokens(rid, [tok_host], False)
+        self._collected[rid] = []
+        self._pos_host[slot] = cursor
+        self._live_host[slot] = max_new > 1  # eos: once the token is here
+        self._firsts.append((slot, rid))
+
+    def _fetch(self, tree):
+        """THE blocking device-to-host read of the serve loop: everything
+        ``step()`` learns from the device comes through here, counted in
+        the step's ``fetches``."""
+        self._step["fetches"] += 1
+        return jax.device_get(tree)
+
+    def _hand_out_firsts(self, seated) -> None:
+        """The first tokens of everything admitted since the last call, in
+        one read of ``seated``: ``self.token`` as the last admission left
+        it, where every seated token sits at its slot until a chunk moves
+        it (that chunk may be queued already).  One stamp for all, then
+        ``on_tokens`` in admission order."""
+        if not self._firsts:
+            return
+        firsts, self._firsts = self._firsts, []
+        with perf.stage_span(self.stage_scope, "serve.first_wait"):
+            tokens = self._fetch(seated)
+        step = self._step
+        step["admit_s"] = _now() - step["t0"]
+        t_first = step["t0"] + step["admit_s"]  # as step_log()'s readers add
+        for slot, rid in firsts:
             if rid not in self._collected:
-                # The callback cancel()ed this very request; writing the
-                # slot state below would resurrect it as an unrouted
-                # zombie that decodes garbage until slot reuse.
-                return
-        done = (max_new == 1 or
-                (self.eos_id is not None and tok_host == self.eos_id))
-        self.token = self.token.at[slot].set(tok_host)
-        self.pos = self.pos.at[slot].set(cursor)
-        self.live = self.live.at[slot].set(not done)
-        self.remaining = self.remaining.at[slot].set(max_new - 1)
+                continue  # cancelled by an earlier first-token callback
+            tok = int(tokens[slot])
+            row = self._rows.get(rid)
+            if row is not None:
+                row["t_first"] = t_first
+            self._collected[rid].append(tok)
+            if tok == self.eos_id:  # max_new == 1: known at admission
+                self._live_host[slot] = False
+            if self.on_tokens is not None:
+                self.on_tokens(rid, [tok], False)
 
     def cancel(self, rid: int) -> bool:
         """Abort a request: de-queue it if pending, else kill its slot so
@@ -714,6 +789,7 @@ class SlotServer:
             if srid == rid:
                 self.live = self.live.at[slot].set(False)
                 self.remaining = self.remaining.at[slot].set(0)
+                self._live_host[slot] = False
                 del self._slot_rid[slot]
                 self._close_row(rid, "cancelled",
                                 len(self._collected.pop(rid, ())))
@@ -731,7 +807,10 @@ class SlotServer:
                        steps=0 if step0 is None else self._n_steps - step0 + 1)
 
     def _harvest_dead(self, finished: dict) -> None:
-        live = np.asarray(self.live)
+        """Close every occupied slot the host knows to be dead
+        (``_live_host``: the chunk's final ``live``, or a first token that
+        ended its request).  Reads nothing from the device."""
+        live = self._live_host
         # Snapshot + tolerant pops: a done-event on_tokens callback may
         # cancel() another request that finished in this same step,
         # removing its entries before the loop reaches them.
@@ -751,15 +830,22 @@ class SlotServer:
     @_on_weights_mesh
     def step(self) -> dict:
         """Admit what fits, decode one chunk; returns {rid: tokens} for
-        requests that finished during this step."""
+        requests that finished during this step.
+
+        The device programs of one step are queued with no host round trip
+        between them: every admission (its admit program, then
+        ``serve_seat``), then the chunk.  Only then does the host read the
+        device, twice at most: the step's first tokens, and the chunk's
+        tokens with the final ``live`` and ``pos``."""
         finished: dict = {}
         scope = self.stage_scope
         self._n_steps += 1
-        admits, admit_s = 0, 0.0
-        dispatch_s = wait_s = harvest_s = 0.0
-        moe: dict = {}
         with perf.stage_span(scope, "serve.step") as whole:
-            self._harvest_dead(finished)  # 1-token/instant-eos admissions
+            step = self._step = {
+                "server": self.server_id, "n_slots": self.n_slots,
+                "t0": whole.t0, "t1": whole.t0, "queued": 0, "live": 0,
+                "admits": 0, "admit_s": 0.0, "dispatch_s": 0.0,
+                "wait_s": 0.0, "harvest_s": 0.0, "fetches": 0}
             free = [s for s in range(self.n_slots)
                     if s not in self._slot_rid]
             # WHICH requests a step admits is the queue's order (its first
@@ -769,9 +855,8 @@ class SlotServer:
             # token as its share of the tokens it is spread over: after a
             # cold fill of many slots the first-admitted would otherwise
             # carry the whole fill on however few tokens they asked for
-            # (PERF.md, PR 26).  Chosen one at a time, from the queue as
-            # it stands: an ``on_tokens`` callback may cancel() a request
-            # that still waits.
+            # (PERF.md, PR 26).  Chosen one at a time: a refused admission
+            # (below) ends the step's admissions where they stand.
             while free and self._pending:
                 at = max(range(min(len(free), len(self._pending))),
                          key=lambda i: (self._pending[i][2], -i))
@@ -788,27 +873,30 @@ class SlotServer:
                         self._admit(free.pop(0), rid, prompt, max_new, prefix)
                 except RuntimeError:
                     # Transient resource exhaustion (the paged server's
-                    # pool): the request STAYS QUEUED, where it was —
+                    # pool), raised before the request's admit program
+                    # was launched: it STAYS QUEUED, where it was —
                     # in-flight work frees capacity and a later step
                     # admits it (the class docstring's "callers keep it
                     # queued / retry" contract).
                     self._pending.insert(at, (rid, prompt, max_new, prefix))
                     break
-                admits += 1
-                admit_s += span.seconds
-            self._harvest_dead(finished)
-            queued, live = len(self._pending), len(self._slot_rid)
-            if live:
+                step["admits"] += 1
+            step["queued"], step["live"] = (len(self._pending),
+                                            len(self._slot_rid))
+            # A slot the host knows dead already (a one-token request just
+            # admitted) needs no chunk; one whose first token may be its
+            # eos is found out after the chunk was queued, and rides it
+            # masked.
+            if any(self._live_host[s] for s in self._slot_rid):
                 self.key, sub = jax.random.split(self.key)
-                with perf.stage_span(scope, "serve.chunk_dispatch") as span:
-                    toks, mask = self._run_chunk(sub)
-                dispatch_s = span.seconds
+                toks, mask = self._run_chunk(sub)
                 with perf.stage_span(scope, "serve.chunk_wait") as span:
-                    toks, mask, pairs = jax.device_get(
-                        (toks, mask, self._pairs))
-                wait_s = span.seconds
+                    toks, mask, pairs, live, pos = self._fetch(
+                        (toks, mask, self._pairs, self.live, self.pos))
+                step["wait_s"] = span.seconds
+                self._live_host, self._pos_host = np.array(live), np.array(pos)
                 if pairs is not None:  # a routed model's
-                    moe = _moe_fields(pairs)
+                    step.update(_moe_fields(pairs))
                 with perf.stage_span(scope, "serve.harvest") as span:
                     # Snapshot: an on_tokens callback may legally cancel()
                     # a request (its own or another), which mutates
@@ -822,13 +910,12 @@ class SlotServer:
                         if self.on_tokens is not None and new:
                             self.on_tokens(rid, new, False)
                     self._harvest_dead(finished)
-                harvest_s = span.seconds
-        _step_log.append({
-            "server": self.server_id, "n_slots": self.n_slots,
-            "t0": whole.t0, "t1": whole.t0 + whole.seconds,
-            "queued": queued, "live": live, "admits": admits,
-            "admit_s": admit_s, "dispatch_s": dispatch_s, "wait_s": wait_s,
-            "harvest_s": harvest_s, **moe})
+                step["harvest_s"] = span.seconds
+            else:
+                self._hand_out_firsts(self.token)
+                self._harvest_dead(finished)
+        step["t1"] = whole.t0 + whole.seconds
+        _step_log.append(step)
         return finished
 
     @property
@@ -837,7 +924,22 @@ class SlotServer:
         return bool(self._pending or self._slot_rid)
 
     def _run_chunk(self, sub):
-        """Advance one decode chunk (subclass hook: the paged server runs
+        """Queue one decode chunk behind the step's admissions and, while
+        the device works through them, hand out the step's first tokens;
+        returns the chunk's (tokens, mask), still on the device.  The
+        first tokens go out from here and not from ``step()``: a caller
+        that times this call and then waits on what it returned (the
+        benchmark's traced runs do) would otherwise hold them back for a
+        whole chunk."""
+        seated = self.token
+        with perf.stage_span(self.stage_scope, "serve.chunk_dispatch") as span:
+            out = self._launch_chunk(sub)
+        self._step["dispatch_s"] = span.seconds
+        self._hand_out_firsts(seated)
+        return out
+
+    def _launch_chunk(self, sub):
+        """Launch the chunk program (subclass hook: the paged server runs
         its page-table program here); returns (tokens, mask)."""
         run = _compiled_chunk(self.cfg, self.n_slots, self.max_len,
                               self.chunk, *self.sampling, self.eos_id,

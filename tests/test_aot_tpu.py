@@ -400,6 +400,72 @@ def test_decode_chunk_fits_32_slots_on_v5e(topo, monkeypatch, kv):
             + m.temp_size_in_bytes - m.alias_size_in_bytes) < 15.75 * 2**30
 
 
+def _cell_model(name):
+    """(program config, abstract weights, n_slots, max_len) of a serving
+    cell of the benchmark, from its own files (shapes only)."""
+    from benchmark.harness import spec as S
+
+    config = S.load_config(S.load_spec(), name)
+    runner = S.load_runner(config["runner"])
+    if config["runner"] == "serve":
+        from benchmark.harness import weights as W
+
+        cfg = runner.llama_config(config)
+    else:
+        from benchmark.harness import weights_mla_moe as W
+
+        cfg = runner.model_config(config)
+    params = jax.eval_shape(
+        lambda: runner.program_tree(W.make_model(0, W.dims(config))))
+    return cfg, params, config["serve"]["n_slots"], config["serve"]["max_len"]
+
+
+@pytest.mark.parametrize("cell,bucket", [("mistral7b", 512), ("kimi-k2", 1024)])
+def test_admission_programs_at_the_cells_sizes_for_v5e(topo, monkeypatch, cell,
+                                                       bucket):
+    """An admission on the device, at the benchmark's sizes (mistral7b 24 x
+    2048, kimi-k2 128 x 5120): the admit program takes the donated cache
+    and hands the same buffer on (all of it aliased, no cache-sized
+    temporary, no copy of a cache-shaped array), and ``serve_seat``, the
+    program that follows it, returns the slot state -- four ``[n_slots]``
+    vectors -- from a token that never left the device, and takes no
+    cache."""
+    import re
+
+    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.serving import _compiled_admit, _seat
+
+    _as_tpu(monkeypatch)
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg, params, n_slots, max_len = _cell_model(cell)
+    cache = jax.eval_shape(lambda: init_cache(cfg, n_slots, max_len))
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    args = (params, cache, _s((1, bucket), I32), _s((), I32), _s((), I32),
+            jax.eval_shape(jax.random.PRNGKey, 0))
+    admit = _compiled_admit(cfg, bucket, 0.0, None, None).lower(
+        *_placed(args, one)).compile()
+    m = admit.memory_analysis()
+    assert m.alias_size_in_bytes == cache_bytes
+    assert m.output_size_in_bytes - cache_bytes < 4096   # + the token
+    assert m.temp_size_in_bytes < cache_bytes / 4
+    shapes = {"[" + ",".join(map(str, leaf.shape)) + "]"
+              for leaf in jax.tree_util.tree_leaves(cache)}
+    copies = [line for line in admit.as_text().splitlines()
+              if re.search(r"\bcopy(-start)?\(", line)
+              and any(shape in line.split(" = ", 1)[-1].split("(", 1)[0]
+                      for shape in shapes)]
+    assert copies == []
+
+    state = (_s((n_slots,), I32), _s((n_slots,), I32), _s((n_slots,), bool),
+             _s((n_slots,), I32))
+    seat = _seat.lower(*_placed((*state, _s((), I32), _s((4,), I32)),
+                                one)).compile()
+    assert [(o.shape, o.dtype) for o in seat.out_info] == [
+        (a.shape, a.dtype) for a in state]
+    assert seat.memory_analysis().argument_size_in_bytes < 4096
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("name,build,kw", [
     ("chunk_int8", _chunk_program, dict(kv_quant="int8")),

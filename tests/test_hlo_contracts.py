@@ -254,3 +254,68 @@ def test_tp_train_step_collective_count_scales_with_layers(cfg):
         return _ops(txt, "all-reduce")
 
     assert count_for(2) == count_for(4)
+
+
+def _admission_programs(cfg):
+    """{name: (program, abstract args, donated argument)} of every admit
+    program a server can end an admission with, at debug widths."""
+    from starway_tpu.models import paged, serving
+    from starway_tpu.models.generate import init_cache, init_rolling_cache
+
+    sampling = (0.0, None, None)
+    s = jax.ShapeDtypeStruct
+    i32 = lambda *shape: s(shape, jnp.int32)
+    key = jax.eval_shape(jax.random.PRNGKey, 0)
+    params = _abstract_params(cfg)
+    cache = jax.eval_shape(lambda: init_cache(cfg, 4, 64))
+    small = jax.eval_shape(lambda: init_cache(cfg, 1, 32))
+    rcfg = LlamaConfig.preset("debug", sliding_window=8)
+    rcache = jax.eval_shape(lambda: init_rolling_cache(rcfg, 4))
+    rsmall = jax.eval_shape(lambda: init_rolling_cache(rcfg, 1))
+    pool = jax.eval_shape(lambda: paged.init_paged_pool(cfg, 17, 16))
+    return {
+        "dense": (serving._compiled_admit(cfg, 32, *sampling),
+                  (params, cache, i32(1, 32), i32(), i32(), key), 1),
+        "prefix": (serving._compiled_prefix_admit(cfg, 32, 32, 64, *sampling),
+                   (params, cache, small, i32(), i32(1, 32), i32(), i32(),
+                    key), 1),
+        "rolling": (serving._compiled_rolling_admit(rcfg, *sampling),
+                    (rcache, rsmall, s((1, cfg.vocab_size), jnp.float32),
+                     i32(), key), 0),
+        "paged": (paged._compiled_paged_admit(cfg, 32, 16, *sampling),
+                  (params, pool, i32(1, 32), i32(), i32(2), key), 1),
+        "paged_prefix": (paged._compiled_paged_prefix_admit(
+            cfg, 32, 16, 4, False, *sampling),
+            (params, pool, i32(1, 4), i32(1, 32), i32(), i32(), key), 1),
+    }
+
+
+@pytest.mark.parametrize("path", ["dense", "prefix", "rolling", "paged",
+                                  "paged_prefix"])
+def test_admit_program_donates_the_cache_and_keeps_its_token_on_the_device(
+        cfg, path):
+    """Every admit program takes the cache DONATED (each leaf aliased to
+    its output) and returns it with the first token as a device scalar --
+    nothing a host must read before the slot can be seated -- and
+    ``serve_seat`` turns that scalar into the slot state: four
+    ``[n_slots]`` vectors in, the same four out."""
+    from starway_tpu.models import serving
+
+    run, args, donated = _admission_programs(cfg)[path]
+    lowered = run.lower(*args)
+    leaves = len(jax.tree_util.tree_leaves(args[donated]))
+    assert len(re.findall(r"tf\.aliasing_output|jax\.buffer_donor",
+                          lowered.as_text())) == leaves
+    out_cache, tok = lowered.out_info
+    assert (jax.tree_util.tree_map(lambda a: a.shape, out_cache)
+            == jax.tree_util.tree_map(lambda a: a.shape, args[donated]))
+    assert tok.shape == () and tok.dtype == jnp.int32
+
+    n = 4
+    state = tuple(jax.ShapeDtypeStruct((n,), d)
+                  for d in (jnp.int32, jnp.int32, bool, jnp.int32))
+    seat = serving._seat.lower(*state, tok,
+                               jax.ShapeDtypeStruct((4,), jnp.int32))
+    assert [(o.shape, o.dtype) for o in seat.out_info] == [
+        (a.shape, a.dtype) for a in state]
+    assert "jit_serve_seat" in seat.as_text()[:400]

@@ -122,6 +122,24 @@ def test_slot_server_ragged_slots_match_reference(ref, runner):
         and r["moe_assign"] == 3 * 4 * 2 * 4 for r in decoded)
 
 
+@pytest.mark.parametrize("k", [1, 3, 4])
+def test_a_latent_step_queues_its_programs_and_fetches_twice(runner, k,
+                                                             monkeypatch):
+    """The latent, routed model's admissions end in the same seat program:
+    no fetch and no scatter a slot, whatever a step admits."""
+    from starway_tpu.models import SlotServer
+    from tests.test_serving import check_step_queues_then_fetches
+
+    params, cfg = _model(runner)
+    srv = SlotServer(params, cfg, n_slots=4, max_len=64, chunk=3)
+    rng = np.random.default_rng(5)
+    requests = [(rng.integers(1, 128, n).astype(np.int32), m, None)
+                for n, m in ((5, 7), (17, 5), (9, 9))]
+    rids, done = check_step_queues_then_fetches(srv, requests, k, monkeypatch)
+    assert [len(done[r]) for r in rids] == [requests[i % 3][1]
+                                            for i in range(k)]
+
+
 def test_dense_model_step_log_has_no_moe_fields():
     from starway_tpu.models import LlamaConfig, SlotServer, init_params, serving
 

@@ -272,3 +272,45 @@ def test_paged_prefix_page_aligned(cfg, params):
         done[rid], _oracle(params, cfg, prefix_toks + [7, 7, 2], 5))
     srv.drop_prefix(pid)
     assert srv.pages_in_use == 0
+
+
+# ------------------------------------------------ a step queues, then fetches
+def _paged(cfg, params):
+    srv = PagedSlotServer(params, cfg, n_slots=4, max_len=64, page=16,
+                          n_pages=17, chunk=3)
+    return srv, [([5, 1, 7, 2, 9], 7, None), ([3, 8, 6], 5, None),
+                 ([4, 2, 8, 1, 6, 6, 3], 9, None)]
+
+
+def _paged_prefix(cfg, params):
+    srv = PagedSlotServer(params, cfg, n_slots=4, max_len=64, page=16,
+                          n_pages=17, chunk=3)
+    pid = srv.register_prefix(list(range(1, 21)))   # a whole page and a tail
+    return srv, [([5, 1, 7], 7, pid), ([3, 8], 5, pid), ([4, 2, 8, 1], 9, pid)]
+
+
+@pytest.mark.parametrize("k", [1, 3, 4])
+@pytest.mark.parametrize("kind", [_paged, _paged_prefix],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_a_paged_step_queues_its_programs_and_fetches_twice(cfg, params, kind,
+                                                            k, monkeypatch):
+    """The paged server rides the dense server's tail: its chunk's page
+    growth reads the host's copy of live/pos, never the device."""
+    from tests.test_serving import check_step_queues_then_fetches
+
+    srv, requests = kind(cfg, params)
+    rids, done = check_step_queues_then_fetches(srv, requests, k, monkeypatch)
+    assert sorted(done) == sorted(rids)
+    for i, rid in enumerate(rids):
+        assert len(done[rid]) == requests[i % len(requests)][1]
+
+
+def test_paged_requests_ending_at_their_first_token_return_their_pages(
+        cfg, params):
+    from tests.test_serving import check_first_token_endings
+
+    srv = PagedSlotServer(params, cfg, n_slots=4, max_len=64, page=16,
+                          n_pages=17, chunk=3)
+    check_first_token_endings(
+        srv, lambda p, n, eos: _oracle(params, cfg, p, n, eos_id=eos))
+    assert srv.pages_in_use == 0

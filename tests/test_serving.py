@@ -543,9 +543,13 @@ def test_step_log_rows_add_up(cfg, params):
     assert sum(r["admits"] for r in steps) == 3
     assert steps[0]["queued"] == 1 and steps[0]["live"] == 2
     for r in steps:
-        parts = (r["admit_s"] + r["dispatch_s"] + r["wait_s"]
+        # A step that admits queues its chunk behind the admissions before
+        # it fetches their tokens: dispatch_s then lies inside admit_s.
+        parts = (max(r["admit_s"], r["dispatch_s"]) + r["wait_s"]
                  + r["harvest_s"])
         assert 0.0 <= parts <= r["t1"] - r["t0"]
+        assert r["dispatch_s"] <= r["admit_s"] or not r["admits"]
+        assert r["fetches"] <= 2
         assert r["n_slots"] == 2 and 0 <= r["live"] <= 2
         assert (r["admit_s"] > 0) == (r["admits"] > 0)
     assert all(a["t1"] <= b["t0"] for a, b in zip(steps, steps[1:]))
@@ -642,11 +646,173 @@ def test_serving_program_names_are_stable(cfg, params):
         "serve_paged_prefix_admit_32": paged._compiled_paged_prefix_admit(
             cfg, 32, 16, 4, False, *sampling),
     }
+    progs["serve_seat"] = serving._seat
     for name, prog in progs.items():
         assert prog.__name__ == name
+    # Only the prefills are ``serve_admit*``: a trace reduction takes the
+    # device time of an admission from the programs of that name.
+    assert not serving._seat.__name__.startswith("serve_admit")
     # ... and that is the name the compiled module carries.
     srv = SlotServer(params, cfg, n_slots=2, max_len=64, chunk=4)
     lowered = progs["serve_decode_chunk"].lower(
         params, srv.cache, srv.token, srv.pos, srv.live, srv.remaining,
         srv.key)
     assert "jit_serve_decode_chunk" in lowered.as_text()[:400]
+
+
+# ------------------------------------------------ a step queues, then fetches
+
+
+def check_step_queues_then_fetches(srv, requests, k, monkeypatch):
+    """One ``step()`` that admits ``k`` requests (``requests``: (prompt,
+    max_new, prefix) triples, cycled): its device programs are queued with
+    nothing from the host between them and it reads the device twice.
+
+    * ``step_log()`` counts ``fetches <= 2`` beside ``admits == k``;
+    * the slot state reaches the chunk program through ``serve_seat``
+      alone: ``k`` calls, each taking the arrays the one before returned,
+      the last one's outputs being what the chunk is launched on (object
+      identity: any other program writing a slot's state, such as a
+      ``.at[slot].set`` from the host, would have put new arrays there);
+    * every first token is handed out before any chunk token.
+
+    Shared by tests/test_paged.py and tests/test_mla_moe.py."""
+    from starway_tpu.models import serving
+
+    srv.submit(*requests[0])
+    srv.run()                      # every program of the step is compiled
+    events = []
+    srv.on_tokens = lambda rid, toks, done: events.append((rid, len(toks), done))
+    rids = [srv.submit(*requests[i % len(requests)]) for i in range(k)]
+
+    seats, at_chunk = [], []
+    seat = serving._seat
+
+    def spy_seat(*args):
+        out = seat(*args)
+        seats.append((args[:4], out))
+        return out
+
+    launch = srv._launch_chunk
+
+    def spy_launch(sub):
+        at_chunk.append((srv.token, srv.pos, srv.live, srv.remaining))
+        return launch(sub)
+
+    monkeypatch.setattr(serving, "_seat", spy_seat)
+    srv._launch_chunk = spy_launch
+    before = (srv.token, srv.pos, srv.live, srv.remaining)
+    srv.step()
+    monkeypatch.undo()
+    srv._launch_chunk = launch
+
+    row = [r for r in serving.step_log() if r["server"] == srv.server_id][-1]
+    assert row["admits"] == k and row["fetches"] == 2 and row["admit_s"] > 0
+    assert len(seats) == k and len(at_chunk) == 1
+    state = before
+    for took, gave in seats:
+        assert all(a is b for a, b in zip(took, state))
+        state = gave
+    assert all(a is b for a, b in zip(at_chunk[0], state))
+    firsts = events[:k]
+    assert sorted(e[0] for e in firsts) == sorted(rids)
+    assert all(n == 1 and not done for _rid, n, done in firsts)
+    assert all(rid in rids for rid, _n, _d in events)
+    log = {r["rid"]: r for r in serving.request_log()
+           if r["side"] == "server" and r["server"] == srv.server_id}
+    assert len({log[rid]["t_first"] for rid in rids}) == 1
+    assert log[rids[0]]["t_first"] <= row["t0"] + row["admit_s"]
+    done = srv.run()
+    srv.on_tokens = None
+    return rids, done
+
+
+def _dense(cfg, params):
+    srv = SlotServer(params, cfg, n_slots=4, max_len=64, chunk=3)
+    return srv, [([5, 1, 7, 2, 9], 7, None), ([3, 8, 6], 5, None),
+                 ([4, 2, 8, 1, 6, 6, 3], 9, None)]
+
+
+def _prefix(cfg, params):
+    srv = SlotServer(params, cfg, n_slots=4, max_len=64, chunk=3)
+    pid = srv.register_prefix([7, 3, 9, 1, 4, 4, 2])
+    return srv, [([5, 1, 7], 7, pid), ([3, 8], 5, pid), ([4, 2, 8, 1], 9, pid)]
+
+
+def _rolling(cfg, params):
+    rcfg = LlamaConfig.preset("debug", sliding_window=8)
+    srv = SlotServer(params, rcfg, n_slots=4, max_len=64, chunk=3)
+    return srv, [([5, 1, 7, 2, 9], 7, None), ([3, 8, 6], 5, None),
+                 (list(range(1, 14)), 9, None)]
+
+
+@pytest.mark.parametrize("k", [1, 3, 4])
+@pytest.mark.parametrize("kind", [_dense, _prefix, _rolling],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_a_step_queues_its_programs_and_fetches_twice(cfg, params, kind, k,
+                                                      monkeypatch):
+    srv, requests = kind(cfg, params)
+    rids, done = check_step_queues_then_fetches(srv, requests, k, monkeypatch)
+    assert sorted(done) == sorted(rids)
+    for i, rid in enumerate(rids):
+        assert len(done[rid]) == requests[i % len(requests)][1]
+
+
+def check_first_token_endings(srv, oracle):
+    """A one-token request and one whose first token is its eos, admitted
+    in one step beside two long requests: each ends in that very step with
+    exactly one token and one done event after it, its slot is free again,
+    and the step still read the device twice.  ``srv``: 4 slots, eos unset;
+    ``oracle(prompt, max_new, eos_id)``.  Shared by tests/test_paged.py."""
+    from starway_tpu.models import serving
+
+    instant = [5, 1, 7, 2, 9]
+    srv.eos_id = int(oracle(instant, 1, None)[0])
+    events = []
+    srv.on_tokens = lambda rid, toks, done: events.append(
+        (rid, list(toks), done))
+    asked = {srv.submit([3, 8, 6], 9): ([3, 8, 6], 9),
+             srv.submit([4, 2, 8, 1], 1): ([4, 2, 8, 1], 1),
+             srv.submit(instant, 8): (instant, 8),
+             srv.submit([9, 1, 5], 6): ([9, 1, 5], 6)}
+    _long_a, one, eos_first, _long_b = asked
+    done = srv.step()
+    row = [r for r in serving.step_log() if r["server"] == srv.server_id][-1]
+    assert row["admits"] == 4 and row["fetches"] == 2
+    assert sorted(done) == [one, eos_first]
+    for rid in (one, eos_first):
+        assert len(done[rid]) == 1
+        assert [e[1:] for e in events if e[0] == rid] == [
+            ([int(done[rid][0])], False), ([], True)]
+    assert done[eos_first][0] == srv.eos_id
+    assert sorted(srv._slot_rid.values()) == [r for r in asked
+                                              if r not in done]
+    done.update(srv.run())
+    for rid, (prompt, n) in asked.items():
+        np.testing.assert_array_equal(done[rid], oracle(prompt, n, srv.eos_id))
+    assert sum(1 for e in events if e[2]) == 4 and not srv.busy
+
+
+def test_requests_ending_at_their_first_token_beside_long_ones(cfg, params):
+    srv = SlotServer(params, cfg, n_slots=4, max_len=64, chunk=3)
+    check_first_token_endings(
+        srv, lambda p, n, eos: _oracle(params, cfg, p, n, eos_id=eos))
+
+
+def test_only_one_token_requests_need_no_chunk(cfg, params):
+    """A step whose every occupied slot holds a request the host knows to
+    end at its first token launches no chunk: one fetch, no key split."""
+    from starway_tpu.models import serving
+
+    srv = SlotServer(params, cfg, n_slots=2, max_len=64, chunk=3)
+    rids = [srv.submit([3, 8, 6], 1), srv.submit([4, 2], 1)]
+    key = srv.key
+    done = srv.step()
+    row = [r for r in serving.step_log() if r["server"] == srv.server_id][-1]
+    assert row["admits"] == 2 and row["fetches"] == 1
+    assert row["dispatch_s"] == row["wait_s"] == 0.0
+    assert sorted(done) == rids and not srv.busy
+    # Two admissions split the key twice; no chunk split it a third time.
+    want = jax.random.split(jax.random.split(key)[0])[0]
+    np.testing.assert_array_equal(jax.random.key_data(srv.key),
+                                  jax.random.key_data(want))
