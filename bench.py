@@ -188,11 +188,11 @@ def main() -> None:
                 # The device the number came from, as JAX reports it.
                 "device": device,
                 # §24: swfast levers armed via env for this run ([] = seed
-                # data path) -- rows are self-describing from BENCH_r06 on.
+                # data path): rows are self-describing.
                 "levers": _active_levers(),
                 # §25 swpulse: the client worker's always-on distributions
                 # (log-bucket percentiles per HIST_NAMES row) from the same
-                # run -- BENCH_r07 on.
+                # run.
                 "hists": pulse,
             }
         )

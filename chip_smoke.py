@@ -520,19 +520,17 @@ def _plain_attn(cfg=None):
     return functools.partial(blockwise_attention, causal=True, sm_scale=scale)
 
 
-@contextlib.contextmanager
-def _lax_experts():
-    """Programs traced inside run the routed experts' grouped matmul in
-    its lax form (the plain side of a comparison; the steering lives
-    here, not in an option of the program)."""
-    from starway_tpu.models import moe
+def _lax_ops():
+    """Programs traced inside run every operation of ``starway_tpu.ops`` in
+    its lax form (the plain side of a comparison: the routed experts'
+    grouped matmul is the one the callers are after).  The steering
+    substitutes the ONE decision function, here, not an option of the
+    program."""
+    from unittest import mock
 
-    kernel = moe.routed_experts
-    moe.routed_experts = functools.partial(kernel, use_pallas=False)
-    try:
-        yield
-    finally:
-        moe.routed_experts = kernel
+    from starway_tpu.ops import dispatch
+
+    return mock.patch.object(dispatch, "use_kernels", lambda: False)
 
 
 def make_reference(cfg, max_len: int, n_new: int):
@@ -555,7 +553,7 @@ def make_reference(cfg, max_len: int, n_new: int):
 
     @jax.jit
     def gaps(params, padded, at, chosen):
-        with _lax_experts():
+        with _lax_ops():
             logits = forward(params, padded, cfg, plain)[0][at]  # [n, V] f32
         top = logits.max(-1)
         got = jnp.take_along_axis(logits, chosen[:, None], -1)[:, 0]
@@ -680,7 +678,7 @@ def _pallas_vs_lax_logits(params, cfg, tokens) -> dict:
     from starway_tpu.models.generate import decode_step
 
     s = tokens.shape[1]
-    with _lax_experts():
+    with _lax_ops():
         plain = jax.jit(lambda p, t: forward(p, t, cfg, _plain_attn(cfg)))(
             params, tokens)
     flash = jax.jit(lambda p, t: forward(p, t, cfg))(params, tokens)

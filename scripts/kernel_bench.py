@@ -250,12 +250,12 @@ def bench_decode(b=1, hq=8, hkv=2, t=8192, d=128, iters: int = 64, impl="ours"):
     (decode is bandwidth-bound: the kernel's job is streaming the grouped
     cache exactly once).  ``impl="int8"``: the quantized-cache path — half
     the bytes stream, dequant folded into the kernel (ops/quantize.py)."""
-    from starway_tpu.models.generate import _attend_cached
+    from starway_tpu.ops.pallas_decode import (decode_attention,
+                                               decode_attention_lax)
 
     q, kc, vc, pos, cache_bytes = _decode_inputs(b, hq, hkv, t, d)
 
     if impl == "int8":
-        from starway_tpu.ops.pallas_decode import decode_attention
         from starway_tpu.ops.quantize import quantize_kv
 
         kc, ks = quantize_kv(kc)
@@ -266,11 +266,10 @@ def bench_decode(b=1, hq=8, hkv=2, t=8192, d=128, iters: int = 64, impl="ours"):
         def kern(q, kc, vc):
             return decode_attention(q, kc, vc, pos, k_scale=ks, v_scale=vs)
     else:
-        use_pallas = impl == "ours"
+        fn = decode_attention if impl == "ours" else decode_attention_lax
 
         def kern(q, kc, vc):
-            return _attend_cached(q, kc, vc, pos, hq // hkv,
-                                  use_pallas=use_pallas)
+            return fn(q, kc, vc, pos)
 
     dt = _timeit(lambda q, kc, vc, iters: _chain(kern, q, kc, vc, iters=iters),
                  q, kc, vc, iters=iters)
@@ -431,9 +430,10 @@ def check_numerics():
     """On-chip numerics: pin the pallas kernels against the lax oracles on
     the REAL backend (the pytest suite pins them in CPU interpret mode; this
     is the hardware half of that contract -- VERDICT r1 #8)."""
-    from starway_tpu.models.generate import _attend_cached
     from starway_tpu.ops.attention import attention_reference, repeat_kv
     from starway_tpu.ops.pallas_attention import flash_attention
+    from starway_tpu.ops.pallas_decode import (decode_attention,
+                                               decode_attention_lax)
 
     b, hq, hkv, s, d = 1, 8, 2, 512, 128
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(7), 3)
@@ -474,8 +474,8 @@ def check_numerics():
     kc = jax.random.normal(kk, (b, hkv, t, d), jnp.bfloat16)
     vc = jax.random.normal(kv, (b, hkv, t, d), jnp.bfloat16)
     pos = jnp.asarray(t // 2, jnp.int32)
-    dk = _attend_cached(qd, kc, vc, pos, hq // hkv, use_pallas=True)
-    dr = _attend_cached(qd, kc, vc, pos, hq // hkv, use_pallas=False)
+    dk = decode_attention(qd, kc, vc, pos)
+    dr = decode_attention_lax(qd, kc, vc, pos)
     derr = float(jnp.max(jnp.abs(dk.astype(jnp.float32) - dr.astype(jnp.float32))))
     rows.append({"metric": "check_decode_onchip", "value": derr,
                  "unit": "max_abs_err", "ok": bool(derr < 2e-2)})
@@ -503,9 +503,9 @@ def check_numerics():
     rows.append({"metric": "check_flash_window_bwd_onchip", "value": gwerr,
                  "unit": "max_rel_err", "ok": bool(gwerr < 2e-2)})
 
-    dwk = _attend_cached(qd, kc, vc, pos, hq // hkv, use_pallas=True,
+    dwk = decode_attention(qd, kc, vc, pos,
                          window=win)
-    dwr = _attend_cached(qd, kc, vc, pos, hq // hkv, use_pallas=False,
+    dwr = decode_attention_lax(qd, kc, vc, pos,
                          window=win)
     dwerr = float(jnp.max(jnp.abs(dwk.astype(jnp.float32)
                                   - dwr.astype(jnp.float32))))
@@ -518,9 +518,9 @@ def check_numerics():
 
     kc8, ks = quantize_kv(kc)
     vc8, vs = quantize_kv(vc)
-    q8k = _attend_cached(qd, kc8, vc8, pos, hq // hkv, use_pallas=True,
+    q8k = decode_attention(qd, kc8, vc8, pos,
                          k_scale=ks, v_scale=vs)
-    q8r = _attend_cached(qd, kc8, vc8, pos, hq // hkv, use_pallas=False,
+    q8r = decode_attention_lax(qd, kc8, vc8, pos,
                          k_scale=ks, v_scale=vs)
     q8err = float(jnp.max(jnp.abs(q8k.astype(jnp.float32)
                                   - q8r.astype(jnp.float32))))
@@ -530,8 +530,8 @@ def check_numerics():
     C = 5
     qc = jax.random.normal(kq, (b, hq, C, d), jnp.bfloat16)
     posv = jnp.asarray([t // 2 - 3], jnp.int32)  # chunk straddles blocks
-    mqk = _attend_cached(qc, kc, vc, posv, hq // hkv, use_pallas=True)
-    mqr = _attend_cached(qc, kc, vc, posv, hq // hkv, use_pallas=False)
+    mqk = decode_attention(qc, kc, vc, posv)
+    mqr = decode_attention_lax(qc, kc, vc, posv)
     mqerr = float(jnp.max(jnp.abs(mqk.astype(jnp.float32)
                                   - mqr.astype(jnp.float32))))
     rows.append({"metric": "check_decode_multiquery_onchip", "value": mqerr,
@@ -575,52 +575,6 @@ def check_numerics():
     rows.append({"metric": "check_spec_chunk_onchip", "value": serr,
                  "unit": "max_rel_err", "ok": bool(serr < 2e-2)})
     return rows
-
-
-def bench_decode_tune(b=1, hq=8, hkv=2, t=8192, d=128, iters: int = 64):
-    """Sweep the STREAM decode kernel's block_k on-chip (plus two grid
-    sentinel points for drift); emits one row per (variant, block) and a
-    summary row with the winner.  The r2
-    re-measurement showed the grid kernel's 128 default losing to the lax
-    path (builder-reported, round 2): ~0.4 us fixed cost x 64 grid cells.  The stream
-    variant (r3) removes the per-block cell cost entirely — b*hkv cells,
-    double-buffered manual DMA — so its block size only tunes DMA
-    granularity vs VMEM footprint."""
-    from starway_tpu.ops.pallas_decode import decode_attention
-
-    q, kc, vc, pos, cache_bytes = _decode_inputs(b, hq, hkv, t, d)
-
-    candidates = [bk for bk in (128, 256, 512, 1024, 2048) if bk <= t]
-    if not candidates:
-        raise ValueError(f"t={t} is smaller than every candidate block size")
-    # The grid variant already lost to stream at its best setting
-    # (builder-reported, round 3); keep two sentinel points for drift
-    # instead of a full sweep (ROADMAP D4 deletes the variant).
-    grid_candidates = [bk for bk in (128, 512) if bk <= t]
-    best = None
-    for stream in (True, False):
-        variant = "stream" if stream else "grid"
-        for bk in (candidates if stream else grid_candidates):
-            kern = functools.partial(decode_attention, block_k=bk,
-                                     stream=stream)
-
-            def run(q, kc, vc, iters, _kern=kern):
-                return _chain(lambda q, kc, vc: _kern(q, kc, vc, pos),
-                              q, kc, vc, iters=iters)
-
-            dt = _timeit(run, q, kc, vc, iters=iters)
-            print(json.dumps(
-                {"metric": f"decode_{variant}_block{bk}_us",
-                 "value": round(dt * 1e6, 2), "unit": "us",
-                 "detail": f"{cache_bytes / dt / 1e9:.0f} GB/s effective"}),
-                flush=True)
-            if best is None or dt < best[2]:
-                best = (variant, bk, dt)
-    return {"metric": "decode_best_config", "value": best[1],
-            "unit": "block_k", "variant": best[0],
-            "detail": f"{best[2] * 1e6:.2f} us with {best[0]} kernel at "
-                      f"block_k={best[1]} "
-                      f"({cache_bytes / best[2] / 1e9:.0f} GB/s)"}
 
 
 def bench_serve(batch=1, model="llama", ragged=False, prompt_len=512,
@@ -937,7 +891,6 @@ BENCHES = {
     "decode": bench_decode,
     "decode_lax": functools.partial(bench_decode, impl="lax"),
     "decode_int8": functools.partial(bench_decode, impl="int8"),
-    "decode_tune": bench_decode_tune,
     "decode_paged": bench_decode_paged,
     "decode_shapes": bench_decode_shapes,
     "train_mfu": bench_train_mfu,

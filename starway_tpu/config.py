@@ -46,12 +46,6 @@ benchmark.md:114-126 for ``UCX_TLS``).  The TPU build mirrors that shape:
     65536); smaller payloads ride the framed stream, where one small copy
     beats a pull round-trip.
 
-``STARWAY_DECODE_STREAM``
-    "1" (default) = the decode-attention kernel's streaming variant
-    (double-buffered manual DMA, ops/pallas_decode.py) -- the one that
-    serves on the chip (chip_smoke.py phase c, TPU v5 lite); "0" = the
-    grid-pipelined variant, kept until ROADMAP D4 deletes it.
-
 ``STARWAY_SM_FORCE_ATOMICS``
     "1" = route the Python sm ring's cursor ops through the native lib's
     acquire/release atomics even on x86 (the off-x86 code path, made
@@ -277,7 +271,6 @@ __all__ = [
     "device_backend",
     "devpull_enabled",
     "devpull_threshold",
-    "decode_stream_enabled",
     "connect_timeout",
     "keepalive_interval",
     "keepalive_misses",
@@ -349,10 +342,6 @@ def advertised_host() -> str:
 
 def devpull_enabled() -> bool:
     return _env("STARWAY_DEVPULL", "1") != "0"
-
-
-def decode_stream_enabled() -> bool:
-    return _env("STARWAY_DECODE_STREAM", "1") != "0"
 
 
 def devpull_threshold() -> int:

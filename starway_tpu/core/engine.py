@@ -977,7 +977,10 @@ class Worker:
     def _on_conn_io(self, conn: TcpConn, mask, fires) -> None:
         if mask & selectors.EVENT_WRITE:
             conn.on_writable(fires)
-        if mask & selectors.EVENT_READ and conn.alive:
+        # A write that broke a session conn SUSPENDS it: still alive, its
+        # socket gone.  The read half of the same event has nothing to read.
+        if (mask & selectors.EVENT_READ and conn.alive
+                and conn.sock is not None):
             conn.on_readable(fires)
 
     def _conn_broken(self, conn, fires) -> None:

@@ -10,7 +10,7 @@ The cache layout is scan-stacked like the parameters: ``k/v
 scan's CARRY (:func:`cached_layer_scan`): they are never a scan input or
 output and never sliced per layer, so one buffer serves the whole
 generation (donate the cache under jit).  On the chip the new entries are
-written in place by ``ops/pallas_decode.py::kv_write`` and attention reads
+written in place by ``ops.cache_write`` and attention reads
 the stacked array through a layer index; the decode step moves no cache
 bytes but the ones attention reads.
 """
@@ -27,6 +27,7 @@ from jax import lax
 from .llama import (LlamaConfig, apply_rope, cfg_rope_tables, embed_tokens,
                     ffn_block, forward, layer_segments, matmul_w, qkv_proj,
                     rmsnorm, scan_segment)
+from ..ops import cache_write, cached_attention, latent_attention
 from ..ops.attention import NEG_BIG, repeat_kv
 
 
@@ -72,106 +73,30 @@ def init_rolling_cache(cfg: LlamaConfig, batch: int) -> dict:
     return init_cache(cfg, batch, cfg.sliding_window)
 
 
-def _attend_cached(q, k_cache, v_cache, pos, n_rep, use_pallas=None,
-                   window=None, k_scale=None, v_scale=None, layer=None):
-    """q: [B, Hq, C, D] — C consecutive query positions per row (C=1 is
-    single-token decode; C>1 the speculative chunk verify, whose entries
-    are already written: write-then-attend).  caches: the stacked [L, B,
-    Hkv, T, D] with ``layer`` the (traced) layer index, or one layer's
-    [B, Hkv, T, D] with ``layer=None``; row b's queries sit at ``pos[b]
-    .. pos[b] + C - 1`` (``pos`` scalar or per-row [B]) and mask key
-    positions above themselves; ``window`` restricts to the last
-    ``window`` positions (sliding-window models).  ``k_scale``/``v_scale``
-    (the caches' shape less D, f32): the caches are int8-quantized
-    (ops/quantize.py) — the kernel streams them at half width; the lax
-    path dequantizes up front.
-
-    On TPU the pallas decode kernel (ops/pallas_decode.py) streams the
-    grouped cache once instead of materialising ``repeat_kv`` — an
-    ``n_rep``× HBM-bandwidth saving on the bandwidth-bound decode step
-    (and only ~window bytes of it under a sliding window); C>1 just adds
-    matmul rows to the same stream.  It takes the stacked cache and the
-    layer index as they are: no layer is sliced out.
-    """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if layer is None:  # one layer's caches: a stack of one
-        k_cache, v_cache, k_scale, v_scale = (
-            None if a is None else a[None]
-            for a in (k_cache, v_cache, k_scale, v_scale))
-        layer = 0
-    if use_pallas:
-        from ..ops.pallas_decode import decode_attention
-        from ..parallel.sharding import per_head_shard
-
-        scales = () if k_scale is None else (k_scale, v_scale)
-
-        def kernel(q, k, v, *rest):  # rest = (*scales, pos, layer)
-            ks, vs = rest[:-2] or (None, None)
-            return decode_attention(q, k, v, rest[-2], layer=rest[-1],
-                                    window=window, k_scale=ks, v_scale=vs)
-
-        # Heads (dim 1 of q, dim 2 of the stacked caches and scales) shard
-        # alike; pos (a scalar, or one cursor per batch row) and the layer
-        # index are the same on every shard.
-        return per_head_shard(
-            kernel, (q, k_cache, v_cache, *scales),
-            (jnp.asarray(pos, jnp.int32), jnp.asarray(layer, jnp.int32)),
-            head_dims=(1,) + (2,) * (2 + len(scales)))
-    k_cache, v_cache, k_scale, v_scale = (
-        None if a is None
-        else lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
-        for a in (k_cache, v_cache, k_scale, v_scale))
-    if k_scale is not None:
-        from ..ops.quantize import dequantize_kv
-
-        k_cache = dequantize_kv(k_cache, k_scale, q.dtype)
-        v_cache = dequantize_kv(v_cache, v_scale, q.dtype)
-    k = repeat_kv(k_cache, n_rep)
-    v = repeat_kv(v_cache, n_rep)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
-    s = s / (q.shape[-1] ** 0.5)
-    kv_pos = jnp.arange(k.shape[2])[None, None, None, :]
-    qp = (jnp.asarray(pos).reshape(-1)[:, None, None, None]
-          + jnp.arange(q.shape[2])[None, None, :, None])
-    keep = kv_pos <= qp
-    if window is not None:
-        keep = keep & (kv_pos > qp - window)
-    s = jnp.where(keep, s, NEG_BIG)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
-
-
 def cache_len(cache: dict) -> int:
     """Positions a cache holds: the T axis sits at index 3 of every leaf."""
     return next(iter(cache.values())).shape[3]
 
 
-def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig,
-                 use_pallas=None):
+def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig):
     """``attend`` of :func:`cached_layer_scan` over a whole-length cache of
-    either kind, queries at ``pos[b] ..``: grouped k/v
-    (:func:`_attend_cached`, windowed and int8-aware), or the latent rows
-    of ``cfg.latent`` (absorbed queries in, ``P c_kv`` out;
-    ops/pallas_decode.py::mla_decode_attention on TPU)."""
+    either kind, queries ``q [B, Hq, C, D]`` at ``pos[b] ..`` (C=1 is
+    single-token decode; C>1 the speculative chunk verify, whose entries
+    are already written: write-then-attend): grouped k/v
+    (``ops.cached_attention``, windowed and int8-aware), or the latent
+    rows of ``cfg.latent`` (absorbed queries in, ``P c_kv`` out;
+    ``ops.latent_attention``)."""
     if "ckv" in cache:
-        from ..ops.pallas_decode import (mla_decode_attention,
-                                         mla_decode_attention_lax)
-
-        if use_pallas is None:
-            use_pallas = jax.default_backend() == "tpu"
-        fn = mla_decode_attention if use_pallas else mla_decode_attention_lax
-        return fn(q, cache["ckv"], pos, rank=cfg.latent.kv_rank,
-                  sm_scale=cfg.latent.sm_scale, layer=layer)
-    return _attend_cached(q, cache["k"], cache["v"], pos,
-                          cfg.n_heads // cfg.n_kv_heads,
-                          use_pallas=use_pallas, window=cfg.sliding_window,
-                          k_scale=cache.get("k_scale"),
-                          v_scale=cache.get("v_scale"), layer=layer)
+        return latent_attention(q, cache["ckv"], pos,
+                                rank=cfg.latent.kv_rank,
+                                sm_scale=cfg.latent.sm_scale, layer=layer)
+    return cached_attention(q, cache["k"], cache["v"], pos, layer=layer,
+                            window=cfg.sliding_window,
+                            k_scale=cache.get("k_scale"),
+                            v_scale=cache.get("v_scale"))
 
 
-def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
-                  use_pallas=None) -> dict:
+def _write_cached(cache: dict, new: dict, layer, pos, rows=None) -> dict:
     """The C new positions of one layer into the stacked cache, every leaf
     (k, v and, int8, their scales): ``cache[name][layer, rows[b], :,
     pos[b] + c] = new[name][b, :, c]``.  ``new[name]``: [B, Hkv, C(, D)];
@@ -180,14 +105,10 @@ def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
     ``pos`` the offsets inside them.  A start above ``T - C`` is clamped,
     as ``lax.dynamic_update_slice`` does.
 
-    On TPU this is ``ops/pallas_decode.py::kv_write``: in place, a tile a
-    row.  Either XLA form (a scatter, or ``dynamic_update_slice`` per
+    The write itself is ``ops.cache_write``: on the chip in place, a tile
+    a row.  Either XLA form (a scatter, or ``dynamic_update_slice`` per
     row) makes the chip's compiler re-lay the scan's carry for the write
     and copy the whole stacked cache back for the kernel, every layer."""
-    from ..ops.pallas_decode import kv_write, kv_write_lax
-
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
     B = next(iter(new.values())).shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     rows = jnp.arange(B) if rows is None else rows
@@ -196,19 +117,9 @@ def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
     groups = ([("ckv",)] if "ckv" in cache else
               [("k", "v")] + [("k_scale", "v_scale")] * ("k_scale" in cache))
     for names in groups:  # same-shaped leaves share one kernel call
-        n = len(names)
-        leaves = tuple(cache[name] for name in names)
-        updates = tuple(new[name] for name in names)
-        if use_pallas:
-            from ..parallel.sharding import per_head_shard
-
-            done = per_head_shard(
-                lambda *a: kv_write(a[:n], a[n:2 * n], *a[2 * n:]),
-                leaves + updates, (layer, rows, pos),
-                head_dims=(2,) * n + (1,) * n, out_head_dims=(2,) * n)
-        else:
-            done = kv_write_lax(leaves, updates, layer, rows, pos)
-        out.update(zip(names, done))
+        out.update(zip(names, cache_write(
+            tuple(cache[name] for name in names),
+            tuple(new[name] for name in names), layer, rows, pos)))
     return out
 
 
@@ -267,11 +178,10 @@ def decode_step_counted(params: dict, cache: dict, token, pos,
             # Warm slots are exactly the window (we just overwrote the
             # oldest); cold-start slots (> pos) are masked by the clamped
             # position.  No window re-mask: absolute order is irrelevant.
-            return _attend_cached(q, cache["k"], cache["v"],
-                                  jnp.minimum(pos, T - 1),
-                                  cfg.n_heads // cfg.n_kv_heads,
-                                  k_scale=cache.get("k_scale"),
-                                  v_scale=cache.get("v_scale"), layer=layer)
+            return cached_attention(q, cache["k"], cache["v"],
+                                    jnp.minimum(pos, T - 1), layer=layer,
+                                    k_scale=cache.get("k_scale"),
+                                    v_scale=cache.get("v_scale"))
         return attend_cache(q, cache, pos, layer, cfg)
 
     h, out, counts = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg,

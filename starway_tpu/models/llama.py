@@ -31,7 +31,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ..ops.attention import blockwise_attention
+from ..ops import quantized_matmul, self_attention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -471,26 +471,15 @@ def quantized_param_specs(cfg: LlamaConfig) -> dict:
 def matmul_w(x, w):
     """``x @ w`` where ``w`` is a raw array or a weight-quantized
     ``{"q": int8, "s": f32}`` pair (ops/quantize.py:quantize_params —
-    the W8A16 serving tree).  Quantized weights stream at half width on
-    TPU through the pallas gemv kernel (ops/pallas_gemv.py) with the
-    per-output-channel scale folded into the product; elsewhere they
-    dequantize-then-matmul.  Every matmul consumer of the parameter tree
+    the W8A16 serving tree), which goes through
+    ``ops.quantized_matmul``.  Every matmul consumer of the parameter tree
     (decoder_layer, head_logits, the cached decode layer scan) routes
     through here, so ONE quantized tree serves
     forward/prefill/decode/serving/speculative alike."""
     if not (isinstance(w, dict) and "q" in w):
         return x @ w
-    wq, s = w["q"], w["s"]
-    lead = x.shape[:-1]
-    x2 = x.reshape(-1, x.shape[-1])
-    if jax.default_backend() == "tpu":
-        from ..ops.pallas_gemv import int8_matmul
-
-        out = int8_matmul(x2, wq, s)
-    else:
-        out = (x2.astype(jnp.float32)
-               @ (wq.astype(jnp.float32) * s[None, :])).astype(x.dtype)
-    return out.reshape(*lead, wq.shape[-1])
+    out = quantized_matmul(x.reshape(-1, x.shape[-1]), w["q"], w["s"])
+    return out.reshape(*x.shape[:-1], w["q"].shape[-1])
 
 
 def rmsnorm(x, w, eps: float):
@@ -664,32 +653,12 @@ def _remat_wrap(layer, cfg: "LlamaConfig"):
     return jax.checkpoint(layer)
 
 
-def default_attn(q, k, v, window: Optional[int] = None,
-                 sm_scale: Optional[float] = None):
-    """Causal attention: the hand-tiled pallas kernel on TPU, the lax
-    blockwise scan elsewhere (bit-compatible algebra, same GQA handling).
-    ``window``: sliding-window causal — the flash kernel masks, skips, and
-    DMA-elides out-of-window blocks in forward AND backward.  ``sm_scale``:
-    the scores' multiplier where it is not ``head_dim ** -0.5``."""
-    if jax.default_backend() == "tpu":
-        from ..ops.pallas_attention import flash_attention
-        from ..parallel.sharding import per_head_shard
-
-        return per_head_shard(
-            lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                            interpret=False, window=window,
-                                            sm_scale=sm_scale),
-            (q, k, v))
-    return blockwise_attention(q, k, v, causal=True, window=window,
-                               sm_scale=sm_scale)
-
-
 def resolve_attn_fn(cfg: LlamaConfig, attn_fn: Optional[Callable]) -> Callable:
     """The one place attn_fn defaults and the sliding-window guard live
     (shared by the scan forward and models/pp_llama.py).
 
-    None -> :func:`default_attn`, window-bound when the config has one.  A
-    supplied attn_fn on a windowed config must declare
+    None -> ``ops.self_attention`` (causal), window-bound when the config
+    has one.  A supplied attn_fn on a windowed config must declare
     ``attn_fn.handles_window = True`` — silently training/serving
     full-causal on a windowed config is a different model.
     :func:`make_sharded_attn` (plain ring layout; band-skipped steps)
@@ -699,10 +668,10 @@ def resolve_attn_fn(cfg: LlamaConfig, attn_fn: Optional[Callable]) -> Callable:
     """
     if attn_fn is None:
         if cfg.latent is not None:
-            return partial(default_attn, sm_scale=cfg.latent.sm_scale)
+            return partial(self_attention, sm_scale=cfg.latent.sm_scale)
         if cfg.sliding_window is not None:
-            return partial(default_attn, window=cfg.sliding_window)
-        return default_attn
+            return partial(self_attention, window=cfg.sliding_window)
+        return self_attention
     if cfg.sliding_window is not None:
         if not getattr(attn_fn, "handles_window", False):
             raise ValueError(

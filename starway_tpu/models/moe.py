@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..ops import grouped_matmul
+
 
 def init_moe_params(key, n_layers: int, n_experts: int, d_model: int,
                     d_ff: int, dtype, swiglu: bool = False) -> dict:
@@ -345,7 +347,7 @@ def make_sharded_moe(mesh, *, ep_axis: str = "ep", dp_axis: str = "dp",
 # takes its ``top_k``, and this chip computes the part of the result that
 # the experts it HOLDS give (plus the shared expert, which every chip
 # holds).  No capacity: the (token, choice) pairs that landed here are
-# sorted by expert and go through a grouped matmul (ops/pallas_gmm.py)
+# sorted by expert and go through a grouped matmul (ops.grouped_matmul)
 # that reads only the experts that got a token.
 
 
@@ -393,7 +395,7 @@ def group_rows(local, n_held: int, tile_m: int):
     return src, row, tile_expert, ends_p[-1] // tile_m, sizes
 
 
-def routed_experts(xt, experts, gates, w, first: int, use_pallas=None):
+def routed_experts(xt, experts, gates, w, first: int):
     """The held experts' part of the layer for ``xt [T, D]``: ``experts`` /
     ``gates`` ``[T, k]`` from the router over ALL experts, ``w`` the held
     experts' stacked SwiGLU weights (``w_gate`` / ``w_up [G, D, F]``,
@@ -403,10 +405,6 @@ def routed_experts(xt, experts, gates, w, first: int, use_pallas=None):
     (:func:`~starway_tpu.models.llama.scan_segment`).  Returns ``(y [T,
     D], sizes [G])``: pairs that chose an expert held elsewhere add
     nothing here."""
-    from ..ops.pallas_gmm import gmm, gmm_lax
-
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
     t, d = xt.shape
     k = experts.shape[1]
     layer = w.get("layer")
@@ -418,8 +416,7 @@ def routed_experts(xt, experts, gates, w, first: int, use_pallas=None):
         experts.reshape(-1) - first, g, tile_m)
     x_rows = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)])[
         jnp.minimum(src // k, t)]
-    run = functools.partial(gmm if use_pallas else gmm_lax, tile_m=tile_m,
-                            layer=layer)
+    run = functools.partial(grouped_matmul, tile_m=tile_m, layer=layer)
     hidden = run(x_rows, w["w_gate"], tile_expert, n_live, w2=w["w_up"])
     out = run(hidden, w["w_down"], tile_expert, n_live)
     held = (row < out.shape[0]).reshape(t, k)

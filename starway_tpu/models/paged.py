@@ -43,8 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.pallas_paged import paged_decode_attention
-from ..parallel.sharding import per_head_shard
+from ..ops import paged_attention
+from ..ops.pallas_decode import kv_write_lax
 from .generate import _sample, _write_cached, cached_layer_scan, prefill
 from .llama import LlamaConfig, cfg_rope_tables, embed_tokens, matmul_w, rmsnorm
 from .serving import (SlotServer, _bucket, _named_jit, _on_weights_mesh,
@@ -58,16 +58,6 @@ def init_paged_pool(cfg: LlamaConfig, n_pages: int, page: int) -> dict:
     shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page, hd)
     return {"k": jnp.zeros(shape, cfg.compute_dtype),
             "v": jnp.zeros(shape, cfg.compute_dtype)}
-
-
-def _paged_attend(q, pool, layer, table, pos):
-    """The paged kernel over the stacked pool ``[L, n_pages, Hkv, page,
-    D]`` (heads at dim 2), per head shard under a tp mesh."""
-    return per_head_shard(
-        lambda q, k, v, table, pos, layer: paged_decode_attention(
-            q, k, v, table, pos, layer=layer),
-        (q, pool["k"], pool["v"]),
-        (table, pos, jnp.asarray(layer, jnp.int32)), head_dims=(1, 2, 2))
 
 
 def paged_decode_step(params, pool, table, token, pos, cfg: LlamaConfig,
@@ -96,7 +86,8 @@ def paged_decode_step(params, pool, table, token, pos, cfg: LlamaConfig,
         return _write_cached(pool, new, layer, offs, rows=pids)
 
     def attend(q, pool, layer):
-        return _paged_attend(q, pool, layer, table, pos)
+        return paged_attention(q, pool["k"], pool["v"], table, pos,
+                               layer=layer)
 
     h = embed_tokens(params, token, cfg)[:, None, :]
     h, out, _ = cached_layer_scan(params, pool, h, cos_p, sin_p, cfg, write,
@@ -214,15 +205,18 @@ def _compiled_paged_prefix_admit(cfg: LlamaConfig, s_bucket: int, page: int,
         def write(pool, new, layer):
             # [1, Hkv, s_bucket, D] -> one row a TOKEN at (pid, :, off).
             # Neighbouring tokens share a page tile, which the in-place
-            # kernel's rows must not (they would race): this write stays
-            # the XLA scatter, whatever the backend (PERF.md section 7).
-            tokens = {name: jnp.moveaxis(u[0], 1, 0)[:, :, None]
-                      for name, u in new.items()}
-            return _write_cached(pool, tokens, layer, offs, rows=pids_c,
-                                 use_pallas=False)
+            # kernel's rows must not (they would race): this write is
+            # kv_write_lax BY NAME, the XLA scatter on every backend, not
+            # ops.cache_write (PERF.md section 7).
+            k, v = kv_write_lax(
+                (pool["k"], pool["v"]),
+                tuple(jnp.moveaxis(new[name][0], 1, 0)[:, :, None]
+                      for name in ("k", "v")), layer, pids_c, offs)
+            return {**pool, "k": k, "v": v}
 
         def attend(q, pool, layer):
-            return _paged_attend(q, pool, layer, row, plen[None])
+            return paged_attention(q, pool["k"], pool["v"], row, plen[None],
+                                   layer=layer)
 
         from .llama import embed_tokens, head_logits
 

@@ -402,7 +402,7 @@ def _weights_mesh(params):
 def _on_weights_mesh(method):
     """Run a server method under the mesh its weights are sharded over:
     the compiled programs trace their Pallas attention calls per head
-    shard of that mesh (parallel/sharding.py per_head_shard) -- the TPU's
+    shard of that mesh (ops/dispatch.py per_head_shard) -- the TPU's
     compiler refuses a Mosaic kernel inside a GSPMD-partitioned program."""
 
     @functools.wraps(method)

@@ -28,7 +28,7 @@ column 0 broadcasts along lanes, the cheap direction:
 
 Both recompute p = exp(s - lse) from the forward's saved log-sum-exp, the
 standard flash trade (FLOPs for HBM).  `flash_attention` carries a
-jax.custom_vjp, so consumers (models/llama.py's default_attn on TPU)
+jax.custom_vjp, so consumers (:func:`self_attention` on TPU)
 differentiate through the kernel on TPU and through interpret mode in CPU
 tests.
 
@@ -52,7 +52,9 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .attention import NEG_BIG
+from . import dispatch
+from .attention import (NEG_BIG, blockwise_attention, partial_attention,
+                        repeat_kv)
 
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
@@ -865,3 +867,93 @@ def flash_attention(
     )
     o, _lse = _flash(q, k, v, cfg)
     return o
+
+
+def self_attention(q, k, v, *, causal: bool = True,
+                   window: Optional[int] = None,
+                   sm_scale: Optional[float] = None):
+    """Attention of a sequence over itself, the operation: the hand-tiled
+    flash kernel on a TPU (per shard of the heads under a ``tp`` mesh),
+    the lax blockwise scan elsewhere (bit-compatible algebra; both take
+    grouped kv).  ``window``: sliding-window causal — the flash kernel
+    masks, skips, and DMA-elides out-of-window blocks in forward AND
+    backward.  ``sm_scale``: the scores' multiplier where it is not
+    ``head_dim ** -0.5``."""
+    if not dispatch.use_kernels():
+        return blockwise_attention(q, k, v, causal=causal, window=window,
+                                   sm_scale=sm_scale)
+    return dispatch.per_head_shard(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                        window=window, sm_scale=sm_scale),
+        (q, k, v))
+
+
+# ---------------------------------------------------------------------------
+# the ring step (parallel/ring_attention.py): kernel + lax pairs, same contract
+# ---------------------------------------------------------------------------
+
+
+def flash_partial_lax(q, k, v, q_offset, kv_offset, *, causal: bool = True,
+                      sm_scale: Optional[float] = None,
+                      window: Optional[int] = None):
+    """:func:`flash_partial` in plain lax (grouped kv expanded), and the
+    only form that carries a sliding-window band."""
+    n_rep = q.shape[1] // k.shape[1]
+    return partial_attention(
+        q, repeat_kv(k, n_rep), repeat_kv(v, n_rep), q_offset=q_offset,
+        kv_offset=kv_offset, causal=causal, sm_scale=sm_scale, window=window)
+
+
+def flash_partial_bwd_lax(q, do, k, v, lse, delta, q_offset, kv_offset, *,
+                          causal: bool = True, sm_scale: float,
+                          window: Optional[int] = None):
+    """:func:`flash_partial_bwd` in plain lax, window-aware."""
+    b, hq, tq, d = q.shape
+    hkv, tk = k.shape[1], k.shape[2]
+    n_rep = hq // hkv
+    ke = repeat_kv(k, n_rep).astype(jnp.float32)
+    ve = repeat_kv(v, n_rep).astype(jnp.float32)
+    qf = q.astype(jnp.float32)
+    dof = do.astype(jnp.float32)
+    s = jnp.einsum("bhqd,bhkd->bhqk", qf, ke) * sm_scale
+    if causal:
+        q_pos = q_offset + jnp.arange(tq)
+        kv_pos = kv_offset + jnp.arange(tk)
+        keep = q_pos[:, None] >= kv_pos[None, :]
+        if window is not None:
+            keep = keep & (kv_pos[None, :] > q_pos[:, None] - window)
+        s = jnp.where(keep[None, None], s, NEG_BIG)
+    p = jnp.exp(s - lse[..., None])  # normalised; masked entries -> 0
+    dp = jnp.einsum("bhqd,bhkd->bhqk", dof, ve)
+    ds = p * (dp - delta[..., None])
+    dq = jnp.einsum("bhqk,bhkd->bhqd", ds, ke) * sm_scale
+    dke = jnp.einsum("bhqk,bhqd->bhkd", ds, qf) * sm_scale
+    dve = jnp.einsum("bhqk,bhqd->bhkd", p, dof)
+    dk = dke.reshape(b, hkv, n_rep, tk, d).sum(2)
+    dv = dve.reshape(b, hkv, n_rep, tk, d).sum(2)
+    return dq, dk, dv
+
+
+def ring_step(q, k, v, q_off, kv_off, causal, sm_scale, window=None):
+    """One kv shard's unnormalised partial, the operation: ``(o, m, l)``,
+    all f32.  The kernel on a TPU; a sliding-window band always takes the
+    lax twin (the kernel carries none; windowed rings skip most pairs
+    outright anyway)."""
+    if dispatch.use_kernels() and window is None:
+        return flash_partial(q, k, v, q_off, kv_off, causal=causal,
+                             sm_scale=sm_scale)
+    return flash_partial_lax(q, k, v, q_off, kv_off, causal=causal,
+                             sm_scale=sm_scale, window=window)
+
+
+def ring_step_bwd(q, do, k, v, lse, delta, q_off, kv_off, causal, sm_scale,
+                  window=None):
+    """One kv shard's gradient contributions, the operation: ``(dq, dk,
+    dv)``, f32, dk/dv grouped.  lse/delta are the globally merged
+    statistics.  Chosen as :func:`ring_step` chooses."""
+    if dispatch.use_kernels() and window is None:
+        return flash_partial_bwd(q, do, k, v, lse, delta, q_off, kv_off,
+                                 causal=causal, sm_scale=sm_scale)
+    return flash_partial_bwd_lax(q, do, k, v, lse, delta, q_off, kv_off,
+                                 causal=causal, sm_scale=sm_scale,
+                                 window=window)

@@ -1,31 +1,23 @@
 """Pallas TPU decode-attention kernel for KV-cache inference.
 
 Single-token decode is HBM-bandwidth bound: the whole KV cache streams
-through the core once per generated token.  The lax path
-(models/generate.py:_attend_cached) materialises ``repeat_kv`` — expanding
+through the core once per generated token.  The lax twin
+(:func:`decode_attention_lax`) materialises ``repeat_kv`` — expanding
 the grouped cache ``n_rep``× before the einsum — so a GQA model reads (and
 first writes) n_rep times more HBM than the cache actually holds.  This
-kernel keeps the cache narrow: the grid walks ``(batch*kv_head, kv_block)``,
-loads each cache block exactly once, and attends all ``n_rep`` query heads
-of the group against it as the rows of one MXU matmul.  Masking and the
-online-softmax accumulation are fused; fully-masked blocks (beyond the
-current position) are skipped via scalar-prefetched ``pos``.
+kernel keeps the cache narrow: it loads each cache block exactly once and
+attends all ``n_rep`` query heads of the group against it as the rows of
+one MXU matmul.  Masking and the online-softmax accumulation are fused;
+fully-masked blocks (beyond the current position) are skipped via
+scalar-prefetched ``pos``.
 
-Two variants share the same online-softmax block body:
-
-* **stream** (default): one grid cell per (batch, kv head); the whole T
-  sweep is a ``fori_loop`` with double-buffered manual DMA
-  (``make_async_copy``) — compute on block i overlaps the HBM stream of
-  block i+1, and the per-cell pipeline cost is paid b*hkv times total,
-  independent of T.  Structural response to the r2 measurement below.
-* **grid** (``stream=False``): one grid cell per kv block, Pallas-pipelined.
-  Decode is bandwidth-bound with a ~0.4 µs fixed cost per grid cell, so
-  small blocks drown in cell overhead (measured r2: block 128 at T=8192 =
-  128 cells ≈ 51 µs of overhead on a 60.8 µs total — slower than the lax
-  path); block 512 quarters the cell count.
-
-``bench.py --kernels decode_tune`` sweeps both variants x block sizes on
-real hardware; which is faster there has not been measured this round.
+The kernel (``sw_decode_attn_stream``) runs one grid cell per (batch, kv
+head): the whole T sweep is a ``fori_loop`` with double-buffered manual
+DMA (``make_async_copy``), so compute on block i overlaps the HBM stream
+of block i+1 and the per-cell pipeline cost is paid b*hkv times a call,
+whatever T.  (A form with one grid cell per kv block paid about 0.4 us a
+cell; it lost its pair on the chip and was deleted in PR 28: PERF.md
+section 6.)
 
 Same online-softmax algebra as ops/pallas_attention.py; layouts follow
 models/generate.py: ``q [B, Hq, 1, D]``, caches ``[B, Hkv, T, D]`` — or the
@@ -35,7 +27,7 @@ The stacked form is what serves.  A decode program that slices one layer
 out of the stacked cache, updates the slice and stores it into a second
 stacked array moves the whole cache four to six times a step (measured,
 PR 23: half the device time of a 16-layer, 24 x 2048 serving step).  So
-both kernels take the layer as a second prefetched scalar and index HBM
+the kernel takes the layer as a second prefetched scalar and indexes HBM
 by it, and :func:`kv_write` (``sw_kv_write``) puts the new entries into
 the SAME buffer (``input_output_aliases``): the cache is only ever an
 operand of these custom calls, never sliced, padded or scattered into by
@@ -51,14 +43,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .attention import NEG_BIG
+from . import dispatch
+from .attention import NEG_BIG, repeat_kv
 from .pallas_attention import _round_up
+from .quantize import dequantize_kv
 
 
 def _softmax_block_update(q, k, v, k_start, pos, m_scr, l_scr, acc_scr, *,
                           sm_scale: float, window: "int | None",
                           k_scale=None, v_scale=None, row_off=None):
-    """The one online-softmax block body both kernel variants share: score
+    """The one online-softmax block body the decode kernels share: score
     the group's query rows against one [block_k, D] cache block, mask by
     global position (and window), and fold into the m/l/acc scratches.
 
@@ -123,51 +117,6 @@ def _head_row(scales, h):
     return jnp.sum(jnp.where(rows == h, scales, 0.0), axis=0, keepdims=True)
 
 
-def _decode_kernel(pos_ref, layer_ref, q_ref, k_ref, v_ref, *refs,
-                   sm_scale: float, block_k: int, hkv: int,
-                   window: "int | None", quant: bool = False, n_q: int = 1):
-    if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_scr, l_scr, acc_scr = refs
-    ki = pl.program_id(1)
-    n_k = pl.num_programs(1)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_BIG)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    # Per-ROW positions (ragged batches): this grid cell serves batch row
-    # bh // hkv, whose own cursor bounds both masking and the DMA clamp.
-    # Multi-query (n_q > 1): queries span pos .. pos + n_q - 1.
-    pos = pos_ref[pl.program_id(0) // hkv]
-    k_start = ki * block_k
-
-    live = k_start <= pos + (n_q - 1)
-    if window is not None:
-        # Sliding window: this block must overlap (pos - window,
-        # pos + n_q - 1] (the union of every query's band).
-        live = live & (k_start + block_k - 1 > pos - window)
-
-    h = jax.lax.rem(pl.program_id(0), hkv)
-
-    @pl.when(live)
-    def _body():
-        _softmax_block_update(
-            q_ref[0], k_ref[...], v_ref[...], k_start, pos, m_scr, l_scr,
-            acc_scr, sm_scale=sm_scale, window=window,
-            k_scale=None if ks_ref is None else _head_row(ks_ref[...], h),
-            v_scale=None if vs_ref is None else _head_row(vs_ref[...], h),
-            row_off=_row_offsets(q_ref.shape[1], n_q))
-
-    @pl.when(ki == n_k - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
-
-
 def _decode_stream_kernel(pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *refs,
                           sm_scale: float, block_k: int, hkv: int,
                           window: "int | None", n_blocks: int,
@@ -176,12 +125,9 @@ def _decode_stream_kernel(pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *refs,
     single cell as a fori_loop over kv blocks with double-buffered manual
     DMA (compute on block i overlaps the HBM stream of block i+1).
 
-    Rationale: the grid kernel pays a fixed ~0.4 us pipeline cost per cell
-    (measured r2: 64 cells at block 128 ~= 51 us of a 60.8 us total — slower
-    than the lax path).  Here the cell count is b*hkv regardless of T, so
-    the overhead term is gone and the kernel's time is the max of the DMA
-    stream (~cache bytes / HBM bandwidth) and the (tiny) grouped-GQA
-    matmuls.
+    The cell count is b*hkv regardless of T, so the kernel's time is the
+    max of the DMA stream (~cache bytes / HBM bandwidth) and the (tiny)
+    grouped-GQA matmuls.
 
     ``k_hbm``/``v_hbm`` are the whole stacked caches ``[L, B, Hkv, T, D]``
     left in HBM; ``layer_ref`` (second prefetched scalar) picks the layer
@@ -282,8 +228,7 @@ def _pick_block(t: int, block_k: int, quant: bool) -> "int | None":
 
 def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
                      block_k: int = 512, interpret=None, window=None,
-                     stream: "bool | None" = None, k_scale=None,
-                     v_scale=None):
+                     k_scale=None, v_scale=None):
     """Cached decode attention (1..C query positions) without expanding
     the grouped cache.
 
@@ -304,7 +249,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
     (static): sliding-window attention over the last ``window`` positions
     — blocks entirely below the window are DMA-elided too, so a windowed
     decode streams ~window bytes of cache regardless of T.  Returns [B,
-    Hq, C, D].  Numerically matches models/generate.py:_attend_cached
+    Hq, C, D].  Numerically matches :func:`decode_attention_lax`
     (softmax in f32).
 
     ``k_scale``/``v_scale`` ([L, B, Hkv, T] f32, or [B, Hkv, T] with
@@ -322,19 +267,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
     bf16 up to 4096, of 8) costs what every length cost before: that
     layer is sliced out and padded, a copy of it a call.  Allocate
     multiples of 128.
-
-    ``stream`` (default True; ``STARWAY_DECODE_STREAM=0`` flips the
-    default): the double-buffered single-cell kernel
-    (:func:`_decode_stream_kernel`) — b*hkv grid cells total, per-cell
-    pipeline overhead independent of T; it is what serves on the chip
-    (chip_smoke.py phase c).  ``stream=False`` keeps the grid-pipelined
-    kernel (one cell per kv block) until ROADMAP D4 deletes it;
-    ``bench.py --kernels decode_tune`` sweeps both on-chip.
     """
-    if stream is None:
-        from ..config import decode_stream_enabled
-
-        stream = decode_stream_enabled()
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
     quant = k_scale is not None or v_scale is not None
@@ -385,79 +318,98 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     q_spec = pl.BlockSpec((1, rows, d), lambda bh, *_: (bh, 0, 0))
-    softmax_scratch = [
-        pltpu.VMEM((rows, 128), jnp.float32),
-        pltpu.VMEM((rows, 128), jnp.float32),
-        pltpu.VMEM((rows, d), jnp.float32),
-    ]
-    common = dict(sm_scale=sm_scale, block_k=block_k, hkv=hkv,
-                  window=None if window is None else int(window),
-                  quant=quant, n_q=n_q)
-
-    if stream:
-        any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
-        quant_scratch = [pltpu.VMEM((2, hkv, block_k), jnp.float32)] * (
-            2 * quant)
-        out = pl.pallas_call(
-            functools.partial(_decode_stream_kernel,
-                              n_blocks=t // block_k, **common),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(b * hkv,),
-                in_specs=[q_spec] + [any_spec] * (2 + 2 * quant),
-                out_specs=q_spec,
-                scratch_shapes=[
-                    pltpu.VMEM((2, block_k, d), k_cache.dtype),
-                    pltpu.VMEM((2, block_k, d), v_cache.dtype),
-                ] + quant_scratch + [
-                    pltpu.SemaphoreType.DMA((2, 4 if quant else 2)),
-                ] + softmax_scratch,
-            ),
-            out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), q.dtype),
-            interpret=interpret,
-            name="sw_decode_attn_stream",
-        )(pos_arr, layer_arr, qf, k_cache, v_cache, *scales)
-        return out.reshape(b, hkv, rows, d)[:, :, :n_rows, :].reshape(
-            b, hq, n_q, d)
-
-    # Clamp the K/V block index into the live range: the kernel body is
-    # skipped outside it (pl.when), and a repeated block index makes the
-    # Pallas pipeline elide the HBM copy entirely -- so a decode at pos
-    # streams only the blocks holding (pos - window, pos + n_q - 1], not
-    # the whole cache.  (pl.when alone skips compute, not DMA.)
-    def _live_block(bh, ki, pos_ref):
-        p = pos_ref[bh // hkv]
-        hi = (p + n_q - 1) // block_k
-        if window is None:
-            return jnp.minimum(ki, hi)
-        lo = jnp.maximum(p - window + 1, 0) // block_k
-        return jnp.clip(ki, lo, hi)
-
-    def _kv_index(bh, ki, pos_ref, layer_ref):
-        return (layer_ref[0], bh // hkv, jax.lax.rem(bh, hkv),
-                _live_block(bh, ki, pos_ref), 0)
-
-    def _scale_index(bh, ki, pos_ref, layer_ref):
-        return (layer_ref[0], bh // hkv, 0, _live_block(bh, ki, pos_ref))
-
-    kv_spec = pl.BlockSpec((None, None, None, block_k, d), _kv_index)
+    any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    quant_scratch = [pltpu.VMEM((2, hkv, block_k), jnp.float32)] * (
+        2 * quant)
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, **common),
+        functools.partial(
+            _decode_stream_kernel, sm_scale=sm_scale, block_k=block_k,
+            hkv=hkv, window=None if window is None else int(window),
+            n_blocks=t // block_k, quant=quant, n_q=n_q),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b * hkv, t // block_k),
-            in_specs=[q_spec, kv_spec, kv_spec] + [
-                pl.BlockSpec((None, None, hkv, block_k), _scale_index)
-            ] * (2 * quant),
+            grid=(b * hkv,),
+            in_specs=[q_spec] + [any_spec] * (2 + 2 * quant),
             out_specs=q_spec,
-            scratch_shapes=softmax_scratch,
+            scratch_shapes=[
+                pltpu.VMEM((2, block_k, d), k_cache.dtype),
+                pltpu.VMEM((2, block_k, d), v_cache.dtype),
+            ] + quant_scratch + [
+                pltpu.SemaphoreType.DMA((2, 4 if quant else 2)),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, d), jnp.float32),
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), q.dtype),
         interpret=interpret,
-        name="sw_decode_attn",
+        name="sw_decode_attn_stream",
     )(pos_arr, layer_arr, qf, k_cache, v_cache, *scales)
     return out.reshape(b, hkv, rows, d)[:, :, :n_rows, :].reshape(
         b, hq, n_q, d)
+
+
+def decode_attention_lax(q, k_cache, v_cache, pos, *, layer=None,
+                         window=None, k_scale=None, v_scale=None):
+    """:func:`decode_attention` in plain lax (softmax in f32): what runs
+    where Pallas does not, and what the kernel is tested against.  It
+    slices the layer out, dequantizes an int8 cache up front and expands
+    the grouped heads (``repeat_kv``): n_rep times the cache's bytes."""
+    if layer is not None:
+        k_cache, v_cache, k_scale, v_scale = (
+            None if a is None
+            else jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for a in (k_cache, v_cache, k_scale, v_scale))
+    if k_scale is not None:
+        k_cache = dequantize_kv(k_cache, k_scale, q.dtype)
+        v_cache = dequantize_kv(v_cache, v_scale, q.dtype)
+    n_rep = q.shape[1] // k_cache.shape[1]
+    k = repeat_kv(k_cache, n_rep)
+    v = repeat_kv(v_cache, n_rep)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
+    s = s / (q.shape[-1] ** 0.5)
+    kv_pos = jnp.arange(k.shape[2])[None, None, None, :]
+    qp = (jnp.asarray(pos).reshape(-1)[:, None, None, None]
+          + jnp.arange(q.shape[2])[None, None, :, None])
+    keep = kv_pos <= qp
+    if window is not None:
+        keep = keep & (kv_pos > qp - window)
+    s = jnp.where(keep, s, NEG_BIG)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+
+
+def cached_attention(q, k_cache, v_cache, pos, *, layer=None, window=None,
+                     k_scale=None, v_scale=None):
+    """Decode attention over a grouped k/v cache, the operation: the
+    arguments of :func:`decode_attention`.  On a TPU the kernel streams
+    the grouped cache once where it lies (an ``n_rep``-fold saving of HBM
+    bandwidth on the bandwidth-bound decode step, and only ~window bytes
+    of it under a sliding window), per shard of the heads under a ``tp``
+    mesh; elsewhere :func:`decode_attention_lax`."""
+    if not dispatch.use_kernels():
+        return decode_attention_lax(q, k_cache, v_cache, pos, layer=layer,
+                                    window=window, k_scale=k_scale,
+                                    v_scale=v_scale)
+    if layer is None:  # one layer's caches: a stack of one
+        k_cache, v_cache, k_scale, v_scale = (
+            None if a is None else a[None]
+            for a in (k_cache, v_cache, k_scale, v_scale))
+        layer = 0
+    scales = () if k_scale is None else (k_scale, v_scale)
+
+    def kernel(q, k, v, *rest):  # rest = (*scales, pos, layer)
+        ks, vs = rest[:-2] or (None, None)
+        return decode_attention(q, k, v, rest[-2], layer=rest[-1],
+                                window=window, k_scale=ks, v_scale=vs)
+
+    # Heads (dim 1 of q, dim 2 of the stacked caches and scales) shard
+    # alike; pos (a scalar, or one cursor per batch row) and the layer
+    # index are the same on every shard.
+    return dispatch.per_head_shard(
+        kernel, (q, k_cache, v_cache, *scales),
+        (jnp.asarray(pos, jnp.int32), jnp.asarray(layer, jnp.int32)),
+        head_dims=(1,) + (2,) * (2 + len(scales)))
 
 
 # ------------------------------------------------- latent (MLA) attention
@@ -569,6 +521,15 @@ def mla_decode_attention(q, latent, pos, *, rank: int, sm_scale: float,
     )(pos_arr, jnp.asarray(layer, jnp.int32).reshape(1),
       q.reshape(b, rows, w), latent)
     return out.reshape(b, h, n_q, rank)
+
+
+def latent_attention(q, latent, pos, *, rank: int, sm_scale: float, layer=0):
+    """Decode attention over a latent cache, the operation: the kernel
+    (:func:`mla_decode_attention`) on a TPU, its lax twin elsewhere.  One
+    row a position serves every head, so there is no head to shard by."""
+    fn = (mla_decode_attention if dispatch.use_kernels()
+          else mla_decode_attention_lax)
+    return fn(q, latent, pos, rank=rank, sm_scale=sm_scale, layer=layer)
 
 
 # ------------------------------------------------------ the in-place write
@@ -719,3 +680,19 @@ def kv_write(caches, updates, layer, rows, pos, *, interpret=None):
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       jnp.asarray(rows, jnp.int32), start, lo, lo + c, *updates, *caches)
     return tuple(out)
+
+
+def cache_write(caches, updates, layer, rows, pos):
+    """The in-place cache write, the operation: the arguments of
+    :func:`kv_write`.  On a TPU the kernel, per shard of the heads under a
+    ``tp`` mesh (dim 2 of the caches, dim 1 of the updates); elsewhere
+    :func:`kv_write_lax`.  A caller whose rows share a tile (one row a
+    token of a page: models/paged.py's prefix admit) must take
+    :func:`kv_write_lax` by name: the kernel's rows would race."""
+    if not dispatch.use_kernels():
+        return kv_write_lax(caches, updates, layer, rows, pos)
+    n = len(caches)
+    return dispatch.per_head_shard(
+        lambda *a: kv_write(a[:n], a[n:2 * n], *a[2 * n:]),
+        tuple(caches) + tuple(updates), (layer, rows, pos),
+        head_dims=(2,) * n + (1,) * n, out_head_dims=(2,) * n)

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import dispatch
 from .pallas_attention import _round_up
 
 
@@ -88,3 +89,19 @@ def int8_matmul(x, wq, scale, *, block_f: "int | None" = None,
         name="sw_gemv_int8",
     )(x, wq, scale2)
     return out[:m, :f]
+
+
+def int8_matmul_lax(x, wq, scale):
+    """:func:`int8_matmul` in plain lax: dequantise, then matmul in f32.
+    What runs where Pallas does not, and what the kernel is tested
+    against."""
+    return (x.astype(jnp.float32)
+            @ (wq.astype(jnp.float32) * scale[None, :])).astype(x.dtype)
+
+
+def quantized_matmul(x, wq, scale):
+    """``x [M, D] @ (wq int8 [D, F] * scale [F])``, the operation: on a TPU
+    the kernel streams the weights at half width with the scale folded
+    into the product; elsewhere dequantise-then-matmul."""
+    fn = int8_matmul if dispatch.use_kernels() else int8_matmul_lax
+    return fn(x, wq, scale)

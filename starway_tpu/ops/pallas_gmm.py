@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import dispatch
+
 # The double-buffered weight blocks of the gated form at K = 7168 are more
 # than the compiler's default scoped limit (16 MiB) leaves room for.
 _VMEM_LIMIT_BYTES = 64 << 20
@@ -134,3 +136,12 @@ def gmm(x, w, tile_expert, n_live, *, tile_m: int, w2=None, layer=None,
         name="sw_moe_gmm",
     )(jnp.asarray(tile_expert, jnp.int32), n_live,
       jnp.asarray(layer, jnp.int32).reshape(1), x, *weights)
+
+
+def grouped_matmul(x, w, tile_expert, n_live, *, tile_m: int, w2=None,
+                   layer=None):
+    """The grouped matmul, the operation: the arguments of :func:`gmm`,
+    which runs on a TPU; :func:`gmm_lax` elsewhere.  The experts a chip
+    holds are whole, so there is no head to shard by."""
+    fn = gmm if dispatch.use_kernels() else gmm_lax
+    return fn(x, w, tile_expert, n_live, tile_m=tile_m, w2=w2, layer=layer)

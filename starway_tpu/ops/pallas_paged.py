@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import dispatch
 from .attention import NEG_BIG
 from .pallas_attention import _round_up
 from .pallas_decode import _row_offsets, _softmax_block_update
@@ -173,6 +174,22 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, *, layer=None,
     )(meta, jnp.asarray(layer, jnp.int32).reshape(1), qf, k_pool, v_pool)
     return out.reshape(b, hkv, rows, d)[:, :, :n_rows, :].reshape(
         b, hq, n_q, d)
+
+
+def paged_attention(q, k_pool, v_pool, table, pos, *, layer=None):
+    """Decode attention over the paged pool, the operation: the arguments
+    of :func:`paged_decode_attention` (pools ``[L, n_pages, Hkv, page, D]``
+    with ``layer``: heads at dim 2), per shard of the heads under a ``tp``
+    mesh.  It has ONE implementation, interpreted off the chip: no lax
+    twin is written for it (tests use :func:`gather_logical` and the dense
+    twin as the oracle)."""
+    if layer is None:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    return dispatch.per_head_shard(
+        lambda q, k, v, table, pos, layer: paged_decode_attention(
+            q, k, v, table, pos, layer=layer),
+        (q, k_pool, v_pool),
+        (table, pos, jnp.asarray(layer, jnp.int32)), head_dims=(1, 2, 2))
 
 
 def gather_logical(pool, table):

@@ -14,16 +14,16 @@ robust to mesh axis ordering.
 
 Both ring variants carry a ``jax.custom_vjp``:
 
-* forward: per-step partials come from the Pallas kernel
-  (ops/pallas_attention.py::flash_partial, ~7x the lax step rate on TPU) or
-  from the lax path elsewhere, selected per-backend at trace time.
+* forward: per-step partials come from ``ops.ring_step``: the Pallas
+  kernel (ops/pallas_attention.py::flash_partial, ~7x the lax step rate
+  on TPU) or its lax twin elsewhere, chosen there at trace time.
 * backward: a second ring pass.  Each device keeps its q/do/lse/delta
   resident and accumulates dq locally, while dk/dv accumulators *rotate
   with their kv shard* -- after the full rotation each shard's gradient
   arrives back at its home device having summed every device's
   contribution.  Per-step math uses the globally merged lse/delta, so each
   step's contribution is exactly its slice of the full attention gradient
-  (ops/pallas_attention.py::flash_partial_bwd).
+  (``ops.ring_step_bwd``).
 """
 
 from __future__ import annotations
@@ -37,80 +37,10 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..ops.attention import (
-    NEG_BIG,
-    finalize_partial,
-    merge_partials,
-    partial_attention,
-    repeat_kv,
-    zero_partial,
-)
+from ..ops import ring_step, ring_step_bwd
+from ..ops.attention import finalize_partial, merge_partials, zero_partial
 from ..ops.collectives import ring_shift
 from .sharding import shard_map_fn
-
-
-def _use_kernel_default() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-# ---------------------------------------------------------------------------
-# per-step primitives (kernel + lax pairs, same contract)
-# ---------------------------------------------------------------------------
-
-
-def _step_fwd(q, k, v, q_off, kv_off, causal, sm_scale, use_kernel,
-              window=None):
-    """One kv shard's unnormalised partial: (o f32, m f32, l f32).
-
-    ``window``: sliding-window band (requires causal) — routed through the
-    lax path (the flash_partial kernel carries no band support; windowed
-    rings skip most pairs outright anyway, see _ring_fwd_impl)."""
-    if use_kernel and window is None:
-        from ..ops.pallas_attention import flash_partial
-
-        return flash_partial(q, k, v, q_off, kv_off, causal=causal,
-                             sm_scale=sm_scale)
-    n_rep = q.shape[1] // k.shape[1]
-    return partial_attention(
-        q, repeat_kv(k, n_rep), repeat_kv(v, n_rep),
-        q_offset=q_off, kv_offset=kv_off, causal=causal, sm_scale=sm_scale,
-        window=window,
-    )
-
-
-def _step_bwd(q, do, k, v, lse, delta, q_off, kv_off, causal, sm_scale,
-              use_kernel, window=None):
-    """One kv shard's gradient contributions: (dq, dk, dv), f32, dk/dv
-    grouped.  lse/delta are the globally merged statistics."""
-    if use_kernel and window is None:
-        from ..ops.pallas_attention import flash_partial_bwd
-
-        return flash_partial_bwd(q, do, k, v, lse, delta, q_off, kv_off,
-                                 causal=causal, sm_scale=sm_scale)
-    b, hq, tq, d = q.shape
-    hkv, tk = k.shape[1], k.shape[2]
-    n_rep = hq // hkv
-    ke = repeat_kv(k, n_rep).astype(jnp.float32)
-    ve = repeat_kv(v, n_rep).astype(jnp.float32)
-    qf = q.astype(jnp.float32)
-    dof = do.astype(jnp.float32)
-    s = jnp.einsum("bhqd,bhkd->bhqk", qf, ke) * sm_scale
-    if causal:
-        q_pos = q_off + jnp.arange(tq)
-        kv_pos = kv_off + jnp.arange(tk)
-        keep = q_pos[:, None] >= kv_pos[None, :]
-        if window is not None:
-            keep = keep & (kv_pos[None, :] > q_pos[:, None] - window)
-        s = jnp.where(keep[None, None], s, NEG_BIG)
-    p = jnp.exp(s - lse[..., None])  # normalised; masked entries -> 0
-    dp = jnp.einsum("bhqd,bhkd->bhqk", dof, ve)
-    ds = p * (dp - delta[..., None])
-    dq = jnp.einsum("bhqk,bhkd->bhqd", ds, ke) * sm_scale
-    dke = jnp.einsum("bhqk,bhqd->bhkd", ds, qf) * sm_scale
-    dve = jnp.einsum("bhqk,bhqd->bhkd", p, dof)
-    dk = dke.reshape(b, hkv, n_rep, tk, d).sum(2)
-    dv = dve.reshape(b, hkv, n_rep, tk, d).sum(2)
-    return dq, dk, dv
 
 
 def _lse_of(acc):
@@ -152,8 +82,7 @@ def _ring_steps(n: int, t_local: int, window) -> int:
     return min(n, (window - 2 + t_local) // t_local + 1)
 
 
-def _ring_fwd_impl(q, k, v, axis_name, causal, sm_scale, use_kernel,
-                   window=None):
+def _ring_fwd_impl(q, k, v, axis_name, causal, sm_scale, window=None):
     n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     t_local = q.shape[2]
@@ -165,8 +94,8 @@ def _ring_fwd_impl(q, k, v, axis_name, causal, sm_scale, use_kernel,
         kv_off = src * t_local
 
         def live_part(_):
-            return _step_fwd(q, k_cur, v_cur, q_off, kv_off, causal,
-                             sm_scale, use_kernel, window)
+            return ring_step(q, k_cur, v_cur, q_off, kv_off, causal,
+                             sm_scale, window)
 
         if window is None:
             part = live_part(None)
@@ -193,7 +122,7 @@ def _ring_fwd_impl(q, k, v, axis_name, causal, sm_scale, use_kernel,
 
 
 def _ring_bwd_impl(q, k, v, out, lse, do, axis_name, causal, sm_scale,
-                   use_kernel, window=None):
+                   window=None):
     n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     t_local = q.shape[2]
@@ -207,8 +136,8 @@ def _ring_bwd_impl(q, k, v, out, lse, do, axis_name, causal, sm_scale,
         kv_off = src * t_local
 
         def live_grads(_):
-            return _step_bwd(q, do, k_cur, v_cur, lse, delta, q_off,
-                             kv_off, causal, sm_scale, use_kernel, window)
+            return ring_step_bwd(q, do, k_cur, v_cur, lse, delta, q_off,
+                                 kv_off, causal, sm_scale, window)
 
         if window is None:
             dq_c, dk_c, dv_c = live_grads(None)
@@ -245,23 +174,21 @@ def _ring_bwd_impl(q, k, v, out, lse, do, axis_name, causal, sm_scale,
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _ring(q, k, v, axis_name, causal, sm_scale, use_kernel, window):
-    out, _ = _ring_fwd_impl(q, k, v, axis_name, causal, sm_scale, use_kernel,
-                            window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _ring(q, k, v, axis_name, causal, sm_scale, window):
+    out, _ = _ring_fwd_impl(q, k, v, axis_name, causal, sm_scale, window)
     return out
 
 
-def _ring_vjp_fwd(q, k, v, axis_name, causal, sm_scale, use_kernel, window):
-    out, lse = _ring_fwd_impl(q, k, v, axis_name, causal, sm_scale,
-                              use_kernel, window)
+def _ring_vjp_fwd(q, k, v, axis_name, causal, sm_scale, window):
+    out, lse = _ring_fwd_impl(q, k, v, axis_name, causal, sm_scale, window)
     return out, (q, k, v, out, lse)
 
 
-def _ring_vjp_bwd(axis_name, causal, sm_scale, use_kernel, window, res, do):
+def _ring_vjp_bwd(axis_name, causal, sm_scale, window, res, do):
     q, k, v, out, lse = res
     return _ring_bwd_impl(q, k, v, out, lse, do, axis_name, causal, sm_scale,
-                          use_kernel, window)
+                          window)
 
 
 _ring.defvjp(_ring_vjp_fwd, _ring_vjp_bwd)
@@ -269,7 +196,6 @@ _ring.defvjp(_ring_vjp_fwd, _ring_vjp_bwd)
 
 def ring_attention(q, k, v, axis_name: str, *, causal: bool = True,
                    sm_scale: Optional[float] = None,
-                   use_kernel: Optional[bool] = None,
                    window: Optional[int] = None):
     """Per-device body (call inside shard_map): q/k/v are local sequence
     shards ``[B, H, T_local, D]``; returns the local output shard.
@@ -295,15 +221,13 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = True,
     """
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    if use_kernel is None:
-        use_kernel = _use_kernel_default()
     if window is not None:
         if not causal:
             raise ValueError("window requires causal attention")
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
     return _ring(q, k, v, axis_name, bool(causal), float(sm_scale),
-                 bool(use_kernel), None if window is None else int(window))
+                 None if window is None else int(window))
 
 
 # ---------------------------------------------------------------------------
@@ -347,7 +271,7 @@ def _zz_offsets(my, src, n, sb):
     )
 
 
-def _zz_fwd_impl(q, k, v, axis_name, sm_scale, use_kernel):
+def _zz_fwd_impl(q, k, v, axis_name, sm_scale):
     n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     sb = q.shape[2] // 2
@@ -363,22 +287,22 @@ def _zz_fwd_impl(q, k, v, axis_name, sm_scale, use_kernel):
         # this pair's causal mask is provably all-ones, so skip the mask.
         acc_hi = merge_partials(
             acc_hi,
-            _step_fwd(q_hi, k_lo, v_lo, o["off_hi"], o["src_lo"], False,
-                      sm_scale, use_kernel),
+            ring_step(q_hi, k_lo, v_lo, o["off_hi"], o["src_lo"], False,
+                      sm_scale),
         )
         acc_lo = lax.cond(
             my >= src,
             lambda a: merge_partials(
-                a, _step_fwd(q_lo, k_lo, v_lo, o["off_lo"], o["src_lo"],
-                             True, sm_scale, use_kernel)),
+                a, ring_step(q_lo, k_lo, v_lo, o["off_lo"], o["src_lo"],
+                             True, sm_scale)),
             lambda a: a,
             acc_lo,
         )
         acc_hi = lax.cond(
             my <= src,
             lambda a: merge_partials(
-                a, _step_fwd(q_hi, k_hi, v_hi, o["off_hi"], o["src_hi"],
-                             True, sm_scale, use_kernel)),
+                a, ring_step(q_hi, k_hi, v_hi, o["off_hi"], o["src_hi"],
+                             True, sm_scale)),
             lambda a: a,
             acc_hi,
         )
@@ -402,7 +326,7 @@ def _zz_fwd_impl(q, k, v, axis_name, sm_scale, use_kernel):
     return out, lse
 
 
-def _zz_bwd_impl(q, k, v, out, lse, do, axis_name, sm_scale, use_kernel):
+def _zz_bwd_impl(q, k, v, out, lse, do, axis_name, sm_scale):
     n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     sb = q.shape[2] // 2
@@ -423,24 +347,22 @@ def _zz_bwd_impl(q, k, v, out, lse, do, axis_name, sm_scale, use_kernel):
         v_lo, v_hi = v_cur[:, :, :sb], v_cur[:, :, sb:]
 
         # Pair hi-lo: always live, mask-free.
-        dqh, dkl, dvl = _step_bwd(q_hi, do_hi, k_lo, v_lo, lse_hi, d_hi,
-                                  o["off_hi"], o["src_lo"], False, sm_scale,
-                                  use_kernel)
+        dqh, dkl, dvl = ring_step_bwd(q_hi, do_hi, k_lo, v_lo, lse_hi, d_hi,
+                                      o["off_hi"], o["src_lo"], False,
+                                      sm_scale)
         # Pair lo-lo: live iff my >= src (diagonal at equality).
         z3 = (jnp.zeros(q_lo.shape, jnp.float32), kv_zero, kv_zero)
         dql, dkl2, dvl2 = lax.cond(
             my >= src,
-            lambda: _step_bwd(q_lo, do_lo, k_lo, v_lo, lse_lo, d_lo,
-                              o["off_lo"], o["src_lo"], True, sm_scale,
-                              use_kernel),
+            lambda: ring_step_bwd(q_lo, do_lo, k_lo, v_lo, lse_lo, d_lo,
+                                  o["off_lo"], o["src_lo"], True, sm_scale),
             lambda: z3,
         )
         # Pair hi-hi: live iff my <= src.
         dqh2, dkh, dvh = lax.cond(
             my <= src,
-            lambda: _step_bwd(q_hi, do_hi, k_hi, v_hi, lse_hi, d_hi,
-                              o["off_hi"], o["src_hi"], True, sm_scale,
-                              use_kernel),
+            lambda: ring_step_bwd(q_hi, do_hi, k_hi, v_hi, lse_hi, d_hi,
+                                  o["off_hi"], o["src_hi"], True, sm_scale),
             lambda: z3,
         )
         dq = dq + jnp.concatenate([dql, dqh + dqh2], axis=2)
@@ -463,29 +385,27 @@ def _zz_bwd_impl(q, k, v, out, lse, do, axis_name, sm_scale, use_kernel):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _zigzag(q, k, v, axis_name, sm_scale, use_kernel):
-    out, _ = _zz_fwd_impl(q, k, v, axis_name, sm_scale, use_kernel)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _zigzag(q, k, v, axis_name, sm_scale):
+    out, _ = _zz_fwd_impl(q, k, v, axis_name, sm_scale)
     return out
 
 
-def _zz_vjp_fwd(q, k, v, axis_name, sm_scale, use_kernel):
-    out, lse = _zz_fwd_impl(q, k, v, axis_name, sm_scale, use_kernel)
+def _zz_vjp_fwd(q, k, v, axis_name, sm_scale):
+    out, lse = _zz_fwd_impl(q, k, v, axis_name, sm_scale)
     return out, (q, k, v, out, lse)
 
 
-def _zz_vjp_bwd(axis_name, sm_scale, use_kernel, res, do):
+def _zz_vjp_bwd(axis_name, sm_scale, res, do):
     q, k, v, out, lse = res
-    return _zz_bwd_impl(q, k, v, out, lse, do, axis_name, sm_scale,
-                        use_kernel)
+    return _zz_bwd_impl(q, k, v, out, lse, do, axis_name, sm_scale)
 
 
 _zigzag.defvjp(_zz_vjp_fwd, _zz_vjp_bwd)
 
 
 def zigzag_ring_attention(q, k, v, axis_name: str, *,
-                          sm_scale: Optional[float] = None,
-                          use_kernel: Optional[bool] = None):
+                          sm_scale: Optional[float] = None):
     """Per-device body (call inside shard_map) for causal zigzag ring
     attention.  Local shards are in zigzag layout (see :func:`zigzag_indices`):
     the first half of the local sequence is original block ``my`` (global
@@ -511,9 +431,7 @@ def zigzag_ring_attention(q, k, v, axis_name: str, *,
         )
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    if use_kernel is None:
-        use_kernel = _use_kernel_default()
-    return _zigzag(q, k, v, axis_name, float(sm_scale), bool(use_kernel))
+    return _zigzag(q, k, v, axis_name, float(sm_scale))
 
 
 def zigzag_wrap(inner, n: int):
@@ -536,8 +454,7 @@ def zigzag_wrap(inner, n: int):
 
 
 def make_zigzag_ring_attention(mesh, axis_name: str = "sp", *,
-                               sm_scale: Optional[float] = None,
-                               use_kernel: Optional[bool] = None):
+                               sm_scale: Optional[float] = None):
     """Jitted global-view causal ring attention in the load-balanced zigzag
     layout: q/k/v are natural-order global arrays ``[B, H, S, D]`` sharded
     on the sequence dimension; the permutation into and out of zigzag order
@@ -545,8 +462,7 @@ def make_zigzag_ring_attention(mesh, axis_name: str = "sp", *,
     spec = P(None, None, axis_name, None)
 
     def local(q, k, v):
-        return zigzag_ring_attention(q, k, v, axis_name, sm_scale=sm_scale,
-                                     use_kernel=use_kernel)
+        return zigzag_ring_attention(q, k, v, axis_name, sm_scale=sm_scale)
 
     inner = shard_map_fn(mesh, local, in_specs=(spec, spec, spec), out_specs=spec)
     return jax.jit(zigzag_wrap(inner, mesh.shape[axis_name]))
@@ -554,7 +470,6 @@ def make_zigzag_ring_attention(mesh, axis_name: str = "sp", *,
 
 def make_ring_attention(mesh, axis_name: str = "sp", *, causal: bool = True,
                         sm_scale: Optional[float] = None,
-                        use_kernel: Optional[bool] = None,
                         window: Optional[int] = None):
     """Jitted global-view ring attention: q/k/v are global arrays sharded on
     the sequence dimension over ``axis_name`` ([B, H, S, D], S sharded).
@@ -563,7 +478,6 @@ def make_ring_attention(mesh, axis_name: str = "sp", *, causal: bool = True,
 
     def local(q, k, v):
         return ring_attention(q, k, v, axis_name, causal=causal,
-                              sm_scale=sm_scale, use_kernel=use_kernel,
-                              window=window)
+                              sm_scale=sm_scale, window=window)
 
     return jax.jit(shard_map_fn(mesh, local, in_specs=(spec, spec, spec), out_specs=spec))

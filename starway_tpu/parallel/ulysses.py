@@ -24,7 +24,8 @@ import jax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..ops.attention import blockwise_attention, repeat_kv
+from ..ops import self_attention
+from ..ops.attention import repeat_kv
 from .sharding import shard_map_fn
 
 
@@ -56,21 +57,12 @@ def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = True,
     q2 = lax.all_to_all(q, axis_name, split_axis=1, concat_axis=2, tiled=True)
     k2 = lax.all_to_all(k, axis_name, split_axis=1, concat_axis=2, tiled=True)
     v2 = lax.all_to_all(v, axis_name, split_axis=1, concat_axis=2, tiled=True)
-    if jax.default_backend() == "tpu":
-        # Same dispatch as models/llama.py:default_attn: the hand-tiled
-        # flash kernel takes GROUPED (narrow) kv and, with a window,
-        # DMA-elides out-of-band tiles — so windowed Ulysses wall-clock
-        # scales with the band, matching the ring path.
-        from ..ops.pallas_attention import flash_attention
-
-        o2 = flash_attention(q2, k2, v2, causal=causal, sm_scale=sm_scale,
-                             window=window)
-    else:
-        if n_rep > 1:
-            k2 = repeat_kv(k2, n_rep)
-            v2 = repeat_kv(v2, n_rep)
-        o2 = blockwise_attention(q2, k2, v2, causal=causal,
-                                 sm_scale=sm_scale, window=window)
+    # Grouped (narrow) kv goes in as it is: the flash kernel indexes it
+    # and, with a window, DMA-elides out-of-band tiles, so windowed
+    # Ulysses wall-clock scales with the band, matching the ring path;
+    # the lax twin expands it.
+    o2 = self_attention(q2, k2, v2, causal=causal, sm_scale=sm_scale,
+                        window=window)
     # Restore: [B, H/n, T, D] -> [B, H, T/n, D].
     return lax.all_to_all(o2, axis_name, split_axis=2, concat_axis=1, tiled=True)
 

@@ -94,6 +94,7 @@ this is a test harness, not a production relay.
 
 from __future__ import annotations
 
+import select
 import socket
 import struct
 import threading
@@ -295,6 +296,10 @@ class FaultProxy:
                 continue
             try:
                 up = socket.create_connection(self.target, timeout=5)
+                # The 5 s bound the CONNECT only: left on the socket it is
+                # an I/O timeout, and a pair whose server side stays quiet
+                # for 5 s (a loaded box) was reset by the pump's recv().
+                up.settimeout(None)
                 up.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             except OSError:
                 down.close()
@@ -438,15 +443,17 @@ class FaultProxy:
         buf = bytearray()
         held: list = []   # SEQ/CSUM prefix units awaiting their frame
         reorder_hold: Optional[bytes] = None
-        try:
-            src.settimeout(0.2)  # idle tick: a held swap must not hang a quiet stream
-        except OSError:
-            pass
         while not self._stopping.is_set() and not pair.dead:
             while (self._stalled.is_set() and not self._stopping.is_set()
                    and not pair.dead):
                 time.sleep(0.01)
             try:
+                # Idle tick (a held swap must not hang a quiet stream) by
+                # select, NOT by a timeout on the socket: that would also
+                # bound the other pump's sendall() INTO this socket, and a
+                # reader 0.2 s late (a loaded box) reset the whole pair.
+                if not select.select([src], [], [], 0.2)[0]:
+                    raise socket.timeout
                 data = src.recv(_CHUNK)
             except socket.timeout:
                 if reorder_hold is not None:
@@ -457,7 +464,7 @@ class FaultProxy:
                     if not self._forward_unit(pair, dst, unit, is_c2s):
                         return
                 continue
-            except OSError:
+            except (OSError, ValueError):  # ValueError: select on a closed fd
                 # RST propagation, like the raw pump above.
                 if not self._partitioned.is_set():
                     pair.kill(rst=True)

@@ -98,3 +98,18 @@ def rolling_primitive_oracle(params, cfg):
         return np.asarray(toks, np.int32)
 
     return oracle
+
+
+@pytest.fixture
+def force_kernels(monkeypatch):
+    """``force_kernels(on)``: substitute the ONE decision of
+    ``starway_tpu.ops`` (``dispatch.use_kernels``) until the test ends.
+    ``True`` makes every operation traced from then on take its Pallas
+    kernel (interpreted on the CPU), ``False`` its lax twin.  For the few
+    tests that need a whole PROGRAM on one side; a test of one side calls
+    the kernel (``interpret=True``) or the ``*_lax`` twin by name."""
+    def force(on: bool) -> None:
+        monkeypatch.setattr("starway_tpu.ops.dispatch.use_kernels",
+                            lambda: on)
+
+    return force

@@ -111,16 +111,24 @@ def _ring_step(*, bwd=False, causal=True, t=2048):
             q, q, kv, kv, stat, stat, off, off)
 
 
-def _decode(hkv, *, c=1, int8=False, stream=True, window=None, b=8, t=2048):
+def _decode(hkv, *, c=1, int8=False, window=None, b=8, t=2048, layers=None):
+    """``layers``: the scan-stacked cache ``[layers, b, hkv, t, D]`` read
+    through a traced layer index, as the serving chunk reads it."""
     from starway_tpu.ops.pallas_decode import decode_attention
 
-    cache = _s((b, hkv, t, D), I8 if int8 else BF16)
+    lead = () if layers is None else (layers,)
+    cache = _s(lead + (b, hkv, t, D), I8 if int8 else BF16)
     args = [_s((b, HQ, c, D), BF16), cache, cache, _s((b,), I32)]
+    if layers is not None:
+        args.append(_s((), I32))
     if int8:
-        args += [_s((b, hkv, t), F32)] * 2
+        args += [_s(lead + (b, hkv, t), F32)] * 2
 
-    def fn(q, k, v, pos, ks=None, vs=None):
-        return decode_attention(q, k, v, pos, interpret=False, stream=stream,
+    def fn(q, k, v, pos, *rest):
+        layer, scales = ((None, rest) if layers is None
+                         else (rest[0], rest[1:]))
+        ks, vs = scales or (None, None)
+        return decode_attention(q, k, v, pos, layer=layer, interpret=False,
                                 window=window, k_scale=ks, v_scale=vs)
 
     return fn, tuple(args)
@@ -207,18 +215,19 @@ KERNELS = {
     "decode_bf16": lambda: _decode(HKV),
     "decode_bf16_c4": lambda: _decode(HKV, c=4),
     "decode_bf16_windowed": lambda: _decode(HKV, window=1024),
-    "decode_bf16_grid": lambda: _decode(HKV, stream=False),
-    "decode_bf16_grid_c4": lambda: _decode(HKV, stream=False, c=4),
+    # mistral7b.chat_closed's own decode attention: 24 slots x 2048, one
+    # layer of the 16 stacked, by a traced index.
+    "decode_bf16_chat_closed": lambda: _decode(HKV, b=24, layers=16),
+    # Lengths no block divides (_pick_block is None: the layer is sliced
+    # out and padded to whole lane tiles; ROADMAP D11).
+    "decode_bf16_padded": lambda: _decode(HKV, t=4104, layers=2),
+    "decode_int8_padded": lambda: _decode(HKV, int8=True, t=2000, layers=2),
     "decode_bf16_mha": lambda: _decode(MHA),
     # Refused before PR 21: the [B*Hkv, T] scale operand was sliced one
     # row at a time, below the (8, 128) tile.
     "decode_int8": lambda: _decode(HKV, int8=True),
     "decode_int8_c4": lambda: _decode(HKV, int8=True, c=4),
     "decode_int8_windowed": lambda: _decode(HKV, int8=True, window=1024),
-    "decode_int8_grid": lambda: _decode(HKV, int8=True, stream=False),
-    "decode_int8_grid_c4": lambda: _decode(HKV, int8=True, stream=False, c=4),
-    "decode_int8_grid_windowed": lambda: _decode(
-        HKV, int8=True, stream=False, window=1024),
     "decode_int8_mha": lambda: _decode(MHA, int8=True),
     "paged_page16": lambda: _paged(16),
     "paged_page64": lambda: _paged(64),

@@ -63,6 +63,14 @@ async def gen_server_client(port):
     client = Client()
     server.listen(SERVER_ADDR, port)
     await client.aconnect(SERVER_ADDR, port)
+    # The native engine writes its HELLO_ACK before its accept callback
+    # has run (the callback needs the GIL): under load ``aconnect`` can
+    # return a scheduling quantum before ``list_clients()`` shows the
+    # client, and every test here takes the endpoint from it.
+    for _ in range(2000):
+        if server.list_clients():
+            break
+        await asyncio.sleep(0.005)
     try:
         yield server, client
     finally:

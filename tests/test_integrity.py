@@ -465,6 +465,10 @@ async def test_poison_fails_queued_sends_with_corrupt_reason(port,
         # corrupted, but it cannot finish instantly) ...
         big = _payload(64 << 20)
         sf = server.asend(ep, big, 0x20)
+        # (ON the conn: the engine handles a wakeup's reads before its
+        # submitted ops, so on a loaded box the poison below could land
+        # first and the send then fail as "not connected", never queued.)
+        await _wait_counter(server, "bytes_tx", 1 << 16)
         # ... while the client's corrupted send poisons the server conn.
         await asyncio.wait_for(client.asend(_payload(256 << 10), 0x21), 30)
         await _wait_counter(server, "csum_fail", 1)

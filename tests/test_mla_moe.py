@@ -155,7 +155,8 @@ def test_dense_model_step_log_has_no_moe_fields():
 def test_absorbed_equals_expanded(runner):
     """The two forms of latent attention are one bilinear form regrouped."""
     from starway_tpu.models import mla
-    from starway_tpu.models.llama import cfg_rope_tables, default_attn
+    from starway_tpu.models.llama import cfg_rope_tables
+    from starway_tpu.ops import self_attention
     from starway_tpu.ops.pallas_decode import mla_decode_attention_lax
 
     params, cfg = _model(runner)
@@ -163,7 +164,7 @@ def test_absorbed_equals_expanded(runner):
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 12, 64))
     cos, sin = cfg_rope_tables(cfg, 12)
     q, k, v, rows = mla.project_expanded(x, lp, cfg, cos, sin)
-    want = default_attn(q, k, v, sm_scale=cfg.latent.sm_scale)
+    want = self_attention(q, k, v, sm_scale=cfg.latent.sm_scale)
     qa, rows_a = mla.project_absorbed(x, lp, cfg, cos, sin)
     np.testing.assert_allclose(rows_a, rows, rtol=1e-6, atol=1e-6)
     o_lat = mla_decode_attention_lax(qa, rows[None], jnp.zeros((2,), jnp.int32),
@@ -269,15 +270,18 @@ def test_gmm_kernel_matches_lax(gated):
     assert (np.asarray(row)[~held] == src.shape[0]).all()
 
 
-def test_routed_experts_pallas_path_matches_lax(runner):
+def test_routed_experts_pallas_path_matches_lax(runner, force_kernels):
+    """The whole routed layer with the grouped matmul on each side."""
     from starway_tpu.models.moe import routed_experts, sigmoid_route
 
     w = W.layer_weights(W.base_key(SEED), 1, W.dims(
         dict(TINY, n_routed_experts=4, expert_share=2)), True)["routed"]
     x = jax.random.normal(jax.random.PRNGKey(17), (21, 64))
     idx, g = sigmoid_route(x, w["router"], w["bias"], 4, 2.827)
-    a, sa = routed_experts(x, idx, g, w, 8, use_pallas=True)
-    b, sb = routed_experts(x, idx, g, w, 8, use_pallas=False)
+    force_kernels(True)
+    a, sa = routed_experts(x, idx, g, w, 8)
+    force_kernels(False)
+    b, sb = routed_experts(x, idx, g, w, 8)
     np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
     np.testing.assert_array_equal(sa, sb)
 

@@ -314,3 +314,51 @@ def test_paged_requests_ending_at_their_first_token_return_their_pages(
     check_first_token_endings(
         srv, lambda p, n, eos: _oracle(params, cfg, p, n, eos_id=eos))
     assert srv.pages_in_use == 0
+
+
+def test_dead_slots_write_the_trash_page_and_nothing_reads_it(cfg, params):
+    """ROADMAP D8's pin.  A dead slot's table row is all zeros, so the
+    chunk program's frozen-cursor writes for it land on page 0, several
+    dead slots on the same tile of it (the in-place write's rows may not
+    share a tile EXCEPT there: whatever the race leaves is never read).
+    Page 0 filled with NaN before the run and found rewritten after it
+    shows both halves: the dead slots did write there, and no live slot's
+    tokens, which equal generate()'s, ever read it (ids past a live
+    slot's cursor point at page 0 too and are never fetched)."""
+    srv = PagedSlotServer(params, cfg, n_slots=4, max_len=64, page=16,
+                          n_pages=9, chunk=4)
+    srv.cache = {n: a.at[:, 0].set(jnp.nan) for n, a in srv.cache.items()}
+    prompt = [5, 9, 2, 7, 3]
+    rid = srv.submit(prompt, 9)
+    srv.step()
+    assert (srv._tables[1:] == 0).all()  # three dead slots, all on page 0
+    done = srv.run()
+    np.testing.assert_array_equal(done[rid], _oracle(params, cfg, prompt, 9))
+    trash = np.asarray(srv.cache["k"][:, 0], np.float32)
+    assert np.isnan(trash).any() and not np.isnan(trash).all()
+    live = np.asarray(srv.cache["k"][:, 1:], np.float32)
+    assert not np.isnan(live).any()
+
+
+def test_prefix_admit_writes_by_scatter_whatever_the_decision(cfg,
+                                                              force_kernels):
+    """The paged prefix admit writes one row a TOKEN, so neighbouring rows
+    share a page tile: it is the one caller that must not take the
+    in-place kernel (``kv_write_lax`` by name), even where the kernels are
+    chosen and its attention does run the paged kernel."""
+    from starway_tpu.models import init_params
+    from starway_tpu.models.paged import (_compiled_paged_prefix_admit,
+                                          init_paged_pool)
+
+    force_kernels(True)
+    page, max_pages, s_bucket = 16, 4, 24
+    run = _compiled_paged_prefix_admit.__wrapped__(
+        cfg, s_bucket, page, max_pages, False, 0.0, None, None)
+    shapes = jax.eval_shape(
+        lambda: (init_params(jax.random.PRNGKey(0), cfg),
+                 init_paged_pool(cfg, 9, page)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    text = str(run.trace(*shapes, i32(1, max_pages), i32(1, s_bucket), i32(),
+                         i32(), jax.eval_shape(jax.random.PRNGKey, 0)).jaxpr)
+    assert "sw_paged_decode_attn" in text
+    assert "sw_kv_write" not in text and "scatter" in text

@@ -86,38 +86,34 @@ def test_flash_grad_uneven_blocks():
                                    atol=5e-5, rtol=5e-4)
 
 
-@pytest.mark.parametrize("stream", [True, False], ids=["stream", "grid"])
 @pytest.mark.parametrize("pos", [0, 5, 127, 128, 299])
 @pytest.mark.parametrize("block_k", [128, None])
-def test_decode_kernel_matches_lax(pos, block_k, stream):
+def test_decode_kernel_matches_lax(pos, block_k):
     """block_k=128 forces a MULTI-block sweep at T=300 (the cross-block
-    online-softmax rescale — and, for the grid kernel, the repeated-block
-    DMA clamp — never run otherwise; the 512 default is single-block at
-    test sizes); None covers the default config.  Both kernel variants
-    (double-buffered stream, grid pipeline) are pinned."""
-    from starway_tpu.models.generate import _attend_cached
-    from starway_tpu.ops.pallas_decode import decode_attention
+    online-softmax rescale never runs otherwise; the 512 default is
+    single-block at test sizes); None covers the default config."""
+    from starway_tpu.ops.pallas_decode import (decode_attention,
+                                               decode_attention_lax)
 
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(2), 3)
     B, Hq, Hkv, T, D = 2, 8, 2, 300, 64
     q = jax.random.normal(k1, (B, Hq, 1, D), jnp.float32)
     k = jax.random.normal(k2, (B, Hkv, T, D), jnp.float32)
     v = jax.random.normal(k3, (B, Hkv, T, D), jnp.float32)
-    ref = _attend_cached(q, k, v, pos, Hq // Hkv, use_pallas=False)
+    ref = decode_attention_lax(q, k, v, pos)
     kw = {} if block_k is None else {"block_k": block_k}
-    out = decode_attention(q, k, v, pos, interpret=True, stream=stream, **kw)
+    out = decode_attention(q, k, v, pos, interpret=True, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("stream", [True, False], ids=["stream", "grid"])
 @pytest.mark.parametrize("window", [None, 96])
-def test_decode_kernel_multi_query(stream, window):
+def test_decode_kernel_multi_query(window):
     """C>1 query positions (the speculative chunk verify): C x n_rep rows
     share one narrow cache stream, each row masked by its own cursor —
     pinned against the generalized lax oracle at ragged per-row bases,
     multi-block, fp and int8, crossing a block boundary mid-chunk."""
-    from starway_tpu.models.generate import _attend_cached
-    from starway_tpu.ops.pallas_decode import decode_attention
+    from starway_tpu.ops.pallas_decode import (decode_attention,
+                                               decode_attention_lax)
     from starway_tpu.ops.quantize import quantize_kv
 
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(5), 3)
@@ -126,18 +122,18 @@ def test_decode_kernel_multi_query(stream, window):
     k = jax.random.normal(k2, (B, Hkv, T, D), jnp.float32)
     v = jax.random.normal(k3, (B, Hkv, T, D), jnp.float32)
     pos = jnp.asarray([125, 290], jnp.int32)  # chunk straddles block 128
-    ref = _attend_cached(q, k, v, pos, Hq // Hkv, use_pallas=False,
+    ref = decode_attention_lax(q, k, v, pos,
                          window=window)
-    out = decode_attention(q, k, v, pos, interpret=True, stream=stream,
+    out = decode_attention(q, k, v, pos, interpret=True,
                            block_k=128, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
                                rtol=2e-5)
 
     k8, ks = quantize_kv(k)
     v8, vs = quantize_kv(v)
-    refq = _attend_cached(q, k8, v8, pos, Hq // Hkv, use_pallas=False,
+    refq = decode_attention_lax(q, k8, v8, pos,
                           window=window, k_scale=ks, v_scale=vs)
-    outq = decode_attention(q, k8, v8, pos, interpret=True, stream=stream,
+    outq = decode_attention(q, k8, v8, pos, interpret=True,
                             block_k=128, window=window, k_scale=ks,
                             v_scale=vs)
     np.testing.assert_allclose(np.asarray(outq), np.asarray(refq),
@@ -145,8 +141,8 @@ def test_decode_kernel_multi_query(stream, window):
 
 
 def test_decode_kernel_traced_pos_under_jit():
-    from starway_tpu.models.generate import _attend_cached
-    from starway_tpu.ops.pallas_decode import decode_attention
+    from starway_tpu.ops.pallas_decode import (decode_attention,
+                                               decode_attention_lax)
 
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(3), 3)
     B, Hq, Hkv, T, D = 1, 4, 4, 130, 32  # no-GQA shape + padding tail
@@ -154,18 +150,17 @@ def test_decode_kernel_traced_pos_under_jit():
     k = jax.random.normal(k2, (B, Hkv, T, D), jnp.float32)
     v = jax.random.normal(k3, (B, Hkv, T, D), jnp.float32)
     step = jax.jit(lambda q, k, v, p: decode_attention(q, k, v, p, interpret=True))
-    ref = _attend_cached(q, k, v, 77, 1, use_pallas=False)
+    ref = decode_attention_lax(q, k, v, 77)
     out = step(q, k, v, jnp.int32(77))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("stream", [True, False], ids=["stream", "grid"])
-def test_decode_kernel_per_row_pos(stream):
+def test_decode_kernel_per_row_pos():
     """Ragged decode: a [B] position vector masks (and DMA-clamps) each
     batch row at its own cursor; every row must match a standalone
     scalar-pos call."""
-    from starway_tpu.models.generate import _attend_cached
-    from starway_tpu.ops.pallas_decode import decode_attention
+    from starway_tpu.ops.pallas_decode import (decode_attention,
+                                               decode_attention_lax)
 
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(4), 3)
     B, Hq, Hkv, T, D = 3, 8, 2, 300, 64
@@ -176,24 +171,22 @@ def test_decode_kernel_per_row_pos(stream):
 
     # block_k=128: multi-block sweep, so each row's DMA really stops at a
     # different block index.
-    out = decode_attention(q, k, v, pos, interpret=True, block_k=128,
-                           stream=stream)
-    lax_out = _attend_cached(q, k, v, pos, Hq // Hkv, use_pallas=False)
+    out = decode_attention(q, k, v, pos, interpret=True, block_k=128)
+    lax_out = decode_attention_lax(q, k, v, pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(lax_out),
                                atol=2e-5, rtol=2e-5)
     for b in range(B):
         solo = decode_attention(q[b:b + 1], k[b:b + 1], v[b:b + 1],
-                                int(pos[b]), interpret=True, stream=stream)
+                                int(pos[b]), interpret=True)
         np.testing.assert_allclose(np.asarray(out[b]), np.asarray(solo[0]),
                                    atol=2e-5, rtol=2e-5, err_msg=f"row {b}")
 
 
-@pytest.mark.parametrize("stream", [True, False], ids=["stream", "grid"])
-def test_decode_kernel_sliding_window(stream):
+def test_decode_kernel_sliding_window():
     """Windowed decode: kernel == lax windowed oracle, multi-block, with
     the window straddling block boundaries; scalar and per-row pos."""
-    from starway_tpu.models.generate import _attend_cached
-    from starway_tpu.ops.pallas_decode import decode_attention
+    from starway_tpu.ops.pallas_decode import (decode_attention,
+                                               decode_attention_lax)
 
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(6), 3)
     B, Hq, Hkv, T, D, W = 2, 8, 2, 520, 64, 200
@@ -202,15 +195,15 @@ def test_decode_kernel_sliding_window(stream):
     v = jax.random.normal(k3, (B, Hkv, T, D), jnp.float32)
     for pos in (0, 150, 380, 519):
         out = decode_attention(q, k, v, pos, interpret=True, block_k=128,
-                               window=W, stream=stream)
-        ref = _attend_cached(q, k, v, pos, Hq // Hkv, use_pallas=False,
+                               window=W)
+        ref = decode_attention_lax(q, k, v, pos,
                              window=W)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5, err_msg=f"pos={pos}")
     pos_v = jnp.asarray([519, 77], jnp.int32)
     out = decode_attention(q, k, v, pos_v, interpret=True, block_k=128,
-                           window=W, stream=stream)
-    ref = _attend_cached(q, k, v, pos_v, Hq // Hkv, use_pallas=False, window=W)
+                           window=W)
+    ref = decode_attention_lax(q, k, v, pos_v, window=W)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -310,8 +303,8 @@ def test_decode_attention_reads_stacked_cache_by_layer(int8, per_row, window,
                                                        n_q, layer):
     """``decode_attention(stacked, layer=i)`` is bit-equal to the per-layer
     call on ``stacked[i]``: the layer only moves the DMA's source address.
-    The layer is traced (as inside the layer scan), T = 256 makes two
-    blocks of 128, and both kernel variants read the same stack."""
+    The layer is traced (as inside the layer scan) and T = 256 makes two
+    blocks of 128."""
     from starway_tpu.ops.pallas_decode import decode_attention
     from starway_tpu.ops.quantize import quantize_kv
 
@@ -325,16 +318,15 @@ def test_decode_attention_reads_stacked_cache_by_layer(int8, per_row, window,
         k, scales["k_scale"] = quantize_kv(k)
         v, scales["v_scale"] = quantize_kv(v)
     pos = jnp.asarray([125, 250], jnp.int32) if per_row else 130
-    for stream in (True, False):
-        kw = dict(window=window, block_k=128, interpret=True, stream=stream)
-        stacked = jax.jit(lambda li: decode_attention(
-            q, k, v, pos, layer=li, **scales, **kw))(jnp.int32(layer))
-        one = decode_attention(
-            q, k[layer], v[layer], pos,
-            **{n: s[layer] for n, s in scales.items()}, **kw)
-        assert stacked.shape == (B, Hq, n_q, D)
-        np.testing.assert_array_equal(np.asarray(stacked, np.float32),
-                                      np.asarray(one, np.float32))
+    kw = dict(window=window, block_k=128, interpret=True)
+    stacked = jax.jit(lambda li: decode_attention(
+        q, k, v, pos, layer=li, **scales, **kw))(jnp.int32(layer))
+    one = decode_attention(
+        q, k[layer], v[layer], pos,
+        **{n: s[layer] for n, s in scales.items()}, **kw)
+    assert stacked.shape == (B, Hq, n_q, D)
+    np.testing.assert_array_equal(np.asarray(stacked, np.float32),
+                                  np.asarray(one, np.float32))
 
 
 @pytest.mark.parametrize("n_new", [1, 4], ids=["c1", "c4"])
@@ -400,3 +392,266 @@ def test_kv_write_long_chunk_goes_in_pieces(monkeypatch):
     want, = kv_write_lax((cache,), (update,), 1, rows, pos)
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(want, np.float32))
+
+
+# ------------------------------------------------ the seam (ops/dispatch.py)
+
+
+def _rand(seed, *shapes, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
+    return [jax.random.normal(k, s).astype(dtype)
+            for k, s in zip(keys, shapes)]
+
+
+def _case_decode(int8=False, window=None, c=1):
+    from starway_tpu.ops import cached_attention
+    from starway_tpu.ops.pallas_decode import (decode_attention,
+                                               decode_attention_lax)
+    from starway_tpu.ops.quantize import quantize_kv
+
+    L, B, Hq, Hkv, T, D = 2, 2, 8, 2, 256, 64
+    q, k, v = _rand(21, (B, Hq, c, D), (L, B, Hkv, T, D), (L, B, Hkv, T, D))
+    kw = dict(layer=jnp.int32(1), window=window)
+    if int8:
+        (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+        kw.update(k_scale=ks, v_scale=vs)
+    args = (q, k, v, jnp.asarray([130, 250 - c], jnp.int32))
+    return (lambda: cached_attention(*args, **kw),
+            lambda: decode_attention(*args, interpret=True, **kw),
+            lambda: decode_attention_lax(*args, **kw))
+
+
+def _case_latent():
+    from starway_tpu.ops import latent_attention
+    from starway_tpu.ops.pallas_decode import (mla_decode_attention,
+                                               mla_decode_attention_lax)
+
+    q, c = _rand(22, (2, 4, 1, 128), (2, 2, 1, 256, 128))
+    args = (q, c, jnp.asarray([100, 255], jnp.int32))
+    kw = dict(rank=64, sm_scale=0.1, layer=jnp.int32(1))
+    return (lambda: latent_attention(*args, **kw),
+            lambda: mla_decode_attention(*args, interpret=True, **kw),
+            lambda: mla_decode_attention_lax(*args, **kw))
+
+
+def _case_write(kind):
+    from starway_tpu.ops import cache_write
+    from starway_tpu.ops.pallas_decode import kv_write, kv_write_lax
+
+    L, R, T = 2, 3, 256
+    tail, hkv, n, dtype = {"kv": ((64,), 2, 2, jnp.bfloat16),
+                           "scales": ((), 2, 2, jnp.float32),
+                           "latent": ((128,), 1, 1, jnp.bfloat16)}[kind]
+    caches = tuple(_rand(23, *[(L, R, hkv, T) + tail] * n, dtype=dtype))
+    updates = tuple(_rand(24, *[(R, hkv, 1) + tail] * n, dtype=dtype))
+    args = (caches, updates, jnp.int32(1), jnp.arange(R),
+            jnp.asarray([0, 131, 255], jnp.int32))
+    return (lambda: cache_write(*args),
+            lambda: kv_write(*args, interpret=True),
+            lambda: kv_write_lax(*args))
+
+
+def _case_gmm(gated):
+    from starway_tpu.ops import grouped_matmul
+    from starway_tpu.ops.pallas_gmm import gmm, gmm_lax
+
+    x, w, w2 = _rand(25, (64, 32), (3, 32, 128), (3, 32, 128))
+    args = (x, w, jnp.asarray([0, 0, 2, 2], jnp.int32), jnp.int32(3))
+    kw = dict(tile_m=16, w2=w2 if gated else None)
+    live = lambda out: out[:48]  # the fourth tile is dead: never written
+    return (lambda: live(grouped_matmul(*args, **kw)),
+            lambda: live(gmm(*args, interpret=True, **kw)),
+            lambda: live(gmm_lax(*args, **kw)))
+
+
+def _case_int8_matmul():
+    from starway_tpu.ops import quantized_matmul
+    from starway_tpu.ops.pallas_gemv import int8_matmul, int8_matmul_lax
+    from starway_tpu.ops.quantize import quantize_weight
+
+    x, w = _rand(26, (5, 64), (64, 256))
+    qw = quantize_weight(w)
+    args = (x, qw["q"], qw["s"])
+    return (lambda: quantized_matmul(*args),
+            lambda: int8_matmul(*args, interpret=True),
+            lambda: int8_matmul_lax(*args))
+
+
+def _case_self_attention(window=None):
+    from starway_tpu.ops import self_attention
+    from starway_tpu.ops.attention import blockwise_attention
+    from starway_tpu.ops.pallas_attention import flash_attention
+
+    q, k, v = _rand(27, (1, 4, 256, 32), (1, 2, 256, 32), (1, 2, 256, 32))
+    kw = dict(causal=True, window=window)
+    return (lambda: self_attention(q, k, v, **kw),
+            lambda: flash_attention(q, k, v, interpret=True, **kw),
+            lambda: blockwise_attention(q, k, v, **kw))
+
+
+def _case_ring_step(causal, bwd=False):
+    from starway_tpu.ops import ring_step, ring_step_bwd
+    from starway_tpu.ops.pallas_attention import (
+        flash_partial, flash_partial_bwd, flash_partial_bwd_lax,
+        flash_partial_lax)
+
+    q, k, v, do = _rand(28, (1, 4, 64, 32), (1, 2, 64, 32), (1, 2, 64, 32),
+                        (1, 4, 64, 32))
+    offs, kw = (jnp.int32(64), jnp.int32(32)), dict(causal=causal,
+                                                    sm_scale=32 ** -0.5)
+    if not bwd:
+        args = (q, k, v, *offs)
+        return (lambda: ring_step(*args, causal, kw["sm_scale"]),
+                lambda: flash_partial(*args, interpret=True, **kw),
+                lambda: flash_partial_lax(*args, **kw))
+    o, m, l = flash_partial_lax(q, k, v, *offs, **kw)
+    lse = m + jnp.log(jnp.maximum(l, 1e-30))
+    out = o / jnp.maximum(l, 1e-30)[..., None]
+    args = (q, do, k, v, lse, jnp.sum(do * out, -1), *offs)
+    return (lambda: ring_step_bwd(*args, causal, kw["sm_scale"]),
+            lambda: flash_partial_bwd(*args, interpret=True, **kw),
+            lambda: flash_partial_bwd_lax(*args, **kw))
+
+
+def _case_paged():
+    """One implementation: the dispatcher is the kernel (interpreted off
+    the chip) whatever the decision says; the oracle is the dense twin
+    over the gathered logical cache."""
+    from starway_tpu.ops import paged_attention
+    from starway_tpu.ops.pallas_decode import decode_attention_lax
+    from starway_tpu.ops.pallas_paged import (gather_logical,
+                                              paged_decode_attention)
+
+    q, kp, vp = _rand(29, (2, 4, 1, 64), (7, 2, 128, 64), (7, 2, 128, 64))
+    table = jnp.asarray([[3, 1, 6], [2, 5, 4]], jnp.int32)
+    pos = jnp.asarray([200, 383], jnp.int32)
+    return (lambda: paged_attention(q, kp, vp, table, pos),
+            lambda: paged_decode_attention(q, kp, vp, table, pos,
+                                           interpret=True),
+            lambda: decode_attention_lax(q, gather_logical(kp, table),
+                                         gather_logical(vp, table), pos))
+
+
+_DISPATCH = {
+    "decode_bf16": _case_decode,
+    "decode_int8": lambda: _case_decode(int8=True),
+    "decode_windowed": lambda: _case_decode(window=96),
+    "decode_c4": lambda: _case_decode(c=4),
+    "latent_decode": _case_latent,
+    "write_kv": lambda: _case_write("kv"),
+    "write_scales": lambda: _case_write("scales"),
+    "write_latent": lambda: _case_write("latent"),
+    "gmm_gated": lambda: _case_gmm(True),
+    "gmm_down": lambda: _case_gmm(False),
+    "int8_matmul": _case_int8_matmul,
+    "attention_full": _case_self_attention,
+    "attention_windowed": lambda: _case_self_attention(window=96),
+    "ring_step_masked": lambda: _case_ring_step(True),
+    "ring_step_unmasked": lambda: _case_ring_step(False),
+    "ring_step_bwd_masked": lambda: _case_ring_step(True, bwd=True),
+    "paged": _case_paged,
+}
+
+
+@pytest.mark.parametrize("name", list(_DISPATCH))
+def test_op_dispatch_agrees_with_its_twin(name, force_kernels):
+    """Every public operation of ``starway_tpu.ops`` decides ALONE and by
+    the one function: with ``dispatch.use_kernels`` forced on it returns
+    exactly what its kernel returns (interpreted here), forced off exactly
+    what its ``*_lax`` twin returns, and the two agree.  (The paged
+    attention has one implementation: both settings give the kernel.)"""
+    op, kernel, twin = _DISPATCH[name]()
+    leaves = lambda out: [np.asarray(x, np.float32)
+                          for x in jax.tree_util.tree_leaves(out)]
+    force_kernels(True)
+    on = leaves(op())
+    force_kernels(False)
+    off = leaves(op())
+    want_on, want_off = leaves(kernel()), leaves(twin())
+    for got, want in zip(on, want_on):
+        np.testing.assert_array_equal(got, want)
+    for got, want in zip(off, want_on if name == "paged" else want_off):
+        np.testing.assert_array_equal(got, want)
+    for a, b in zip(want_on, want_off):
+        np.testing.assert_allclose(a, b, atol=3e-5, rtol=3e-4)
+
+
+def test_only_ops_chooses_an_implementation():
+    """The decision has one home.  Outside ``starway_tpu/ops/`` no module
+    asks ``jax.default_backend()`` (``device.py``'s platform check is no
+    choice of implementation and is the one exception), none names the
+    switches PR 28 removed (spelled in halves below so that a grep for
+    them finds nothing), and none imports from a ``pallas_*``
+    module anything but a ``*_lax`` twin: a kernel is reached through its
+    operation in ``starway_tpu.ops``."""
+    import ast
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1] / "starway_tpu"
+    bad = []
+    for path in sorted(root.rglob("*.py")):
+        rel = path.relative_to(root).as_posix()
+        if rel.startswith("ops/"):
+            continue
+        for node in ast.walk(ast.parse(path.read_text())):
+            where = f"{rel}:{getattr(node, 'lineno', 0)}"
+            if (isinstance(node, ast.Attribute)
+                    and node.attr == "default_backend"
+                    and rel != "device.py"):
+                bad.append(f"{where} asks default_backend")
+            names = {getattr(node, "id", None), getattr(node, "arg", None),
+                     getattr(node, "attr", None)}
+            if names & {"use_" + "pallas", "use_" + "kernel"}:
+                bad.append(f"{where} names a removed switch")
+            if (isinstance(node, ast.ImportFrom) and node.module
+                    and "pallas_" in node.module.rsplit(".", 1)[-1]):
+                bad += [f"{where} imports {a.name} from {node.module}"
+                        for a in node.names if not a.name.endswith("_lax")]
+    assert not bad, "\n".join(bad)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("t,want", [
+    (128, (128, 128)), (256, (256, 256)), (296, (296, None)),
+    (300, (None, None)), (2048, (512, 512)), (4104, (None, None)),
+    (5120, (512, 512))])
+def test_pick_block_table(t, want, quant):
+    """The kv block DIVIDES the cache length: the widest multiple of 128
+    up to ``block_k`` (512); a bf16 cache of whole sublane tiles up to
+    4096 goes as one block (the scales of an int8 one lie on the 128
+    lanes); ``None`` sends the call down the slice-and-pad path."""
+    from starway_tpu.ops.pallas_decode import _pick_block
+
+    got = _pick_block(t, 512, quant)
+    assert got == want[quant]
+    assert got is None or t % got == 0
+
+
+def test_kv_write_rows_sharing_a_tile_lose_an_update():
+    """The documented limit of ``kv_write`` (ROADMAP D8): the rows of one
+    call must not share a tile.  Two neighbouring positions of one cache
+    row, written as two rows of a call, each read the tile, set their own
+    position and write the tile back: the later write-back carries the
+    earlier position as it was READ, so one update is lost.  The lax twin
+    keeps both, which is why the paged prefix admit (one row a token of a
+    page) calls ``kv_write_lax`` by name.  If the kernel learns to merge
+    such rows, this test changes with its docstring."""
+    from starway_tpu.ops.pallas_decode import kv_write, kv_write_lax
+
+    (cache,) = _rand(31, (1, 2, 2, 128, 64), dtype=jnp.bfloat16)
+    (upd,) = _rand(32, (2, 2, 1, 64), dtype=jnp.bfloat16)
+    args = ((cache,), (upd,), jnp.int32(0), jnp.asarray([1, 1]),
+            jnp.asarray([40, 41], jnp.int32))
+    (lax_out,) = kv_write_lax(*args)
+    np.testing.assert_array_equal(np.asarray(lax_out[0, 1, :, 40:42], np.float32),
+                                  np.asarray(jnp.moveaxis(upd[:, :, 0], 0, 1),
+                                             np.float32))
+    (out,) = kv_write(*args, interpret=True)
+    kept = [bool(jnp.array_equal(out[0, 1, :, 40 + i], upd[i, :, 0]))
+            for i in range(2)]
+    assert kept.count(True) == 1, kept
+    # Everything outside the two positions is untouched either way.
+    rest = np.ones(cache.shape, bool)
+    rest[0, 1, :, 40:42] = False
+    np.testing.assert_array_equal(np.asarray(out, np.float32)[rest],
+                                  np.asarray(cache, np.float32)[rest])

@@ -227,30 +227,37 @@ def test_zigzag_ring_gradients_match_oracle(gqa):
                                    atol=5e-5, rtol=5e-4)
 
 
-def test_ring_attention_kernel_path_interpret():
-    """use_kernel=True routes ring steps through the Pallas partials
+def _on_both_sides(force_kernels, make, q, k, v):
+    """``(out, grads)`` of ``make()``'s attention with the ops' decision
+    forced to the kernels (interpret mode on the CPU), then to the lax
+    twins.  Each side is built and traced under its own setting."""
+    sides = []
+    for on in (True, False):
+        force_kernels(on)
+        attn = make()
+        sides.append((attn(q, k, v), jax.grad(
+            lambda *a: jnp.sum(attn(*a) ** 2), argnums=(0, 1, 2))(q, k, v)))
+    return sides
+
+
+def test_ring_attention_kernel_path_interpret(force_kernels):
+    """With the kernels chosen the ring steps run the Pallas partials
     (interpret mode on CPU): forward AND gradients must match the lax
     path exactly enough."""
     mesh = make_mesh({"sp": 2})
     q, k, v = _qkv(jax.random.PRNGKey(16), b=1, h=2, t=32, d=16)
-    ring_lax = make_ring_attention(mesh, "sp", causal=True, use_kernel=False)
-    ring_ker = make_ring_attention(mesh, "sp", causal=True, use_kernel=True)
-    np.testing.assert_allclose(np.asarray(ring_ker(q, k, v)),
-                               np.asarray(ring_lax(q, k, v)),
+    (out_k, g1), (out_l, g2) = _on_both_sides(
+        force_kernels, lambda: make_ring_attention(mesh, "sp", causal=True),
+        q, k, v)
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_l),
                                atol=2e-5, rtol=2e-5)
-
-    def loss(ring):
-        return lambda q, k, v: jnp.sum(ring(q, k, v) ** 2)
-
-    g1 = jax.grad(loss(ring_ker), argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss(ring_lax), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=5e-4)
 
 
-def test_zigzag_ring_kernel_path_interpret():
-    """Zigzag with use_kernel=True: Pallas partials under lax.cond with
+def test_zigzag_ring_kernel_path_interpret(force_kernels):
+    """Zigzag with the kernels chosen: Pallas partials under lax.cond with
     offsets, incl. the causal=False hi-lo pair and GQA -- fwd and grads
     must match the lax path."""
     from starway_tpu.parallel import make_zigzag_ring_attention
@@ -258,13 +265,11 @@ def test_zigzag_ring_kernel_path_interpret():
     mesh = make_mesh({"sp": 2})
     q, _, _ = _qkv(jax.random.PRNGKey(17), b=1, h=2, t=32, d=16)
     _, k, v = _qkv(jax.random.PRNGKey(18), b=1, h=1, t=32, d=16)  # GQA 2
-    zz_lax = make_zigzag_ring_attention(mesh, "sp", use_kernel=False)
-    zz_ker = make_zigzag_ring_attention(mesh, "sp", use_kernel=True)
-    np.testing.assert_allclose(np.asarray(zz_ker(q, k, v)),
-                               np.asarray(zz_lax(q, k, v)),
+    (out_k, g1), (out_l, g2) = _on_both_sides(
+        force_kernels, lambda: make_zigzag_ring_attention(mesh, "sp"),
+        q, k, v)
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_l),
                                atol=2e-5, rtol=2e-5)
-    g1 = jax.grad(lambda *a: jnp.sum(zz_ker(*a) ** 2), argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(lambda *a: jnp.sum(zz_lax(*a) ** 2), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=5e-4)
@@ -369,17 +374,21 @@ def test_windowed_model_sharded_attn():
 
 
 @pytest.mark.parametrize("case", ["decode_bf16", "decode_int8", "flash"])
-def test_per_head_shard_matches_unsharded(case):
+def test_per_head_shard_matches_unsharded(case, force_kernels):
     """The Pallas attention calls run per tp shard of the head dimension
-    under an ambient mesh (parallel/sharding.py per_head_shard: the TPU's
+    under an ambient mesh (ops/dispatch.py per_head_shard: the TPU's
     compiler refuses a Mosaic kernel inside a partitioned program).  GQA
-    pairing must survive the split: q head h reads kv head h // n_rep."""
+    pairing must survive the split: q head h reads kv head h // n_rep.
+    The decode cases are the WHOLE op with the kernels chosen (a program
+    on one side: the decision function is substituted), the flash case
+    the wrapper around the kernel itself."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from starway_tpu.models.generate import _attend_cached
+    from starway_tpu.ops import cached_attention, per_head_shard
     from starway_tpu.ops.pallas_attention import flash_attention
     from starway_tpu.ops.quantize import quantize_kv
-    from starway_tpu.parallel.sharding import per_head_shard
+
+    force_kernels(True)
 
     b, hq, hkv, t, d = 2, 8, 4, 256, 64
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(11), 3)
@@ -403,8 +412,7 @@ def test_per_head_shard_matches_unsharded(case):
             scales = dict(k_scale=ks, v_scale=vs)
 
         def run(q, k, v, **sc):
-            return _attend_cached(q, k, v, pos, hq // hkv, use_pallas=True,
-                                  **sc)
+            return cached_attention(q, k, v, pos, **sc)
         args = (q, k, v)
 
     want = jax.jit(run)(*args, **(scales if case != "flash" else {}))
@@ -422,7 +430,7 @@ def test_per_head_shard_matches_unsharded(case):
                                np.asarray(want, np.float32),
                                atol=2e-6, rtol=2e-6)
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_per_head_shard_stacked_cache(kv):
+def test_per_head_shard_stacked_cache(kv, force_kernels):
     """Write-then-attend on the scan-stacked cache ``[L, B, Hkv, T, D]``
     under a tp mesh: the caches' heads are dim 2 there (dim 1 of q and of
     the new entries), which ``per_head_shard`` is told, not left to guess.
@@ -430,9 +438,11 @@ def test_per_head_shard_stacked_cache(kv):
     and both kernels agree with the unsharded run."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from starway_tpu.models.generate import _attend_cached, _write_cached
+    from starway_tpu.models.generate import _write_cached
+    from starway_tpu.ops import cached_attention
     from starway_tpu.ops.quantize import quantize_kv
 
+    force_kernels(True)
     n_layers, b, hq, hkv, t, d = 3, 2, 8, 4, 256, 64
     keys = jax.random.split(jax.random.PRNGKey(17), 5)
     q = jax.random.normal(keys[0], (b, hq, 1, d)).astype(jnp.bfloat16)
@@ -449,10 +459,10 @@ def test_per_head_shard_stacked_cache(kv):
     pos = jnp.asarray([100, 37], jnp.int32)
 
     def run(q, cache, new, layer):
-        cache = _write_cached(cache, new, layer, pos, use_pallas=True)
-        out = _attend_cached(q, cache["k"], cache["v"], pos, hq // hkv,
-                             use_pallas=True, k_scale=cache.get("k_scale"),
-                             v_scale=cache.get("v_scale"), layer=layer)
+        cache = _write_cached(cache, new, layer, pos)
+        out = cached_attention(q, cache["k"], cache["v"], pos, layer=layer,
+                               k_scale=cache.get("k_scale"),
+                               v_scale=cache.get("v_scale"))
         return out, cache
 
     layer = jnp.int32(1)

@@ -44,13 +44,13 @@ def test_quantize_zero_vectors_stay_zero():
     assert bool(jnp.all(dequantize_kv(q, s) == 0))
 
 
-@pytest.mark.parametrize("stream", [True, False])
 @pytest.mark.parametrize("window,ragged", [(None, False), (None, True),
                                            (96, True)])
-def test_decode_kernel_int8_matches_dequant_oracle(stream, window, ragged):
+def test_decode_kernel_int8_matches_dequant_oracle(window, ragged):
     """Kernel on the int8 cache == lax path on the dequantized cache: the
     in-kernel scale folding is algebraically exact (f32 score chain)."""
-    from starway_tpu.models.generate import _attend_cached
+    from starway_tpu.ops.pallas_decode import (decode_attention,
+                                               decode_attention_lax)
 
     b, hq, hkv, t, d = 2, 8, 2, 384, 64
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
@@ -62,14 +62,11 @@ def test_decode_kernel_int8_matches_dequant_oracle(stream, window, ragged):
     pos = (jnp.asarray([133, 380], jnp.int32) if ragged
            else jnp.asarray(300, jnp.int32))
 
-    from starway_tpu.ops.pallas_decode import decode_attention
-
     out = decode_attention(q, kq8, vq8, pos, k_scale=ks, v_scale=vs,
-                           interpret=True, block_k=128, stream=stream,
-                           window=window)
-    ref = _attend_cached(q, dequantize_kv(kq8, ks, jnp.float32),
-                         dequantize_kv(vq8, vs, jnp.float32), pos,
-                         hq // hkv, use_pallas=False, window=window)
+                           interpret=True, block_k=128, window=window)
+    ref = decode_attention_lax(q, dequantize_kv(kq8, ks, jnp.float32),
+                               dequantize_kv(vq8, vs, jnp.float32), pos,
+                               window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 

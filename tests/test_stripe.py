@@ -235,19 +235,35 @@ async def test_rail_death_redistribution_no_session(port, monkeypatch):
     monkeypatch.setenv("STARWAY_STRIPE_CHUNK", str(256 << 10))
     server = Server()
     server.listen(ADDR, port)
+    # Every lane runs through a byte pipe that can be stalled: while it
+    # holds the bytes no chunk is SACKed, so the rail killed below holds
+    # claimed chunks however this thread and the engine's are scheduled
+    # (killing it "soon after asend" lost that race on a loaded box).
+    proxy = FaultProxy(ADDR, port).start()
     client = Client()
     try:
-        await _connect(client, server, port)
+        await _connect(client, server, proxy.port)
         rails = _client_rails(client)
         assert len(rails) == 2
         n = 32 << 20
         payload = _payload(n)
         sink = np.zeros(n, dtype=np.uint8)
         rf = server.arecv(sink, 21, MASK)
+        proxy.stall()
         send_fut = client.asend(payload, 21)
-        # Kill one secondary while chunks are in flight (shutdown is
+        stripe = client._client.primary_conn.stripe
+        for _ in range(6000):
+            if any(src.rail_offs.get(rails[0].conn_id)
+                   or src.done_offs.get(rails[0].conn_id)
+                   for src in list(stripe.by_id.values())):
+                break
+            await asyncio.sleep(0.005)
+        else:
+            raise AssertionError("the rail never claimed a chunk")
+        # Kill one secondary while its chunks are in flight (shutdown is
         # syscall-safe from this thread; the engine sees the reset).
         rails[0].sock.shutdown(socket.SHUT_RDWR)
+        proxy.unstall()
         await asyncio.wait_for(send_fut, 30)
         await asyncio.wait_for(client.aflush(), 60)
         await asyncio.wait_for(rf, 60)
@@ -256,6 +272,7 @@ async def test_rail_death_redistribution_no_session(port, monkeypatch):
         assert cc["rail_resteals"] > 0, cc  # the dead rail held chunks
         assert len(_client_rails(client)) == 1  # pruned from the group
     finally:
+        proxy.stop()
         await _aclose_all(client, server)
 
 

@@ -224,10 +224,15 @@ def test_garbled_doc_table(tmp_path):
 
 
 def test_version_drift(tmp_path):
+    # The version is read from the engine, not written here: a literal
+    # went stale twice (12 where the engine said 14).
     root = _seed(tmp_path)
+    src = (root / "native" / "sw_engine.cpp").read_text()
+    now = int(re.search(r'return "starway-native-(\d+)"', src).group(1))
     _edit(root, "native/sw_engine.cpp",
-          'return "starway-native-12"', 'return "starway-native-13"')
-    _assert_caught(root, "contract-version", "starway-native-13", "sw_engine.h")
+          f'return "starway-native-{now}"', f'return "starway-native-{now + 1}"')
+    _assert_caught(root, "contract-version", f"starway-native-{now + 1}",
+                   "sw_engine.h")
 
 
 def test_unmarked_multi_gib_test(tmp_path):
@@ -1830,6 +1835,15 @@ def _shadow_ledger(root: Path) -> Path:
     return dst
 
 
+def _ledger_row(led: Path, engine: str, path: str, metric: str):
+    """``(line with its newline, pinned value)`` of one ledger row, found
+    by its fields: the file's column widths are the writer's business."""
+    m = re.search(rf"^{engine}[ \t]+{path}[ \t]+{metric}[ \t]+(\d+)[ \t]*\n",
+                  led.read_text(), re.M)
+    assert m, f"fixture drift: no row {engine} {path} {metric} in {led.name}"
+    return m.group(0), int(m.group(1))
+
+
 def test_swcost_rules_registered():
     # The three new finding codes are waiver targets (--rules) and
     # render as problem-matcher rows like every pass.
@@ -1909,8 +1923,9 @@ def test_cost_ratchet_fires_on_improvement(tmp_path):
     # must demand the ratchet, not silently accept the slack.
     root = _seed(tmp_path)
     led = _shadow_ledger(root)
+    row, pinned = _ledger_row(led, "py", "eager_tx", "syscalls")
     led.write_text(led.read_text().replace(
-        "py  eager_tx    syscalls  1", "py  eager_tx    syscalls  3", 1))
+        row, f"py eager_tx syscalls {pinned + 2}\n", 1))
     _assert_caught(root, "cost-budget", "beats the pinned budget",
                    "cost_budgets.txt")
 
@@ -1930,8 +1945,8 @@ def test_cost_ledger_malformed_and_unknown_rows(tmp_path):
 def test_cost_ledger_missing_row(tmp_path):
     root = _seed(tmp_path)
     led = _shadow_ledger(root)
-    led.write_text(led.read_text().replace(
-        "py  eager_tx    syscalls  1\n", "", 1))
+    row, _ = _ledger_row(led, "py", "eager_tx", "syscalls")
+    led.write_text(led.read_text().replace(row, "", 1))
     _assert_caught(root, "cost-model", "no ledger row for py eager_tx",
                    "cost_budgets.txt")
 
