@@ -61,8 +61,10 @@ SOCKET_SIZES = (MiB, 8 * MiB + 1, 256 * MiB)
 # 5.6 GB of bf16 weights plus an 8 x 2048 cache fit one 16 GB chip.
 SERVE = {"preset": "llama3-8b", "n_layers": 8, "n_slots": 8, "max_len": 2048,
          "chunk": 8, "page": 64}
-# (prompt tokens, new tokens): ragged, 5-1500 in, 16-64 out, three prompt
-# buckets (32 / 256 / 2048) so admission compiles three programs a server.
+# (prompt tokens, new tokens): ragged, 5-1500 in, 16-64 out.  The dense
+# bf16 server ingests them inside its decode chunks, at widths 128 and 256
+# (the 1100- and 1500-token prompts); the int8 and the paged server admit
+# through three prompt buckets (32 or 64 / 256 / 2048).
 REQUESTS = ((5, 16), (12, 24), (200, 32), (230, 48), (1100, 64), (1500, 64),
             (29, 20))
 RERUN = 2  # the request re-run through standalone generate()
@@ -1097,17 +1099,20 @@ def phase_mesh_train(devices) -> dict:
 TP_REQUESTS = ((5, 16), (200, 32), (230, 24), (1100, 32))
 
 
-def tp_chunk_program(devices):
+def tp_chunk_program(devices, ingest=None):
     """(mesh, the SlotServer decode-chunk program, abstract args) with
     the weights tensor-parallel over ``devices`` -- pure GSPMD, as
     __graft_entry__.py's serving phase shards them.  Lower it under
-    ``jax.set_mesh(mesh)``, as SlotServer.step runs it."""
+    ``jax.set_mesh(mesh)``, as SlotServer.step runs it.  ``ingest``: the
+    mixed chunk of that piece width (what phase_tp_serve's server runs
+    while prompts come in) instead of the plain one."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from starway_tpu.models import init_cache
-    from starway_tpu.models.serving import _compiled_chunk
+    from starway_tpu.models.serving import (PIECE_FIELDS, _compiled_chunk,
+                                            _compiled_ingest_chunk)
 
     cfg = serve_config()
     mesh, p_sh = _param_shardings(cfg, {"tp": len(devices)}, devices)
@@ -1119,8 +1124,15 @@ def tp_chunk_program(devices):
     rep = NamedSharding(mesh, P())
     state = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep), state)
-    run = _compiled_chunk(cfg, n, SERVE["max_len"], SERVE["chunk"], 0.0, None,
-                          None, None)
+    if ingest is None:
+        run = _compiled_chunk(cfg, n, SERVE["max_len"], SERVE["chunk"], 0.0,
+                              None, None, None)
+    else:
+        run = _compiled_ingest_chunk(cfg, n, SERVE["max_len"], SERVE["chunk"],
+                                     ingest, 0.0, None, None, None)
+        state += tuple(jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
+                       for shape in ((SERVE["chunk"], len(PIECE_FIELDS)),
+                                     (SERVE["chunk"], ingest)))
     return mesh, run, (_abstract_params(cfg, p_sh), *state)
 
 

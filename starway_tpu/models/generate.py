@@ -27,7 +27,8 @@ from jax import lax
 from .llama import (LlamaConfig, apply_rope, cfg_rope_tables, embed_tokens,
                     ffn_block, forward, layer_segments, matmul_w, qkv_proj,
                     rmsnorm, scan_segment)
-from ..ops import cache_write, cached_attention, latent_attention
+from ..ops import (cache_write, cached_attention, ingest_attention,
+                   latent_attention)
 from ..ops.attention import NEG_BIG, repeat_kv
 
 
@@ -96,14 +97,17 @@ def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig):
                             v_scale=cache.get("v_scale"))
 
 
-def _write_cached(cache: dict, new: dict, layer, pos, rows=None) -> dict:
+def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
+                  count=None) -> dict:
     """The C new positions of one layer into the stacked cache, every leaf
     (k, v and, int8, their scales): ``cache[name][layer, rows[b], :,
     pos[b] + c] = new[name][b, :, c]``.  ``new[name]``: [B, Hkv, C(, D)];
     ``pos``: scalar or per-row [B]; ``rows`` (default ``arange(B)``): the
     cache row each batch row owns — the paged pool passes page ids, with
     ``pos`` the offsets inside them.  A start above ``T - C`` is clamped,
-    as ``lax.dynamic_update_slice`` does.
+    as ``lax.dynamic_update_slice`` does; with ``count`` ([B]) only each
+    row's first ``count[b]`` positions are written and nothing is clamped
+    (``ops.cache_write``).
 
     The write itself is ``ops.cache_write``: on the chip in place, a tile
     a row.  Either XLA form (a scatter, or ``dynamic_update_slice`` per
@@ -119,7 +123,7 @@ def _write_cached(cache: dict, new: dict, layer, pos, rows=None) -> dict:
     for names in groups:  # same-shaped leaves share one kernel call
         out.update(zip(names, cache_write(
             tuple(cache[name] for name in names),
-            tuple(new[name] for name in names), layer, rows, pos)))
+            tuple(new[name] for name in names), layer, rows, pos, count)))
     return out
 
 
@@ -191,13 +195,80 @@ def decode_step_counted(params: dict, cache: dict, token, pos,
     return logits, out, counts
 
 
+def ingest_decode_step(params: dict, cache: dict, token, pos, piece,
+                       cfg: LlamaConfig, rope):
+    """One decode step of the ``B`` cache rows AND up to ``W`` prompt
+    tokens of one request, as ONE batch of ``B + W`` rows: embedding,
+    projections, ``wo``, FFN and norms read every weight once for both.
+    ``token``, ``pos``: ``[B]``, as :func:`decode_step_counted` takes them.
+    ``piece = (ids [W], slot, first, valid)``: the request's prompt tokens
+    at positions ``first .. first + valid - 1`` (the rest of ``ids`` is
+    padding), to be ingested into cache row ``slot``; ``valid == 0`` is a
+    step with nothing to ingest (its W rows compute and write nothing).
+
+    Only ``write`` and ``attend`` of :func:`cached_layer_scan` tell the
+    rows apart.  The decode rows write at their cursors and attend their
+    own rows, as ever.  The piece is written to its slot (only its valid
+    positions: a last piece's pads may reach past the cache) AFTER the
+    decode rows' write -- the slot's own decode row is dead and writes
+    junk at its frozen cursor, which the caller keeps at the piece's end,
+    where the next piece or the request's first decode step overwrites it
+    before anything reads it -- and attends that one row at ``W`` query
+    positions, write-then-attend (:func:`~starway_tpu.models.speculative.
+    chunk_decode_step`'s semantics; ``ops.ingest_attention``).  A dense
+    k/v cache without a window.  (An int8 cache's scale leaves go through
+    the same lines, but its pieces would attend over quantized entries
+    where a prefill reads them exact, so no server sends one here:
+    ``serving.SlotServer._ingest_widths``.)
+
+    Returns ``(logits [B, V], cache, piece_logits [V], counts)``:
+    ``piece_logits`` are the next-token logits of the piece's last valid
+    position (the request's first token, when the piece ends its prompt);
+    the head runs on ``B + 1`` rows."""
+    ids, slot, first, valid = piece
+    B, W = token.shape[0], ids.shape[0]
+    cos, sin = rope
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    one = lambda x: jnp.asarray(x, jnp.int32).reshape(1)
+    at = jnp.minimum(jnp.concatenate([pos, first + jnp.arange(W)]),
+                     cos.shape[0] - 1)
+    cos_p, sin_p = cos[at][:, None, None, :], sin[at][:, None, None, :]
+    h = embed_tokens(params, jnp.concatenate([token, ids]), cfg)[:, None, :]
+
+    def write(cache, new, layer):
+        cache = _write_cached(cache, {name: x[:B] for name, x in new.items()},
+                              layer, pos)
+        # [W, Hkv, 1(, D)] -> [1, Hkv, W(, D)]: one cache row's W positions.
+        mine = {name: jnp.swapaxes(x[B:], 0, 2) for name, x in new.items()}
+        return _write_cached(cache, mine, layer, one(first), rows=one(slot),
+                             count=one(valid))
+
+    def attend(q, cache, layer):
+        mine = ingest_attention(
+            jnp.swapaxes(q[B:], 0, 2), cache["k"], cache["v"], one(first),
+            one(slot), layer=layer, k_scale=cache.get("k_scale"),
+            v_scale=cache.get("v_scale"))
+        return jnp.concatenate([attend_cache(q[:B], cache, pos, layer, cfg),
+                                jnp.swapaxes(mine, 0, 2)])
+
+    h, out, counts = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg,
+                                       write, attend)
+    last = lax.dynamic_slice_in_dim(h[:, 0], B + jnp.maximum(valid, 1) - 1, 1)
+    rows = rmsnorm(jnp.concatenate([h[:B, 0], last]), params["final_norm"],
+                   cfg.norm_eps)
+    logits = matmul_w(rows, params["lm_head"]).astype(jnp.float32)
+    return logits[:B], out, logits[B], counts
+
+
 def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
                       write, attend):
     """The ONE per-layer body of every cached decode path — decode_step's
     C=1, the speculative chunk verify's C>1
-    (models/speculative.py:chunk_decode_step) and the paged pool's
-    (models/paged.py) run exactly this: the attention kind's projection
-    and RoPE, quantize-on-write when the cache is int8, ``write`` at the
+    (models/speculative.py:chunk_decode_step), the paged pool's
+    (models/paged.py) and the serving step that carries a prompt piece
+    beside its decode rows (:func:`ingest_decode_step`) run exactly this:
+    the attention kind's projection and RoPE, quantize-on-write when the
+    cache is int8, ``write`` at the
     caller's cursor(s), ``attend``, the FFN kind
     (:func:`~starway_tpu.models.llama.ffn_block`).  Sharing it is what
     keeps the pinned chunk==stepwise parity a tautology instead of a

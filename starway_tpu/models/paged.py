@@ -305,6 +305,10 @@ class PagedSlotServer(SlotServer):
     def _make_cache(self):
         return init_paged_pool(self.cfg, self.n_pages, self.page)
 
+    def _ingest_widths(self) -> tuple:
+        # Prompts enter the page pool by its own admit programs (_admit).
+        return ()
+
     def _post_init(self) -> None:
         # Host-side allocator: every slot starts on the trash page.
         self._tables = np.zeros((self.n_slots, self.max_pages), np.int32)

@@ -14,27 +14,48 @@ a FIXED, small set of compiled programs:
   Dh]``; every per-slot cursor (position, liveness, token budget) is a
   ``[n_slots]`` vector.  Shapes never depend on which requests are in
   flight.
-* **Admission = bucketed prefill.**  A new request's prompt is right-padded
-  to a power-of-two bucket and prefilled in its own dispatch (one compile
-  per bucket), then its kv rows are written into the slot with a dynamic
-  slice.  Pad/garbage columns are never read: attention masks by the
-  slot's cursor, and decode overwrites each position before the cursor
-  reaches it (write-then-attend).
 * **Decode runs in chunks.**  One compiled ``lax.scan`` advances ALL live
   slots ``chunk`` tokens (dead slots are masked: frozen cursor, writes
   land on a position that admission or the advancing cursor overwrites
   before any read).  Host round-trips happen once per chunk, not once
   per token.
-* **A step queues, then fetches.**  ``step()`` launches every admission
-  it makes and then the chunk, back to back, with no device value read in
-  between: an admit program's sampled token stays on the device, where
-  ``serve_seat`` files it and the slot's cursor, budget and liveness into
-  the slot state.  Two blocking reads follow, however many requests were
-  admitted: the step's first tokens (ready when the last admit program
-  has run, the chunk already queued behind it) and the chunk's result.
+* **Prompts ride the decode chunk** (dense k/v caches that hold k and v
+  as computed, no window; the one decision is
+  ``SlotServer._ingest_widths``).  A request that takes a
+  free slot launches nothing: the host lays its prompt over the coming
+  chunks' steps in pieces of ``W`` tokens (``INGEST_WIDTHS``, the smallest
+  width that brings what waits in within a chunk: all of it, or the
+  longest prompt while requests queue for slots), and
+  each step of the mixed chunk
+  (``serve_decode_chunk_ingest_<W>``) puts the ``n_slots`` decode rows and
+  one piece's ``W`` rows through the weights as ONE batch: the decode step
+  is bound by its weight reads, so the prompt tokens ride reads it pays
+  for anyway, and no lane stands still for a prefill.  Only the cache
+  write and the attention tell the rows apart (write-then-attend on the
+  request's own cache row).  The step that holds a prompt's last piece
+  samples the first token and seats the slot inside the scan.  One
+  blocking read a step.
+* **Admission = bucketed prefill** (every other kind: an int8 cache, a
+  latent cache, a rolling window, the page pool, a ``prefix=`` request).  A new
+  request's prompt is right-padded to a power-of-two bucket and prefilled
+  in its own dispatch (one compile per bucket), then its kv rows are
+  written into the slot with a dynamic slice.  Pad/garbage columns are
+  never read: attention masks by the slot's cursor, and decode overwrites
+  each position before the cursor reaches it (write-then-attend).
+* **A step queues, then fetches.**  On that path ``step()`` launches every
+  admission it makes and then the chunk, back to back, with no device
+  value read in between: an admit program's sampled token stays on the
+  device, where ``serve_seat`` files it and the slot's cursor, budget and
+  liveness into the slot state.  Two blocking reads follow, however many
+  requests were admitted: the step's first tokens (ready when the last
+  admit program has run, the chunk already queued behind it) and the
+  chunk's result.
 * **Greedy continuous batching is BIT-IDENTICAL to standalone
-  ``generate()``** for every request, whatever the interleaving: same
-  prefill, same decode step, same masking — pinned by
+  ``generate()``** for every request, whatever the interleaving: the
+  admit programs run the same prefill, and an ingested prompt's positions
+  see what a prefill's see (which is why an int8 cache keeps its admit
+  programs: a piece would attend over the cache's quantized entries,
+  where a prefill reads the prompt's k/v exact) — pinned by
   tests/test_serving.py against the one-request oracle.
 
 * **Prefix caching.**  ``register_prefix`` prefills a shared prefix once
@@ -74,8 +95,9 @@ from jax import lax
 
 from .. import perf
 from ..core import swtrace
-from .generate import (_sample, cache_len, decode_step_counted, init_cache,
-                       init_rolling_cache, prefill)
+from .generate import (_sample, cache_len, decode_step_counted,
+                       ingest_decode_step, init_cache, init_rolling_cache,
+                       prefill)
 from .llama import LlamaConfig, cfg_rope_tables
 
 # ----------------------------------------------------------- the serve logs
@@ -100,12 +122,13 @@ def request_log() -> list:
     """The last ``LOG_ROWS`` requests of this process, oldest first, as
     copies.  A SERVER row (``side: "server"``, written by
     :class:`SlotServer`) is identified by ``(server, rid)`` and carries
-    ``n_prompt``, ``bucket`` (the admit program's prompt bucket, 0 on the
-    rolling path), ``n_out``, ``step0`` (this server's ``step()`` count at
+    ``n_prompt``, ``bucket`` (the admit program's prompt bucket; 0 where
+    there is none: the rolling path, a prompt ingested inside the decode
+    chunk), ``n_out``, ``step0`` (this server's ``step()`` count at
     its admission), ``steps`` (``step()`` calls it lived through) and the
-    stamps ``t_submit``, ``t_admit0`` (its admission begins),
-    ``t_first`` (its first token is on the host: one instant for all a
-    step admits), ``t_done``; ``status`` is
+    stamps ``t_submit``, ``t_admit0`` (its admission begins: it takes its
+    slot), ``t_first`` (its first token is on the host: one instant for
+    all a step admits or seats), ``t_done``; ``status`` is
     ``queued`` / ``running`` until it ends as ``done`` / ``cancelled`` /
     ``rejected`` (a rejected row has no rid).  Behind the transport bridge
     (models/remote_serving.py) the same row also carries ``route``
@@ -133,7 +156,14 @@ def step_log() -> list:
     there until the step returns, those ``on_tokens`` calls included) and
     ``fetches`` (blocking device-to-host reads the step made: at most two,
     the first tokens and the chunk's result, however many it admitted).
-    The durations leave out the Python between them.  A model with a
+    On the ingest path (:meth:`SlotServer._ingest_widths`) no lane ever
+    stands still for an admission: ``admit_s`` is 0 and ``fetches`` 1 unless a ``prefix=``
+    request ran its admit program, ``admits`` counts the requests that
+    took a slot, and three fields say what the chunk ingested:
+    ``ingest_width`` (the width of the mixed chunk the step launched, 0
+    for the plain chunk), ``ingest_rows`` (that width times the steps that
+    carried a piece) and ``ingest_tokens`` (the real prompt tokens in
+    them).  The durations leave out the Python between them.  A model with a
     routed FFN (``cfg.routed``) adds, for the chunk's decode steps and
     every slot's row in them: ``moe_assign`` ((token, choice) pairs that
     landed on held experts, all routed layers), ``moe_touched`` (held
@@ -187,12 +217,13 @@ def _write_slot_and_sample(cache, small, logits, slot, key, temperature,
     return cache, tok
 
 
-def _seat(token, pos, live, remaining, tok, seat):
-    """An admission's slot state, set on the device from the admit
-    program's own sampled token ``tok``: ``seat`` is ``[slot, cursor,
-    max_new, eos id or -1]``.  One shared program behind every admit
-    program (dense, prefix, rolling, paged), so the host never reads a
-    first token in order to seat its request."""
+def _seat_state(token, pos, live, remaining, tok, seat):
+    """An admission's slot state, set on the device from its own sampled
+    first token ``tok``: ``seat`` is ``[slot, cursor, max_new, eos id or
+    -1]``.  The host never reads a first token in order to seat its
+    request: behind every admit program (prefix, rolling, paged, latent)
+    this is the program ``serve_seat``; a prompt ingested inside the
+    decode chunk is seated by the same lines in the chunk's scan."""
     slot, cursor, max_new, eos = seat[0], seat[1], seat[2], seat[3]
     done = (max_new == 1) | (tok == eos)
     return (token.at[slot].set(tok), pos.at[slot].set(cursor),
@@ -200,7 +231,8 @@ def _seat(token, pos, live, remaining, tok, seat):
 
 
 # Not ``serve_admit*``: a trace reduction takes those for the prefills.
-_seat = _named_jit(_seat, "serve_seat")
+_seat = _named_jit(lambda *state_tok_seat: _seat_state(*state_tok_seat),
+                   "serve_seat")
 
 
 @functools.cache
@@ -356,17 +388,95 @@ def _compiled_chunk(cfg: LlamaConfig, n_slots: int, max_len: int, chunk: int,
     return _named_jit(run, "serve_decode_chunk", donate_argnums=(1,))
 
 
+# Widths of a prompt piece, the rows a step of the mixed chunk carries
+# beside its decode rows: one compiled program a width.  A decode step is
+# bound by the weights it reads and has rows to spare, so a narrow piece
+# rides those reads and a wide one is paid in every lane's step: a chunk
+# takes the smallest width at which what waits is in within the chunk (all
+# of it, or the longest prompt while requests queue for slots:
+# SlotServer._plan_ingest; what each width costs on the chip is PERF.md
+# section 5's).
+INGEST_WIDTHS = (128, 256)
+
+# A step's piece as the host describes it to the mixed chunk, int32 each.
+PIECE_FIELDS = ("slot", "first", "valid", "last", "max_new", "eos")
+
+
+@functools.cache
+def _compiled_ingest_chunk(cfg: LlamaConfig, n_slots: int, max_len: int,
+                           chunk: int, width: int, temperature: float,
+                           top_k: Optional[int], top_p: Optional[float],
+                           eos_id: Optional[int]):
+    """:func:`_compiled_chunk` whose every step also carries one piece of
+    one request's prompt, up to ``width`` tokens (dense k/v caches).
+
+    ``desc [chunk, 6]`` (``PIECE_FIELDS``) and ``ids [chunk, width]`` are
+    the scan's inputs: step s ingests ``ids[s, :valid]`` at positions
+    ``first ..`` of cache row ``slot`` in the same batch as the decode rows
+    (:func:`~starway_tpu.models.generate.ingest_decode_step`) and leaves
+    the slot's cursor at the piece's end.  A step that holds a prompt's
+    LAST piece samples the request's first token from the piece's last
+    valid position and seats the slot in the carry (:func:`_seat_state`,
+    ``max_new == 1`` and eos-as-first-token included): the slot decodes
+    from the next step of the same chunk.  ``valid == 0``: nothing to
+    ingest in that step.  Emits, beside :func:`_compiled_chunk`'s outputs,
+    ``firsts [chunk]``: each step's sampled first token (meaningful where
+    ``last`` is set)."""
+    rope = cfg_rope_tables(cfg, max_len)
+
+    def run(params, cache, token, pos, live, remaining, key, desc, ids):
+        def decode_one(cache, token, pos, piece):
+            d, piece_ids = piece
+            return ingest_decode_step(params, cache, token, pos,
+                                      (piece_ids, d[0], d[1], d[2]), cfg, rope)
+
+        decode = make_chunk_scan_step(decode_one, max_len, temperature,
+                                      top_k, top_p, eos_id)
+
+        def step(carry, piece):
+            carry, (nxt, emit, piece_logits, pairs) = decode(carry, piece)
+            cache, token, pos, live, remaining, key = carry
+            slot, first, valid, last, max_new, eos = (
+                piece[0][i] for i in range(len(PIECE_FIELDS)))
+            key, sub = jax.random.split(key)
+            tok = _sample(piece_logits[None], sub, temperature, top_k,
+                          top_p)[0]
+            # The slot is dead while its prompt comes in: the chunk's own
+            # update left its state alone.  Its cursor follows the pieces
+            # (ingest_decode_step says why); the last piece seats it.
+            pos = jnp.where(valid > 0, pos.at[slot].set(first + valid), pos)
+            seated = _seat_state(token, pos, live, remaining, tok, jnp.stack(
+                [slot, first + valid, max_new, eos]))
+            token, pos, live, remaining = (
+                jnp.where(last > 0, new, old) for new, old
+                in zip(seated, (token, pos, live, remaining)))
+            return ((cache, token, pos, live, remaining, key),
+                    (nxt, emit, pairs, tok))
+
+        (cache, token, pos, live, remaining, key), (toks, mask, pairs,
+                                                    firsts) = lax.scan(
+            step, (cache, token, pos, live, remaining, key), (desc, ids))
+        return (cache, token, pos, live, remaining, key, toks, mask, pairs,
+                firsts)
+
+    return _named_jit(run, f"serve_decode_chunk_ingest_{width}",
+                      donate_argnums=(1,))
+
+
 def make_chunk_scan_step(decode_one, max_len: int, temperature: float,
                          top_k, top_p, eos_id):
     """THE per-step body of every chunked serving loop — dense and paged
     (models/paged.py) scan exactly this, so the liveness/eos/budget/
     emission semantics cannot drift between cache layouts.
     ``decode_one(cache, token, pos) -> (logits, cache)``; what it returns
-    beyond the two is emitted per step after ``(tokens, mask)``."""
+    beyond the two is emitted per step after ``(tokens, mask)``.  A scan
+    over inputs hands each step's to ``decode_one`` as a fourth argument
+    (the mixed chunk's prompt pieces)."""
 
-    def step(carry, _):
+    def step(carry, xs):
         cache, token, pos, live, remaining, key = carry
-        logits, cache, *extra = decode_one(cache, token, pos)
+        logits, cache, *extra = decode_one(
+            cache, token, pos, *(() if xs is None else (xs,)))
         key, sub = jax.random.split(key)
         nxt = _sample(logits, sub, temperature, top_k, top_p)
         emit_live = live & (remaining > 0)
@@ -427,15 +537,31 @@ class SlotServer:
     ``run()`` loops until everything queued has finished.  Generated
     tokens INCLUDE the terminating eos (when ``eos_id`` fires).
 
-    STREAMING: ``on_tokens(rid, tokens, done)`` fires inside ``step()``.
-    A step launches its admissions and its chunk first and reads the
-    device afterwards, so the first tokens of everything it admitted
-    arrive together, in admission order, once the last admit program has
-    run; each request's chunk tokens follow when the chunk has, and
-    ``([], True)`` exactly once when the request finishes.  A request
-    ``cancel()``led from a first-token callback has by then a chunk in
-    flight: that chunk decodes its slot once more and the tokens are
-    thrown away, never delivered.
+    WHICH PATH a request takes is decided from the cache this server
+    holds (:meth:`_ingest_widths`), never from a model's name.  Dense k/v
+    leaves as computed (no int8 scales, no window): the request takes its
+    slot at once and its prompt is ingested inside the decode chunks that
+    follow, a piece a step, beside the slots that decode; no admit program
+    runs.  An int8 cache, a latent cache, a rolling window, the page pool
+    (:class:`~starway_tpu.models.paged.PagedSlotServer`) and, on any
+    server, a ``prefix=`` request: an admit program a request, queued in
+    front of the chunk.
+
+    STREAMING: ``on_tokens(rid, tokens, done)`` fires inside ``step()``,
+    after the step's one read of the chunk's result (ingest path): for each
+    request whose last piece the chunk held, its first token, in the order
+    they were seated; then each request's chunk tokens (a request seated
+    in step ``s`` of the chunk decodes from step ``s + 1``), and ``([],
+    True)`` exactly once when the request finishes.  A prompt longer than
+    a chunk ingests (``chunk x W`` tokens) yields nothing until a later
+    step.  Behind admit programs a step launches its admissions and its
+    chunk first and reads the device afterwards, so the first tokens of
+    everything it admitted arrive together, in admission order, once the
+    last admit program has run, before the chunk's result is read.  A
+    request ``cancel()``led from a first-token callback has by then a
+    chunk in flight: that chunk decodes its slot once more and the tokens
+    are thrown away, never delivered; one cancelled half ingested frees
+    its slot at the next step and delivers nothing.
 
     PREFIX CACHING: ``register_prefix(tokens)`` prefills a shared prefix
     (system prompt, few-shot preamble) once; ``submit(suffix,
@@ -503,6 +629,8 @@ class SlotServer:
         # not cache memory.  (_make_cache is a subclass hook: the paged
         # server allocates a shared page pool instead — models/paged.py.)
         self.cache = self._make_cache()
+        # How a prompt enters that cache: () = by an admit program.
+        self._widths = self._ingest_widths()
         self.token = jnp.zeros((n_slots,), jnp.int32)
         self.pos = jnp.zeros((n_slots,), jnp.int32)
         self.live = jnp.zeros((n_slots,), bool)
@@ -516,6 +644,14 @@ class SlotServer:
         self._pos_host = np.zeros((n_slots,), np.int32)
         # Admitted, first token still on the device: (slot, rid).
         self._firsts: list = []
+        # The ingest path's: prompts on their way into a slot, in admission
+        # order (slot -> [rid, prompt, max_new, tokens ingested]); the steps
+        # of the chunk in flight that seat one, (step, rid), and their
+        # first tokens, still on the device; whether the programs are built.
+        self._ingest: dict[int, list] = {}
+        self._seats: list = []
+        self._seat_toks = None
+        self._built = not self._widths
         self._step: dict = {}  # the step_log() row of the step under way
 
         self._next_rid = 0
@@ -543,6 +679,34 @@ class SlotServer:
     def _make_cache(self):
         return (init_rolling_cache(self.cfg, self.n_slots) if self.rolling
                 else init_cache(self.cfg, self.n_slots, self.max_len))
+
+    def _ingest_widths(self) -> tuple:
+        """THE decision of how a prompt enters the cache, made once, from
+        the cache this server holds: a slot's row of dense k/v leaves of
+        ``max_len`` positions, holding k and v as computed, takes its
+        prompts piece by piece inside the decode chunk, at the widths
+        returned (those of ``INGEST_WIDTHS`` the plan can choose,
+        :meth:`_plan_ingest`: the last is the first at which the longest
+        prompt this cache holds comes in within one chunk).  Every other
+        kind keeps its admit programs and gets ``()``: a latent cache
+        (``ckv``), a rolling window, an int8 cache (a piece attends over
+        what the cache holds, quantized there, where a prefill reads the
+        prompt's k/v exact: other tokens than ``generate()``'s) and a
+        subclass with a layout of its own (the page pool overrides this).
+        A ``prefix=`` request takes its admit program on every kind
+        (:meth:`_ingests`)."""
+        if self.rolling or "k" not in self.cache or "k_scale" in self.cache:
+            return ()
+        widths = []
+        for w in INGEST_WIDTHS:
+            widths.append(w)
+            if self.chunk * w >= self.max_len - 1:
+                break
+        return tuple(widths)
+
+    def _ingests(self, prefix: Optional[int]) -> bool:
+        """Whether a request's prompt comes in inside the decode chunk."""
+        return bool(self._widths) and prefix is None
 
     def _post_init(self) -> None:
         """Subclass hook, called at the end of __init__."""
@@ -676,6 +840,12 @@ class SlotServer:
     # ------------------------------------------------------------- engine
     def _admit(self, slot: int, rid: int, prompt: np.ndarray,
                max_new: int, prefix: Optional[int] = None) -> None:
+        if self._ingests(prefix):
+            # No program: the coming chunks carry the prompt, piece by
+            # piece (_plan_ingest), and seat the request themselves.
+            self._ingest[slot] = [rid, prompt, max_new, 0]
+            self._occupy(slot, rid)
+            return
         self.key, sub = jax.random.split(self.key)
         plen = 0
         if prefix is not None:
@@ -727,14 +897,18 @@ class SlotServer:
         self.token, self.pos, self.live, self.remaining = _seat(
             self.token, self.pos, self.live, self.remaining, tok,
             jnp.asarray([slot, cursor, max_new, eos], jnp.int32))
+        self._occupy(slot, rid)
+        self._pos_host[slot] = cursor
+        self._live_host[slot] = max_new > 1  # eos: once the token is here
+        self._firsts.append((slot, rid))
+
+    def _occupy(self, slot: int, rid: int) -> None:
+        """The slot is the request's from now on (either path)."""
         row = self._rows.get(rid)
         if row is not None:
             row.update(status="running", step0=self._n_steps)
         self._slot_rid[slot] = rid
         self._collected[rid] = []
-        self._pos_host[slot] = cursor
-        self._live_host[slot] = max_new > 1  # eos: once the token is here
-        self._firsts.append((slot, rid))
 
     def _fetch(self, tree):
         """THE blocking device-to-host read of the serve loop: everything
@@ -791,6 +965,7 @@ class SlotServer:
                 self.remaining = self.remaining.at[slot].set(0)
                 self._live_host[slot] = False
                 del self._slot_rid[slot]
+                self._ingest.pop(slot, None)  # a prompt half ingested
                 self._close_row(rid, "cancelled",
                                 len(self._collected.pop(rid, ())))
                 self._on_slot_freed(slot)
@@ -815,7 +990,7 @@ class SlotServer:
         # cancel() another request that finished in this same step,
         # removing its entries before the loop reaches them.
         for slot, rid in list(self._slot_rid.items()):
-            if not live[slot]:
+            if not live[slot] and slot not in self._ingest:
                 if rid not in self._collected:
                     self._slot_rid.pop(slot, None)  # cancelled mid-loop
                     continue
@@ -833,10 +1008,12 @@ class SlotServer:
         requests that finished during this step.
 
         The device programs of one step are queued with no host round trip
-        between them: every admission (its admit program, then
-        ``serve_seat``), then the chunk.  Only then does the host read the
-        device, twice at most: the step's first tokens, and the chunk's
-        tokens with the final ``live`` and ``pos``."""
+        between them: every admission that has a program (its admit
+        program, then ``serve_seat``), then the chunk, which on the ingest
+        path carries the waiting prompts' pieces.  Only then does the host
+        read the device: the first tokens of what the admit programs
+        admitted, if any, and the chunk's tokens with the first tokens it
+        seated and the final ``live`` and ``pos``."""
         finished: dict = {}
         scope = self.stage_scope
         self._n_steps += 1
@@ -845,7 +1022,10 @@ class SlotServer:
                 "server": self.server_id, "n_slots": self.n_slots,
                 "t0": whole.t0, "t1": whole.t0, "queued": 0, "live": 0,
                 "admits": 0, "admit_s": 0.0, "dispatch_s": 0.0,
-                "wait_s": 0.0, "harvest_s": 0.0, "fetches": 0}
+                "wait_s": 0.0, "harvest_s": 0.0, "fetches": 0,
+                "ingest_tokens": 0, "ingest_rows": 0, "ingest_width": 0}
+            if not self._built:
+                self._build_programs()
             free = [s for s in range(self.n_slots)
                     if s not in self._slot_rid]
             # WHICH requests a step admits is the queue's order (its first
@@ -868,8 +1048,9 @@ class SlotServer:
                     with span:
                         if row is not None:
                             row["t_admit0"] = span.t0
-                            row["bucket"] = (_bucket(len(prompt), self.buckets)
-                                             if self.buckets else 0)
+                            row["bucket"] = (
+                                0 if not self.buckets or self._ingests(prefix)
+                                else _bucket(len(prompt), self.buckets))
                         self._admit(free.pop(0), rid, prompt, max_new, prefix)
                 except RuntimeError:
                     # Transient resource exhaustion (the paged server's
@@ -887,17 +1068,20 @@ class SlotServer:
             # admitted) needs no chunk; one whose first token may be its
             # eos is found out after the chunk was queued, and rides it
             # masked.
-            if any(self._live_host[s] for s in self._slot_rid):
+            if self._ingest or any(self._live_host[s]
+                                   for s in self._slot_rid):
                 self.key, sub = jax.random.split(self.key)
                 toks, mask = self._run_chunk(sub)
                 with perf.stage_span(scope, "serve.chunk_wait") as span:
-                    toks, mask, pairs, live, pos = self._fetch(
-                        (toks, mask, self._pairs, self.live, self.pos))
+                    toks, mask, pairs, firsts, live, pos = self._fetch(
+                        (toks, mask, self._pairs, self._seat_toks, self.live,
+                         self.pos))
                 step["wait_s"] = span.seconds
                 self._live_host, self._pos_host = np.array(live), np.array(pos)
                 if pairs is not None:  # a routed model's
                     step.update(_moe_fields(pairs))
                 with perf.stage_span(scope, "serve.harvest") as span:
+                    self._hand_out_seated(firsts)
                     # Snapshot: an on_tokens callback may legally cancel()
                     # a request (its own or another), which mutates
                     # _slot_rid/_collected.
@@ -940,15 +1124,106 @@ class SlotServer:
 
     def _launch_chunk(self, sub):
         """Launch the chunk program (subclass hook: the paged server runs
-        its page-table program here); returns (tokens, mask)."""
-        run = _compiled_chunk(self.cfg, self.n_slots, self.max_len,
-                              self.chunk, *self.sampling, self.eos_id,
-                              rolling=self.rolling)
+        its page-table program here); returns (tokens, mask).  With
+        prompts waiting to be ingested it is the mixed chunk of the width
+        the plan chose (:meth:`_plan_ingest`), else the plain one."""
+        pieces = self._plan_ingest() if self._ingest else ()
+        run = self._chunk_program(pieces[1].shape[1] if pieces else None)
         (self.cache, self.token, self.pos, self.live, self.remaining,
-         _key, toks, mask, self._pairs) = run(
+         _key, toks, mask, self._pairs, *firsts) = run(
              self.params, self.cache, self.token, self.pos, self.live,
-             self.remaining, sub)
+             self.remaining, sub, *pieces)
+        self._seat_toks = firsts[0] if firsts else None
         return toks, mask
+
+    def _chunk_program(self, width: Optional[int]):
+        """The plain chunk (``width`` None) or the mixed one of a width."""
+        if width is None:
+            return _compiled_chunk(
+                self.cfg, self.n_slots, self.max_len, self.chunk,
+                *self.sampling, self.eos_id, rolling=self.rolling)
+        return _compiled_ingest_chunk(
+            self.cfg, self.n_slots, self.max_len, self.chunk, width,
+            *self.sampling, self.eos_id)
+
+    def _build_programs(self) -> None:
+        """Before the first request is served: every program the ingest
+        path can launch is compiled (or loaded from the compile cache) by
+        one run on the idle server -- the plain chunk and the mixed chunk
+        of each width, nothing to ingest, no slot live -- so that no later
+        step compiles, whatever backlog it meets."""
+        for width in (None, *self._widths):
+            pieces = () if width is None else (
+                np.zeros((self.chunk, len(PIECE_FIELDS)), np.int32),
+                np.zeros((self.chunk, width), np.int32))
+            (self.cache, self.token, self.pos, self.live,
+             self.remaining, *_rest) = self._chunk_program(width)(
+                 self.params, self.cache, self.token, self.pos, self.live,
+                 self.remaining, self.key, *pieces)
+        self._built = True
+
+    def _plan_ingest(self):
+        """Lay the waiting prompts' pieces over the coming chunk's steps:
+        in admission order, one request's piece a step, a prompt's pieces
+        in consecutive steps, its last piece padded to the width; what does
+        not fit continues in the next chunk.  The width is the smallest of
+        ``self._widths`` at which what waits comes in within this chunk,
+        else the largest: ALL the waiting prompts while no request waits
+        for a slot, the LONGEST of them while some do.  A wider piece's
+        rows are paid in every lane's step and a prompt left to the next
+        chunk idles one lane: with requests queued for slots the lanes are
+        what is scarce, without them a chunk's wait for a first token is
+        what is felt (and a width that leaves the second of two arrivals
+        to the next chunk puts a second mode at the 95th percentile of the
+        time to first token: PERF.md section 6, PR 29, has the readings).
+        The backlog decides, nothing else.  Returns ``(desc [chunk, 6],
+        ids [chunk, width])`` for :func:`_compiled_ingest_chunk` and notes
+        in ``self._seats`` which steps seat which request."""
+        left = [len(p) - at for _rid, p, _max_new, at in self._ingest.values()]
+        pieces = max if self._pending else sum
+        width = next((w for w in self._widths
+                      if pieces(-(-n // w) for n in left) <= self.chunk),
+                     self._widths[-1])
+        desc = np.zeros((self.chunk, len(PIECE_FIELDS)), np.int32)
+        ids = np.zeros((self.chunk, width), np.int32)
+        eos = -1 if self.eos_id is None else self.eos_id
+        s = 0
+        for slot, req in list(self._ingest.items()):
+            rid, prompt, max_new, at = req
+            while s < self.chunk and at < len(prompt):
+                n = min(width, len(prompt) - at)
+                last = at + n == len(prompt)
+                desc[s] = (slot, at, n, last, max_new, eos)
+                ids[s, :n] = prompt[at:at + n]
+                at += n
+                if last:
+                    self._seats.append((s, rid))
+                    del self._ingest[slot]
+                s += 1
+            req[3] = at
+            if s == self.chunk:
+                break
+        self._step.update(ingest_tokens=int(desc[:, 2].sum()),
+                          ingest_rows=s * width, ingest_width=width)
+        return desc, ids
+
+    def _hand_out_seated(self, firsts) -> None:
+        """The first tokens of the requests the chunk seated (``firsts``:
+        its fourth output, on the host), in the order it seated them; each
+        before the request's chunk tokens, which the caller hands out
+        next."""
+        seats, self._seats = self._seats, []
+        t_first = _now()
+        for at, rid in seats:
+            if rid not in self._collected:
+                continue  # cancelled while its chunk was in flight
+            tok = int(firsts[at])
+            row = self._rows.get(rid)
+            if row is not None:
+                row["t_first"] = t_first
+            self._collected[rid].append(tok)
+            if self.on_tokens is not None:
+                self.on_tokens(rid, [tok], False)
 
     def run(self) -> dict:
         """Drive step() until every submitted request has finished."""

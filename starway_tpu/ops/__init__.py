@@ -11,8 +11,8 @@ i.e. CollectivePermute; Ulysses = all-to-all composed from P2P").
 
 Every operation that has a Pallas kernel is ONE function here, and that
 function alone decides what runs (ops/dispatch.py): ``self_attention``,
-``cached_attention``, ``latent_attention``, ``cache_write``,
-``paged_attention``, ``grouped_matmul``, ``quantized_matmul`` and the ring
+``cached_attention``, ``ingest_attention``, ``latent_attention``,
+``cache_write``, ``paged_attention``, ``grouped_matmul``, ``quantized_matmul`` and the ring
 step ``ring_step`` / ``ring_step_bwd``.  Each lives in the file that holds
 its kernel, beside its ``*_lax`` twin.
 """
@@ -26,7 +26,8 @@ from .collectives import (
 )
 from .dispatch import per_head_shard
 from .pallas_attention import ring_step, ring_step_bwd, self_attention
-from .pallas_decode import cache_write, cached_attention, latent_attention
+from .pallas_decode import (cache_write, cached_attention, ingest_attention,
+                            latent_attention)
 from .pallas_gemv import quantized_matmul
 from .pallas_gmm import grouped_matmul
 from .pallas_paged import paged_attention
@@ -34,6 +35,7 @@ from .quantize import quantize_params
 
 __all__ = ["ring_shift", "all_to_all", "all_gather", "psum",
            "reduce_scatter", "quantize_params", "per_head_shard",
-           "self_attention", "cached_attention", "latent_attention",
+           "self_attention", "cached_attention", "ingest_attention",
+           "latent_attention",
            "cache_write", "paged_attention", "grouped_matmul",
            "quantized_matmul", "ring_step", "ring_step_bwd"]

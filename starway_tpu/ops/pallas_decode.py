@@ -117,10 +117,11 @@ def _head_row(scales, h):
     return jnp.sum(jnp.where(rows == h, scales, 0.0), axis=0, keepdims=True)
 
 
-def _decode_stream_kernel(pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *refs,
+def _decode_stream_kernel(pos_ref, layer_ref, *refs,
                           sm_scale: float, block_k: int, hkv: int,
                           window: "int | None", n_blocks: int,
-                          quant: bool = False, n_q: int = 1):
+                          quant: bool = False, n_q: int = 1,
+                          by_row: bool = False):
     """One grid cell per (batch, kv head): the WHOLE cache sweep runs in a
     single cell as a fori_loop over kv blocks with double-buffered manual
     DMA (compute on block i overlaps the HBM stream of block i+1).
@@ -138,7 +139,14 @@ def _decode_stream_kernel(pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *refs,
     pipeline; the int8 cache blocks halve the DMA bytes.  A cell fetches
     its batch row's ``[Hkv, block_k]`` scale block whole (one head's row
     of it is a slice below the (8, 128) tile) and keeps its own row.
+
+    ``by_row``: a third prefetched scalar array names the CACHE row each
+    batch row reads (:func:`slot_attention`: the pieces of one prompt, all
+    on their request's slot); without it batch row b reads cache row b.
     """
+    if by_row:
+        row_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, *refs = refs
     if quant:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems, m_scr,
          l_scr, acc_scr) = refs
@@ -151,6 +159,10 @@ def _decode_stream_kernel(pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *refs,
     layer = layer_ref[0]
     pos = pos_ref[b]
     hi = (pos + n_q - 1) // block_k  # last live block (queries span n_q)
+    if by_row:
+        # A prompt's last piece is padded: its pad queries may lie past T.
+        hi = jnp.minimum(hi, n_blocks - 1)
+        b = row_ref[b]
     if window is None:
         lo = jnp.int32(0)
     else:
@@ -228,7 +240,8 @@ def _pick_block(t: int, block_k: int, quant: bool) -> "int | None":
 
 def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
                      block_k: int = 512, interpret=None, window=None,
-                     k_scale=None, v_scale=None):
+                     k_scale=None, v_scale=None, rows=None,
+                     kernel_name: str = "sw_decode_attn_stream"):
     """Cached decode attention (1..C query positions) without expanding
     the grouped cache.
 
@@ -267,6 +280,11 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
     bf16 up to 4096, of 8) costs what every length cost before: that
     layer is sliced out and padded, a copy of it a call.  Allocate
     multiples of 128.
+
+    ``rows`` ([B] ints; :func:`slot_attention`): the cache row each batch
+    row reads, a third prefetched scalar array; queries may then lie past
+    the cache's end (they see every position).  ``kernel_name``: what a
+    trace calls the kernel.
     """
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
@@ -298,11 +316,11 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
     # this layout).  repeat_kv maps q head h -> kv head h // n_rep, so the
     # reshape groups correctly (ops/attention.py:repeat_kv).
     n_rows = n_rep * n_q
-    rows = _round_up(max(n_rows, 8), 8)  # TPU sublane tile
+    rows_q = _round_up(max(n_rows, 8), 8)  # TPU sublane tile
     qg = q.reshape(b, hkv, n_rows, d)
-    if rows != n_rows:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - n_rows), (0, 0)))
-    qf = qg.reshape(b * hkv, rows, d)
+    if rows_q != n_rows:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_q - n_rows), (0, 0)))
+    qf = qg.reshape(b * hkv, rows_q, d)
 
     if _pick_block(t, block_k, quant) is None:
         def one_padded(a):
@@ -317,7 +335,8 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
     block_k = _pick_block(t, block_k, quant)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
-    q_spec = pl.BlockSpec((1, rows, d), lambda bh, *_: (bh, 0, 0))
+    by_row = () if rows is None else (jnp.asarray(rows, jnp.int32),)
+    q_spec = pl.BlockSpec((1, rows_q, d), lambda bh, *_: (bh, 0, 0))
     any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     quant_scratch = [pltpu.VMEM((2, hkv, block_k), jnp.float32)] * (
         2 * quant)
@@ -325,9 +344,10 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
         functools.partial(
             _decode_stream_kernel, sm_scale=sm_scale, block_k=block_k,
             hkv=hkv, window=None if window is None else int(window),
-            n_blocks=t // block_k, quant=quant, n_q=n_q),
+            n_blocks=t // block_k, quant=quant, n_q=n_q,
+            by_row=bool(by_row)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=2 + len(by_row),
             grid=(b * hkv,),
             in_specs=[q_spec] + [any_spec] * (2 + 2 * quant),
             out_specs=q_spec,
@@ -336,16 +356,16 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
                 pltpu.VMEM((2, block_k, d), v_cache.dtype),
             ] + quant_scratch + [
                 pltpu.SemaphoreType.DMA((2, 4 if quant else 2)),
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, d), jnp.float32),
+                pltpu.VMEM((rows_q, 128), jnp.float32),
+                pltpu.VMEM((rows_q, 128), jnp.float32),
+                pltpu.VMEM((rows_q, d), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b * hkv, rows, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b * hkv, rows_q, d), q.dtype),
         interpret=interpret,
-        name="sw_decode_attn_stream",
-    )(pos_arr, layer_arr, qf, k_cache, v_cache, *scales)
-    return out.reshape(b, hkv, rows, d)[:, :, :n_rows, :].reshape(
+        name=kernel_name,
+    )(pos_arr, layer_arr, *by_row, qf, k_cache, v_cache, *scales)
+    return out.reshape(b, hkv, rows_q, d)[:, :, :n_rows, :].reshape(
         b, hq, n_q, d)
 
 
@@ -409,6 +429,84 @@ def cached_attention(q, k_cache, v_cache, pos, *, layer=None, window=None,
     return dispatch.per_head_shard(
         kernel, (q, k_cache, v_cache, *scales),
         (jnp.asarray(pos, jnp.int32), jnp.asarray(layer, jnp.int32)),
+        head_dims=(1,) + (2,) * (2 + len(scales)))
+
+
+# ------------------------------------------- a prompt's piece on its slot
+
+# Query positions a grid cell of :func:`slot_attention` takes: with the
+# group's n_rep heads they are the rows of one matmul a kv block (512 at
+# Mistral's 4), and a cell sweeps the cache only as far as its own last
+# query.
+_SLOT_BLOCK_Q = 128
+
+
+def slot_attention(q, k_cache, v_cache, pos, rows, *, layer, k_scale=None,
+                   v_scale=None, interpret=None):
+    """``C`` consecutive queries a batch row against ONE row of the stacked
+    cache, named by an index: ``q [B, Hq, C, D]`` at positions ``pos[b] ..
+    pos[b] + C - 1`` attends ``cache[layer, rows[b]]`` (write-then-attend:
+    the entries are in the cache already, :func:`kv_write`).  This is how a
+    prompt's piece attends inside a serving step (models/generate.py::
+    ingest_decode_step): its request's slot is where the piece was just
+    written, beside the decode rows' own.  Returns ``[B, Hq, C, D]``.
+
+    The kernel is :func:`decode_attention`'s, under the name
+    ``sw_ingest_attn``: the queries are cut into tiles of
+    ``_SLOT_BLOCK_Q`` positions, each tile a batch row of that kernel at
+    its own cursor, all reading the cache row their request owns through a
+    prefetched row index beside the layer index.  Nothing is sliced out of
+    the stacked cache.  Queries past the cache's end (a last piece's pads)
+    are computed and mean nothing."""
+    b, hq, c, d = q.shape
+    tq = _SLOT_BLOCK_Q if c % _SLOT_BLOCK_Q == 0 else c
+    n = c // tq
+    tiles = q.reshape(b, hq, n, tq, d).transpose(0, 2, 1, 3, 4)
+    at = (jnp.asarray(pos, jnp.int32).reshape(-1, 1)
+          + tq * jnp.arange(n, dtype=jnp.int32)[None, :])
+    out = decode_attention(
+        tiles.reshape(b * n, hq, tq, d), k_cache, v_cache, at.reshape(-1),
+        layer=layer, k_scale=k_scale, v_scale=v_scale, interpret=interpret,
+        rows=jnp.repeat(jnp.asarray(rows, jnp.int32).reshape(-1), n),
+        kernel_name="sw_ingest_attn")
+    return out.reshape(b, n, hq, tq, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, hq, c, d)
+
+
+def slot_attention_lax(q, k_cache, v_cache, pos, rows, *, layer,
+                       k_scale=None, v_scale=None):
+    """:func:`slot_attention` in plain lax: the layer and the rows are
+    sliced out (copies), then :func:`decode_attention_lax`."""
+    rows = jnp.asarray(rows, jnp.int32).reshape(-1)
+    k, v, ks, vs = (
+        None if a is None else jnp.take(
+            jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+            rows, axis=0)
+        for a in (k_cache, v_cache, k_scale, v_scale))
+    return decode_attention_lax(q, k, v, pos, k_scale=ks, v_scale=vs)
+
+
+def ingest_attention(q, k_cache, v_cache, pos, rows, *, layer, k_scale=None,
+                     v_scale=None):
+    """A prompt piece's attention over its request's cache row, the
+    operation: the arguments of :func:`slot_attention`.  On a TPU the
+    kernel, per shard of the heads under a ``tp`` mesh; elsewhere
+    :func:`slot_attention_lax`."""
+    if not dispatch.use_kernels():
+        return slot_attention_lax(q, k_cache, v_cache, pos, rows,
+                                  layer=layer, k_scale=k_scale,
+                                  v_scale=v_scale)
+    scales = () if k_scale is None else (k_scale, v_scale)
+
+    def kernel(q, k, v, *rest):  # rest = (*scales, pos, rows, layer)
+        ks, vs = rest[:-3] or (None, None)
+        return slot_attention(q, k, v, rest[-3], rest[-2], layer=rest[-1],
+                              k_scale=ks, v_scale=vs)
+
+    return dispatch.per_head_shard(
+        kernel, (q, k_cache, v_cache, *scales),
+        (jnp.asarray(pos, jnp.int32), jnp.asarray(rows, jnp.int32),
+         jnp.asarray(layer, jnp.int32)),
         head_dims=(1,) + (2,) * (2 + len(scales)))
 
 
@@ -547,7 +645,12 @@ def _kv_write_kernel(layer_ref, row_ref, start_ref, lo_ref, hi_ref, *refs,
     positions out of HBM, replace positions ``lo .. hi - 1`` of it by the
     update (a select), DMA it back to where it came from.  All of a
     group's reads are in flight together, then all of its writes: a
-    decode step's 24 rows cost two DMA latencies, not 48."""
+    decode step's 24 rows cost two DMA latencies, not 48.
+
+    The rows are walked by loops, not unrolled in Python: unrolled, the
+    indexing of a group's windows was most of the seconds a serving
+    program takes to trace (24 rows: half a second on the chip's host,
+    once a program, PR 29; 128 rows in a latent cache's write)."""
     updates = refs[:n_arr]
     src = refs[n_arr:2 * n_arr]        # the caches, in HBM
     dst = refs[2 * n_arr:3 * n_arr]    # the same buffers (aliased outputs)
@@ -556,49 +659,58 @@ def _kv_write_kernel(layer_ref, row_ref, start_ref, lo_ref, hi_ref, *refs,
     layer = layer_ref[0]
     base = pl.program_id(0) * group
 
-    def window(ref, i):
-        return ref.at[(layer, row_ref[i], slice(None),
-                       pl.ds(pl.multiple_of(start_ref[i], align), width))
-                      + (slice(None),) * (len(ref.shape) - 4)]
+    def copy(a, j, back):
+        """The DMA of row ``base + j``'s window of array ``a``: out of HBM,
+        or ``back`` to where it came from."""
+        ref, i = (dst if back else src)[a], base + j
+        window = ref.at[(layer, row_ref[i], slice(None),
+                         pl.ds(pl.multiple_of(start_ref[i], align), width))
+                        + (slice(None),) * (len(ref.shape) - 4)]
+        ends = (bufs[a].at[j], window) if back else (window, bufs[a].at[j])
+        return pltpu.make_async_copy(*ends, sems.at[a, j])
 
-    def each(make):
-        return [make(a, j) for a in range(n_arr) for j in range(group)]
+    def each_row(body):
+        jax.lax.fori_loop(0, group, lambda j, _: body(j) or 0, 0)
 
-    reads = each(lambda a, j: pltpu.make_async_copy(
-        window(src[a], base + j), bufs[a].at[j], sems.at[a, j]))
-    for cp in reads:
-        cp.start()
-    for cp in reads:
-        cp.wait()
-    for j in range(group):
+    def each_copy(back, act):
+        each_row(lambda j: [getattr(copy(a, j, back), act)()
+                            for a in range(n_arr)] and None)
+
+    def select(j):
         at = jax.lax.broadcasted_iota(jnp.int32, bufs[0].shape[1:], 1)
         new = (at >= lo_ref[base + j]) & (at < hi_ref[base + j])
         for a in range(n_arr):
             bufs[a][j] = jnp.where(new, updates[a][j], bufs[a][j])
-    writes = each(lambda a, j: pltpu.make_async_copy(
-        bufs[a].at[j], window(dst[a], base + j), sems.at[a, j]))
-    for cp in writes:
-        cp.start()
-    for cp in writes:
-        cp.wait()
+
+    each_copy(False, "start")
+    each_copy(False, "wait")
+    each_row(select)
+    each_copy(True, "start")
+    each_copy(True, "wait")
 
 
-def kv_write_lax(caches, updates, layer, rows, pos):
+def kv_write_lax(caches, updates, layer, rows, pos, count=None):
     """:func:`kv_write` in plain lax (one scatter an array): what runs
     where Pallas does not (the CPU), where a cache length has no whole
     tiles, and what the kernel is tested against.  Same clamp."""
     c = updates[0].shape[2]
     t = caches[0].shape[3]
-    pos = jnp.clip(jnp.asarray(pos, jnp.int32), 0, t - c)
+    pos = jnp.asarray(pos, jnp.int32)
+    if count is None:
+        pos = jnp.clip(pos, 0, t - c)
     at = pos[:, None] + jnp.arange(c)[None, :]                  # [N, C]
+    if count is not None:  # the others go past the end, and are dropped
+        at = jnp.where(jnp.arange(c)[None, :]
+                       < jnp.asarray(count, jnp.int32)[:, None], at, t)
     rows = jnp.asarray(rows, jnp.int32)[:, None]
     # Advanced indices (row, position) lead the update: [N, C, Hkv(, D)].
     return tuple(
-        x.at[layer, rows, :, at].set(jnp.moveaxis(u, 2, 1))
+        x.at[layer, rows, :, at].set(jnp.moveaxis(u, 2, 1), mode="drop")
         for x, u in zip(caches, updates))
 
 
-def kv_write(caches, updates, layer, rows, pos, *, interpret=None):
+def kv_write(caches, updates, layer, rows, pos, *, count=None,
+             interpret=None):
     """``cache[layer, rows[n], :, pos[n] + c] = update[n, :, c]`` for every
     ``(cache, update)`` pair, IN PLACE: each output aliases its cache
     operand, only the new entries' tiles move, and the stacked array's
@@ -614,7 +726,11 @@ def kv_write(caches, updates, layer, rows, pos, *, interpret=None):
     arange(B)``, the paged pool ``rows = page ids, pos = offsets``.  As
     with ``lax.dynamic_update_slice`` a start above ``T - C`` is clamped.
     The N windows of one call must not overlap (two rows in one tile
-    would race, each writing back what it read).
+    would race, each writing back what it read).  ``count`` ([N] ints):
+    only the first ``count[n]`` of row n's C positions are written and
+    nothing is clamped; the caller keeps ``pos + count <= T`` (a prompt's
+    last piece, padded to the piece's width, may reach past the cache's
+    end with its pads).
 
     DMAs move whole tiles, so a row's window is the aligned span of
     ``width`` positions around ``pos .. pos + C - 1`` (at C = 1: 16 for
@@ -630,22 +746,30 @@ def kv_write(caches, updates, layer, rows, pos, *, interpret=None):
     # of the scales.
     align = 128 if c0.ndim == 4 else 32 // c0.dtype.itemsize
     if t % align:
-        return kv_write_lax(caches, updates, layer, rows, pos)
+        return kv_write_lax(caches, updates, layer, rows, pos, count)
     tail = c0.shape[4:]
     row_bytes = sum(x.dtype.itemsize for x in caches) * hkv * (
         tail[0] if tail else 1)  # of one position, all arrays
     c_max = max(_WRITE_VMEM_BYTES // row_bytes - align, align)
-    pos = jnp.clip(jnp.asarray(pos, jnp.int32), 0, t - c)
+    pos = jnp.asarray(pos, jnp.int32)
+    if count is None:
+        pos = jnp.clip(pos, 0, t - c)
+    else:
+        pos = jnp.clip(pos, 0, t)
+        count = jnp.asarray(count, jnp.int32)
     if c > c_max:  # a long chunk (a prefix admit's suffix): in pieces
         for at in range(0, c, c_max):
             caches = kv_write(
                 caches, [u[:, :, at:at + c_max] for u in updates], layer,
-                rows, pos + at, interpret=interpret)
+                rows, pos + at, interpret=interpret,
+                count=None if count is None else jnp.clip(count - at, 0,
+                                                          c_max))
         return tuple(caches)
 
     width = min(_round_up(align - 1 + c, align), t)
     start = jnp.minimum(pos // align * align, t - width)
     lo = pos - start
+    hi = lo + c if count is None else jnp.minimum(lo + count, width)
     if c > 1:
         # The update, placed in its window by XLA (a gather over a few
         # KiB): in the kernel a shift by a traced count is not a select.
@@ -678,11 +802,11 @@ def kv_write(caches, updates, layer, rows, pos, *, interpret=None):
         interpret=interpret,
         name="sw_kv_write",
     )(jnp.asarray(layer, jnp.int32).reshape(1),
-      jnp.asarray(rows, jnp.int32), start, lo, lo + c, *updates, *caches)
+      jnp.asarray(rows, jnp.int32), start, lo, hi, *updates, *caches)
     return tuple(out)
 
 
-def cache_write(caches, updates, layer, rows, pos):
+def cache_write(caches, updates, layer, rows, pos, count=None):
     """The in-place cache write, the operation: the arguments of
     :func:`kv_write`.  On a TPU the kernel, per shard of the heads under a
     ``tp`` mesh (dim 2 of the caches, dim 1 of the updates); elsewhere
@@ -690,9 +814,11 @@ def cache_write(caches, updates, layer, rows, pos):
     token of a page: models/paged.py's prefix admit) must take
     :func:`kv_write_lax` by name: the kernel's rows would race."""
     if not dispatch.use_kernels():
-        return kv_write_lax(caches, updates, layer, rows, pos)
+        return kv_write_lax(caches, updates, layer, rows, pos, count)
     n = len(caches)
     return dispatch.per_head_shard(
-        lambda *a: kv_write(a[:n], a[n:2 * n], *a[2 * n:]),
-        tuple(caches) + tuple(updates), (layer, rows, pos),
+        lambda *a: kv_write(a[:n], a[n:2 * n], *a[2 * n:2 * n + 3],
+                            count=a[2 * n + 3] if count is not None else None),
+        tuple(caches) + tuple(updates),
+        (layer, rows, pos) + (() if count is None else (count,)),
         head_dims=(2,) * n + (1,) * n, out_head_dims=(2,) * n)

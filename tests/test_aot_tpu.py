@@ -295,6 +295,19 @@ def _chunk_program(cfg, n_slots=N_SLOTS):
     return run, (_param_shapes(cfg), cache, *_slot_state(n_slots))
 
 
+def _ingest_chunk_program(cfg, width, n_slots=N_SLOTS):
+    """The mixed chunk (models/serving.py): the decode chunk whose steps
+    also carry a prompt piece of ``width`` tokens."""
+    from starway_tpu.models.serving import (PIECE_FIELDS,
+                                            _compiled_ingest_chunk)
+
+    run = _compiled_ingest_chunk(cfg, n_slots, MAX_LEN, CHUNK, width, 0.0,
+                                 None, None, None)
+    _plain, args = _chunk_program(cfg, n_slots)
+    return run, (*args, _s((CHUNK, len(PIECE_FIELDS)), I32),
+                 _s((CHUNK, width), I32))
+
+
 def _admit_program(cfg, bucket=2048):
     from starway_tpu.models.generate import init_cache
     from starway_tpu.models.serving import _compiled_admit
@@ -394,6 +407,39 @@ def test_decode_chunk_moves_no_cache_for_v5e(topo, monkeypatch, kv):
         a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(
             jax.eval_shape(lambda: init_cache(cfg, CELL_SLOTS, MAX_LEN))))
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 2
+    assert _cache_moves(text, 16, CELL_SLOTS) == []
+
+
+def _ingest_widths():
+    from starway_tpu.models.serving import INGEST_WIDTHS
+
+    return INGEST_WIDTHS
+
+
+@pytest.mark.parametrize("width", _ingest_widths())
+def test_ingest_chunk_moves_no_cache_for_v5e(topo, monkeypatch, width):
+    """Every ``serve_decode_chunk_ingest_<W>`` at the serving cells'
+    geometry (mistral7b, 24 x 2048): the piece's write and its attention
+    are kernels on the stacked cache too (``sw_kv_write``,
+    ``sw_ingest_attn`` through a row index), so the mixed chunk holds no
+    copy of the cache either -- no cache-shaped or layer-shaped array is
+    produced by anything but a kernel -- and its temporaries (printed;
+    the plain chunk has 806,048,768 B) stay under half the cache."""
+    _as_tpu(monkeypatch)
+    cfg = _mistral7b_16l()
+    compiled = _compile_program(topo, _ingest_chunk_program, cfg,
+                                width=width, n_slots=CELL_SLOTS)
+    text = compiled.as_text()
+    assert all(name in text for name in (
+        "sw_kv_write", "sw_decode_attn_stream", "sw_ingest_attn"))
+    from starway_tpu.models.generate import init_cache
+
+    cache_bytes = sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(
+            jax.eval_shape(lambda: init_cache(cfg, CELL_SLOTS, MAX_LEN))))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"serve_decode_chunk_ingest_{width}: temporaries {temp} B")
+    assert temp < cache_bytes / 2
     assert _cache_moves(text, 16, CELL_SLOTS) == []
 
 
@@ -551,11 +597,12 @@ def test_mesh_train_step_compiles_for_v5e_2x2(topo, monkeypatch):
 
 
 @pytest.mark.slow
-def test_tp_slot_server_chunk_compiles_for_v5e_2x2(topo, monkeypatch):
+@pytest.mark.parametrize("ingest", [None, 128], ids=["plain", "ingest_128"])
+def test_tp_slot_server_chunk_compiles_for_v5e_2x2(topo, monkeypatch, ingest):
     import chip_smoke
 
     _as_tpu(monkeypatch)
-    mesh, run, args = chip_smoke.tp_chunk_program(topo.devices[:2])
+    mesh, run, args = chip_smoke.tp_chunk_program(topo.devices[:2], ingest)
     with jax.set_mesh(mesh):
         compiled = run.lower(*args).compile()
     txt = compiled.as_text()

@@ -394,6 +394,80 @@ def test_kv_write_long_chunk_goes_in_pieces(monkeypatch):
                                   np.asarray(want, np.float32))
 
 
+def _ingest_widths():
+    from starway_tpu.models.serving import INGEST_WIDTHS
+
+    return INGEST_WIDTHS
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("first", [0, 384, 896], ids=lambda f: f"at{f}")
+@pytest.mark.parametrize("width", _ingest_widths())
+def test_slot_attention_matches_lax(width, first, int8):
+    """``slot_attention`` (the kernel behind ``ops.ingest_attention``:
+    ``W`` queries of one prompt piece against the ONE cache row its
+    request owns, named by an index beside the layer's) against its lax
+    twin, at every width the server ingests at.  T = 1024 is two kv blocks
+    of 512: a piece at 384 straddles the block boundary, one at 896
+    reaches past the cache's end with its pads (their rows mean nothing
+    and are left out), and the row read is not the batch row."""
+    from starway_tpu.ops.pallas_decode import (slot_attention,
+                                               slot_attention_lax)
+    from starway_tpu.ops.quantize import quantize_kv
+
+    L, R, Hq, Hkv, T, D = 2, 3, 4, 2, 1024, 64
+    q, k, v = _rand(31, (1, Hq, width, D), (L, R, Hkv, T, D),
+                    (L, R, Hkv, T, D), dtype=jnp.bfloat16)
+    kw = {}
+    if int8:
+        (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+        kw.update(k_scale=ks, v_scale=vs)
+    args = (q, k, v, jnp.asarray([first], jnp.int32),
+            jnp.asarray([2], jnp.int32))
+    got = jax.jit(lambda li: slot_attention(
+        *args, layer=li, interpret=True, **kw))(jnp.int32(1))
+    want = slot_attention_lax(*args, layer=jnp.int32(1), **kw)
+    live = min(width, T - first)
+    assert got.shape == (1, Hq, width, D) and live >= 128
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:, :, :live],
+                               np.asarray(want, np.float32)[:, :, :live],
+                               atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("leaf", ["bf16", "int8", "scales"])
+def test_kv_write_counted_writes_only_what_is_valid(leaf):
+    """``kv_write(count=)``: of a row's C positions only the first
+    ``count`` are written and the start is NOT clamped -- a prompt's last
+    piece, padded to the piece's width, ends inside the cache while its
+    pads would lie past it.  Rows: a whole piece, a short one, an empty
+    one, and one that ends at the cache's last position; kernel and lax
+    twin against a plain loop."""
+    from starway_tpu.ops.pallas_decode import kv_write, kv_write_lax
+
+    dtype, tile = {"bf16": (jnp.bfloat16, 16), "int8": (jnp.int8, 32),
+                   "scales": (jnp.float32, 128)}[leaf]
+    L, R, Hkv, T, D, C = 2, 5, 2, 4 * tile, 64, tile + 8
+    tail = () if leaf == "scales" else (D,)
+    ks = jax.random.split(jax.random.PRNGKey(17), 2)
+    draw = lambda key, shape: (jax.random.normal(key, shape) * 40).astype(dtype)
+    cache = draw(ks[0], (L, R, Hkv, T) + tail)
+    update = draw(ks[1], (4, Hkv, C) + tail)
+    rows = jnp.asarray([3, 0, 4, 1], jnp.int32)
+    pos = jnp.asarray([tile, 5, 0, T - 9], jnp.int32)
+    count = jnp.asarray([C, 3, 0, 9], jnp.int32)
+    want = np.array(cache)
+    for n in range(4):
+        c, at = int(count[n]), int(pos[n])
+        want[1, int(rows[n]), :, at:at + c] = np.asarray(update)[n, :, :c]
+    got, = jax.jit(lambda li: kv_write(
+        (cache,), (update,), li, rows, pos, count=count,
+        interpret=True))(jnp.int32(1))
+    lax_got, = kv_write_lax((cache,), (update,), jnp.int32(1), rows, pos,
+                            count)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(lax_got), want)
+
+
 # ------------------------------------------------ the seam (ops/dispatch.py)
 
 
@@ -421,6 +495,24 @@ def _case_decode(int8=False, window=None, c=1):
             lambda: decode_attention_lax(*args, **kw))
 
 
+def _case_ingest(int8=False):
+    from starway_tpu.ops import ingest_attention
+    from starway_tpu.ops.pallas_decode import (slot_attention,
+                                               slot_attention_lax)
+    from starway_tpu.ops.quantize import quantize_kv
+
+    L, R, Hq, Hkv, T, D = 2, 3, 8, 2, 256, 64
+    q, k, v = _rand(25, (1, Hq, 16, D), (L, R, Hkv, T, D), (L, R, Hkv, T, D))
+    kw = dict(layer=jnp.int32(1))
+    if int8:
+        (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+        kw.update(k_scale=ks, v_scale=vs)
+    args = (q, k, v, jnp.asarray([120], jnp.int32), jnp.asarray([2], jnp.int32))
+    return (lambda: ingest_attention(*args, **kw),
+            lambda: slot_attention(*args, interpret=True, **kw),
+            lambda: slot_attention_lax(*args, **kw))
+
+
 def _case_latent():
     from starway_tpu.ops import latent_attention
     from starway_tpu.ops.pallas_decode import (mla_decode_attention,
@@ -440,12 +532,18 @@ def _case_write(kind):
 
     L, R, T = 2, 3, 256
     tail, hkv, n, dtype = {"kv": ((64,), 2, 2, jnp.bfloat16),
+                           "counted": ((64,), 2, 2, jnp.bfloat16),
                            "scales": ((), 2, 2, jnp.float32),
                            "latent": ((128,), 1, 1, jnp.bfloat16)}[kind]
     caches = tuple(_rand(23, *[(L, R, hkv, T) + tail] * n, dtype=dtype))
     updates = tuple(_rand(24, *[(R, hkv, 1) + tail] * n, dtype=dtype))
     args = (caches, updates, jnp.int32(1), jnp.arange(R),
             jnp.asarray([0, 131, 255], jnp.int32))
+    if kind == "counted":  # a prompt piece: some of its positions are pads
+        args += (jnp.asarray([1, 0, 1], jnp.int32),)
+        return (lambda: cache_write(*args),
+                lambda: kv_write(*args[:5], count=args[5], interpret=True),
+                lambda: kv_write_lax(*args))
     return (lambda: cache_write(*args),
             lambda: kv_write(*args, interpret=True),
             lambda: kv_write_lax(*args))
@@ -537,8 +635,11 @@ _DISPATCH = {
     "decode_int8": lambda: _case_decode(int8=True),
     "decode_windowed": lambda: _case_decode(window=96),
     "decode_c4": lambda: _case_decode(c=4),
+    "ingest_bf16": _case_ingest,
+    "ingest_int8": lambda: _case_ingest(int8=True),
     "latent_decode": _case_latent,
     "write_kv": lambda: _case_write("kv"),
+    "write_counted": lambda: _case_write("counted"),
     "write_scales": lambda: _case_write("scales"),
     "write_latent": lambda: _case_write("latent"),
     "gmm_gated": lambda: _case_gmm(True),

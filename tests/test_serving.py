@@ -446,10 +446,10 @@ def _cancelled_while_queued(srv, cfg, params):
 
 def _cancelled_in_slot(srv, cfg, params):
     rid = srv.submit([4, 2, 8, 1], 20)
-    srv.step()                          # admitted, one chunk decoded
+    srv.step()      # ingested in the chunk's first step, decoded in two
     assert srv.cancel(rid)
     srv.run()
-    return rid, "cancelled", 1 + 3, True
+    return rid, "cancelled", 1 + 2, True
 
 
 @pytest.mark.parametrize("scenario", [
@@ -467,7 +467,8 @@ def test_request_log_stamps(cfg, params, scenario):
     if admitted:
         assert (row["t_submit"] <= row["t_admit0"] <= row["t_first"]
                 <= row["t_done"])
-        assert row["bucket"] == 32 and row["steps"] >= 1
+        # No admit program on the ingest path, so no bucket.
+        assert row["bucket"] == 0 and row["steps"] >= 1
     else:
         assert row["t_admit0"] is None and row["t_first"] is None
         assert row["t_submit"] <= row["t_done"] and row["steps"] == 0
@@ -543,15 +544,14 @@ def test_step_log_rows_add_up(cfg, params):
     assert sum(r["admits"] for r in steps) == 3
     assert steps[0]["queued"] == 1 and steps[0]["live"] == 2
     for r in steps:
-        # A step that admits queues its chunk behind the admissions before
-        # it fetches their tokens: dispatch_s then lies inside admit_s.
-        parts = (max(r["admit_s"], r["dispatch_s"]) + r["wait_s"]
-                 + r["harvest_s"])
+        # The dense server ingests its prompts inside the chunk: no lane
+        # ever stands still for an admission, and a step reads once.
+        parts = r["dispatch_s"] + r["wait_s"] + r["harvest_s"]
         assert 0.0 <= parts <= r["t1"] - r["t0"]
-        assert r["dispatch_s"] <= r["admit_s"] or not r["admits"]
-        assert r["fetches"] <= 2
+        assert r["admit_s"] == 0.0 and r["fetches"] == 1
         assert r["n_slots"] == 2 and 0 <= r["live"] <= 2
-        assert (r["admit_s"] > 0) == (r["admits"] > 0)
+        assert (r["ingest_width"] > 0) == (r["ingest_tokens"] > 0)
+    assert sum(r["ingest_tokens"] for r in steps) == 3 + 4 + 2
     assert all(a["t1"] <= b["t0"] for a, b in zip(steps, steps[1:]))
     # The server's own accumulator saw the same phases.
     snap = srv.stage_scope.snapshot()
@@ -727,6 +727,45 @@ def check_step_queues_then_fetches(srv, requests, k, monkeypatch):
     return rids, done
 
 
+def check_step_ingests_and_fetches_once(srv, requests, k, monkeypatch):
+    """The dense server's counterpart of
+    :func:`check_step_queues_then_fetches`: one ``step()`` that takes
+    ``k`` requests launches ONE program (the mixed chunk: no admit
+    program, no ``serve_seat``), reads the device once, and hands each
+    request its first token before its chunk tokens."""
+    from starway_tpu.models import serving
+
+    srv.submit(*requests[0])
+    srv.run()
+    events = []
+    srv.on_tokens = lambda rid, toks, done: events.append((rid, len(toks), done))
+    rids = [srv.submit(*requests[i % len(requests)]) for i in range(k)]
+    launched = []
+    monkeypatch.setattr(serving, "_seat", lambda *a: launched.append("seat"))
+    monkeypatch.setattr(serving, "_compiled_admit",
+                        lambda *a: launched.append("admit"))
+    launch = srv._launch_chunk
+    srv._launch_chunk = lambda sub: launched.append("chunk") or launch(sub)
+    srv.step()
+    monkeypatch.undo()
+    srv._launch_chunk = launch
+    row = [r for r in serving.step_log() if r["server"] == srv.server_id][-1]
+    assert launched == ["chunk"]
+    assert row["admits"] == k and row["fetches"] == 1 and row["admit_s"] == 0
+    asked = [requests[i % len(requests)] for i in range(k)]
+    # A piece a step, the longest budget first: the rest ride the next chunk.
+    order = sorted(range(k), key=lambda i: (-asked[i][1], i))[:srv.chunk]
+    assert row["ingest_tokens"] == sum(len(asked[i][0]) for i in order)
+    seated = [rids[i] for i in order]
+    for rid in seated:
+        mine = [n for r, n, _d in events if r == rid]
+        assert mine[0] == 1 and len(mine) <= 2
+    assert not [e for e in events if e[0] not in seated]
+    done = srv.run()
+    srv.on_tokens = None
+    return rids, done
+
+
 def _dense(cfg, params):
     srv = SlotServer(params, cfg, n_slots=4, max_len=64, chunk=3)
     return srv, [([5, 1, 7, 2, 9], 7, None), ([3, 8, 6], 5, None),
@@ -752,17 +791,21 @@ def _rolling(cfg, params):
 def test_a_step_queues_its_programs_and_fetches_twice(cfg, params, kind, k,
                                                       monkeypatch):
     srv, requests = kind(cfg, params)
-    rids, done = check_step_queues_then_fetches(srv, requests, k, monkeypatch)
+    check = (check_step_ingests_and_fetches_once if kind is _dense
+             else check_step_queues_then_fetches)
+    rids, done = check(srv, requests, k, monkeypatch)
     assert sorted(done) == sorted(rids)
     for i, rid in enumerate(rids):
         assert len(done[rid]) == requests[i % len(requests)][1]
 
 
-def check_first_token_endings(srv, oracle):
+def check_first_token_endings(srv, oracle, fetches=2):
     """A one-token request and one whose first token is its eos, admitted
     in one step beside two long requests: each ends in that very step with
     exactly one token and one done event after it, its slot is free again,
-    and the step still read the device twice.  ``srv``: 4 slots, eos unset;
+    and the step still read the device ``fetches`` times (twice behind
+    admit programs, once where the chunk ingests the prompts: its steps
+    must then hold all four).  ``srv``: 4 slots, eos unset;
     ``oracle(prompt, max_new, eos_id)``.  Shared by tests/test_paged.py."""
     from starway_tpu.models import serving
 
@@ -778,7 +821,7 @@ def check_first_token_endings(srv, oracle):
     _long_a, one, eos_first, _long_b = asked
     done = srv.step()
     row = [r for r in serving.step_log() if r["server"] == srv.server_id][-1]
-    assert row["admits"] == 4 and row["fetches"] == 2
+    assert row["admits"] == 4 and row["fetches"] == fetches
     assert sorted(done) == [one, eos_first]
     for rid in (one, eos_first):
         assert len(done[rid]) == 1
@@ -794,17 +837,21 @@ def check_first_token_endings(srv, oracle):
 
 
 def test_requests_ending_at_their_first_token_beside_long_ones(cfg, params):
-    srv = SlotServer(params, cfg, n_slots=4, max_len=64, chunk=3)
+    srv = SlotServer(params, cfg, n_slots=4, max_len=64, chunk=4)
     check_first_token_endings(
-        srv, lambda p, n, eos: _oracle(params, cfg, p, n, eos_id=eos))
+        srv, lambda p, n, eos: _oracle(params, cfg, p, n, eos_id=eos),
+        fetches=1)
 
 
 def test_only_one_token_requests_need_no_chunk(cfg, params):
     """A step whose every occupied slot holds a request the host knows to
-    end at its first token launches no chunk: one fetch, no key split."""
+    end at its first token launches no chunk: one fetch, no key split.
+    (On a server whose prompts come in by admit programs, here the rolling
+    one; the dense server's chunk is what ingests a prompt.)"""
     from starway_tpu.models import serving
 
-    srv = SlotServer(params, cfg, n_slots=2, max_len=64, chunk=3)
+    srv = SlotServer(params, LlamaConfig.preset("debug", sliding_window=8),
+                     n_slots=2, max_len=64, chunk=3)
     rids = [srv.submit([3, 8, 6], 1), srv.submit([4, 2], 1)]
     key = srv.key
     done = srv.step()
@@ -816,3 +863,282 @@ def test_only_one_token_requests_need_no_chunk(cfg, params):
     want = jax.random.split(jax.random.split(key)[0])[0]
     np.testing.assert_array_equal(jax.random.key_data(srv.key),
                                   jax.random.key_data(want))
+
+
+# ------------------------------------------- prompts ride the decode chunk
+#
+# The dense server (k/v leaves as computed, no int8 scales, no window)
+# launches no admit program: a prompt comes in piece by piece inside the decode chunk
+# (serving._compiled_ingest_chunk), at the smallest of INGEST_WIDTHS that
+# brings what waits in within a chunk (all of it; the longest prompt while
+# requests queue for slots).  Small widths here; the constant's own below.
+
+
+def _ingest_server(monkeypatch, params, cfg, widths=(8,), **kw):
+    from starway_tpu.models import serving
+
+    monkeypatch.setattr(serving, "INGEST_WIDTHS", tuple(widths))
+    kw = {"n_slots": 2, "max_len": 64, "chunk": 3, **kw}
+    srv = SlotServer(params, cfg, **kw)
+    assert srv._widths == tuple(widths)[:len(srv._widths)] and srv._widths
+    return srv
+
+
+def _prompt(n, seed=0, vocab=512):
+    return [int(t) for t in
+            np.random.default_rng([seed, n]).integers(1, vocab, n)]
+
+
+def _served_as_generate(srv, params, cfg, reqs, oracle=None):
+    oracle = oracle or (lambda p, n: _oracle(params, cfg, p, n,
+                                             eos_id=srv.eos_id))
+    rids = [srv.submit(p, n) for p, n in reqs]
+    done = srv.run()
+    assert sorted(done) == sorted(rids)
+    for rid, (p, n) in zip(rids, reqs):
+        np.testing.assert_array_equal(
+            done[rid], oracle(p, n), err_msg=f"request {rid} (P={len(p)})")
+
+
+def _ingest_length(n):
+    """One prompt of ``n`` tokens at width 8, chunk 3, beside a short one."""
+    def case(monkeypatch, params, cfg):
+        srv = _ingest_server(monkeypatch, params, cfg)
+        _served_as_generate(srv, params, cfg, [(_prompt(n), 6),
+                                               (_prompt(3, 1), 9)])
+    case.__name__ = f"length_{n}"
+    return case
+
+
+def _ingest_arrivals(monkeypatch, params, cfg):
+    """Requests arriving while other slots decode, more than there are
+    slots, widths chosen by the backlog (4, 8 and 16 all in play)."""
+    srv = _ingest_server(monkeypatch, params, cfg, widths=(4, 8, 16),
+                         n_slots=3, chunk=4)
+    reqs = [(_prompt(n, 2), m) for n, m in
+            [(5, 12), (19, 4), (2, 7), (33, 9), (8, 3), (13, 11), (40, 5)]]
+    rids, done = [], {}
+    for p, m in reqs:
+        rids.append(srv.submit(p, m))
+        done.update(srv.step())      # the next arrives one chunk later
+    done.update(srv.run())
+    for rid, (p, m) in zip(rids, reqs):
+        np.testing.assert_array_equal(done[rid], _oracle(params, cfg, p, m))
+    from starway_tpu.models import serving
+
+    used = {r["ingest_width"] for r in serving.step_log()
+            if r["server"] == srv.server_id}
+    assert {4, 8, 16} <= used
+
+
+def _ingest_width_follows_the_queue(queued):
+    """Two prompts of 3 pieces each at width 4, chunk 4: with no request
+    waiting for a slot the chunk takes width 8, at which both come in
+    within it; with one waiting, width 4, at which the longer one does."""
+    def case(monkeypatch, params, cfg):
+        from starway_tpu.models import serving
+
+        srv = _ingest_server(monkeypatch, params, cfg, widths=(4, 8),
+                             chunk=4)
+        reqs = [(_prompt(11, 7), 5), (_prompt(10, 8), 5)]
+        reqs += [(_prompt(2, 9), 4)] * queued
+        _served_as_generate(srv, params, cfg, reqs)
+        first = next(r for r in serving.step_log()
+                     if r["server"] == srv.server_id)
+        assert first["queued"] == queued
+        assert first["ingest_width"] == (4 if queued else 8)
+        assert first["ingest_tokens"] == (15 if queued else 21)
+    case.__name__ = f"width_with_{queued}_queued"
+    return case
+
+
+def _ingest_int8(monkeypatch, params, cfg):
+    """An int8 cache is NOT ingested: a piece would attend over the
+    cache's quantized entries where ``generate()``'s prefill reads the
+    prompt's k/v exact, so that kind keeps its admit programs, and
+    ``generate()``'s tokens exactly."""
+    cfg8 = LlamaConfig.preset("debug", kv_quant="int8")
+    srv = SlotServer(params, cfg8, n_slots=2, max_len=64, chunk=3)
+    assert srv._widths == ()
+    _served_as_generate(srv, params, cfg8,
+                        [(_prompt(21, 4), 6), (_prompt(9, 5), 5),
+                         (_prompt(3, 6), 8)])
+
+
+def _ingest_one_token(monkeypatch, params, cfg):
+    """``max_new_tokens == 1``: seated dead, one token, its slot free."""
+    srv = _ingest_server(monkeypatch, params, cfg)
+    _served_as_generate(srv, params, cfg, [(_prompt(11), 1), (_prompt(4), 6),
+                                           (_prompt(17), 1)])
+    assert not srv.busy
+
+
+def _ingest_eos_first(monkeypatch, params, cfg):
+    """eos as the first token: the request ends where it is seated."""
+    prompt = _prompt(13)
+    srv = _ingest_server(monkeypatch, params, cfg)
+    srv.eos_id = int(_oracle(params, cfg, prompt, 1)[0])
+    events = []
+    srv.on_tokens = lambda rid, toks, done: events.append(
+        (rid, list(toks), done))
+    rid = srv.submit(prompt, 8)
+    other = srv.submit(_prompt(5, 1), 7)
+    done = srv.run()
+    assert list(done[rid]) == [srv.eos_id]
+    assert [e[1:] for e in events if e[0] == rid] == [([srv.eos_id], False),
+                                                      ([], True)]
+    np.testing.assert_array_equal(
+        done[other], _oracle(params, cfg, _prompt(5, 1), 7, eos_id=srv.eos_id))
+
+
+def _ingest_cancel_half_way(monkeypatch, params, cfg):
+    """``cancel()`` of a request half ingested (40 tokens, 24 a chunk):
+    nothing is delivered, its slot is free at the next step, and the
+    request that takes the slot gets its own tokens (the prompt half
+    written there is overwritten before anything reads it)."""
+    srv = _ingest_server(monkeypatch, params, cfg, n_slots=1)
+    events = []
+    srv.on_tokens = lambda rid, toks, done: events.append(rid)
+    victim = srv.submit(_prompt(40), 6)
+    srv.step()
+    assert srv._ingest and not events       # 24 of its 40 tokens are in
+    assert srv.cancel(victim) and not srv._ingest and not srv._slot_rid
+    nxt = srv.submit(_prompt(7, 3), 9)
+    done = srv.run()
+    assert sorted(done) == [nxt] and victim not in events
+    np.testing.assert_array_equal(done[nxt],
+                                  _oracle(params, cfg, _prompt(7, 3), 9))
+
+
+def _ingest_kernels(monkeypatch, params, cfg):
+    """The whole mixed chunk on the kernels' side (interpreted):
+    ``sw_kv_write`` with a count and ``sw_ingest_attn`` through a row
+    index, a 125-token prompt on a 128-position cache."""
+    from starway_tpu.models import serving
+
+    monkeypatch.setattr("starway_tpu.ops.dispatch.use_kernels", lambda: True)
+    srv = _ingest_server(monkeypatch, params, cfg, max_len=128)
+    reqs = [(_prompt(3, 7), 6), (_prompt(21, 7), 4), (_prompt(125, 7), 2)]
+    rids = [srv.submit(p, n) for p, n in reqs]
+    try:
+        done = srv.run()
+    finally:  # programs traced on this side must not outlive the case
+        serving._compiled_ingest_chunk.cache_clear()
+        serving._compiled_chunk.cache_clear()
+    monkeypatch.undo()
+    for rid, (p, n) in zip(rids, reqs):
+        np.testing.assert_array_equal(done[rid], _oracle(params, cfg, p, n))
+
+
+def _ingest_width_of_the_constant(width):
+    """The constant's own widths (128, 256) on a 520-position cache, chunk
+    2: a prompt that needs exactly this width to come in within one chunk,
+    so the plan picks it; one of 515 tokens comes in at 256 and, its last
+    3 tokens, at 128, a piece whose pads reach past the cache's end."""
+    def case(monkeypatch, params, cfg):
+        from starway_tpu.models import serving
+
+        assert serving.INGEST_WIDTHS == (128, 256)
+        srv = SlotServer(params, cfg, n_slots=2, max_len=520, chunk=2)
+        assert srv._widths == serving.INGEST_WIDTHS
+        n = {128: 200, 256: 400}[width]
+        _served_as_generate(srv, params, cfg, [
+            (_prompt(n), 4), (_prompt(3, 1), 5), (_prompt(515, 2), 3)])
+        used = [r["ingest_width"] for r in serving.step_log()
+                if r["server"] == srv.server_id and r["ingest_width"]]
+        assert used[0] == width and used[-2:] == [256, 128]
+    case.__name__ = f"width_{width}"
+    return case
+
+
+@pytest.mark.parametrize("case", [
+    *(_ingest_length(n) for n in (1, 7, 8, 9, 3 * 8 + 5, 40)),
+    _ingest_arrivals, _ingest_int8, _ingest_one_token, _ingest_eos_first,
+    _ingest_cancel_half_way, _ingest_kernels,
+    *(_ingest_width_of_the_constant(w) for w in (128, 256)),
+    *(_ingest_width_follows_the_queue(q) for q in (0, 1))],
+    ids=lambda f: f.__name__.lstrip("_"))
+def test_ingest_path_returns_generates_tokens(cfg, params, monkeypatch, case):
+    """A server on the ingest path returns ``generate()``'s tokens exactly
+    (float32, greedy): prompt lengths 1, W - 1, W, W + 1, 3W + 5 and one
+    longer than ``chunk x W`` (W = 8, chunk 3); arrivals while other slots
+    decode; an int8 cache (which keeps its admit programs for the sake of
+    exactly this); one-token requests; eos as the first token; a request
+    cancelled half ingested; the kernels' side of the program
+    (interpreted); each width of the constant."""
+    case(monkeypatch, params, cfg)
+
+
+def _kind_dense(cfg, params):
+    return SlotServer(params, cfg, n_slots=2, max_len=64, chunk=4), None, 0
+
+
+def _kind_dense_int8(cfg, params):
+    return SlotServer(params, LlamaConfig.preset("debug", kv_quant="int8"),
+                      n_slots=2, max_len=64, chunk=4), None, 3
+
+
+def _kind_prefix(cfg, params):
+    srv = SlotServer(params, cfg, n_slots=2, max_len=64, chunk=4)
+    return srv, srv.register_prefix([7, 3, 9, 1, 4, 4, 2]), 3
+
+
+def _kind_rolling(cfg, params):
+    rcfg = LlamaConfig.preset("debug", sliding_window=8)
+    return SlotServer(params, rcfg, n_slots=2, max_len=64, chunk=4), None, 3
+
+
+def _kind_paged(cfg, params):
+    from starway_tpu.models import PagedSlotServer
+
+    return PagedSlotServer(params, cfg, n_slots=2, max_len=64, page=16,
+                           chunk=4), None, 3
+
+
+def _kind_latent(cfg, params):
+    from benchmark.harness import spec as S
+    from tests import test_mla_moe as latent
+
+    lparams, lcfg = latent._model(S.load_runner("serve_mla_moe"))
+    assert "ckv" in SlotServer(lparams, lcfg, n_slots=1, max_len=64).cache
+    return SlotServer(lparams, lcfg, n_slots=2, max_len=64, chunk=4), None, 3
+
+
+@pytest.mark.parametrize("kind", [
+    _kind_dense, _kind_dense_int8, _kind_prefix, _kind_rolling, _kind_paged,
+    _kind_latent], ids=lambda f: f.__name__[6:])
+def test_which_requests_ingest_and_which_keep_admit_programs(cfg, params,
+                                                             kind,
+                                                             monkeypatch):
+    """``step_log()`` pinned for both paths, chosen from the cache kind
+    alone.  Dense k/v as computed: every step ``fetches == 1`` and
+    ``admit_s == 0``, no ``serve_seat`` runs, and ``ingest_tokens`` sums to
+    the prompts' lengths.  A ``prefix=`` request (on that same dense
+    server), an int8 cache, a rolling window, the page pool and a latent
+    cache still launch their admit programs (``serve_seat`` behind each, ``admit_s >
+    0`` in a step that admits) and ingest nothing."""
+    from starway_tpu.models import serving
+
+    srv, prefix, admitted = kind(cfg, params)
+    seats = []
+    seat = serving._seat
+    monkeypatch.setattr(serving, "_seat",
+                        lambda *a: seats.append(1) or seat(*a))
+    prompts = [[5, 1, 7, 2, 9], [3, 8, 6], [4, 2, 8, 1, 6, 6, 3]]
+    for p in prompts:
+        srv.submit(p, 5, prefix)
+    done = srv.run()
+    assert len(done) == 3 and all(len(t) == 5 for t in done.values())
+    steps = [r for r in serving.step_log() if r["server"] == srv.server_id]
+    assert sum(r["admits"] for r in steps) == 3 and len(seats) == admitted
+    if admitted:
+        assert srv._widths == () or prefix is not None
+        assert all((r["admit_s"] > 0) == (r["admits"] > 0) for r in steps)
+        assert all(r["ingest_tokens"] == r["ingest_rows"]
+                   == r["ingest_width"] == 0 for r in steps)
+        assert max(r["fetches"] for r in steps) == 2
+    else:
+        assert srv._widths == (128,)      # the first that holds a prompt
+        assert all(r["fetches"] == 1 and r["admit_s"] == 0.0 for r in steps)
+        assert sum(r["ingest_tokens"] for r in steps) == sum(map(len, prompts))
+        assert sum(r["ingest_rows"] for r in steps) == 3 * 128
