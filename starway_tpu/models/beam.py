@@ -125,7 +125,7 @@ def generate_beam(params: dict, cfg: LlamaConfig, prompt,
         raise ValueError(f"beams must be >= 1, got {beams}")
     if beams > cfg.vocab_size:
         raise ValueError(f"beams={beams} exceeds the vocab ({cfg.vocab_size})")
-    if cfg.sliding_window is not None:
+    if cfg.sliding_window is not None or cfg.kinds is not None:
         raise ValueError("beam search needs full caches; rolling-cache "
                          "support is not wired")
     total = P + max_new_tokens

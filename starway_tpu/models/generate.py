@@ -13,6 +13,15 @@ generation (donate the cache under jit).  On the chip the new entries are
 written in place by ``ops.cache_write`` and attention reads
 the stacked array through a layer index; the decode step moves no cache
 bytes but the ones attention reads.
+
+A RING is a cache of exactly one window's positions, written at ``pos %
+window`` and attended whole (``_write_cached`` / ``attend_cache`` with
+``ring=True``: the one implementation).  A model whose every layer has
+the window (``cfg.sliding_window``) may keep all its layers so
+(``init_rolling_cache``, ``decode_step(rolling=True)``); a model whose
+layers differ (``cfg.kinds``) keeps its window layers' rings under
+``k_ring`` / ``v_ring`` BESIDE its full layers' rows under ``k`` / ``v``,
+each stacked over the layers of its own kind (``init_cache``).
 """
 
 from __future__ import annotations
@@ -26,7 +35,7 @@ from jax import lax
 
 from .llama import (LlamaConfig, apply_rope, cfg_rope_tables, embed_tokens,
                     ffn_block, forward, layer_segments, matmul_w, qkv_proj,
-                    rmsnorm, scan_segment)
+                    rmsnorm, scan_segment, segment_kind)
 from ..ops import (cache_write, cached_attention, ingest_attention,
                    latent_attention)
 from ..ops.attention import NEG_BIG, repeat_kv
@@ -45,12 +54,24 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
     + rope_dim`` values in whole lane tiles), the same five axes with a
     single "head", so slot writes, padding and ``kv_write`` treat it as
     they treat ``k``.
+
+    Layers of different kinds (``cfg.kinds``) keep TWO kinds of leaves:
+    ``k`` / ``v [full layers, B, Hkv, max_len, head_dim]`` and the window
+    layers' rings ``k_ring`` / ``v_ring [window layers, B, Hkv, window,
+    head_dim]``, each stacked over its own layers in model order.
     """
     if cfg.latent is not None:
         return {"ckv": jnp.zeros(
             (cfg.n_layers, batch, 1, max_len, cfg.latent.cache_width),
             cfg.compute_dtype)}
     hd = cfg.head_dim
+    if cfg.kinds is not None:
+        rings = cfg.window_layers(cfg.n_layers)
+        shapes = {"": (cfg.n_layers - rings, max_len),
+                  "_ring": (rings, cfg.kinds.window)}
+        return {name + kind: jnp.zeros((n, batch, cfg.n_kv_heads, t, hd),
+                                       cfg.compute_dtype)
+                for kind, (n, t) in shapes.items() for name in ("k", "v")}
     shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, hd)
     if cfg.kv_quant == "int8":
         return {
@@ -75,22 +96,57 @@ def init_rolling_cache(cfg: LlamaConfig, batch: int) -> dict:
 
 
 def cache_len(cache: dict) -> int:
-    """Positions a cache holds: the T axis sits at index 3 of every leaf."""
-    return next(iter(cache.values())).shape[3]
+    """Positions a cache's rows hold: the T axis sits at index 3 of every
+    leaf (the ring leaves of a ``cfg.kinds`` cache hold a window's; this
+    is the full layers' length)."""
+    return (cache["k"] if "k" in cache else
+            next(iter(cache.values()))).shape[3]
 
 
-def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig):
-    """``attend`` of :func:`cached_layer_scan` over a whole-length cache of
-    either kind, queries ``q [B, Hq, C, D]`` at ``pos[b] ..`` (C=1 is
+def ring_fold(a, lengths, window: int):
+    """A ring out of whole rows: ``a [L, B, Hkv, S(, D)]`` holds positions
+    ``0 .. S - 1`` of which row b's first ``lengths[b]`` are real; returns
+    ``[L, B, Hkv, window(, D)]`` whose slot ``s`` holds row b's LATEST real
+    position ``p`` with ``p % window == s``: the layout ``pos % window``
+    writes leave behind.  Slots no real position reached yet hold junk
+    that the decode steps overwrite before the cursor lets them be read."""
+    last = jnp.asarray(lengths, jnp.int32).reshape(-1, 1) - 1       # [B, 1]
+    src = last - (last - jnp.arange(window, dtype=jnp.int32)[None, :]) % window
+    src = jnp.clip(src, 0, a.shape[3] - 1)                          # [B, W]
+    return jnp.take_along_axis(
+        a, src.reshape((1, -1, 1, window) + (1,) * (a.ndim - 4)), axis=3)
+
+
+def _ring_names(cache: dict) -> dict:
+    """The leaves that hold rings, by the plain name of each: a cache with
+    ``*_ring`` leaves keeps them there; else every leaf is one (a
+    whole-model rolling cache, ``init_rolling_cache``)."""
+    if "k_ring" in cache:
+        return {"k": "k_ring", "v": "v_ring"}
+    return {name: name for name in cache}
+
+
+def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig,
+                 ring: bool = False):
+    """``attend`` of :func:`cached_layer_scan` over a cache of either kind,
+    queries ``q [B, Hq, C, D]`` at ``pos[b] ..`` (C=1 is
     single-token decode; C>1 the speculative chunk verify, whose entries
     are already written: write-then-attend): grouped k/v
     (``ops.cached_attention``, windowed and int8-aware), or the latent
     rows of ``cfg.latent`` (absorbed queries in, ``P c_kv`` out;
-    ``ops.latent_attention``)."""
+    ``ops.latent_attention``).  ``ring``: the layer's entries lie in a
+    ring (written at ``pos % window``): its warm slots ARE the window, so
+    every slot up to the clamped cursor is attended and nothing is masked
+    again; cold slots (> pos) are masked by the clamped position."""
     if "ckv" in cache:
         return latent_attention(q, cache["ckv"], pos,
                                 rank=cfg.latent.kv_rank,
                                 sm_scale=cfg.latent.sm_scale, layer=layer)
+    if ring:
+        at = _ring_names(cache)
+        scales = {n: cache[at[n]] for n in ("k_scale", "v_scale") if n in at}
+        return cached_attention(q, cache[at["k"]], cache[at["v"]], pos,
+                                layer=layer, ring=True, **scales)
     return cached_attention(q, cache["k"], cache["v"], pos, layer=layer,
                             window=cfg.sliding_window,
                             k_scale=cache.get("k_scale"),
@@ -98,7 +154,7 @@ def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig):
 
 
 def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
-                  count=None) -> dict:
+                  count=None, ring: bool = False) -> dict:
     """The C new positions of one layer into the stacked cache, every leaf
     (k, v and, int8, their scales): ``cache[name][layer, rows[b], :,
     pos[b] + c] = new[name][b, :, c]``.  ``new[name]``: [B, Hkv, C(, D)];
@@ -107,7 +163,9 @@ def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
     ``pos`` the offsets inside them.  A start above ``T - C`` is clamped,
     as ``lax.dynamic_update_slice`` does; with ``count`` ([B]) only each
     row's first ``count[b]`` positions are written and nothing is clamped
-    (``ops.cache_write``).
+    (``ops.cache_write``).  ``ring`` (C = 1): the layer's entries lie in
+    a ring and ``pos`` is the ABSOLUTE position: the write goes to ``pos
+    % T`` of the ring leaves, ``layer`` counting them.
 
     The write itself is ``ops.cache_write``: on the chip in place, a tile
     a row.  Either XLA form (a scatter, or ``dynamic_update_slice`` per
@@ -118,8 +176,15 @@ def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
     rows = jnp.arange(B) if rows is None else rows
     layer = jnp.asarray(layer, jnp.int32)
     out = dict(cache)
-    groups = ([("ckv",)] if "ckv" in cache else
-              [("k", "v")] + [("k_scale", "v_scale")] * ("k_scale" in cache))
+    if ring:
+        at = _ring_names(cache)
+        pos = lax.rem(pos, cache[at["k"]].shape[3])
+        groups = [(at["k"], at["v"])] + (
+            [(at["k_scale"], at["v_scale"])] if "k_scale" in at else [])
+        new = {at[name]: x for name, x in new.items()}
+    else:
+        groups = ([("ckv",)] if "ckv" in cache else [("k", "v")] + [
+            ("k_scale", "v_scale")] * ("k_scale" in cache))
     for names in groups:  # same-shaped leaves share one kernel call
         out.update(zip(names, cache_write(
             tuple(cache[name] for name in names),
@@ -142,12 +207,15 @@ def decode_step_counted(params: dict, cache: dict, token, pos,
     row sits at its own cursor).  Returns (logits [B, V], updated cache,
     the third value of :func:`cached_layer_scan`).
 
-    ``rolling``: the cache is a circular window of exactly
+    ``rolling``: EVERY layer's cache is a ring of exactly
     ``cfg.sliding_window`` slots (``init_rolling_cache``) — writes go to
     ``pos % window``, and attention covers every warm slot with no window
     re-mask (the residents ARE the window; keys carry their absolute RoPE,
     and attention is permutation-invariant over keys, so slot order never
-    matters).  Cache memory is O(window) for any generation length."""
+    matters).  Cache memory is O(window) for any generation length.  A
+    ``cfg.kinds`` model's window layers keep such rings beside its full
+    layers' rows (``init_cache``) and :func:`cached_layer_scan` says which
+    a layer has: the same write and the same attention."""
     T = cache_len(cache)
     if rolling:
         if cfg.sliding_window is None or T != cfg.sliding_window:
@@ -163,7 +231,6 @@ def decode_step_counted(params: dict, cache: dict, token, pos,
     cos, sin = rope
     pos = jnp.asarray(pos, jnp.int32)
     per_row = pos.ndim == 1
-    slot = jax.lax.rem(pos, T) if rolling else pos
     if per_row:
         # [B, 1, 1, hd/2]: one rotation angle per row, broadcast over heads.
         cos_p = cos[pos][:, None, None, :]
@@ -174,19 +241,11 @@ def decode_step_counted(params: dict, cache: dict, token, pos,
 
     h = embed_tokens(params, token, cfg)[:, None, :]  # [B, 1, D]
 
-    def write(cache, new, layer):
-        return _write_cached(cache, new, layer, slot)
+    def write(cache, new, layer, ring=rolling):
+        return _write_cached(cache, new, layer, pos, ring=ring)
 
-    def attend(q, cache, layer):
-        if rolling:
-            # Warm slots are exactly the window (we just overwrote the
-            # oldest); cold-start slots (> pos) are masked by the clamped
-            # position.  No window re-mask: absolute order is irrelevant.
-            return cached_attention(q, cache["k"], cache["v"],
-                                    jnp.minimum(pos, T - 1), layer=layer,
-                                    k_scale=cache.get("k_scale"),
-                                    v_scale=cache.get("v_scale"))
-        return attend_cache(q, cache, pos, layer, cfg)
+    def attend(q, cache, layer, ring=rolling):
+        return attend_cache(q, cache, pos, layer, cfg, ring=ring)
 
     h, out, counts = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg,
                                        write, attend)
@@ -290,15 +349,22 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
     caller's cursor(s) of layer ``layer`` (:func:`_write_cached`);
     ``attend(q, cache, layer)`` returns [B, Hq, C, hd] (latent: absorbed
     queries in, ``P c_kv`` [B, H, C, kv_rank] out; write-then-attend: it
-    sees the entries just written; :func:`attend_cache`).  Returns ``(h
-    [B, C, D], cache, counts)``: the pairs each held expert of each routed
-    layer got, ``[routed layers, n_held]`` int32 (None for a model with no
-    routed layer).
+    sees the entries just written; :func:`attend_cache`).  A cache of TWO
+    kinds of leaves (a ``cfg.kinds`` model's: full rows under ``k`` /
+    ``v``, the window layers' rings under ``k_ring`` / ``v_ring``, each
+    stacked over its own layers) rides the carry whole; a window layer's
+    hooks are called with ``ring=True`` and ``layer`` counting the ring
+    leaves' layers, a full layer's as ever with ``layer`` counting the
+    full ones (a caller whose hooks take no ``ring`` serves no such
+    model).  Returns ``(h [B, C, D], cache, counts)``: the pairs each held
+    expert of each routed layer got, ``[routed layers, n_held]`` int32
+    (None for a model with no routed layer).
     """
     B, C = h.shape[0], h.shape[1]
     quant = "k_scale" in cache  # int8 cache (init_cache's format marker)
 
-    def layer(carry, lp, li):
+    def layer(carry, lp, li, *, rope=True, ring=False):
+        kind = {"ring": True} if ring else {}
         h, cache = carry
         x = rmsnorm(h, lp["attn_norm"], cfg.norm_eps)
         if "wq_a" in lp:
@@ -309,8 +375,9 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
             o = expand_values(attend(q, cache, li), lp, cfg)
         else:
             q, k, v = qkv_proj(x, lp, cfg)
-            q = apply_rope(q, cos_p, sin_p)
-            k = apply_rope(k, cos_p, sin_p)
+            if rope:
+                q = apply_rope(q, cos_p, sin_p)
+                k = apply_rope(k, cos_p, sin_p)
             new = {"k": k, "v": v}
             if quant:
                 from ..ops.quantize import quantize_kv
@@ -318,19 +385,26 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
                 # Quantize-on-write: the cache never holds a wide entry.
                 new["k"], new["k_scale"] = quantize_kv(k)
                 new["v"], new["v_scale"] = quantize_kv(v)
-            cache = write(cache, new, li)
-            o = attend(q, cache, li)
+            cache = write(cache, new, li, **kind)
+            o = attend(q, cache, li, **kind)
         o = o.transpose(0, 2, 1, 3).reshape(B, C, -1)
         h = h + matmul_w(o, lp["wo"])
         y, _aux, stats = ffn_block(rmsnorm(h, lp["mlp_norm"], cfg.norm_eps),
-                                   lp, cfg)
+                                   lp, cfg, attn_in=x)
         return (h + y, cache), (stats if "routed" in lp else None)
 
     carry, counts = (h, dict(cache)), []
     for seg, first in layer_segments(params["layers"]):
         n = jax.tree_util.tree_leaves(seg)[0].shape[0]
+        body = layer
+        if cfg.kinds is not None:
+            # The layers of this segment among those of their cache kind.
+            window, rope = segment_kind(cfg, seg, first)
+            before = cfg.window_layers(first)
+            first = before if window is not None else first - before
+            body = functools.partial(layer, rope=rope, ring=window is not None)
         carry, ys = scan_segment(
-            layer, carry, seg, first + jnp.arange(n, dtype=jnp.int32))
+            body, carry, seg, first + jnp.arange(n, dtype=jnp.int32))
         if ys is not None:
             counts.append(ys)
     return (*carry, jnp.concatenate(counts) if counts else None)
@@ -351,6 +425,11 @@ def prefill(params: dict, cfg: LlamaConfig, prompt,
     ``logit_positions`` ([B] ints, ragged right-padded batches): the
     returned logits come from each row's own position instead of the last
     column (no [B, P, V] tensor is built either way).
+
+    A ``cfg.kinds`` model's window layers come back as RINGS
+    (:func:`ring_fold`: each row's last ``window`` real positions at their
+    residues, a row's length being ``logit_positions + 1``, else P), its
+    full layers padded to ``max_len``: :func:`init_cache`'s two kinds.
     """
     B, P = prompt.shape
     if max_len is None:
@@ -367,15 +446,21 @@ def prefill(params: dict, cfg: LlamaConfig, prompt,
 
         cache["k"], cache["k_scale"] = quantize_kv(kv["k"])
         cache["v"], cache["v_scale"] = quantize_kv(kv["v"])
+    rings = {}
+    if cfg.kinds is not None:  # the window layers' whole rows -> rings
+        lengths = (jnp.full((B,), P, jnp.int32) if logit_positions is None
+                   else logit_positions + 1)
+        rings = {name: ring_fold(cache.pop(name), lengths, cfg.kinds.window)
+                 for name in [n for n in cache if n.endswith("_ring")]}
     pad = max_len - P
     if pad:
         # Every leaf's T axis sits at index 3 (the scale arrays only drop
-        # the trailing D dim) — same invariant the rolling gather relies on.
+        # the trailing D dim) — same invariant the ring fold relies on.
         cache = jax.tree_util.tree_map(
             lambda a: jnp.pad(
                 a, ((0, 0),) * 3 + ((0, pad),) + ((0, 0),) * (a.ndim - 4)),
             cache)
-    return logits[:, 0], cache
+    return logits[:, 0], {**cache, **rings}
 
 
 def prefill_rolling(params: dict, cfg: LlamaConfig, prompt, *,
@@ -620,18 +705,10 @@ def _compiled_generate(cfg: LlamaConfig, B: int, P: int, max_new: int,
 
     def run(params, prompt, key, lengths):
         if rolling:
-            if P <= W:
-                # prefill's own padding already yields the rolling layout
-                # (slot p % W == p while p < W).
-                logits, cache = prefill(params, cfg, prompt, W)
-            else:
-                logits, cache = prefill(params, cfg, prompt, P)  # unpadded
-                # Keep the last W positions, each at its slot p % W.  The T
-                # axis sits at index 3 for every cache leaf (k/v AND the
-                # int8 format's scale arrays, which only drop trailing D).
-                src = (P - W) + ((jnp.arange(W) - (P - W)) % W)
-                cache = jax.tree_util.tree_map(
-                    lambda a: jnp.take(a, src, axis=3), cache)
+            # Every layer's last W positions, each at its slot p % W.
+            logits, cache = prefill(params, cfg, prompt, P)  # unpadded
+            cache = jax.tree_util.tree_map(
+                lambda a: ring_fold(a, jnp.full((B,), P), W), cache)
             pos0 = jnp.asarray(P, jnp.int32)
         elif ragged:
             # Right-padded prompts: causal attention already confines every

@@ -60,13 +60,23 @@ class LatentAttn:
 
 @dataclasses.dataclass(frozen=True)
 class RoutedFFN:
-    """FFN kind: sigmoid-routed, dropless experts beside a shared expert
+    """FFN kind: routed, dropless experts beside ``n_shared`` shared ones
     (models/moe.py::routed_ffn).  The router is ``n_experts`` wide and
     every token takes ``top_k``; THIS holder computes experts
     ``first_held .. first_held + n_held - 1`` (one chip's share of an
     expert-parallel deployment; all of them when ``n_held ==
     n_experts``).  The first ``first_dense`` layers are dense SwiGLU of
-    width ``d_ff`` and form a segment of their own."""
+    width ``d_ff`` and form a segment of their own.
+
+    ``score``: ``"sigmoid"`` (DeepSeek-V3: sigmoid scores, the selection
+    corrected by the ``bias`` leaf, gates the chosen scores over their
+    sum) or ``"softmax"`` (the ``top_k`` largest logits, gates their
+    softmax; no ``bias`` leaf); either times ``scale``.  ``act``: the
+    experts' gate activation (``"silu"``: SwiGLU; ``"relu"``: ReGLU).
+    ``router_in``: which normed state the router scores, the FFN's own
+    input (``"mlp_norm"``) or the block's input as attention sees it
+    (``"attn_norm"``: experts are chosen BEFORE attention and applied
+    after it)."""
     n_experts: int
     top_k: int
     d_expert: int
@@ -75,6 +85,50 @@ class RoutedFFN:
     n_shared: int = 1
     scale: float = 1.0
     first_dense: int = 0
+    score: str = "sigmoid"
+    act: str = "silu"
+    router_in: str = "mlp_norm"
+
+    def __post_init__(self):
+        for field, allowed in (("score", ("sigmoid", "softmax")),
+                               ("act", ("silu", "relu")),
+                               ("router_in", ("mlp_norm", "attn_norm"))):
+            if getattr(self, field) not in allowed:
+                raise ValueError(f"RoutedFFN.{field} must be one of "
+                                 f"{allowed}, got {getattr(self, field)!r}")
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKinds:
+    """Attention kinds that differ by layer, on a period: layer ``i`` has
+    the window ``windows[i % period]`` (None: full causal attention) and
+    rotates q and k where ``rope[i % period]`` (False: no positional
+    encoding, NoPE).  Layers of one kind in a row are one SEGMENT of the
+    stacked tree (``layer_segments``).  The windows of a model are of one
+    length: its cache then holds the full layers' rows of ``max_len``
+    beside the window layers' RINGS of that length
+    (models/generate.py::init_cache).  A model whose every layer has the
+    same window is ``LlamaConfig.sliding_window``'s, not this group's."""
+    windows: tuple
+    rope: tuple
+
+    def __post_init__(self):
+        object.__setattr__(self, "windows", tuple(self.windows))
+        object.__setattr__(self, "rope", tuple(bool(r) for r in self.rope))
+        if not self.windows or len(self.windows) != len(self.rope):
+            raise ValueError(
+                f"LayerKinds needs one window (or None) and one rope flag a "
+                f"layer of the period, got {self.windows} / {self.rope}")
+        sizes = {w for w in self.windows if w is not None}
+        if len(sizes) != 1 or min(sizes) < 1 or None not in self.windows:
+            raise ValueError(
+                f"LayerKinds needs full layers (None) beside window layers "
+                f"of ONE length >= 1, got {self.windows}")
+
+    @property
+    def window(self) -> int:
+        """The window layers' one length: the ring's."""
+        return next(w for w in self.windows if w is not None)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,8 +209,16 @@ class LlamaConfig:
     # its leaves, what it needs beyond them from these.
     latent: Optional[LatentAttn] = None
     routed: Optional[RoutedFFN] = None
+    kinds: Optional[LayerKinds] = None
 
     def __post_init__(self):
+        if self.kinds is not None and (
+                self.sliding_window is not None or self.latent is not None
+                or self.kv_quant != "none"):
+            raise ValueError(
+                "kinds (window and RoPE by layer) goes with grouped-query "
+                "attention over a bf16/f32 cache and no whole-model "
+                "sliding_window")
         if self.sliding_window is not None and self.sliding_window < 1:
             raise ValueError(
                 f"sliding_window must be >= 1, got {self.sliding_window}")
@@ -233,6 +295,42 @@ class LlamaConfig:
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
+    def layer_kind(self, i: int) -> tuple:
+        """``(window or None, rotates q/k)`` of layer ``i``."""
+        if self.kinds is None:
+            return self.sliding_window, True
+        at = i % len(self.kinds.windows)
+        return self.kinds.windows[at], self.kinds.rope[at]
+
+    def window_layers(self, upto: int) -> int:
+        """Layers with a window among the first ``upto``: a layer's index
+        among those of its cache kind (generate.init_cache) follows."""
+        return sum(self.layer_kind(i)[0] is not None for i in range(upto))
+
+    def kind_runs(self) -> list:
+        """``[(first layer, layers)]`` of the runs of layers of one kind,
+        in order: the model's attention segments."""
+        runs = []
+        for i in range(self.n_layers):
+            if runs and self.layer_kind(i) == self.layer_kind(runs[-1][0]):
+                runs[-1][1] += 1
+            else:
+                runs.append([i, 1])
+        return [tuple(r) for r in runs]
+
+    def segment_plan(self) -> list:
+        """``[(first layer, layers, routed FFN?)]``: the homogeneous
+        segments ``params["layers"]`` is stacked in: cut where the
+        attention kind changes and behind the leading dense layers."""
+        dense = (self.routed.first_dense if self.routed else self.n_layers)
+        plan = []
+        for first, n in self.kind_runs():
+            for lo, hi in ((first, min(first + n, dense)),
+                           (max(first, dense), first + n)):
+                if hi > lo:
+                    plan.append((lo, hi - lo, lo >= dense))
+        return plan
+
     PRESETS = {
         # BASELINE config 5 workload shape (Llama-3 8B).
         "llama3-8b": dict(vocab_size=128256, d_model=4096, n_layers=32,
@@ -258,12 +356,27 @@ class LlamaConfig:
 def layer_segments(layers) -> list:
     """``params["layers"]`` as ``[(stacked tree, index of its first
     layer)]``: one dict is a model of one segment, a tuple of dicts one
-    segment each (a leading dense layer, then the expert layers)."""
+    segment each (a leading dense layer, then the expert layers; the runs
+    of full and of window layers of a ``cfg.kinds`` model)."""
     out, at = [], 0
     for seg in (layers if isinstance(layers, (tuple, list)) else (layers,)):
         out.append((seg, at))
         at += jax.tree_util.tree_leaves(seg)[0].shape[0]
     return out
+
+
+def segment_kind(cfg: "LlamaConfig", seg, first: int) -> tuple:
+    """``(window or None, rotates q/k)`` of the segment whose first layer
+    is ``first``: every layer of a segment is of one kind, so that one
+    scan body serves it (``LlamaConfig.segment_plan`` cuts them so)."""
+    n = jax.tree_util.tree_leaves(seg)[0].shape[0]
+    kinds = {cfg.layer_kind(i) for i in range(first, first + n)}
+    if len(kinds) != 1:
+        raise ValueError(
+            f"layers {first}..{first + n - 1} are one segment of the tree "
+            f"and of {len(kinds)} attention kinds: cut params['layers'] "
+            f"where cfg.kinds changes (init_params does)")
+    return kinds.pop()
 
 
 # Leaves a kernel indexes by layer itself: a scan leaves them whole.
@@ -334,22 +447,27 @@ def _init_block_params(key, cfg: LlamaConfig) -> tuple:
             r = cfg.routed
             seg["routed"] = {
                 "router": norm(ks[5], (L, D, r.n_experts), D**-0.5),
+                **mlp(ks[7], L, (r.n_held,), r.d_expert)}
+            if r.score == "sigmoid":
                 # Small and not zero: the selection path is worked.  Small
                 # against the scores' spacing at the top-k threshold, or
                 # the experts' popularity is the seed's (PERF.md, PR 26).
-                "bias": 0.005 * jax.random.normal(ks[6], (L, r.n_experts),
-                                                  jnp.float32),
-                **mlp(ks[7], L, (r.n_held,), r.d_expert),
-                "shared": mlp(jax.random.fold_in(ks[7], 1), L, (),
-                              r.n_shared * r.d_expert)}
+                seg["routed"]["bias"] = 0.005 * jax.random.normal(
+                    ks[6], (L, r.n_experts), jnp.float32)
+            if r.n_shared:
+                seg["routed"]["shared"] = mlp(
+                    jax.random.fold_in(ks[7], 1), L, (),
+                    r.n_shared * r.d_expert)
         else:
             seg.update(mlp(ks[7], L, (), cfg.d_ff))
         return seg
 
-    n_dense = cfg.routed.first_dense if cfg.routed else cfg.n_layers
-    plan = [(n_dense, False), (cfg.n_layers - n_dense, True)]
-    return tuple(segment(jax.random.fold_in(key, 31 + i), n, routed)
-                 for i, (n, routed) in enumerate(plan) if n)
+    def key_of(first, routed):
+        k = jax.random.fold_in(key, 31 + routed)   # as before cfg.kinds
+        return k if cfg.kinds is None else jax.random.fold_in(k, first)
+
+    return tuple(segment(key_of(first, routed), n, routed)
+                 for first, n, routed in cfg.segment_plan())
 
 
 def init_params(key, cfg: LlamaConfig) -> dict:
@@ -363,7 +481,8 @@ def init_params(key, cfg: LlamaConfig) -> dict:
 
     L, D, F = cfg.n_layers, cfg.d_model, cfg.d_ff
     Hq, Hkv = cfg.n_heads, cfg.n_kv_heads
-    if cfg.latent is not None or cfg.routed is not None:
+    if (cfg.latent is not None or cfg.routed is not None
+            or cfg.kinds is not None):
         segs = _init_block_params(jax.random.fold_in(key, 23), cfg)
         return {"embed": norm(keys[0], (cfg.vocab_size, D), 0.02),
                 "layers": segs[0] if len(segs) == 1 else segs,
@@ -408,11 +527,13 @@ def param_specs(cfg: LlamaConfig) -> dict:
     over the tp-sharded dim, so XLA inserts the reduce-scatter/all-reduce
     pattern over ICI automatically.  Embedding/lm_head shard the vocab dim.
     """
-    if cfg.latent is not None or cfg.routed is not None:
+    if (cfg.latent is not None or cfg.routed is not None
+            or cfg.kinds is not None):
         raise NotImplementedError(
-            "latent attention and the routed FFN have no sharding rules "
-            "yet: they serve on one chip as one holder of an expert-"
-            "parallel deployment (ROADMAP M2)")
+            "latent attention, the routed FFN and attention kinds by layer "
+            "have no sharding rules yet: they serve on one chip (the routed "
+            "FFN as one holder of an expert-parallel deployment; ROADMAP "
+            "M1, M2)")
     layers = {
         "wq": P(None, None, "tp"),
         "wk": P(None, None, "tp"),
@@ -666,6 +787,12 @@ def resolve_attn_fn(cfg: LlamaConfig, attn_fn: Optional[Callable]) -> Callable:
     declare it when built with ``window=``; zigzag doesn't implement
     windows.
     """
+    if cfg.kinds is not None:
+        if attn_fn is not None:
+            raise ValueError(
+                "cfg.kinds gives each layer its own attention (a window or "
+                "none): attn_fn must be None")
+        return self_attention  # forward binds each segment's window
     if attn_fn is None:
         if cfg.latent is not None:
             return partial(self_attention, sm_scale=cfg.latent.sm_scale)
@@ -732,10 +859,13 @@ def qkv_proj(x, lp, cfg: "LlamaConfig"):
             v.reshape(B, S, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3))
 
 
-def ffn_block(x, lp, cfg: "LlamaConfig", moe_fn: Optional[Callable] = None):
+def ffn_block(x, lp, cfg: "LlamaConfig", moe_fn: Optional[Callable] = None,
+              attn_in=None):
     """The FFN of one block on the normed ``x [B, S, D]``, by the kind its
-    leaves name: ``routed`` (sigmoid-routed dropless experts and a shared
-    one), ``moe`` (capacity-buffer Switch/Mixtral) or dense gated MLP.
+    leaves name: ``routed`` (routed dropless experts beside shared ones;
+    ``attn_in``, the block's normed input as attention saw it, is what a
+    ``router_in="attn_norm"`` router scores), ``moe`` (capacity-buffer
+    Switch/Mixtral) or dense gated MLP.
     Returns ``(y, aux, stats)``: the MoE balance term (0 elsewhere) and,
     routed, the pairs each held expert got ``[n_held]``; capacity MoE,
     the router-health dict of a ``with_stats`` ``moe_fn``; else None.  The
@@ -744,8 +874,9 @@ def ffn_block(x, lp, cfg: "LlamaConfig", moe_fn: Optional[Callable] = None):
     if "routed" in lp:
         from .moe import routed_ffn
 
+        early = cfg.routed.router_in == "attn_norm"
         y, stats = routed_ffn(x, lp["routed"], cfg.routed,
-                              act=partial(mlp_gate_act, cfg=cfg))
+                              router_x=attn_in if early else None)
     elif "moe" in lp:
         if moe_fn is not None:
             # SwiGLU expert trees carry w_gate; pass it only when
@@ -778,7 +909,8 @@ def ffn_block(x, lp, cfg: "LlamaConfig", moe_fn: Optional[Callable] = None):
 def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
                   attn_fn: Callable, moe_fn: Optional[Callable] = None):
     """One pre-norm decoder block on ``h [B, S, D]`` with layer params
-    ``lp`` (one slice of a stacked segment).  Returns
+    ``lp`` (one slice of a stacked segment); ``cos``/``sin`` None: a layer
+    that does not rotate q and k (NoPE).  Returns
     ``(h, aux, kv, stats)`` — aux is the MoE balance term (0 for dense),
     kv what the cache holds of these positions, under the cache's own
     keys (``k`` / ``v``: the post-RoPE grouped heads; latent attention:
@@ -797,6 +929,8 @@ def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
     # name-saves only the gate/up dots — the backward replays nothing but
     # norms, rope, and silu.
     chunked = cfg.remat and cfg.remat_policy == "dots"
+    # The router of such a model scores what attention reads.
+    early = cfg.routed is not None and cfg.routed.router_in == "attn_norm"
 
     def pre(h, lp):
         x = rmsnorm(h, lp["attn_norm"], cfg.norm_eps)
@@ -804,19 +938,20 @@ def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
             from .mla import project_expanded
 
             q, k, v, rows = project_expanded(x, lp, cfg, cos, sin)
-            return q, k, v, {"ckv": rows}
+            return q, k, v, {"ckv": rows}, None
         q, k, v = qkv_proj(x, lp, cfg)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if cos is not None:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         # kv stays in grouped (narrow) form; attention impls expand it, so
         # the ring rotates 1/n_rep of the bytes over ICI.
-        return q, k, v, {"k": k, "v": v}
+        return q, k, v, {"k": k, "v": v}, (x if early else None)
 
-    def post(h, o, lp):
+    def post(h, o, lp, attn_in):
         o = o.transpose(0, 2, 1, 3).reshape(B, S, -1)
         h = h + matmul_w(o, lp["wo"])
         y, aux, stats = ffn_block(rmsnorm(h, lp["mlp_norm"], cfg.norm_eps),
-                                  lp, cfg, moe_fn)
+                                  lp, cfg, moe_fn, attn_in)
         if "routed" in lp:
             stats = None  # the held experts' pair counts: the decode path's
         return h + y, aux, stats
@@ -840,12 +975,12 @@ def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
                 jax.checkpoint_policies.save_only_these_names(
                     "mlp_gate", "mlp_up")))
 
-    q, k, v, kv = pre(h, lp)
+    q, k, v, kv, attn_in = pre(h, lp)
     o = attn_fn(q, k, v)  # [B, H, S, Dh]
     # Tag kept for user-supplied whole-model remat policies; the flash
     # kernel additionally tags o and lse internally (pallas_attention).
     o = checkpoint_name(o, "attn_out")
-    h, aux, stats = post(h, o, lp)
+    h, aux, stats = post(h, o, lp, attn_in)
     return h, aux, kv, stats
 
 
@@ -859,7 +994,9 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
     ``return_kv`` additionally returns what the cache holds of every
     layer, under the cache's keys and stacked over ALL layers (``k`` /
     ``v [n_layers, B, Hkv, S, Dh]``, the post-RoPE grouped heads; latent
-    attention: ``ckv [n_layers, B, 1, S, cache_width]``) -- the
+    attention: ``ckv [n_layers, B, 1, S, cache_width]``; a ``cfg.kinds``
+    model: the full layers under ``k`` / ``v`` and the window layers,
+    every position of them, under ``k_ring`` / ``v_ring``) -- the
     KV-cache prefix for :func:`~starway_tpu.models.generate.prefill` (one
     flash-attention pass over the whole prompt instead of S cached decode
     steps).
@@ -899,23 +1036,32 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
 
     h = embed_tokens(params, tokens, cfg)  # [B, S, D]
 
-    def layer(carry, lp):
-        h, aux = carry
-        h, layer_aux, kv, stats = decoder_layer(lp, h, cfg, cos, sin,
-                                                attn_fn, moe_fn=moe_fn)
-        if return_moe_stats and stats is None:
-            raise ValueError("return_moe_stats=True but moe_fn returned no "
-                             "stats (build it with with_stats=True)")
-        return (h, aux + layer_aux), (kv if return_kv else None,
-                                      stats if return_moe_stats else None)
+    def layer_of(window, rope: bool):
+        """The scan body of a segment of that attention kind."""
+        attend = (attn_fn if cfg.kinds is None or window is None
+                  else partial(attn_fn, window=window))
+        tables = (cos, sin) if rope else (None, None)
 
-    body = _remat_wrap(layer, cfg)
+        def layer(carry, lp):
+            h, aux = carry
+            h, layer_aux, kv, stats = decoder_layer(lp, h, cfg, *tables,
+                                                    attend, moe_fn=moe_fn)
+            if return_moe_stats and stats is None:
+                raise ValueError("return_moe_stats=True but moe_fn returned "
+                                 "no stats (build it with with_stats=True)")
+            return (h, aux + layer_aux), (kv if return_kv else None,
+                                          stats if return_moe_stats else None)
+
+        return _remat_wrap(layer, cfg)
+
     carry = (h, jnp.zeros((), jnp.float32))
     outs = []
     # One scan a segment: the segments differ in their trees (a leading
-    # dense layer before the expert layers), the body reads a layer's kind
-    # off its leaves.
-    for seg, _first in layer_segments(params["layers"]):
+    # dense layer before the expert layers) or in their attention kind
+    # (cfg.kinds); the body reads a layer's kind off its leaves.
+    for seg, first in layer_segments(params["layers"]):
+        window, rope = segment_kind(cfg, seg, first)
+        body = layer_of(window, rope)
         if cfg.scan_layers:
             carry, ys = scan_segment(body, carry, seg)
         else:
@@ -927,10 +1073,20 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
                 carry, y = body(carry, lp)
                 ys.append(y)
             ys = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ys)
+        if return_kv and cfg.kinds is not None and window is not None:
+            ys = ({name + "_ring": x for name, x in ys[0].items()}, ys[1])
         outs.append(ys)
     h, aux = carry
-    kv, moe_stats = (outs[0] if len(outs) == 1 else jax.tree_util.tree_map(
-        lambda *xs: jnp.concatenate(xs), *outs))
+    by_name: dict = {}
+    for kv, _stats in outs if return_kv else ():
+        for name, x in kv.items():
+            by_name.setdefault(name, []).append(x)
+    kv = {name: xs[0] if len(xs) == 1 else jnp.concatenate(xs)
+          for name, xs in by_name.items()}
+    stats = [st for _kv, st in outs if st is not None]
+    moe_stats = (None if not stats else stats[0] if len(stats) == 1 else
+                 jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs),
+                                        *stats))
     if last_only:
         h = h[:, -1:]
     elif logit_positions is not None:

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..ops import grouped_matmul
+from ..ops import GATE_ACTS, grouped_matmul
 
 
 def init_moe_params(key, n_layers: int, n_experts: int, d_model: int,
@@ -342,11 +342,13 @@ def make_sharded_moe(mesh, *, ep_axis: str = "ep", dp_axis: str = "dp",
 
 # --------------------------------------------------- routed (dropless) FFN
 #
-# The DeepSeek-V3 / Kimi-K2 expert layer as ONE chip of an expert-parallel
-# deployment runs it: the router scores all ``n_experts``, every token
-# takes its ``top_k``, and this chip computes the part of the result that
-# the experts it HOLDS give (plus the shared expert, which every chip
-# holds).  No capacity: the (token, choice) pairs that landed here are
+# The routed expert layer (DeepSeek-V3 / Kimi-K2: sigmoid scores, a shared
+# expert; SmallThinker: softmax over the chosen logits, ReGLU experts, no
+# shared one, a router that reads the block's input) as ONE chip of an
+# expert-parallel deployment runs it: the router scores all ``n_experts``,
+# every token takes its ``top_k``, and this chip computes the part of the
+# result that the experts it HOLDS give (plus the shared experts, which
+# every chip holds).  No capacity: the (token, choice) pairs that landed here are
 # sorted by expert and go through a grouped matmul (ops.grouped_matmul)
 # that reads only the experts that got a token.
 
@@ -362,6 +364,15 @@ def sigmoid_route(xt, router_w, bias, top_k: int, scale: float):
     g = jnp.take_along_axis(s, idx, axis=-1)
     g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20) * scale
     return idx.astype(jnp.int32), g
+
+
+def softmax_route(xt, router_w, top_k: int, scale: float):
+    """``xt [T, D]`` -> ``(experts [T, k] int32, gates [T, k] f32)``: the
+    ``top_k`` largest logits (f32), the gates their softmax (over the
+    chosen alone: it sums to 1), scaled.  No selection bias."""
+    logits = jnp.dot(xt, router_w, preferred_element_type=jnp.float32)
+    top, idx = lax.top_k(logits, top_k)
+    return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1) * scale
 
 
 def group_rows(local, n_held: int, tile_m: int):
@@ -395,11 +406,12 @@ def group_rows(local, n_held: int, tile_m: int):
     return src, row, tile_expert, ends_p[-1] // tile_m, sizes
 
 
-def routed_experts(xt, experts, gates, w, first: int):
+def routed_experts(xt, experts, gates, w, first: int, act: str = "silu"):
     """The held experts' part of the layer for ``xt [T, D]``: ``experts`` /
     ``gates`` ``[T, k]`` from the router over ALL experts, ``w`` the held
-    experts' stacked SwiGLU weights (``w_gate`` / ``w_up [G, D, F]``,
-    ``w_down [G, F, D]``), which are experts ``first .. first + G - 1``;
+    experts' stacked gated weights (``w_gate`` / ``w_up [G, D, F]``,
+    ``w_down [G, F, D]``; ``act`` on the gate: a name of ``ops.GATE_ACTS``),
+    which are experts ``first .. first + G - 1``;
     with ``w["layer"]`` (a traced scalar) the three are every layer's
     ``[L, G, ...]`` and the kernel picks the layer
     (:func:`~starway_tpu.models.llama.scan_segment`).  Returns ``(y [T,
@@ -417,7 +429,8 @@ def routed_experts(xt, experts, gates, w, first: int):
     x_rows = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)])[
         jnp.minimum(src // k, t)]
     run = functools.partial(grouped_matmul, tile_m=tile_m, layer=layer)
-    hidden = run(x_rows, w["w_gate"], tile_expert, n_live, w2=w["w_up"])
+    hidden = run(x_rows, w["w_gate"], tile_expert, n_live, w2=w["w_up"],
+                 act=act)
     out = run(hidden, w["w_down"], tile_expert, n_live)
     held = (row < out.shape[0]).reshape(t, k)
     picked = out[jnp.minimum(row, out.shape[0] - 1)].reshape(t, k, d)
@@ -427,19 +440,29 @@ def routed_experts(xt, experts, gates, w, first: int):
     return y.astype(xt.dtype), sizes
 
 
-def routed_ffn(x, rp, routed, act=jax.nn.silu):
+def routed_ffn(x, rp, routed, router_x=None):
     """One routed FFN layer on ``x [B, S, D]``: router over all experts,
-    the held experts' share of the routed result, plus the shared expert.
-    ``rp``: ``router [D, E]``, ``bias [E]``, ``w_gate`` / ``w_up`` /
-    ``w_down`` (held experts, stacked) and ``shared`` (one dense SwiGLU).
-    ``routed``: the configuration's :class:`~.llama.RoutedFFN`.  Returns
-    ``(y, sizes [G])``."""
+    the held experts' share of the routed result, plus the shared experts.
+    ``rp``: ``router [D, E]``, ``w_gate`` / ``w_up`` / ``w_down`` (held
+    experts, stacked), ``bias [E]`` (sigmoid scoring) and ``shared`` (one
+    dense gated MLP; absent with ``n_shared == 0``).  ``routed``: the
+    configuration's :class:`~.llama.RoutedFFN`: its scoring rule and gate
+    activation.  ``router_x`` (default ``x``): what the router scores,
+    where that is not the experts' input.  Returns ``(y, sizes [G])``."""
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
-    experts, gates = sigmoid_route(xt, rp["router"], rp["bias"],
-                                   routed.top_k, routed.scale)
-    y, sizes = routed_experts(xt, experts, gates, rp, routed.first_held)
-    sh = rp["shared"]
-    gate = act((xt @ sh["w_gate"]).astype(jnp.float32)).astype(xt.dtype)
-    y = y + (gate * (xt @ sh["w_up"])) @ sh["w_down"]
+    rt = xt if router_x is None else router_x.reshape(b * s, d)
+    if routed.score == "softmax":
+        experts, gates = softmax_route(rt, rp["router"], routed.top_k,
+                                       routed.scale)
+    else:
+        experts, gates = sigmoid_route(rt, rp["router"], rp["bias"],
+                                       routed.top_k, routed.scale)
+    y, sizes = routed_experts(xt, experts, gates, rp, routed.first_held,
+                              routed.act)
+    if "shared" in rp:
+        sh = rp["shared"]
+        gate = GATE_ACTS[routed.act](
+            (xt @ sh["w_gate"]).astype(jnp.float32)).astype(xt.dtype)
+        y = y + (gate * (xt @ sh["w_up"])) @ sh["w_down"]
     return y.reshape(b, s, d), sizes

@@ -267,10 +267,12 @@ class PagedSlotServer(SlotServer):
                  top_p: Optional[float] = None,
                  eos_id: Optional[int] = None, seed: int = 0,
                  on_tokens=None):
-        if cfg.sliding_window is not None:
+        if cfg.sliding_window is not None or cfg.kinds is not None:
             raise NotImplementedError(
                 "paged serving v1 is full-causal; sliding-window models "
-                "already serve in O(window) via the rolling SlotServer")
+                "already serve in O(window) via the rolling SlotServer, "
+                "window layers beside full ones via the dense one "
+                "(ROADMAP M1: a pool with window pages)")
         if cfg.kv_quant != "none":
             raise NotImplementedError(
                 "int8 paged pools are not wired yet; use the dense "

@@ -36,7 +36,8 @@ a FIXED, small set of compiled programs:
   samples the first token and seats the slot inside the scan.  One
   blocking read a step.
 * **Admission = bucketed prefill** (every other kind: an int8 cache, a
-  latent cache, a rolling window, the page pool, a ``prefix=`` request).  A new
+  latent cache, a rolling window, rings beside full rows, the page pool,
+  a ``prefix=`` request).  A new
   request's prompt is right-padded to a power-of-two bucket and prefilled
   in its own dispatch (one compile per bucket), then its kv rows are
   written into the slot with a dynamic slice.  Pad/garbage columns are
@@ -73,7 +74,13 @@ Sliding-window (Mistral-family) models serve through per-slot ROLLING
 caches: O(window) memory per slot however long each generation runs,
 admission via the chunked ``prefill_rolling`` (no prompt bucketing — its
 compiled chunk body is length-independent), and ``max_len`` bounding only
-the rope horizon.  MoE models serve when capacity is provably dropless
+the rope horizon.  A model whose layers differ in kind (``cfg.kinds``:
+window layers beside full ones) serves through the SAME dense programs
+with two kinds of cache leaves side by side: the full layers' rows of
+``max_len`` and the window layers' rings of one window a slot
+(``generate.init_cache``); its bucketed admit programs fold each window
+layer's last ``window`` prompt positions into the slot's ring
+(``generate.ring_fold``).  MoE models serve when capacity is provably dropless
 (``moe_capacity_factor >= n_experts``): expert capacity is shared
 batch-wide, so slot cohabitation could otherwise perturb routing — the
 same rule as ragged ``generate()``.
@@ -169,7 +176,13 @@ def step_log() -> list:
     landed on held experts, all routed layers), ``moe_touched`` (held
     experts with at least one pair, mean over layers and steps) and
     ``moe_max`` (the most pairs one expert got in one layer of one step);
-    other models' rows carry none of the three."""
+    other models' rows carry none of the three.  A server whose cache
+    holds rings beside full rows (``cfg.kinds``) adds ``kv_rows_full`` and
+    ``kv_rows_window``: the cache positions the chunk's FIRST decode step
+    attends in one full layer (``pos + 1``) and in one window layer
+    (``min(pos + 1, window)``), summed over the slots that decode, from
+    the cursors the host holds (each grows by one a step inside the chunk
+    while its slot lives)."""
     return [dict(row) for row in list(_step_log)]
 
 
@@ -629,6 +642,9 @@ class SlotServer:
         # not cache memory.  (_make_cache is a subclass hook: the paged
         # server allocates a shared page pool instead — models/paged.py.)
         self.cache = self._make_cache()
+        # Positions a window layer's ring holds, 0 with no ring leaves.
+        self._ring = (self.cache["k_ring"].shape[3]
+                      if "k_ring" in self.cache else 0)
         # How a prompt enters that cache: () = by an admit program.
         self._widths = self._ingest_widths()
         self.token = jnp.zeros((n_slots,), jnp.int32)
@@ -689,13 +705,16 @@ class SlotServer:
         :meth:`_plan_ingest`: the last is the first at which the longest
         prompt this cache holds comes in within one chunk).  Every other
         kind keeps its admit programs and gets ``()``: a latent cache
-        (``ckv``), a rolling window, an int8 cache (a piece attends over
+        (``ckv``), a rolling window, rings beside the full rows (``k_ring``:
+        a piece would have to attend over a ring its own later tokens
+        overwrite), an int8 cache (a piece attends over
         what the cache holds, quantized there, where a prefill reads the
         prompt's k/v exact: other tokens than ``generate()``'s) and a
         subclass with a layout of its own (the page pool overrides this).
         A ``prefix=`` request takes its admit program on every kind
         (:meth:`_ingests`)."""
-        if self.rolling or "k" not in self.cache or "k_scale" in self.cache:
+        if (self.rolling or self._ring or "k" not in self.cache
+                or "k_scale" in self.cache):
             return ()
         widths = []
         for w in INGEST_WIDTHS:
@@ -745,7 +764,7 @@ class SlotServer:
         costs one suffix-bucket chunk ingest, not a full-prompt prefill.
         The prefix cache lives in host-visible HBM ([L, 1, Hkv, bucket,
         D] per prefix) until :meth:`drop_prefix`."""
-        if self.rolling:
+        if self.rolling or self._ring:
             raise ValueError("prefix caching needs the dense slot cache; "
                              "rolling (sliding-window) slots rebuild their "
                              "window per request anyway")
@@ -1064,6 +1083,11 @@ class SlotServer:
                 step["admits"] += 1
             step["queued"], step["live"] = (len(self._pending),
                                             len(self._slot_rid))
+            if self._ring:
+                at = 1 + self._pos_host[[s for s in self._slot_rid
+                                         if self._live_host[s]]].astype(int)
+                step.update(kv_rows_full=int(at.sum()),
+                            kv_rows_window=int(np.minimum(at, self._ring).sum()))
             # A slot the host knows dead already (a one-token request just
             # admitted) needs no chunk; one whose first token may be its
             # eos is found out after the chunk was queued, and rides it

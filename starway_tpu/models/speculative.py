@@ -70,6 +70,11 @@ def chunk_decode_step(params, cache, tokens, pos, cfg: LlamaConfig, rope):
     """
     B, C = tokens.shape
     T_cache = cache_len(cache)
+    if "k_ring" in cache:
+        raise ValueError(
+            "chunk_decode_step does not support rings (cfg.kinds): a chunk "
+            "written into a ring overwrites entries its own earlier "
+            "positions attend")
     if cfg.sliding_window is not None and T_cache == cfg.sliding_window:
         # Mirrors decode_step's rolling-cache shape check, inverted: a
         # cache of exactly sliding_window slots is a rolling cache

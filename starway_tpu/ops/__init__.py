@@ -29,7 +29,7 @@ from .pallas_attention import ring_step, ring_step_bwd, self_attention
 from .pallas_decode import (cache_write, cached_attention, ingest_attention,
                             latent_attention)
 from .pallas_gemv import quantized_matmul
-from .pallas_gmm import grouped_matmul
+from .pallas_gmm import GATE_ACTS, grouped_matmul
 from .pallas_paged import paged_attention
 from .quantize import quantize_params
 
@@ -37,5 +37,5 @@ __all__ = ["ring_shift", "all_to_all", "all_gather", "psum",
            "reduce_scatter", "quantize_params", "per_head_shard",
            "self_attention", "cached_attention", "ingest_attention",
            "latent_attention",
-           "cache_write", "paged_attention", "grouped_matmul",
+           "cache_write", "paged_attention", "grouped_matmul", "GATE_ACTS",
            "quantized_matmul", "ring_step", "ring_step_bwd"]

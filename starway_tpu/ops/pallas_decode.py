@@ -400,13 +400,24 @@ def decode_attention_lax(q, k_cache, v_cache, pos, *, layer=None,
 
 
 def cached_attention(q, k_cache, v_cache, pos, *, layer=None, window=None,
-                     k_scale=None, v_scale=None):
+                     k_scale=None, v_scale=None, ring: bool = False):
     """Decode attention over a grouped k/v cache, the operation: the
     arguments of :func:`decode_attention`.  On a TPU the kernel streams
     the grouped cache once where it lies (an ``n_rep``-fold saving of HBM
     bandwidth on the bandwidth-bound decode step, and only ~window bytes
     of it under a sliding window), per shard of the heads under a ``tp``
-    mesh; elsewhere :func:`decode_attention_lax`."""
+    mesh; elsewhere :func:`decode_attention_lax`.
+
+    ``ring``: the cache is a circular window of its own length ``T``,
+    written at ``pos % T`` (models/generate.py): its warm slots ARE the
+    window, so the query at ``pos`` sees every slot up to ``min(pos, T -
+    1)`` with no window re-mask (keys carry their absolute RoPE, and
+    attention does not depend on the order of its keys).  The same
+    kernel, which a trace then calls ``sw_decode_attn_ring``."""
+    if ring:
+        if window is not None:
+            raise ValueError("a ring's residents are its window: no window=")
+        pos = jnp.minimum(jnp.asarray(pos, jnp.int32), k_cache.shape[-2] - 1)
     if not dispatch.use_kernels():
         return decode_attention_lax(q, k_cache, v_cache, pos, layer=layer,
                                     window=window, k_scale=k_scale,
@@ -417,11 +428,13 @@ def cached_attention(q, k_cache, v_cache, pos, *, layer=None, window=None,
             for a in (k_cache, v_cache, k_scale, v_scale))
         layer = 0
     scales = () if k_scale is None else (k_scale, v_scale)
+    name = "sw_decode_attn_ring" if ring else "sw_decode_attn_stream"
 
     def kernel(q, k, v, *rest):  # rest = (*scales, pos, layer)
         ks, vs = rest[:-2] or (None, None)
         return decode_attention(q, k, v, rest[-2], layer=rest[-1],
-                                window=window, k_scale=ks, v_scale=vs)
+                                window=window, k_scale=ks, v_scale=vs,
+                                kernel_name=name)
 
     # Heads (dim 1 of q, dim 2 of the stacked caches and scales) shard
     # alike; pos (a scalar, or one cursor per batch row) and the layer

@@ -12,8 +12,9 @@ they move no bytes and do no work, so a step's cost follows the pairs that
 really landed here, not the worst case the static shape allows.
 
 Two forms under one name (``sw_moe_gmm``): ``x @ w[e]`` and, with a second
-weight, the gated pair ``silu(x @ w[e]) * (x @ w2[e])`` of a SwiGLU
-expert's first half, which reads the row tile once for both.
+weight, the gated pair ``act(x @ w[e]) * (x @ w2[e])`` of a gated expert's
+first half (``act``: SiLU for SwiGLU, ReLU for ReGLU), which reads the row
+tile once for both.
 """
 
 from __future__ import annotations
@@ -32,9 +33,12 @@ from . import dispatch
 _VMEM_LIMIT_BYTES = 64 << 20
 _BLOCK_BYTES = 4 << 20   # one weight block [K, tn]
 
+# The gate activations of the gated form, by the name a configuration gives.
+GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
 
 def _gmm_kernel(tile_expert_ref, n_live_ref, layer_ref, x_ref, *refs,
-                gated: bool):
+                gated: bool, act: str):
     if gated:
         w_ref, w2_ref, o_ref = refs
     else:
@@ -45,8 +49,8 @@ def _gmm_kernel(tile_expert_ref, n_live_ref, layer_ref, x_ref, *refs,
         x = x_ref[...]
         y = jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
         if gated:
-            y = jax.nn.silu(y) * jnp.dot(x, w2_ref[...],
-                                         preferred_element_type=jnp.float32)
+            y = GATE_ACTS[act](y) * jnp.dot(
+                x, w2_ref[...], preferred_element_type=jnp.float32)
         o_ref[...] = y.astype(o_ref.dtype)
 
 
@@ -59,7 +63,8 @@ def column_block(k: int, n: int, itemsize: int) -> int:
     return max(fits) if fits else n
 
 
-def gmm_lax(x, w, tile_expert, n_live, tile_m: int, w2=None, layer=None):
+def gmm_lax(x, w, tile_expert, n_live, tile_m: int, w2=None, layer=None,
+            act: str = "silu"):
     """:func:`gmm` in plain lax: what runs where Pallas does not, and what
     the kernel is tested against.  Rows of dead tiles come out zero."""
     if layer is not None:
@@ -69,16 +74,17 @@ def gmm_lax(x, w, tile_expert, n_live, tile_m: int, w2=None, layer=None):
         jnp.where(jnp.arange(tile_expert.shape[0]) < n_live, tile_m, 0))
     y = jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32)
     if w2 is not None:
-        y = jax.nn.silu(y) * jax.lax.ragged_dot(
+        y = GATE_ACTS[act](y) * jax.lax.ragged_dot(
             x, w2, sizes, preferred_element_type=jnp.float32)
     return y.astype(x.dtype)
 
 
 def gmm(x, w, tile_expert, n_live, *, tile_m: int, w2=None, layer=None,
-        interpret=None):
+        act: str = "silu", interpret=None):
     """``out[r] = x[r] @ w[tile_expert[r // tile_m]]`` for the rows of the
     first ``n_live`` row tiles; rows of later tiles are NOT written (the
-    caller never reads them).  With ``w2``: ``silu(x @ w[e]) * (x @ w2[e])``.
+    caller never reads them).  With ``w2``: ``act(x @ w[e]) * (x @ w2[e])``,
+    ``act`` a name of ``GATE_ACTS``.
 
     x: ``[M, K]``, M a multiple of ``tile_m``, rows grouped by expert in
     whole tiles; w (and w2): ``[G, K, N]``, or every layer's stacked ``[L,
@@ -120,7 +126,7 @@ def gmm(x, w, tile_expert, n_live, *, tile_m: int, w2=None, layer=None,
     w_spec = pl.BlockSpec((None, None, k, tn), w_index)
     weights = (w,) if w2 is None else (w, w2)
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, gated=w2 is not None),
+        functools.partial(_gmm_kernel, gated=w2 is not None, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(m // tile_m, n_col),
@@ -139,9 +145,10 @@ def gmm(x, w, tile_expert, n_live, *, tile_m: int, w2=None, layer=None,
 
 
 def grouped_matmul(x, w, tile_expert, n_live, *, tile_m: int, w2=None,
-                   layer=None):
+                   layer=None, act: str = "silu"):
     """The grouped matmul, the operation: the arguments of :func:`gmm`,
     which runs on a TPU; :func:`gmm_lax` elsewhere.  The experts a chip
     holds are whole, so there is no head to shard by."""
     fn = gmm if dispatch.use_kernels() else gmm_lax
-    return fn(x, w, tile_expert, n_live, tile_m=tile_m, w2=w2, layer=layer)
+    return fn(x, w, tile_expert, n_live, tile_m=tile_m, w2=w2, layer=layer,
+              act=act)

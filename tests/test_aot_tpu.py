@@ -111,14 +111,15 @@ def _ring_step(*, bwd=False, causal=True, t=2048):
             q, q, kv, kv, stat, stat, off, off)
 
 
-def _decode(hkv, *, c=1, int8=False, window=None, b=8, t=2048, layers=None):
+def _decode(hkv, *, c=1, int8=False, window=None, b=8, t=2048, layers=None,
+            hq=HQ, name="sw_decode_attn_stream"):
     """``layers``: the scan-stacked cache ``[layers, b, hkv, t, D]`` read
     through a traced layer index, as the serving chunk reads it."""
     from starway_tpu.ops.pallas_decode import decode_attention
 
     lead = () if layers is None else (layers,)
     cache = _s(lead + (b, hkv, t, D), I8 if int8 else BF16)
-    args = [_s((b, HQ, c, D), BF16), cache, cache, _s((b,), I32)]
+    args = [_s((b, hq, c, D), BF16), cache, cache, _s((b,), I32)]
     if layers is not None:
         args.append(_s((), I32))
     if int8:
@@ -129,9 +130,21 @@ def _decode(hkv, *, c=1, int8=False, window=None, b=8, t=2048, layers=None):
                          else (rest[0], rest[1:]))
         ks, vs = scales or (None, None)
         return decode_attention(q, k, v, pos, layer=layer, interpret=False,
-                                window=window, k_scale=ks, v_scale=vs)
+                                window=window, k_scale=ks, v_scale=vs,
+                                kernel_name=name)
 
     return fn, tuple(args)
+
+
+def _kv_write(b, hkv, t, layers):
+    """A decode step's new k and v of ``b`` slots into one layer of the
+    stacked caches, in place."""
+    from starway_tpu.ops.pallas_decode import kv_write
+
+    cache, new = _s((layers, b, hkv, t, D), BF16), _s((b, hkv, 1, D), BF16)
+    return (lambda k, v, nk, nv, layer, rows, pos: kv_write(
+        (k, v), (nk, nv), layer, rows, pos, interpret=False)), (
+            cache, cache, new, new, _s((), I32), _s((b,), I32), _s((b,), I32))
 
 
 def _paged(page, *, b=8, max_len=2048):
@@ -188,15 +201,17 @@ def _latent_write(b=16, t=5120, layers=2):
             _s((), I32), _s((b,), I32), _s((b,), I32))
 
 
-def _gmm(pairs, tile_m, k, n, gated):
+def _gmm(pairs, tile_m, k, n, gated, held=None, act="silu"):
     from starway_tpu.ops.pallas_gmm import gmm
 
-    m = pairs + K2_HELD * tile_m
-    w = _s((K2_HELD, k, n), BF16)
+    held = held or K2_HELD
+    m = pairs + held * tile_m
+    w = _s((held, k, n), BF16)
     args = (_s((m, k), BF16), w, _s((m // tile_m,), I32), _s((), I32))
     if gated:
         return (lambda x, w, te, nl, w2: gmm(
-            x, w, te, nl, tile_m=tile_m, w2=w2, interpret=False)), args + (w,)
+            x, w, te, nl, tile_m=tile_m, w2=w2, act=act,
+            interpret=False)), args + (w,)
     return (lambda x, w, te, nl: gmm(
         x, w, te, nl, tile_m=tile_m, interpret=False)), args
 
@@ -247,6 +262,19 @@ KERNELS = {
     "gmm_down_decode": lambda: _gmm(1024, 16, K2_FE, K2_D, False),
     "gmm_gated_admit": lambda: _gmm(32768, 128, K2_D, K2_FE, True),
     "gmm_down_admit": lambda: _gmm(32768, 128, K2_FE, K2_D, False),
+    # smallthinker-21b.longdoc_closed: 48 slots, 28 query heads over 4 kv
+    # heads; the full layers' rows of 16,384 and the window layers' rings
+    # of 4,096 (the same kernel under the ring's name), their writes, and
+    # 64 ReGLU experts of width 768: 48 x 6 pairs a decode step, a
+    # 14,336-token admit's 86,016.
+    "decode_longdoc_full": lambda: _decode(4, b=48, t=16384, layers=2, hq=28),
+    "decode_longdoc_ring": lambda: _decode(4, b=48, t=4096, layers=6, hq=28,
+                                           name="sw_decode_attn_ring"),
+    "kv_write_longdoc_full": lambda: _kv_write(48, 4, 16384, 2),
+    "kv_write_longdoc_ring": lambda: _kv_write(48, 4, 4096, 6),
+    "gmm_relu_decode": lambda: _gmm(288, 16, 2560, 768, True, 64, "relu"),
+    "gmm_relu_admit": lambda: _gmm(86016, 128, 2560, 768, True, 64, "relu"),
+    "gmm_down_longdoc": lambda: _gmm(288, 16, 768, 2560, False, 64),
 }
 
 
@@ -467,8 +495,10 @@ def _cell_model(name):
 
         cfg = runner.llama_config(config)
     else:
-        from benchmark.harness import weights_mla_moe as W
+        import importlib
 
+        W = importlib.import_module(
+            "benchmark.harness.weights_" + config["runner"][len("serve_"):])
         cfg = runner.model_config(config)
     params = jax.eval_shape(
         lambda: runner.program_tree(W.make_model(0, W.dims(config))))
@@ -519,6 +549,53 @@ def test_admission_programs_at_the_cells_sizes_for_v5e(topo, monkeypatch, cell,
     assert [(o.shape, o.dtype) for o in seat.out_info] == [
         (a.shape, a.dtype) for a in state]
     assert seat.memory_analysis().argument_size_in_bytes < 4096
+
+
+def test_two_cache_kinds_ride_the_decode_chunk_for_v5e(topo, monkeypatch):
+    """smallthinker-21b.longdoc_closed's decode chunk at the cell's shapes
+    (48 slots; the 2 full layers' rows of 16,384 beside the 6 window
+    layers' rings of 4,096): both kinds of leaves ride the scans' carries,
+    written in place and read by one decode kernel under two names; no
+    instruction copies, slices or scatters an array of either cache's
+    shape or of one layer of it, and the temporaries are a small part of
+    either kind (the XLA-only write forms would copy a whole cache a
+    layer, which no CPU test shows)."""
+    import re
+
+    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.serving import _compiled_chunk
+
+    _as_tpu(monkeypatch)
+    cfg, params, n_slots, max_len = _cell_model("smallthinker-21b")
+    assert (n_slots, max_len) == (48, 16384)
+    cache = jax.eval_shape(lambda: init_cache(cfg, n_slots, max_len))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (2, 48, 4, 16384, 128), "v": (2, 48, 4, 16384, 128),
+        "k_ring": (6, 48, 4, 4096, 128), "v_ring": (6, 48, 4, 4096, 128)}
+    run = _compiled_chunk(cfg, n_slots, max_len, CHUNK, 0.0, None, None, None)
+    compiled = run.lower(*_placed(
+        (params, cache, *_slot_state(n_slots)),
+        SingleDeviceSharding(topo.devices[0]))).compile()
+    text = compiled.as_text()
+    for name in ("sw_kv_write", "sw_decode_attn_stream",
+                 "sw_decode_attn_ring", "sw_moe_gmm"):
+        assert name in text, name
+    ring_bytes = cache["k_ring"].size * 2
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < ring_bytes / 4      # 0.2 GB of 1.2
+    assert m.alias_size_in_bytes == sum(
+        a.size * 2 for a in jax.tree_util.tree_leaves(cache))
+    moved = []
+    for layers, t in ((2, 16384), (6, 4096)):
+        shaped = re.compile(
+            rf"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[(?:{layers},|1,)?"
+            + re.escape(f"{n_slots},4,{t},128]") + r"\S* ([\w\-]+)\(")
+        moved += [(mm.group(1), mm.group(2))
+                  for mm in map(shaped.match, text.splitlines())
+                  if mm and mm.group(2) not in {
+                      "parameter", "get-tuple-element", "bitcast",
+                      "custom-call"}]
+    assert moved == []
 
 
 @pytest.mark.slow

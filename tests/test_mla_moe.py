@@ -202,28 +202,64 @@ def test_share_holds_its_experts_numbers(share):
     np.testing.assert_array_equal(part["router"], whole["router"])
 
 
-def test_shares_add_up_to_the_uncut_layer(ref, runner):
-    """model-configs guide, section 4: the routed parts that the shares
-    give, with the shared expert counted once, add up to what the uncut
-    reference gives for the whole layer.  Program and reference alike."""
+def _kimi_share(ref, runner, x, share):
+    """(the reference's routed part, the program's, what every holder
+    computes alike, pairs held) of share ``share`` of 4, or of the whole
+    layer (``share`` None), for the sigmoid-routed layer with its shared
+    expert."""
     from starway_tpu.models.llama import ffn_block
 
+    config = TINY if share is None else dict(
+        TINY, n_routed_experts=4, expert_share=share)
+    d, cfg = W.dims(config), runner.model_config(config)
+    w = W.layer_weights(W.base_key(SEED), 1, d, True)
+    flat = x.reshape(-1, 64)
+    shared = ref._swiglu(flat, w["routed"]["shared"], None)
+    y, _aux, sizes = ffn_block(x, w, cfg)
+    return (ref.routed_part(flat, w["routed"], d), y.reshape(-1, 64) - shared,
+            shared, int(sizes.sum()))
+
+
+def _smallthinker_share(ref, runner, x, share):
+    """The same for the softmax-routed ReGLU layer: no shared expert, the
+    router on the block's input (here the experts' input rolled by one
+    token, so that the two differ)."""
+    from benchmark.harness import weights_window_moe as WW
+    from starway_tpu.models.llama import ffn_block
+
+    from test_window_moe import TINY as SMALL
+
+    config = SMALL if share is None else dict(SMALL, experts_held=2,
+                                              expert_share=share)
+    d, cfg = WW.dims(config), runner.model_config(config)
+    w = WW.layer_weights(WW.base_key(SEED), 1, d)
+    r = jnp.roll(x, 1, axis=1)
+    y, _aux, sizes = ffn_block(x, w, cfg, attn_in=r)
+    return (ref.routed_part(x.reshape(-1, 64), r.reshape(-1, 64), w["routed"], d),
+            y.reshape(-1, 64), 0.0, int(sizes.sum()))
+
+
+@pytest.mark.parametrize("model,share_of,top_k", [
+    ("kimi-k2", _kimi_share, 4), ("smallthinker-21b", _smallthinker_share, 3)])
+def test_shares_add_up_to_the_uncut_layer(model, share_of, top_k):
+    """model-configs guide, section 4: the routed parts that the shares
+    give, with what every holder computes alike (a shared expert) counted
+    once, add up to what the uncut reference gives for the whole layer.
+    Program and reference alike; sigmoid scoring with a shared expert and
+    softmax scoring with ReLU and none."""
+    ref = S.load_reference(model)
+    runner = S.load_runner(S.load_config(S.load_spec(), model)["runner"])
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 9, 64))
-    whole = W.layer_weights(W.base_key(SEED), 1, W.dims(TINY), True)
-    want = (ref.routed_part(x.reshape(-1, 64), whole["routed"], W.dims(TINY))
-            + ref._swiglu(x.reshape(-1, 64), whole["routed"]["shared"], None))
+    whole_ref, _prog, shared, _pairs = share_of(ref, runner, x, None)
+    want = whole_ref + shared
     total_prog = total_ref = 0.0
     pairs = 0
     for share in range(4):
-        config = dict(TINY, n_routed_experts=4, expert_share=share)
-        d, cfg = W.dims(config), runner.model_config(config)
-        w = W.layer_weights(W.base_key(SEED), 1, d, True)
-        total_ref += ref.routed_part(x.reshape(-1, 64), w["routed"], d)
-        y, _aux, sizes = ffn_block(x, w, cfg)
-        shared = ref._swiglu(x.reshape(-1, 64), w["routed"]["shared"], None)
-        total_prog += y.reshape(-1, 64) - shared
-        pairs += int(sizes.sum())
-    assert pairs == 2 * 9 * 4   # every (token, choice) pair landed once
+        part_ref, part_prog, shared, held = share_of(ref, runner, x, share)
+        total_ref += part_ref
+        total_prog += part_prog
+        pairs += held
+    assert pairs == 2 * 9 * top_k   # every (token, choice) pair landed once
     np.testing.assert_allclose(total_ref + shared, want, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(total_prog + shared, want, rtol=1e-4, atol=1e-4)
 
@@ -246,8 +282,9 @@ def test_mla_decode_kernel_matches_lax():
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("gated", [False, True])
-def test_gmm_kernel_matches_lax(gated):
+@pytest.mark.parametrize("gated,act", [(False, "silu"), (True, "silu"),
+                                       (True, "relu")])
+def test_gmm_kernel_matches_lax(gated, act):
     from starway_tpu.models.moe import group_rows
     from starway_tpu.ops.pallas_gmm import gmm, gmm_lax
 
@@ -259,8 +296,12 @@ def test_gmm_kernel_matches_lax(gated):
     x = jnp.asarray(rng.normal(size=(src.shape[0], K)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(G, K, N)), jnp.float32)
     w2 = jnp.asarray(rng.normal(size=(G, K, N)), jnp.float32) if gated else None
-    got = gmm(x, w, tile_expert, n_live, tile_m=tm, w2=w2, interpret=True)
-    want = gmm_lax(x, w, tile_expert, n_live, tm, w2=w2)
+    got = gmm(x, w, tile_expert, n_live, tile_m=tm, w2=w2, act=act,
+              interpret=True)
+    want = gmm_lax(x, w, tile_expert, n_live, tm, w2=w2, act=act)
+    if act == "relu":   # and not SiLU's numbers under another name
+        assert not np.allclose(
+            want, gmm_lax(x, w, tile_expert, n_live, tm, w2=w2), atol=1e-3)
     live = int(n_live) * tm
     np.testing.assert_allclose(got[:live], want[:live], rtol=1e-4, atol=1e-4)
     # Each held pair's row lies in its expert's tiles.
