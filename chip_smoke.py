@@ -388,18 +388,24 @@ async def phase_transport_sockets(dev, sizes=SOCKET_SIZES) -> dict:
             await client.aclose()
             await server.aclose()
         stages = {k: v["count"] for k, v in perf.stage_snapshot().items()}
-        if not native and dev.platform != "cpu":
-            # The Python engine places each arrived chunk while the rest
-            # is still on the wire (device._rx_overlap_ok): more "place"
-            # samples than receives says the chunked path ran.
-            check(stages.get("place", 0) > 3 * len(sizes),
-                  f"chunked receive placement never ran: stages {stages}")
+        # A staged payload crosses the host WHOLE on both engines: one
+        # "place" a staged device receive (two host->HBM a size, and the
+        # HBM->HBM one where it was not pulled), one "stage" a staged
+        # device send (that HBM->HBM one, and the HBM->host of its size:
+        # one rule decides pull or stage for both).
+        staged_d2d = sum(r["hbm_to_hbm"] == "staged" for r in rows)
+        want = {"place": 2 * len(rows) + staged_d2d, "stage": 2 * staged_d2d}
+        check({k: stages.get(k, 0) for k in want} == want,
+              f"staged payloads did not cross whole: stages {stages}, "
+              f"expected {want}")
         out["engines"].append({
             "engine": sw.check_sys_libs(), "transports": transports,
             "transfers": rows, "stage_samples": stages,
             "staging_pool": {
                 "hits": device._staging_pool.hits - pool0[0],
-                "misses": device._staging_pool.misses - pool0[1]}})
+                "misses": device._staging_pool.misses - pool0[1]},
+            "prefetch_peak": {"bytes": device._prefetch.peak_bytes,
+                              "depth": device._prefetch.peak_depth}})
     out["sw_version"] = native_mod.load().sw_version().decode()
 
     # A chip-less peer PROCESS (JAX never imported there), started by the

@@ -262,6 +262,7 @@ const char* kCounterNames[] = {
     "bytes_tx",          "bytes_rx",
     "gather_passes",     "gather_items",
     "staging_hits",      "staging_misses",
+    "prefetch_started",  "prefetch_depth_peak",
     "ka_misses",         "reconnects",
     "sessions_resumed",  "frames_replayed",
     "dup_frames_dropped",
@@ -302,6 +303,7 @@ struct Counters {
   std::atomic<uint64_t> bytes_tx{0}, bytes_rx{0};
   std::atomic<uint64_t> gather_passes{0}, gather_items{0};
   std::atomic<uint64_t> staging_hits{0}, staging_misses{0};  // wrapper-owned
+  std::atomic<uint64_t> prefetch_started{0}, prefetch_depth_peak{0};  // wrapper
   std::atomic<uint64_t> ka_misses{0}, reconnects{0};         // reconnects: wrapper
   std::atomic<uint64_t> sessions_resumed{0}, frames_replayed{0};
   std::atomic<uint64_t> dup_frames_dropped{0};
@@ -785,13 +787,7 @@ bool integrity_enabled() {
 uint64_t stripe_chunk_env() {
   const char* e = getenv("STARWAY_STRIPE_CHUNK");
   uint64_t v = e ? strtoull(e, nullptr, 10) : 0;
-  if (v == 0) {
-    // Default: 4x the §12 staging granularity = 1 MiB (config.py twin).
-    const char* ch = getenv("STARWAY_CHUNK");
-    uint64_t base = ch ? strtoull(ch, nullptr, 10) : (uint64_t)(256u << 10);
-    if (base == 0) base = 256u << 10;
-    v = 4 * base;
-  }
+  if (v == 0) v = 1u << 20;  // default 1 MiB (config.py twin)
   return v < 4096 ? 4096 : v;
 }
 
@@ -7086,6 +7082,7 @@ int sw_counters(void* h, char* out, int cap) {
       c.bytes_tx.load(),       c.bytes_rx.load(),
       c.gather_passes.load(),  c.gather_items.load(),
       c.staging_hits.load(),   c.staging_misses.load(),
+      c.prefetch_started.load(), c.prefetch_depth_peak.load(),
       c.ka_misses.load(),      c.reconnects.load(),
       c.sessions_resumed.load(), c.frames_replayed.load(),
       c.dup_frames_dropped.load(),

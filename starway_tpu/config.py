@@ -51,15 +51,6 @@ benchmark.md:114-126 for ``UCX_TLS``).  The TPU build mirrors that shape:
     acquire/release atomics even on x86 (the off-x86 code path, made
     testable on x86 CI; see core/shmring.py).
 
-``STARWAY_CHUNK``
-    Data-plane pipelining granularity in bytes (default 256 KiB; 0
-    disables pipelining).  Device payloads crossing the framed stream are
-    staged device-to-host one chunk at a time so the D2H of chunk k+1
-    overlaps the transport write of chunk k, and receive-side host-to-
-    device placement of completed chunks overlaps the remaining stream
-    reads (DESIGN.md §12).  Also sizes the reusable host staging-buffer
-    pool that replaces per-transfer allocation.
-
 ``STARWAY_CONNECT_TIMEOUT``
     Per-attempt connect + handshake deadline in seconds (default 3.0).
     Both engines honour it; ``aconnect(..., timeout=)`` overrides it per
@@ -130,10 +121,9 @@ benchmark.md:114-126 for ``UCX_TLS``).  The TPU build mirrors that shape:
     always, is promised only by ``aflush``.
 
 ``STARWAY_STRIPE_CHUNK``
-    Stripe granularity in bytes (default: 4x the ``STARWAY_CHUNK`` §12
-    staging granularity = 1 MiB, the measured sweet spot on the 1-core
-    dev box -- smaller chunks pay a sendmsg per chunk, larger ones
-    starve the work stealing; floor 4 KiB).  Each chunk is an
+    Stripe granularity in bytes (default 1 MiB, the measured sweet spot
+    on the 1-core dev box -- smaller chunks pay a sendmsg per chunk,
+    larger ones starve the work stealing; floor 4 KiB).  Each chunk is an
     independent self-describing frame (msg id, offset, total), which is
     what makes chunk-level work stealing, rail-death redistribution, and
     receiver-side offset dedup possible.
@@ -266,7 +256,6 @@ __all__ = [
     "transports_enabled",
     "advertised_host",
     "rndv_threshold",
-    "chunk_bytes",
     "use_native",
     "device_backend",
     "devpull_enabled",
@@ -352,16 +341,6 @@ def rndv_threshold() -> int:
     return int(_env("STARWAY_RNDV_THRESHOLD", str(8 * 1024 * 1024)))
 
 
-def chunk_bytes() -> int:
-    """Data-plane pipelining granularity (STARWAY_CHUNK); 0 disables
-    chunked staging and the receive-side placement overlap."""
-    try:
-        v = int(_env("STARWAY_CHUNK", str(256 * 1024)))
-    except ValueError:
-        return 256 * 1024
-    return v if v > 0 else 0
-
-
 def connect_timeout() -> float:
     try:
         v = float(_env("STARWAY_CONNECT_TIMEOUT", "3.0"))
@@ -435,15 +414,14 @@ def stripe_threshold() -> int:
 
 
 def stripe_chunk() -> int:
-    """Stripe granularity in bytes (STARWAY_STRIPE_CHUNK; defaults to 4x
-    the §12 STARWAY_CHUNK staging granularity = 1 MiB)."""
+    """Stripe granularity in bytes (STARWAY_STRIPE_CHUNK; default 1 MiB)."""
     raw = _env("STARWAY_STRIPE_CHUNK", "")
     if raw:
         try:
             return max(4096, int(raw))
         except ValueError:
             pass
-    return max(4096, 4 * (chunk_bytes() or 256 * 1024))
+    return 1024 * 1024
 
 
 def stripe_weighted() -> bool:

@@ -95,13 +95,12 @@ _ORPHAN_HISTS = swtrace.Hists()
 class TxData:
     """An outgoing tagged message (header + zero-copy payload view).
 
-    ``payload`` is either a flat host ``memoryview`` or a *chunked* payload
-    duck type (``nbytes`` + ``host_chunk(pos) -> (chunk_start, view)``, see
-    device.py DevicePayload.chunked): the TX pump then materialises host
-    bytes one chunk at a time, and the payload prefetches the next chunk's
-    device-to-host copy before returning the current one -- staging overlaps
-    transmission (DESIGN.md §12).  Either way the wire sees one ordinary
-    DATA frame.
+    ``payload`` is either a flat host ``memoryview`` or a *staged* payload
+    duck type (``nbytes`` + ``as_host_view()``, see device.py
+    DevicePayload): the TX pump then asks for the host view when the
+    message's first payload byte is due, and blocks only for what is left
+    of a device-to-host copy that was started when the send was queued
+    (DESIGN.md §12).  Either way the wire sees one ordinary DATA frame.
     """
 
     # __weakref__: deadline timers (core/engine.py) hold queued sends
@@ -110,18 +109,16 @@ class TxData:
     __slots__ = ("header", "payload", "nbytes", "tag", "off", "done", "fail",
                  "owner", "rndv", "local_done", "switch_after", "counted",
                  "sess_seq", "sess_nbytes", "e2e_ord", "t_post", "t_park",
-                 "hists", "_chunk_start", "_chunk_view", "__weakref__")
+                 "hists", "_view", "__weakref__")
 
     def __init__(self, tag: int, payload, done, fail, owner,
                  hists: Optional[swtrace.Hists] = None):
         if isinstance(payload, memoryview):
             self.nbytes = len(payload)
-            self._chunk_start = 0
-            self._chunk_view: Optional[memoryview] = payload
-        else:  # chunked payload duck type
+            self._view: Optional[memoryview] = payload
+        else:  # staged payload duck type: the view comes when it is due
             self.nbytes = int(payload.nbytes)
-            self._chunk_start = 0
-            self._chunk_view = None
+            self._view = None
         self.header = frames.pack_data_header(tag, self.nbytes)
         self.payload = payload
         self.tag = tag
@@ -164,18 +161,15 @@ class TxData:
         return self.total - self.off
 
     def payload_slice(self, pos: int, limit: int) -> memoryview:
-        """Up to ``limit`` payload bytes starting at ``pos``, never crossing
-        a staging-chunk boundary."""
-        view, start = self._chunk_view, self._chunk_start
-        if view is None or not (start <= pos < start + len(view)):
-            start, view = self.payload.host_chunk(pos)
-            self._chunk_start, self._chunk_view = start, view
-        rel = pos - start
-        return view[rel : rel + limit]
+        """Up to ``limit`` payload bytes starting at ``pos``."""
+        view = self._view
+        if view is None:
+            view = self._view = self.payload.as_host_view()
+        return view[pos : pos + limit]
 
     def tx_views(self, max_bytes: int) -> list:
         """Unwritten views for the gathered socket pump (header remnant +
-        the current payload chunk), bounded by ``max_bytes``."""
+        payload), bounded by ``max_bytes``."""
         views = []
         off, hlen, take = self.off, len(self.header), 0
         if off < hlen:
@@ -253,23 +247,19 @@ class TxData:
         by-reference (delivery is only promised after a flush; the
         journal pins the payload object until the peer ACKs -- the §14
         stability contract).  Eager payloads are always flat host views
-        here: device.py keeps the lazy-chunked pipeline off session
-        conns, so the snapshot below covers every eager frame."""
+        here: device.py hands session conns the flat snapshot, never the
+        staged payload, so the snapshot below covers every eager frame."""
         self.sess_seq = seq
         self.header = prefix + self.header
         if not self.rndv and isinstance(self.payload, memoryview):
             # swcheck: allow(hotpath-copy): journal must own eager payload bytes past local completion (session opt-in)
             snap = memoryview(bytes(self.payload))
-            self.payload = snap
-            self._chunk_view = snap
-            self._chunk_start = 0
+            self.payload = self._view = snap
             self.owner = None
         self.sess_nbytes = self.total
 
     def reset_for_replay(self) -> None:
         self.off = 0
-        self._chunk_start = 0
-        self._chunk_view = self.payload if isinstance(self.payload, memoryview) else None
 
 
 class TxDevpull:
@@ -505,7 +495,9 @@ class TcpConn(BaseConn):
         # PJRT pull extension (frames.py T_DEVPULL): negotiated in the
         # handshake; descriptors received on this conn that have not yet
         # resolved (pull done/failed) hold back FLUSH_ACKs so the sender's
-        # flush barrier covers pulled payloads too.
+        # flush barrier covers pulled payloads too.  A message whose device
+        # placement is still running beside the engine (``msg.placing``) is
+        # held in the same set for the same reason.
         self.devpull_ok = False
         self._remote_msgs: set = set()
         self._deferred_flush_acks: list = []
@@ -1473,8 +1465,7 @@ class TcpConn(BaseConn):
             if item.switch_after:
                 break
             if offered < item.remaining:
-                # Item not fully offered (byte budget, or a chunked payload
-                # whose later chunks are not staged yet): nothing behind it
+                # Item not fully offered (byte budget): nothing behind it
                 # may ride this pass, or the later frame's bytes would land
                 # inside this item's in-flight DATA payload.
                 break
@@ -1551,13 +1542,13 @@ class TcpConn(BaseConn):
             self.worker._conn_broken(self, fires)
             return
         except Exception:
-            # Chunked D2H staging failed mid-message (host_chunk raised:
-            # the array was deleted/donated after asend, or a device
-            # runtime error).  The frame header already promised nbytes the
-            # stream can no longer produce, so reset the connection (the
-            # same discipline as a deadline on a started send) -- queued
-            # ops fail with the stable "cancel" reason instead of the
-            # whole engine emergency-closing.
+            # D2H staging failed (as_host_view raised: the array was
+            # deleted/donated after asend, or a device runtime error).
+            # The frame header may already promise nbytes the stream can
+            # no longer produce, so reset the connection (the same
+            # discipline as a deadline on a started send) -- queued ops
+            # fail with the stable "cancel" reason instead of the whole
+            # engine emergency-closing.
             logger.exception("starway: TX staging failed; resetting connection")
             self.worker._conn_broken(self, fires)
             return
@@ -1829,12 +1820,6 @@ class TcpConn(BaseConn):
                     self._csum_accum = frames.crc32c(target[:n],
                                                      self._csum_accum)
                 m.received += n
-                if (m.progress is not None and not m.discard
-                        and m.sink is not None):
-                    # Device-sink overlap: fully-arrived chunks start their
-                    # async H2D while the rest of the payload streams in
-                    # (device.py DeviceRecvSink.staged; DESIGN.md §12).
-                    m.progress(m.received)
                 if m.received >= m.length:
                     if self._csum_pend is not None:
                         # Verified BEFORE the matcher completes the
@@ -1846,7 +1831,16 @@ class TcpConn(BaseConn):
                             self._corrupt(fires, "payload checksum (DATA)")
                             return
                     with lock:
-                        fires.extend(matcher.on_message_complete(m))
+                        fires.extend(matcher.on_message_complete(
+                            m, place_beside=True))
+                    if m.placing:
+                        # Streamed into a device sink: its ONE placement
+                        # runs beside this thread (DESIGN.md §12).  Until
+                        # the bytes are resident the receive is not
+                        # complete and barriers behind it are not ACKed,
+                        # exactly as for an unresolved pull.
+                        self.remote_received(m)
+                        self.worker._place_beside(self, m)
                     self._rx_msg = None
                     self._rx_e2e(m.length)
                     self._sess_commit()

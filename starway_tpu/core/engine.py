@@ -89,11 +89,11 @@ class FlushRec:
 
 class Worker:
     kind = "worker"
-    # The TX pump understands chunked payload duck types (TxData in
-    # core/conn.py): device.py routes incremental device-to-host staging
-    # through this engine only.  The native engine stages via a flat host
-    # view instead (its ABI takes a raw pointer + length).
-    supports_chunked_tx = True
+    # The TX pump takes a staged payload duck type (TxData in core/conn.py)
+    # and asks for its host view when the first payload byte is due, so
+    # device.py hands this engine the DevicePayload itself.  The native
+    # engine's ABI takes a raw pointer + length: it gets the flat view.
+    lazy_device_tx = True
 
     def __init__(self, name: str = ""):
         self.lock = threading.RLock()
@@ -157,6 +157,9 @@ class Worker:
         # (device.py TransferManager); created lazily, dropped at close so
         # unpulled sends die with the worker (close-cancel contract).
         self._xfer_mgr = None
+        # Device receives' placements run on this thread, beside the engine
+        # (device.py Beside); created by the first one, closed at close.
+        self._placer = None
 
     # ------------------------------------------------------------ app side
     def _require_running(self) -> None:
@@ -462,24 +465,51 @@ class Worker:
             # released; resolution also releases any flush barriers.
             fires.append(lambda m=msg: m.remote.start(m))
 
-    def _on_pull_done(self, msg, payload, error) -> None:
-        """Completion callback from the TransferManager thread.
-
-        Conn I/O (deferred flush ACKs) is engine-thread territory, so hop
+    def _hop(self, op: tuple, settle) -> None:
+        """From a thread beside the engine (device.py Beside): conn I/O
+        (deferred flush ACKs) is engine-thread territory, so ``op`` hops
         onto the engine via the op queue; a worker already closing only
-        needs the matcher bookkeeping."""
+        needs the matcher bookkeeping, ``settle() -> fires``."""
         with self.lock:
             if self.status == state.RUNNING:
                 self._busy += 1
-                self.ops.append(("pull_done", msg, payload, error))
-                queued = True
+                self.ops.append(op)
+                fires = None
             else:
-                fires = self.matcher.on_remote_complete(msg, payload, error)
-                queued = False
-        if queued:
+                fires = settle()
+        if fires is None:
             self._wake()
         else:
             _run_fires(fires)
+
+    def _on_pull_done(self, msg, payload, error) -> None:
+        """Completion callback from the TransferManager thread."""
+        self._hop(("pull_done", msg, payload, error),
+                  lambda: self.matcher.on_remote_complete(msg, payload, error))
+
+    # ------------------------------------------------- device placement
+    def _place_beside(self, conn, msg) -> None:
+        """Engine thread: the last byte of ``msg`` is in its device sink's
+        staging buffer.  The ONE host-to-device copy blocks until the bytes
+        are resident (device.py DeviceRecvSink.place), so it runs beside
+        this thread, which goes on draining and filling the transport."""
+        placer = self._placer
+        if placer is None:
+            from .. import device as _device
+
+            placer = self._placer = _device.Beside("starway-place")
+        sink = msg.posted.buf
+        placer.submit(lambda: self._run_place(conn, msg, sink))
+
+    def _run_place(self, conn, msg, sink) -> None:
+        """Placer thread: place, then hand the result to the engine."""
+        try:
+            array, error = sink.place(msg.length), None
+        except Exception as exc:
+            logger.exception("starway: device placement failed")
+            array, error = None, f"device placement failed: {exc}"
+        self._hop(("placed", conn, msg, array, error),
+                  lambda: self.matcher.on_placed(msg, array, error))
 
     def _force_start_pulls(self, conn, fires) -> None:
         """A FLUSH barrier arrived with descriptors still waiting for a
@@ -489,7 +519,8 @@ class Worker:
         worker lock, so a duplicate thunk is a cheap no-op."""
         with self.lock:
             pending = [m for m in conn._remote_msgs
-                       if m.posted is None and not m.remote.started]
+                       if m.posted is None and not m.placing
+                       and not m.remote.started]
         for msg in pending:
             fires.append(lambda m=msg: m.remote.start(m))
 
@@ -853,6 +884,11 @@ class Worker:
             with self.lock:
                 fires.extend(self.matcher.on_remote_complete(msg, payload, error))
             msg.remote.conn.remote_resolved(msg, fires)
+        elif op[0] == "placed":
+            _, conn, msg, array, error = op
+            with self.lock:
+                fires.extend(self.matcher.on_placed(msg, array, error))
+            conn.remote_resolved(msg, fires)
         elif op[0] == "fc_grant":
             _, conn, gen, nbytes = op
             if gen == conn.fc_rx_gen:
@@ -1052,6 +1088,8 @@ class Worker:
         if remote_msgs:
             with self.lock:
                 for msg in list(remote_msgs):
+                    if msg.placing:
+                        continue  # resolves on its own (on_placed)
                     if msg.posted is None and not msg.remote.started:
                         msg.discard = True
                         try:
@@ -1161,6 +1199,10 @@ class Worker:
             fires.extend(self.matcher.cancel_all())
             conns = list(self.conns.values())
             mgr, self._xfer_mgr = self._xfer_mgr, None
+            placer, self._placer = self._placer, None
+        if placer is not None:
+            placer.close()  # placements still queued run; their receives
+            #                 were cancelled above, so nothing fires
         if mgr is not None:
             # Dropping the transfer server cancels unpulled offers (the
             # close-cancels-in-flight contract for device sends).

@@ -19,7 +19,7 @@ ride the same matcher with no jax dependency here:
 
 * host target: writable ``memoryview``; host payload: ``memoryview``;
 * device target: ``DeviceRecvSink`` (``nbytes`` / ``host_staging()`` /
-  ``finalize_from_host()`` / ``accept_device()`` / optional
+  ``place()`` + ``deliver()`` / ``accept_device()`` / optional
   ``accept_host()`` for complete-bytes-in-hand delivery, see device.py);
 * device payload: ``DevicePayload`` (``nbytes`` / ``as_host_view()`` /
   ``.array``).
@@ -104,7 +104,7 @@ class InboundMsg:
     """
 
     __slots__ = ("tag", "length", "sink", "received", "posted", "complete",
-                 "discard", "spill", "device_payload", "remote", "progress",
+                 "discard", "spill", "device_payload", "remote", "placing",
                  "fc_owner", "fc_gen", "fc_bytes", "born")
 
     def __init__(self, tag: int, length: int):
@@ -130,12 +130,10 @@ class InboundMsg:
         # sender's transfer server until pulled.  Duck-typed: the matcher
         # only ever calls ``remote.start(msg)`` via fire thunks.
         self.remote = None
-        # Optional RX progress hook (device sinks streaming directly into
-        # their staging buffer): the conn calls ``progress(received)`` after
-        # each read so placement can overlap the remaining stream
-        # (device.py DeviceRecvSink.staged).  Duck-typed; None for host
-        # targets and spill buffers.
-        self.progress = None
+        # Every byte has streamed into a device sink's staging buffer and
+        # its one placement is running beside the engine thread; the
+        # message stays in flight until on_placed (DESIGN.md §12).
+        self.placing = False
 
 
 def _copy_complete(pr: PostedRecv, payload, length: int) -> None:
@@ -309,16 +307,26 @@ class TagMatcher:
                     msg.sink = pr.buf
                 else:
                     msg.sink = pr.buf.host_staging()
-                    msg.progress = getattr(pr.buf, "staged", None)
                 return msg, fires
         msg.spill = bytearray(length)
         msg.sink = memoryview(msg.spill)
         self.unexpected.append(msg)
         return msg, fires
 
-    def on_message_complete(self, msg: InboundMsg) -> list:
-        """All payload bytes of ``msg`` have been ingested."""
+    def on_message_complete(self, msg: InboundMsg,
+                            place_beside: bool = False) -> list:
+        """All payload bytes of ``msg`` have been ingested.
+
+        ``place_beside``: the caller runs a device sink's placement beside
+        its thread.  A message streamed into such a sink is then only
+        marked ``placing`` here: it stays in flight (a close or a deadline
+        still cancels its receive) until :meth:`on_placed` completes it."""
         fires: list = []
+        pr = msg.posted
+        if (place_beside and pr is not None and not msg.discard
+                and msg.spill is None and not _is_host(pr.buf)):
+            msg.placing = True
+            return fires
         msg.complete = True
         self.inflight.discard(msg)
         if msg.discard:
@@ -340,6 +348,26 @@ class TagMatcher:
             self._pulse_wait(pr)
             fires.append(lambda pr=pr, m=msg: pr.done(m.tag, m.length))
         # else: stays in the unexpected queue until a matching recv is posted.
+        return fires
+
+    def on_placed(self, msg: InboundMsg, array, error: Optional[str]) -> list:
+        """The placement of a ``placing`` message ended: ``array`` is
+        resident on the sink's device, or ``error`` says why it is not.
+        Nothing fires for a receive that was cancelled or timed out
+        meanwhile (the array is dropped, the DeviceBuffer untouched)."""
+        fires: list = []
+        self.inflight.discard(msg)
+        pr = msg.posted
+        if msg.discard or pr is None:
+            return fires
+        msg.complete = True
+        if error is not None:
+            fires.append(lambda pr=pr: pr.fail(error))
+            return fires
+        pr.buf.deliver(array)
+        self.counters.recvs_completed += 1
+        self._pulse_wait(pr)
+        fires.append(lambda pr=pr, m=msg: pr.done(m.tag, m.length))
         return fires
 
     # ------------------------------------------------------- remote (pull)
@@ -490,7 +518,6 @@ class TagMatcher:
                 if msg.posted is pr and not msg.complete:
                     msg.posted = None
                     msg.sink = None  # remaining bytes drain to conn scratch
-                    msg.progress = None
                     self.purge_inflight(msg)
                     break
             else:
@@ -515,7 +542,6 @@ class TagMatcher:
                 pr = msg.posted
                 msg.posted = None
                 msg.sink = None
-                msg.progress = None
                 self.purge_inflight(msg)
                 fires.append(lambda pr=pr, reason=reason: pr.fail(reason))
         return fires

@@ -105,10 +105,11 @@ EV_STALL = "stall"            # swpulse stall-sentinel alert (DESIGN.md
 # ----------------------------------------------------- counter vocabulary
 #
 # One name list, two implementations (engine.py Worker.counters and the
-# C++ kCounterNames/Counters pair).  `staging_hits`/`staging_misses` and
-# `reconnects` are PROCESS-GLOBAL (the staging pool and the api-layer
-# reconnect loop are not per-worker); merge_global_counters overlays them
-# onto every worker snapshot so one dict answers "what happened here".
+# C++ kCounterNames/Counters pair).  `staging_hits`/`staging_misses`,
+# `prefetch_*` and `reconnects` are PROCESS-GLOBAL (the staging pool, the
+# prefetch window and the api-layer reconnect loop are not per-worker);
+# merge_global_counters overlays them onto every worker snapshot so one
+# dict answers "what happened here".
 
 COUNTER_NAMES = (
     "sends_posted",       # tagged sends + DEVPULL descriptors submitted
@@ -125,6 +126,11 @@ COUNTER_NAMES = (
     "gather_items",       # iovecs submitted across gathered passes
     "staging_hits",       # staging-pool buffer reuses (process-global)
     "staging_misses",     # staging-pool fresh allocations (process-global)
+    "prefetch_started",   # device sends whose D2H copy was started ahead
+    #                       of the TX pump (device.py _PrefetchWindow;
+    #                       process-global, like the staging pool)
+    "prefetch_depth_peak",  # most such copies in flight at once: a
+    #                       high-water mark, not a sum (process-global)
     "ka_misses",          # peers declared dead by keepalive liveness
     "reconnects",         # aconnect retry attempts (process-global)
     "sessions_resumed",   # session conns resumed after a reconnect
@@ -291,7 +297,8 @@ STALL_REASONS = (
 #: Process-global counters (staging pool, api-layer reconnects).
 GLOBAL = Counters()
 
-_GLOBAL_NAMES = ("staging_hits", "staging_misses", "reconnects",
+_GLOBAL_NAMES = ("staging_hits", "staging_misses", "prefetch_started",
+                 "prefetch_depth_peak", "reconnects",
                  "reshard_bytes", "reshard_rounds")
 
 

@@ -25,16 +25,24 @@ small duck-typed protocols (no jax import in the core):
 * :class:`DevicePayload` -- wraps an array for sending (``nbytes``,
   ``as_host_view()``, ``.array``).
 * :class:`DeviceRecvSink` -- wraps a :class:`DeviceBuffer` for receiving
-  (``nbytes``, ``host_staging()``, ``finalize_from_host()``,
+  (``nbytes``, ``host_staging()``, ``place()`` / ``deliver()``,
   ``accept_device()``).
+
+A staged payload crosses the host WHOLE: one device-to-host copy and one
+placement a message, whatever its size.  The overlap comes from the queue
+of messages, not from pieces of one (DESIGN.md §12): queued sends' copies
+are started ahead of the TX pump (:class:`_PrefetchWindow`), and a
+receive's placement runs beside the engine thread (:class:`Beside`).
 """
 
 from __future__ import annotations
 
 import logging
+import queue
 import sys
 import threading
 import time
+from collections import deque
 from typing import Optional
 
 logger = logging.getLogger("starway_tpu")
@@ -187,13 +195,112 @@ class _StagingPool:
 _staging_pool = _StagingPool()
 
 
-def _rx_overlap_ok(device) -> bool:
-    """Chunked receive placement (async H2D per completed chunk + one
-    device-side concatenate) only pays on accelerator targets where the
-    DMA genuinely overlaps the remaining stream reads; on CPU the
-    concatenate costs more than it hides.  Module-level so tests can
-    force the path on the virtual CPU mesh."""
-    return device is not None and getattr(device, "platform", "cpu") != "cpu"
+# ---------------------------------------------------------- prefetch window
+#
+# The device-to-host copy of a queued send is STARTED (copy_to_host_async,
+# no wait) when the send is posted, so the copies of the messages queued
+# behind the one on the transport run while that one drains: with any queue
+# the next message's transfer is the overlap, and it costs one call a
+# message.  Bounded in BYTES like the staging pool: a thousand queued sends
+# pin at most ``cap_bytes`` of host copies; the rest wait unstarted, in post
+# order, and start as earlier sends settle.  One message is always
+# admitted, whatever its size (it would otherwise never start).
+
+_PF_NONE, _PF_WAITING, _PF_STARTED, _PF_SETTLED = range(4)  # a payload's _pf
+
+
+class _PrefetchWindow:
+    def __init__(self, cap_bytes: int = 64 << 20):
+        self._lock = threading.Lock()
+        self._cap = cap_bytes
+        self._held = 0      # bytes of copies started and not yet settled
+        self._depth = 0     # how many copies those are
+        self._waiting: deque = deque()
+        self.peak_bytes = 0
+        self.peak_depth = 0
+
+    def _admit(self, payload) -> bool:
+        """Lock held.  Account ``payload`` as started if it fits."""
+        from .core import swtrace
+
+        n = payload.nbytes
+        if self._held and self._held + n > self._cap:
+            return False
+        payload._pf = _PF_STARTED
+        self._held += n
+        self._depth += 1
+        self.peak_bytes = max(self.peak_bytes, self._held)
+        self.peak_depth = max(self.peak_depth, self._depth)
+        g = swtrace.GLOBAL
+        g.prefetch_started += 1
+        g.prefetch_depth_peak = max(g.prefetch_depth_peak, self._depth)
+        return True
+
+    def post(self, payload) -> None:
+        """A send was queued: start its copy now, or when there is room."""
+        with self._lock:
+            if self._waiting or not self._admit(payload):
+                payload._pf = _PF_WAITING
+                self._waiting.append(payload)
+                return
+        payload.start_fetch()
+
+    def settle(self, payload) -> None:
+        """The send completed or failed: its bytes leave the window and the
+        sends waiting behind it start, as far as they fit."""
+        start = []
+        with self._lock:
+            if payload._pf == _PF_STARTED:
+                self._held -= payload.nbytes
+                self._depth -= 1
+            payload._pf = _PF_SETTLED
+            waiting = self._waiting
+            while waiting:
+                nxt = waiting[0]
+                if nxt._pf == _PF_WAITING:
+                    if not self._admit(nxt):
+                        break
+                    start.append(nxt)
+                waiting.popleft()  # started now, or settled while waiting
+        for p in start:
+            p.start_fetch()
+
+
+_prefetch = _PrefetchWindow()
+
+
+class Beside:
+    """One lazily started daemon thread that runs thunks in order BESIDE an
+    engine thread, for what must never hold that thread: a wait on the
+    device (a receive's placement, a pull's completion).  Whoever owns it
+    closes it; thunks still queued then are run first."""
+
+    def __init__(self, name: str):
+        self._name = name
+        self._lock = threading.Lock()
+        self._q: "queue.Queue" = queue.Queue()
+        self._thread = None
+
+    def submit(self, thunk) -> None:
+        with self._lock:
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name=self._name, daemon=True)
+                self._thread.start()
+        self._q.put(thunk)
+
+    def _run(self) -> None:
+        while True:
+            thunk = self._q.get()
+            if thunk is None:
+                return
+            try:
+                thunk()
+            except Exception:
+                logger.exception("%s: thunk failed", self._name)
+
+    def close(self) -> None:
+        self._q.put(None)
 
 
 _jax_array_type = None
@@ -258,33 +365,33 @@ class DeviceBuffer:
 
 
 class DevicePayload:
-    """Send-side wrapper: a jax.Array plus a lazily-created host view.
+    """Send-side wrapper: a jax.Array and the host view it is staged into.
 
-    Two staging modes feed the framed stream:
+    ONE full-payload device-to-host copy a message: ``start_fetch()`` starts
+    it without waiting (the prefetch window calls it for queued sends) and
+    ``as_host_view()`` waits for it, on whoever needs the bytes first: the
+    engine's TX pump when the message's first payload byte is due (eager
+    sends on the Python engine hand the payload itself to ``TxData``), or
+    the poster for the sends that need a flat view up front (send_device).
+    The duck protocol core/ sees is ``nbytes`` + ``as_host_view()``."""
 
-    * ``as_host_view()`` -- one full-payload D2H (the in-process delivery
-      path, and the fallback for engines without chunked TX support).
-    * ``chunked(chunk_bytes)`` + ``host_chunk(pos)`` -- incremental D2H:
-      the TX pump asks for the chunk containing byte ``pos`` and the
-      payload kicks off the async device-to-host copy of the NEXT chunk
-      before returning, so staging chunk k+1 overlaps the transport write
-      of chunk k (DESIGN.md §12).  The duck protocol core/conn.py sees is
-      just ``nbytes`` + ``host_chunk``.
-    """
-
-    __slots__ = ("array", "nbytes", "scope", "_host_view", "_flat",
-                 "_chunk_elems", "_chunk_b", "_dev_chunks", "_host_chunks")
+    __slots__ = ("array", "nbytes", "scope", "_host_view", "_pf")
 
     def __init__(self, array):
         self.array = array
         self.nbytes = int(array.nbytes)
         self.scope = None  # owning worker's perf.StageScope (send_device)
         self._host_view: Optional[memoryview] = None
-        self._flat = None  # chunked mode state (see chunked())
-        self._chunk_elems = 0
-        self._chunk_b = 0
-        self._dev_chunks: Optional[dict] = None
-        self._host_chunks: Optional[dict] = None
+        self._pf = _PF_NONE  # _PrefetchWindow's state for this send
+
+    def start_fetch(self) -> None:
+        try:
+            self.array.copy_to_host_async()
+        except Exception:
+            # Best-effort (a deleted or donated array raises here): the
+            # np.asarray of as_host_view still blocks, or raises where the
+            # TX pump can fail the send.
+            logger.debug("copy_to_host_async refused", exc_info=True)
 
     def as_host_view(self) -> memoryview:
         if self._host_view is None:
@@ -301,88 +408,23 @@ class DevicePayload:
                           self.scope)
         return self._host_view
 
-    # ------------------------------------------------------- chunked D2H
-    def chunked(self, chunk_bytes: int) -> Optional["DevicePayload"]:
-        """Arm incremental staging, or None when it cannot help (payload
-        smaller than two chunks, pipelining disabled, or the array refuses
-        the flat view).  Arming prefetches chunk 0 so its D2H runs while
-        the message header is still being written."""
-        if chunk_bytes <= 0 or self.nbytes < 2 * chunk_bytes:
-            return None
-        try:
-            flat = self.array.reshape(-1)
-            itemsize = _np_dtype(flat.dtype).itemsize
-            elems = chunk_bytes // itemsize
-            if elems <= 0 or self.nbytes < 2 * elems * itemsize:
-                return None
-            self._flat = flat
-            self._chunk_elems = elems
-            self._chunk_b = elems * itemsize
-            self._dev_chunks = {}
-            self._host_chunks = {}
-            self._prefetch(0)
-        except Exception:
-            logger.debug("chunked staging unavailable for this payload",
-                         exc_info=True)
-            return None
-        return self
-
-    def _prefetch(self, k: int) -> None:
-        """Start the async D2H of chunk ``k`` (device-side slice +
-        copy_to_host_async); no-op past the end or when already started."""
-        if k * self._chunk_b >= self.nbytes or k in self._dev_chunks:
-            return
-        if self._host_chunks is not None and k in self._host_chunks:
-            return
-        sl = self._flat[k * self._chunk_elems:(k + 1) * self._chunk_elems]
-        try:
-            sl.copy_to_host_async()
-        except Exception:
-            pass  # best-effort: np.asarray below still blocks correctly
-        self._dev_chunks[k] = sl
-
-    def host_chunk(self, pos: int) -> tuple[int, memoryview]:
-        """(chunk_start, host_view) for the chunk containing byte ``pos``,
-        prefetching the following chunk before materialising this one."""
-        import numpy as np
-
-        k = pos // self._chunk_b
-        self._prefetch(k)
-        self._prefetch(k + 1)
-        view = self._host_chunks.get(k)
-        if view is None:
-            t0 = time.perf_counter()
-            host = np.ascontiguousarray(np.asarray(self._dev_chunks.pop(k)))
-            view = memoryview(host).cast("B")
-            _record_stage("stage", time.perf_counter() - t0, len(view),
-                          self.scope)
-            self._host_chunks[k] = view
-            # The pump only moves forward: chunk k-1 is fully on the wire.
-            self._host_chunks.pop(k - 1, None)
-        return k * self._chunk_b, view
-
 
 class DeviceRecvSink:
     """Receive-side adapter bridging the byte matcher to a DeviceBuffer.
 
-    Streamed (TCP/sm) payloads land in a pooled host staging buffer; on
-    accelerator targets the conn's RX pump reports progress via
-    :meth:`staged` and every completed chunk starts its async H2D while
-    later chunks are still on the wire, with one device-side concatenate
-    at :meth:`finalize_from_host` (DESIGN.md §12)."""
+    Streamed (TCP/sm) payloads land in a pooled host staging buffer; when
+    the last byte has arrived ONE placement puts them on the device
+    (:meth:`place`, which blocks until they are resident -- the Python
+    engine runs it beside its thread, DESIGN.md §12) and :meth:`deliver`
+    swaps the array into the DeviceBuffer."""
 
-    __slots__ = ("devbuf", "scope", "_staging", "_staging_view",
-                 "_chunk_elems", "_chunk_b", "_placed", "_recyclable")
+    __slots__ = ("devbuf", "scope", "_staging", "_staging_view")
 
     def __init__(self, devbuf: DeviceBuffer):
         self.devbuf = devbuf
         self.scope = None  # owning worker's perf.StageScope (post_device_recv)
         self._staging = None
         self._staging_view: Optional[memoryview] = None
-        self._chunk_elems = 0  # >0 = chunked placement armed
-        self._chunk_b = 0
-        self._placed: Optional[list] = None
-        self._recyclable = True
 
     @property
     def nbytes(self) -> int:
@@ -391,98 +433,29 @@ class DeviceRecvSink:
     def host_staging(self) -> memoryview:
         """Host bounce buffer for streamed (TCP) payloads (pooled)."""
         if self._staging_view is None:
-            from . import config
-
             self._staging = _staging_pool.get(self.nbytes)
             self._staging_view = memoryview(self._staging).cast("B")
-            chunk = config.chunk_bytes()
-            itemsize = self.devbuf.dtype.itemsize
-            elems = chunk // itemsize if chunk > 0 else 0
-            if (elems > 0 and self.nbytes >= 2 * elems * itemsize
-                    and _rx_overlap_ok(self.devbuf.device)):
-                self._chunk_elems = elems
-                self._chunk_b = elems * itemsize
-                self._placed = []
         return self._staging_view
 
-    def staged(self, received: int) -> None:
-        """RX progress hook (engine thread): start the async H2D of every
-        fully-arrived chunk.  No-op unless chunked placement is armed.
+    def place(self, length: int):
+        """Staged bytes fully arrived: ONE host-to-device copy of them.
+        Returns the array once it is RESIDENT; only then does the staging
+        buffer go back to the pool, and may the caller complete the receive
+        (the PR 21 hazard, _fast_h2d)."""
+        staging, self._staging, self._staging_view = self._staging, None, None
+        placed, copied = self._put(staging[:length], length)
+        if copied:
+            _staging_pool.put(staging)
+        return placed
 
-        Chunked placement is purely an overlap optimisation -- the staging
-        buffer receives every byte regardless -- so any failure here (or in
-        the finalize assemble) disarms it and the transfer falls back to
-        one full-buffer placement instead of killing the engine thread."""
-        if not self._chunk_b:
-            return
-        try:
-            while (len(self._placed) + 1) * self._chunk_b <= received:
-                off = len(self._placed) * self._chunk_b
-                self._place_chunk(off, self._chunk_b)
-        except Exception:
-            logger.warning("chunked H2D placement failed; falling back to "
-                           "full-buffer placement", exc_info=True)
-            self._disarm_chunks()
-
-    def _disarm_chunks(self) -> None:
-        self._chunk_elems = self._chunk_b = 0
-        self._placed = None
-
-    def _place_chunk(self, off: int, nbytes: int) -> None:
-        # Armed only for a concrete target (_rx_overlap_ok): always PJRT.
-        t0 = time.perf_counter()
-        arr = self._staging[off:off + nbytes].view(self.devbuf.dtype)
-        self._placed.append(_fast_h2d(arr, self.devbuf.device))
-        _record_stage("place", time.perf_counter() - t0, nbytes, self.scope)
+    def deliver(self, array) -> None:
+        self.devbuf.array = array
+        self.devbuf.last_transport = "staged"
 
     def finalize_from_host(self, length: int) -> None:
-        """Staged bytes fully arrived: view as dtype/shape, place on device."""
-        import numpy as np
-
-        assembled = False
-        if self._placed:
-            try:
-                self._finalize_chunked(length)
-                assembled = True
-            except Exception:
-                logger.warning("chunked H2D assemble failed; falling back "
-                               "to full-buffer placement", exc_info=True)
-                self._disarm_chunks()
-        if not assembled:
-            self._place(np.asarray(self._staging[:length]), length)
-        if self._recyclable and self._staging is not None:
-            _staging_pool.put(self._staging)
-        self._staging = None
-        self._staging_view = None
-        self._disarm_chunks()
-        self._recyclable = True
-
-    def _finalize_chunked(self, length: int) -> None:
-        """Assemble the chunk arrays placed mid-stream into the delivered
-        array (one device-side concatenate, pinned to the target device)."""
-        import contextlib
-
-        import jax
-        import jax.numpy as jnp
-
-        done_b = len(self._placed) * self._chunk_b
-        if done_b < length:
-            self._place_chunk(done_b, length - done_b)
-        t0 = time.perf_counter()
-        dev = self.devbuf.device
-        # buffer_from_pyval chunks are uncommitted: pin the assemble to
-        # the target device or jax's default device would claim it.
-        ctx = jax.default_device(dev) if dev is not None else contextlib.nullcontext()
-        with ctx:
-            arr = (jnp.concatenate(self._placed) if len(self._placed) > 1
-                   else self._placed[0])
-            if length == self.nbytes:
-                arr = arr.reshape(self.devbuf.shape)
-        if dev is not None and arr.devices() != {dev}:
-            arr = _copy_to_device(arr, dev, self.devbuf._plan)
-        self.devbuf.array = arr
-        self.devbuf.last_transport = "staged"
-        _record_stage("place", time.perf_counter() - t0, 0, self.scope)
+        """place + deliver in the caller's thread (the native engine's
+        completion callback; a spill copied into the staging buffer)."""
+        self.deliver(self.place(length))
 
     def accept_host(self, view, length: int) -> None:
         """Complete host bytes already in hand (in-process delivery, or an
@@ -504,47 +477,32 @@ class DeviceRecvSink:
         import jax
 
         raw = np.frombuffer(view, dtype=np.uint8, count=length)
-        t0 = time.perf_counter()
-        placed = _fast_h2d(self._as_target(raw, length), self.devbuf.device)
-        if placed is not None:
-            placed.block_until_ready()  # recv-complete = data resident
-            self.devbuf.array = placed
-            self.devbuf.last_transport = "staged"
-            _record_stage("place", time.perf_counter() - t0, length, self.scope)
-            return
-        dev = self.devbuf.device
-        platform = dev.platform if dev is not None else jax.local_devices()[0].platform
-        if platform == "cpu":
+        if (self.devbuf.device is None
+                and jax.local_devices()[0].platform == "cpu"):
             raw = raw.copy()  # private snapshot; aliasing it is then fine
-            self._place(raw, length)
-        else:
-            # H2D device_put is async: the DMA reads the source view after
-            # the call returns, and completion licenses the sender to reuse
-            # that buffer.  Block until the data is resident (the same
-            # recv-complete semantics accept_device enforces).
-            self._place(raw, length)
-            self.devbuf.array.block_until_ready()
+        self.deliver(self._put(raw, length)[0])
 
-    def _as_target(self, raw, length: int):
-        """View staged uint8 bytes as the sink's dtype (and shape, when the
-        payload fills the buffer exactly)."""
+    def _put(self, raw, length: int) -> tuple:
+        """``(array, copied)``: ONE host-to-device copy of ``raw`` viewed as
+        the sink's dtype (and shape, when the payload fills the buffer
+        exactly), blocked on until the bytes are resident -- what receive
+        completion means.  The seconds blocked are the ``place`` stage.
+        ``copied`` is False where the array may alias ``raw``: a sink with no
+        target device places through jax.device_put (jax's default device),
+        which zero-copies host memory on CPU targets."""
+        import jax
+
         arr = raw.view(self.devbuf.dtype)
         if length == self.nbytes:
             arr = arr.reshape(self.devbuf.shape)
-        return arr
-
-    def _place(self, raw, length: int) -> None:
-        import jax
-
-        arr = self._as_target(raw, length)
         t0 = time.perf_counter()
         placed = _fast_h2d(arr, self.devbuf.device)
-        if placed is None:  # no target device: jax's default device
-            self._recyclable = False  # device_put may alias `raw` (CPU)
+        copied = placed is not None
+        if not copied:
             placed = jax.device_put(arr)
-        self.devbuf.array = placed
-        self.devbuf.last_transport = "staged"
+        placed.block_until_ready()
         _record_stage("place", time.perf_counter() - t0, length, self.scope)
+        return placed, copied
 
     def accept_device(self, array) -> None:
         """Direct device handoff (in-process path): HBM -> HBM over ICI when
@@ -617,14 +575,12 @@ class TransferManager:
 
     Owned by a Worker; dropped at worker close so unpulled sends die with
     the worker (the close-cancels-in-flight contract).  Server creation and
-    peer connections are lazy; completion waits run on one daemon thread so
-    the engine loop never blocks on a transfer.
+    peer connections are lazy; completion waits run on one daemon thread
+    (:class:`Beside`) so the engine loop never blocks on a transfer.
     """
 
     def __init__(self, host: str):
         import itertools
-        import queue
-        import threading
 
         self._host = host
         self._server = None
@@ -632,8 +588,7 @@ class TransferManager:
         self._conns: dict = {}  # address -> TransferConnection
         self._uuid = itertools.count(1)
         self._lock = threading.Lock()
-        self._q: "queue.Queue" = queue.Queue()
-        self._thread = None
+        self._beside = Beside("starway-devpull")
         self._closed = False
 
     # ------------------------------------------------------------- server
@@ -686,7 +641,8 @@ class TransferManager:
         typically the engine thread and must never stall.  Exactly one of
         the callbacks fires, on that thread.
         """
-        self._submit(lambda: self._do_pull(desc, device, on_done, on_fail))
+        self._beside.submit(
+            lambda: self._do_pull(desc, device, on_done, on_fail))
 
     def _do_pull(self, desc: dict, device, on_done, on_fail):
         try:
@@ -724,33 +680,13 @@ class TransferManager:
             return
         on_done(arr)
 
-    def _submit(self, thunk) -> None:
-        import threading
-
-        with self._lock:
-            if self._thread is None:
-                self._thread = threading.Thread(
-                    target=self._run, name="starway-devpull", daemon=True)
-                self._thread.start()
-        self._q.put(thunk)
-
-    def _run(self):
-        while True:
-            thunk = self._q.get()
-            if thunk is None:
-                return
-            try:
-                thunk()
-            except Exception:
-                logger.exception("devpull completion callback failed")
-
     def close(self) -> None:
         """Drop the server: unpulled offers die (close-cancel contract)."""
         with self._lock:
             self._closed = True
             self._server = None
             self._conns.clear()
-        self._q.put(None)
+        self._beside.close()
 
 
 class PulledPayload:
@@ -835,46 +771,51 @@ def send_device(worker, conn, buffer, tag, done, fail):
         if desc is not None:
             worker.submit_devpull(conn, desc, tag, done, fail, payload)
             return
-    # A session conn's replay journal must OWN every eager frame's bytes
-    # past local completion (core/conn.py sess_wrap snapshots flat host
-    # views), but a chunked payload is re-staged lazily from the device
-    # buffer -- which the eager contract lets the caller delete or donate
-    # once ``done`` fires.  Journaled eager sends therefore take the full
-    # host snapshot below instead of the chunked pipeline.
-    journaled = (config.session_enabled() if conn is None
-                 else getattr(conn, "sess", None) is not None)
-    # §19 integrity conns checksum at framing time, which needs the whole
-    # payload resident: device sends on them take the flat host snapshot
-    # too (the CRC folds once over the full view; DESIGN.md §19).
-    journaled = journaled or (
-        config.integrity_enabled() if conn is None
-        else bool(getattr(conn, "csum_ok", False)))
-    # Multi-rail striping (DESIGN.md §17) needs a flat host view -- chunks
-    # are random-offset slices, and the §12 lazy-chunked pipeline stages
-    # strictly in order.  A stripe-eligible device send therefore takes
-    # the full host snapshot; the stripe scheduler's chunk-level dispatch
-    # then supplies the transport overlap the pipeline would have.
+    # Who needs the whole payload as a flat host view at POST time:
+    # * a session conn's replay journal must OWN every eager frame's bytes
+    #   past local completion (core/conn.py sess_wrap snapshots flat views);
+    # * §19 integrity conns checksum at framing time (the CRC folds once
+    #   over the full view; DESIGN.md §19);
+    # * multi-rail striping (DESIGN.md §17) slices chunks at random offsets;
+    # * a rendezvous send completes at header-on-wire, and that completion
+    #   licenses the caller to delete or donate the array, so nothing may
+    #   still read it afterwards;
+    # * the native engine, whose ABI takes a raw pointer + length.
+    # They take the snapshot in the poster's thread, as they always have.
+    flat = (config.session_enabled() or config.integrity_enabled()
+            if conn is None else
+            getattr(conn, "sess", None) is not None
+            or bool(getattr(conn, "csum_ok", False)))
     stripe_thr = config.stripe_threshold()
-    striped = (stripe_thr > 0 and payload.nbytes >= stripe_thr
-               and bool(getattr(conn, "rails", None)))
-    if (getattr(worker, "supports_chunked_tx", False)
-            and not journaled and not striped
-            and payload.nbytes <= config.rndv_threshold()):
-        # Framed-stream staging pipelines: the TX pump pulls host chunks
-        # incrementally so the D2H of chunk k+1 overlaps the write of
-        # chunk k (core/conn.py TxData; DESIGN.md §12).  Eager payloads
-        # only: an eager send completes when the LAST chunk is staged and
-        # written, so completion still licenses the caller to delete or
-        # donate the array.  A rendezvous send completes at header-on-wire
-        # with lazy staging still reading the array afterwards, which
-        # would silently revoke that license -- rndv payloads keep the
-        # full up-front host snapshot instead.
-        chunked = payload.chunked(config.chunk_bytes())
-        if chunked is not None:
-            worker.submit_send(conn, chunked, tag, done, fail, payload)
-            return
-    view = payload.as_host_view()
-    worker.submit_send(conn, view, tag, done, fail, payload)
+    flat = flat or (stripe_thr > 0 and payload.nbytes >= stripe_thr
+                    and bool(getattr(conn, "rails", None)))
+    if (flat or payload.nbytes > config.rndv_threshold()
+            or not getattr(worker, "lazy_device_tx", False)):
+        worker.submit_send(conn, payload.as_host_view(), tag, done, fail,
+                           payload)
+        return
+    # Every other staged send, whatever its size: the copy is started now
+    # if the prefetch window has room, and the TX pump waits for it
+    # (as_host_view) when the message's first payload byte is due -- never
+    # the poster.  Eager completion keeps its meaning: ``done`` fires when
+    # the last byte is written, after the ONE copy has left the device, so
+    # it still licenses the caller to delete or donate the array
+    # (DESIGN.md §12).
+    def settled_done():
+        _prefetch.settle(payload)
+        done()
+
+    def settled_fail(reason):
+        _prefetch.settle(payload)
+        fail(reason)
+
+    _prefetch.post(payload)
+    try:
+        worker.submit_send(conn, payload, tag, settled_done, settled_fail,
+                           payload)
+    except Exception:
+        _prefetch.settle(payload)  # refused at submit: neither will fire
+        raise
 
 
 def post_device_recv(worker, buffer, tag, mask, done, fail):

@@ -1,7 +1,7 @@
 """Data-plane pipelining tests (DESIGN.md §12).
 
-Covers the PR-3 hot-path work: chunked device staging overlapping the
-framed stream, receive-side placement overlap, the pooled staging buffers,
+Covers the hot path: device payloads staged whole across the framed stream
+(one prefetched fetch, one placement beside the engine), the pooled staging buffers,
 the gathered socket TX pump, per-stage telemetry, and -- the pinned
 regression -- batched completion delivery: a burst of N completions crosses
 the engine->asyncio boundary in O(1) ``call_soon_threadsafe`` hops, not N.
@@ -36,12 +36,12 @@ async def _pair(port):
     return server, client, server.list_clients().pop()
 
 
-def _force_tcp(monkeypatch, *, native: bool, chunk: int | None = None):
-    monkeypatch.setenv("STARWAY_TLS", "tcp")
+def _force_tcp(monkeypatch, *, native: bool, tls: str = "tcp"):
+    """The framed stream (no pull) over ``tls``: "tcp" is the real socket,
+    "sm,tcp" the shared-memory ring behind it (Python engine only)."""
+    monkeypatch.setenv("STARWAY_TLS", tls)
     monkeypatch.setenv("STARWAY_NATIVE", "1" if native else "0")
     monkeypatch.setenv("STARWAY_DEVPULL", "0")  # exercise the framed stream
-    if chunk is not None:
-        monkeypatch.setenv("STARWAY_CHUNK", str(chunk))
 
 
 # ------------------------------------------------- completion batching
@@ -92,47 +92,52 @@ async def test_completion_batch_single_trampoline_hop(port, monkeypatch, engine)
         await server.aclose()
 
 
-# ------------------------------------------------- chunked device staging
+# --------------------------------------- staged device payloads, whole
 
 
-async def test_chunked_send_overlaps_staging(port, monkeypatch):
-    """A device payload on the framed stream stages D2H chunk-by-chunk
-    (DevicePayload.host_chunk) instead of one full-payload np.asarray."""
-    _force_tcp(monkeypatch, native=False, chunk=64 * 1024)
-    calls: list = []
-    orig = device.DevicePayload.host_chunk
-
-    def spy(self, pos):
-        calls.append(pos)
-        return orig(self, pos)
-
-    monkeypatch.setattr(device.DevicePayload, "host_chunk", spy)
+@pytest.mark.parametrize("nbytes", [512 * 1024, 4 << 20],
+                         ids=["512K", "4M"])
+@pytest.mark.parametrize("tls", ["tcp", "sm,tcp"], ids=["tcp", "sm"])
+async def test_staged_device_message_crosses_whole(port, monkeypatch, tls,
+                                                   nbytes):
+    """A device payload on the framed stream is fetched and placed WHOLE
+    (DESIGN.md §12): exactly one ``stage`` and one ``place`` a message,
+    whatever its size, and the receive's completion means the array is
+    resident on the device named."""
+    _force_tcp(monkeypatch, native=False, tls=tls)
     server, client, _ep = await _pair(port)
     try:
         src = jax.device_put(
-            jnp.arange(256 * 1024, dtype=jnp.float32), jax.devices()[0])
-        sink = DeviceBuffer((256 * 1024,), jnp.float32, device=jax.devices()[1])
+            jnp.arange(nbytes // 4, dtype=jnp.float32), jax.devices()[0])
+        sink = DeviceBuffer((nbytes // 4,), jnp.float32,
+                            device=jax.devices()[3])
+        perf.stage_reset()
         recv_fut = server.arecv(sink, 31, MASK)
         await asyncio.sleep(0.01)
         await client.asend(src, 31)
         tag, length = await recv_fut
-        assert (tag, length) == (31, src.nbytes)
+        assert sink.array.is_ready(), "receive completed before residency"
+        assert (tag, length) == (31, nbytes)
+        assert sink.array.devices() == {jax.devices()[3]}
+        assert sink.last_transport == "staged"
         np.testing.assert_array_equal(np.asarray(sink.array), np.asarray(src))
-        chunks_touched = {pos // (64 * 1024) for pos in calls}
-        assert len(chunks_touched) >= 2, (
-            f"chunked staging never engaged (host_chunk calls: {calls[:8]})")
+        snap = perf.stage_snapshot()
+        assert snap["stage"]["count"] == 1, snap
+        assert snap["place"]["count"] == 1, snap
+        assert snap["stage"]["bytes"] == snap["place"]["bytes"] == nbytes
     finally:
         await client.aclose()
         await server.aclose()
 
 
-async def test_chunked_send_with_queued_frames_behind(port, monkeypatch):
-    """Frames queued behind a partially-staged chunked send must NOT ride
-    the same gathered sendmsg pass (their bytes would land inside the
-    in-flight DATA payload).  Regression for the _gather_tx over-offer:
-    a chunked payload + a second send + a flush, all queued in one burst,
-    must deliver both payloads intact and complete the flush."""
-    _force_tcp(monkeypatch, native=False, chunk=64 * 1024)
+async def test_staged_send_with_queued_frames_behind(port, monkeypatch):
+    """Frames queued behind a staged device send whose host view is not
+    materialised yet must NOT ride the same gathered sendmsg pass (their
+    bytes would land inside the in-flight DATA payload).  Regression for
+    the _gather_tx over-offer: a staged payload + a second send + a flush,
+    all queued in one burst, must deliver both payloads intact and
+    complete the flush -- after the device bytes are resident."""
+    _force_tcp(monkeypatch, native=False)
     server, client, _ep = await _pair(port)
     try:
         src = jax.device_put(
@@ -145,8 +150,10 @@ async def test_chunked_send_with_queued_frames_behind(port, monkeypatch):
         await asyncio.sleep(0.01)
         s1 = client.asend(src, 61)
         s2 = client.asend(tail, 62)
-        fl = client.aflush()
-        await asyncio.gather(s1, s2, fl, f1, f2)
+        await client.aflush()
+        # The barrier covers the placement that runs beside the engine.
+        assert f1.done() or sink.array is not None
+        await asyncio.gather(s1, s2, f1, f2)
         np.testing.assert_array_equal(np.asarray(sink.array), np.asarray(src))
         np.testing.assert_array_equal(tail_sink, tail)
     finally:
@@ -154,57 +161,124 @@ async def test_chunked_send_with_queued_frames_behind(port, monkeypatch):
         await server.aclose()
 
 
-async def test_chunked_send_over_sm_ring(port, monkeypatch):
-    """The chunked payload protocol also feeds the sm ring TX path
-    (TxData.write payload_slice), not just the socket gather."""
-    monkeypatch.setenv("STARWAY_TLS", "sm,tcp")
-    monkeypatch.setenv("STARWAY_NATIVE", "0")
-    monkeypatch.setenv("STARWAY_DEVPULL", "0")
-    monkeypatch.setenv("STARWAY_CHUNK", str(64 * 1024))
+async def test_flush_waits_for_device_placement(port, monkeypatch):
+    """``aflush`` returns only after every byte sent is RESIDENT on the
+    receiver's device: a placement held up beside the engine holds the
+    FLUSH_ACK back, and the receive with it."""
+    import threading
+
+    _force_tcp(monkeypatch, native=False)
+    gate = threading.Event()
+    orig = device.DeviceRecvSink.place
+
+    def slow_place(self, length):
+        gate.wait(10)
+        return orig(self, length)
+
+    monkeypatch.setattr(device.DeviceRecvSink, "place", slow_place)
     server, client, _ep = await _pair(port)
     try:
-        src = jnp.arange(128 * 1024, dtype=jnp.float32)  # 512 KiB = 8 chunks
-        sink = DeviceBuffer((128 * 1024,), jnp.float32, device=jax.devices()[2])
-        recv_fut = server.arecv(sink, 33, MASK)
+        src = np.random.randint(0, 255, 256 * 1024, dtype=np.uint8)
+        sink = DeviceBuffer((256 * 1024,), jnp.uint8, device=jax.devices()[2])
+        recv_fut = server.arecv(sink, 63, MASK)
         await asyncio.sleep(0.01)
-        await client.asend(src, 33)
-        tag, length = await recv_fut
-        assert (tag, length) == (33, src.nbytes)
-        np.testing.assert_array_equal(np.asarray(sink.array), np.asarray(src))
+        await client.asend(src, 63)
+        flush_fut = asyncio.ensure_future(client.aflush())
+        await asyncio.sleep(0.3)
+        assert not flush_fut.done(), "flush returned before residency"
+        assert not recv_fut.done(), "receive completed before residency"
+        assert sink.array is None
+        gate.set()
+        await asyncio.wait_for(asyncio.gather(flush_fut, recv_fut), 10)
+        assert sink.array.is_ready()
+        np.testing.assert_array_equal(np.asarray(sink.array), src)
+    finally:
+        gate.set()
+        await client.aclose()
+        await server.aclose()
+
+
+async def test_prefetch_window_is_bounded_in_bytes(port, monkeypatch):
+    """200 device sends queued at once: the copies started ahead of the TX
+    pump never hold more than the window's bound, every send is prefetched
+    in the end, and every payload arrives intact."""
+    _force_tcp(monkeypatch, native=False)
+    nbytes, n_msgs = 64 * 1024, 200
+    window = device._PrefetchWindow(cap_bytes=8 * nbytes)
+    monkeypatch.setattr(device, "_prefetch", window)
+    server, client, _ep = await _pair(port)
+    try:
+        srcs = [jax.device_put(jnp.full((nbytes,), i % 251, dtype=jnp.uint8),
+                               jax.devices()[i % 4]) for i in range(n_msgs)]
+        sinks = [DeviceBuffer((nbytes,), jnp.uint8, device=jax.devices()[4])
+                 for _ in range(n_msgs)]
+        recvs = [server.arecv(b, 0xA00 + i, MASK) for i, b in enumerate(sinks)]
+        await asyncio.sleep(0.05)
+        sends = [client.asend(a, 0xA00 + i) for i, a in enumerate(srcs)]
+        await asyncio.gather(*sends, *recvs)
+        await client.aflush()
+        assert 0 < window.peak_bytes <= 8 * nbytes, window.peak_bytes
+        assert 1 <= window.peak_depth <= 8, window.peak_depth
+        assert window._held == 0 and window._depth == 0
+        assert not window._waiting
+        for i, b in enumerate(sinks):
+            assert int(np.asarray(b.array)[0]) == i % 251
+            assert int(np.asarray(b.array)[-1]) == i % 251
     finally:
         await client.aclose()
         await server.aclose()
 
 
-async def test_chunked_recv_placement_overlap(port, monkeypatch):
-    """With the overlap gate forced open (it is accelerator-only by
-    default), completed chunks start their H2D mid-stream and the
-    finalize concatenates them into the target dtype/shape/device."""
-    _force_tcp(monkeypatch, native=False, chunk=64 * 1024)
-    monkeypatch.setattr(device, "_rx_overlap_ok", lambda dev: dev is not None)
-    placed: list = []
-    orig = device.DeviceRecvSink._place_chunk
-
-    def spy(self, off, nbytes):
-        placed.append((off, nbytes))
-        return orig(self, off, nbytes)
-
-    monkeypatch.setattr(device.DeviceRecvSink, "_place_chunk", spy)
-    server, client, _ep = await _pair(port)
+@pytest.mark.parametrize("tls", ["tcp", "sm,tcp"], ids=["tcp", "sm"])
+async def test_overwrite_hazard_both_directions(port, monkeypatch, tls):
+    """The PR 21 hazard.  A sender that DELETES its array the moment
+    ``done`` fires (the licence eager completion gives), and a receiver
+    whose pooled staging buffer is reused at once by the next message of
+    the same size, still deliver every message's original bytes."""
+    _force_tcp(monkeypatch, native=False, tls=tls)
+    server, client, ep = await _pair(port)
+    loop = asyncio.get_running_loop()
     try:
-        src = np.random.randint(0, 255, 512 * 1024, dtype=np.uint8)
-        sink = DeviceBuffer((128 * 1024,), jnp.float32, device=jax.devices()[3])
-        assert sink.nbytes == src.nbytes
-        recv_fut = server.arecv(sink, 35, MASK)
-        await asyncio.sleep(0.01)
-        await client.asend(src, 35)
-        tag, length = await recv_fut
-        assert (tag, length) == (35, src.nbytes)
-        assert len(placed) >= 2, "chunked placement never engaged"
-        assert sink.array.devices() == {jax.devices()[3]}
-        assert sink.last_transport == "staged"
-        np.testing.assert_array_equal(
-            np.asarray(sink.array), src.view(np.float32).reshape(128 * 1024))
+        nbytes, n_msgs = 1 << 20, 12
+        want = [np.random.randint(0, 255, nbytes, dtype=np.uint8)
+                for _ in range(n_msgs)]
+        srcs = [jax.device_put(w, jax.devices()[0]) for w in want]
+        sinks = [DeviceBuffer((nbytes,), jnp.uint8, device=jax.devices()[5])
+                 for _ in range(n_msgs)]
+        hits0 = device._staging_pool.hits
+        recvs = [server.arecv(b, 0xB00 + i, MASK) for i, b in enumerate(sinks)]
+        await asyncio.sleep(0.05)
+        sent = [loop.create_future() for _ in range(n_msgs)]
+
+        def done(i):
+            srcs[i].delete()  # on the engine thread, the moment it fires
+            loop.call_soon_threadsafe(sent[i].set_result, None)
+
+        def fail(i, reason):
+            loop.call_soon_threadsafe(
+                sent[i].set_exception, RuntimeError(reason))
+
+        for i in range(n_msgs):
+            client.send(srcs[i], 0xB00 + i, lambda i=i: done(i),
+                        lambda r, i=i: fail(i, r))
+        await asyncio.wait_for(asyncio.gather(*sent, *recvs), 30)
+        await client.aflush()
+        assert device._staging_pool.hits > hits0, "staging never reused"
+        for i, b in enumerate(sinks):
+            assert b.array.is_ready()
+            np.testing.assert_array_equal(np.asarray(b.array), want[i])
+        # ... and the other direction, host payloads overwritten at once.
+        back = [DeviceBuffer((nbytes,), jnp.uint8, device=jax.devices()[6])
+                for _ in range(n_msgs)]
+        recvs = [client.arecv(b, 0xC00 + i, MASK) for i, b in enumerate(back)]
+        await asyncio.sleep(0.05)
+        buf = np.empty(nbytes, dtype=np.uint8)
+        for i in range(n_msgs):
+            buf[:] = want[i]
+            await server.asend(ep, buf, 0xC00 + i)  # eager: buf is ours again
+        await asyncio.wait_for(asyncio.gather(*recvs), 30)
+        for i, b in enumerate(back):
+            np.testing.assert_array_equal(np.asarray(b.array), want[i])
     finally:
         await client.aclose()
         await server.aclose()

@@ -177,3 +177,89 @@ async def test_devicebuffer_send_side(port):
     finally:
         await client.aclose()
         await server.aclose()
+
+
+# ----------------------------------------------- the whole-message plane
+
+
+class _FakePayload:
+    """What _PrefetchWindow sees of a DevicePayload."""
+
+    def __init__(self, nbytes, log):
+        from starway_tpu import device
+
+        self.nbytes, self._pf, self._log = nbytes, device._PF_NONE, log
+
+    def start_fetch(self):
+        self._log.append(self)
+
+
+@pytest.mark.parametrize("case", ["fifo", "oversize", "settled_waiting"])
+def test_prefetch_window_rules(case):
+    """The bytes-bounded window of device-to-host copies started ahead of
+    the TX pump: post order, one message always admitted, and a send that
+    settles while it still waits is skipped, never started."""
+    from starway_tpu import device
+
+    log: list = []
+    win = device._PrefetchWindow(cap_bytes=100)
+    if case == "fifo":
+        ps = [_FakePayload(40, log) for _ in range(5)]
+        for p in ps:
+            win.post(p)
+        assert log == ps[:2] and win._held == 80
+        win.settle(ps[0])
+        assert log == ps[:3] and win._held == 80
+        for p in ps[1:]:
+            win.settle(p)
+        assert log == ps and win._held == 0 and win._depth == 0
+        assert win.peak_bytes == 80 and win.peak_depth == 2
+    elif case == "oversize":
+        big, small = _FakePayload(1000, log), _FakePayload(10, log)
+        win.post(big)          # alone in the window: admitted past the cap
+        win.post(small)        # waits: nothing overtakes, nothing fits
+        assert log == [big] and win._held == 1000
+        win.settle(big)
+        assert log == [big, small] and win._held == 10
+        win.settle(small)
+        assert win._held == 0 and not win._waiting
+    else:
+        a, b, c = (_FakePayload(60, log) for _ in range(3))
+        for p in (a, b, c):
+            win.post(p)
+        assert log == [a]
+        win.settle(b)          # cancelled while waiting (the conn died)
+        win.settle(a)
+        assert log == [a, c] and win._held == 60
+        win.settle(c)
+        win.settle(c)          # idempotent
+        assert win._held == 0 and win._depth == 0 and not win._waiting
+
+
+def test_recv_sink_place_blocks_until_resident_then_recycles():
+    """place(): ONE copy out of the pooled staging buffer, returned only
+    when the array is resident; only then is the buffer recycled, and a
+    rewrite of it by the next transfer leaves the placed bytes alone."""
+    from starway_tpu import device, perf
+
+    nbytes = 160 * 1024 + 64  # a bucket no other suite uses
+    dev = jax.devices()[2]
+    first = device.DeviceRecvSink(DeviceBuffer((nbytes,), jnp.uint8, device=dev))
+    view = first.host_staging()
+    view[:] = bytes([7]) * nbytes
+    perf.stage_reset()
+    placed = first.place(nbytes)
+    assert placed.is_ready() and placed.devices() == {dev}
+    assert first.devbuf.array is None, "place() must not deliver"
+    assert perf.stage_snapshot()["place"]["count"] == 1
+    second = device.DeviceRecvSink(DeviceBuffer((nbytes,), jnp.uint8, device=dev))
+    hits0 = device._staging_pool.hits
+    view2 = second.host_staging()
+    assert device._staging_pool.hits == hits0 + 1, "staging not recycled"
+    view2[:] = bytes([9]) * nbytes       # the next message's bytes
+    first.deliver(placed)
+    assert first.devbuf.last_transport == "staged"
+    assert np.asarray(first.devbuf.array).min() == 7
+    assert np.asarray(first.devbuf.array).max() == 7
+    second.finalize_from_host(nbytes)
+    assert int(np.asarray(second.devbuf.array)[0]) == 9

@@ -622,7 +622,6 @@ async def test_device_payload_stage_spans_in_trace(port, monkeypatch):
     import jax
 
     _env(monkeypatch, native=False)
-    monkeypatch.setenv("STARWAY_CHUNK", str(64 * 1024))
     server, client, _ep = await _pair(port)
     try:
         src = jax.device_put(jnp.arange(64 * 1024, dtype=jnp.float32),
