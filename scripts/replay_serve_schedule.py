@@ -6,12 +6,15 @@ between seeds is the ORDER of the traffic's fixed set, and this answers how
 far that alone spreads the two metrics, for any ``set_size``, before a
 chip-minute is spent (PERF.md section 6, PR 30).
 
-The costs are ``smallthinker-21b.longdoc_closed``'s, read from its traced
-runs on the chip (PR 30): seconds of each admit bucket, and a decode step
-as a constant plus the two attention kernels by the cache positions they
-sweep.  With them the replay gave 19 measured seeds' ``tok_s`` within 1%
-(12.7 +- 4.5 tokens/s high) and ``tpot_p95_ms`` within 0.5 ms.  Another
-cell needs its own numbers here.
+The costs are a cell's own, read from its traced runs on the chip and kept
+in ``CELLS`` under its traffic's name: seconds of each admit bucket, and a
+decode step as a constant plus its attention kernels by the cache positions
+they sweep.  ``smallthinker-21b.longdoc_closed`` (PR 30): the replay gave 19
+measured seeds' ``tok_s`` within 1% (12.7 +- 4.5 tokens/s high) and
+``tpot_p95_ms`` within 0.5 ms.  ``kimi-linear.reason_closed`` (PR 32): a
+step's 19.9 ms do not depend on the cursors (the state is constant in
+length), the two latent layers' attention is 2.2 ms at 293k attended rows.
+Another cell needs its own numbers here.
 
     python scripts/replay_serve_schedule.py --set-sizes 16 32 --seeds 240
 """
@@ -31,27 +34,44 @@ sys.path.insert(0, str(ROOT))
 from benchmark.harness import stats  # noqa: E402
 from benchmark.harness import traffic as T  # noqa: E402
 
-N_SLOTS, CHUNK, WINDOW = 48, 8, 4096
-ADMIT_S = {2048: .0334, 4096: .0647, 6144: .0990, 8192: .1334,
-           11264: .1904, 14336: .2473}
+CHUNK = 8
 HOST_S = 0.003            # a step's dispatch, fetch and harvest
 
 
-def step_s(cursors) -> float:
+def _longdoc_step_s(cursors) -> float:
     """One decode step: 10.3 ms that do not depend on the cursors, the two
     full layers' attention (3.7 ms at 362k attended positions) and the six
     rings' (6.0 ms at 186k)."""
     full = sum(p + 1 for p in cursors)
-    ring = sum(min(p + 1, WINDOW) for p in cursors)
+    ring = sum(min(p + 1, 4096) for p in cursors)
     return (10.3 + 0.3 + 3.43 * full / 362e3 + 0.5 + 5.53 * ring / 186e3) / 1e3
 
 
-def replay(traffic: dict, seed: int, seconds: float = 45.0) -> dict:
+def _reason_step_s(cursors) -> float:
+    """One decode step: 19.9 ms whatever the cursors (six layers' state in
+    and out, weights, experts) and the two latent layers' attention (2.2 ms
+    at 293k attended rows)."""
+    return (19.9 + 2.2 * sum(p + 1 for p in cursors) / 293e3) / 1e3
+
+
+# traffic name -> (slots, seconds of each admit bucket, a decode step)
+CELLS = {
+    "longdoc_closed_c72": (48, {
+        2048: .0334, 4096: .0647, 6144: .0990, 8192: .1334, 11264: .1904,
+        14336: .2473}, _longdoc_step_s),
+    "reason_closed_c320": (256, {
+        128: .0062, 256: .0096, 512: .0168, 1024: .0285, 2048: .0666,
+        4096: .1401}, _reason_step_s),
+}
+
+
+def replay(traffic: dict, seed: int, cell: tuple, seconds: float = 45.0) -> dict:
     """``serve.inproc_window`` over ``SlotServer.step``: admissions in the
     queue's order (of the first ``len(free)`` the one with most tokens to
     produce first), their first tokens when the last admit program has run,
     a chunk's tokens at its end, a finished request's client asking again
     at once."""
+    n_slots, admit_cost, step_s = cell
     lengths = T.request_lengths(traffic, seed, 8192)
     pending, slots, rows, nxt = [], {}, {}, 0
 
@@ -66,14 +86,14 @@ def replay(traffic: dict, seed: int, seconds: float = 45.0) -> dict:
     t = admit_s = 0.0
     tokens = 0
     while t < seconds:
-        free = [s for s in range(N_SLOTS) if s not in slots]
+        free = [s for s in range(n_slots) if s not in slots]
         admitted = []
         while free and pending:
             at = max(range(min(len(free), len(pending))),
                      key=lambda i: (lengths[pending[i]][1], -i))
             idx = pending.pop(at)
             prompt, want = lengths[idx]
-            cost = ADMIT_S[next(b for b in ADMIT_S if b >= prompt)] + 0.0005
+            cost = admit_cost[next(b for b in admit_cost if b >= prompt)] + 0.0005
             t, admit_s = t + cost, admit_s + cost
             slots[free.pop(0)] = {"idx": idx, "left": want - 1, "pos": prompt}
             admitted.append(idx)
@@ -122,7 +142,7 @@ def main(argv=None) -> int:
     rng = random.Random(5)
     seeds = [2 ** 31 + rng.randrange(2 ** 30) for _ in range(a.seeds)]
     for n in a.set_sizes:
-        runs = [replay(dict(traffic, set_size=n), s) for s in seeds]
+        runs = [replay(dict(traffic, set_size=n), s, CELLS[a.traffic]) for s in seeds]
         tok = [r["tok_s"] for r in runs]
         tpot = [r["tpot_p95_ms"] for r in runs]
         sets = [rng.sample(range(len(runs)), 6) for _ in range(4000)]

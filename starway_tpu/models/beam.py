@@ -125,6 +125,10 @@ def generate_beam(params: dict, cfg: LlamaConfig, prompt,
         raise ValueError(f"beams must be >= 1, got {beams}")
     if beams > cfg.vocab_size:
         raise ValueError(f"beams={beams} exceeds the vocab ({cfg.vocab_size})")
+    if cfg.linear is not None:
+        raise ValueError("beam search reorders cache rows by position; a "
+                         "linear-attention layer's state (cfg.linear) has "
+                         "none and is not wired (ROADMAP M4)")
     if cfg.sliding_window is not None or cfg.kinds is not None:
         raise ValueError("beam search needs full caches; rolling-cache "
                          "support is not wired")

@@ -22,6 +22,14 @@ the window (``cfg.sliding_window``) may keep all its layers so
 layers differ (``cfg.kinds``) keeps its window layers' rings under
 ``k_ring`` / ``v_ring`` BESIDE its full layers' rows under ``k`` / ``v``,
 each stacked over the layers of its own kind (``init_cache``).
+
+A STATE is what a linear-attention layer keeps (``cfg.linear``,
+models/kda.py): ``kda_state`` / ``kda_conv``, a matrix a head and the
+convolutions' last inputs, with NO position axis.  Nothing is written at a
+cursor and nothing masks by one: a decode step moves the whole state on
+(``ops.kda_step``, in place), a prefill hands back the state after each
+row's own last token (:func:`prefill`'s ``logit_positions``), and whoever
+seats a request replaces the row's state whole.
 """
 
 from __future__ import annotations
@@ -58,20 +66,34 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
     Layers of different kinds (``cfg.kinds``) keep TWO kinds of leaves:
     ``k`` / ``v [full layers, B, Hkv, max_len, head_dim]`` and the window
     layers' rings ``k_ring`` / ``v_ring [window layers, B, Hkv, window,
-    head_dim]``, each stacked over its own layers in model order.
+    head_dim]``, each stacked over its own layers in model order.  Linear
+    layers (``cfg.linear``) add a third kind with NO position axis:
+    ``kda_state [linear layers, B, H, d, d]`` float32 and ``kda_conv
+    [linear layers, B, taps - 1, 3*H*d]``; ``max_len`` then sizes the
+    attention layers alone.
     """
+    full = cfg.kind_layers("full")
+    state = {}
+    if cfg.linear is not None:
+        la, n = cfg.linear, cfg.kind_layers("linear")
+        state = {
+            "kda_state": jnp.zeros(
+                (n, batch, la.n_heads, la.head_dim, la.head_dim), jnp.float32),
+            "kda_conv": jnp.zeros((n, batch, la.conv - 1, 3 * la.width),
+                                  cfg.compute_dtype)}
     if cfg.latent is not None:
         return {"ckv": jnp.zeros(
-            (cfg.n_layers, batch, 1, max_len, cfg.latent.cache_width),
-            cfg.compute_dtype)}
+            (full, batch, 1, max_len, cfg.latent.cache_width),
+            cfg.compute_dtype), **state}
     hd = cfg.head_dim
     if cfg.kinds is not None:
-        rings = cfg.window_layers(cfg.n_layers)
-        shapes = {"": (cfg.n_layers - rings, max_len),
-                  "_ring": (rings, cfg.kinds.window)}
-        return {name + kind: jnp.zeros((n, batch, cfg.n_kv_heads, t, hd),
-                                       cfg.compute_dtype)
-                for kind, (n, t) in shapes.items() for name in ("k", "v")}
+        shapes = {"": (full, max_len)}
+        if cfg.kinds.window is not None:
+            shapes["_ring"] = (cfg.kind_layers("ring"), cfg.kinds.window)
+        return {**{name + kind: jnp.zeros((n, batch, cfg.n_kv_heads, t, hd),
+                                          cfg.compute_dtype)
+                   for kind, (n, t) in shapes.items() for name in ("k", "v")},
+                **state}
     shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, hd)
     if cfg.kv_quant == "int8":
         return {
@@ -97,10 +119,19 @@ def init_rolling_cache(cfg: LlamaConfig, batch: int) -> dict:
 
 def cache_len(cache: dict) -> int:
     """Positions a cache's rows hold: the T axis sits at index 3 of every
-    leaf (the ring leaves of a ``cfg.kinds`` cache hold a window's; this
-    is the full layers' length)."""
-    return (cache["k"] if "k" in cache else
-            next(iter(cache.values()))).shape[3]
+    leaf that has one (the ring leaves of a ``cfg.kinds`` cache hold a
+    window's and a linear layer's state has none; this is the full
+    layers' length)."""
+    for name in ("k", "ckv"):
+        if name in cache:
+            return cache[name].shape[3]
+    return next(iter(cache.values())).shape[3]
+
+
+def is_state(name: str) -> bool:
+    """Whether a cache leaf is a linear layer's state: no position axis,
+    the whole of a row's entry is the request's (:func:`init_cache`)."""
+    return name.startswith("kda_")
 
 
 def ring_fold(a, lengths, window: int):
@@ -356,7 +387,10 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
     hooks are called with ``ring=True`` and ``layer`` counting the ring
     leaves' layers, a full layer's as ever with ``layer`` counting the
     full ones (a caller whose hooks take no ``ring`` serves no such
-    model).  Returns ``(h [B, C, D], cache, counts)``: the pairs each held
+    model).  A linear layer's state leaves (``kda_state`` / ``kda_conv``)
+    ride the carry too and NO hook is called for them: a state has no
+    cursor, the layer moves it on by one token itself (models/kda.py
+    ``kda_decode``, C = 1 only).  Returns ``(h [B, C, D], cache, counts)``: the pairs each held
     expert of each routed layer got, ``[routed layers, n_held]`` int32
     (None for a model with no routed layer).
     """
@@ -367,10 +401,21 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
         kind = {"ring": True} if ring else {}
         h, cache = carry
         x = rmsnorm(h, lp["attn_norm"], cfg.norm_eps)
-        if "wq_a" in lp:
+        if "kda" in lp:
+            from .kda import kda_decode
+
+            if C != 1:
+                raise ValueError(
+                    "a linear-attention layer's state moves one token a "
+                    "step: it cannot verify or ingest a chunk of C > 1")
+            # No cursor and no hook: the state has no position to write at.
+            o, cache = kda_decode(x, lp["kda"], cfg, cache, li)
+        elif "wkv_a" in lp:
             from .mla import expand_values, project_absorbed
 
-            q, rows = project_absorbed(x, lp, cfg, cos_p, sin_p)
+            q, rows = project_absorbed(x, lp, cfg,
+                                       *((cos_p, sin_p) if rope
+                                         else (None, None)))
             cache = write(cache, {"ckv": rows}, li)
             o = expand_values(attend(q, cache, li), lp, cfg)
         else:
@@ -399,9 +444,8 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
         body = layer
         if cfg.kinds is not None:
             # The layers of this segment among those of their cache kind.
-            window, rope = segment_kind(cfg, seg, first)
-            before = cfg.window_layers(first)
-            first = before if window is not None else first - before
+            window, rope, _linear = segment_kind(cfg, seg, first)
+            first = cfg.kind_layers(cfg.cache_kind(first), first)
             body = functools.partial(layer, rope=rope, ring=window is not None)
         carry, ys = scan_segment(
             body, carry, seg, first + jnp.arange(n, dtype=jnp.int32))
@@ -429,7 +473,11 @@ def prefill(params: dict, cfg: LlamaConfig, prompt,
     A ``cfg.kinds`` model's window layers come back as RINGS
     (:func:`ring_fold`: each row's last ``window`` real positions at their
     residues, a row's length being ``logit_positions + 1``, else P), its
-    full layers padded to ``max_len``: :func:`init_cache`'s two kinds.
+    full layers padded to ``max_len``: :func:`init_cache`'s two kinds.  Its
+    linear layers (``cfg.linear``) come back as the STATE after each row's
+    own last token, ``logit_positions + 1`` long: the positions behind it
+    do not move the state and stay out of the convolutions' tails, so a
+    padded bucket leaves what the unpadded prompt leaves.
     """
     B, P = prompt.shape
     if max_len is None:
@@ -439,7 +487,9 @@ def prefill(params: dict, cfg: LlamaConfig, prompt,
     logits, _aux, kv = forward(
         params, prompt, cfg, attn_fn, return_aux=True, return_kv=True,
         last_only=logit_positions is None, logit_positions=logit_positions,
+        lengths=None if logit_positions is None else logit_positions + 1,
     )
+    state = {name: kv.pop(name) for name in list(kv) if is_state(name)}
     cache = dict(kv)
     if cfg.kv_quant == "int8":
         from ..ops.quantize import quantize_kv
@@ -460,7 +510,7 @@ def prefill(params: dict, cfg: LlamaConfig, prompt,
             lambda a: jnp.pad(
                 a, ((0, 0),) * 3 + ((0, pad),) + ((0, 0),) * (a.ndim - 4)),
             cache)
-    return logits[:, 0], {**cache, **rings}
+    return logits[:, 0], {**cache, **rings, **state}
 
 
 def prefill_rolling(params: dict, cfg: LlamaConfig, prompt, *,

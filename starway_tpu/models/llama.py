@@ -39,8 +39,12 @@ class LatentAttn:
     """Attention kind: multi-head latent attention (models/mla.py).  Low-rank
     q and kv projections, heads split into a no-rope and a rope part, one
     rope key head shared by all heads; the cache holds ``kv_rank +
-    rope_dim`` values a token a layer."""
-    q_rank: int
+    rope_dim`` values a token a layer.  ``q_rank`` None: the queries come
+    from ONE projection ``wq`` (``q_lora_rank: null``), no low-rank pair
+    and no norm between.  Whether the rope part is rotated at all is the
+    layer's kind (``LayerKinds.rope``: a NoPE latent layer caches ``k_pe``
+    as projected)."""
+    q_rank: Optional[int]
     kv_rank: int
     nope_dim: int
     rope_dim: int
@@ -56,6 +60,26 @@ class LatentAttn:
         out so whatever is asked for (576 -> 640), and its compiler takes
         no DMA of part of a tile: the padding is made explicit."""
         return -(-(self.kv_rank + self.rope_dim) // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearAttn:
+    """Attention kind: gated delta-rule linear attention with a decay a
+    channel (KDA; models/kda.py).  ``n_heads`` heads whose keys and values
+    are both ``head_dim`` wide; q, k and v each pass a depthwise causal
+    convolution of ``conv`` taps; the decay and the output gate come
+    through low-rank maps of rank ``head_dim``.  A layer of this kind keeps
+    no keys and no values: its state a request is one ``[head_dim,
+    head_dim]`` float32 matrix a head and the last ``conv - 1`` inputs of
+    the convolutions, whatever the length."""
+    n_heads: int
+    head_dim: int
+    conv: int = 4
+
+    @property
+    def width(self) -> int:
+        """Channels of each of q, k and v."""
+        return self.n_heads * self.head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,34 +125,49 @@ class RoutedFFN:
 @dataclasses.dataclass(frozen=True)
 class LayerKinds:
     """Attention kinds that differ by layer, on a period: layer ``i`` has
-    the window ``windows[i % period]`` (None: full causal attention) and
+    the window ``windows[i % period]`` (None: full causal attention),
     rotates q and k where ``rope[i % period]`` (False: no positional
-    encoding, NoPE).  Layers of one kind in a row are one SEGMENT of the
-    stacked tree (``layer_segments``).  The windows of a model are of one
-    length: its cache then holds the full layers' rows of ``max_len``
-    beside the window layers' RINGS of that length
+    encoding, NoPE) and is a LINEAR layer (``cfg.linear``: models/kda.py)
+    where ``linear[i % period]``; the others attend, latent where
+    ``cfg.latent`` is set and grouped-query else.  Layers of one kind in a
+    row are one SEGMENT of the stacked tree (``layer_segments``).  The
+    windows of a model are of one length: its cache then holds the full
+    layers' rows of ``max_len`` beside the window layers' RINGS of that
+    length and the linear layers' STATE, which has no position axis at all
     (models/generate.py::init_cache).  A model whose every layer has the
     same window is ``LlamaConfig.sliding_window``'s, not this group's."""
     windows: tuple
     rope: tuple
+    linear: tuple = ()
 
     def __post_init__(self):
         object.__setattr__(self, "windows", tuple(self.windows))
         object.__setattr__(self, "rope", tuple(bool(r) for r in self.rope))
-        if not self.windows or len(self.windows) != len(self.rope):
+        object.__setattr__(self, "linear", tuple(
+            bool(x) for x in (self.linear or (False,) * len(self.windows))))
+        if not self.windows or not (len(self.windows) == len(self.rope)
+                                    == len(self.linear)):
             raise ValueError(
-                f"LayerKinds needs one window (or None) and one rope flag a "
-                f"layer of the period, got {self.windows} / {self.rope}")
+                f"LayerKinds needs one window (or None), one rope flag and "
+                f"one linear flag a layer of the period, got {self.windows} "
+                f"/ {self.rope} / {self.linear}")
         sizes = {w for w in self.windows if w is not None}
-        if len(sizes) != 1 or min(sizes) < 1 or None not in self.windows:
+        if any(self.linear):
+            if sizes or all(self.linear):
+                raise ValueError(
+                    f"LayerKinds with linear layers needs attention layers "
+                    f"beside them and no window, got {self.windows} / "
+                    f"{self.linear}")
+        elif len(sizes) != 1 or min(sizes) < 1 or None not in self.windows:
             raise ValueError(
                 f"LayerKinds needs full layers (None) beside window layers "
                 f"of ONE length >= 1, got {self.windows}")
 
     @property
-    def window(self) -> int:
-        """The window layers' one length: the ring's."""
-        return next(w for w in self.windows if w is not None)
+    def window(self) -> Optional[int]:
+        """The window layers' one length: the ring's (None: no window
+        layer)."""
+        return next((w for w in self.windows if w is not None), None)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -210,15 +249,22 @@ class LlamaConfig:
     latent: Optional[LatentAttn] = None
     routed: Optional[RoutedFFN] = None
     kinds: Optional[LayerKinds] = None
+    linear: Optional[LinearAttn] = None
 
     def __post_init__(self):
-        if self.kinds is not None and (
-                self.sliding_window is not None or self.latent is not None
-                or self.kv_quant != "none"):
+        has_linear = self.kinds is not None and any(self.kinds.linear)
+        if has_linear != (self.linear is not None):
             raise ValueError(
-                "kinds (window and RoPE by layer) goes with grouped-query "
-                "attention over a bf16/f32 cache and no whole-model "
-                "sliding_window")
+                "linear (the linear-attention layers' sizes) goes with "
+                "kinds.linear (which layers they are), and the other way")
+        if self.kinds is not None and (
+                self.sliding_window is not None or self.kv_quant != "none"
+                or (self.latent is not None and not has_linear)):
+            raise ValueError(
+                "kinds (window, RoPE and linear by layer) goes with a "
+                "bf16/f32 cache and no whole-model sliding_window; latent "
+                "attention beside it only as the attention layers of a "
+                "model with linear layers")
         if self.sliding_window is not None and self.sliding_window < 1:
             raise ValueError(
                 f"sliding_window must be >= 1, got {self.sliding_window}")
@@ -296,16 +342,28 @@ class LlamaConfig:
         return jnp.dtype(self.dtype)
 
     def layer_kind(self, i: int) -> tuple:
-        """``(window or None, rotates q/k)`` of layer ``i``."""
+        """``(window or None, rotates q/k, linear)`` of layer ``i``."""
         if self.kinds is None:
-            return self.sliding_window, True
+            return self.sliding_window, True, False
         at = i % len(self.kinds.windows)
-        return self.kinds.windows[at], self.kinds.rope[at]
+        return (self.kinds.windows[at], self.kinds.rope[at],
+                self.kinds.linear[at])
 
-    def window_layers(self, upto: int) -> int:
-        """Layers with a window among the first ``upto``: a layer's index
-        among those of its cache kind (generate.init_cache) follows."""
-        return sum(self.layer_kind(i)[0] is not None for i in range(upto))
+    def cache_kind(self, i: int) -> str:
+        """Which leaves of the cache layer ``i`` keeps its state in:
+        ``"linear"`` (a matrix a head, no position axis), ``"ring"`` (one
+        window's positions) or ``"full"`` (a row a position)."""
+        window, _rope, linear = self.layer_kind(i)
+        return ("linear" if linear else
+                "ring" if window is not None and self.kinds is not None
+                else "full")
+
+    def kind_layers(self, kind: str, upto: Optional[int] = None) -> int:
+        """Layers of that cache kind among the first ``upto`` (default:
+        all): a layer's index among those of its kind, which is where it
+        lies in that kind's stacked leaves (generate.init_cache)."""
+        upto = self.n_layers if upto is None else upto
+        return sum(self.cache_kind(i) == kind for i in range(upto))
 
     def kind_runs(self) -> list:
         """``[(first layer, layers)]`` of the runs of layers of one kind,
@@ -366,9 +424,9 @@ def layer_segments(layers) -> list:
 
 
 def segment_kind(cfg: "LlamaConfig", seg, first: int) -> tuple:
-    """``(window or None, rotates q/k)`` of the segment whose first layer
-    is ``first``: every layer of a segment is of one kind, so that one
-    scan body serves it (``LlamaConfig.segment_plan`` cuts them so)."""
+    """``(window or None, rotates q/k, linear)`` of the segment whose first
+    layer is ``first``: every layer of a segment is of one kind, so that
+    one scan body serves it (``LlamaConfig.segment_plan`` cuts them so)."""
     n = jax.tree_util.tree_leaves(seg)[0].shape[0]
     kinds = {cfg.layer_kind(i) for i in range(first, first + n)}
     if len(kinds) != 1:
@@ -421,17 +479,27 @@ def _init_block_params(key, cfg: LlamaConfig) -> tuple:
                 "w_up": norm(ks[1], (L, *lead, D, F), D**-0.5),
                 "w_down": norm(ks[2], (L, *lead, F, D), F**-0.5)}
 
-    def segment(k, L, routed: bool):
+    def segment(k, L, routed: bool, linear: bool):
         ks = jax.random.split(k, 8)
         seg = {"attn_norm": jnp.ones((L, D), dt),
                "mlp_norm": jnp.ones((L, D), dt)}
         la = cfg.latent
-        if la is not None:
+        if linear:
+            from .kda import init_kda_params
+
+            seg["kda"] = init_kda_params(ks[0], L, cfg)
+            seg["wo"] = norm(ks[4], (L, cfg.linear.width, D),
+                             cfg.linear.width**-0.5)
+        elif la is not None:
+            hq = H * (la.nope_dim + la.rope_dim)
+            if la.q_rank is None:
+                seg.update(wq=norm(ks[0], (L, D, hq), D**-0.5))
+            else:
+                seg.update(
+                    wq_a=norm(ks[0], (L, D, la.q_rank), D**-0.5),
+                    q_norm=jnp.ones((L, la.q_rank), dt),
+                    wq_b=norm(ks[1], (L, la.q_rank, hq), la.q_rank**-0.5))
             seg.update(
-                wq_a=norm(ks[0], (L, D, la.q_rank), D**-0.5),
-                q_norm=jnp.ones((L, la.q_rank), dt),
-                wq_b=norm(ks[1], (L, la.q_rank, H * (la.nope_dim + la.rope_dim)),
-                          la.q_rank**-0.5),
                 wkv_a=norm(ks[2], (L, D, la.kv_rank + la.rope_dim), D**-0.5),
                 kv_norm=jnp.ones((L, la.kv_rank), dt),
                 wkv_b=norm(ks[3], (L, la.kv_rank, H * (la.nope_dim + la.v_dim)),
@@ -466,7 +534,8 @@ def _init_block_params(key, cfg: LlamaConfig) -> tuple:
         k = jax.random.fold_in(key, 31 + routed)   # as before cfg.kinds
         return k if cfg.kinds is None else jax.random.fold_in(k, first)
 
-    return tuple(segment(key_of(first, routed), n, routed)
+    return tuple(segment(key_of(first, routed), n, routed,
+                         cfg.layer_kind(first)[2])
                  for first, n, routed in cfg.segment_plan())
 
 
@@ -531,9 +600,9 @@ def param_specs(cfg: LlamaConfig) -> dict:
             or cfg.kinds is not None):
         raise NotImplementedError(
             "latent attention, the routed FFN and attention kinds by layer "
-            "have no sharding rules yet: they serve on one chip (the routed "
-            "FFN as one holder of an expert-parallel deployment; ROADMAP "
-            "M1, M2)")
+            "(linear layers among them) have no sharding rules yet: they "
+            "serve on one chip (the routed FFN as one holder of an "
+            "expert-parallel deployment; ROADMAP M1, M2, M4)")
     layers = {
         "wq": P(None, None, "tp"),
         "wk": P(None, None, "tp"),
@@ -792,7 +861,9 @@ def resolve_attn_fn(cfg: LlamaConfig, attn_fn: Optional[Callable]) -> Callable:
             raise ValueError(
                 "cfg.kinds gives each layer its own attention (a window or "
                 "none): attn_fn must be None")
-        return self_attention  # forward binds each segment's window
+        # forward binds each segment's window
+        return (self_attention if cfg.latent is None else
+                partial(self_attention, sm_scale=cfg.latent.sm_scale))
     if attn_fn is None:
         if cfg.latent is not None:
             return partial(self_attention, sm_scale=cfg.latent.sm_scale)
@@ -907,14 +978,17 @@ def ffn_block(x, lp, cfg: "LlamaConfig", moe_fn: Optional[Callable] = None,
 
 
 def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
-                  attn_fn: Callable, moe_fn: Optional[Callable] = None):
+                  attn_fn: Callable, moe_fn: Optional[Callable] = None,
+                  lengths=None):
     """One pre-norm decoder block on ``h [B, S, D]`` with layer params
     ``lp`` (one slice of a stacked segment); ``cos``/``sin`` None: a layer
     that does not rotate q and k (NoPE).  Returns
     ``(h, aux, kv, stats)`` — aux is the MoE balance term (0 for dense),
     kv what the cache holds of these positions, under the cache's own
     keys (``k`` / ``v``: the post-RoPE grouped heads; latent attention:
-    ``ckv``, models/mla.py), stats the capacity MoE's router-health dict
+    ``ckv``, models/mla.py; a linear layer: ``kda_state`` / ``kda_conv``,
+    its state after each row's first ``lengths[b]`` positions, default
+    all S: models/kda.py), stats the capacity MoE's router-health dict
     when ``moe_fn`` returns one (``with_stats=True`` builders), else None.
     Shared by the scan forward, and the pipeline-parallel stage body
     (models/pp_llama.py)."""
@@ -934,7 +1008,7 @@ def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
 
     def pre(h, lp):
         x = rmsnorm(h, lp["attn_norm"], cfg.norm_eps)
-        if "wq_a" in lp:
+        if "wkv_a" in lp:
             from .mla import project_expanded
 
             q, k, v, rows = project_expanded(x, lp, cfg, cos, sin)
@@ -975,6 +1049,15 @@ def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
                 jax.checkpoint_policies.save_only_these_names(
                     "mlp_gate", "mlp_up")))
 
+    if "kda" in lp:
+        from .kda import kda_prefill
+
+        # No keys, no values, no attention call: the layer's own chunked
+        # recurrence (ops.kda_chunk) and the state it leaves behind.
+        x = rmsnorm(h, lp["attn_norm"], cfg.norm_eps)
+        o, kv = kda_prefill(x, lp["kda"], cfg, lengths)
+        h, aux, stats = post(h, o, lp, None)
+        return h, aux, kv, stats
     q, k, v, kv, attn_in = pre(h, lp)
     o = attn_fn(q, k, v)  # [B, H, S, Dh]
     # Tag kept for user-supplied whole-model remat policies; the flash
@@ -988,7 +1071,7 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
             attn_fn: Optional[Callable] = None, *, return_aux: bool = False,
             moe_fn: Optional[Callable] = None, return_kv: bool = False,
             last_only: bool = False, logit_positions=None,
-            return_moe_stats: bool = False):
+            return_moe_stats: bool = False, lengths=None):
     """Next-token logits ``[B, S, V]`` for token ids ``[B, S]``.
 
     ``return_kv`` additionally returns what the cache holds of every
@@ -1006,6 +1089,11 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
     one caller-chosen position per row.  Return value is ``logits``,
     extended to a tuple ``(logits[, aux][, moe_stats][, (k, v)])`` by
     ``return_aux`` / ``return_moe_stats`` / ``return_kv``.
+
+    ``lengths`` ([B] ints; default: every row is S long) says how many
+    positions of each right-padded row are real, for the layers whose state
+    is not a row a position (``cfg.linear``): a pad must not move it.
+    Attention layers need no telling, a pad lies behind every real query.
 
     ``attn_fn(q, k, v) -> out`` takes q ``[B, Hq, S, Dh]`` and *grouped*
     kv ``[B, Hkv, S, Dh]`` (impls expand GQA heads internally); defaults to
@@ -1044,8 +1132,8 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
 
         def layer(carry, lp):
             h, aux = carry
-            h, layer_aux, kv, stats = decoder_layer(lp, h, cfg, *tables,
-                                                    attend, moe_fn=moe_fn)
+            h, layer_aux, kv, stats = decoder_layer(
+                lp, h, cfg, *tables, attend, moe_fn=moe_fn, lengths=lengths)
             if return_moe_stats and stats is None:
                 raise ValueError("return_moe_stats=True but moe_fn returned "
                                  "no stats (build it with with_stats=True)")
@@ -1060,7 +1148,7 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
     # dense layer before the expert layers) or in their attention kind
     # (cfg.kinds); the body reads a layer's kind off its leaves.
     for seg, first in layer_segments(params["layers"]):
-        window, rope = segment_kind(cfg, seg, first)
+        window, rope, _linear = segment_kind(cfg, seg, first)
         body = layer_of(window, rope)
         if cfg.scan_layers:
             carry, ys = scan_segment(body, carry, seg)

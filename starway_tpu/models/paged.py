@@ -267,6 +267,12 @@ class PagedSlotServer(SlotServer):
                  top_p: Optional[float] = None,
                  eos_id: Optional[int] = None, seed: int = 0,
                  on_tokens=None):
+        if cfg.linear is not None:
+            raise NotImplementedError(
+                "the page pool holds rows a position: a linear-attention "
+                "layer's state (cfg.linear) is one matrix a request, with "
+                "nothing to page; such a model serves through the dense "
+                "SlotServer (ROADMAP M4: state in the pool)")
         if cfg.sliding_window is not None or cfg.kinds is not None:
             raise NotImplementedError(
                 "paged serving v1 is full-causal; sliding-window models "

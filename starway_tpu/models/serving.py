@@ -36,13 +36,17 @@ a FIXED, small set of compiled programs:
   samples the first token and seats the slot inside the scan.  One
   blocking read a step.
 * **Admission = bucketed prefill** (every other kind: an int8 cache, a
-  latent cache, a rolling window, rings beside full rows, the page pool,
-  a ``prefix=`` request).  A new
+  latent cache, a rolling window, rings beside full rows, a linear
+  layer's state, the page pool, a ``prefix=`` request).  A new
   request's prompt is right-padded to a power-of-two bucket and prefilled
   in its own dispatch (one compile per bucket), then its kv rows are
-  written into the slot with a dynamic slice.  Pad/garbage columns are
-  never read: attention masks by the slot's cursor, and decode overwrites
-  each position before the cursor reaches it (write-then-attend).
+  written into the slot with a dynamic slice.  Pad/garbage columns of a
+  cache ROW are never read: attention masks by the slot's cursor, and
+  decode overwrites each position before the cursor reaches it
+  (write-then-attend).  A linear-attention layer's STATE has no cursor to
+  hide a pad behind: its prefill is told the prompt's own length, the
+  pads stand still (``generate.prefill``), and the admission REPLACES the
+  slot's state whole, so nothing of the request before lives on in it.
 * **A step queues, then fetches.**  On that path ``step()`` launches every
   admission it makes and then the chunk, back to back, with no device
   value read in between: an admit program's sampled token stays on the
@@ -83,7 +87,11 @@ layer's last ``window`` prompt positions into the slot's ring
 (``generate.ring_fold``).  MoE models serve when capacity is provably dropless
 (``moe_capacity_factor >= n_experts``): expert capacity is shared
 batch-wide, so slot cohabitation could otherwise perturb routing — the
-same rule as ragged ``generate()``.
+same rule as ragged ``generate()``.  A model with linear-attention
+layers (``cfg.linear``: a matrix a head a request, not a row a token)
+serves through the same dense programs with a third kind of leaf beside
+its attention layers' rows; its admit programs seat the state after the
+prompt's own last token.
 """
 
 from __future__ import annotations
@@ -104,7 +112,7 @@ from .. import perf
 from ..core import swtrace
 from .generate import (_sample, cache_len, decode_step_counted,
                        ingest_decode_step, init_cache, init_rolling_cache,
-                       prefill)
+                       is_state, prefill)
 from .llama import LlamaConfig, cfg_rope_tables
 
 # ----------------------------------------------------------- the serve logs
@@ -182,7 +190,11 @@ def step_log() -> list:
     attends in one full layer (``pos + 1``) and in one window layer
     (``min(pos + 1, window)``), summed over the slots that decode, from
     the cursors the host holds (each grows by one a step inside the chunk
-    while its slot lives)."""
+    while its slot lives).  A server whose cache holds linear-attention
+    state (``cfg.linear``) adds ``state_slots`` (the slots that decode:
+    each one's state, every linear layer, is read and written once a step)
+    and ``kv_rows_latent`` (``pos + 1`` summed over them: the cache rows
+    one attention layer's step attends), from the same cursors."""
     return [dict(row) for row in list(_step_log)]
 
 
@@ -220,12 +232,18 @@ def _write_slot_and_sample(cache, small, logits, slot, key, temperature,
     """Shared tail of BOTH admission paths: file one request's [L, 1, Hkv,
     T', D] cache rows into the slot and sample its first token.  Writes
     every cache leaf — the int8 format's [L, 1, Hkv, T'] scale arrays ride
-    along (the slot axis sits at index 1 in all of them)."""
-    cache = {
-        name: lax.dynamic_update_slice(
-            cache[name], small[name], (0, slot) + (0,) * (cache[name].ndim - 2))
-        for name in cache
-    }
+    along (the slot axis sits at index 1 in all of them).  A linear
+    layer's state leaves (no position axis) are REPLACED whole for the
+    slot, under a name of their own in the trace (``sw_kda_seat``)."""
+    def put(name):
+        return lax.dynamic_update_slice(
+            cache[name], small[name].astype(cache[name].dtype),
+            (0, slot) + (0,) * (cache[name].ndim - 2))
+
+    rows = {name: put(name) for name in cache if not is_state(name)}
+    with jax.named_scope("sw_kda_seat"):
+        cache = {**rows,
+                 **{name: put(name) for name in cache if is_state(name)}}
     tok = _sample(logits, key, temperature, top_k, top_p)[0]
     return cache, tok
 
@@ -252,12 +270,18 @@ _seat = _named_jit(lambda *state_tok_seat: _seat_state(*state_tok_seat),
 def _compiled_admit(cfg: LlamaConfig, p_bucket: int, temperature: float,
                     top_k: Optional[int], top_p: Optional[float]):
     """Prefill one request into one slot: returns the updated cache and the
-    request's FIRST generated token.  One compile per prompt bucket."""
+    request's FIRST generated token.  One compile per prompt bucket.  What
+    the bucket's pads leave behind is harmless by KIND, not in general:
+    rows of a cache are hidden by the cursor, a state is computed without
+    them (``run``'s comment)."""
 
     def run(params, cache, prompt, length, slot, key):
         # prompt [1, p_bucket] right-padded; ragged single-row prefill.
-        # Columns >= length hold pad-garbage that is overwritten (position
-        # by position) before the cursor lets attention read it.
+        # In a cache ROW, columns >= length hold pad-garbage that is
+        # overwritten (position by position) before the cursor lets
+        # attention read it.  A linear layer's STATE has no such cover: it
+        # comes back as it stood after position length - 1 (the pads do
+        # not move it) and replaces the slot's.
         logits, small = prefill(params, cfg, prompt, p_bucket,
                                 logit_positions=length[None] - 1)
         return _write_slot_and_sample(cache, small, logits, slot, key,
@@ -645,6 +669,8 @@ class SlotServer:
         # Positions a window layer's ring holds, 0 with no ring leaves.
         self._ring = (self.cache["k_ring"].shape[3]
                       if "k_ring" in self.cache else 0)
+        # Whether it holds linear-attention state (no position axis).
+        self._state = any(is_state(name) for name in self.cache)
         # How a prompt enters that cache: () = by an admit program.
         self._widths = self._ingest_widths()
         self.token = jnp.zeros((n_slots,), jnp.int32)
@@ -705,7 +731,9 @@ class SlotServer:
         :meth:`_plan_ingest`: the last is the first at which the longest
         prompt this cache holds comes in within one chunk).  Every other
         kind keeps its admit programs and gets ``()``: a latent cache
-        (``ckv``), a rolling window, rings beside the full rows (``k_ring``:
+        (``ckv``), a linear layer's state beside it (``kda_state``: a piece
+        would have to move the state on by W tokens inside a decode step),
+        a rolling window, rings beside the full rows (``k_ring``:
         a piece would have to attend over a ring its own later tokens
         overwrite), an int8 cache (a piece attends over
         what the cache holds, quantized there, where a prefill reads the
@@ -768,6 +796,11 @@ class SlotServer:
             raise ValueError("prefix caching needs the dense slot cache; "
                              "rolling (sliding-window) slots rebuild their "
                              "window per request anyway")
+        if self._state:
+            raise ValueError("prefix caching copies cache rows by position; "
+                             "a linear-attention layer's state has none: a "
+                             "prefix would need a snapshot of the state at "
+                             "its end (ROADMAP M4)")
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         if len(tokens) < 1:
             raise ValueError("empty prefix")
@@ -1083,11 +1116,16 @@ class SlotServer:
                 step["admits"] += 1
             step["queued"], step["live"] = (len(self._pending),
                                             len(self._slot_rid))
-            if self._ring:
+            if self._ring or self._state:
                 at = 1 + self._pos_host[[s for s in self._slot_rid
                                          if self._live_host[s]]].astype(int)
-                step.update(kv_rows_full=int(at.sum()),
-                            kv_rows_window=int(np.minimum(at, self._ring).sum()))
+                if self._ring:
+                    step.update(
+                        kv_rows_full=int(at.sum()),
+                        kv_rows_window=int(np.minimum(at, self._ring).sum()))
+                else:
+                    step.update(state_slots=len(at),
+                                kv_rows_latent=int(at.sum()))
             # A slot the host knows dead already (a one-token request just
             # admitted) needs no chunk; one whose first token may be its
             # eos is found out after the chunk was queued, and rides it

@@ -70,6 +70,11 @@ def chunk_decode_step(params, cache, tokens, pos, cfg: LlamaConfig, rope):
     """
     B, C = tokens.shape
     T_cache = cache_len(cache)
+    if "kda_state" in cache:
+        raise ValueError(
+            "chunk_decode_step does not support linear-attention layers "
+            "(cfg.linear): a state moved on by C tokens cannot be taken "
+            "back to where the accepted ones end (ROADMAP M4: snapshots)")
     if "k_ring" in cache:
         raise ValueError(
             "chunk_decode_step does not support rings (cfg.kinds): a chunk "

@@ -12,8 +12,9 @@ i.e. CollectivePermute; Ulysses = all-to-all composed from P2P").
 Every operation that has a Pallas kernel is ONE function here, and that
 function alone decides what runs (ops/dispatch.py): ``self_attention``,
 ``cached_attention``, ``ingest_attention``, ``latent_attention``,
-``cache_write``, ``paged_attention``, ``grouped_matmul``, ``quantized_matmul`` and the ring
-step ``ring_step`` / ``ring_step_bwd``.  Each lives in the file that holds
+``cache_write``, ``paged_attention``, ``grouped_matmul``,
+``quantized_matmul``, the linear-attention recurrence ``kda_step`` /
+``kda_chunk`` and the ring step ``ring_step`` / ``ring_step_bwd``.  Each lives in the file that holds
 its kernel, beside its ``*_lax`` twin.
 """
 
@@ -30,6 +31,7 @@ from .pallas_decode import (cache_write, cached_attention, ingest_attention,
                             latent_attention)
 from .pallas_gemv import quantized_matmul
 from .pallas_gmm import GATE_ACTS, grouped_matmul
+from .pallas_kda import kda_chunk, kda_step
 from .pallas_paged import paged_attention
 from .quantize import quantize_params
 
@@ -38,4 +40,5 @@ __all__ = ["ring_shift", "all_to_all", "all_gather", "psum",
            "self_attention", "cached_attention", "ingest_attention",
            "latent_attention",
            "cache_write", "paged_attention", "grouped_matmul", "GATE_ACTS",
-           "quantized_matmul", "ring_step", "ring_step_bwd"]
+           "quantized_matmul", "kda_step", "kda_chunk", "ring_step",
+           "ring_step_bwd"]

@@ -216,6 +216,26 @@ def _gmm(pairs, tile_m, k, n, gated, held=None, act="silu"):
         x, w, te, nl, tile_m=tile_m, interpret=False)), args
 
 
+def _kda_step(b=256, layers=6, h=32, d=128):
+    from starway_tpu.ops.pallas_kda import kda_step_kernel
+
+    vec = _s((b, h, d), F32)
+    return (lambda st, q, k, v, g, beta, layer: kda_step_kernel(
+        st, q, k, v, g, beta, layer=layer, interpret=False)), (
+        _s((layers, b, h, d, d), F32), vec, vec, vec, vec, _s((b, h), F32),
+        _s((), I32))
+
+
+def _kda_chunk_carry(positions=1024, h=32, d=128, c=64):
+    from starway_tpu.ops.pallas_kda import kda_chunk_carry_kernel
+
+    n = positions // c
+    rows = _s((1, h, n, c, d), F32)
+    return (lambda *a: kda_chunk_carry_kernel(*a, interpret=False)), (
+        rows, rows, rows, _s((1, h, n, c, c), F32), rows,
+        _s((1, h, n, 1, d), F32))
+
+
 KERNELS = {
     "flash_fwd": lambda: _flash(HKV),
     "flash_fwd_windowed": lambda: _flash(HKV, window=1024),
@@ -275,6 +295,14 @@ KERNELS = {
     "gmm_relu_decode": lambda: _gmm(288, 16, 2560, 768, True, 64, "relu"),
     "gmm_relu_admit": lambda: _gmm(86016, 128, 2560, 768, True, 64, "relu"),
     "gmm_down_longdoc": lambda: _gmm(288, 16, 768, 2560, False, 64),
+    # kimi-linear.reason_closed: 256 slots' state of 32 heads x 128 x 128
+    # float32 in six stacked layers, by a traced index; a 1,024- and a
+    # 4,096-token admission's chunk-to-chunk carry; 256 x 8 pairs a decode
+    # step on 16 held experts of width 1024.
+    "kda_step_reason": lambda: _kda_step(),
+    "kda_chunk_carry_1024": lambda: _kda_chunk_carry(1024),
+    "kda_chunk_carry_4096": lambda: _kda_chunk_carry(4096),
+    "gmm_gated_reason": lambda: _gmm(2048, 16, 2304, 1024, True, 16),
 }
 
 
@@ -590,6 +618,53 @@ def test_two_cache_kinds_ride_the_decode_chunk_for_v5e(topo, monkeypatch):
         shaped = re.compile(
             rf"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[(?:{layers},|1,)?"
             + re.escape(f"{n_slots},4,{t},128]") + r"\S* ([\w\-]+)\(")
+        moved += [(mm.group(1), mm.group(2))
+                  for mm in map(shaped.match, text.splitlines())
+                  if mm and mm.group(2) not in {
+                      "parameter", "get-tuple-element", "bitcast",
+                      "custom-call"}]
+    assert moved == []
+
+
+def test_state_without_positions_rides_the_decode_chunk_for_v5e(topo,
+                                                                monkeypatch):
+    """kimi-linear.reason_closed's decode chunk at the cell's shapes (the
+    6 linear layers' state of 256 slots beside the 2 latent layers' rows
+    of 6,144): all three kinds of leaves ride the scans' carries; the
+    state is moved in place by ``sw_kda_step`` and no instruction copies
+    an array of the state's or of the latent rows' shape, whole or one
+    layer of it (a second copy of the state would be 3.2 GB)."""
+    import re
+
+    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.serving import _compiled_chunk
+
+    _as_tpu(monkeypatch)
+    cfg, params, n_slots, max_len = _cell_model("kimi-linear")
+    assert max_len == 6144
+    cache = jax.eval_shape(lambda: init_cache(cfg, n_slots, max_len))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "ckv": (2, n_slots, 1, 6144, 640),
+        "kda_state": (6, n_slots, 32, 128, 128),
+        "kda_conv": (6, n_slots, 3, 3 * 4096)}
+    run = _compiled_chunk(cfg, n_slots, max_len, CHUNK, 0.0, None, None, None)
+    compiled = run.lower(*_placed(
+        (params, cache, *_slot_state(n_slots)),
+        SingleDeviceSharding(topo.devices[0]))).compile()
+    text = compiled.as_text()
+    for name in ("sw_kda_step", "sw_mla_decode_attn", "sw_kv_write",
+                 "sw_moe_gmm"):
+        assert name in text, name
+    state_bytes = cache["kda_state"].size * 4
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < state_bytes / 4        # 0.4 GB of 3.2
+    assert m.alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(cache))
+    moved = []
+    for shape in (f"{n_slots},32,128,128]", f"{n_slots},1,6144,640]"):
+        shaped = re.compile(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[(?:\d+,)?"
+            + re.escape(shape) + r"\S* ([\w\-]+)\(")
         moved += [(mm.group(1), mm.group(2))
                   for mm in map(shaped.match, text.splitlines())
                   if mm and mm.group(2) not in {

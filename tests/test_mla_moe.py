@@ -442,3 +442,76 @@ def test_prefix_admission_reads_the_latent_cache(ref, runner):
     gaps = ref.served_gaps(TINY, SEED, [(np.concatenate([prefix, suffix]), got)],
                            96, 8)
     assert gaps["gap_max"] < 1e-5, gaps
+
+
+# ------------------- a direct q projection and no rotation (Kimi-Linear's)
+
+
+def test_nope_latent_with_a_direct_q_matches_its_reference():
+    """``q_rank=None`` (one ``wq``, no low-rank pair) and a layer that does
+    not rotate (``cos`` None): the expanded form equals the kimi-linear
+    reference's latent attention, the absorbed form over the cached rows
+    equals it too, and the cached ``k_pe`` is the projection itself."""
+    from benchmark.harness import weights_kda_mla_moe as WK
+    from starway_tpu.models import mla
+    from starway_tpu.models.llama import cfg_rope_tables, rmsnorm
+    from starway_tpu.ops import latent_attention, self_attention
+
+    from test_kda import TINY as KL
+
+    ref = S.load_reference("kimi-linear")
+    cfg = S.load_runner("serve_kda_mla_moe").model_config(KL)
+    d = WK.dims(KL)
+    lp = WK.layer_weights(WK.base_key(SEED), 3, d, False, True)
+    assert "wq" in lp and "wq_a" not in lp and cfg.latent.q_rank is None
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 21, 64))
+    want = ref._latent(x[0], lp, d, None)
+
+    q, k, v, rows = mla.project_expanded(x, lp, cfg, None, None)
+    o = self_attention(q, k, v, sm_scale=cfg.latent.sm_scale)
+    got = o.transpose(0, 2, 1, 3).reshape(1, 21, -1) @ lp["wo"]
+    np.testing.assert_allclose(got[0], want, rtol=2e-4, atol=2e-4)
+    # The row cached is [norm(c_kv) | k_pe] as projected, zeros above.
+    kv = x @ lp["wkv_a"]
+    np.testing.assert_allclose(rows[0, 0, :, 32:40], kv[0, :, 32:], rtol=1e-6)
+    np.testing.assert_allclose(
+        rows[0, 0, :, :32], rmsnorm(kv[0, :, :32], lp["kv_norm"], cfg.norm_eps),
+        rtol=1e-5, atol=1e-6)
+    assert not np.asarray(rows[..., 40:]).any()
+    # With tables the same layer would cache another k_pe: NoPE is a choice.
+    turned = mla.latent_rows(x, lp, cfg, *cfg_rope_tables(cfg, 21))
+    assert np.abs(np.asarray(turned - rows))[0, 0, 1:, 32:40].max() > 1e-3
+
+    cache = jnp.pad(rows, ((0, 0), (0, 0), (0, 32 - 21), (0, 0)))[None]
+    for t in (0, 7, 20):
+        q_abs, row = mla.project_absorbed(x[:, t:t + 1], lp, cfg, None, None)
+        np.testing.assert_allclose(row, rows[:, :, t:t + 1], rtol=1e-6, atol=1e-6)
+        o_lat = latent_attention(q_abs, cache, jnp.asarray([t]), rank=32,
+                                 sm_scale=cfg.latent.sm_scale, layer=0)
+        o = mla.expand_values(o_lat, lp, cfg)
+        got = o.transpose(0, 2, 1, 3).reshape(1, 1, -1) @ lp["wo"]
+        np.testing.assert_allclose(got[0, 0], want[t], rtol=2e-4, atol=2e-4)
+
+
+def test_a_latent_model_with_a_direct_q_serves_like_the_low_rank_one(runner):
+    """The marker of a latent layer is ``wkv_a``: a tree without ``wq_a``
+    prefills, decodes and matches its own forward."""
+    import dataclasses
+
+    from starway_tpu.models import forward, init_params
+    from starway_tpu.models.generate import decode_step, prefill
+
+    cfg = runner.model_config(TINY)
+    cfg = dataclasses.replace(cfg, latent=dataclasses.replace(
+        cfg.latent, q_rank=None))
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    assert all("wq_a" not in seg and "wq" in seg and "wkv_a" in seg
+               for seg in params["layers"])
+    toks = _tokens(2, 14, seed=4)
+    want = forward(params, jnp.asarray(toks), cfg)
+    logits, cache = prefill(params, cfg, jnp.asarray(toks[:, :6]), 32)
+    np.testing.assert_allclose(logits, want[:, 5], rtol=2e-4, atol=2e-4)
+    step = jax.jit(lambda cache, tok, t: decode_step(params, cache, tok, t, cfg))
+    for t in range(6, 14):
+        logits, cache = step(cache, jnp.asarray(toks[:, t]), jnp.int32(t))
+        np.testing.assert_allclose(logits, want[:, t], rtol=2e-4, atol=2e-4)

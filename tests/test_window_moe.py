@@ -73,8 +73,10 @@ def test_the_file_keys_give_one_group_a_kind(runner):
     assert cfg.kinds.windows == (None, 8, 8, 8)
     assert cfg.kinds.rope == (False, True, True, True)
     assert cfg.kinds.window == 8 and cfg.sliding_window is None
+    # (window, rotates, linear): no layer of this model is linear.
     assert [cfg.layer_kind(i) for i in (0, 1, 4, 7)] == [
-        (None, False), (8, True), (None, False), (8, True)]
+        (None, False, False), (8, True, False)] * 2
+    assert cfg.kinds.linear == (False,) * 4 and cfg.linear is None
     assert cfg.kind_runs() == [(0, 1), (1, 3), (4, 1), (5, 3)]
     assert cfg.segment_plan() == [(0, 1, True), (1, 3, True), (4, 1, True),
                                   (5, 3, True)]
@@ -124,7 +126,7 @@ def test_init_params_stacks_one_segment_a_run_of_a_kind(runner):
     segs = layer_segments(params["layers"])
     assert [(first, seg["wq"].shape[0]) for seg, first in segs] == cfg.kind_runs()
     assert [segment_kind(cfg, seg, first) for seg, first in segs] == [
-        (None, False), (8, True)] * 2
+        (None, False, False), (8, True, False)] * 2
     assert "bias" not in segs[0][0]["routed"]
     assert "shared" not in segs[0][0]["routed"]
     one = jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs),
