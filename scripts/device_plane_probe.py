@@ -13,6 +13,13 @@ Host -> device, the placement of a staged receive (device.py ``_fast_h2d``):
 Device -> host, the staging of a send: ``np.asarray`` one array at a time
 against ``copy_to_host_async`` on all first (the prefetch window).
 
+Chip -> chip (``--mode d2d``, on a host with several chips: ``chiprun
+--chips 4``), the in-process handoff (device.py ``_copy_to_device``): one
+16 MiB copy with its wait, the all-to-all's twelve in turn (each waited for
+before the next is issued: the transport before PR 34), and the twelve
+issued together and waited for once (the transport since, and the
+benchmark's raw round, whose ``jax.device_put`` is timed beside it).
+
 One JSON line a row; no number from the CPU backend says anything about a
 chip (the script prints the platform it ran on).
 """
@@ -131,13 +138,77 @@ def d2h(dev, nbytes: int, n: int) -> dict:
             "GBps_prefetched": n * nbytes / ahead / 1e9}
 
 
+def d2d(devs, nbytes: int, reps: int) -> dict:
+    """Every ordered pair of ``devs`` carries ``nbytes``, as a round of the
+    all-to-all does: in turn, then all in flight at once."""
+    import jax
+    import jax.numpy as jnp
+
+    from starway_tpu import device
+
+    pairs = [(a, b) for a in range(len(devs)) for b in range(len(devs)) if a != b]
+    src = {}
+    for a, b in pairs:
+        with jax.default_device(devs[a]):
+            src[(a, b)] = jnp.full((nbytes,), 16 * a + b, jnp.uint8)
+    jax.block_until_ready(list(src.values()))
+    plans = {b: [None] for b in range(len(devs))}
+
+    def issue(a, b):
+        return device._copy_to_device(src[(a, b)], devs[b], plans[b])
+
+    issue(*pairs[0]).block_until_ready()   # warm the client and the plans
+    one, issue_s, turn, together, put = [], [], [], [], []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        c = issue(*pairs[0])
+        t1 = time.perf_counter()
+        c.block_until_ready()
+        one.append(time.perf_counter() - t0)
+        issue_s.append(t1 - t0)
+        t0 = time.perf_counter()
+        for a, b in pairs:
+            issue(a, b).block_until_ready()
+        turn.append(time.perf_counter() - t0)
+        t0 = time.perf_counter()
+        jax.block_until_ready([issue(a, b) for a, b in pairs])
+        together.append(time.perf_counter() - t0)
+        t0 = time.perf_counter()
+        jax.block_until_ready([jax.device_put(src[(a, b)], devs[b])
+                               for a, b in pairs])
+        put.append(time.perf_counter() - t0)
+    total = len(pairs) * nbytes
+    return {"row": "d2d", "bytes": nbytes, "chips": len(devs),
+            "copies_a_round": len(pairs), "reps": reps,
+            "one_copy_with_wait_ms": med_ms(one),
+            "one_copy_issue_ms": med_ms(issue_s),
+            "round_in_turn_ms": med_ms(turn),
+            "round_together_ms": med_ms(together),
+            "round_device_put_ms": med_ms(put),
+            "GBps_in_turn": total / statistics.median(turn) / 1e9,
+            "GBps_together": total / statistics.median(together) / 1e9}
+
+
 def main() -> int:
+    import argparse
+
     import jax
 
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--mode", choices=("host", "d2d"), default="host")
+    args = ap.parse_args()
     dev = jax.devices()[0]
     print(json.dumps({"row": "device", "platform": dev.platform,
-                      "kind": dev.device_kind, "jax": jax.__version__}),
+                      "kind": dev.device_kind, "jax": jax.__version__,
+                      "devices": len(jax.devices())}),
           flush=True)
+    if args.mode == "d2d":
+        devs = jax.devices()[:4]
+        if len(devs) < 2:
+            raise SystemExit("device_plane_probe: --mode d2d needs two chips")
+        for nbytes, reps in ((16 * MiB, 30), (4 * MiB, 30)):
+            print(json.dumps(d2d(devs, nbytes, reps)), flush=True)
+        return 0
     h2d(dev, MiB, 2)  # warm the client
     for nbytes, reps in ((4 * MiB, 20), (64 * MiB, 5), (256 * MiB, 3)):
         print(json.dumps(h2d(dev, nbytes, reps)), flush=True)

@@ -2216,6 +2216,57 @@ def socket_linger_struct() -> bytes:
     return _s.pack("ii", 1, 0)  # l_onoff=1, l_linger=0 -> RST on close
 
 
+class InprocSend:
+    """Completion of one in-process send: ``sent(error=None)``, run ONCE
+    with no lock held.  The receiver's matcher fires it (matching.py
+    ``deliver``): behind the receive when the bytes are in the receiver's
+    hands on return, or -- a device payload copied onto another device --
+    when that copy is resident (``InboundMsg.sent`` holds it meanwhile).
+    Nothing on the sending side completes before then: ``done``,
+    ``sends_completed`` and a flush barrier behind it (``send_flush``)."""
+
+    __slots__ = ("conn", "nbytes", "done", "fail", "t_post")
+
+    def __init__(self, conn, payload, done, fail):
+        self.conn = conn
+        self.done = done
+        self.fail = fail
+        if isinstance(payload, memoryview):
+            self.nbytes, self.t_post = len(payload), 0.0
+        else:
+            # A device payload may stay in flight.  Until it settles, its
+            # worker counts as busy: later sends and flushes queue on the
+            # engine thread and none runs inline, so a barrier held for
+            # this send is acknowledged where flush records are owned.
+            self.nbytes, self.t_post = int(payload.nbytes), time.perf_counter()
+            worker = conn.worker
+            with worker.lock:
+                worker._busy += 1
+
+    def __call__(self, error: Optional[str] = None) -> None:
+        conn = self.conn
+        bucket = 0  # §25: a host payload completes locally at post
+        if self.t_post:
+            worker = conn.worker
+            with worker.lock:
+                worker._busy -= 1
+            bucket = swtrace.hist_bucket(
+                int((time.perf_counter() - self.t_post) * 1e6))
+        if error is not None:
+            if self.fail is not None:
+                self.fail(error)
+            return
+        conn._ctr.bytes_tx += self.nbytes
+        conn._ctr.sends_completed += 1
+        conn._hists.send_local_us[bucket] += 1
+        peer = conn.peer_worker_ref()
+        peer_ctr = getattr(peer, "counters", None)
+        if peer_ctr is not None:
+            peer_ctr.bytes_rx += self.nbytes
+        if self.done is not None:
+            self.done()
+
+
 class InprocConn(BaseConn):
     kind = "inproc"
 
@@ -2226,38 +2277,61 @@ class InprocConn(BaseConn):
 
     def send_data(self, tag: int, payload, done, fail, owner, fires: list,
                   kick: bool = True) -> None:
-        # ``kick`` is the TcpConn deferred-push knob; in-process delivery
-        # is synchronous, so there is nothing to defer.
+        # ``kick`` is the TcpConn deferred-push knob; in-process MATCHING
+        # is synchronous, so there is nothing to defer.  Completion is
+        # the peer matcher's to fire (InprocSend): a device payload copied
+        # onto another chip is not resident when deliver returns.
         peer = self.peer_worker_ref()
         if not self.alive or peer is None or peer.status != state.RUNNING:
             if fail is not None:
                 fires.append(lambda: fail(REASON_NOT_CONNECTED + " (peer closed)"))
             return
+        sent = InprocSend(self, payload, done, fail)
         with peer.lock:
-            peer_fires = peer.matcher.deliver(tag, payload)
+            peer_fires = peer.matcher.deliver(tag, payload, sent)
         fires.extend(peer_fires)
-        nbytes = len(payload) if isinstance(payload, memoryview) else int(payload.nbytes)
-        self._ctr.bytes_tx += nbytes
-        self._ctr.sends_completed += 1
-        # §25: synchronous delivery -- local completion at post (bucket 0).
-        self._hists.send_local_us[0] += 1
-        peer_ctr = getattr(peer, "counters", None)
-        if peer_ctr is not None:
-            peer_ctr.bytes_rx += nbytes
-        if done is not None:
-            fires.append(done)
 
     def send_flush(self, seq: int, fires: list) -> None:
-        # In-process delivery is synchronous and FIFO on the engine thread:
+        # In-process MATCHING is synchronous and FIFO on the engine thread:
         # by the time the flush op is processed every prior send has been
-        # ingested by the peer's matcher, so the barrier is already met.
+        # ingested by the peer's matcher.  RESIDENCY is not: a device
+        # payload's copy onto another chip may still be in flight.  The
+        # barrier then waits on the peer (matcher.hold_flush) and is
+        # acknowledged through flush_landed when the last such copy has
+        # landed -- the rule TcpConn keeps with _remote_msgs /
+        # _deferred_flush_acks.
+        # (Unlocked peek: a handoff holding a send of ours entered
+        # ``landing`` before that send's submit returned, and leaves it
+        # before the send completes.)
+        peer = self.peer_worker_ref()
+        if peer is not None and peer.matcher.landing:
+            with peer.lock:
+                if peer.matcher.hold_flush(self, seq):
+                    return
         self.flush_acked = seq
         self.worker._on_flush_ack(self, seq, fires)
+
+    def flush_landed(self, seq: int) -> None:
+        """Fire thunk from the PEER's threads: every handoff this conn had
+        in flight when barrier ``seq`` came has landed (seq 0: the peer
+        closed instead; nothing is acknowledged and this conn is dead).
+        Flush records are this worker's engine-thread territory, so the
+        acknowledgement hops there."""
+        self.worker._hop(("flush_ack", self, seq), lambda: [])
 
     def close(self, fires: list) -> None:
         self.alive = False
         if self.peer_conn is not None:
             self.peer_conn.alive = False
+        # Close cancels in-flight ops: this conn's sends still held by
+        # handoffs on the peer (the copies themselves go on).
+        peer = self.peer_worker_ref()
+        if peer is not None:
+            with peer.lock:
+                held = peer.matcher.withdraw_sends(self)
+            for sent in held:
+                self._ctr.ops_cancelled += 1
+                fires.append(lambda s=sent: s(REASON_CANCELLED))
 
     def mark_dead(self, fires: list) -> None:
         self.close(fires)

@@ -157,9 +157,15 @@ class Worker:
         # (device.py TransferManager); created lazily, dropped at close so
         # unpulled sends die with the worker (close-cancel contract).
         self._xfer_mgr = None
-        # Device receives' placements run on this thread, beside the engine
-        # (device.py Beside); created by the first one, closed at close.
-        self._placer = None
+        # Device receives' waits -- a staged placement, an in-process
+        # handoff's copy -- run on this thread, beside the engine
+        # (device.py Beside; it starts its thread at the first one).  Made
+        # here, not at first use: a handoff arrives under the lock on ANY
+        # thread, a placement on the engine thread without it.
+        from .. import device as _device
+
+        self._placer = _device.Beside("starway-place")
+        self.matcher.land_beside = self._land_beside
 
     # ------------------------------------------------------------ app side
     def _require_running(self) -> None:
@@ -343,8 +349,10 @@ class Worker:
                 self._busy += 1
                 self.ops.append(("send", conn, view, tag, done, fail, owner, timeout))
         if inline:
-            # Synchronous delivery: the op settles before a deadline could
-            # ever be armed, so `timeout` is moot here.
+            # Synchronous matching: the op settles before a deadline could
+            # ever be armed (a device payload whose copy onto another chip
+            # is in flight settles when it lands: conn.py InprocSend), so
+            # `timeout` is moot here.
             fires: list = []
             conn.send_data(tag, view, done, fail, owner, fires)
             _run_fires(fires)
@@ -373,8 +381,12 @@ class Worker:
                 self._busy += 1
                 self.ops.append(("flush", done, fail, conns, timeout))
         if inline:
-            # All in-process traffic already delivered synchronously in
-            # submission order: the barrier is trivially met.
+            # All in-process traffic was MATCHED synchronously in
+            # submission order, and `_busy == 0` says no device send of
+            # this worker is still in flight to another chip (InprocSend
+            # holds `_busy` until it lands): the barrier is met.  With one
+            # in flight the flush queues above, and InprocConn.send_flush
+            # holds it on the engine thread until the copy is resident.
             fires = []
             self._start_flush(done, fail, targets, fires, timeout)
             _run_fires(fires)
@@ -510,6 +522,27 @@ class Worker:
             array, error = None, f"device placement failed: {exc}"
         self._hop(("placed", conn, msg, array, error),
                   lambda: self.matcher.on_placed(msg, array, error))
+
+    def _land_beside(self, msg) -> None:
+        """Matcher hook, lock held, any thread: the copy of an in-process
+        device payload onto ``msg``'s sink on another chip was ISSUED
+        (matching.py _land).  The wait until it is resident holds a thread,
+        so it runs beside the engine like a placement, and in issue order:
+        the completions it releases (receive, send, held flushes) fire in
+        delivery order."""
+        sink, copy = msg.posted.buf, msg.landing
+        self._placer.submit(lambda: self._run_land(msg, sink, copy))
+
+    def _run_land(self, msg, sink, copy) -> None:
+        """Placer thread: wait, then hand the outcome to the engine."""
+        try:
+            sink.land(copy)
+            error = None
+        except Exception as exc:
+            logger.exception("starway: device handoff failed")
+            error = f"device handoff failed: {exc}"
+        self._hop(("landed", msg, error),
+                  lambda: self.matcher.on_landed(msg, error))
 
     def _force_start_pulls(self, conn, fires) -> None:
         """A FLUSH barrier arrived with descriptors still waiting for a
@@ -883,12 +916,27 @@ class Worker:
             _, msg, payload, error = op
             with self.lock:
                 fires.extend(self.matcher.on_remote_complete(msg, payload, error))
-            msg.remote.conn.remote_resolved(msg, fires)
+            if msg.landing is None:
+                msg.remote.conn.remote_resolved(msg, fires)
         elif op[0] == "placed":
             _, conn, msg, array, error = op
             with self.lock:
                 fires.extend(self.matcher.on_placed(msg, array, error))
             conn.remote_resolved(msg, fires)
+        elif op[0] == "landed":
+            _, msg, error = op
+            with self.lock:
+                fires.extend(self.matcher.on_landed(msg, error))
+            if msg.remote is not None:
+                # Pulled onto another device than its sink's (matching.py
+                # on_remote_complete): resident only now.
+                msg.remote.conn.remote_resolved(msg, fires)
+        elif op[0] == "flush_ack":
+            # An in-process barrier held for handoffs (InprocConn
+            # flush_landed).  Seq 0: the peer closed; the conn is dead and
+            # the records waiting on it fail.
+            _, conn, seq = op
+            self._on_flush_ack(conn, seq, fires)
         elif op[0] == "fc_grant":
             _, conn, gen, nbytes = op
             if gen == conn.fc_rx_gen:

@@ -19,8 +19,9 @@ ride the same matcher with no jax dependency here:
 
 * host target: writable ``memoryview``; host payload: ``memoryview``;
 * device target: ``DeviceRecvSink`` (``nbytes`` / ``host_staging()`` /
-  ``place()`` + ``deliver()`` / ``accept_device()`` / optional
-  ``accept_host()`` for complete-bytes-in-hand delivery, see device.py);
+  ``place()`` + ``deliver()`` / ``accept_device()`` + ``deliver_device()``
+  / optional ``accept_host()`` for complete-bytes-in-hand delivery, see
+  device.py);
 * device payload: ``DevicePayload`` (``nbytes`` / ``as_host_view()`` /
   ``.array``).
 
@@ -36,7 +37,8 @@ import time
 from collections import deque
 from typing import Callable, Optional
 
-from ..errors import REASON_CANCELLED, REASON_TIMEOUT, REASON_TRUNCATED
+from ..errors import (REASON_CANCELLED, REASON_NOT_CONNECTED, REASON_TIMEOUT,
+                      REASON_TRUNCATED)
 from . import swtrace
 
 DoneCb = Callable[[int, int], None]  # (sender_tag, length)
@@ -105,7 +107,7 @@ class InboundMsg:
 
     __slots__ = ("tag", "length", "sink", "received", "posted", "complete",
                  "discard", "spill", "device_payload", "remote", "placing",
-                 "fc_owner", "fc_gen", "fc_bytes", "born")
+                 "landing", "sent", "fc_owner", "fc_gen", "fc_bytes", "born")
 
     def __init__(self, tag: int, length: int):
         self.tag = tag
@@ -134,10 +136,23 @@ class InboundMsg:
         # its one placement is running beside the engine thread; the
         # message stays in flight until on_placed (DESIGN.md §12).
         self.placing = False
+        # In-process device payload claimed by a sink on ANOTHER device:
+        # ``landing`` is the copy, issued and waited for beside the engine
+        # thread; the message stays in flight until on_landed takes it
+        # back.  ``sent`` is the in-process sender's completion
+        # (``sent(error=None)``, conn.py InprocSend), held with it: the
+        # send completes when its bytes are resident, not when the copy
+        # was issued.  None for a message that waited in the unexpected
+        # queue (its send completed when it was queued).
+        self.landing = None
+        self.sent = None
 
 
-def _copy_complete(pr: PostedRecv, payload, length: int) -> None:
-    """Move a fully-arrived payload into a posted receive target."""
+def _copy_complete(pr: PostedRecv, payload, length: int):
+    """Move a fully-arrived payload into a posted receive target.  Returns
+    None when it is there; a device payload whose copy onto the sink's
+    device was only ISSUED comes back as that copy, in flight (the caller
+    keeps the message in flight too: :meth:`TagMatcher._land`)."""
     if _is_host(pr.buf):
         if _is_host(payload):
             pr.buf[:length] = payload
@@ -156,8 +171,9 @@ def _copy_complete(pr: PostedRecv, payload, length: int) -> None:
                 staging = pr.buf.host_staging()
                 staging[:length] = payload
                 pr.buf.finalize_from_host(length)
-        else:  # device -> device: direct HBM handoff / ICI copy
-            pr.buf.accept_device(payload.array)
+        else:  # device -> device: direct HBM handoff / ICI copy issued
+            return pr.buf.accept_device(payload.array)
+    return None
 
 
 class TagMatcher:
@@ -183,6 +199,16 @@ class TagMatcher:
         # user code or touches conn I/O).
         self.unexp_bytes = 0
         self.fc_grant = None  # fn(conn, gen, nbytes) | None
+        # In-process device handoffs in flight into this worker (a copy
+        # onto another device issued, not yet resident: DESIGN.md §12),
+        # and the in-process flush barriers held behind them: (origin
+        # conn, seq, the handoffs that conn had in flight when the barrier
+        # came).  ``land_beside`` is the worker-installed hook that waits
+        # for a handoff beside the engine thread -- called UNDER the
+        # worker lock (it only queues a thunk), like fc_grant.
+        self.landing: set = set()
+        self.held_flushes: list = []
+        self.land_beside = None  # fn(msg) | None
 
     # ------------------------------------------------------- flow control
     def fc_track(self, msg: "InboundMsg", conn, gen: int, nbytes: int) -> None:
@@ -257,12 +283,16 @@ class TagMatcher:
                 if msg.complete:
                     self.unexpected.remove(msg)
                     self.fc_release(msg)
+                    copy = None
                     if msg.device_payload is not None:
-                        _copy_complete(pr, msg.device_payload, msg.length)
+                        copy = _copy_complete(pr, msg.device_payload, msg.length)
                     else:
                         _copy_complete(pr, memoryview(msg.spill)[: msg.length] if msg.spill is not None else memoryview(b""), msg.length)
                     stag, length = msg.tag, msg.length
                     self._rec_match(stag, length)
+                    if copy is not None:
+                        self._land(pr, msg, copy)
+                        return fires
                     self.counters.recvs_completed += 1
                     self._pulse_wait(pr)
                     fires.append(lambda done=done, stag=stag, length=length: done(stag, length))
@@ -370,6 +400,95 @@ class TagMatcher:
         fires.append(lambda pr=pr, m=msg: pr.done(m.tag, m.length))
         return fires
 
+    # ------------------------------------------------- handoffs (inproc)
+    def _land(self, pr: PostedRecv, msg: InboundMsg, copy, sent=None) -> None:
+        """``copy``, a device payload's copy onto ``pr``'s sink on ANOTHER
+        device, was issued and not awaited.  ``msg`` is matched and
+        claimed now -- order, tags, truncation and the match event are
+        decided -- and stays in flight, as a ``placing`` message does (a
+        close or a deadline still cancels its receive), until
+        :meth:`on_landed`.  ``sent`` is held with it."""
+        # (``placing`` itself stays False: it steers conn-side bookkeeping
+        # of STREAMED messages, core/engine.py _conn_broken.)
+        pr.claimed = True
+        msg.posted = pr
+        msg.complete = False
+        msg.landing = copy
+        msg.sent = sent
+        self.inflight.add(msg)
+        self.counters.handoffs += 1
+        if self.landing:
+            self.counters.handoffs_overlapped += 1
+        self.landing.add(msg)
+        self.land_beside(msg)
+
+    def on_landed(self, msg: InboundMsg, error: Optional[str]) -> list:
+        """The wait for a handoff ended: its copy is resident on the sink's
+        device, or ``error`` says why it is not.  Fires, in this order: the
+        receive (nothing if it was cancelled or timed out meanwhile: the
+        copy is dropped, the DeviceBuffer untouched), the send held with
+        it, and each in-process flush barrier whose last outstanding
+        handoff this was."""
+        fires: list = []
+        self.landing.discard(msg)
+        self.inflight.discard(msg)
+        pr = msg.posted
+        if not msg.discard and pr is not None:
+            msg.complete = True
+            if error is not None:
+                fires.append(lambda pr=pr: pr.fail(error))
+            else:
+                pr.buf.deliver_device(msg.landing)
+                self.counters.recvs_completed += 1
+                self._pulse_wait(pr)
+                fires.append(lambda pr=pr, m=msg: pr.done(m.tag, m.length))
+        msg.landing = None
+        sent, msg.sent = msg.sent, None
+        if sent is not None:
+            fires.append(lambda: sent(error))
+        if self.held_flushes:
+            held, self.held_flushes = self.held_flushes, []
+            for rec in held:
+                origin, seq, waiting = rec
+                waiting.discard(msg)
+                if waiting:
+                    self.held_flushes.append(rec)
+                else:
+                    fires.append(lambda o=origin, s=seq: o.flush_landed(s))
+        return fires
+
+    def _held_for(self, origin) -> list:
+        """Handoffs in flight that hold a send of conn ``origin``."""
+        return [m for m in self.landing
+                if m.sent is not None and m.sent.conn is origin]
+
+    def hold_flush(self, origin, seq: int) -> bool:
+        """An in-process flush barrier from conn ``origin``.  False: every
+        send it covers is resident, the caller acknowledges it at once.
+        True: sends of that conn are still held by handoffs in flight; the
+        barrier waits here and ``origin.flush_landed(seq)`` fires when the
+        last of them has landed (a later handoff does not extend the wait:
+        the rule TcpConn keeps with ``_deferred_flush_acks``)."""
+        waiting = set(self._held_for(origin))
+        if not waiting:
+            return False
+        self.held_flushes.append((origin, seq, waiting))
+        return True
+
+    def withdraw_sends(self, origin) -> list:
+        """The worker behind conn ``origin`` is closing: hand back the
+        completions of its sends still held by handoffs in flight (it
+        cancels them itself) and drop its held barriers (it cancels its
+        flush records too).  The handoffs go on: the copies were issued
+        and their receives complete when they land, as a ``placing``
+        message resolves on its own after its conn died."""
+        sents = []
+        for m in self._held_for(origin):
+            sents.append(m.sent)
+            m.sent = None
+        self.held_flushes = [h for h in self.held_flushes if h[0] is not origin]
+        return sents
+
     # ------------------------------------------------------- remote (pull)
     def on_remote_message(self, tag: int, length: int, remote) -> tuple[InboundMsg, list]:
         """A DEVPULL descriptor arrived: the payload stays on the sender's
@@ -428,7 +547,13 @@ class TagMatcher:
         msg.complete = True
         pr = msg.posted
         if pr is not None:
-            _copy_complete(pr, payload, msg.length)
+            copy = _copy_complete(pr, payload, msg.length)
+            if copy is not None:
+                # Pulled onto another device than the sink's (force-started
+                # by a barrier before this receive claimed it): one more
+                # copy, in flight like any handoff.
+                self._land(pr, msg, copy)
+                return fires
             self.counters.recvs_completed += 1
             self._pulse_wait(pr)
             fires.append(lambda pr=pr, m=msg: pr.done(m.tag, m.length))
@@ -440,29 +565,46 @@ class TagMatcher:
         return fires
 
     # ------------------------------------------------------ inproc delivery
-    def deliver(self, tag: int, payload) -> list:
+    def deliver(self, tag: int, payload, sent=None) -> list:
         """Deliver a complete message in one step (in-process fast path).
 
         ``payload`` is a host memoryview (single copy into the posted buffer)
         or a DevicePayload (direct array handoff -- the path ICI device
         transfers ride, no host serialization).
+
+        Matching is synchronous: which receive takes the message, and
+        whether it fits, is decided before this returns.  A device
+        payload's RESIDENCY on another device is not: its copy is issued
+        and the receive stays in flight until :meth:`on_landed`.  ``sent``,
+        the in-process sender's completion (``sent(error=None)``), fires
+        behind the receive: among the returned fires on every other path,
+        from on_landed on that one.
         """
         fires: list = []
+        if not self._deliver(tag, payload, sent, fires) and sent is not None:
+            fires.append(sent)
+        return fires
+
+    def _deliver(self, tag: int, payload, sent, fires: list) -> bool:
+        """:meth:`deliver`; True when ``sent`` is held by a handoff."""
         length = _size(payload)
         if tag == PROBE_TAG:
-            return fires  # probe traffic is dropped, never queued
+            return False  # probe traffic is dropped, never queued
         for pr in self.posted:
             if not pr.claimed and tags_match(tag, pr.tag, pr.mask):
                 self.posted.remove(pr)
                 if length > pr.size:
                     fires.append(lambda pr=pr: pr.fail(REASON_TRUNCATED))
-                    return fires
-                _copy_complete(pr, payload, length)
+                    return False
+                copy = _copy_complete(pr, payload, length)
                 self._rec_match(tag, length)
+                if copy is not None:
+                    self._land(pr, InboundMsg(tag, length), copy, sent)
+                    return True
                 self.counters.recvs_completed += 1
                 self._pulse_wait(pr)
                 fires.append(lambda pr=pr, t=tag, n=length: pr.done(t, n))
-                return fires
+                return False
         msg = InboundMsg(tag, length)
         if _is_host(payload):
             msg.spill = bytearray(payload)
@@ -472,7 +614,7 @@ class TagMatcher:
             msg.device_payload = payload
         msg.complete = True
         self.unexpected.append(msg)
-        return fires
+        return False
 
     # -------------------------------------------------------- conn death
     def purge_inflight(self, msg: InboundMsg) -> None:
@@ -572,4 +714,18 @@ class TagMatcher:
         self.inflight.clear()
         self.unexpected.clear()
         self.unexp_bytes = 0  # close wipes the queue; grants are moot
+        # Handoffs in flight: their receives were cancelled above and the
+        # copies are dropped when they land.  The sends held with them
+        # fail like a send to a closed peer, and held barriers are let go
+        # with seq 0, which acknowledges nothing: their conn is dead by the
+        # time the fires run, so the sender's flush fails.
+        for msg in self.landing:
+            sent, msg.sent = msg.sent, None
+            if sent is not None:
+                fires.append(lambda s=sent: s(
+                    REASON_NOT_CONNECTED + " (peer closed)"))
+        self.landing.clear()
+        for origin, _seq, _ in self.held_flushes:
+            fires.append(lambda o=origin: o.flush_landed(0))
+        self.held_flushes = []
         return fires

@@ -825,7 +825,12 @@ class NativeWorkerBase:
         try:
             user_done, _fail, mv, sink = entry.claimed
             if sink is not None:
-                sink.accept_device(arr)
+                copy = sink.accept_device(arr)
+                if copy is not None:
+                    # Pulled onto another device than the sink's: delivered
+                    # means resident, so this thread waits for the copy.
+                    sink.land(copy)
+                    sink.deliver_device(copy)
             elif mv is not None:
                 host = np.asarray(arr).view(np.uint8).reshape(-1)
                 mv[: entry.nbytes] = memoryview(host)[: entry.nbytes]

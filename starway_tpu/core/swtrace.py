@@ -131,6 +131,11 @@ COUNTER_NAMES = (
     #                       process-global, like the staging pool)
     "prefetch_depth_peak",  # most such copies in flight at once: a
     #                       high-water mark, not a sum (process-global)
+    "handoffs",           # in-process device payloads whose copy onto
+    #                       ANOTHER device was issued (counted on the
+    #                       receiving worker; DESIGN.md §12)
+    "handoffs_overlapped",  # ... issued while an earlier such copy into
+    #                       the same worker had not landed yet
     "ka_misses",          # peers declared dead by keepalive liveness
     "reconnects",         # aconnect retry attempts (process-global)
     "sessions_resumed",   # session conns resumed after a reconnect

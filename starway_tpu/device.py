@@ -8,10 +8,12 @@ primitives operate on jax.Array device buffers in HBM").
 Three transfer paths, chosen per connection:
 
 * **in-process, device payload -> device sink**: the sender hands the
-  ``jax.Array`` itself to the receiver's matcher; the receiver materialises
-  it on its target device with ``jax.device_put`` -- on TPU hardware with
-  both devices in the same process this is an HBM-to-HBM copy over ICI with
-  zero host staging.  (Same-device delivery is a reference handoff.)
+  ``jax.Array`` itself to the receiver's matcher; the receiver ISSUES the
+  copy onto its target device (PJRT, no wait) -- on TPU hardware with both
+  devices in the same process an HBM-to-HBM copy over ICI with zero host
+  staging -- and completes receive, send and flush when it is resident,
+  waited for beside the engine.  (Same-device delivery is a reference
+  handoff.)
 * **in-process, mixed host/device**: one host copy at the boundary
   (``np.asarray`` of the payload, or ``device_put`` of the staged bytes).
 * **cross-process (TCP / DCN bootstrap path)**: payload bytes are staged to
@@ -26,7 +28,7 @@ small duck-typed protocols (no jax import in the core):
   ``as_host_view()``, ``.array``).
 * :class:`DeviceRecvSink` -- wraps a :class:`DeviceBuffer` for receiving
   (``nbytes``, ``host_staging()``, ``place()`` / ``deliver()``,
-  ``accept_device()``).
+  ``accept_device()`` / ``land()`` / ``deliver_device()``).
 
 A staged payload crosses the host WHOLE: one device-to-host copy and one
 placement a message, whatever its size.  The overlap comes from the queue
@@ -504,24 +506,32 @@ class DeviceRecvSink:
         _record_stage("place", time.perf_counter() - t0, length, self.scope)
         return placed, copied
 
-    def accept_device(self, array) -> None:
-        """Direct device handoff (in-process path): HBM -> HBM over ICI when
-        source and target devices differ, reference handoff when they match."""
-        import jax
-
-        self.devbuf.last_transport = "device"
+    def accept_device(self, array):
+        """Direct device handoff (in-process path).  Source and target
+        device match, or the sink names no device: a reference handoff,
+        complete on return (None).  They differ: the HBM -> HBM copy over
+        ICI is ISSUED, an enqueue, and returned IN FLIGHT.  Nothing waits
+        here -- the caller may hold a worker lock, and a round of handoffs
+        must all be in flight at once (DESIGN.md §12).  Completion still
+        means "data resident on target", the reference's recv-complete
+        semantics: whoever took the copy waits for it (:meth:`land`) beside
+        its engine and only then swaps it in (:meth:`deliver_device`)."""
         target = self.devbuf.device
         if target is not None:
             src_devs = array.devices() if hasattr(array, "devices") else set()
-            if src_devs == {target}:
-                self.devbuf.array = array
-                return
-            self.devbuf.array = _copy_to_device(array, target, self.devbuf._plan)
-            # Make completion mean "data resident on target", matching the
-            # reference's recv-complete semantics.
-            self.devbuf.array.block_until_ready()
-        else:
-            self.devbuf.array = array
+            if src_devs != {target}:
+                return _copy_to_device(array, target, self.devbuf._plan)
+        self.deliver_device(array)
+        return None
+
+    @staticmethod
+    def land(copy) -> None:
+        """Block until ``copy`` (from :meth:`accept_device`) is resident."""
+        copy.block_until_ready()
+
+    def deliver_device(self, array) -> None:
+        self.devbuf.array = array
+        self.devbuf.last_transport = "device"
 
 
 # ------------------------------------------------------ cross-process pull

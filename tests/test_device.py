@@ -263,3 +263,236 @@ def test_recv_sink_place_blocks_until_resident_then_recycles():
     assert np.asarray(first.devbuf.array).max() == 7
     second.finalize_from_host(nbytes)
     assert int(np.asarray(second.devbuf.array)[0]) == 9
+
+
+# ------------------------------------- in-process handoffs across devices
+
+
+@pytest.fixture
+def gate(monkeypatch):
+    """The wait for a cross-device copy (DeviceRecvSink.land), held until
+    the test opens it: what is in flight on a chip for a millisecond stays
+    in flight here for as long as the test looks at it."""
+    import threading
+
+    from starway_tpu import device
+
+    opened = threading.Event()
+    real = device.DeviceRecvSink.land
+
+    def gated(copy):
+        assert opened.wait(30), "the test never opened the gate"
+        real(copy)
+
+    monkeypatch.setattr(device.DeviceRecvSink, "land", staticmethod(gated))
+    yield opened
+    opened.set()
+
+
+async def _until(cond, what, timeout=10.0):
+    loop = asyncio.get_running_loop()
+    t_end = loop.time() + timeout
+    while not cond():
+        assert loop.time() < t_end, f"never happened: {what}"
+        await asyncio.sleep(0.005)
+
+
+def _reason(fut) -> str:
+    return str(fut.exception())
+
+
+@pytest.mark.parametrize("case", [
+    "posted_first", "unexpected_then_post", "receiver_closes",
+    "sender_closes", "recv_deadline", "copy_fails", "same_device"])
+async def test_cross_device_handoff_lifecycle(port, gate, monkeypatch, case):
+    """An in-process device payload for a sink on ANOTHER device: the copy
+    is issued at delivery and waited for beside the engine.  Matching is
+    decided at delivery; the receive, its send and a flush behind them
+    complete only when the copy is resident, in delivery order; a close,
+    a dead peer, a deadline or a failed copy settles both ends with the
+    stable reasons and leaves nothing in flight."""
+    from starway_tpu import device
+
+    n = 4
+    devs = jax.devices()
+    server, client = await _pair(port)
+    rx = server._server
+    counters = rx.counters_snapshot
+    srcs = [jax.device_put(jnp.full((256,), k + 1, jnp.float32), devs[0])
+            for k in range(n)]
+    sinks = [DeviceBuffer((256,), jnp.float32, device=devs[1 + k])
+             for k in range(n)]
+    order: list = []
+
+    def note(fut, name):
+        fut.add_done_callback(lambda _f: order.append(name))
+        return fut
+
+    try:
+        if case == "same_device":
+            sink = DeviceBuffer((256,), jnp.float32, device=devs[0])
+            recv = server.arecv(sink, 1, MASK)
+            send = client.asend(srcs[0], 1)
+            # Inline on this thread, nothing to wait for: done on return.
+            assert send.done() and recv.done()
+            await client.aflush()
+            assert sink.array is srcs[0] and sink.last_transport == "device"
+            assert counters()["handoffs"] == 0
+            return
+
+        if case == "unexpected_then_post":
+            sends = [client.asend(srcs[k], k) for k in range(n)]
+            await asyncio.gather(*sends)     # parked: no copy, nothing held
+            assert counters()["handoffs"] == 0
+            recvs = [note(server.arecv(sinks[k], k, MASK), f"recv{k}")
+                     for k in range(n)]
+            flush = None
+        elif case == "recv_deadline":
+            n = 1
+            outcome: list = []
+            sink = device.DeviceRecvSink(sinks[0])
+            rx.post_recv(sink, 0, MASK, lambda *a: outcome.append(a),
+                         lambda r: outcome.append(r), owner=sink, timeout=0.05)
+            recvs = []
+            sends = [client.asend(srcs[0], 0)]
+            flush = client.aflush()
+        else:
+            if case in ("receiver_closes", "sender_closes", "copy_fails"):
+                n = 2
+            recvs = [note(server.arecv(sinks[k], k, MASK), f"recv{k}")
+                     for k in range(n)]
+            sends = [note(client.asend(srcs[k], k), f"send{k}") for k in range(n)]
+            flush = note(client.aflush(), "flush")
+
+        # Every copy is ISSUED while the first has not landed ...
+        await _until(lambda: counters()["handoffs"] == n, "all issued")
+        assert counters()["handoffs_overlapped"] == n - 1
+        assert len(rx.matcher.inflight) == len(rx.matcher.landing) == n
+        # ... and nothing completed on the strength of an enqueue.
+        await asyncio.sleep(0.05)
+        pending = recvs + ([flush] if flush else [])
+        if case != "unexpected_then_post":
+            pending += sends
+        assert not any(f.done() for f in pending)
+        assert all(s.array is None for s in sinks)
+        c0 = counters()
+        assert c0["recvs_completed"] == 0
+        if case != "unexpected_then_post":
+            assert client._client.counters_snapshot()["sends_completed"] == 0
+
+        if case == "receiver_closes":
+            await server.aclose()
+            await asyncio.wait(recvs + sends + [flush], timeout=10)
+            assert all("cancel" in _reason(f) for f in recvs)
+            assert all("not connected" in _reason(f) for f in sends + [flush])
+            assert not rx.matcher.inflight and not rx.matcher.landing
+            gate.set()
+            await asyncio.sleep(0.05)
+            assert all(s.array is None for s in sinks)   # copies dropped
+            return
+        if case == "sender_closes":
+            await client.aclose()
+            await asyncio.wait(sends + [flush], timeout=10)
+            assert all("cancel" in _reason(f) for f in sends + [flush])
+            assert not any(f.done() for f in recvs)
+            gate.set()    # the copies were issued: the receives still land
+            assert await asyncio.wait_for(asyncio.gather(*recvs), 10) == \
+                [(k, srcs[k].nbytes) for k in range(n)]
+        elif case == "recv_deadline":
+            await _until(lambda: outcome, "the deadline")
+            assert outcome == ["timed out"] or "timed out" in outcome[0]
+            assert not rx.matcher.inflight and not sends[0].done()
+            gate.set()    # the send (and the flush) still wait for the copy
+            await asyncio.wait_for(asyncio.gather(sends[0], flush), 10)
+            assert sinks[0].array is None and len(outcome) == 1
+        elif case == "copy_fails":
+            def broken(copy):
+                raise RuntimeError("link down")
+
+            monkeypatch.setattr(device.DeviceRecvSink, "land",
+                                staticmethod(broken))
+            gate.set()    # handoff 0 lands; handoff 1's wait raises
+            await asyncio.wait(recvs + sends + [flush], timeout=10)
+            assert await recvs[0] == (0, srcs[0].nbytes) and sends[0].exception() is None
+            assert "device handoff failed" in _reason(recvs[1])
+            assert "device handoff failed" in _reason(sends[1])
+            assert flush.exception() is None     # resolved, as a failed pull is
+            assert sinks[1].array is None
+        else:
+            gate.set()
+            await asyncio.wait_for(
+                asyncio.gather(*recvs, *sends, *([flush] if flush else [])), 10)
+            await asyncio.sleep(0)    # the last done-callbacks
+            if case == "posted_first":
+                assert order == [f"{op}{k}" for k in range(n)
+                                 for op in ("recv", "send")] + ["flush"]
+            else:
+                assert order == [f"recv{k}" for k in range(n)]
+            for k in range(n):
+                assert sinks[k].array.devices() == {devs[1 + k]}
+                assert sinks[k].array.is_ready()
+                assert sinks[k].last_transport == "device"
+                assert float(sinks[k].array[0]) == k + 1
+            assert counters()["recvs_completed"] == n
+            await asyncio.wait_for(client.aflush(), 10)   # nothing held: inline
+        assert not rx.matcher.inflight and not rx.matcher.landing
+        assert not rx.matcher.held_flushes
+    finally:
+        gate.set()
+        for w in (client, server):
+            try:
+                await w.aclose()
+            except Exception:
+                pass   # closed by the case itself
+
+
+async def test_cross_device_handoffs_all_to_all_under_switching():
+    """Four workers, every one sending to every other at once, round after
+    round, with the interpreter switching threads every 10 us: posters,
+    four placer threads and eight engine threads race on the matchers'
+    handoff state.  Every round's twelve receives, sends and flushes
+    complete with the right bytes, and when it is over nothing is held:
+    no handoff in flight, no barrier waiting, every worker idle."""
+    import sys
+
+    n, rounds, devs = 4, 12, jax.devices()
+    servers, clients = [], {}
+    for k in range(n):
+        s = Server()
+        s.listen(SERVER_ADDR, 0)
+        servers.append(s)
+    for a in range(n):
+        for b in range(n):
+            if a != b:
+                clients[(a, b)] = c = Client()
+                await c.aconnect_address(servers[b].get_worker_address())
+    pairs = sorted(clients)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for r in range(rounds):
+            srcs = {(a, b): jax.device_put(
+                jnp.full((4096,), 100 * r + 10 * a + b, jnp.int32), devs[a])
+                for a, b in pairs}
+            sinks = {(a, b): DeviceBuffer((4096,), jnp.int32, device=devs[b])
+                     for a, b in pairs}
+            recvs = [servers[b].arecv(sinks[(a, b)], a, MASK) for a, b in pairs]
+            sends = [clients[(a, b)].asend(srcs[(a, b)], a) for a, b in pairs]
+            flushes = [c.aflush() for c in clients.values()]
+            await asyncio.wait_for(asyncio.gather(*recvs, *sends, *flushes), 60)
+            for a, b in pairs:
+                got = sinks[(a, b)].array
+                assert got.devices() == {devs[b]} and got.is_ready()
+                assert int(got[0]) == int(got[-1]) == 100 * r + 10 * a + b
+    finally:
+        sys.setswitchinterval(old)
+    workers = [s._server for s in servers] + [c._client for c in clients.values()]
+    assert sum(w.counters_snapshot()["handoffs"] for w in workers) == rounds * len(pairs)
+    for w in workers:
+        assert not w.matcher.landing and not w.matcher.inflight
+        assert not w.matcher.held_flushes and not w.flush_records
+        assert w._busy == 0
+    for c in clients.values():
+        await c.aclose()
+    for s in servers:
+        await s.aclose()
