@@ -262,7 +262,7 @@ const char* kCounterNames[] = {
     "bytes_tx",          "bytes_rx",
     "gather_passes",     "gather_items",
     "staging_hits",      "staging_misses",
-    "prefetch_started",  "prefetch_depth_peak",
+    "prefetch_started",
     "handoffs",          "handoffs_overlapped",
     "ka_misses",         "reconnects",
     "sessions_resumed",  "frames_replayed",
@@ -304,7 +304,7 @@ struct Counters {
   std::atomic<uint64_t> bytes_tx{0}, bytes_rx{0};
   std::atomic<uint64_t> gather_passes{0}, gather_items{0};
   std::atomic<uint64_t> staging_hits{0}, staging_misses{0};  // wrapper-owned
-  std::atomic<uint64_t> prefetch_started{0}, prefetch_depth_peak{0};  // wrapper
+  std::atomic<uint64_t> prefetch_started{0};                 // wrapper-owned
   // In-process device handoffs: the Python engine's path (this engine has
   // no in-process conns); declared for the shared vocabulary, always 0.
   std::atomic<uint64_t> handoffs{0}, handoffs_overlapped{0};
@@ -7086,7 +7086,7 @@ int sw_counters(void* h, char* out, int cap) {
       c.bytes_tx.load(),       c.bytes_rx.load(),
       c.gather_passes.load(),  c.gather_items.load(),
       c.staging_hits.load(),   c.staging_misses.load(),
-      c.prefetch_started.load(), c.prefetch_depth_peak.load(),
+      c.prefetch_started.load(),
       c.handoffs.load(),       c.handoffs_overlapped.load(),
       c.ka_misses.load(),      c.reconnects.load(),
       c.sessions_resumed.load(), c.frames_replayed.load(),

@@ -22,13 +22,14 @@ import asyncio
 import logging
 import random
 import threading
+import time
 import weakref
 from collections import deque
 from typing import Callable, Optional
 
 import numpy as np
 
-from . import config
+from . import config, perf
 from .core import swtrace
 from .core.endpoint import ServerEndpoint
 from .core.engine import ClientWorker, ServerWorker
@@ -193,7 +194,8 @@ def _loop_trampoline(loop: asyncio.AbstractEventLoop) -> _CompletionTrampoline:
         return tramp
 
 
-def _future_pair(loop: Optional[asyncio.AbstractEventLoop], result_factory=None):
+def _future_pair(loop: Optional[asyncio.AbstractEventLoop], result_factory=None,
+                 scope=None, tag: int = 0):
     """Build (future, done_cb, fail_cb) bridging completions to asyncio.
 
     Completions from engine threads hop via the per-loop trampoline --
@@ -201,15 +203,26 @@ def _future_pair(loop: Optional[asyncio.AbstractEventLoop], result_factory=None)
     op: src/starway/__init__.py:124-128).  Completions fired on the loop
     thread itself (the in-process inline fast path) resolve directly --
     no self-pipe write, no extra scheduler pass.
+
+    With ``scope`` (the ``a*`` variants pass their worker's) a completion
+    that does hop records how long it waited for the loop: the
+    ``loop_hop`` stage, from ``done`` / ``fail`` called off the loop's
+    thread to ``apply()`` on it, under the op's ``tag`` (a receive's is
+    its sender's).  One that resolves inline records nothing.
     """
     if loop is None:
         loop = asyncio.get_running_loop()
     fut: asyncio.Future = asyncio.Future(loop=loop)
 
     def _safe(call, *args):
+        t_hop = 0.0
+
         def apply():
             if not fut.done():
                 call(*args)
+            if t_hop:
+                now = time.perf_counter()
+                perf.record_phase(scope, tag, "loop_hop", now - t_hop, 0, now)
 
         # Same-loop detection via thread id: CPython's BaseEventLoop pins
         # `_thread_id` while running, and threading.get_ident() is ~100x
@@ -228,15 +241,30 @@ def _future_pair(loop: Optional[asyncio.AbstractEventLoop], result_factory=None)
         if same:
             apply()
             return
+        if scope is not None:
+            t_hop = time.perf_counter()
         _loop_trampoline(loop).submit(apply)
 
     def done(*args):
+        nonlocal tag
+        if args:
+            tag = args[0]  # a receive: the SENDER's tag names the message
         _safe(fut.set_result, result_factory(*args) if result_factory else None)
 
     def fail(reason: str):
         _safe(fut.set_exception, Exception(reason))
 
     return fut, done, fail
+
+
+def _posted(scope, tag: int, t0: float) -> None:
+    """Every ``a*`` variant below records its own ``post`` stage (DESIGN.md
+    §12) as it returns: its entry (``t0``) to here -- future pair, device
+    payload or sink, the worker's submit, an inline match included -- into
+    the worker's scope under the op's tag, with no lock taken.  A post
+    that raises was no op and records nothing."""
+    t1 = time.perf_counter()
+    perf.record_phase(scope, tag, "post", t1 - t0, 0, t1)
 
 
 class Server:
@@ -283,8 +311,9 @@ class Server:
         if _is_device_payload(buffer):
             from . import device
 
-            device.send_device(self._server, client_ep._conn, buffer, _tag(tag),
-                               done_callback, fail_callback)
+            with perf.xfer_note("post"):  # the device part
+                device.send_device(self._server, client_ep._conn, buffer,
+                                   _tag(tag), done_callback, fail_callback)
             return
         owner, view = _send_view(buffer)
         self._server.submit_send(client_ep._conn, view, _tag(tag),
@@ -294,8 +323,10 @@ class Server:
     def asend(self, client_ep: ServerEndpoint, buffer, tag: int,
               loop: Optional[asyncio.AbstractEventLoop] = None,
               timeout: Optional[float] = None):
-        fut, done, fail = _future_pair(loop)
+        t0, scope = time.perf_counter(), self._server.stage_scope
+        fut, done, fail = _future_pair(loop, None, scope, tag)
         self.send(client_ep, buffer, tag, done, fail, timeout=timeout)
+        _posted(scope, tag, t0)
         return fut
 
     # ----------------------------------------------------------------- recv
@@ -309,8 +340,10 @@ class Server:
         if _is_device_payload(buffer):
             from . import device
 
-            device.post_device_recv(self._server, buffer, _tag(tag), _tag(tag_mask),
-                                    done_callback, fail_callback)
+            with perf.xfer_note("post"):
+                device.post_device_recv(self._server, buffer, _tag(tag),
+                                        _tag(tag_mask), done_callback,
+                                        fail_callback)
             return
         owner, view = _recv_view(buffer)
         self._server.post_recv(view, _tag(tag), _tag(tag_mask),
@@ -320,8 +353,10 @@ class Server:
     def arecv(self, buffer, tag: int, tag_mask: int,
               loop: Optional[asyncio.AbstractEventLoop] = None,
               timeout: Optional[float] = None):
-        fut, done, fail = _future_pair(loop, result_factory=lambda st, ln: (st, ln))
+        t0, scope = time.perf_counter(), self._server.stage_scope
+        fut, done, fail = _future_pair(loop, lambda st, ln: (st, ln), scope, tag)
         self.recv(buffer, tag, tag_mask, done, fail, timeout=timeout)
+        _posted(scope, tag, t0)
         return fut
 
     # ---------------------------------------------------------------- flush
@@ -332,8 +367,10 @@ class Server:
 
     def aflush(self, loop: Optional[asyncio.AbstractEventLoop] = None,
                timeout: Optional[float] = None):
-        fut, done, fail = _future_pair(loop)
+        t0, scope = time.perf_counter(), self._server.stage_scope
+        fut, done, fail = _future_pair(loop, None, scope)
         self.flush(done, fail, timeout=timeout)
+        _posted(scope, 0, t0)
         return fut
 
     def flush_ep(self, client_ep: ServerEndpoint, done_callback: Callable[[], None],
@@ -345,8 +382,10 @@ class Server:
     def aflush_ep(self, client_ep: ServerEndpoint,
                   loop: Optional[asyncio.AbstractEventLoop] = None,
                   timeout: Optional[float] = None):
-        fut, done, fail = _future_pair(loop)
+        t0, scope = time.perf_counter(), self._server.stage_scope
+        fut, done, fail = _future_pair(loop, None, scope)
         self.flush_ep(client_ep, done, fail, timeout=timeout)
+        _posted(scope, 0, t0)
         return fut
 
     # ------------------------------------------------------------ telemetry
@@ -499,8 +538,10 @@ class Client:
         if _is_device_payload(buffer):
             from . import device
 
-            device.send_device(self._client, self._client.primary_conn, buffer,
-                               _tag(tag), done_callback, fail_callback)
+            with perf.xfer_note("post"):
+                device.send_device(self._client, self._client.primary_conn,
+                                   buffer, _tag(tag), done_callback,
+                                   fail_callback)
             return
         owner, view = _send_view(buffer)
         self._client.submit_send(self._client.primary_conn, view, _tag(tag),
@@ -510,8 +551,10 @@ class Client:
     def asend(self, buffer, tag: int,
               loop: Optional[asyncio.AbstractEventLoop] = None,
               timeout: Optional[float] = None):
-        fut, done, fail = _future_pair(loop)
+        t0, scope = time.perf_counter(), self._client.stage_scope
+        fut, done, fail = _future_pair(loop, None, scope, tag)
         self.send(buffer, tag, done, fail, timeout=timeout)
+        _posted(scope, tag, t0)
         return fut
 
     # ----------------------------------------------------------------- recv
@@ -524,8 +567,10 @@ class Client:
         if _is_device_payload(buffer):
             from . import device
 
-            device.post_device_recv(self._client, buffer, _tag(tag), _tag(tag_mask),
-                                    done_callback, fail_callback)
+            with perf.xfer_note("post"):
+                device.post_device_recv(self._client, buffer, _tag(tag),
+                                        _tag(tag_mask), done_callback,
+                                        fail_callback)
             return
         owner, view = _recv_view(buffer)
         self._client.post_recv(view, _tag(tag), _tag(tag_mask),
@@ -535,8 +580,10 @@ class Client:
     def arecv(self, buffer, tag: int, tag_mask: int,
               loop: Optional[asyncio.AbstractEventLoop] = None,
               timeout: Optional[float] = None):
-        fut, done, fail = _future_pair(loop, result_factory=lambda st, ln: (st, ln))
+        t0, scope = time.perf_counter(), self._client.stage_scope
+        fut, done, fail = _future_pair(loop, lambda st, ln: (st, ln), scope, tag)
         self.recv(buffer, tag, tag_mask, done, fail, timeout=timeout)
+        _posted(scope, tag, t0)
         return fut
 
     # ---------------------------------------------------------------- flush
@@ -547,8 +594,10 @@ class Client:
 
     def aflush(self, loop: Optional[asyncio.AbstractEventLoop] = None,
                timeout: Optional[float] = None):
-        fut, done, fail = _future_pair(loop)
+        t0, scope = time.perf_counter(), self._client.stage_scope
+        fut, done, fail = _future_pair(loop, None, scope)
         self.flush(done, fail, timeout=timeout)
+        _posted(scope, 0, t0)
         return fut
 
     # ------------------------------------------------------------ telemetry

@@ -487,6 +487,14 @@ class TcpConn(BaseConn):
         self.sm_active = False
         self.sm_negotiated = False  # sticky: survives teardown for introspection
         self._tx_via_ring = False
+        # The ``ring_wait`` stage (DESIGN.md §12): ``_ring_blocked`` is the
+        # perf_counter reading at which kick_tx left BLOCKED on a full
+        # ring (0.0 when not blocked); the next put that lands adds the
+        # wait to ``_ring_waited``, and the item that was being written
+        # records the sum when its last byte is in the ring: one sample a
+        # message that blocked, never one a put.
+        self._ring_blocked = 0.0
+        self._ring_waited = 0.0
         # Doorbell bytes that hit a full socket buffer: flushed on EPOLLOUT.
         # A starving byte (DB_STARVING) is the only wakeup a ring-blocked
         # producer gets, so doorbells must never be silently dropped.
@@ -656,6 +664,12 @@ class TcpConn(BaseConn):
         self._ctr.bytes_tx += n
         self._ctr.hot_copies += 1  # §23 sm ring put (one slot copy)
         perf.record_stage("tx", time.perf_counter() - t0, n, self._scope)
+        blocked = self._ring_blocked
+        if blocked:
+            # The producer was asleep on a full ring until the peer's
+            # doorbell: from the block to this first put that landed.
+            self._ring_blocked = 0.0
+            self._ring_waited += t0 - blocked
         return n
 
     def _tx_writev(self, views: list) -> int:
@@ -1492,6 +1506,12 @@ class TcpConn(BaseConn):
                         blocked = True
                         break
                     self.tx.popleft()
+                    waited = self._ring_waited
+                    if waited:
+                        self._ring_waited = 0.0
+                        perf.record_phase(
+                            self._scope, getattr(item, "tag", 0), "ring_wait",
+                            waited, 0, time.perf_counter())
                     if not isinstance(item, TxCtl) and not item.counted:
                         item.counted = True
                         self._ctr.sends_completed += 1
@@ -1558,6 +1578,8 @@ class TcpConn(BaseConn):
                 # Blocked on the ring, not the socket (EPOLLOUT would spin).
                 # Ask the peer to reply once it drains; the starving byte
                 # doubles as the data doorbell for anything published above.
+                if not self._ring_blocked:
+                    self._ring_blocked = time.perf_counter()
                 self._doorbell(fires, DB_STARVING)
                 return
         else:

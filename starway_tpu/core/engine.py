@@ -511,10 +511,14 @@ class Worker:
 
             placer = self._placer = _device.Beside("starway-place")
         sink = msg.posted.buf
-        placer.submit(lambda: self._run_place(conn, msg, sink))
+        t_q = time.perf_counter()
+        placer.submit(lambda: self._run_place(conn, msg, sink, t_q))
 
-    def _run_place(self, conn, msg, sink) -> None:
-        """Placer thread: place, then hand the result to the engine."""
+    def _run_place(self, conn, msg, sink, t_q: float) -> None:
+        """Placer thread: place, then hand the result to the engine.  How
+        long the message queued for this ONE thread rides the sink and is
+        recorded with its ``place`` (the ``place_queue`` stage)."""
+        sink.queued = (msg.tag, time.perf_counter() - t_q)
         try:
             array, error = sink.place(msg.length), None
         except Exception as exc:
@@ -541,8 +545,21 @@ class Worker:
         except Exception as exc:
             logger.exception("starway: device handoff failed")
             error = f"device handoff failed: {exc}"
-        self._hop(("landed", msg, error),
+        msg.t_landed = time.perf_counter()  # ``land`` ends, ``settle`` begins
+        self._hop(("landed", msg, sink, error),
                   lambda: self.matcher.on_landed(msg, error))
+
+    def _record_handoff(self, msg, sink) -> None:
+        """Fire thunk of the ``"landed"`` op, no lock held, behind
+        everything the landing released: the ONE record of where the
+        message waited.  The stamps it carried become its ``issue``,
+        ``land`` and ``settle`` stages (DESIGN.md §12)."""
+        now = time.perf_counter()
+        t_land, t_landed, n = msg.t_land, msg.t_landed, msg.length
+        perf.record_stages(self.stage_scope, msg.tag, (
+            ("issue", getattr(sink, "issue_s", 0.0), n, t_land),
+            ("land", t_landed - t_land, n, t_landed),
+            ("settle", now - t_landed, 0, now)))
 
     def _force_start_pulls(self, conn, fires) -> None:
         """A FLUSH barrier arrived with descriptors still waiting for a
@@ -924,13 +941,14 @@ class Worker:
                 fires.extend(self.matcher.on_placed(msg, array, error))
             conn.remote_resolved(msg, fires)
         elif op[0] == "landed":
-            _, msg, error = op
+            _, msg, sink, error = op
             with self.lock:
                 fires.extend(self.matcher.on_landed(msg, error))
             if msg.remote is not None:
                 # Pulled onto another device than its sink's (matching.py
                 # on_remote_complete): resident only now.
                 msg.remote.conn.remote_resolved(msg, fires)
+            fires.append(lambda: self._record_handoff(msg, sink))
         elif op[0] == "flush_ack":
             # An in-process barrier held for handoffs (InprocConn
             # flush_landed).  Seq 0: the peer closed; the conn is dead and

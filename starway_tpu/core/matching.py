@@ -107,7 +107,8 @@ class InboundMsg:
 
     __slots__ = ("tag", "length", "sink", "received", "posted", "complete",
                  "discard", "spill", "device_payload", "remote", "placing",
-                 "landing", "sent", "fc_owner", "fc_gen", "fc_bytes", "born")
+                 "landing", "sent", "fc_owner", "fc_gen", "fc_bytes", "born",
+                 "t_land", "t_landed")
 
     def __init__(self, tag: int, length: int):
         self.tag = tag
@@ -146,6 +147,9 @@ class InboundMsg:
         # queue (its send completed when it was queued).
         self.landing = None
         self.sent = None
+        # (``t_land`` / ``t_landed``, the stamps its ``land`` and ``settle``
+        # stages are recorded from, exist on a handoff only: _land sets
+        # the first, the engine's placer thread the second.)
 
 
 def _copy_complete(pr: PostedRecv, payload, length: int):
@@ -414,6 +418,7 @@ class TagMatcher:
         msg.posted = pr
         msg.complete = False
         msg.landing = copy
+        msg.t_land = time.perf_counter()  # the ``land`` stage begins
         msg.sent = sent
         self.inflight.add(msg)
         self.counters.handoffs += 1

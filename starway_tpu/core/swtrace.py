@@ -129,8 +129,6 @@ COUNTER_NAMES = (
     "prefetch_started",   # device sends whose D2H copy was started ahead
     #                       of the TX pump (device.py _PrefetchWindow;
     #                       process-global, like the staging pool)
-    "prefetch_depth_peak",  # most such copies in flight at once: a
-    #                       high-water mark, not a sum (process-global)
     "handoffs",           # in-process device payloads whose copy onto
     #                       ANOTHER device was issued (counted on the
     #                       receiving worker; DESIGN.md §12)
@@ -303,8 +301,7 @@ STALL_REASONS = (
 GLOBAL = Counters()
 
 _GLOBAL_NAMES = ("staging_hits", "staging_misses", "prefetch_started",
-                 "prefetch_depth_peak", "reconnects",
-                 "reshard_bytes", "reshard_rounds")
+                 "reconnects", "reshard_bytes", "reshard_rounds")
 
 
 def merge_global_counters(snap: dict) -> dict:
@@ -355,6 +352,15 @@ class TraceRing:
             reason: str = "", dur: float = 0.0) -> None:
         self.events.append(
             (time.perf_counter(), ev, tag, conn, nbytes, reason, dur))
+
+    def span(self, t_end: float, tag: int, nbytes: int, reason: str,
+             dur: float) -> None:
+        """An EV_STAGE span that ENDED at ``t_end`` (perf.record_phase: a
+        message's phases are recorded where it settles, each with the
+        stamp that closed it and all under the message's tag, taken here
+        as the engines take it: an unsigned 64-bit int)."""
+        self.events.append((t_end, EV_STAGE, int(tag) & 0xFFFFFFFFFFFFFFFF, 0,
+                            nbytes, reason, dur))
 
     def snapshot(self) -> list:
         return list(self.events)

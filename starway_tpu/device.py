@@ -47,13 +47,9 @@ import time
 from collections import deque
 from typing import Optional
 
+from . import perf
+
 logger = logging.getLogger("starway_tpu")
-
-
-def _record_stage(name: str, seconds: float, nbytes: int, scope=None) -> None:
-    from . import perf
-
-    perf.record_stage(name, seconds, nbytes, scope)
 
 
 def _np_dtype(dtype):
@@ -233,9 +229,7 @@ class _PrefetchWindow:
         self._depth += 1
         self.peak_bytes = max(self.peak_bytes, self._held)
         self.peak_depth = max(self.peak_depth, self._depth)
-        g = swtrace.GLOBAL
-        g.prefetch_started += 1
-        g.prefetch_depth_peak = max(g.prefetch_depth_peak, self._depth)
+        swtrace.GLOBAL.prefetch_started += 1
         return True
 
     def post(self, payload) -> None:
@@ -377,37 +371,53 @@ class DevicePayload:
     the poster for the sends that need a flat view up front (send_device).
     The duck protocol core/ sees is ``nbytes`` + ``as_host_view()``."""
 
-    __slots__ = ("array", "nbytes", "scope", "_host_view", "_pf")
+    __slots__ = ("array", "nbytes", "scope", "tag", "_host_view", "_pf",
+                 "_fetch")
 
     def __init__(self, array):
         self.array = array
         self.nbytes = int(array.nbytes)
         self.scope = None  # owning worker's perf.StageScope (send_device)
+        self.tag = 0       # the message's tag, for its stages (send_device)
         self._host_view: Optional[memoryview] = None
         self._pf = _PF_NONE  # _PrefetchWindow's state for this send
+        # Stamps of start_fetch, (t0, t1): the ``fetch_start`` stage,
+        # recorded with ``stage`` when the view is taken (DESIGN.md §12).
+        self._fetch = None
 
     def start_fetch(self) -> None:
-        try:
-            self.array.copy_to_host_async()
-        except Exception:
-            # Best-effort (a deleted or donated array raises here): the
-            # np.asarray of as_host_view still blocks, or raises where the
-            # TX pump can fail the send.
-            logger.debug("copy_to_host_async refused", exc_info=True)
+        with perf.xfer_note("fetch_start"):
+            t0 = time.perf_counter()
+            try:
+                self.array.copy_to_host_async()
+            except Exception:
+                # Best-effort (a deleted or donated array raises here):
+                # the np.asarray of as_host_view still blocks, or raises
+                # where the TX pump can fail the send.
+                logger.debug("copy_to_host_async refused", exc_info=True)
+            self._fetch = (t0, time.perf_counter())
 
     def as_host_view(self) -> memoryview:
         if self._host_view is None:
             import numpy as np
 
-            t0 = time.perf_counter()
-            host = np.ascontiguousarray(np.asarray(self.array))
-            # view(uint8) first: extension dtypes (ml_dtypes bfloat16 et
-            # al) have no buffer-protocol format char, so memoryview()
-            # on the raw array raises for exactly the payloads TPU work
-            # ships most.
-            self._host_view = memoryview(host.view(np.uint8)).cast("B")
-            _record_stage("stage", time.perf_counter() - t0, self.nbytes,
-                          self.scope)
+            with perf.xfer_note("stage"):
+                t0 = time.perf_counter()
+                host = np.ascontiguousarray(np.asarray(self.array))
+                # view(uint8) first: extension dtypes (ml_dtypes bfloat16
+                # et al) have no buffer-protocol format char, so
+                # memoryview() on the raw array raises for exactly the
+                # payloads TPU work ships most.
+                self._host_view = memoryview(host.view(np.uint8)).cast("B")
+                t1 = time.perf_counter()
+            fetch = self._fetch
+            if fetch is None:
+                perf.record_stage("stage", t1 - t0, self.nbytes, self.scope)
+            else:
+                f0, f1 = fetch
+                perf.record_stages(self.scope, self.tag, (
+                    ("fetch_start", f1 - f0, self.nbytes, f1),
+                    ("stage", t1 - t0, self.nbytes, t1)))
         return self._host_view
 
 
@@ -420,11 +430,20 @@ class DeviceRecvSink:
     engine runs it beside its thread, DESIGN.md §12) and :meth:`deliver`
     swaps the array into the DeviceBuffer."""
 
-    __slots__ = ("devbuf", "scope", "_staging", "_staging_view")
+    __slots__ = ("devbuf", "scope", "queued", "issue_s", "_staging",
+                 "_staging_view")
 
     def __init__(self, devbuf: DeviceBuffer):
         self.devbuf = devbuf
         self.scope = None  # owning worker's perf.StageScope (post_device_recv)
+        # Stamps the message's stages are recorded from (DESIGN.md §12):
+        # ``queued``, (tag, seconds) of the wait for the placer thread,
+        # set by the engine when that thread takes the message and
+        # recorded with ``place``; ``issue_s``, the seconds accept_device
+        # spent enqueueing a copy onto another chip, recorded by the
+        # engine when the handoff settles.
+        self.queued = None
+        self.issue_s = 0.0
         self._staging = None
         self._staging_view: Optional[memoryview] = None
 
@@ -497,13 +516,22 @@ class DeviceRecvSink:
         arr = raw.view(self.devbuf.dtype)
         if length == self.nbytes:
             arr = arr.reshape(self.devbuf.shape)
-        t0 = time.perf_counter()
-        placed = _fast_h2d(arr, self.devbuf.device)
-        copied = placed is not None
-        if not copied:
-            placed = jax.device_put(arr)
-        placed.block_until_ready()
-        _record_stage("place", time.perf_counter() - t0, length, self.scope)
+        with perf.xfer_note("place"):
+            t0 = time.perf_counter()
+            placed = _fast_h2d(arr, self.devbuf.device)
+            copied = placed is not None
+            if not copied:
+                placed = jax.device_put(arr)
+            placed.block_until_ready()
+            t1 = time.perf_counter()
+        queued, self.queued = self.queued, None
+        if queued is None:
+            perf.record_stage("place", t1 - t0, length, self.scope)
+        else:
+            tag, waited = queued
+            perf.record_stages(self.scope, tag, (
+                ("place_queue", waited, 0, t0),
+                ("place", t1 - t0, length, t1)))
         return placed, copied
 
     def accept_device(self, array):
@@ -520,14 +548,20 @@ class DeviceRecvSink:
         if target is not None:
             src_devs = array.devices() if hasattr(array, "devices") else set()
             if src_devs != {target}:
-                return _copy_to_device(array, target, self.devbuf._plan)
+                with perf.xfer_note("issue"):
+                    t0 = time.perf_counter()
+                    copy = _copy_to_device(array, target, self.devbuf._plan)
+                    self.issue_s = time.perf_counter() - t0
+                return copy
         self.deliver_device(array)
         return None
 
     @staticmethod
     def land(copy) -> None:
         """Block until ``copy`` (from :meth:`accept_device`) is resident."""
-        copy.block_until_ready()
+        # (the placer's part of the ``land`` stage)
+        with perf.xfer_note("land"):
+            copy.block_until_ready()
 
     def deliver_device(self, array) -> None:
         self.devbuf.array = array
@@ -771,6 +805,7 @@ def send_device(worker, conn, buffer, tag, done, fail):
     else:
         payload = DevicePayload(buffer)
     payload.scope = getattr(worker, "stage_scope", None)
+    payload.tag = tag
     if conn is not None and conn.kind == "inproc":
         worker.submit_send(conn, payload, tag, done, fail, payload)
         return

@@ -25,8 +25,11 @@ peer's matcher consumes and drops them (core/matching.py, sw_engine.cpp).
 
 from __future__ import annotations
 
+import contextlib
+import sys
 import threading
 import time
+import weakref
 
 # ------------------------------------------------------ per-stage telemetry
 #
@@ -38,15 +41,30 @@ import time
 #   ``rx``    -- transport reads (core/conn.py)
 #   ``place`` -- host-to-device placement (H2D) on the receive side
 #
-# Recording is two perf_counter calls + one short lock per transport
-# syscall -- noise next to the syscall itself.  Samples land twice: in the
+# and where a device message WAITS (:data:`MSG_STAGES`; DESIGN.md §12 has
+# the table: from -> to, thread, the metric that reads each):
+#
+#   ``post``        -- an ``asend`` / ``arecv`` / ``aflush`` call (api.py)
+#   ``fetch_start`` -- starting a queued send's D2H copy (device.py)
+#   ``issue``       -- enqueueing a handoff's chip-to-chip copy (device.py)
+#   ``land``        -- that copy issued -> resident (matching.py, engine.py)
+#   ``settle``      -- resident -> its completions fired (engine.py)
+#   ``loop_hop``    -- a completion called off the loop -> applied on it
+#   ``place_queue`` -- a received message waiting for the placer (engine.py)
+#   ``ring_wait``   -- a producer blocked on a full sm ring (core/conn.py)
+#
+# These ride the message as plain ``perf_counter`` stamps and are recorded
+# ONCE, where it settles (:func:`record_stages`: every phase under the
+# message's tag).
+#
+# Recording is two perf_counter calls and a few list-slot ``+=`` per
+# transport syscall, with NO lock (the note above :data:`_live` says why)
+# -- noise next to the syscall itself.  A sample lands ONCE, in the
 # recorder's :class:`StageScope` (per worker, so two concurrent clients --
 # or bench loopback's two roles -- never pollute each other's
-# ``evaluate_perf_detail()["stages"]``) and in the module-level aggregate
-# below (the whole-process view bench.py and the bench CLI report).
-
-_stage_lock = threading.Lock()
-_stages: dict[str, list] = {}  # name -> [count, seconds, bytes]
+# ``evaluate_perf_detail()["stages"]``); the whole-process view bench.py
+# and the bench CLI report (:func:`stage_snapshot`) adds the scopes up
+# when it is read.
 
 
 class StageScope:
@@ -57,12 +75,21 @@ class StageScope:
     a bench run's Chrome export shows the stage timeline per op stream.
     """
 
-    __slots__ = ("_lock", "_stages", "ring")
+    __slots__ = ("_lock", "_stages", "_msg", "ring", "__weakref__")
 
     def __init__(self, ring=None):
+        # ``record``'s samples (the layers above the engines: stage_span),
+        # this scope's alone, under its lock.
         self._lock = threading.Lock()
         self._stages: dict[str, list] = {}
+        # record_stage's and record_phase's samples (the engines, the
+        # device plane, the API), written with no lock.  They are counted
+        # HERE only: the module's view adds the scopes up when it is read
+        # (_live), and keeps what a scope held when it goes (_retire).
+        self._msg: dict[str, list] = {}
         self.ring = ring
+        _live.add(self)
+        weakref.finalize(self, _retire, self._msg).atexit = False
 
     def record(self, name: str, seconds: float, nbytes: int = 0) -> None:
         with self._lock:
@@ -76,14 +103,41 @@ class StageScope:
 
     def snapshot(self) -> dict:
         with self._lock:
-            return _render_stages(self._stages)
+            return _render_stages(self._stages, [self._msg])
 
     def reset(self) -> None:
         with self._lock:
             self._stages.clear()
+            self._msg.clear()
 
 
 _annotation = None  # jax.profiler.TraceAnnotation, bound at first use
+
+
+_NO_NOTE = contextlib.nullcontext()  # a phase that nobody is tracing
+
+
+def xfer_note(name: str):
+    """``with xfer_note(name):`` -- a ``TraceAnnotation("sw:xfer.<name>")``
+    while a profiler session runs, else a null context: a transport phase
+    that runs on ONE thread lands in ``/host:CPU`` of the xplane on the
+    clock of the device's programs, as ``sw:serve.*`` do.  jax is never
+    imported for this: a process that has none (the engines' chip-less
+    peers) pays a dict lookup, and with no session running the price is
+    a flag check."""
+    global _annotation
+    note = _annotation
+    if note is None:
+        if "jax" not in sys.modules:
+            return _NO_NOTE
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:  # jax is mid-import on another thread
+            return _NO_NOTE
+        note = _annotation = TraceAnnotation
+    if not note.is_enabled():
+        return _NO_NOTE
+    return note("sw:xfer." + name)
 
 
 class stage_span:
@@ -146,9 +200,23 @@ def percentile(sorted_vals, q: float) -> float:
     return sorted_vals[idx]
 
 
-def _render_stages(stages: dict) -> dict:
+def _sum_into(total: dict, msg: dict) -> None:
+    """``msg`` may be written meanwhile, with no lock: the copy of its
+    items is one atomic call."""
+    for name, (count, seconds, nbytes) in list(msg.items()):
+        acc = total.setdefault(name, [0, 0.0, 0])
+        acc[0] += count
+        acc[1] += seconds
+        acc[2] += nbytes
+
+
+def _render_stages(stages: dict, msgs) -> dict:
+    """``stages`` (its lock held) and the ``msgs`` dicts as one view."""
+    total = {name: list(acc) for name, acc in stages.items()}
+    for msg in msgs:
+        _sum_into(total, msg)
     out = {}
-    for name, (count, seconds, nbytes) in stages.items():
+    for name, (count, seconds, nbytes) in total.items():
         out[name] = {
             "count": count,
             "seconds": seconds,
@@ -160,39 +228,98 @@ def _render_stages(stages: dict) -> dict:
 
 def record_stage(name: str, seconds: float, nbytes: int = 0,
                  scope: "StageScope | None" = None) -> None:
-    """Accumulate one sample for pipeline stage ``name`` (thread-safe;
-    called from engine threads and the app thread alike).  ``scope`` is
-    the recording worker's :class:`StageScope`; the module aggregate is
-    always updated too."""
-    with _stage_lock:
-        acc = _stages.get(name)
-        if acc is None:
-            _stages[name] = [1, seconds, nbytes]
-        else:
-            acc[0] += 1
-            acc[1] += seconds
-            acc[2] += nbytes
-    if scope is not None:
-        scope.record(name, seconds, nbytes)
-        ring = scope.ring
-        if ring is not None:
-            from .core import swtrace
+    """Accumulate one sample for pipeline stage ``name`` (``tx`` / ``rx``
+    a transport syscall, ``stage`` / ``place`` a message) into ``scope``,
+    the recording worker's :class:`StageScope` -- and so into
+    :func:`stage_snapshot` -- with no lock taken: :func:`record_phase`
+    with no tag, closed now.  Its body written out, because ``tx`` and
+    ``rx`` run hundreds of times a message (each paid two locks until
+    PR 35) and a second call doubles what a sample costs."""
+    if scope is None:
+        scope = _orphans
+    msg = scope._msg
+    acc = msg.get(name) or msg.setdefault(name, [0, 0.0, 0])
+    acc[0] += 1
+    acc[1] += seconds
+    acc[2] += nbytes
+    ring = scope.ring
+    if ring is not None:
+        ring.span(time.perf_counter(), 0, nbytes, name, seconds)
 
-            ring.rec(swtrace.EV_STAGE, 0, 0, nbytes, name, seconds)
+
+#: Where a device message waits: the stages recorded once a message by
+#: :func:`record_stages` (the table is in DESIGN.md §12).
+MSG_STAGES = ("post", "fetch_start", "issue", "land", "settle", "loop_hop",
+              "place_queue", "ring_wait")
+
+# A message settles on the threads that can least afford a lock another
+# thread holds: the poster between two posts, an engine between two
+# landings (chip, PR 35: the same records under a process-wide lock and
+# the scope's cost `hbm_duplex.a2a_16m_x4` 0.44 ms of a 5.6 ms round;
+# PERF.md section 6).  So stages are accumulated as the worker counters
+# and the swpulse histograms are (core/swtrace.py): plain ``+=`` on a list
+# slot of the recording worker's scope, no lock, ONE place.  A stage has
+# one writing thread a worker as a rule (the loop's for ``post`` /
+# ``loop_hop``, the engine's for ``tx`` / ``rx`` / ``issue`` / ``land`` /
+# ``settle`` / ``ring_wait``, the placer's for ``place`` /
+# ``place_queue``); the window in which two writers could lose a sample
+# is theoretical and telemetry tolerates it.  The module's view is the
+# sum over the scopes, taken when read.
+_live: "weakref.WeakSet[StageScope]" = weakref.WeakSet()
+_retired: dict[str, list] = {}  # what the scopes that are gone had recorded
+
+
+def _retire(msg: dict) -> None:
+    """A scope's finaliser.  It can run wherever the collector does, so
+    it takes no lock either."""
+    _sum_into(_retired, msg)
+
+
+_orphans = StageScope()  # samples recorded with no scope
+
+
+def record_phase(scope: "StageScope | None", tag: int, name: str,
+                 seconds: float, nbytes: int, t_end: float) -> None:
+    """ONE phase a message (or an op) waited in, ``t_end`` the
+    ``perf_counter`` reading that closed it: into ``scope``, no lock
+    taken (and so into :func:`stage_snapshot`, which adds the scopes up).
+    With a ring on the scope it lands as an ``EV_STAGE`` whose tag is
+    ``tag`` -- the message's -- and whose time is ``t_end``, not the time
+    of this call."""
+    if scope is None:
+        scope = _orphans
+    msg = scope._msg
+    acc = msg.get(name) or msg.setdefault(name, [0, 0.0, 0])
+    acc[0] += 1
+    acc[1] += seconds
+    acc[2] += nbytes
+    ring = scope.ring
+    if ring is not None:
+        ring.span(t_end, tag, nbytes, name, seconds)
+
+
+def record_stages(scope: "StageScope | None", tag: int, stages) -> None:
+    """Every phase of ONE message at once, where it settles: ``stages``
+    is ``[(name, seconds, nbytes, t_end), ...]`` from the stamps the
+    message carried (:func:`record_phase` each).  One call a message; the
+    phases share its tag, so ``python -m starway_tpu.trace`` draws them
+    as one chain, each where it ran."""
+    for name, seconds, nbytes, t_end in stages:
+        record_phase(scope, tag, name, seconds, nbytes, t_end)
 
 
 def stage_snapshot() -> dict:
     """``{stage: {"count", "seconds", "bytes", "gbps"}}`` accumulated since
     process start (or the last :func:`stage_reset`) -- the whole-process
     aggregate; per-worker views live on ``Worker.stage_scope``."""
-    with _stage_lock:
-        return _render_stages(_stages)
+    return _render_stages({}, [_retired] + [s._msg for s in list(_live)])
 
 
 def stage_reset() -> None:
     """Drop accumulated stage samples (bench warmup boundary)."""
-    with _stage_lock:
-        _stages.clear()
+    _retired.clear()
+    for scope in list(_live):
+        scope._msg.clear()
 
 
 # transport -> (alpha seconds, beta bytes/second)

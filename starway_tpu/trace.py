@@ -32,7 +32,12 @@ matches -- stage spans (``stage_span`` events from perf.record_stage, and
 the serve scope's ``serve.*`` / ``bridge.*`` phases from perf.stage_span,
 whose ``args.tag`` is the request id) as
 "X" spans of their measured duration, and everything unpaired (matches,
-E2E ordinals, connection churn) as instants.
+E2E ordinals, connection churn) as instants.  The stages ONE device
+message waited in (perf.record_stages: ``post`` -> ``issue`` -> ``land``
+-> ``settle`` -> ``loop_hop``; ``fetch_start`` -> ``stage``;
+``place_queue`` -> ``place``; ``ring_wait``) carry the message's tag and
+render on a track of that message's own ("msg tag=0x..."), in the order
+their stamps were taken, whichever thread took each.
 """
 
 from __future__ import annotations
@@ -45,7 +50,7 @@ from pathlib import Path
 from typing import Iterable, Optional
 
 from .core import swtrace
-from .perf import percentile
+from .perf import MSG_STAGES, percentile
 
 # POST event -> (span kind, terminal event)
 _POSTS = {
@@ -63,6 +68,11 @@ _DONES = {
 #: any realistic per-process conn id, so epoch tracks never collide with
 #: epoch-0 tracks (which keep tid = conn id).
 _EPOCH_TID_BASE = 1_000_000
+
+#: First synthetic tid of the per-message tracks, and the stage names that
+#: go there when their event carries a tag (perf.record_stages).
+_MSG_TID_BASE = 2_000_000
+_MSG_TRACK_STAGES = frozenset(MSG_STAGES) | {"stage", "place"}
 
 
 def _pop_start(open_spans: dict, kind: str, tag: int, fifo_fallback: bool):
@@ -123,6 +133,15 @@ def chrome_events(label: str, events: Iterable, pid: int,
             tid_map[(conn, e)] = t
         return t
 
+    msg_tids: dict = {}  # message tag -> tid of its own track
+
+    def msg_tid_of(tag: int) -> int:
+        t = msg_tids.get(tag)
+        if t is None:
+            t = msg_tids[tag] = _MSG_TID_BASE + pid * 10_000 + len(msg_tids)
+            tid_label[t] = f"msg tag={tag:#x}"
+        return t
+
     open_spans: dict = {}  # (kind, tag) -> deque[(ts_us, conn, nbytes)]
     for t, ev, tag, conn, nbytes, reason, dur in events:
         ts = (t + ts_shift) * 1e6
@@ -161,7 +180,10 @@ def chrome_events(label: str, events: Iterable, pid: int,
         elif ev == swtrace.EV_STAGE:
             out.append({"ph": "X", "name": reason or "stage",
                         "ts": ts - dur * 1e6, "dur": max(0.0, dur * 1e6),
-                        "pid": pid, "tid": tid_of(conn), "cat": "stage",
+                        "pid": pid, "cat": "stage",
+                        "tid": (msg_tid_of(tag)
+                                if tag and reason in _MSG_TRACK_STAGES
+                                else tid_of(conn)),
                         "args": {"nbytes": nbytes, "tag": tag}})
         else:  # recv_match, conn churn, e2e, clock, anything future
             if e2e_out is not None and ev == swtrace.EV_E2E:

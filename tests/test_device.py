@@ -496,3 +496,142 @@ async def test_cross_device_handoffs_all_to_all_under_switching():
         await c.aclose()
     for s in servers:
         await s.aclose()
+
+
+# ------------------------------- where a message waits (DESIGN.md §12, §13)
+
+
+def _stage(worker, name: str) -> dict:
+    return worker.stage_scope.snapshot().get(
+        name, {"count": 0, "seconds": 0.0, "bytes": 0})
+
+
+@pytest.mark.parametrize("case", [
+    "handoff_across_devices", "handoff_same_device", "staged_over_full_ring",
+    "loop_hop_from_engine_thread", "no_loop_hop_inline", "ring_carries_tag"])
+async def test_message_stages(port, monkeypatch, case):
+    """A message carries its own stamps and records them once, into the
+    scope of the worker it waited in: ``post`` a call of the API;
+    ``issue`` / ``land`` / ``settle`` a handoff onto another device;
+    ``fetch_start`` / ``place_queue`` a staged message and ``ring_wait`` a
+    block on a full ring; ``loop_hop`` a completion that crossed to the
+    event loop; with a ring on the scope every phase of one message under
+    that message's tag."""
+    from starway_tpu import trace as trace_mod
+    from starway_tpu.core import swtrace
+
+    devs = jax.devices()
+    if case == "staged_over_full_ring":
+        import platform
+
+        if platform.machine() not in ("x86_64", "AMD64"):
+            pytest.skip("python sm transport requires x86-64")
+        monkeypatch.setenv("STARWAY_TLS", "sm,tcp")
+        monkeypatch.setenv("STARWAY_SM_RING", "65536")  # under one message
+    elif case == "loop_hop_from_engine_thread":
+        monkeypatch.setenv("STARWAY_TLS", "tcp")
+    if case in ("staged_over_full_ring", "loop_hop_from_engine_thread"):
+        monkeypatch.setenv("STARWAY_NATIVE", "0")
+        monkeypatch.setenv("STARWAY_DEVPULL", "0")
+    if case == "ring_carries_tag":
+        monkeypatch.setenv("STARWAY_TRACE", "1")
+        swtrace.reset()
+    server, client = await _pair(port)
+    rx, tx = server._server, client._client
+    try:
+        if case in ("loop_hop_from_engine_thread", "no_loop_hop_inline"):
+            n = 5
+            sinks = [np.empty(256, np.uint8) for _ in range(n)]
+            recvs = [server.arecv(b, 0x70 + k, MASK) for k, b in enumerate(sinks)]
+            await asyncio.sleep(0.05)
+            await asyncio.gather(*(client.asend(np.full(256, k, np.uint8), 0x70 + k)
+                                   for k in range(n)))
+            await asyncio.gather(*recvs)
+            await client.aflush()
+            assert _stage(rx, "post")["count"] == n          # the receives
+            assert _stage(tx, "post")["count"] == n + 1      # sends + flush
+            if case == "no_loop_hop_inline":
+                # Matched and completed on the poster's own thread, which
+                # is the loop's: nothing crossed.
+                assert _stage(rx, "loop_hop")["count"] == 0
+                assert _stage(tx, "loop_hop")["count"] == 0
+            else:
+                # Completions fired on the engines' threads: every one
+                # crossed, and waited a positive time for the loop.
+                assert _stage(rx, "loop_hop")["count"] == n
+                assert _stage(tx, "loop_hop")["count"] == n + 1
+                assert _stage(rx, "loop_hop")["seconds"] > 0
+            return
+
+        n = 3
+        words = (1 << 20) // 4 if case == "staged_over_full_ring" else 4096
+        same = case == "handoff_same_device"
+        srcs = [jax.device_put(jnp.full((words,), k + 1, jnp.int32), devs[0])
+                for k in range(n)]
+        sinks = [DeviceBuffer((words,), jnp.int32,
+                              device=devs[0] if same else devs[1 + k])
+                 for k in range(n)]
+        recvs = [server.arecv(s, 0xA0 + k, MASK) for k, s in enumerate(sinks)]
+        await asyncio.sleep(0.02)
+        sends = [client.asend(a, 0xA0 + k) for k, a in enumerate(srcs)]
+        await asyncio.gather(*sends, *recvs)
+        await client.aflush()
+        for k, s in enumerate(sinks):
+            assert int(s.array[0]) == int(s.array[-1]) == k + 1
+        assert _stage(rx, "post")["count"] == n
+        assert _stage(tx, "post")["count"] == n + 1
+
+        if case == "handoff_same_device":
+            # A reference handoff, complete on return: no copy, no wait.
+            assert set(rx.stage_scope.snapshot()) == {"post"}
+            assert set(tx.stage_scope.snapshot()) <= {"post", "loop_hop"}
+            assert rx.counters_snapshot()["handoffs"] == 0
+        elif case == "staged_over_full_ring":
+            assert _stage(tx, "fetch_start")["count"] == n
+            assert _stage(tx, "stage")["count"] == n
+            assert _stage(rx, "place_queue")["count"] == n
+            assert _stage(rx, "place")["count"] == n
+            assert _stage(rx, "place_queue")["seconds"] >= 0
+            # 16 rings' worth a message: the producer blocked again and
+            # again, and a message's blocks are ONE sample, recorded when
+            # its last byte is in the ring, however many puts it took.
+            waits, puts = _stage(tx, "ring_wait"), _stage(tx, "tx")
+            assert 1 <= waits["count"] <= n + 1 < puts["count"], (waits, puts)
+            assert waits["seconds"] > 0
+        else:
+            assert rx.counters_snapshot()["handoffs"] == n
+            for name in ("issue", "land", "settle"):
+                got = _stage(rx, name)
+                assert got["count"] == n, (name, got)
+                assert got["seconds"] >= 0
+            assert _stage(rx, "issue")["bytes"] == n * words * 4
+            assert not set(tx.stage_scope.snapshot()) & {"issue", "land", "settle"}
+
+        if case == "ring_carries_tag":
+            # One message, one identifier: its phases on the receiving
+            # worker, in the order their stamps were taken ...
+            want = ["post", "issue", "land", "settle", "loop_hop"]
+            for k in range(n):
+                mine = [e for e in rx.trace_events()
+                        if e[1] == swtrace.EV_STAGE and e[2] == 0xA0 + k]
+                assert sorted((e[5] for e in mine), key=want.index) == want, mine
+                ends = {e[5]: e[0] for e in mine}
+                assert (ends["post"] <= ends["issue"] <= ends["land"]
+                        <= ends["settle"]), ends
+            assert all(e[2] for e in rx.trace_events()
+                       if e[1] == swtrace.EV_STAGE), "an untagged message stage"
+            # ... and the Chrome export draws them as ONE chain a message.
+            doc = trace_mod.to_chrome(
+                [{"worker": rx.trace_label, "events": rx.trace_events()}])
+            spans = [e for e in doc["traceEvents"] if e.get("cat") == "stage"]
+            tracks = {e["tid"] for e in spans if e["args"]["tag"] == 0xA1}
+            assert len(tracks) == 1, tracks
+            chain = sorted((e for e in spans if e["tid"] in tracks),
+                           key=lambda e: e["ts"] + e["dur"])
+            assert [e["name"] for e in chain if e["name"] != "loop_hop"] == want[:4]
+            names = {e["args"]["name"] for e in doc["traceEvents"]
+                     if e["ph"] == "M" and e["name"] == "thread_name"}
+            assert "msg tag=0xa1" in names, names
+    finally:
+        await client.aclose()
+        await server.aclose()

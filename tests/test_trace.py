@@ -16,6 +16,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 
 import numpy as np
 import pytest
@@ -284,6 +285,7 @@ async def test_tracing_off_hot_path_is_dark(port, monkeypatch):
             raise AssertionError("swtrace hot-path hook ran with tracing off")
 
         monkeypatch.setattr(swtrace.TraceRing, "rec", boom)
+        monkeypatch.setattr(swtrace.TraceRing, "span", boom)
         monkeypatch.setattr(swtrace, "wrap_op", boom)
         monkeypatch.setattr(swtrace, "flight_dump", boom)
         sinks = [np.empty(512, dtype=np.uint8) for _ in range(8)]
@@ -299,6 +301,156 @@ async def test_tracing_off_hot_path_is_dark(port, monkeypatch):
     finally:
         await client.aclose()
         await server.aclose()
+
+
+_NO_JAX_SCRIPT = r"""
+import asyncio, sys
+import numpy as np
+from starway_tpu import Client, Server, perf
+
+async def main():
+    server, client = Server(), Client()
+    server.listen("127.0.0.1", 0)
+    await client.aconnect_address(server.get_worker_address())
+    sink = np.empty(512, np.uint8)
+    fut = server.arecv(sink, 5, (1 << 64) - 1)
+    await client.asend(np.ones(512, np.uint8), 5)
+    await fut
+    await client.aflush()
+    posts = perf.stage_snapshot()["post"]["count"]
+    await client.aclose()
+    await server.aclose()
+    print("posts", posts, "jax", "jax" in sys.modules)
+
+asyncio.run(main())
+"""
+
+
+@pytest.mark.parametrize("case", ["three_records_an_op", "no_jax_import"])
+async def test_message_stage_record_count(port, monkeypatch, case):
+    """The COUNT guard beside the overhead guard (steady, no timing): the
+    stamps ride the message and are recorded once where each part of it
+    settles, with no lock taken.  One in-process device message across two
+    devices costs its receive THREE record calls (the poster's ``post``,
+    the engine's ONE call for ``issue`` / ``land`` / ``settle``, the
+    loop's ``loop_hop``) and its send two; and a process that had no jax
+    gets none from the stamps or their annotations."""
+    if case == "no_jax_import":
+        env = dict(os.environ, STARWAY_NATIVE="0")
+        env.pop("STARWAY_TRACE", None)
+        out = subprocess.run([sys.executable, "-c", _NO_JAX_SCRIPT], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.split() == ["posts", "3", "jax", "False"], out.stdout
+        return
+    import jax
+
+    monkeypatch.delenv("STARWAY_TRACE", raising=False)
+    monkeypatch.delenv("STARWAY_FLIGHT_DIR", raising=False)
+    server, client = Server(), Client()
+    server.listen(ADDR, port)
+    await client.aconnect(ADDR, port)
+    calls: list = []   # (worker's scope, tag, the phases of ONE call)
+    inside: list = []  # a record_stages call is running on this thread
+    real_many, real_one = perf.record_stages, perf.record_phase
+
+    def many(scope, tag, stages):
+        calls.append((scope, tag, tuple(st[0] for st in stages)))
+        inside.append(threading.get_ident())
+        try:
+            real_many(scope, tag, stages)
+        finally:
+            inside.remove(threading.get_ident())
+
+    def one(scope, tag, name, *rest):
+        if threading.get_ident() not in inside:
+            calls.append((scope, tag, (name,)))
+        real_one(scope, tag, name, *rest)
+
+    def per_syscall(*a, **k):
+        raise AssertionError("a handoff recorded a tx / rx / stage / place")
+
+    try:
+        n = 4
+        srcs = [jax.device_put(jnp.full((1024,), k, jnp.int32), jax.devices()[0])
+                for k in range(n)]
+        sinks = [DeviceBuffer((1024,), jnp.int32, device=jax.devices()[1 + k])
+                 for k in range(n)]
+        monkeypatch.setattr(perf, "record_stages", many)
+        monkeypatch.setattr(perf, "record_phase", one)
+        monkeypatch.setattr(perf, "record_stage", per_syscall)
+        recvs = [server.arecv(s, 0x50 + k, MASK) for k, s in enumerate(sinks)]
+        await asyncio.sleep(0.02)
+        await asyncio.gather(
+            *(client.asend(a, 0x50 + k) for k, a in enumerate(srcs)), *recvs)
+        for _ in range(400):   # the engine's record runs behind the fires
+            if sum("settle" in c[2] for c in calls) == n:
+                break
+            await asyncio.sleep(0.005)
+        rx, tx = server._server.stage_scope, client._client.stage_scope
+        for k in range(n):
+            mine = [c for c in calls if c[1] == 0x50 + k]
+            assert sorted(c[2] for c in mine if c[0] is rx) == [
+                ("issue", "land", "settle"), ("loop_hop",), ("post",)], mine
+            assert sorted(c[2] for c in mine if c[0] is tx) == [
+                ("loop_hop",), ("post",)], mine
+        assert len(calls) == 5 * n, calls
+    finally:
+        await client.aclose()
+        await server.aclose()
+
+
+@pytest.mark.parametrize("case", [
+    "once_in_its_scope", "module_adds_the_scopes_up", "a_scope_that_goes",
+    "no_scope", "reset", "ring_event"])
+def test_stage_recorder_views(case):
+    """ONE recorder (DESIGN.md §12, §13): ``record_stage`` (a syscall's
+    ``tx`` / ``rx``, a message's ``stage`` / ``place``) and ``record_phase``
+    put a sample in the recording worker's scope alone, and
+    ``perf.stage_snapshot()`` is the sum over the scopes, those that are
+    gone included."""
+    import gc
+
+    def seen(name):
+        return perf.stage_snapshot().get(name, {"count": 0, "bytes": 0})
+
+    name = f"t_{case}"  # this test's own stage: other tests' workers live on
+    a, b = perf.StageScope(), perf.StageScope()
+    if case == "once_in_its_scope":
+        perf.record_stage(name, 0.5, 100, a)
+        perf.record_phase(a, 7, name, 0.25, 50, 1.0)
+        assert a.snapshot()[name] == {"count": 2, "seconds": 0.75,
+                                      "bytes": 150, "gbps": 150 / 0.75 / 1e9}
+        assert name not in b.snapshot()
+    elif case == "module_adds_the_scopes_up":
+        perf.record_stage(name, 0.5, 100, a)
+        perf.record_stage(name, 0.5, 100, b)
+        a.record(name, 9.0, 9)  # a layer above the engines: its scope's own
+        assert seen(name)["count"] == 2 and seen(name)["bytes"] == 200
+        assert a.snapshot()[name]["count"] == 2
+    elif case == "a_scope_that_goes":
+        perf.record_stage(name, 0.5, 100, a)
+        del a
+        gc.collect()
+        assert seen(name)["count"] == 1
+    elif case == "no_scope":
+        perf.record_stage(name, 0.5, 100)
+        perf.record_phase(None, 0, name, 0.5, 100, 1.0)
+        assert seen(name)["count"] == 2
+        assert name not in a.snapshot()
+    elif case == "reset":
+        perf.record_stage(name, 0.5, 100, a)
+        del b
+        gc.collect()
+        perf.stage_reset()
+        assert name not in perf.stage_snapshot()
+    else:
+        a.ring = swtrace.TraceRing(8)
+        perf.record_stage(name, 0.5, 100, a)          # untagged, stamped now
+        perf.record_phase(a, 0xBEEF, name, 0.25, 50, 123.0)
+        untagged, tagged = a.ring.snapshot()
+        assert untagged[1:] == (swtrace.EV_STAGE, 0, 0, 100, name, 0.5)
+        assert tagged == (123.0, swtrace.EV_STAGE, 0xBEEF, 0, 50, name, 0.25)
 
 
 # ---------------------------------------------------------- chrome export
