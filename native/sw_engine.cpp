@@ -1037,7 +1037,7 @@ bool sm_enabled() {
 
 uint64_t sm_ring_size() {
   const char* e = getenv("STARWAY_SM_RING");
-  uint64_t r = e ? strtoull(e, nullptr, 10) : (uint64_t)(1u << 20);
+  uint64_t r = e ? strtoull(e, nullptr, 10) : (uint64_t)(1u << 24);
   if (r < 4096) r = 4096;
   if (r > (1ull << 30)) r = 1ull << 30;
   // round up to a power of two
@@ -1951,6 +1951,7 @@ struct Conn {
   SmRing sm_tx{}, sm_rx{};
   bool sm_active = false;
   bool sm_negotiated = false;  // sticky: survives teardown for introspection
+  uint64_t sm_ring = 0;        // bytes a direction; sticky like sm_negotiated
   bool tx_via_ring = false;
   // Doorbell bytes that hit a full socket buffer: flushed on EPOLLOUT.  A
   // starving byte is the only wakeup a ring-blocked producer gets, so
@@ -2033,6 +2034,7 @@ struct Conn {
     }
     sm_active = true;
     sm_negotiated = true;
+    sm_ring = seg->ring_size;
     seg->unlink();
     if (!defer_tx) {
       if (tx.empty()) tx_via_ring = true;
@@ -6630,7 +6632,7 @@ std::string wire_decode_stream(const uint8_t* buf, uint64_t n, bool csum) {
 
 std::string wire_decode_recs(const uint8_t* buf, uint64_t n) {
   uint64_t pos = 0, consumed = 0, seq = 0;
-  const uint64_t ring_size = 1ull << 20;  // shmring.DEFAULT_RING model size
+  const uint64_t ring_size = 1ull << 24;  // shmring.DEFAULT_RING model size
   DecodeOut o;
   char tmp[32];
   for (;;) {
@@ -7062,11 +7064,13 @@ int sw_conn_info(void* h, uint64_t conn_id, char* out, int cap) {
                    "{\"name\": \"%s\", \"mode\": \"%s\", \"alive\": %d, "
                    "\"local_addr\": \"%s\", \"local_port\": %d, "
                    "\"remote_addr\": \"%s\", \"remote_port\": %d, "
-                   "\"transport\": \"%s\", \"devpull\": %d, \"rails\": %d}",
+                   "\"transport\": \"%s\", \"sm_ring\": %llu, "
+                   "\"devpull\": %d, \"rails\": %d}",
                    c->peer_name.c_str(), c->mode.c_str(), c->alive ? 1 : 0,
                    c->local_addr.c_str(), c->local_port,
                    c->remote_addr.c_str(), c->remote_port,
-                   c->sm_negotiated ? "sm" : "tcp", c->devpull_ok ? 1 : 0,
+                   c->sm_negotiated ? "sm" : "tcp",
+                   (unsigned long long)c->sm_ring, c->devpull_ok ? 1 : 0,
                    (int)c->rails.size());
   if (n < 0 || n >= cap) return -1;
   memcpy(out, buf, (size_t)n + 1);

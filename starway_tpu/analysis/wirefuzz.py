@@ -97,7 +97,7 @@ class Tables:
     ctl_max: int = 0
     header: struct.Struct = struct.Struct("<BQQ")
     sub: struct.Struct = struct.Struct("<QQQ")
-    rec_ring: int = 1 << 20                    # shmring.DEFAULT_RING
+    rec_ring: int = 1 << 24                    # shmring.DEFAULT_RING
     decode_line: int = 1                       # frames.decode_stream anchor
     rec_line: int = 1                          # shmring decoder anchor
 

@@ -15,8 +15,8 @@ benchmark.md:114-126 for ``UCX_TLS``).  The TPU build mirrors that shape:
 
 ``STARWAY_SM_RING``
     Per-direction shared-memory ring size in bytes (rounded up to a power
-    of two; default 1 MiB -- sized to stay cache-resident, see
-    core/shmring.py).
+    of two; default 16 MiB -- four 4 MiB messages deep, sized by a sweep
+    on the chip's host, see core/shmring.py).
 
 ``STARWAY_HOST``
     Routable host address advertised in worker-address blobs (default

@@ -486,6 +486,7 @@ class TcpConn(BaseConn):
         self.sm_rx = None
         self.sm_active = False
         self.sm_negotiated = False  # sticky: survives teardown for introspection
+        self.sm_ring = 0            # ring bytes a direction; sticky likewise
         self._tx_via_ring = False
         # The ``ring_wait`` stage (DESIGN.md §12): ``_ring_blocked`` is the
         # perf_counter reading at which kick_tx left BLOCKED on a full
@@ -590,6 +591,7 @@ class TcpConn(BaseConn):
         self.sm_tx, self.sm_rx = seg.tx_rx(creator)
         self.sm_active = True
         self.sm_negotiated = True
+        self.sm_ring = seg.ring_size
         seg.unlink()
         if not defer_tx:
             if self.tx:

@@ -408,6 +408,11 @@ class NativeConn:
     def remote_port(self) -> int:
         return int(self._info().get("remote_port", 0))
 
+    @property
+    def sm_ring(self) -> int:
+        """Bytes a direction of the conn's sm ring (0: never negotiated)."""
+        return int(self._info().get("sm_ring", 0))
+
     def transports(self) -> list[tuple[str, str]]:
         # The transport is fixed at handshake time: memoize so per-message
         # callers (evaluate_perf) pay the FFI round-trip once.

@@ -66,13 +66,18 @@ Wakeup protocol: every cross-side wakeup rides the TCP socket, never shared
 memory.  A producer that advances ``tail`` sends a doorbell byte (DB_DATA);
 a producer that finds the ring full sends a *starving* byte (DB_STARVING)
 and sleeps; the consumer, upon seeing a starving byte, drains the ring and
-replies with a doorbell.  Because the signal is a send/recv syscall pair,
+replies with ONE doorbell.  Because the signal is a send/recv syscall pair,
 the sleeping side's next cursor read is ordered after the waking side's
 cursor write (the kernel transition is a full barrier on both ends) -- the
 classic store-load race of flag-based schemes cannot occur, in any
 language, with no fence and no timed poll.  A doorbell that meets a full
 socket buffer is queued and flushed on EPOLLOUT (core/conn.py), so the one
-wakeup a sleeping producer depends on is never dropped.
+wakeup a sleeping producer depends on is never dropped.  A parked producer
+and its consumer copy in turn, two socket wake-ups a turn, so what keeps
+both copying at once is a ring that seldom fills: ``DEFAULT_RING`` holds
+several bulk messages.  Only a message larger than the ring still pays
+the turns (an earlier reply, at half the ring free, was measured and left
+out: ROADMAP.md S8).
 """
 
 from __future__ import annotations
@@ -82,6 +87,8 @@ import mmap
 import os
 import secrets
 import struct
+
+import numpy as np
 
 from . import frames
 
@@ -105,12 +112,39 @@ _SEQ8 = struct.Struct("<Q")
 
 SHM_DIR = "/dev/shm"
 
-# 1 MiB keeps the ring + both working chunks cache-resident: measured on the
-# dev box, 256K-1M rings stream at ~11-12 GB/s single-process while 4M+ rings
-# fall to ~5 GB/s (DRAM eviction).  Large transfers are DRAM-bound anyway;
-# small rings also bound the wakeup ping-pong granularity.
-DEFAULT_RING = 1 << 20
+# 16 MiB: four of the 4 MiB messages the streaming-duplex scenario keeps in
+# flight.  Sized by a sweep on the chip's host (TPU v5e VM, 13 cores; PERF.md
+# section 6, PR 36; DESIGN.md 5b): 64 x 4 MiB each way a round, GB/s both
+# ways summed at 1 / 4 / 8 / 16 / 32 MiB: the ring alone (numpy both ends)
+# 3.3 / 5.1 / 5.9 / 7.0 / 7.5, the hbm_duplex.stream_4m cell 2.1 / 3.1 /
+# 3.6 / 3.9 / 3.9: the smallest size within 3% of the cell's best.  A ring
+# under the message is filled and drained in turn, two wake-ups over the
+# socket a turn.  (The old reason for 1 MiB, that ONE process streams 11-12
+# GB/s through a ring that fits its cache and 5 through 4 MiB and more, is
+# not what two processes pass through it: 3.3.)  A conn reserves 2 x ring +
+# 384 bytes of /dev/shm, 32 MiB at the default, and holds what its cursors
+# have walked: tmpfs pages exist from their first touch, so a conn of small
+# frames grows to the whole segment only once it has passed 16 MiB each way
+# (DESIGN.md 5b has what that means on a small /dev/shm).  One size for
+# every conn, decided by the connector before any message is seen; the
+# acceptor follows the segment's header.  STARWAY_SM_RING overrides.
+DEFAULT_RING = 1 << 24
 MAX_RING = 1 << 30
+
+# A put or take of this many bytes or more copies through numpy, which
+# releases the interpreter lock for the copy; a memoryview slice assignment
+# holds it.  Bulk: an engine that streams through a ring deep enough not to
+# park never sleeps, so it never gave the lock up either, and every other
+# thread of its process (the placer, the event loop) waited the
+# interpreter's whole switch interval, 5 ms, for each turn: with memoryview
+# copies hbm_duplex.stream_4m LOST from a ring of 8 MiB up (3.1 / 2.7 / 2.4
+# GB/s at 8 / 16 / 32) and reads 4.0-4.1 with numpy's.  Small: each release
+# hands the lock to the loop's thread and the engine waits to get it back;
+# a flood of messages between two chip-less processes on the chip's host
+# pays numpy +12 to +18% a message from 1 to 64 KiB, +9% at 256 KiB, +5% at
+# 512 KiB and nothing from 1 MiB up, and the cell reads the same with the
+# fork at 64 KiB or here (PERF.md section 6, PR 36; DESIGN.md 5b).
+BULK_COPY = 1 << 20
 
 
 def _use_portable_atomics() -> bool:
@@ -155,7 +189,7 @@ class Ring:
 
     __slots__ = ("_u64", "_data", "size", "_hdr_idx", "_at", "_tail_addr",
                  "_head_addr", "slotted", "_tx_seq", "_rx_seq", "_rec_left",
-                 "_rec_crc", "_rec_accum")
+                 "_rec_crc", "_rec_accum", "_bulk")
 
     def __init__(self, seg_mv: memoryview, hdr_off: int, data_off: int, size: int):
         self.slotted = False
@@ -167,6 +201,9 @@ class Ring:
         # One u64 view over the whole segment: index = byte offset / 8.
         self._u64 = seg_mv.cast("B").cast("Q")
         self._data = seg_mv[data_off : data_off + size]
+        # The same bytes for copies of BULK_COPY and more: numpy copies
+        # with the interpreter lock released.
+        self._bulk = np.frombuffer(self._data, np.uint8)
         self.size = size
         self._hdr_idx = hdr_off // 8
         self._at = None
@@ -233,9 +270,12 @@ class Ring:
         n = len(src)
         idx = cursor & (self.size - 1)
         first = min(n, self.size - idx)
-        self._data[idx : idx + first] = src[:first]
+        data = self._data
+        if n >= BULK_COPY:
+            data, src = self._bulk, np.frombuffer(src, np.uint8)
+        data[idx : idx + first] = src[:first]
         if n > first:
-            self._data[: n - first] = src[first:n]
+            data[: n - first] = src[first:n]
 
     def _take(self, cursor: int, dst) -> None:
         """Copy ``len(dst)`` bytes out of the data area at ``cursor``
@@ -243,9 +283,12 @@ class Ring:
         n = len(dst)
         idx = cursor & (self.size - 1)
         first = min(n, self.size - idx)
-        dst[:first] = self._data[idx : idx + first]
+        data = self._data
+        if n >= BULK_COPY:
+            data, dst = self._bulk, np.frombuffer(dst, np.uint8)
+        dst[:first] = data[idx : idx + first]
         if n > first:
-            dst[first:n] = self._data[: n - first]
+            dst[first:n] = data[: n - first]
 
     def write(self, src: memoryview) -> int:
         """Producer: append up to ``len(src)`` bytes; returns bytes written
@@ -328,6 +371,7 @@ class Ring:
         # sw_atomic_load_u64 on an unmapped page and segfault the process.
         self._at = None
         self._tail_addr = self._head_addr = 0
+        self._bulk = None
         self._data.release()
         self._u64.release()
 

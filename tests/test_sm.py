@@ -93,6 +93,40 @@ def test_ring_byte_stream_wrap_and_backpressure():
     assert seg.key not in _shm_segments()
 
 
+@pytest.mark.parametrize("slotted", [False, True], ids=["plain", "slot-records"])
+def test_ring_bulk_copies_match_small_ones(slotted):
+    """Puts and takes of ``BULK_COPY`` bytes and more copy through numpy
+    (the interpreter lock released), smaller ones through memoryviews: the
+    same bytes either way, across the ring's wrap, with and without slot
+    records."""
+    size = 4 * shmring.BULK_COPY
+    seg = shmring.ShmSegment.create("bulk", ring_size=size)
+    try:
+        if slotted:
+            seg.enable_integrity()
+        tx, _ = seg.tx_rx(creator=True)
+        _, rx = seg.tx_rx(creator=False)
+        rng = np.random.default_rng(64)
+        # 3/4 of the ring a lap: the second lap's put and take both wrap.
+        for lap, piece in enumerate((size * 3 // 4, size * 3 // 4,
+                                     shmring.BULK_COPY - 1, 100)):
+            blob = rng.integers(0, 256, piece, dtype=np.uint8).tobytes()
+            assert piece >= shmring.BULK_COPY or lap >= 2
+            sent = 0
+            out = bytearray(piece)
+            got = 0
+            while got < piece:
+                if sent < piece:
+                    sent += tx.write(memoryview(blob)[sent:])
+                got += rx.read_into(memoryview(out)[got:])
+            assert bytes(out) == blob, (lap, piece)
+        assert rx.readable() == 0
+    finally:
+        seg.unlink()
+        seg.close()
+    assert seg.key not in _shm_segments()
+
+
 def test_ring_portable_atomics_path(monkeypatch):
     """The non-TSO cursor path (native acquire/release atomics via ctypes,
     forced here with STARWAY_SM_FORCE_ATOMICS) must carry the same byte
@@ -254,6 +288,138 @@ async def test_sm_tiny_ring_streams_large_messages(port, sm_env, monkeypatch, sh
         await server.asend(ep, payload, 6)
         await fut2
         np.testing.assert_array_equal(buf2, payload)
+    assert not _shm_leftovers(shm_baseline)
+
+
+# ==============================================================================
+# Flow control: the ring's size, and a message larger than the ring
+# ==============================================================================
+
+
+def _conns(server, client):
+    """(the acceptor's conn, the connector's conn) of a ``_pair``."""
+    return server.list_clients().pop()._conn, client._client.primary_conn
+
+
+async def _stream_through_small_ring(port, monkeypatch, integrity=False):
+    """One payload of four rings' worth, client -> server, over a 64 KiB
+    ring.  Returns the producer's starving bytes, the consumer's doorbell
+    bytes (each the bytes left in its ring when it was sent) and the
+    connector's worker."""
+    from starway_tpu.core import conn as conn_mod
+
+    ring = 65536
+    monkeypatch.setenv("STARWAY_SM_RING", str(ring))
+    if integrity:
+        monkeypatch.setenv("STARWAY_INTEGRITY", "1")
+    starving, replies = [], []
+    async with _pair(port) as (server, client):
+        consumer, producer = _conns(server, client)
+        assert consumer.sm_ring == producer.sm_ring == ring
+        assert consumer.sm_rx.slotted is producer.sm_tx.slotted is integrity
+
+        def bell_from(conn, seen):
+            sent = conn._doorbell
+
+            def bell(fires, val=conn_mod.DB_DATA):
+                seen(val)
+                sent(fires, val)
+            monkeypatch.setattr(conn, "_doorbell", bell)
+
+        bell_from(producer, lambda val: val == conn_mod.DB_STARVING
+                  and starving.append(val))
+        bell_from(consumer, lambda val: replies.append(
+            consumer.sm_rx.readable()))
+        payload = np.random.default_rng(36).integers(
+            0, 256, 4 * ring, dtype=np.uint8)
+        buf = np.zeros(4 * ring, dtype=np.uint8)
+        fut = server.arecv(buf, 0x36, (1 << 64) - 1)
+        await asyncio.wait_for(client.asend(payload, 0x36), 30)
+        assert await asyncio.wait_for(fut, 30) == (0x36, 4 * ring)
+        np.testing.assert_array_equal(buf, payload)
+        return starving, replies, client._client
+
+
+@pytest.mark.parametrize("integrity", [False, True],
+                         ids=["plain", "slot-records"])
+async def test_sm_message_streams_through_small_ring(
+        port, sm_env, monkeypatch, shm_baseline, integrity):
+    """A message larger than the ring arrives byte for byte: its producer
+    parks on the full ring (at least three times for four rings' worth)
+    and is woken each time, and the whole message is ONE ``ring_wait``
+    sample, whatever its blocks."""
+    starving, _replies, tx = await _stream_through_small_ring(
+        port, monkeypatch, integrity)
+    assert len(starving) >= 3, starving
+    waits = tx.stage_scope.snapshot()["ring_wait"]
+    assert waits["count"] == 1 and waits["seconds"] > 0, waits
+    assert not _shm_leftovers(shm_baseline)
+
+
+async def test_sm_starving_byte_answered_once(port, sm_env, monkeypatch,
+                                              shm_baseline):
+    """One reply a starving byte: the consumer sends nothing else during a
+    one-way transfer, so its doorbell bytes count the replies, and the
+    producer is woken exactly as often as it parked.  Each reply follows a
+    drain: the consumer's ring is empty when it rings."""
+    starving, replies, _tx = await _stream_through_small_ring(
+        port, monkeypatch)
+    assert len(starving) >= 3 and len(replies) == len(starving), \
+        (starving, replies)
+    assert not any(replies), replies
+    assert not _shm_leftovers(shm_baseline)
+
+
+@pytest.mark.parametrize("native_engine", [False, True],
+                         ids=["python", "native"])
+async def test_sm_default_ring_size(port, monkeypatch, shm_baseline,
+                                    native_engine):
+    """With no ``STARWAY_SM_RING`` a conn's ring is ``DEFAULT_RING`` in
+    either engine (the connector's engine sizes the segment; the acceptor
+    follows its header), and both say so."""
+    from starway_tpu.core import native
+
+    if native_engine and not native.available():
+        pytest.skip("native engine unavailable (no toolchain)")
+    monkeypatch.delenv("STARWAY_SM_RING", raising=False)
+    monkeypatch.setenv("STARWAY_TLS", "tcp,sm")
+    monkeypatch.setenv("STARWAY_NATIVE", "1" if native_engine else "0")
+    async with _pair(port) as (server, client):
+        for _ in range(3000):  # the native engine lists an accept a moment late
+            if server.list_clients():
+                break
+            await asyncio.sleep(0.01)
+        acceptor = server.list_clients().pop()._conn
+        assert acceptor.transports() == [("shm", "sm")]
+        assert acceptor.sm_ring == shmring.DEFAULT_RING
+        if not native_engine:
+            connector = client._client.primary_conn
+            assert connector.sm_ring == connector._sm.ring_size \
+                == shmring.DEFAULT_RING
+        buf = np.zeros(1024, dtype=np.uint8)
+        fut = server.arecv(buf, 0x1, (1 << 64) - 1)
+        await asyncio.wait_for(client.asend(np.full(1024, 7, np.uint8), 0x1), 15)
+        await asyncio.wait_for(fut, 15)
+        assert int(buf[0]) == int(buf[-1]) == 7
+    assert not _shm_leftovers(shm_baseline)
+
+
+async def test_sm_message_under_ring_never_waits(port, sm_env, monkeypatch,
+                                                 shm_baseline):
+    """A message smaller than the ring meets no full ring: no starving
+    byte, no ``ring_wait`` sample."""
+    monkeypatch.delenv("STARWAY_SM_RING", raising=False)
+    async with _pair(port) as (server, client):
+        n = shmring.DEFAULT_RING // 4
+        payload = np.random.default_rng(4).integers(0, 256, n, dtype=np.uint8)
+        buf = np.zeros(n, dtype=np.uint8)
+        fut = server.arecv(buf, 0x2, (1 << 64) - 1)
+        await asyncio.wait_for(client.asend(payload, 0x2), 30)
+        await asyncio.wait_for(fut, 30)
+        await asyncio.wait_for(client.aflush(), 30)
+        np.testing.assert_array_equal(buf, payload)
+        for worker in (client._client, server._server):
+            assert "ring_wait" not in worker.stage_scope.snapshot()
     assert not _shm_leftovers(shm_baseline)
 
 

@@ -1443,8 +1443,8 @@ def test_wirefuzz_smrec_ring_bound_drift_seeded(tmp_path):
     # dynamically too).
     root = _seed(tmp_path)
     _edit(root, "native/sw_engine.cpp",
-          "const uint64_t ring_size = 1ull << 20;",
-          "const uint64_t ring_size = 1ull << 21;")
+          "const uint64_t ring_size = 1ull << 24;",
+          "const uint64_t ring_size = 1ull << 25;")
     _assert_caught(root, "wire-diff", "record-length bound", "shmring.py")
 
 
