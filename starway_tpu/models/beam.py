@@ -125,6 +125,9 @@ def generate_beam(params: dict, cfg: LlamaConfig, prompt,
         raise ValueError(f"beams must be >= 1, got {beams}")
     if beams > cfg.vocab_size:
         raise ValueError(f"beams={beams} exceeds the vocab ({cfg.vocab_size})")
+    if cfg.mtp:
+        raise ValueError("beam search scores one token a step; an MTP "
+                         "block's drafts are not wired into it (ROADMAP M5)")
     if cfg.linear is not None:
         raise ValueError("beam search reorders cache rows by position; a "
                          "linear-attention layer's state (cfg.linear) has "

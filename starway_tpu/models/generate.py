@@ -70,10 +70,19 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
     layers (``cfg.linear``) add a third kind with NO position axis:
     ``kda_state [linear layers, B, H, d, d]`` float32 and ``kda_conv
     [linear layers, B, taps - 1, 3*H*d]``; ``max_len`` then sizes the
-    attention layers alone.
+    attention layers alone.  A ring holds ``cfg.kinds.ring`` positions:
+    the window and the slack a step of several positions needs.
+
+    An MTP block (``cfg.mtp``, models/mtp.py) keeps a full row of its own
+    a batch row, ``k_mtp`` / ``v_mtp [1, B, Hkv, max_len, head_dim]``,
+    beside the model's leaves.
     """
     full = cfg.kind_layers("full")
     state = {}
+    if cfg.mtp:
+        state = {name: jnp.zeros(
+            (cfg.mtp, batch, cfg.n_kv_heads, max_len, cfg.head_dim),
+            cfg.compute_dtype) for name in ("k_mtp", "v_mtp")}
     if cfg.linear is not None:
         la, n = cfg.linear, cfg.kind_layers("linear")
         state = {
@@ -89,7 +98,7 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
     if cfg.kinds is not None:
         shapes = {"": (full, max_len)}
         if cfg.kinds.window is not None:
-            shapes["_ring"] = (cfg.kind_layers("ring"), cfg.kinds.window)
+            shapes["_ring"] = (cfg.kind_layers("ring"), cfg.kinds.ring)
         return {**{name + kind: jnp.zeros((n, batch, cfg.n_kv_heads, t, hd),
                                           cfg.compute_dtype)
                    for kind, (n, t) in shapes.items() for name in ("k", "v")},
@@ -105,6 +114,7 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
     return {
         "k": jnp.zeros(shape, cfg.compute_dtype),
         "v": jnp.zeros(shape, cfg.compute_dtype),
+        **state,
     }
 
 
@@ -139,8 +149,10 @@ def ring_fold(a, lengths, window: int):
     ``0 .. S - 1`` of which row b's first ``lengths[b]`` are real; returns
     ``[L, B, Hkv, window(, D)]`` whose slot ``s`` holds row b's LATEST real
     position ``p`` with ``p % window == s``: the layout ``pos % window``
-    writes leave behind.  Slots no real position reached yet hold junk
-    that the decode steps overwrite before the cursor lets them be read."""
+    writes leave behind (``window``: the RING's length, which a
+    ``LayerKinds.slack`` makes longer than the attention window).  Slots
+    no real position reached yet hold junk that the decode steps overwrite
+    before the cursor lets them be read."""
     last = jnp.asarray(lengths, jnp.int32).reshape(-1, 1) - 1       # [B, 1]
     src = last - (last - jnp.arange(window, dtype=jnp.int32)[None, :]) % window
     src = jnp.clip(src, 0, a.shape[3] - 1)                          # [B, W]
@@ -157,6 +169,13 @@ def _ring_names(cache: dict) -> dict:
     return {name: name for name in cache}
 
 
+def mtp_rows(cache: dict) -> dict:
+    """An MTP block's own rows as a cache of their own, under the plain
+    names (``k`` / ``v``): what :func:`cached_layer_scan` and
+    :func:`_write_cached` take."""
+    return {"k": cache["k_mtp"], "v": cache["v_mtp"]}
+
+
 def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig,
                  ring: bool = False):
     """``attend`` of :func:`cached_layer_scan` over a cache of either kind,
@@ -166,9 +185,12 @@ def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig,
     (``ops.cached_attention``, windowed and int8-aware), or the latent
     rows of ``cfg.latent`` (absorbed queries in, ``P c_kv`` out;
     ``ops.latent_attention``).  ``ring``: the layer's entries lie in a
-    ring (written at ``pos % window``): its warm slots ARE the window, so
-    every slot up to the clamped cursor is attended and nothing is masked
-    again; cold slots (> pos) are masked by the clamped position."""
+    ring (written at ``pos % T``).  A ring of exactly one window: its
+    warm slots ARE the window, so every slot up to the clamped cursor is
+    attended and nothing is masked again; cold slots (> pos) are masked by
+    the clamped position.  A ring LONGER than its window
+    (``LayerKinds.slack``): every slot is read under the mask of the
+    position it holds, ``i - window < j <= i`` for the query at ``i``."""
     if "ckv" in cache:
         return latent_attention(q, cache["ckv"], pos,
                                 rank=cfg.latent.kv_rank,
@@ -176,8 +198,11 @@ def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig,
     if ring:
         at = _ring_names(cache)
         scales = {n: cache[at[n]] for n in ("k_scale", "v_scale") if n in at}
-        return cached_attention(q, cache[at["k"]], cache[at["v"]], pos,
-                                layer=layer, ring=True, **scales)
+        window = cfg.kinds.window if "k_ring" in cache else cfg.sliding_window
+        return cached_attention(
+            q, cache[at["k"]], cache[at["v"]], pos, layer=layer, ring=True,
+            window=None if cache[at["k"]].shape[3] == window else window,
+            **scales)
     return cached_attention(q, cache["k"], cache["v"], pos, layer=layer,
                             window=cfg.sliding_window,
                             k_scale=cache.get("k_scale"),
@@ -194,9 +219,10 @@ def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
     ``pos`` the offsets inside them.  A start above ``T - C`` is clamped,
     as ``lax.dynamic_update_slice`` does; with ``count`` ([B]) only each
     row's first ``count[b]`` positions are written and nothing is clamped
-    (``ops.cache_write``).  ``ring`` (C = 1): the layer's entries lie in
-    a ring and ``pos`` is the ABSOLUTE position: the write goes to ``pos
-    % T`` of the ring leaves, ``layer`` counting them.
+    (``ops.cache_write``).  ``ring``: the layer's entries lie in a ring
+    and ``pos`` is the ABSOLUTE position: position ``pos + c`` goes to
+    ``(pos + c) % T`` of the ring leaves, ``layer`` counting them (one
+    write a position: two of a chunk may lie at the ring's two ends).
 
     The write itself is ``ops.cache_write``: on the chip in place, a tile
     a row.  Either XLA form (a scatter, or ``dynamic_update_slice`` per
@@ -209,17 +235,23 @@ def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
     out = dict(cache)
     if ring:
         at = _ring_names(cache)
-        pos = lax.rem(pos, cache[at["k"]].shape[3])
+        T = cache[at["k"]].shape[3]
         groups = [(at["k"], at["v"])] + (
             [(at["k_scale"], at["v_scale"])] if "k_scale" in at else [])
         new = {at[name]: x for name, x in new.items()}
+        C = next(iter(new.values())).shape[2]
+        writes = [(new, lax.rem(pos, T))] if C == 1 else [
+            ({n: x[:, :, c:c + 1] for n, x in new.items()},
+             lax.rem(pos + c, T)) for c in range(C)]
     else:
         groups = ([("ckv",)] if "ckv" in cache else [("k", "v")] + [
             ("k_scale", "v_scale")] * ("k_scale" in cache))
-    for names in groups:  # same-shaped leaves share one kernel call
-        out.update(zip(names, cache_write(
-            tuple(cache[name] for name in names),
-            tuple(new[name] for name in names), layer, rows, pos, count)))
+        writes = [(new, pos)]
+    for new, pos in writes:
+        for names in groups:  # same-shaped leaves share one kernel call
+            out.update(zip(names, cache_write(
+                tuple(out[name] for name in names),
+                tuple(new[name] for name in names), layer, rows, pos, count)))
     return out
 
 
@@ -390,7 +422,8 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
     model).  A linear layer's state leaves (``kda_state`` / ``kda_conv``)
     ride the carry too and NO hook is called for them: a state has no
     cursor, the layer moves it on by one token itself (models/kda.py
-    ``kda_decode``, C = 1 only).  Returns ``(h [B, C, D], cache, counts)``: the pairs each held
+    ``kda_decode``, C = 1 only).  ``cos_p`` None: no layer rotates (the
+    MTP block's one full layer, models/mtp.py).  Returns ``(h [B, C, D], cache, counts)``: the pairs each held
     expert of each routed layer got, ``[routed layers, n_held]`` int32
     (None for a model with no routed layer).
     """
@@ -420,7 +453,7 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
             o = expand_values(attend(q, cache, li), lp, cfg)
         else:
             q, k, v = qkv_proj(x, lp, cfg)
-            if rope:
+            if rope and cos_p is not None:
                 q = apply_rope(q, cos_p, sin_p)
                 k = apply_rope(k, cos_p, sin_p)
             new = {"k": k, "v": v}
@@ -456,7 +489,7 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
 
 def prefill(params: dict, cfg: LlamaConfig, prompt,
             max_len: Optional[int] = None, attn_fn=None,
-            logit_positions=None):
+            logit_positions=None, return_hidden: bool = False):
     """One parallel forward pass over the whole prompt -> the decode state.
 
     Returns ``(next_logits [B, V], cache)`` where the cache holds the
@@ -484,10 +517,11 @@ def prefill(params: dict, cfg: LlamaConfig, prompt,
         max_len = P
     elif max_len < P:
         raise ValueError(f"max_len={max_len} is smaller than the prompt ({P})")
-    logits, _aux, kv = forward(
+    logits, _aux, kv, *hidden = forward(
         params, prompt, cfg, attn_fn, return_aux=True, return_kv=True,
         last_only=logit_positions is None, logit_positions=logit_positions,
         lengths=None if logit_positions is None else logit_positions + 1,
+        return_hidden=return_hidden,
     )
     state = {name: kv.pop(name) for name in list(kv) if is_state(name)}
     cache = dict(kv)
@@ -500,7 +534,7 @@ def prefill(params: dict, cfg: LlamaConfig, prompt,
     if cfg.kinds is not None:  # the window layers' whole rows -> rings
         lengths = (jnp.full((B,), P, jnp.int32) if logit_positions is None
                    else logit_positions + 1)
-        rings = {name: ring_fold(cache.pop(name), lengths, cfg.kinds.window)
+        rings = {name: ring_fold(cache.pop(name), lengths, cfg.kinds.ring)
                  for name in [n for n in cache if n.endswith("_ring")]}
     pad = max_len - P
     if pad:
@@ -510,7 +544,7 @@ def prefill(params: dict, cfg: LlamaConfig, prompt,
             lambda a: jnp.pad(
                 a, ((0, 0),) * 3 + ((0, pad),) + ((0, 0),) * (a.ndim - 4)),
             cache)
-    return logits[:, 0], {**cache, **rings, **state}
+    return (logits[:, 0], {**cache, **rings, **state}, *hidden)
 
 
 def prefill_rolling(params: dict, cfg: LlamaConfig, prompt, *,

@@ -134,11 +134,18 @@ class LayerKinds:
     windows of a model are of one length: its cache then holds the full
     layers' rows of ``max_len`` beside the window layers' RINGS of that
     length and the linear layers' STATE, which has no position axis at all
-    (models/generate.py::init_cache).  A model whose every layer has the
+    (models/generate.py::init_cache).  ``slack``: positions a ring holds
+    BEYOND its window.  0: the ring's warm slots ARE the window and a step
+    writes one position.  A step that writes ``C`` positions before it
+    knows which of them stay (a draft beside the pending token) needs
+    ``slack >= C - 1``: the ring is then read under position masks, and
+    neither the draft's write nor its rejection touches an entry a live
+    query attends (DESIGN.md 9b).  A model whose every layer has the
     same window is ``LlamaConfig.sliding_window``'s, not this group's."""
     windows: tuple
     rope: tuple
     linear: tuple = ()
+    slack: int = 0
 
     def __post_init__(self):
         object.__setattr__(self, "windows", tuple(self.windows))
@@ -162,12 +169,21 @@ class LayerKinds:
             raise ValueError(
                 f"LayerKinds needs full layers (None) beside window layers "
                 f"of ONE length >= 1, got {self.windows}")
+        if self.slack < 0 or (self.slack and not sizes):
+            raise ValueError(f"LayerKinds.slack lengthens rings: it needs "
+                             f"window layers and >= 0, got {self.slack}")
 
     @property
     def window(self) -> Optional[int]:
         """The window layers' one length: the ring's (None: no window
         layer)."""
         return next((w for w in self.windows if w is not None), None)
+
+    @property
+    def ring(self) -> Optional[int]:
+        """Positions a window layer's ring holds: the window and the
+        slack (None: no window layer)."""
+        return None if self.window is None else self.window + self.slack
 
 
 @dataclasses.dataclass(frozen=True)
@@ -250,8 +266,34 @@ class LlamaConfig:
     routed: Optional[RoutedFFN] = None
     kinds: Optional[LayerKinds] = None
     linear: Optional[LinearAttn] = None
+    # RMSNorm over each head's q and k before the rotation, one gain vector
+    # of head_dim a layer each (``q_head_norm`` / ``k_head_norm`` leaves;
+    # qkv_proj keys off their presence).
+    qk_norm: bool = False
+    # Multi-token-prediction blocks behind the last layer (models/mtp.py):
+    # each one decoder layer of the model's own kinds (full attention, the
+    # FFN of the later layers) that reads the main model's last hidden
+    # state and the NEXT token and predicts the token after it, sharing the
+    # embedding and the head; its weights are the subtree ``params["mtp"]``.
+    # A SlotServer built on such a configuration drafts with it and
+    # verifies inside every decode step.  0 or 1.
+    mtp: int = 0
 
     def __post_init__(self):
+        if self.mtp not in (0, 1):
+            raise ValueError(
+                f"mtp must be 0 or 1 (one draft a step; a second needs a "
+                f"verify of three positions: ROADMAP M5), got {self.mtp}")
+        if self.mtp and (self.latent is not None or self.linear is not None
+                         or self.kv_quant != "none" or self.n_experts
+                         or self.sliding_window is not None):
+            raise ValueError(
+                "an MTP block drafts over grouped-query rows and rings in "
+                "bf16/f32: no latent rows (the draft's entry would have to "
+                "be taken out of the latent cache), no linear layers (a "
+                "state moved on by a rejected draft cannot be taken back), "
+                "no int8 cache, no whole-model rolling window, no capacity "
+                "MoE (ROADMAP M5)")
         has_linear = self.kinds is not None and any(self.kinds.linear)
         if has_linear != (self.linear is not None):
             raise ValueError(
@@ -465,8 +507,10 @@ def scan_segment(body, carry, seg, *xs):
     return lax.scan(step, carry, (rest, jnp.arange(n, dtype=jnp.int32), *xs))
 
 
-def _init_block_params(key, cfg: LlamaConfig) -> tuple:
-    """The segments of a model whose blocks are not the default kinds."""
+def _init_block_params(key, cfg: LlamaConfig, plan=None) -> tuple:
+    """The segments of a model whose blocks are not the default kinds
+    (``plan``: other segments than ``cfg.segment_plan()``'s, of attention
+    layers: the MTP block's one layer)."""
     dt = cfg.compute_dtype
     D, H = cfg.d_model, cfg.n_heads
 
@@ -511,6 +555,9 @@ def _init_block_params(key, cfg: LlamaConfig) -> tuple:
                        wk=norm(ks[1], (L, D, Hkv * hd), D**-0.5),
                        wv=norm(ks[2], (L, D, Hkv * hd), D**-0.5),
                        wo=norm(ks[4], (L, H * hd, D), (H * hd)**-0.5))
+            if cfg.qk_norm:
+                seg.update(q_head_norm=jnp.ones((L, hd), dt),
+                           k_head_norm=jnp.ones((L, hd), dt))
         if routed:
             r = cfg.routed
             seg["routed"] = {
@@ -534,6 +581,9 @@ def _init_block_params(key, cfg: LlamaConfig) -> tuple:
         k = jax.random.fold_in(key, 31 + routed)   # as before cfg.kinds
         return k if cfg.kinds is None else jax.random.fold_in(k, first)
 
+    if plan is not None:
+        return tuple(segment(jax.random.fold_in(key, 97 + first), n, routed,
+                             False) for first, n, routed in plan)
     return tuple(segment(key_of(first, routed), n, routed,
                          cfg.layer_kind(first)[2])
                  for first, n, routed in cfg.segment_plan())
@@ -551,12 +601,17 @@ def init_params(key, cfg: LlamaConfig) -> dict:
     L, D, F = cfg.n_layers, cfg.d_model, cfg.d_ff
     Hq, Hkv = cfg.n_heads, cfg.n_kv_heads
     if (cfg.latent is not None or cfg.routed is not None
-            or cfg.kinds is not None):
+            or cfg.kinds is not None or cfg.qk_norm or cfg.mtp):
         segs = _init_block_params(jax.random.fold_in(key, 23), cfg)
-        return {"embed": norm(keys[0], (cfg.vocab_size, D), 0.02),
-                "layers": segs[0] if len(segs) == 1 else segs,
-                "final_norm": jnp.ones((D,), dt),
-                "lm_head": norm(keys[8], (D, cfg.vocab_size), D**-0.5)}
+        out = {"embed": norm(keys[0], (cfg.vocab_size, D), 0.02),
+               "layers": segs[0] if len(segs) == 1 else segs,
+               "final_norm": jnp.ones((D,), dt),
+               "lm_head": norm(keys[8], (D, cfg.vocab_size), D**-0.5)}
+        if cfg.mtp:
+            from .mtp import init_mtp_params
+
+            out["mtp"] = init_mtp_params(jax.random.fold_in(key, 29), cfg)
+        return out
     layers = {
         "wq": norm(keys[1], (L, D, Hq * hd), D**-0.5),
         "wk": norm(keys[2], (L, D, Hkv * hd), D**-0.5),
@@ -597,10 +652,11 @@ def param_specs(cfg: LlamaConfig) -> dict:
     pattern over ICI automatically.  Embedding/lm_head shard the vocab dim.
     """
     if (cfg.latent is not None or cfg.routed is not None
-            or cfg.kinds is not None):
+            or cfg.kinds is not None or cfg.qk_norm or cfg.mtp):
         raise NotImplementedError(
-            "latent attention, the routed FFN and attention kinds by layer "
-            "(linear layers among them) have no sharding rules yet: they "
+            "latent attention, the routed FFN, attention kinds by layer "
+            "(linear layers among them), head norms and the MTP block have "
+            "no sharding rules yet: they "
             "serve on one chip (the routed FFN as one holder of an "
             "expert-parallel deployment; ROADMAP M1, M2, M4)")
     layers = {
@@ -913,7 +969,8 @@ def qkv_proj(x, lp, cfg: "LlamaConfig"):
     """q/k/v projections on ``x [B, S, D]`` -> ``[B, H, S, hd]`` heads,
     pre-RoPE.  Optional per-head biases (Qwen2 family) apply when the
     layer tree carries ``bq``/``bk``/``bv`` — leaf presence is the
-    marker, so converted trees work wherever the config doesn't travel.
+    marker, so converted trees work wherever the config doesn't travel;
+    likewise ``q_head_norm`` / ``k_head_norm`` (``cfg.qk_norm``).
     The ONE projection site shared by the scan forward (decoder_layer)
     and the cached decode layer scan (generate.py)."""
     B, S = x.shape[0], x.shape[1]
@@ -925,9 +982,12 @@ def qkv_proj(x, lp, cfg: "LlamaConfig"):
         q = q + lp["bq"]
         k = k + lp["bk"]
         v = v + lp["bv"]
-    return (q.reshape(B, S, cfg.n_heads, hd).transpose(0, 2, 1, 3),
-            k.reshape(B, S, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3),
-            v.reshape(B, S, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3))
+    q = q.reshape(B, S, cfg.n_heads, hd).transpose(0, 2, 1, 3)
+    k = k.reshape(B, S, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3)
+    if "q_head_norm" in lp:  # RMSNorm over each head, before the rotation
+        q = rmsnorm(q, lp["q_head_norm"], cfg.norm_eps)
+        k = rmsnorm(k, lp["k_head_norm"], cfg.norm_eps)
+    return q, k, v.reshape(B, S, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3)
 
 
 def ffn_block(x, lp, cfg: "LlamaConfig", moe_fn: Optional[Callable] = None,
@@ -1071,7 +1131,8 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
             attn_fn: Optional[Callable] = None, *, return_aux: bool = False,
             moe_fn: Optional[Callable] = None, return_kv: bool = False,
             last_only: bool = False, logit_positions=None,
-            return_moe_stats: bool = False, lengths=None):
+            return_moe_stats: bool = False, lengths=None,
+            return_hidden: bool = False):
     """Next-token logits ``[B, S, V]`` for token ids ``[B, S]``.
 
     ``return_kv`` additionally returns what the cache holds of every
@@ -1087,8 +1148,10 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
     (``[B, 1, V]``), skipping the ``[B, S, V]`` logit tensor a prefill never
     reads; ``logit_positions`` ([B] ints) is its ragged analog — logits for
     one caller-chosen position per row.  Return value is ``logits``,
-    extended to a tuple ``(logits[, aux][, moe_stats][, (k, v)])`` by
-    ``return_aux`` / ``return_moe_stats`` / ``return_kv``.
+    extended to a tuple ``(logits[, aux][, moe_stats][, (k, v)][, hidden])``
+    by ``return_aux`` / ``return_moe_stats`` / ``return_kv`` /
+    ``return_hidden`` (the last layer's output at EVERY position, ``[B, S,
+    D]``, before the final norm: what an MTP block reads, models/mtp.py).
 
     ``lengths`` ([B] ints; default: every row is S long) says how many
     positions of each right-padded row are real, for the layers whose state
@@ -1165,6 +1228,7 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
             ys = ({name + "_ring": x for name, x in ys[0].items()}, ys[1])
         outs.append(ys)
     h, aux = carry
+    hidden = h
     by_name: dict = {}
     for kv, _stats in outs if return_kv else ():
         for name, x in kv.items():
@@ -1187,6 +1251,8 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
         out += (moe_stats,)  # scan-stacked: leaves lead with n_layers
     if return_kv:
         out += (kv,)
+    if return_hidden:
+        out += (hidden,)
     return out if len(out) > 1 else logits
 
 

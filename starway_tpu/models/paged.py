@@ -267,6 +267,12 @@ class PagedSlotServer(SlotServer):
                  top_p: Optional[float] = None,
                  eos_id: Optional[int] = None, seed: int = 0,
                  on_tokens=None):
+        if cfg.mtp:
+            raise NotImplementedError(
+                "the page pool's step writes one position a slot: an MTP "
+                "block's draft beside it, and the block's own row, need "
+                "pages that a rejection gives back; such a model serves "
+                "through the dense SlotServer (ROADMAP M5)")
         if cfg.linear is not None:
             raise NotImplementedError(
                 "the page pool holds rows a position: a linear-attention "

@@ -110,10 +110,10 @@ from jax import lax
 
 from .. import perf
 from ..core import swtrace
-from .generate import (_sample, cache_len, decode_step_counted,
-                       ingest_decode_step, init_cache, init_rolling_cache,
-                       is_state, prefill)
-from .llama import LlamaConfig, cfg_rope_tables
+from .generate import (_filter_logits, _sample, cache_len,
+                       decode_step_counted, ingest_decode_step, init_cache,
+                       init_rolling_cache, is_state, prefill)
+from .llama import LlamaConfig, cfg_rope_tables, head_logits
 
 # ----------------------------------------------------------- the serve logs
 #
@@ -149,7 +149,9 @@ def request_log() -> list:
     (models/remote_serving.py) the same row also carries ``route``
     (``"<client id>:<nonce>"``), ``t_recv`` (the REQUEST receive
     completed), ``t_first_post`` and ``t_done_post`` (the TOKENS sends
-    were posted).  A CLIENT row (``side: "client"``, written by
+    were posted).  On a server that speculates (``cfg.mtp``) a row also
+    carries ``spec_accepted``: the drafts of this request that the main
+    model accepted.  A CLIENT row (``side: "client"``, written by
     ``RemoteGenerateSession.generate``) carries ``route``, ``t_send``,
     ``t_first_rx``, ``t_done_rx``, ``n_out``, ``status`` and ``server_us``
     (the done frame's timing trailer, None from a server without one)."""
@@ -194,7 +196,16 @@ def step_log() -> list:
     state (``cfg.linear``) adds ``state_slots`` (the slots that decode:
     each one's state, every linear layer, is read and written once a step)
     and ``kv_rows_latent`` (``pos + 1`` summed over them: the cache rows
-    one attention layer's step attends), from the same cursors."""
+    one attention layer's step attends), from the same cursors.  A server
+    that speculates (``cfg.mtp``) adds ``spec_drafted`` (drafts verified:
+    one a live slot a step of the chunk), ``spec_accepted`` (of them, those
+    the main model accepted) and ``spec_emitted`` (tokens the chunk's steps
+    yielded: one or two a live slot a step), from the chunk's own result;
+    its cursors are the ones the chunk returned (a slot moves by one or
+    two a step), and its ``kv_rows_full`` / ``kv_rows_window`` count what
+    a step's TWO query positions read: ``pos + 2`` in a full row,
+    ``min(pos + 2, ring)`` in a ring of ``window + slack`` positions,
+    which is read whole once warm."""
     return [dict(row) for row in list(_step_log)]
 
 
@@ -227,14 +238,13 @@ def _bucket(n: int, buckets) -> int:
                      f"{buckets[-1]}")
 
 
-def _write_slot_and_sample(cache, small, logits, slot, key, temperature,
-                           top_k, top_p):
-    """Shared tail of BOTH admission paths: file one request's [L, 1, Hkv,
-    T', D] cache rows into the slot and sample its first token.  Writes
-    every cache leaf — the int8 format's [L, 1, Hkv, T'] scale arrays ride
-    along (the slot axis sits at index 1 in all of them).  A linear
-    layer's state leaves (no position axis) are REPLACED whole for the
-    slot, under a name of their own in the trace (``sw_kda_seat``)."""
+def _write_slot(cache, small, slot):
+    """File one request's [L, 1, Hkv, T', D] cache rows into the slot.
+    Writes every cache leaf — the int8 format's [L, 1, Hkv, T'] scale
+    arrays and an MTP block's own rows ride along (the slot axis sits at
+    index 1 in all of them).  A linear layer's state leaves (no position
+    axis) are REPLACED whole for the slot, under a name of their own in
+    the trace (``sw_kda_seat``)."""
     def put(name):
         return lax.dynamic_update_slice(
             cache[name], small[name].astype(cache[name].dtype),
@@ -242,10 +252,41 @@ def _write_slot_and_sample(cache, small, logits, slot, key, temperature,
 
     rows = {name: put(name) for name in cache if not is_state(name)}
     with jax.named_scope("sw_kda_seat"):
-        cache = {**rows,
-                 **{name: put(name) for name in cache if is_state(name)}}
+        return {**rows,
+                **{name: put(name) for name in cache if is_state(name)}}
+
+
+def _write_slot_and_sample(cache, small, logits, slot, key, temperature,
+                           top_k, top_p):
+    """Shared tail of BOTH admission paths: :func:`_write_slot`, and the
+    request's first token sampled."""
+    cache = _write_slot(cache, small, slot)
     tok = _sample(logits, key, temperature, top_k, top_p)[0]
     return cache, tok
+
+
+def _logp_of(logits, tokens, temperature: float):
+    """Each token's log-probability under ``logits [..., V]`` at the served
+    temperature, BEFORE top-k / nucleus (greedy: at temperature 1): what
+    ``on_logprobs`` hands out."""
+    lp = jax.nn.log_softmax(logits / (temperature or 1.0), axis=-1)
+    return jnp.take_along_axis(lp, tokens[..., None], axis=-1)[..., 0]
+
+
+def _draft_from(logits, key, temperature: float, top_k, top_p):
+    """An MTP block's draft from its logits ``[B, V]``: ``(d [B]`` drawn as
+    the server samples, ``q`` the distribution it was drawn from (``[B,
+    V]``; greedy: ``[B, 1]`` zeros, the rule compares ids), ``log q(d)`` as
+    :func:`_logp_of` reads it``)``: the draft state a slot carries from
+    the step (or the admission) that drafted to the step that verifies."""
+    if temperature == 0.0:
+        d = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        q = jnp.zeros(logits.shape[:-1] + (1,), jnp.float32)
+    else:
+        fl = _filter_logits(logits, temperature, top_k, top_p)
+        d = jax.random.categorical(key, fl, axis=-1).astype(jnp.int32)
+        q = jax.nn.softmax(fl, axis=-1)
+    return d, q, _logp_of(logits, d, temperature)
 
 
 def _seat_state(token, pos, live, remaining, tok, seat):
@@ -264,6 +305,12 @@ def _seat_state(token, pos, live, remaining, tok, seat):
 # Not ``serve_admit*``: a trace reduction takes those for the prefills.
 _seat = _named_jit(lambda *state_tok_seat: _seat_state(*state_tok_seat),
                    "serve_seat")
+# A speculating server's: the slot's first draft (d, q, log q(d)) and its
+# first token's log-probability, from the admit program that made them.
+_seat_draft = _named_jit(
+    lambda draft, first_lp, slot, d, q, dlp, lp: (
+        tuple(a.at[slot].set(x) for a, x in zip(draft, (d, q, dlp))),
+        first_lp.at[slot].set(lp)), "serve_seat_draft")
 
 
 @functools.cache
@@ -287,7 +334,31 @@ def _compiled_admit(cfg: LlamaConfig, p_bucket: int, temperature: float,
         return _write_slot_and_sample(cache, small, logits, slot, key,
                                       temperature, top_k, top_p)
 
-    return _named_jit(run, f"serve_admit_{p_bucket}", donate_argnums=(1,))
+    def run_mtp(params, cache, prompt, length, slot, key):
+        # The same prefill, and the MTP block over the prompt behind it:
+        # the block's row t is made of the main model's hidden state at t
+        # and the token at t + 1, the request's FIRST token behind the
+        # prompt's last, so the token is sampled first.  The block's rows
+        # are seated beside the model's and its output at the prompt's
+        # last position drafts the token after the first.
+        from .mtp import mtp_logits, mtp_prefill
+
+        logits, small, hidden = prefill(
+            params, cfg, prompt, p_bucket, logit_positions=length[None] - 1,
+            return_hidden=True)
+        key, sub = jax.random.split(key)
+        tok = _sample(logits, sub, temperature, top_k, top_p)
+        nxt = jnp.roll(prompt, -1, axis=1).at[0, length - 1].set(tok[0])
+        m, rows = mtp_prefill(params, cfg, hidden, nxt, p_bucket)
+        last = lax.dynamic_index_in_dim(m[0], length - 1, keepdims=True)
+        with jax.named_scope("sw_mtp_draft"):
+            d, q, dlp = _draft_from(mtp_logits(params, cfg, last), key,
+                                    temperature, top_k, top_p)
+        return _write_slot(cache, {**small, **rows}, slot), (
+            tok[0], d[0], q[0], dlp[0], _logp_of(logits, tok, temperature)[0])
+
+    return _named_jit(run_mtp if cfg.mtp else run, f"serve_admit_{p_bucket}",
+                      donate_argnums=(1,))
 
 
 @functools.cache
@@ -396,8 +467,8 @@ def _rolling_prefill_state(params, cfg: LlamaConfig, prompt: np.ndarray):
 def _compiled_chunk(cfg: LlamaConfig, n_slots: int, max_len: int, chunk: int,
                     temperature: float, top_k: Optional[int],
                     top_p: Optional[float], eos_id: Optional[int],
-                    rolling: bool = False):
-    """Advance every live slot ``chunk`` tokens in ONE dispatch.
+                    rolling: bool = False, logprobs: bool = False):
+    """Advance every live slot ``chunk`` steps in ONE dispatch.
 
     Per step: the pending token (at its slot's cursor) runs
     ``decode_step`` with per-row positions, the next token is sampled,
@@ -405,7 +476,10 @@ def _compiled_chunk(cfg: LlamaConfig, n_slots: int, max_len: int, chunk: int,
     [chunk, B])`` — mask marks which emissions are real (slot was live
     when its PENDING token was consumed, i.e. the sampled token continues
     a real request).  ``rolling``: the cache is circular per slot
-    (``max_len`` is the rope horizon, not the cache size).
+    (``max_len`` is the rope horizon, not the cache size).  A model with
+    an MTP block (``cfg.mtp``) speculates in every step (``run_mtp``
+    below); ``logprobs``: its steps also emit what ``on_logprobs`` hands
+    out.
     """
     rope = cfg_rope_tables(cfg, max_len)
 
@@ -422,7 +496,45 @@ def _compiled_chunk(cfg: LlamaConfig, n_slots: int, max_len: int, chunk: int,
                      length=chunk))
         return cache, token, pos, live, remaining, key, toks, mask, pairs
 
-    return _named_jit(run, "serve_decode_chunk", donate_argnums=(1,))
+    def run_mtp(params, cache, token, pos, live, remaining, key, draft):
+        """The chunk of a server that speculates (``cfg.mtp``): a step
+        verifies ``[pending, draft]`` at ``[pos, pos + 1]`` in one pass,
+        accepts or resamples, and drafts again.  ``draft``: the slots'
+        draft state (:func:`_draft_from`), carried beside the cursors.
+        Emits tokens and masks ``[chunk, 2, B]``, the pair counts (of the
+        model's routed layers over both verified rows a slot; the block's
+        own layer is not among them) and ``spec``
+        (:func:`make_chunk_scan_step`)."""
+        from .mtp import mtp_chunk, mtp_logits
+        from .speculative import chunk_decode_hidden
+
+        def verify(cache, tokens, pos):
+            # A dead slot may stand at the cache's last position: it
+            # verifies one lower, where its junk is as harmless.
+            h, cache, counts = chunk_decode_hidden(
+                params, cache, tokens, jnp.minimum(pos, max_len - 2), cfg,
+                rope)
+            return (head_logits(h, params["final_norm"], params["lm_head"],
+                                cfg.norm_eps), cache, h, counts)
+
+        def draft_one(cache, hidden, tokens, pos, at):
+            m, cache = mtp_chunk(params, cfg, cache, hidden, tokens,
+                                 jnp.minimum(pos, max_len - 2))
+            m = jnp.take_along_axis(m, at[:, None, None], axis=1)[:, 0]
+            return mtp_logits(params, cfg, m), cache
+
+        step = make_chunk_scan_step(verify, max_len, temperature, top_k,
+                                    top_p, eos_id, draft_one=draft_one,
+                                    logprobs=logprobs)
+        (cache, token, pos, live, remaining, key, draft), (
+            toks, mask, pairs, spec) = lax.scan(
+                step, (cache, token, pos, live, remaining, key, draft), None,
+                length=chunk)
+        return (cache, token, pos, live, remaining, key, draft, toks, mask,
+                pairs, spec)
+
+    return _named_jit(run_mtp if cfg.mtp else run, "serve_decode_chunk",
+                      donate_argnums=(1,))
 
 
 # Widths of a prompt piece, the rows a step of the mixed chunk carries
@@ -501,37 +613,105 @@ def _compiled_ingest_chunk(cfg: LlamaConfig, n_slots: int, max_len: int,
 
 
 def make_chunk_scan_step(decode_one, max_len: int, temperature: float,
-                         top_k, top_p, eos_id):
+                         top_k, top_p, eos_id, draft_one=None,
+                         logprobs: bool = False):
     """THE per-step body of every chunked serving loop — dense and paged
     (models/paged.py) scan exactly this, so the liveness/eos/budget/
     emission semantics cannot drift between cache layouts.
     ``decode_one(cache, token, pos) -> (logits, cache)``; what it returns
     beyond the two is emitted per step after ``(tokens, mask)``.  A scan
     over inputs hands each step's to ``decode_one`` as a fourth argument
-    (the mixed chunk's prompt pieces)."""
+    (the mixed chunk's prompt pieces).
+
+    ``draft_one`` (a model with an MTP block): the step SPECULATES and
+    yields one or two tokens a slot.  The carry then ends in the slots'
+    draft state ``(d [B], q, log q(d) [B])`` (:func:`_draft_from`):
+
+    1. verify (``sw_mtp_verify``): ``decode_one(cache, [pending, d], pos)
+       -> (logits [B, 2, V], cache, hidden [B, 2, D], counts)``, both
+       positions in one pass through the cache;
+    2. accept (``sw_mtp_accept``): :func:`~starway_tpu.models.speculative.
+       accept_rule`, the rule of every speculative driver: the step's
+       tokens are ``[d, bonus]`` or ``[correction]``.  Budget, eos and
+       ``max_len`` are applied token by token, in the lines every server
+       runs, so a request never gets more than it asked for;
+    3. draft (``sw_mtp_draft``): ``draft_one(cache, hidden, tokens [B, 2],
+       pos, at) -> (draft logits [B, V], cache)`` runs the block
+       at both positions (its row ``pos`` is made of the first token
+       whether that was the draft or its correction) and drafts from the
+       last emitted one's, ``at``.
+
+    It emits ``(tokens [2, B], masks [2, B], counts, spec)``; ``spec``:
+    ``accepted [B]`` (the slot's draft was accepted, and emitted) and,
+    with ``logprobs``, ``logp [2, B]`` (each token's log-probability under
+    the main model, :func:`_logp_of`), ``draft [B]`` and ``draft_logp
+    [B]`` (the draft the step verified and its ``log q``).  A rejected
+    draft leaves nothing behind: its entries lie beyond the cursor, where
+    the next step's writes land before any query reaches them."""
+    greedy = temperature == 0.0
+
+    def probs_of(logits):
+        return jax.nn.softmax(
+            _filter_logits(logits, temperature, top_k, top_p), axis=-1)
 
     def step(carry, xs):
-        cache, token, pos, live, remaining, key = carry
-        logits, cache, *extra = decode_one(
-            cache, token, pos, *(() if xs is None else (xs,)))
-        key, sub = jax.random.split(key)
-        nxt = _sample(logits, sub, temperature, top_k, top_p)
-        emit_live = live & (remaining > 0)
-        if eos_id is not None:
-            newly_done = emit_live & (nxt == eos_id)
+        cache, token, pos, live, remaining, key, *draft = carry
+        if draft_one is None:
+            logits, cache, *extra = decode_one(
+                cache, token, pos, *(() if xs is None else (xs,)))
+            key, sub = jax.random.split(key)
+            toks = [_sample(logits, sub, temperature, top_k, top_p)]
         else:
-            newly_done = jnp.zeros_like(emit_live)
-        remaining = remaining - emit_live.astype(jnp.int32)
-        live = emit_live & ~newly_done & (remaining > 0) & (
-            pos + 2 < max_len)
+            from .speculative import accept_rule
+
+            (d, q, dlp), pos0 = draft[0], pos
+            with jax.named_scope("sw_mtp_verify"):
+                logits, cache, hidden, counts = decode_one(
+                    cache, jnp.stack([token, d], axis=1), pos)
+            with jax.named_scope("sw_mtp_accept"):
+                a, c, key = accept_rule(d[:, None], q[:, None], logits, key,
+                                        greedy=greedy, probs_of=probs_of)
+                toks = [jnp.where(a > 0, d, c), c]
+        # Token by token: the i-th is emitted where the slot still lives
+        # behind the one before it (and the drafts before it were taken).
+        masks = []
+        for i, nxt in enumerate(toks):
+            emit_live = live & (remaining > 0)
+            if i:
+                emit_live = emit_live & (a >= i)
+            if eos_id is not None:
+                newly_done = emit_live & (nxt == eos_id)
+            else:
+                newly_done = jnp.zeros_like(emit_live)
+            remaining = remaining - emit_live.astype(jnp.int32)
+            after = emit_live & ~newly_done & (remaining > 0) & (
+                pos + (i + 2) < max_len)
+            live = jnp.where(emit_live, after, live) if i else after
+            masks.append(emit_live)
         # Dead slots freeze: cursor stays, pending token irrelevant
         # (their cache writes land on a position admission or the
         # cursor overwrites before any read — or, paged, in the trash
         # page).
-        pos = pos + emit_live.astype(jnp.int32)
-        token = jnp.where(emit_live, nxt, token)
-        return (cache, token, pos, live, remaining, key), (nxt, emit_live,
-                                                           *extra)
+        for emit_live, nxt in zip(masks, toks):
+            pos = pos + emit_live.astype(jnp.int32)
+            token = jnp.where(emit_live, nxt, token)
+        if draft_one is None:
+            return (cache, token, pos, live, remaining, key), (
+                toks[0], masks[0], *extra)
+        spec = {"accepted": masks[0] & (a > 0)}
+        if logprobs:
+            with jax.named_scope("sw_mtp_accept"):
+                spec.update(logp=_logp_of(logits, jnp.stack(toks, axis=1),
+                                          temperature).T,
+                            draft=d, draft_logp=dlp)
+        with jax.named_scope("sw_mtp_draft"):
+            dl, cache = draft_one(
+                cache, hidden, jnp.stack(toks, axis=1), pos0,
+                masks[1].astype(jnp.int32))
+            key, sub = jax.random.split(key)
+            draft = _draft_from(dl, sub, temperature, top_k, top_p)
+        return (cache, token, pos, live, remaining, key, draft), (
+            jnp.stack(toks), jnp.stack(masks), counts, spec)
 
     return step
 
@@ -616,7 +796,8 @@ class SlotServer:
                  max_len: int = 512, chunk: int = 8,
                  temperature: float = 0.0, top_k: Optional[int] = None,
                  top_p: Optional[float] = None, eos_id: Optional[int] = None,
-                 prompt_buckets=None, seed: int = 0, on_tokens=None):
+                 prompt_buckets=None, seed: int = 0, on_tokens=None,
+                 on_logprobs=None):
         from .moe import require_dropless
 
         # Cohabiting slots share the batch-wide expert capacity; only
@@ -629,6 +810,11 @@ class SlotServer:
         from .llama import resolve_longrope
 
         cfg = resolve_longrope(cfg, max_len)
+        if on_logprobs is not None and not cfg.mtp:
+            raise ValueError(
+                "on_logprobs hands out what a speculating server's accept "
+                "rule computed: it needs a configuration with an MTP block "
+                "(generate(return_logprobs=True) serves the others)")
         self.rolling = cfg.sliding_window is not None
         if n_slots < 1 or chunk < 1:
             # Zero slots/chunk would make run() spin forever, not error.
@@ -678,6 +864,16 @@ class SlotServer:
         self.live = jnp.zeros((n_slots,), bool)
         self.remaining = jnp.zeros((n_slots,), jnp.int32)
         self._pairs = None  # the last chunk's third output (_launch_chunk)
+        # A speculating server's (cfg.mtp): the slots' draft state, each
+        # admission's first token's log-probability until it is handed
+        # out, and the last chunk's ``spec`` output.
+        self._spec = None
+        if cfg.mtp:
+            wide = cfg.vocab_size if self.sampling[0] else 1
+            self._draft = (jnp.zeros((n_slots,), jnp.int32),
+                           jnp.zeros((n_slots, wide), jnp.float32),
+                           jnp.zeros((n_slots,), jnp.float32))
+            self._first_lp = jnp.zeros((n_slots,), jnp.float32)
         # What the host knows of ``live`` and ``pos`` without asking the
         # device: the last chunk's final values (fetched with its tokens),
         # then each admission's cursor and whether it can outlive its first
@@ -705,6 +901,15 @@ class SlotServer:
         # transport bridge (models/remote_serving.py) rides this to stream
         # tokens over the wire without waiting for full completion.
         self.on_tokens = on_tokens
+        # ``on_logprobs(rid, logp, drafts)`` (a speculating server's):
+        # fires just before the ``on_tokens`` call that hands out the same
+        # tokens, with each one's log-probability under the main model at
+        # the served temperature, before top-k / nucleus (greedy: at
+        # temperature 1), and ``drafts``: ``(at, token, log q, accepted)``
+        # of each draft verified in the steps those tokens came from (none
+        # beside a first token); ``at``: the index among them of the token
+        # the draft stood for, which is the draft itself where accepted.
+        self.on_logprobs = on_logprobs
         # The serve scope (DESIGN.md §13): this server's rows of the module
         # logs while their requests are open, its phase accumulator, and --
         # only when swtrace is armed -- a ring of its own in the registry,
@@ -737,12 +942,14 @@ class SlotServer:
         a piece would have to attend over a ring its own later tokens
         overwrite), an int8 cache (a piece attends over
         what the cache holds, quantized there, where a prefill reads the
-        prompt's k/v exact: other tokens than ``generate()``'s) and a
+        prompt's k/v exact: other tokens than ``generate()``'s), a model
+        with an MTP block (its admission runs the block over the prompt
+        and seats its row and the first draft) and a
         subclass with a layout of its own (the page pool overrides this).
         A ``prefix=`` request takes its admit program on every kind
         (:meth:`_ingests`)."""
         if (self.rolling or self._ring or "k" not in self.cache
-                or "k_scale" in self.cache):
+                or "k_scale" in self.cache or self.cfg.mtp):
             return ()
         widths = []
         for w in INGEST_WIDTHS:
@@ -792,6 +999,10 @@ class SlotServer:
         costs one suffix-bucket chunk ingest, not a full-prompt prefill.
         The prefix cache lives in host-visible HBM ([L, 1, Hkv, bucket,
         D] per prefix) until :meth:`drop_prefix`."""
+        if self.cfg.mtp:
+            raise ValueError("prefix caching copies the model's rows; an "
+                             "MTP block's own row over the prefix would "
+                             "have to be kept and copied too (ROADMAP M5)")
         if self.rolling or self._ring:
             raise ValueError("prefix caching needs the dense slot cache; "
                              "rolling (sliding-window) slots rebuild their "
@@ -847,6 +1058,8 @@ class SlotServer:
                "n_prompt": len(prompt), "bucket": None, "n_out": 0,
                "step0": None, "steps": 0, "t_submit": _now(), "t_admit0": None,
                "t_first": None, "t_done": None, "status": "queued"}
+        if self.cfg.mtp:
+            row["spec_accepted"] = 0
         try:
             self._check_request(prompt, max_new_tokens, prefix)
         except (ValueError, KeyError):
@@ -946,6 +1159,11 @@ class SlotServer:
         it admitted at once (:meth:`_hand_out_firsts`), and ``on_tokens``
         fires there."""
         eos = -1 if self.eos_id is None else self.eos_id
+        if self.cfg.mtp:  # the admit program's: token, then the draft's
+            tok, *spec = tok
+            self._draft, self._first_lp = _seat_draft(
+                self._draft, self._first_lp, jnp.asarray(slot, jnp.int32),
+                *spec)
         self.token, self.pos, self.live, self.remaining = _seat(
             self.token, self.pos, self.live, self.remaining, tok,
             jnp.asarray([slot, cursor, max_new, eos], jnp.int32))
@@ -969,6 +1187,13 @@ class SlotServer:
         self._step["fetches"] += 1
         return jax.device_get(tree)
 
+    def _seated(self):
+        """What :meth:`_hand_out_firsts` reads, as the admissions left it:
+        the slots' pending tokens and, where log-probabilities are handed
+        out, the first tokens' beside them."""
+        return (self.token if self.on_logprobs is None
+                else (self.token, self._first_lp))
+
     def _hand_out_firsts(self, seated) -> None:
         """The first tokens of everything admitted since the last call, in
         one read of ``seated``: ``self.token`` as the last admission left
@@ -980,6 +1205,8 @@ class SlotServer:
         firsts, self._firsts = self._firsts, []
         with perf.stage_span(self.stage_scope, "serve.first_wait"):
             tokens = self._fetch(seated)
+        if self.on_logprobs is not None:
+            tokens, first_lp = tokens
         step = self._step
         step["admit_s"] = _now() - step["t0"]
         t_first = step["t0"] + step["admit_s"]  # as step_log()'s readers add
@@ -993,6 +1220,8 @@ class SlotServer:
             self._collected[rid].append(tok)
             if tok == self.eos_id:  # max_new == 1: known at admission
                 self._live_host[slot] = False
+            if self.on_logprobs is not None:
+                self.on_logprobs(rid, [float(first_lp[slot])], [])
             if self.on_tokens is not None:
                 self.on_tokens(rid, [tok], False)
 
@@ -1117,8 +1346,10 @@ class SlotServer:
             step["queued"], step["live"] = (len(self._pending),
                                             len(self._slot_rid))
             if self._ring or self._state:
-                at = 1 + self._pos_host[[s for s in self._slot_rid
-                                         if self._live_host[s]]].astype(int)
+                # (a speculating step's verify attends from pos + 1 too)
+                at = 1 + self.cfg.mtp + self._pos_host[
+                    [s for s in self._slot_rid
+                     if self._live_host[s]]].astype(int)
                 if self._ring:
                     step.update(
                         kv_rows_full=int(at.sum()),
@@ -1135,13 +1366,17 @@ class SlotServer:
                 self.key, sub = jax.random.split(self.key)
                 toks, mask = self._run_chunk(sub)
                 with perf.stage_span(scope, "serve.chunk_wait") as span:
-                    toks, mask, pairs, firsts, live, pos = self._fetch(
+                    toks, mask, pairs, firsts, live, pos, spec = self._fetch(
                         (toks, mask, self._pairs, self._seat_toks, self.live,
-                         self.pos))
+                         self.pos, self._spec))
                 step["wait_s"] = span.seconds
                 self._live_host, self._pos_host = np.array(live), np.array(pos)
                 if pairs is not None:  # a routed model's
                     step.update(_moe_fields(pairs))
+                if spec is not None:  # a speculating server's
+                    step.update(spec_drafted=int(mask[:, 0].sum()),
+                                spec_accepted=int(spec["accepted"].sum()),
+                                spec_emitted=int(mask.sum()))
                 with perf.stage_span(scope, "serve.harvest") as span:
                     self._hand_out_seated(firsts)
                     # Snapshot: an on_tokens callback may legally cancel()
@@ -1150,19 +1385,43 @@ class SlotServer:
                     for slot, rid in list(self._slot_rid.items()):
                         if rid not in self._collected:
                             continue  # cancelled by an earlier callback
-                        new = [int(t) for t, m
-                               in zip(toks[:, slot], mask[:, slot]) if m]
+                        # ([chunk, B], or a speculating server's [chunk,
+                        # 2, B]: a step's tokens in their order)
+                        kept = mask[..., slot].ravel()
+                        new = [int(t) for t in toks[..., slot].ravel()[kept]]
                         self._collected[rid].extend(new)
+                        if spec is not None and new:
+                            self._note_spec(rid, slot, kept, mask, spec)
                         if self.on_tokens is not None and new:
                             self.on_tokens(rid, new, False)
                     self._harvest_dead(finished)
                 step["harvest_s"] = span.seconds
             else:
-                self._hand_out_firsts(self.token)
+                self._hand_out_firsts(self._seated())
                 self._harvest_dead(finished)
         step["t1"] = whole.t0 + whole.seconds
         _step_log.append(step)
         return finished
+
+    def _note_spec(self, rid: int, slot: int, kept, mask, spec) -> None:
+        """A chunk's speculation for one request: its accepted drafts into
+        its :func:`request_log` row and, where asked for, the tokens'
+        log-probabilities and the drafts to ``on_logprobs``."""
+        verified = mask[:, 0, slot]
+        row = self._rows.get(rid)
+        if row is not None:
+            row["spec_accepted"] += int(spec["accepted"][verified, slot].sum())
+        if self.on_logprobs is not None:
+            # where each verifying step's first token lies among ``kept``
+            at = (np.cumsum(kept) - 1)[0::2][verified]
+            drafts = [(int(i), int(d), float(lq), bool(ok))
+                      for i, d, lq, ok in zip(
+                          at, spec["draft"][verified, slot],
+                          spec["draft_logp"][verified, slot],
+                          spec["accepted"][verified, slot])]
+            self.on_logprobs(
+                rid, [float(x) for x in spec["logp"][..., slot].ravel()[kept]],
+                drafts)
 
     @property
     def busy(self) -> bool:
@@ -1177,7 +1436,7 @@ class SlotServer:
         that times this call and then waits on what it returned (the
         benchmark's traced runs do) would otherwise hold them back for a
         whole chunk."""
-        seated = self.token
+        seated = self._seated()
         with perf.stage_span(self.stage_scope, "serve.chunk_dispatch") as span:
             out = self._launch_chunk(sub)
         self._step["dispatch_s"] = span.seconds
@@ -1191,6 +1450,12 @@ class SlotServer:
         the plan chose (:meth:`_plan_ingest`), else the plain one."""
         pieces = self._plan_ingest() if self._ingest else ()
         run = self._chunk_program(pieces[1].shape[1] if pieces else None)
+        if self.cfg.mtp:  # the draft state in, and out with ``spec``
+            (self.cache, self.token, self.pos, self.live, self.remaining,
+             _key, self._draft, toks, mask, self._pairs, self._spec) = run(
+                 self.params, self.cache, self.token, self.pos, self.live,
+                 self.remaining, sub, self._draft)
+            return toks, mask
         (self.cache, self.token, self.pos, self.live, self.remaining,
          _key, toks, mask, self._pairs, *firsts) = run(
              self.params, self.cache, self.token, self.pos, self.live,
@@ -1203,7 +1468,9 @@ class SlotServer:
         if width is None:
             return _compiled_chunk(
                 self.cfg, self.n_slots, self.max_len, self.chunk,
-                *self.sampling, self.eos_id, rolling=self.rolling)
+                *self.sampling, self.eos_id, rolling=self.rolling,
+                **({"logprobs": True} if self.on_logprobs is not None
+                   else {}))
         return _compiled_ingest_chunk(
             self.cfg, self.n_slots, self.max_len, self.chunk, width,
             *self.sampling, self.eos_id)

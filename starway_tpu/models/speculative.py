@@ -42,8 +42,7 @@ from jax import lax
 
 from .generate import (_filter_logits, _sample, _write_cached, attend_cache,
                        cache_len, cached_layer_scan, prefill)
-from .llama import (LlamaConfig, cfg_rope_tables, embed_tokens, matmul_w,
-                    rmsnorm)
+from .llama import LlamaConfig, cfg_rope_tables, embed_tokens, head_logits
 
 
 def chunk_decode_step(params, cache, tokens, pos, cfg: LlamaConfig, rope):
@@ -59,15 +58,35 @@ def chunk_decode_step(params, cache, tokens, pos, cfg: LlamaConfig, rope):
     falls out of the positions.  This is the speculative VERIFY step, and
     generally useful for multi-token ingestion (teacher forcing, cache
     warm-up) at decode-path semantics.  Dense FFN and MoE follow
-    decode_step; rolling caches are not supported (speculative decoding
-    targets the full-cache path) — a window-sized cache raises rather
-    than silently writing absolute positions into a modular window.  The
-    check is a shape heuristic (rolling and full caches share a layout),
-    so a FULL cache allocated with max_len exactly == sliding_window is
-    rejected too; allocate max_len = window + C for ingestion — positions
-    past the window are masked out of attention anyway, so the extra
-    slots change nothing.
+    decode_step.
+
+    A ``cfg.kinds`` cache's RINGS take a chunk when they are longer than
+    their window by at least ``C - 1`` positions (``LayerKinds.slack``):
+    position ``p`` is written at ``p % T`` and every slot is read under
+    the mask of the position it holds, so the chunk's last write reaches
+    no entry its first query attends, and a position written and then
+    given up (a rejected draft) is overwritten before any query reaches it
+    (the invariant of the full rows).  A ring of exactly one window
+    refuses.  The whole-model rolling cache is not supported (speculative
+    decoding targets the full-cache path) — a window-sized cache raises
+    rather than silently writing absolute positions into a modular window.
+    The check is a shape heuristic (rolling and full caches share a
+    layout), so a FULL cache allocated with max_len exactly ==
+    sliding_window is rejected too; allocate max_len = window + C for
+    ingestion — positions past the window are masked out of attention
+    anyway, so the extra slots change nothing.
     """
+    h, out, _ = chunk_decode_hidden(params, cache, tokens, pos, cfg, rope)
+    return head_logits(h, params["final_norm"], params["lm_head"],
+                       cfg.norm_eps), out  # [B, C, V]
+
+
+def chunk_decode_hidden(params, cache, tokens, pos, cfg: LlamaConfig, rope):
+    """:func:`chunk_decode_step` up to the last layer's output: ``(h [B,
+    C, D]`` before the final norm, the updated cache, the routed layers'
+    pair counts of :func:`~starway_tpu.models.generate.cached_layer_scan``)``.
+    What a server that drafts with an MTP block verifies with: the block
+    reads ``h`` (models/mtp.py)."""
     B, C = tokens.shape
     T_cache = cache_len(cache)
     if "kda_state" in cache:
@@ -75,11 +94,14 @@ def chunk_decode_step(params, cache, tokens, pos, cfg: LlamaConfig, rope):
             "chunk_decode_step does not support linear-attention layers "
             "(cfg.linear): a state moved on by C tokens cannot be taken "
             "back to where the accepted ones end (ROADMAP M4: snapshots)")
-    if "k_ring" in cache:
+    if "k_ring" in cache and (cache["k_ring"].shape[3]
+                              < cfg.kinds.window + C - 1):
         raise ValueError(
-            "chunk_decode_step does not support rings (cfg.kinds): a chunk "
-            "written into a ring overwrites entries its own earlier "
-            "positions attend")
+            f"chunk_decode_step needs rings of at least window + C - 1 = "
+            f"{cfg.kinds.window + C - 1} positions, got "
+            f"{cache['k_ring'].shape[3]}: a chunk written into a ring of "
+            f"one window overwrites entries its own earlier positions "
+            f"attend (LayerKinds.slack lengthens the rings)")
     if cfg.sliding_window is not None and T_cache == cfg.sliding_window:
         # Mirrors decode_step's rolling-cache shape check, inverted: a
         # cache of exactly sliding_window slots is a rolling cache
@@ -100,42 +122,38 @@ def chunk_decode_step(params, cache, tokens, pos, cfg: LlamaConfig, rope):
     cos_p = cos[pos_bc][:, None]  # [B, 1, C, hd/2]
     sin_p = sin[pos_bc][:, None]
 
-    def write(cache, new, layer):
-        # C contiguous entries at each row's cursor.
-        return _write_cached(cache, new, layer, pos_b)
+    def write(cache, new, layer, ring=False):
+        # C contiguous entries at each row's cursor (a ring: modulo).
+        return _write_cached(cache, new, layer, pos_b, ring=ring)
 
-    def attend(q, cache, layer):
+    def attend(q, cache, layer, ring=False):
         # The SAME grouped-stream attention decode_step uses, at C query
         # positions: on TPU the pallas kernel packs C x n_rep rows into
         # one per-(batch, kv head) matmul over the narrow (int8-capable)
         # cache stream — the verify costs one decode step's bytes.
-        return attend_cache(q, cache, pos_b, layer, cfg)
+        return attend_cache(q, cache, pos_b, layer, cfg, ring=ring)
 
     h = embed_tokens(params, tokens, cfg)  # [B, C, D]
-    h, out, _ = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg, write,
-                                  attend)
-    h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    logits = matmul_w(h, params["lm_head"]).astype(jnp.float32)  # [B, C, V]
-    return logits, out
+    return cached_layer_scan(params, cache, h, cos_p, sin_p, cfg, write,
+                             attend)
 
 
 # ------------------------------------------------------------- the driver
 
 
-def _accept_emit(drafts, pd, t_logits, key, out, n_out, t_pend, pos, stats,
-                 *, greedy: bool, G: int, B: int, max_new: int, probs_of):
-    """The acceptance rule + output bookkeeping every speculative driver
-    shares (model-draft and prompt-lookup): leading-accept count, the
-    correction/bonus token, per-row emit at the cursor, and the
-    freeze/clamp logic that keeps every position inside max_len.
+def accept_rule(drafts, pd, t_logits, key, *, greedy: bool, probs_of):
+    """THE acceptance rule of speculative decoding, once, for every
+    driver (model-draft, prompt-lookup, and the serving step that drafts
+    with an MTP block: models/serving.py).
 
     drafts [B, G-1], pd [B, G-1, V] (the PROPOSAL distributions — one-hot
-    for deterministic drafters), t_logits [B, G, V] from the chunk
-    verify.  Returns ``(out, n_out, t_pend, pos, key, stats, emit)``;
-    ``emit [B, G]`` is the written token vector ([d_1..d_a, c, junk]) so
-    a caller maintaining its own sequence buffer can mirror the write.
-    """
-    idx = jnp.arange(G - 1)[None, :]
+    for deterministic drafters; unread when ``greedy``), t_logits [B, G,
+    V] from the chunk verify; ``probs_of(logits)``: the distribution the
+    target samples from (temperature, then top-k / nucleus).  Returns
+    ``(a [B]`` the leading-accept count in ``[0, G-1]``, ``c [B]`` the
+    correction (a rejection's draw from ``norm(max(p - q, 0))``) or bonus
+    token at ``pos + a + 1``, ``key)``."""
+    G = t_logits.shape[1]
     if greedy:
         tgt = jnp.argmax(t_logits[:, :-1], -1)  # [B, G-1]
         ok = drafts == tgt
@@ -176,6 +194,23 @@ def _accept_emit(drafts, pd, t_logits, key, out, n_out, t_pend, pos, stats,
         c = jax.random.categorical(
             ckey, jnp.log(jnp.maximum(dist, 1e-30)), axis=-1
         ).astype(jnp.int32)
+    return a, c, key
+
+
+def _accept_emit(drafts, pd, t_logits, key, out, n_out, t_pend, pos, stats,
+                 *, greedy: bool, G: int, B: int, max_new: int, probs_of):
+    """:func:`accept_rule` + the output bookkeeping the generation drivers
+    share (model-draft and prompt-lookup): per-row emit at the cursor,
+    and the freeze/clamp logic that keeps every position inside max_len.
+
+    drafts [B, G-1], pd [B, G-1, V], t_logits [B, G, V] as the rule takes
+    them.  Returns ``(out, n_out, t_pend, pos, key, stats, emit)``;
+    ``emit [B, G]`` is the written token vector ([d_1..d_a, c, junk]) so
+    a caller maintaining its own sequence buffer can mirror the write.
+    """
+    idx = jnp.arange(G - 1)[None, :]
+    a, c, key = accept_rule(drafts, pd, t_logits, key, greedy=greedy,
+                            probs_of=probs_of)
 
     # Emit d_1..d_a then c: a+1 tokens at each row's cursor.
     emit = jnp.where(idx < a[:, None], drafts, 0)

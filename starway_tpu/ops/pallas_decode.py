@@ -51,7 +51,8 @@ from .quantize import dequantize_kv
 
 def _softmax_block_update(q, k, v, k_start, pos, m_scr, l_scr, acc_scr, *,
                           sm_scale: float, window: "int | None",
-                          k_scale=None, v_scale=None, row_off=None):
+                          k_scale=None, v_scale=None, row_off=None,
+                          ring: "tuple | None" = None):
     """The one online-softmax block body the decode kernels share: score
     the group's query rows against one [block_k, D] cache block, mask by
     global position (and window), and fold into the m/l/acc scratches.
@@ -67,7 +68,12 @@ def _softmax_block_update(q, k, v, k_start, pos, m_scr, l_scr, acc_scr, *,
     folded into the existing algebra instead of widening the operands —
     k's scale multiplies the score COLUMNS (``(q . k_int8[c]) * s_k[c]``)
     and v's scale folds into the softmax weights before the ``p @ v``
-    matmul, so no dequantized [block_k, D] tile is ever materialised."""
+    matmul, so no dequantized [block_k, D] tile is ever materialised.
+
+    ``ring = (T, top)`` (a ring longer than its window, written at ``p %
+    T``): slot ``s`` holds position ``top - (top - s) % T``, ``top`` being
+    the last position written; a slot no position reached yet reads a
+    negative one and is masked."""
     s = jax.lax.dot_general(
         q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -78,7 +84,12 @@ def _softmax_block_update(q, k, v, k_start, pos, m_scr, l_scr, acc_scr, *,
         s = s * sm_scale
     kv_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     q_pos = pos if row_off is None else pos + row_off  # [rows, 1]
+    if ring is not None:
+        t, top = ring
+        kv_pos = top - jax.lax.rem(top - kv_pos + t, t)  # 0 <= slot < t
     keep = kv_pos <= q_pos
+    if ring is not None:
+        keep = keep & (kv_pos >= 0)
     if window is not None:
         keep = keep & (kv_pos > q_pos - window)
     s = jnp.where(keep, s, NEG_BIG)
@@ -121,7 +132,7 @@ def _decode_stream_kernel(pos_ref, layer_ref, *refs,
                           sm_scale: float, block_k: int, hkv: int,
                           window: "int | None", n_blocks: int,
                           quant: bool = False, n_q: int = 1,
-                          by_row: bool = False):
+                          by_row: bool = False, ring: bool = False):
     """One grid cell per (batch, kv head): the WHOLE cache sweep runs in a
     single cell as a fori_loop over kv blocks with double-buffered manual
     DMA (compute on block i overlaps the HBM stream of block i+1).
@@ -143,6 +154,10 @@ def _decode_stream_kernel(pos_ref, layer_ref, *refs,
     ``by_row``: a third prefetched scalar array names the CACHE row each
     batch row reads (:func:`slot_attention`: the pieces of one prompt, all
     on their request's slot); without it batch row b reads cache row b.
+
+    ``ring`` (with ``window``): the cache is a ring of ``T > window``
+    positions written at ``p % T`` and ``pos`` is absolute: every warm
+    block is streamed and each slot masked by the position it holds.
     """
     if by_row:
         row_ref, *refs = refs
@@ -159,11 +174,13 @@ def _decode_stream_kernel(pos_ref, layer_ref, *refs,
     layer = layer_ref[0]
     pos = pos_ref[b]
     hi = (pos + n_q - 1) // block_k  # last live block (queries span n_q)
+    if ring:
+        hi = jnp.minimum(hi, n_blocks - 1)
     if by_row:
         # A prompt's last piece is padded: its pad queries may lie past T.
         hi = jnp.minimum(hi, n_blocks - 1)
         b = row_ref[b]
-    if window is None:
+    if window is None or ring:
         lo = jnp.int32(0)
     else:
         lo = jnp.maximum(pos - window + 1, 0) // block_k
@@ -216,7 +233,8 @@ def _decode_stream_kernel(pos_ref, layer_ref, *refs,
                 acc_scr, sm_scale=sm_scale, window=window,
                 k_scale=None if not quant else _head_row(ks_buf[slot], h),
                 v_scale=None if not quant else _head_row(vs_buf[slot], h),
-                row_off=_row_offsets(q.shape[0], n_q))
+                row_off=_row_offsets(q.shape[0], n_q),
+                ring=(n_blocks * block_k, pos + n_q - 1) if ring else None)
 
         return 0
 
@@ -241,7 +259,8 @@ def _pick_block(t: int, block_k: int, quant: bool) -> "int | None":
 def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
                      block_k: int = 512, interpret=None, window=None,
                      k_scale=None, v_scale=None, rows=None,
-                     kernel_name: str = "sw_decode_attn_stream"):
+                     kernel_name: str = "sw_decode_attn_stream",
+                     ring: bool = False):
     """Cached decode attention (1..C query positions) without expanding
     the grouped cache.
 
@@ -285,7 +304,17 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
     row reads, a third prefetched scalar array; queries may then lie past
     the cache's end (they see every position).  ``kernel_name``: what a
     trace calls the kernel.
+
+    ``ring`` (with ``window``; T a multiple of 128): the cache is a ring
+    of ``T > window`` positions written at ``p % T``, ``pos`` stays
+    absolute and every slot is masked by the position it holds
+    (:func:`_softmax_block_update`): what a step that writes several
+    positions a row before it knows which of them stay reads its window
+    layers through.
     """
+    if ring and (window is None or k_cache.shape[-2] % 128):
+        raise ValueError("a masked ring needs its window and a length of "
+                         "whole 128-lane tiles")
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
     quant = k_scale is not None or v_scale is not None
@@ -345,7 +374,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
             _decode_stream_kernel, sm_scale=sm_scale, block_k=block_k,
             hkv=hkv, window=None if window is None else int(window),
             n_blocks=t // block_k, quant=quant, n_q=n_q,
-            by_row=bool(by_row)),
+            by_row=bool(by_row), ring=ring),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2 + len(by_row),
             grid=(b * hkv,),
@@ -370,7 +399,8 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
 
 
 def decode_attention_lax(q, k_cache, v_cache, pos, *, layer=None,
-                         window=None, k_scale=None, v_scale=None):
+                         window=None, k_scale=None, v_scale=None,
+                         ring: bool = False):
     """:func:`decode_attention` in plain lax (softmax in f32): what runs
     where Pallas does not, and what the kernel is tested against.  It
     slices the layer out, dequantizes an int8 cache up front and expands
@@ -391,7 +421,12 @@ def decode_attention_lax(q, k_cache, v_cache, pos, *, layer=None,
     kv_pos = jnp.arange(k.shape[2])[None, None, None, :]
     qp = (jnp.asarray(pos).reshape(-1)[:, None, None, None]
           + jnp.arange(q.shape[2])[None, None, :, None])
+    if ring:  # slot s holds the latest position p <= top with p % T == s
+        top = qp[:, :, -1:, :]
+        kv_pos = top - (top - kv_pos) % k.shape[2]
     keep = kv_pos <= qp
+    if ring:
+        keep = keep & (kv_pos >= 0)
     if window is not None:
         keep = keep & (kv_pos > qp - window)
     s = jnp.where(keep, s, NEG_BIG)
@@ -412,16 +447,28 @@ def cached_attention(q, k_cache, v_cache, pos, *, layer=None, window=None,
     written at ``pos % T`` (models/generate.py): its warm slots ARE the
     window, so the query at ``pos`` sees every slot up to ``min(pos, T -
     1)`` with no window re-mask (keys carry their absolute RoPE, and
-    attention does not depend on the order of its keys).  The same
-    kernel, which a trace then calls ``sw_decode_attn_ring``."""
-    if ring:
-        if window is not None:
-            raise ValueError("a ring's residents are its window: no window=")
+    attention does not depend on the order of its keys).  With
+    ``window=`` the ring is LONGER than that window (``T > window``,
+    ``LayerKinds.slack``): ``pos`` stays absolute, every slot is read
+    under the mask of the position it holds (:func:`decode_attention`'s
+    ``ring``), and ``C`` query positions may have been written.  The
+    same kernel either way, which a trace then calls
+    ``sw_decode_attn_ring``."""
+    masked = ring and window is not None
+    if masked and not window < k_cache.shape[-2]:
+        raise ValueError(f"a masked ring is longer than its window, got "
+                         f"{k_cache.shape[-2]} for window {window}")
+    if ring and not masked:
+        if q.shape[2] != 1:
+            raise ValueError(
+                "a ring of exactly one window takes one position a step: a "
+                "chunk written into it overwrites entries its own earlier "
+                "positions attend (LayerKinds.slack lengthens it)")
         pos = jnp.minimum(jnp.asarray(pos, jnp.int32), k_cache.shape[-2] - 1)
     if not dispatch.use_kernels():
         return decode_attention_lax(q, k_cache, v_cache, pos, layer=layer,
                                     window=window, k_scale=k_scale,
-                                    v_scale=v_scale)
+                                    v_scale=v_scale, ring=masked)
     if layer is None:  # one layer's caches: a stack of one
         k_cache, v_cache, k_scale, v_scale = (
             None if a is None else a[None]
@@ -434,7 +481,7 @@ def cached_attention(q, k_cache, v_cache, pos, *, layer=None, window=None,
         ks, vs = rest[:-2] or (None, None)
         return decode_attention(q, k, v, rest[-2], layer=rest[-1],
                                 window=window, k_scale=ks, v_scale=vs,
-                                kernel_name=name)
+                                kernel_name=name, ring=masked)
 
     # Heads (dim 1 of q, dim 2 of the stacked caches and scales) shard
     # alike; pos (a scalar, or one cursor per batch row) and the layer

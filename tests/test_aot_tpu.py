@@ -626,6 +626,62 @@ def test_two_cache_kinds_ride_the_decode_chunk_for_v5e(topo, monkeypatch):
     assert moved == []
 
 
+def test_draft_and_verify_chunk_moves_no_rows_or_rings_for_v5e(topo,
+                                                               monkeypatch):
+    """k-exaone.think_closed's decode chunk at the cell's shapes (the 2
+    full layers' rows of 4,096, the 6 window layers' masked rings of 256 =
+    window 128 + slack, and the MTP block's own row), sampled as the cell
+    samples and emitting log-probabilities: a step verifies two positions
+    a slot, so a ring takes TWO writes a layer and is read under position
+    masks, and the block's rows leave the cache dict for its own layer
+    scan and come back.  All three kinds ride the scans' carries in place:
+    every cache byte is aliased and no instruction copies, slices or
+    scatters an array of any kind's shape or of one layer of it."""
+    import re
+
+    from benchmark.harness import spec as S
+    from benchmark.harness import weights_k_exaone as W
+    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.serving import _compiled_chunk
+
+    _as_tpu(monkeypatch)
+    config = S.load_config(S.load_spec(), "k-exaone")
+    runner = S.load_runner(config["runner"])
+    cfg, sv = runner.model_config(config), config["serve"]
+    params = jax.eval_shape(
+        lambda: runner.program_tree(W.make_model(0, W.dims(config))))
+    n, max_len = sv["n_slots"], sv["max_len"]
+    cache = jax.eval_shape(lambda: init_cache(cfg, n, max_len))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (2, n, 8, 4096, 128), "v": (2, n, 8, 4096, 128),
+        "k_ring": (6, n, 8, 256, 128), "v_ring": (6, n, 8, 256, 128),
+        "k_mtp": (1, n, 8, 4096, 128), "v_mtp": (1, n, 8, 4096, 128)}
+    run = _compiled_chunk(cfg, n, max_len, sv["chunk"], sv["temperature"],
+                          None, sv["top_p"], None, logprobs=True)
+    draft = (_s((n,), I32), _s((n, cfg.vocab_size), F32), _s((n,), F32))
+    compiled = run.lower(*_placed(
+        (params, cache, *_slot_state(n), draft),
+        SingleDeviceSharding(topo.devices[0]))).compile()
+    text = compiled.as_text()
+    for name in ("sw_kv_write", "sw_decode_attn_stream",
+                 "sw_decode_attn_ring", "sw_moe_gmm"):
+        assert name in text, name
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= sum(
+        a.size * 2 for a in jax.tree_util.tree_leaves(cache))
+    moved = []
+    for t in (4096, 256):
+        shaped = re.compile(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[(?:\d+,)?"
+            + re.escape(f"{n},8,{t},128]") + r"\S* ([\w\-]+)\(")
+        moved += [(mm.group(1), mm.group(2))
+                  for mm in map(shaped.match, text.splitlines())
+                  if mm and mm.group(2) not in {
+                      "parameter", "get-tuple-element", "bitcast",
+                      "custom-call"}]
+    assert moved == []
+
+
 def test_state_without_positions_rides_the_decode_chunk_for_v5e(topo,
                                                                 monkeypatch):
     """kimi-linear.reason_closed's decode chunk at the cell's shapes (the

@@ -444,8 +444,25 @@ def test_ring_attention_is_window_attention_over_whole_rows(pos):
     got = cached_attention(q, fold(full_k), fold(full_v), pos, layer=1,
                            ring=True)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    with pytest.raises(ValueError, match="no window"):
-        cached_attention(q, full_k, full_v, pos, layer=1, window=T, ring=True)
+    # A ring LONGER than its window (LayerKinds.slack) is read under the
+    # masks of the positions its slots hold: here 12 slots for a window of
+    # 8, and a second query a row (a draft beside the pending token).
+    R = 12
+    top = pos[:, None] + 1
+    src = top - (top - jnp.arange(R)[None, :]) % R
+    fold = lambda a: jnp.take_along_axis(
+        a, jnp.clip(src, 0)[None, :, None, :, None], axis=3)
+    q2 = jax.random.normal(ks[2], (B, 2 * H, 2, D))
+    want = cached_attention(q2, full_k, full_v, pos, layer=1, window=T)
+    got = cached_attention(q2, fold(full_k), fold(full_v), pos, layer=1,
+                           ring=True, window=T)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    with pytest.raises(ValueError, match="longer than its window"):
+        cached_attention(q, full_k[..., :T, :], full_v[..., :T, :], pos,
+                         layer=1, window=T, ring=True)
+    with pytest.raises(ValueError, match="one position a step"):
+        cached_attention(q2, full_k[..., :T, :], full_v[..., :T, :], pos,
+                         layer=1, ring=True)
 
 
 def test_ring_decode_kernel_matches_lax(force_kernels):
@@ -465,6 +482,16 @@ def test_ring_decode_kernel_matches_lax(force_kernels):
     fn = jax.jit(lambda q, k, v, pos: cached_attention(q, k, v, pos, layer=1,
                                                        ring=True))
     np.testing.assert_allclose(fn(q, k, v, pos), want, rtol=2e-5, atol=2e-5)
+    # The same ring as one LONGER than a window of 128, two queries a row:
+    # every slot under the mask of the position it holds, cold slots out.
+    q2 = jax.random.normal(ks[2], (B, Hq, 2, D))
+    masked = lambda q, k, v, pos: cached_attention(
+        q, k, v, pos, layer=1, ring=True, window=128)
+    force_kernels(False)
+    want = masked(q2, k, v, pos)
+    force_kernels(True)
+    np.testing.assert_allclose(jax.jit(masked)(q2, k, v, pos), want,
+                               rtol=2e-5, atol=2e-5)
 
 
 def test_both_cache_kinds_on_the_kernels_side(runner, force_kernels):
