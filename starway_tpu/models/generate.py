@@ -728,24 +728,56 @@ def validate_prompt_lengths(prompt_lengths, B: int, P: int):
     return lengths
 
 
+def _nucleus_key(l):
+    """float32 -> uint32 in the same order: a non-negative's sign bit is
+    set, a negative's bits are negated (so -0.0 meets +0.0, as it does
+    in a float comparison)."""
+    bits = lax.bitcast_convert_type(l, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, -bits, bits | jnp.uint32(1 << 31))
+
+
 def _filter_logits(logits, temperature: float, top_k: Optional[int],
                    top_p: Optional[float]):
     """The sampling distribution's logits: temperature-scaled, then top-k /
     nucleus masked (NEG_BIG outside the kept set).  ``softmax`` of the
     result IS the distribution :func:`_sample` draws from — speculative
     decoding's acceptance rule needs exactly it (models/speculative.py).
-    Only meaningful for ``temperature > 0``."""
+    Only meaningful for ``temperature > 0``.
+
+    The nucleus, with ``l = logits / temperature`` (after top-k's mask)
+    and ``p = softmax(l)``: the kept set is ``{i : l_i >= t}``, ``t`` the
+    smallest entry ``v`` of the row whose mass strictly above it, ``S(v) =
+    sum of p_j over l_j > v``, is ``< top_p``.  So ties at the threshold
+    are all kept, and the row's maximum always is (``S(max) = 0``).
+
+    ``S`` falls as ``v`` rises, so ``t`` needs no sort: it is the largest
+    ``T``, over the logits' ordered 32-bit keys (:func:`_nucleus_key`),
+    whose mass AT OR ABOVE it, ``sum of e_j over key_j >= T`` with ``e =
+    exp(l - max)``, still reaches ``top_p * sum(e)``: built bit by bit from
+    the top, 32 passes over the row in float32, every one of them needed
+    (no data value lies between that ``T`` and ``t``)."""
     l = logits / temperature
     if top_k is not None and top_k < l.shape[-1]:
         kth = lax.top_k(l, top_k)[0][..., -1:]
         l = jnp.where(l < kth, NEG_BIG, l)
     if top_p is not None and top_p < 1.0:
-        srt = jnp.sort(l, axis=-1)[..., ::-1]
-        probs = jax.nn.softmax(srt, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        keep = (cum - probs) < top_p  # exclusive prefix mass; index 0 stays
-        thresh = jnp.min(jnp.where(keep, srt, jnp.inf), axis=-1, keepdims=True)
-        l = jnp.where(l < thresh, NEG_BIG, l)
+        # Rows by vocabulary, whatever the caller's leading axes: a
+        # ``[B, 1, V]`` operand would ride the loop one sublane a tile.
+        rows = l.reshape(-1, l.shape[-1])
+        f32 = rows.astype(jnp.float32)
+        key = _nucleus_key(f32)
+        e = jnp.exp(f32 - jnp.max(f32, axis=-1, keepdims=True))
+        budget = top_p * jnp.sum(e, axis=-1, keepdims=True)
+
+        def take_bit(i, T):
+            cand = T | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+            mass = jnp.sum(jnp.where(key >= cand, e, 0.0), axis=-1,
+                           keepdims=True)
+            return jnp.where(mass >= budget, cand, T)
+
+        thresh = lax.fori_loop(0, 32, take_bit,
+                               jnp.zeros(budget.shape, jnp.uint32))
+        l = jnp.where(key < thresh, NEG_BIG, rows).reshape(l.shape)
     return l
 
 
@@ -754,8 +786,10 @@ def _sample(logits, key, temperature: float, top_k: Optional[int],
     """One sampled token id per row of ``logits [B, V]``.  Static Python
     ``temperature``/``top_k``/``top_p`` (baked into the compiled step):
     temperature 0 = greedy; top-k keeps the k largest logits; top-p keeps
-    the smallest prefix of the sorted distribution with cumulative mass
-    >= top_p (the first token is always kept)."""
+    every token whose logit is at least the nucleus threshold of
+    :func:`_filter_logits` (the tokens with less than ``top_p`` of the
+    mass strictly above them: ties at the threshold all stay, and the
+    most likely token always does)."""
     if temperature == 0.0:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     l = _filter_logits(logits, temperature, top_k, top_p)

@@ -100,6 +100,15 @@ def rolling_primitive_oracle(params, cfg):
     return oracle
 
 
+def sorts_over(text: str, width: int) -> list:
+    """The ``sort`` instructions of a compiled program's text whose
+    operands' last dimension is ``width`` (a nucleus found by sorting its
+    vocabulary would be one: tests/test_mtp_serving.py, test_aot_tpu.py)."""
+    return [line.strip()[:160] for line in text.splitlines()
+            if " sort(" in line
+            and f",{width}]" in line.split(" sort(")[0].replace("[", ",")]
+
+
 @pytest.fixture
 def force_kernels(monkeypatch):
     """``force_kernels(on)``: substitute the ONE decision of

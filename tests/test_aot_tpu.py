@@ -636,11 +636,14 @@ def test_draft_and_verify_chunk_moves_no_rows_or_rings_for_v5e(topo,
     masks, and the block's rows leave the cache dict for its own layer
     scan and come back.  All three kinds ride the scans' carries in place:
     every cache byte is aliased and no instruction copies, slices or
-    scatters an array of any kind's shape or of one layer of it."""
+    scatters an array of any kind's shape or of one layer of it.  The
+    step's three nucleus filters (top-p 0.95 over 96 x 19,200) search the
+    logits' ordered bits: no ``sort`` over the vocabulary is compiled in."""
     import re
 
     from benchmark.harness import spec as S
     from benchmark.harness import weights_k_exaone as W
+    from conftest import sorts_over
     from starway_tpu.models.generate import init_cache
     from starway_tpu.models.serving import _compiled_chunk
 
@@ -666,6 +669,9 @@ def test_draft_and_verify_chunk_moves_no_rows_or_rings_for_v5e(topo,
     for name in ("sw_kv_write", "sw_decode_attn_stream",
                  "sw_decode_attn_ring", "sw_moe_gmm"):
         assert name in text, name
+    assert (sv["temperature"], sv["top_p"], cfg.vocab_size) == (
+        1.0, 0.95, 19200)
+    assert sorts_over(text, 19200) == []
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= sum(
         a.size * 2 for a in jax.tree_util.tree_leaves(cache))

@@ -8,8 +8,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from jax import lax
+
 from starway_tpu.models import LlamaConfig, forward, init_params
-from starway_tpu.models.generate import decode_step, generate, init_cache
+from starway_tpu.models.generate import (_filter_logits, decode_step,
+                                         generate, init_cache)
+from starway_tpu.ops.attention import NEG_BIG
 from starway_tpu.models.llama import rope_tables
 
 
@@ -100,6 +104,112 @@ def test_generate_top_p(cfg, params):
     tiny = generate(params, cfg, prompt, max_new_tokens=4, temperature=1.0,
                     top_p=1e-9, key=jax.random.PRNGKey(5))
     np.testing.assert_array_equal(np.asarray(greedy), np.asarray(tiny))
+
+
+def _sorted_nucleus(l, top_p):
+    """The nucleus by a full sort: the form the program had before it
+    searched the logits' ordered bits, kept here as the reference.  Same
+    definition: exclusive prefix mass ``< top_p``, ties at the threshold
+    all kept, the maximum always."""
+    srt = jnp.sort(l, axis=-1)[..., ::-1]
+    probs = jax.nn.softmax(srt, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep = (cum - probs) < top_p
+    thresh = jnp.min(jnp.where(keep, srt, jnp.inf), axis=-1, keepdims=True)
+    return jnp.where(l < thresh, NEG_BIG, l)
+
+
+def _nucleus_case(name):
+    """``(logits, temperature, top_k, top_p)`` of one case."""
+    B, V = 96, 19200
+    key = jax.random.PRNGKey(38)
+    normal = jax.random.normal(key, (B, V), jnp.float32)
+    if name == "normal_1":
+        return normal, 1.0, None, 0.95
+    if name == "normal_3":
+        return 3.0 * normal, 1.0, None, 0.95
+    if name == "quarters":  # ties abound, at the threshold too
+        return jnp.round(8.0 * normal) / 4.0, 1.0, None, 0.95
+    if name == "constant":
+        return jnp.full((4, V), 1.25, jnp.float32), 0.7, None, 0.3
+    if name == "dominant":
+        return normal[:8].at[:, 17].set(40.0), 1.0, None, 0.5
+    if name == "after_top_k":
+        return 2.0 * normal[:16], 0.8, 50, 0.9
+    if name == "negative_only":
+        return -jnp.abs(2.0 * normal[:16]) - 1.0, 1.3, None, 0.95
+    if name == "signed_zeros":  # -0.0 and +0.0 are ONE value, as in a sort
+        row = jnp.asarray([0.0, -0.0, 1.0, -0.0, 0.0, -1.0, -2.0], jnp.float32)
+        return jnp.tile(row, (3, 1)), 1.0, None, 0.6
+    if name == "top_p_one":
+        return normal[:4], 1.0, None, 1.0
+    if name == "top_p_none":
+        return normal[:4], 1.0, None, None
+    if name == "rows_of_a_verify":  # [B, 1, V], as accept_rule passes it
+        return 1.5 * normal[:16, None, :], 1.0, None, 0.95
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "normal_1", "normal_3", "quarters", "constant", "dominant",
+    "after_top_k", "negative_only", "signed_zeros", "top_p_one",
+    "top_p_none", "rows_of_a_verify"])
+def test_filter_logits_nucleus_matches_sorted_form(name):
+    """``_filter_logits`` finds top-p's threshold by a search over the
+    logits' ordered bits; the sorted form is the SAME definition at the
+    same precision.  The kept sets are equal, except that the one value
+    at a row's nucleus boundary may fall on the other side where its own
+    mass above, ``S(v)``, is within 1e-5 of ``top_p`` (the two forms add
+    the same float32 probabilities in a different order): such values are
+    counted, and a row may have one."""
+    logits, temperature, top_k, top_p = _nucleus_case(name)
+    got = np.asarray(jax.jit(
+        lambda x: _filter_logits(x, temperature, top_k, top_p))(logits))
+    assert got.shape == logits.shape and got.dtype == np.float32
+
+    @jax.jit  # as the program scales: a compiled division is not eager's
+    def scaled(l):
+        l = l / temperature
+        if top_k is not None:
+            l = jnp.where(l < lax.top_k(l, top_k)[0][..., -1:], NEG_BIG, l)
+        return l
+
+    l = scaled(logits)
+    if top_p is None or top_p >= 1.0:
+        np.testing.assert_array_equal(got, np.asarray(l))  # no mask at all
+        return
+    ref = np.asarray(_sorted_nucleus(l, top_p))
+    l = np.asarray(l)
+    V = l.shape[-1]
+    kept, kept_ref = got != NEG_BIG, ref != NEG_BIG
+    # Kept entries pass through untouched; the maximum is always kept.
+    np.testing.assert_array_equal(got[kept], l[kept])
+    assert kept.reshape(-1, V)[np.arange(l.size // V),
+                               l.reshape(-1, V).argmax(-1)].all()
+
+    for row, k, kr in zip(l.reshape(-1, V), kept.reshape(-1, V),
+                          kept_ref.reshape(-1, V)):
+        values = np.unique(row[k != kr])
+        assert len(values) <= 1, (name, values)
+        for v in values:
+            p = np.exp(row.astype(np.float64) - row.max())
+            mass_above = p[row > v].sum() / p.sum()
+            assert abs(mass_above - top_p) < 1e-5, (name, v, mass_above)
+
+    n_kept = kept.reshape(-1, V).sum(-1)
+    if name == "constant":
+        assert (n_kept == V).all()
+    if name == "dominant":
+        assert (n_kept == 1).all() and kept[:, 17].all()
+    if name == "after_top_k":
+        assert (n_kept <= top_k).all() and not kept[l == NEG_BIG].any()
+    if name == "signed_zeros":
+        # S(0) = p(1.0) = 0.38 < 0.6: every zero stays, of either sign.
+        assert (n_kept == 5).all()
+    if name == "quarters":
+        # The threshold's tie group is kept whole.
+        for row, k in zip(l, kept):
+            assert not np.isin(row[~k], row[k]).any()
 
 
 def test_generate_tp_sharded(cfg, params):

@@ -251,6 +251,37 @@ def test_sampled_speculation_preserves_the_target_distribution():
     assert 0.2 < rate < 0.95, rate            # both branches ran, often
 
 
+def test_a_sampled_chunk_finds_its_nucleus_without_a_sort():
+    """A sampled draft-and-verify chunk (temperature 1.0, top-p 0.95, as
+    ``k-exaone.think_closed`` serves) filters three distributions a step
+    (two under the accept rule, one under the draft); none of them sorts
+    its vocabulary: the compiled program holds no ``sort`` over ``[..,
+    V]``.  The routed FFN's ``argsort`` of a step's pairs by expert stays,
+    and is another width."""
+    from conftest import sorts_over
+    from starway_tpu.models.serving import _compiled_chunk
+
+    V, n, max_len = 48, 3, 64
+    cfg = tiny_cfg(vocab=V)
+    assert V not in (2 * n * cfg.routed.top_k, n * cfg.routed.top_k)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: init_cache(cfg, n, max_len))
+    i32 = jax.ShapeDtypeStruct((n,), jnp.int32)
+    f32 = jax.ShapeDtypeStruct((n,), jnp.float32)
+    state = (i32, i32, jax.ShapeDtypeStruct((n,), bool), i32,
+             jax.eval_shape(jax.random.PRNGKey, 0))
+    draft = (i32, jax.ShapeDtypeStruct((n, V), jnp.float32), f32)
+    run = _compiled_chunk(cfg, n, max_len, 4, 1.0, None, 0.95, None,
+                          logprobs=True)
+    text = run.lower(params, cache, *state, draft).compile().as_text()
+    assert " sort(" in text          # the pairs' argsort: the text shows sorts
+    assert sorts_over(text, V) == []
+    # The reading is not blind: the sorted form's text is caught.
+    sorted_text = jax.jit(lambda l: jnp.sort(l, axis=-1)).lower(
+        draft[1]).compile().as_text()
+    assert len(sorts_over(sorted_text, V)) == 1
+
+
 # ------------------------------------------------------------------ the ring
 
 
