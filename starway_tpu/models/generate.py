@@ -41,9 +41,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .llama import (LlamaConfig, apply_rope, cfg_rope_tables, embed_tokens,
-                    ffn_block, forward, layer_segments, matmul_w, qkv_proj,
-                    rmsnorm, scan_segment, segment_kind)
+from .llama import (LlamaConfig, apply_rope, cfg_rmsnorm, cfg_rope_tables,
+                    embed_tokens, ffn_block, forward, gate_heads,
+                    layer_segments, matmul_w, qkv_proj, scan_segment,
+                    segment_kind)
 from ..ops import (cache_write, cached_attention, ingest_attention,
                    latent_attention)
 from ..ops.attention import NEG_BIG, repeat_kv
@@ -69,9 +70,11 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
     head_dim]``, each stacked over its own layers in model order.  Linear
     layers (``cfg.linear``) add a third kind with NO position axis:
     ``kda_state [linear layers, B, H, d, d]`` float32 and ``kda_conv
-    [linear layers, B, taps - 1, 3*H*d]``; ``max_len`` then sizes the
-    attention layers alone.  A ring holds ``cfg.kinds.ring`` positions:
-    the window and the slack a step of several positions needs.
+    [linear layers, B, taps - 1, conv_width]`` (q, k and v side by side:
+    ``3*H*d``, less where the key heads are fewer); ``max_len`` then
+    sizes the attention layers alone, latent rows or grouped-query
+    ``k`` / ``v`` as the model has them.  A ring holds ``cfg.kinds.ring``
+    positions: the window and the slack a step of several positions needs.
 
     An MTP block (``cfg.mtp``, models/mtp.py) keeps a full row of its own
     a batch row, ``k_mtp`` / ``v_mtp [1, B, Hkv, max_len, head_dim]``,
@@ -88,7 +91,7 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
         state = {
             "kda_state": jnp.zeros(
                 (n, batch, la.n_heads, la.head_dim, la.head_dim), jnp.float32),
-            "kda_conv": jnp.zeros((n, batch, la.conv - 1, 3 * la.width),
+            "kda_conv": jnp.zeros((n, batch, la.conv - 1, la.conv_width),
                                   cfg.compute_dtype)}
     if cfg.latent is not None:
         return {"ckv": jnp.zeros(
@@ -312,7 +315,7 @@ def decode_step_counted(params: dict, cache: dict, token, pos,
 
     h, out, counts = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg,
                                        write, attend)
-    h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
+    h = cfg_rmsnorm(h, params["final_norm"], cfg)
     logits = matmul_w(h[:, 0, :], params["lm_head"]).astype(jnp.float32)
     return logits, out, counts
 
@@ -376,8 +379,8 @@ def ingest_decode_step(params: dict, cache: dict, token, pos, piece,
     h, out, counts = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg,
                                        write, attend)
     last = lax.dynamic_slice_in_dim(h[:, 0], B + jnp.maximum(valid, 1) - 1, 1)
-    rows = rmsnorm(jnp.concatenate([h[:B, 0], last]), params["final_norm"],
-                   cfg.norm_eps)
+    rows = cfg_rmsnorm(jnp.concatenate([h[:B, 0], last]),
+                       params["final_norm"], cfg)
     logits = matmul_w(rows, params["lm_head"]).astype(jnp.float32)
     return logits[:B], out, logits[B], counts
 
@@ -433,7 +436,8 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
     def layer(carry, lp, li, *, rope=True, ring=False):
         kind = {"ring": True} if ring else {}
         h, cache = carry
-        x = rmsnorm(h, lp["attn_norm"], cfg.norm_eps)
+        gate = None
+        x = cfg_rmsnorm(h, lp["attn_norm"], cfg)
         if "kda" in lp:
             from .kda import kda_decode
 
@@ -452,7 +456,7 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
             cache = write(cache, {"ckv": rows}, li)
             o = expand_values(attend(q, cache, li), lp, cfg)
         else:
-            q, k, v = qkv_proj(x, lp, cfg)
+            q, k, v, gate = qkv_proj(x, lp, cfg)
             if rope and cos_p is not None:
                 q = apply_rope(q, cos_p, sin_p)
                 k = apply_rope(k, cos_p, sin_p)
@@ -465,9 +469,9 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
                 new["v"], new["v_scale"] = quantize_kv(v)
             cache = write(cache, new, li, **kind)
             o = attend(q, cache, li, **kind)
-        o = o.transpose(0, 2, 1, 3).reshape(B, C, -1)
+        o = gate_heads(o.transpose(0, 2, 1, 3).reshape(B, C, -1), gate)
         h = h + matmul_w(o, lp["wo"])
-        y, _aux, stats = ffn_block(rmsnorm(h, lp["mlp_norm"], cfg.norm_eps),
+        y, _aux, stats = ffn_block(cfg_rmsnorm(h, lp["mlp_norm"], cfg),
                                    lp, cfg, attn_in=x)
         return (h + y, cache), (stats if "routed" in lp else None)
 
@@ -619,7 +623,8 @@ def prefill_rolling(params: dict, cfg: LlamaConfig, prompt, *,
                                   cos[c0:c0 + Cc], sin[c0:c0 + Cc])
         c0 += Cc
     logits = head_logits(h_last[:, -1:], params["final_norm"],
-                         params["lm_head"], cfg.norm_eps)
+                         params["lm_head"], cfg.norm_eps,
+                         cfg.norm_zero_centred)
     return logits[:, 0], cache
 
 

@@ -64,22 +64,56 @@ class LatentAttn:
 
 @dataclasses.dataclass(frozen=True)
 class LinearAttn:
-    """Attention kind: gated delta-rule linear attention with a decay a
-    channel (KDA; models/kda.py).  ``n_heads`` heads whose keys and values
-    are both ``head_dim`` wide; q, k and v each pass a depthwise causal
-    convolution of ``conv`` taps; the decay and the output gate come
-    through low-rank maps of rank ``head_dim``.  A layer of this kind keeps
-    no keys and no values: its state a request is one ``[head_dim,
-    head_dim]`` float32 matrix a head and the last ``conv - 1`` inputs of
-    the convolutions, whatever the length."""
+    """Attention kind: gated delta-rule linear attention (models/kda.py).
+    ``n_heads`` value heads of ``head_dim`` (keys are as wide); q, k and v
+    pass a depthwise causal convolution of ``conv`` taps.  A layer of this
+    kind keeps no keys and no values: its state a request is one
+    ``[head_dim, head_dim]`` float32 matrix a value head and the last
+    ``conv - 1`` inputs of the convolution, whatever the length.
+
+    ``decay`` selects the parameterisation, which is also the layer's tree:
+    ``"channel"`` (Kimi Delta Attention): a log-decay a CHANNEL of a head's
+    keys and a sigmoid output gate, both through low-rank maps of rank
+    ``head_dim``, three projections side by side (``wqkv``);
+    ``"head"`` (Gated DeltaNet, Qwen3-Next): ONE log-decay a value head,
+    made with ``beta`` by one ``[D, 2 * n_heads]`` projection (``w_ba``),
+    and the output gate ``SiLU(z)`` of a full projection ``z`` that rides
+    the q/k/v projection (``w_qkvz``).
+    ``n_k_heads`` (default ``n_heads``): key heads, where they are fewer
+    than the value heads: value head ``j`` reads the q and k of key head
+    ``j // (n_heads // n_k_heads)``; the state's shape does not change."""
     n_heads: int
     head_dim: int
     conv: int = 4
+    n_k_heads: Optional[int] = None
+    decay: str = "channel"
+
+    def __post_init__(self):
+        if self.decay not in ("channel", "head"):
+            raise ValueError(f"LinearAttn.decay must be 'channel' or 'head', "
+                             f"got {self.decay!r}")
+        if self.n_heads % self.key_heads:
+            raise ValueError(f"LinearAttn: {self.n_heads} value heads do not "
+                             f"share {self.key_heads} key heads evenly")
 
     @property
     def width(self) -> int:
-        """Channels of each of q, k and v."""
+        """Channels of v (and of the layer's output before ``wo``)."""
         return self.n_heads * self.head_dim
+
+    @property
+    def key_heads(self) -> int:
+        return self.n_k_heads or self.n_heads
+
+    @property
+    def key_width(self) -> int:
+        """Channels of each of q and k."""
+        return self.key_heads * self.head_dim
+
+    @property
+    def conv_width(self) -> int:
+        """Channels the convolution runs over: q, k and v side by side."""
+        return 2 * self.key_width + self.width
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,7 +134,9 @@ class RoutedFFN:
     ``router_in``: which normed state the router scores, the FFN's own
     input (``"mlp_norm"``) or the block's input as attention sees it
     (``"attn_norm"``: experts are chosen BEFORE attention and applied
-    after it)."""
+    after it).  ``shared_gate``: the shared expert's output is scaled by a
+    gate of its own, ``sigmoid(x . shared_gate)``, one number a token (the
+    ``shared_gate [D, 1]`` leaf; Qwen3-Next)."""
     n_experts: int
     top_k: int
     d_expert: int
@@ -112,8 +148,12 @@ class RoutedFFN:
     score: str = "sigmoid"
     act: str = "silu"
     router_in: str = "mlp_norm"
+    shared_gate: bool = False
 
     def __post_init__(self):
+        if self.shared_gate and not self.n_shared:
+            raise ValueError("RoutedFFN.shared_gate gates the shared expert: "
+                             "it needs n_shared >= 1")
         for field, allowed in (("score", ("sigmoid", "softmax")),
                                ("act", ("silu", "relu")),
                                ("router_in", ("mlp_norm", "attn_norm"))):
@@ -270,6 +310,21 @@ class LlamaConfig:
     # of head_dim a layer each (``q_head_norm`` / ``k_head_norm`` leaves;
     # qkv_proj keys off their presence).
     qk_norm: bool = False
+    # Values of a head that are rotated, where that is not all of them
+    # (``partial_rotary_factor``): the first ``rotary_dim`` of each q and k
+    # head turn, split-half among themselves, the rest pass as projected.
+    # The tables are that wide (``rope_dim``) and ``apply_rope`` reads the
+    # width off them.  Grouped-query attention only.
+    rotary_dim: Optional[int] = None
+    # An output gate on attention: ``wq`` is twice as wide, each head's
+    # ``head_dim`` query values beside as many gate values, and the heads'
+    # outputs are multiplied by ``sigmoid(gate)`` before ``wo``
+    # (Qwen3-Next).  Grouped-query attention only.
+    attn_gate: bool = False
+    # Every RMSNorm of the model multiplies by ``1 + w`` in float32, not
+    # by ``w`` (a zero-centred gain: Gemma's and Qwen3-Next's form; a
+    # linear layer's head-wise output norm is not one of them).
+    norm_zero_centred: bool = False
     # Multi-token-prediction blocks behind the last layer (models/mtp.py):
     # each one decoder layer of the model's own kinds (full attention, the
     # FFN of the later layers) that reads the main model's last hidden
@@ -294,6 +349,11 @@ class LlamaConfig:
                 "state moved on by a rejected draft cannot be taken back), "
                 "no int8 cache, no whole-model rolling window, no capacity "
                 "MoE (ROADMAP M5)")
+        if self.latent is not None and (self.rotary_dim is not None
+                                        or self.attn_gate):
+            raise ValueError("rotary_dim and attn_gate are grouped-query "
+                             "attention's: latent attention has a rope part "
+                             "of its own and no gate")
         has_linear = self.kinds is not None and any(self.kinds.linear)
         if has_linear != (self.linear is not None):
             raise ValueError(
@@ -321,6 +381,12 @@ class LlamaConfig:
         elif self.head_dim_override < 2 or self.head_dim_override % 2:
             raise ValueError(f"head_dim_override must be an even int >= 2, "
                              f"got {self.head_dim_override}")
+        if self.rotary_dim is not None and (
+                self.rotary_dim < 2 or self.rotary_dim % 2
+                or self.rotary_dim > self.head_dim):
+            raise ValueError(f"rotary_dim must be an even int in [2, "
+                             f"head_dim={self.head_dim}], got "
+                             f"{self.rotary_dim}")
         if self.mlp_act not in ("silu", "gelu_tanh"):
             raise ValueError(
                 f"mlp_act must be 'silu' or 'gelu_tanh', got "
@@ -377,7 +443,9 @@ class LlamaConfig:
     @property
     def rope_dim(self) -> int:
         """Width of the rotated part of a head: the tables' width."""
-        return self.latent.rope_dim if self.latent else self.head_dim
+        if self.latent:
+            return self.latent.rope_dim
+        return self.rotary_dim or self.head_dim
 
     @property
     def compute_dtype(self):
@@ -507,6 +575,12 @@ def scan_segment(body, carry, seg, *xs):
     return lax.scan(step, carry, (rest, jnp.arange(n, dtype=jnp.int32), *xs))
 
 
+def _unit_gain(cfg: LlamaConfig, shape):
+    """A fresh norm's weight: the one that multiplies by one."""
+    return (jnp.zeros if cfg.norm_zero_centred else jnp.ones)(
+        shape, cfg.compute_dtype)
+
+
 def _init_block_params(key, cfg: LlamaConfig, plan=None) -> tuple:
     """The segments of a model whose blocks are not the default kinds
     (``plan``: other segments than ``cfg.segment_plan()``'s, of attention
@@ -517,6 +591,8 @@ def _init_block_params(key, cfg: LlamaConfig, plan=None) -> tuple:
     def norm(k, shape, scale):
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
 
+    unit_gain = partial(_unit_gain, cfg)
+
     def mlp(k, L, lead, F):
         ks = jax.random.split(k, 3)
         return {"w_gate": norm(ks[0], (L, *lead, D, F), D**-0.5),
@@ -525,8 +601,7 @@ def _init_block_params(key, cfg: LlamaConfig, plan=None) -> tuple:
 
     def segment(k, L, routed: bool, linear: bool):
         ks = jax.random.split(k, 8)
-        seg = {"attn_norm": jnp.ones((L, D), dt),
-               "mlp_norm": jnp.ones((L, D), dt)}
+        seg = {"attn_norm": unit_gain((L, D)), "mlp_norm": unit_gain((L, D))}
         la = cfg.latent
         if linear:
             from .kda import init_kda_params
@@ -551,13 +626,15 @@ def _init_block_params(key, cfg: LlamaConfig, plan=None) -> tuple:
                 wo=norm(ks[4], (L, H * la.v_dim, D), (H * la.v_dim)**-0.5))
         else:
             hd, Hkv = cfg.head_dim, cfg.n_kv_heads
-            seg.update(wq=norm(ks[0], (L, D, H * hd), D**-0.5),
+            # With an output gate each head's columns are [q | gate].
+            wide = 2 if cfg.attn_gate else 1
+            seg.update(wq=norm(ks[0], (L, D, wide * H * hd), D**-0.5),
                        wk=norm(ks[1], (L, D, Hkv * hd), D**-0.5),
                        wv=norm(ks[2], (L, D, Hkv * hd), D**-0.5),
                        wo=norm(ks[4], (L, H * hd, D), (H * hd)**-0.5))
             if cfg.qk_norm:
-                seg.update(q_head_norm=jnp.ones((L, hd), dt),
-                           k_head_norm=jnp.ones((L, hd), dt))
+                seg.update(q_head_norm=unit_gain((L, hd)),
+                           k_head_norm=unit_gain((L, hd)))
         if routed:
             r = cfg.routed
             seg["routed"] = {
@@ -573,6 +650,9 @@ def _init_block_params(key, cfg: LlamaConfig, plan=None) -> tuple:
                 seg["routed"]["shared"] = mlp(
                     jax.random.fold_in(ks[7], 1), L, (),
                     r.n_shared * r.d_expert)
+            if r.shared_gate:
+                seg["routed"]["shared_gate"] = norm(
+                    jax.random.fold_in(ks[7], 2), (L, D, 1), D**-0.5)
         else:
             seg.update(mlp(ks[7], L, (), cfg.d_ff))
         return seg
@@ -601,11 +681,12 @@ def init_params(key, cfg: LlamaConfig) -> dict:
     L, D, F = cfg.n_layers, cfg.d_model, cfg.d_ff
     Hq, Hkv = cfg.n_heads, cfg.n_kv_heads
     if (cfg.latent is not None or cfg.routed is not None
-            or cfg.kinds is not None or cfg.qk_norm or cfg.mtp):
+            or cfg.kinds is not None or cfg.qk_norm or cfg.mtp
+            or cfg.attn_gate or cfg.norm_zero_centred):
         segs = _init_block_params(jax.random.fold_in(key, 23), cfg)
         out = {"embed": norm(keys[0], (cfg.vocab_size, D), 0.02),
                "layers": segs[0] if len(segs) == 1 else segs,
-               "final_norm": jnp.ones((D,), dt),
+               "final_norm": _unit_gain(cfg, (D,)),
                "lm_head": norm(keys[8], (D, cfg.vocab_size), D**-0.5)}
         if cfg.mtp:
             from .mtp import init_mtp_params
@@ -652,10 +733,12 @@ def param_specs(cfg: LlamaConfig) -> dict:
     pattern over ICI automatically.  Embedding/lm_head shard the vocab dim.
     """
     if (cfg.latent is not None or cfg.routed is not None
-            or cfg.kinds is not None or cfg.qk_norm or cfg.mtp):
+            or cfg.kinds is not None or cfg.qk_norm or cfg.mtp
+            or cfg.attn_gate or cfg.norm_zero_centred):
         raise NotImplementedError(
             "latent attention, the routed FFN, attention kinds by layer "
-            "(linear layers among them), head norms and the MTP block have "
+            "(linear layers among them), head norms, the attention gate, "
+            "zero-centred norms and the MTP block have "
             "no sharding rules yet: they "
             "serve on one chip (the routed FFN as one holder of an "
             "expert-parallel deployment; ROADMAP M1, M2, M4)")
@@ -728,10 +811,20 @@ def matmul_w(x, w):
     return out.reshape(*x.shape[:-1], w["q"].shape[-1])
 
 
-def rmsnorm(x, w, eps: float):
+def rmsnorm(x, w, eps: float, zero_centred: bool = False):
+    """``x / rms(x) * w``; ``zero_centred``: ``* (1 + w)``, the gain taken
+    in float32 (``LlamaConfig.norm_zero_centred``)."""
     xf = x.astype(jnp.float32)
     scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    if zero_centred:
+        return (xf * scale * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
     return (xf * scale).astype(x.dtype) * w
+
+
+def cfg_rmsnorm(x, w, cfg: "LlamaConfig"):
+    """:func:`rmsnorm` keyed off a config: THE way model code norms (its
+    eps and whether its gains are zero-centred)."""
+    return rmsnorm(x, w, cfg.norm_eps, cfg.norm_zero_centred)
 
 
 def rope_tables(seq_len: int, head_dim: int, theta: float, scaling=None):
@@ -855,17 +948,25 @@ def apply_rope(x, cos, sin):
     are [S, Dh/2] tables, or already-broadcastable 4-D (e.g. per-row
     [B, 1, 1, Dh/2] angles for ragged decode).  NOTE: Meta's released Llama
     checkpoints use the interleaved-pair convention; loading them requires
-    permuting wq/wk columns accordingly."""
+    permuting wq/wk columns accordingly.  Tables narrower than half a head
+    (``LlamaConfig.rotary_dim``) rotate the head's first values, twice the
+    tables' width, among themselves and leave the rest as they are."""
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     c = cos[None, None, :, :] if cos.ndim == 2 else cos
     s = sin[None, None, :, :] if sin.ndim == 2 else sin
     return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1).astype(x.dtype)
 
 
-def head_logits(h, final_norm_w, lm_head_w, eps: float):
+def head_logits(h, final_norm_w, lm_head_w, eps: float,
+                zero_centred: bool = False):
     """Model tail: final RMSNorm + lm_head, f32 logits.  Shared by the scan
     forward and the pipeline last stage (models/pp_llama.py)."""
-    return matmul_w(rmsnorm(h, final_norm_w, eps), lm_head_w).astype(jnp.float32)
+    return matmul_w(rmsnorm(h, final_norm_w, eps, zero_centred),
+                    lm_head_w).astype(jnp.float32)
 
 
 def token_ce(logits, targets):
@@ -967,10 +1068,14 @@ def mlp_gate_act(x, cfg: "LlamaConfig"):
 
 def qkv_proj(x, lp, cfg: "LlamaConfig"):
     """q/k/v projections on ``x [B, S, D]`` -> ``[B, H, S, hd]`` heads,
-    pre-RoPE.  Optional per-head biases (Qwen2 family) apply when the
-    layer tree carries ``bq``/``bk``/``bv`` — leaf presence is the
-    marker, so converted trees work wherever the config doesn't travel;
-    likewise ``q_head_norm`` / ``k_head_norm`` (``cfg.qk_norm``).
+    pre-RoPE, and the output gate.  Optional per-head biases (Qwen2
+    family) apply when the layer tree carries ``bq``/``bk``/``bv`` — leaf
+    presence is the marker, so converted trees work wherever the config
+    doesn't travel; likewise ``q_head_norm`` / ``k_head_norm``
+    (``cfg.qk_norm``).  Returns ``(q, k, v, gate)``: ``gate`` is None, or
+    with ``cfg.attn_gate`` (``wq`` twice as wide, a head's columns ``[q |
+    gate]``) the sigmoid ``[B, S, H * hd]`` that :func:`gate_heads`
+    multiplies the heads' outputs by.
     The ONE projection site shared by the scan forward (decoder_layer)
     and the cached decode layer scan (generate.py)."""
     B, S = x.shape[0], x.shape[1]
@@ -982,12 +1087,27 @@ def qkv_proj(x, lp, cfg: "LlamaConfig"):
         q = q + lp["bq"]
         k = k + lp["bk"]
         v = v + lp["bv"]
+    gate = None
+    if cfg.attn_gate:
+        q, gate = jnp.split(q.reshape(B, S, cfg.n_heads, 2 * hd), 2, axis=-1)
+        gate = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(x.dtype)
+        gate = gate.reshape(B, S, cfg.n_heads * hd)
     q = q.reshape(B, S, cfg.n_heads, hd).transpose(0, 2, 1, 3)
     k = k.reshape(B, S, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3)
     if "q_head_norm" in lp:  # RMSNorm over each head, before the rotation
-        q = rmsnorm(q, lp["q_head_norm"], cfg.norm_eps)
-        k = rmsnorm(k, lp["k_head_norm"], cfg.norm_eps)
-    return q, k, v.reshape(B, S, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3)
+        q = cfg_rmsnorm(q, lp["q_head_norm"], cfg)
+        k = cfg_rmsnorm(k, lp["k_head_norm"], cfg)
+    return (q, k, v.reshape(B, S, cfg.n_kv_heads, hd).transpose(0, 2, 1, 3),
+            gate)
+
+
+def gate_heads(o, gate):
+    """The heads' outputs ``o [B, S, H * hd]`` (before ``wo``) times the
+    attention gate of :func:`qkv_proj`; ``gate`` None: as they are."""
+    if gate is None:
+        return o
+    with jax.named_scope("sw_attn_gate"):
+        return o * gate
 
 
 def ffn_block(x, lp, cfg: "LlamaConfig", moe_fn: Optional[Callable] = None,
@@ -1067,24 +1187,24 @@ def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
     early = cfg.routed is not None and cfg.routed.router_in == "attn_norm"
 
     def pre(h, lp):
-        x = rmsnorm(h, lp["attn_norm"], cfg.norm_eps)
+        x = cfg_rmsnorm(h, lp["attn_norm"], cfg)
         if "wkv_a" in lp:
             from .mla import project_expanded
 
             q, k, v, rows = project_expanded(x, lp, cfg, cos, sin)
-            return q, k, v, {"ckv": rows}, None
-        q, k, v = qkv_proj(x, lp, cfg)
+            return q, k, v, {"ckv": rows}, None, None
+        q, k, v, gate = qkv_proj(x, lp, cfg)
         if cos is not None:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
         # kv stays in grouped (narrow) form; attention impls expand it, so
         # the ring rotates 1/n_rep of the bytes over ICI.
-        return q, k, v, {"k": k, "v": v}, (x if early else None)
+        return q, k, v, {"k": k, "v": v}, (x if early else None), gate
 
-    def post(h, o, lp, attn_in):
-        o = o.transpose(0, 2, 1, 3).reshape(B, S, -1)
+    def post(h, o, lp, attn_in, gate=None):
+        o = gate_heads(o.transpose(0, 2, 1, 3).reshape(B, S, -1), gate)
         h = h + matmul_w(o, lp["wo"])
-        y, aux, stats = ffn_block(rmsnorm(h, lp["mlp_norm"], cfg.norm_eps),
+        y, aux, stats = ffn_block(cfg_rmsnorm(h, lp["mlp_norm"], cfg),
                                   lp, cfg, moe_fn, attn_in)
         if "routed" in lp:
             stats = None  # the held experts' pair counts: the decode path's
@@ -1114,16 +1234,16 @@ def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
 
         # No keys, no values, no attention call: the layer's own chunked
         # recurrence (ops.kda_chunk) and the state it leaves behind.
-        x = rmsnorm(h, lp["attn_norm"], cfg.norm_eps)
+        x = cfg_rmsnorm(h, lp["attn_norm"], cfg)
         o, kv = kda_prefill(x, lp["kda"], cfg, lengths)
         h, aux, stats = post(h, o, lp, None)
         return h, aux, kv, stats
-    q, k, v, kv, attn_in = pre(h, lp)
+    q, k, v, kv, attn_in, gate = pre(h, lp)
     o = attn_fn(q, k, v)  # [B, H, S, Dh]
     # Tag kept for user-supplied whole-model remat policies; the flash
     # kernel additionally tags o and lse internally (pallas_attention).
     o = checkpoint_name(o, "attn_out")
-    h, aux, stats = post(h, o, lp, attn_in)
+    h, aux, stats = post(h, o, lp, attn_in, gate)
     return h, aux, kv, stats
 
 
@@ -1243,7 +1363,8 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
         h = h[:, -1:]
     elif logit_positions is not None:
         h = jnp.take_along_axis(h, logit_positions[:, None, None], axis=1)
-    logits = head_logits(h, params["final_norm"], params["lm_head"], cfg.norm_eps)
+    logits = head_logits(h, params["final_norm"], params["lm_head"],
+                         cfg.norm_eps, cfg.norm_zero_centred)
     out = (logits,)
     if return_aux:
         out += (aux,)

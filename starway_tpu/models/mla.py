@@ -36,12 +36,12 @@ import jax.numpy as jnp
 def _queries(x, lp, cfg):
     """x [B, S, D] -> (q_nope [B, H, S, nope], q_pe [B, H, S, rope]),
     q_pe before its rotation."""
-    from .llama import matmul_w, rmsnorm
+    from .llama import cfg_rmsnorm, matmul_w
 
     la = cfg.latent
     b, s = x.shape[:2]
     if "wq_a" in lp:
-        cq = rmsnorm(matmul_w(x, lp["wq_a"]), lp["q_norm"], cfg.norm_eps)
+        cq = cfg_rmsnorm(matmul_w(x, lp["wq_a"]), lp["q_norm"], cfg)
         q = matmul_w(cq, lp["wq_b"])
     else:
         q = matmul_w(x, lp["wq"])
@@ -60,12 +60,12 @@ def _rotate(x, cos, sin):
 def latent_rows(x, lp, cfg, cos, sin):
     """What the cache holds of x [B, S, D]: ``[B, 1, S, cache_width]``,
     c_kv after its norm beside k_pe after RoPE, zeros above."""
-    from .llama import matmul_w, rmsnorm
+    from .llama import cfg_rmsnorm, matmul_w
 
     r = cfg.latent.kv_rank
     kv = matmul_w(x, lp["wkv_a"])[:, None]
     return _to_cache_width(jnp.concatenate(
-        [rmsnorm(kv[..., :r], lp["kv_norm"], cfg.norm_eps),
+        [cfg_rmsnorm(kv[..., :r], lp["kv_norm"], cfg),
          _rotate(kv[..., r:], cos, sin)], axis=-1), cfg)
 
 

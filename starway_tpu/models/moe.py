@@ -445,7 +445,9 @@ def routed_ffn(x, rp, routed, router_x=None):
     the held experts' share of the routed result, plus the shared experts.
     ``rp``: ``router [D, E]``, ``w_gate`` / ``w_up`` / ``w_down`` (held
     experts, stacked), ``bias [E]`` (sigmoid scoring) and ``shared`` (one
-    dense gated MLP; absent with ``n_shared == 0``).  ``routed``: the
+    dense gated MLP; absent with ``n_shared == 0``) and ``shared_gate [D,
+    1]`` (where the shared expert has a gate of its own: its output times
+    ``sigmoid(x . shared_gate)``, a number a token).  ``routed``: the
     configuration's :class:`~.llama.RoutedFFN`: its scoring rule and gate
     activation.  ``router_x`` (default ``x``): what the router scores,
     where that is not the experts' input.  Returns ``(y, sizes [G])``."""
@@ -464,5 +466,9 @@ def routed_ffn(x, rp, routed, router_x=None):
         sh = rp["shared"]
         gate = GATE_ACTS[routed.act](
             (xt @ sh["w_gate"]).astype(jnp.float32)).astype(xt.dtype)
-        y = y + (gate * (xt @ sh["w_up"])) @ sh["w_down"]
+        shared = (gate * (xt @ sh["w_up"])) @ sh["w_down"]
+        if "shared_gate" in rp:
+            shared = shared * jax.nn.sigmoid(
+                (xt @ rp["shared_gate"]).astype(jnp.float32)).astype(xt.dtype)
+        y = y + shared
     return y.reshape(b, s, d), sizes

@@ -33,8 +33,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from .llama import (LlamaConfig, _init_block_params, decoder_layer,
-                    embed_tokens, matmul_w, resolve_attn_fn, rmsnorm)
+from .llama import (LlamaConfig, _init_block_params, cfg_rmsnorm,
+                    decoder_layer, embed_tokens, matmul_w, resolve_attn_fn)
 
 
 def mtp_config(cfg: LlamaConfig) -> LlamaConfig:
@@ -63,16 +63,16 @@ def mtp_inputs(params: dict, cfg: LlamaConfig, hidden, next_tokens):
     layer's output at positions ``t``) and ``next_tokens [B, C]`` (the
     tokens at ``t + 1``)."""
     mp = params["mtp"]
-    e = rmsnorm(embed_tokens(params, next_tokens, cfg), mp["embed_norm"],
-                cfg.norm_eps)
-    h = rmsnorm(hidden, mp["hidden_norm"], cfg.norm_eps)
+    e = cfg_rmsnorm(embed_tokens(params, next_tokens, cfg), mp["embed_norm"],
+                    cfg)
+    h = cfg_rmsnorm(hidden, mp["hidden_norm"], cfg)
     return matmul_w(jnp.concatenate([e, h], axis=-1), mp["w_eh"])
 
 
 def mtp_logits(params: dict, cfg: LlamaConfig, m):
     """Draft logits (float32) of the block's outputs ``m [..., D]``,
     through the model's own head."""
-    m = rmsnorm(m, params["mtp"]["final_norm"], cfg.norm_eps)
+    m = cfg_rmsnorm(m, params["mtp"]["final_norm"], cfg)
     return matmul_w(m, params["lm_head"]).astype(jnp.float32)
 
 

@@ -46,7 +46,8 @@ from jax import lax
 from ..ops import paged_attention
 from ..ops.pallas_decode import kv_write_lax
 from .generate import _sample, _write_cached, cached_layer_scan, prefill
-from .llama import LlamaConfig, cfg_rope_tables, embed_tokens, matmul_w, rmsnorm
+from .llama import (LlamaConfig, cfg_rmsnorm, cfg_rope_tables, embed_tokens,
+                    matmul_w)
 from .serving import (SlotServer, _bucket, _named_jit, _on_weights_mesh,
                       make_chunk_scan_step)
 
@@ -92,7 +93,7 @@ def paged_decode_step(params, pool, table, token, pos, cfg: LlamaConfig,
     h = embed_tokens(params, token, cfg)[:, None, :]
     h, out, _ = cached_layer_scan(params, pool, h, cos_p, sin_p, cfg, write,
                                   attend)
-    h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
+    h = cfg_rmsnorm(h, params["final_norm"], cfg)
     logits = matmul_w(h[:, 0, :], params["lm_head"]).astype(jnp.float32)
     return logits, out
 
@@ -225,7 +226,7 @@ def _compiled_paged_prefix_admit(cfg: LlamaConfig, s_bucket: int, page: int,
                                        write, attend)
         logits = head_logits(h[:, s_len - 1][:, None], params["final_norm"],
                              params["lm_head"],
-                             cfg.norm_eps)[:, 0]
+                             cfg.norm_eps, cfg.norm_zero_centred)[:, 0]
         tok = _sample(logits, key, temperature, top_k, top_p)[0]
         return pool, tok
 

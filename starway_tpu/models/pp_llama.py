@@ -274,7 +274,7 @@ def make_pp_llama_train(mesh, cfg: LlamaConfig, *, axis_name: str = "pp",
 
     def loss_fn(head, y, target):
         logits = head_logits(y, head["final_norm"], head["lm_head"],
-                             cfg.norm_eps)
+                             cfg.norm_eps, cfg.norm_zero_centred)
         return token_ce(logits, target)
 
     if n_chunks > 1:

@@ -195,8 +195,10 @@ def step_log() -> list:
     while its slot lives).  A server whose cache holds linear-attention
     state (``cfg.linear``) adds ``state_slots`` (the slots that decode:
     each one's state, every linear layer, is read and written once a step)
-    and ``kv_rows_latent`` (``pos + 1`` summed over them: the cache rows
-    one attention layer's step attends), from the same cursors.  A server
+    and ``pos + 1`` summed over them, the cache rows one attention layer's
+    step attends, from the same cursors: ``kv_rows_latent`` where those
+    rows are latent, ``kv_rows_full`` where they are grouped-query ``k`` /
+    ``v`` (state BESIDE full rows: no ``kv_rows_window``).  A server
     that speculates (``cfg.mtp``) adds ``spec_drafted`` (drafts verified:
     one a live slot a step of the chunk), ``spec_accepted`` (of them, those
     the main model accepted) and ``spec_emitted`` (tokens the chunk's steps
@@ -515,7 +517,8 @@ def _compiled_chunk(cfg: LlamaConfig, n_slots: int, max_len: int, chunk: int,
                 params, cache, tokens, jnp.minimum(pos, max_len - 2), cfg,
                 rope)
             return (head_logits(h, params["final_norm"], params["lm_head"],
-                                cfg.norm_eps), cache, h, counts)
+                                cfg.norm_eps, cfg.norm_zero_centred),
+                    cache, h, counts)
 
         def draft_one(cache, hidden, tokens, pos, at):
             m, cache = mtp_chunk(params, cfg, cache, hidden, tokens,
@@ -936,8 +939,9 @@ class SlotServer:
         :meth:`_plan_ingest`: the last is the first at which the longest
         prompt this cache holds comes in within one chunk).  Every other
         kind keeps its admit programs and gets ``()``: a latent cache
-        (``ckv``), a linear layer's state beside it (``kda_state``: a piece
-        would have to move the state on by W tokens inside a decode step),
+        (``ckv``), a linear layer's state beside latent or grouped-query
+        rows (``kda_state``: a piece would have to move the state on by W
+        tokens inside a decode step),
         a rolling window, rings beside the full rows (``k_ring``:
         a piece would have to attend over a ring its own later tokens
         overwrite), an int8 cache (a piece attends over
@@ -948,8 +952,9 @@ class SlotServer:
         subclass with a layout of its own (the page pool overrides this).
         A ``prefix=`` request takes its admit program on every kind
         (:meth:`_ingests`)."""
-        if (self.rolling or self._ring or "k" not in self.cache
-                or "k_scale" in self.cache or self.cfg.mtp):
+        if (self.rolling or self._ring or self._state
+                or "k" not in self.cache or "k_scale" in self.cache
+                or self.cfg.mtp):
             return ()
         widths = []
         for w in INGEST_WIDTHS:
@@ -1354,9 +1359,11 @@ class SlotServer:
                     step.update(
                         kv_rows_full=int(at.sum()),
                         kv_rows_window=int(np.minimum(at, self._ring).sum()))
-                else:
-                    step.update(state_slots=len(at),
-                                kv_rows_latent=int(at.sum()))
+                else:   # state beside rows: latent, or grouped-query k / v
+                    rows = ("kv_rows_latent" if "ckv" in self.cache
+                            else "kv_rows_full")
+                    step.update({"state_slots": len(at),
+                                 rows: int(at.sum())})
             # A slot the host knows dead already (a one-token request just
             # admitted) needs no chunk; one whose first token may be its
             # eos is found out after the chunk was queued, and rides it

@@ -78,7 +78,7 @@ def chunk_decode_step(params, cache, tokens, pos, cfg: LlamaConfig, rope):
     """
     h, out, _ = chunk_decode_hidden(params, cache, tokens, pos, cfg, rope)
     return head_logits(h, params["final_norm"], params["lm_head"],
-                       cfg.norm_eps), out  # [B, C, V]
+                       cfg.norm_eps, cfg.norm_zero_centred), out
 
 
 def chunk_decode_hidden(params, cache, tokens, pos, cfg: LlamaConfig, rope):

@@ -1,6 +1,8 @@
 """The gated delta rule with a decay a channel (Kimi Delta Attention, KDA:
-arXiv 2510.26692), in the two forms a served model runs.  A head's state
-is a matrix ``S [d_k, d_v]`` in float32 and one token moves it by
+arXiv 2510.26692) or a decay a head (Gated DeltaNet, Qwen3-Next: every
+channel of a head decays alike), in the two forms a served model runs.  A
+head's state is a matrix ``S [d_k, d_v]`` in float32 and one token moves it
+by
 
     S' = diag(exp(g_t)) S          g_t <= 0: a log-decay a channel of d_k
     S  = S' + beta_t k_t (v_t - S'^T k_t)^T
@@ -23,7 +25,10 @@ is a matrix ``S [d_k, d_v]`` in float32 and one token moves it by
   U`` behind (``K+``, ``Q+``: rows times ``exp(G)``).  Every exponent is a
   decay BETWEEN two positions of the chunk, never above 0: nothing
   overflows however strong the decay (the factored form ``exp(G_i)
-  exp(-G_j)`` does; :func:`_pairwise` says how the decays are taken).  What is the same for every chunk (``A``, ``P``, the
+  exp(-G_j)`` does; :func:`_pairwise` says how the decays are taken; with
+  ONE decay a head they are a ``[C, C]`` matrix a head and ``A``, ``P``
+  plain matmuls times it: :func:`_pairwise_head`).  What is the same for
+  every chunk (``A``, ``P``, the
   solve against ``beta V`` and ``beta K+``) is computed for all chunks in
   plain lax; the part that carries ``S`` from chunk to chunk, three
   matmuls and an update a chunk, is the kernel ``sw_kda_chunk``
@@ -195,6 +200,21 @@ def _pairwise(q, k, gc, beta):
     return jnp.where(rows > cols, both[..., 0, :, :], 0.0), both[..., 1, :, :]
 
 
+def _pairwise_head(q, k, gc, beta):
+    """:func:`_pairwise` where a head has ONE decay: gc ``[..., C]``.  The
+    decay between two positions is then a number, ``exp(G_i - G_j)`` a
+    ``[C, C]`` matrix a head (every exponent at most 0), and ``A`` and
+    ``P`` are ``K K^T`` and ``Q K^T`` times it: two matmuls over d_k, no
+    ``[C, C, d_k]`` array and no sub-chunks."""
+    c = q.shape[-2]
+    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    decay = jnp.exp(jnp.where(i >= j, gc[..., :, None] - gc[..., None, :],
+                              -jnp.inf))
+    kk = jnp.einsum("...id,...jd->...ij", k * beta[..., None], k, precision=HI)
+    qk = jnp.einsum("...id,...jd->...ij", q, k, precision=HI)
+    return jnp.where(i > j, kk * decay, 0.0), qk * decay
+
+
 def _solve_unit_lower(a, rhs):
     """``(I + a)^{-1} rhs`` for strictly lower ``a [..., C, C]``, in blocks
     of ``SUB`` rows.  A diagonal block ``d`` is nilpotent (``d^SUB = 0``),
@@ -318,14 +338,18 @@ def kda_chunk_carry(qp, w, ut, p, ktail, decay):
 
 def kda_chunk(q, k, v, g, beta, *, chunk: int = 64):
     """A whole prompt through the recurrence, chunk by chunk, from a zero
-    state, the operation.  q, k, g ``[B, H, S, d_k]``, v ``[B, H, S,
-    d_v]``, beta ``[B, H, S]``; a position with ``g = 0`` and ``beta = 0``
+    state, the operation.  q, k ``[B, H, S, d_k]``, v ``[B, H, S, d_v]``,
+    g ``[B, H, S, d_k]`` (a log-decay a channel) or ``[B, H, S]`` (one a
+    head), beta ``[B, H, S]``; a position with ``g = 0`` and ``beta = 0``
     does not move the state (a bucket's pads).  Returns ``(o [B, H, S,
     d_v] float32, state [B, H, d_k, d_v] float32)``: every position's
     read-out and the state after the last.  S is padded to whole chunks
     with such standing positions."""
     f32 = jnp.float32
     s = q.shape[2]
+    by_head = g.ndim == 3
+    if by_head:
+        g = g[..., None]          # [B, H, S, 1]: broadcasts against d_k
     pad = -s % chunk
     if pad:
         q, k, v, g = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
@@ -334,16 +358,23 @@ def kda_chunk(q, k, v, g, beta, *, chunk: int = 64):
     q, k, v, g, beta = (_chunked(x.astype(f32), chunk)
                         for x in (q, k, v, g, beta))
     gc = jnp.cumsum(g, axis=-2)                          # [B, H, N, C, d_k]
-    # A chunk at a time: [B, H, C, C, d_k] of decays is 64 MiB at 32 heads.
-    a, p = lax.map(lambda x: _pairwise(*x), tuple(
-        jnp.moveaxis(x, 2, 0) for x in (q, k, gc, beta)))
-    a, p = jnp.moveaxis(a, 0, 2), jnp.moveaxis(p, 0, 2)
+    if by_head:
+        a, p = _pairwise_head(q, k, gc[..., 0], beta)
+    else:
+        # A chunk at a time: [B, H, C, C, d_k] of decays is 64 MiB at 32
+        # heads.
+        a, p = lax.map(lambda x: _pairwise(*x), tuple(
+            jnp.moveaxis(x, 2, 0) for x in (q, k, gc, beta)))
+        a, p = jnp.moveaxis(a, 0, 2), jnp.moveaxis(p, 0, 2)
     grow = jnp.exp(gc)
     dv = v.shape[-1]
     solved = _solve_unit_lower(a, jnp.concatenate(
         [v, k * grow], -1) * beta[..., None])
     total = gc[..., -1:, :]                              # G at the chunk's end
+    decay = jnp.exp(total)
+    if by_head:   # the carry takes a decay a channel: every channel alike
+        decay = jnp.broadcast_to(decay, total.shape[:-1] + k.shape[-1:])
     o, state = kda_chunk_carry(q * grow, solved[..., dv:], solved[..., :dv],
-                               p, k * jnp.exp(total - gc), jnp.exp(total))
+                               p, k * jnp.exp(total - gc), decay)
     o = o.reshape(o.shape[:2] + (-1, dv))
     return o[:, :, :s], state

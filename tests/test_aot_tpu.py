@@ -77,7 +77,7 @@ def _s(shape, dtype):
 # ------------------------------------------------------------------ kernels
 
 
-def _flash(hkv, *, window=None, grad=False, s=4096):
+def _flash(hkv, *, window=None, grad=False, s=4096, hq=HQ, d=D):
     from starway_tpu.ops.pallas_attention import flash_attention
 
     def fwd(q, k, v):
@@ -88,8 +88,8 @@ def _flash(hkv, *, window=None, grad=False, s=4096):
         return jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    kv = _s((1, hkv, s, D), BF16)
-    return (bwd if grad else fwd), (_s((1, HQ, s, D), BF16), kv, kv)
+    kv = _s((1, hkv, s, d), BF16)
+    return (bwd if grad else fwd), (_s((1, hq, s, d), BF16), kv, kv)
 
 
 def _ring_step(*, bwd=False, causal=True, t=2048):
@@ -112,14 +112,14 @@ def _ring_step(*, bwd=False, causal=True, t=2048):
 
 
 def _decode(hkv, *, c=1, int8=False, window=None, b=8, t=2048, layers=None,
-            hq=HQ, name="sw_decode_attn_stream"):
-    """``layers``: the scan-stacked cache ``[layers, b, hkv, t, D]`` read
+            hq=HQ, name="sw_decode_attn_stream", d=D):
+    """``layers``: the scan-stacked cache ``[layers, b, hkv, t, d]`` read
     through a traced layer index, as the serving chunk reads it."""
     from starway_tpu.ops.pallas_decode import decode_attention
 
     lead = () if layers is None else (layers,)
-    cache = _s(lead + (b, hkv, t, D), I8 if int8 else BF16)
-    args = [_s((b, hq, c, D), BF16), cache, cache, _s((b,), I32)]
+    cache = _s(lead + (b, hkv, t, d), I8 if int8 else BF16)
+    args = [_s((b, hq, c, d), BF16), cache, cache, _s((b,), I32)]
     if layers is not None:
         args.append(_s((), I32))
     if int8:
@@ -136,12 +136,12 @@ def _decode(hkv, *, c=1, int8=False, window=None, b=8, t=2048, layers=None,
     return fn, tuple(args)
 
 
-def _kv_write(b, hkv, t, layers):
+def _kv_write(b, hkv, t, layers, d=D):
     """A decode step's new k and v of ``b`` slots into one layer of the
     stacked caches, in place."""
     from starway_tpu.ops.pallas_decode import kv_write
 
-    cache, new = _s((layers, b, hkv, t, D), BF16), _s((b, hkv, 1, D), BF16)
+    cache, new = _s((layers, b, hkv, t, d), BF16), _s((b, hkv, 1, d), BF16)
     return (lambda k, v, nk, nv, layer, rows, pos: kv_write(
         (k, v), (nk, nv), layer, rows, pos, interpret=False)), (
             cache, cache, new, new, _s((), I32), _s((b,), I32), _s((b,), I32))
@@ -303,6 +303,19 @@ KERNELS = {
     "kda_chunk_carry_1024": lambda: _kda_chunk_carry(1024),
     "kda_chunk_carry_4096": lambda: _kda_chunk_carry(4096),
     "gmm_gated_reason": lambda: _gmm(2048, 16, 2304, 1024, True, 16),
+    # qwen3-next.answer_closed: 16 query heads over 2 kv heads of 256 (8 a
+    # kv head) in a 2,048-token admission's flash pass, the decode kernel
+    # and the write over 192 slots' rows of 4,096; 192 slots' DeltaNet
+    # state in six stacked layers; 192 x 10 pairs a decode step and a
+    # 2,048-token admit's 20,480 on 128 held experts of width 512.
+    "flash_fwd_answer": lambda: _flash(2, s=2048, hq=16, d=256),
+    "decode_answer_full": lambda: _decode(2, b=192, t=4096, layers=2, hq=16,
+                                          d=256),
+    "kv_write_answer": lambda: _kv_write(192, 2, 4096, 2, d=256),
+    "kda_step_answer": lambda: _kda_step(b=192),
+    "gmm_gated_answer": lambda: _gmm(1920, 16, 2048, 512, True, 128),
+    "gmm_down_answer": lambda: _gmm(1920, 16, 512, 2048, False, 128),
+    "gmm_gated_answer_admit": lambda: _gmm(20480, 128, 2048, 512, True, 128),
 }
 
 
@@ -724,6 +737,57 @@ def test_state_without_positions_rides_the_decode_chunk_for_v5e(topo,
         a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(cache))
     moved = []
     for shape in (f"{n_slots},32,128,128]", f"{n_slots},1,6144,640]"):
+        shaped = re.compile(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[(?:\d+,)?"
+            + re.escape(shape) + r"\S* ([\w\-]+)\(")
+        moved += [(mm.group(1), mm.group(2))
+                  for mm in map(shaped.match, text.splitlines())
+                  if mm and mm.group(2) not in {
+                      "parameter", "get-tuple-element", "bitcast",
+                      "custom-call"}]
+    assert moved == []
+
+
+def test_state_beside_grouped_query_rows_rides_the_decode_chunk_for_v5e(
+        topo, monkeypatch):
+    """qwen3-next.answer_closed's decode chunk at the cell's shapes (the 6
+    DeltaNet layers' state of 192 slots beside the 2 gated-attention
+    layers' k / v rows of 4,096 at 256-wide heads): state and rows ride
+    the scans' carries; the state is moved in place by ``sw_kda_step``
+    (fed one decay a head as a decay a channel), the rows are written by
+    ``sw_kv_write`` and read by ``sw_decode_attn_stream``, and no
+    instruction copies an array of the state's or of the rows' shape,
+    whole or one layer of it (a second copy of the state would be 2.4 GB,
+    of the rows 3.2)."""
+    import re
+
+    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.serving import _compiled_chunk
+
+    _as_tpu(monkeypatch)
+    cfg, params, n_slots, max_len = _cell_model("qwen3-next")
+    assert max_len == 4096
+    cache = jax.eval_shape(lambda: init_cache(cfg, n_slots, max_len))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (2, n_slots, 2, 4096, 256), "v": (2, n_slots, 2, 4096, 256),
+        "kda_state": (6, n_slots, 32, 128, 128),
+        "kda_conv": (6, n_slots, 3, 2048 + 2048 + 4096)}
+    run = _compiled_chunk(cfg, n_slots, max_len, CHUNK, 0.0, None, None, None)
+    compiled = run.lower(*_placed(
+        (params, cache, *_slot_state(n_slots)),
+        SingleDeviceSharding(topo.devices[0]))).compile()
+    text = compiled.as_text()
+    for name in ("sw_kda_step", "sw_decode_attn_stream", "sw_kv_write",
+                 "sw_moe_gmm"):
+        assert name in text, name
+    state_bytes = cache["kda_state"].size * 4
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < state_bytes / 4        # 0.16 GB of 2.4
+    assert m.alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(cache))
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes) < 15.75 * 2**30
+    moved = []
+    for shape in (f"{n_slots},32,128,128]", f"{n_slots},2,4096,256]"):
         shaped = re.compile(
             r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[(?:\d+,)?"
             + re.escape(shape) + r"\S* ([\w\-]+)\(")
