@@ -4,14 +4,24 @@
     JAX_PLATFORMS=cpu python scripts/compare_serving_programs.py <tree A> <tree B> [config ...]
 
 For each serving configuration of the benchmark that BOTH trees can build
-(default: ``kimi-k2`` and ``mistral7b``), each tree's decode chunk and one
-admission program (an admit bucket, or the mixed chunk where the server
-ingests) are compiled at the cell's own size for a described ``v5e:2x2``,
-no chip attached, one child process a tree, and compared: the compiled HLO
-with what names a source line taken out, and each Pallas kernel's Mosaic
-module printed without debug locations.  "SAME" means the change left that
-cell's device programs as they were: what a PR that touches shared model
-code quotes for a cell whose spread sits at its gate.
+(default: ``kimi-k2`` and ``mistral7b``), each tree's decode chunk and its
+admission programs (an admit bucket, or the mixed chunk of every width
+where the server ingests) are compiled at the cell's own size for a
+described ``v5e:2x2``, no chip attached, one child process a tree, and
+compared: the compiled HLO with what names a source line taken out, and
+each Pallas kernel's Mosaic module printed without debug locations.
+"SAME" means the change left that cell's device programs as they were:
+what a PR that touches shared model code quotes for a cell whose spread
+sits at its gate.
+
+Beside each program, for both trees: the seconds its trace and lowering
+took (what a warm start, its compile cache hit, still pays for every
+program before it can hash and load it) and the printed size of each
+distinct kernel module.  A kernel PR holds both within 1.25 times the
+parent's before it spends chip time (PERF.md section 7: ``setup_s``
+refused PR 41 for a body unrolled over the heads).  The seconds are this
+host's, under whatever else runs on it: read them as a ratio, and run
+twice where they decide.
 """
 
 from __future__ import annotations
@@ -23,13 +33,16 @@ import os
 import re
 import subprocess
 import sys
+import time
 
 CHILD = "--dump"
+# A runner's weights module where its name does not give it.
+WEIGHTS = {"serve_window_moe_mtp": "weights_k_exaone"}
 
 
 def _dump(root: str, configs: list) -> dict:
     """In a child, from the tree at ``root``: {program: [hlo text, [kernel
-    module text, ...]]}."""
+    module text, ...], seconds to trace and lower]}."""
     sys.path.insert(0, root)
     os.chdir(root)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -51,7 +64,10 @@ def _dump(root: str, configs: list) -> dict:
     on_chip = lambda tree: jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
 
-    def texts(lowered):
+    def texts(lower):
+        t0 = time.perf_counter()
+        lowered = lower()
+        seconds = time.perf_counter() - t0
         hlo = lowered.compile().as_text()
         kernels = []
         for m in re.finditer(r'\\?"body\\?": ?\\?"([A-Za-z0-9+/=]+)\\?"', hlo):
@@ -70,7 +86,7 @@ def _dump(root: str, configs: list) -> dict:
             if "tpu_custom_call" in line:   # its body is compared above
                 line = re.sub(r'backend_config=.*$', "", line)
             keep.append(line)
-        return "\n".join(keep), kernels
+        return "\n".join(keep), kernels, seconds
 
     out, spec = {}, S.load_spec()
     for name in configs:
@@ -81,8 +97,9 @@ def _dump(root: str, configs: list) -> dict:
             cfg = runner.llama_config(config)
         else:
             import importlib
-            W = importlib.import_module(
-                "benchmark.harness.weights_" + config["runner"][len("serve_"):])
+            W = importlib.import_module("benchmark.harness." + WEIGHTS.get(
+                config["runner"],
+                "weights_" + config["runner"][len("serve_"):]))
             cfg = runner.model_config(config)
         sv = config["serve"]
         n, max_len, chunk = sv["n_slots"], sv["max_len"], sv["chunk"]
@@ -93,21 +110,43 @@ def _dump(root: str, configs: list) -> dict:
         scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
         key = on_chip(jax.eval_shape(jax.random.PRNGKey, 0))
         state = (vec(jnp.int32), vec(jnp.int32), vec(bool), vec(jnp.int32), key)
-        run = serving._compiled_chunk(cfg, n, max_len, chunk, 0.0, None, None, None)
-        out[f"{name}.chunk"] = texts(run.lower(params, cache, *state))
+        if getattr(cfg, "mtp", None):   # drafts and verifies: as it samples
+            run = serving._compiled_chunk(
+                cfg, n, max_len, chunk, float(sv["temperature"]), None,
+                sv.get("top_p"), None, logprobs=True)
+            state += ((vec(jnp.int32), jax.ShapeDtypeStruct(
+                (n, cfg.vocab_size), jnp.float32, sharding=one),
+                vec(jnp.float32)),)
+        else:
+            run = serving._compiled_chunk(cfg, n, max_len, chunk, 0.0, None,
+                                          None, None)
+        out[f"{name}.chunk"] = texts(
+            lambda: run.lower(params, cache, *state))
         if config["runner"] == "serve":    # a dense server ingests
-            run = serving._compiled_ingest_chunk(cfg, n, max_len, chunk, 128,
-                                                 0.0, None, None, None)
             shaped = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)
-            out[f"{name}.ingest_128"] = texts(run.lower(
-                params, cache, *state,
-                shaped(chunk, len(serving.PIECE_FIELDS)), shaped(chunk, 128)))
+            for width in serving.INGEST_WIDTHS:
+                mixed = serving._compiled_ingest_chunk(
+                    cfg, n, max_len, chunk, width, 0.0, None, None, None)
+                out[f"{name}.ingest_{width}"] = texts(lambda: mixed.lower(
+                    params, cache, *state,
+                    shaped(chunk, len(serving.PIECE_FIELDS)),
+                    shaped(chunk, width)))
         else:
             admit = serving._compiled_admit(cfg, 1024, 0.0, None, None)
             prompt = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=one)
-            out[f"{name}.admit_1024"] = texts(admit.lower(
+            out[f"{name}.admit_1024"] = texts(lambda: admit.lower(
                 params, cache, prompt, scalar, scalar, key))
     return out
+
+
+def _sizes(kernels: list) -> dict:
+    """{kernel name: the distinct sizes its printed modules have}."""
+    by_name = {}
+    for text in kernels:
+        name = re.match(r"module @(\w+)", text)
+        by_name.setdefault(name.group(1) if name else "?", set()).add(
+            len(text))
+    return {name: sorted(sizes) for name, sizes in sorted(by_name.items())}
 
 
 def main(argv) -> int:
@@ -126,12 +165,18 @@ def main(argv) -> int:
     digest = lambda text: hashlib.sha256(text.encode()).hexdigest()[:12]
     same = True
     for program in dumps[0]:
-        (hlo_a, ker_a), (hlo_b, ker_b) = dumps[0][program], dumps[1][program]
+        (hlo_a, ker_a, sec_a), (hlo_b, ker_b, sec_b) = (
+            dumps[0][program], dumps[1][program])
         ok = hlo_a == hlo_b and ker_a == ker_b
         same &= ok
         print(f"{program}: {'SAME' if ok else 'DIFFERENT'}: HLO "
               f"{digest(hlo_a)} / {digest(hlo_b)}, {len(ker_a)} kernels "
               f"{[digest(k) for k in ker_a]} / {[digest(k) for k in ker_b]}")
+        print(f"    trace and lower {sec_a:.2f} s / {sec_b:.2f} s "
+              f"(x{sec_b / sec_a:.2f}); kernel modules, chars: "
+              f"{_sizes(ker_a)} / {_sizes(ker_b)}, all {sum(map(len, ker_a))}"
+              f" / {sum(map(len, ker_b))} "
+              f"(x{sum(map(len, ker_b)) / max(sum(map(len, ker_a)), 1):.2f})")
     return 0 if same else 1
 
 

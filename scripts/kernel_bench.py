@@ -397,6 +397,62 @@ def bench_decode_shapes(iters: int = 64, shapes=None):
                 f"({b},{hq},{hkv},{t})" for b, hq, hkv, t in shapes)}
 
 
+# The decode attention calls of the served cells (one layer's call at the
+# cell's slots, heads and cache length; cursors spread over what the cell's
+# traffic reaches): name -> (B, Hq, Hkv, D, C, T, window, ring, first and
+# last cursor).  ``ring``: "masked" a ring longer than its window, "whole"
+# a ring of exactly one window, None plain rows.
+DECODE_CELLS = {
+    "think_ring": (96, 64, 8, 128, 2, 256, 128, "masked", 64, 1800),
+    "think_full": (96, 64, 8, 128, 2, 4096, None, None, 64, 1800),
+    "chat_closed": (24, 32, 8, 128, 1, 2048, None, None, 32, 1000),
+    "longdoc_ring": (48, 28, 4, 128, 1, 4096, None, "whole", 2560, 12400),
+    "longdoc_full": (48, 28, 4, 128, 1, 16384, None, None, 2560, 12400),
+    "answer_closed": (192, 16, 2, 256, 1, 4096, None, None, 160, 2120),
+}
+
+
+def bench_decode_cells(iters: int = 64, cells=None):
+    """The decode attention kernel alone at the served cells' own shapes
+    (``DECODE_CELLS``): us a call and GB/s of the k/v it attends, one row
+    a shape and their sum: the yardstick a kernel PR starts from (PR 41's
+    builder made it and lost it; PERF.md section 6)."""
+    from starway_tpu.ops.pallas_decode import cached_attention
+
+    total, each = 0.0, {}
+    for name in cells or DECODE_CELLS:
+        b, hq, hkv, d, c, t, window, ring, first, last = DECODE_CELLS[name]
+        kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
+        q = jax.random.normal(kq, (b, hq, c, d), jnp.bfloat16)
+        kc = jax.random.normal(kk, (b, hkv, t, d), jnp.bfloat16)
+        vc = jax.random.normal(kv, (b, hkv, t, d), jnp.bfloat16)
+        # Every cursor of the span once, in a scrambled order of rows.
+        pos = first + (jnp.arange(b) * 7919 % b) * (last - first) // b
+        pos = pos.astype(jnp.int32)
+
+        def kern(q, kc, vc, pos=pos, window=window, ring=ring):
+            return cached_attention(q, kc, vc, pos, window=window,
+                                    ring=ring is not None)
+
+        dt = _timeit(
+            lambda q, kc, vc, iters: _chain(kern, q, kc, vc, iters=iters),
+            q, kc, vc, iters=iters)
+        attended = jnp.minimum(pos + c, t) if ring != "masked" else t
+        n_bytes = int(jnp.sum(jnp.broadcast_to(attended, (b,)))) * (
+            2 * hkv * d * 2)
+        us = round(dt * 1e6, 1)
+        total, each[name] = total + us, us
+        print(json.dumps({
+            "metric": f"decode_cell_{name}_us", "value": us, "unit": "us",
+            "detail": f"B={b} Hq={hq} Hkv={hkv} D={d} C={c} T={t} "
+                      f"window={window} ring={ring} mean cursor "
+                      f"{int(pos.mean())}: {us / (b * hkv):.3f} us a (slot, "
+                      f"kv head), {n_bytes / 1e6:.1f} MB attended -> "
+                      f"{n_bytes / dt / 1e9:.0f} GB/s"}), flush=True)
+    return {"metric": "decode_cells_us", "value": round(total, 1),
+            "unit": "us", "detail": json.dumps(each)}
+
+
 def bench_train_mfu(iters: int = 4, B: int = 8, S: int = 1024):
     """Tiny-Llama MFU (the r2 row; kept for continuity of the table)."""
     return _train_mfu_row(
@@ -893,6 +949,7 @@ BENCHES = {
     "decode_int8": functools.partial(bench_decode, impl="int8"),
     "decode_paged": bench_decode_paged,
     "decode_shapes": bench_decode_shapes,
+    "decode_cells": bench_decode_cells,
     "train_mfu": bench_train_mfu,
     "train_mfu_large": bench_train_mfu_large,
     "serve": bench_serve,
@@ -933,7 +990,8 @@ def main():
         # pass from minutes to an hour.
         heavy = ("serve", "serve_b8", "serve_ragged_b8", "serve_mistral",
                  "serve_int8_b8", "serve_w8_b1", "serve_continuous",
-                 "train_mfu_large", "decode_shapes", "spec_verify",
+                 "train_mfu_large", "decode_shapes", "decode_cells",
+                 "spec_verify",
                  "gemv_int8")
         names = [n for n in BENCHES
                  if not n.endswith("_tune") and n not in heavy]

@@ -11,13 +11,25 @@ one MXU matmul.  Masking and the online-softmax accumulation are fused;
 fully-masked blocks (beyond the current position) are skipped via
 scalar-prefetched ``pos``.
 
-The kernel (``sw_decode_attn_stream``) runs one grid cell per (batch, kv
-head): the whole T sweep is a ``fori_loop`` with double-buffered manual
-DMA (``make_async_copy``), so compute on block i overlaps the HBM stream
-of block i+1 and the per-cell pipeline cost is paid b*hkv times a call,
-whatever T.  (A form with one grid cell per kv block paid about 0.4 us a
-cell; it lost its pair on the chip and was deleted in PR 28: PERF.md
-section 6.)
+The kernel (``sw_decode_attn_stream``) runs one grid cell per batch row:
+the row's kv heads share the cell (all of them at decode; a group of them
+where VMEM forbids more, :func:`_cell_shape`, decided from the shapes).
+The whole T sweep is a ``fori_loop`` with double-buffered manual DMA
+(``make_async_copy``): one descriptor a kv block for ALL the cell's heads,
+their scores one matmul batched over the heads and their weighted sums
+another, so compute on block i overlaps the HBM stream of block i+1 and
+the body is the same whatever the heads, the query rows or T (a body
+unrolled over the heads ran as fast and cost every warm start seconds of
+tracing and lowering: PERF.md section 6, PRs 41 and 42).  The stream does
+not stop at a cell's end: a cell's last block computes while the NEXT
+cell's first block (its cursor and cache row are prefetched scalars) is
+on its way into the other buffer, so a pipeline's cold start is paid once
+a call and not b*hkv times.  That chain is why the grid axis is
+sequential ("arbitrary") and must stay so.  (A form with one grid cell
+per kv block paid about 0.4 us a cell; it lost its pair on the chip and
+was deleted in PR 28.  One cell a (batch row, kv head), each starting
+cold, paid about 1.1 us a cell until PR 42: 768 cells a call where 96
+slots have 8 kv heads.)
 
 Same online-softmax algebra as ops/pallas_attention.py; layouts follow
 models/generate.py: ``q [B, Hq, 1, D]``, caches ``[B, Hkv, T, D]`` — or the
@@ -54,8 +66,11 @@ def _softmax_block_update(q, k, v, k_start, pos, m_scr, l_scr, acc_scr, *,
                           k_scale=None, v_scale=None, row_off=None,
                           ring: "tuple | None" = None):
     """The one online-softmax block body the decode kernels share: score
-    the group's query rows against one [block_k, D] cache block, mask by
-    global position (and window), and fold into the m/l/acc scratches.
+    the query rows against one cache block, mask by global position (and
+    window), and fold into the m/l/acc scratches.  ``q [rows, D]`` against
+    ``k``, ``v`` ``[block_k, D]``, or every head of a grid cell at once:
+    ``q [heads, rows, D]`` against ``[heads, block_k, D]``, both matmuls
+    batched over the heads and the softmax state ``[heads, rows, ...]``.
 
     ``row_off`` ([rows, 1] int32 — rank-2, Mosaic rejects rank-1 iota;
     multi-query decode): row r's query sits at global position
@@ -63,26 +78,29 @@ def _softmax_block_update(q, k, v, k_start, pos, m_scr, l_scr, acc_scr, *,
     positions x n_rep query heads as the matmul rows, so each row masks
     by its own cursor.  ``None`` = all rows at ``pos``.
 
-    ``k_scale``/``v_scale`` ([1, block_k] f32 rows, int8 cache; see
-    :func:`decode_attention` on the scale layout): dequantization is
-    folded into the existing algebra instead of widening the operands —
-    k's scale multiplies the score COLUMNS (``(q . k_int8[c]) * s_k[c]``)
-    and v's scale folds into the softmax weights before the ``p @ v``
-    matmul, so no dequantized [block_k, D] tile is ever materialised.
+    ``k_scale``/``v_scale`` (f32 rows ``[(heads,) 1, block_k]``, int8
+    cache; see :func:`decode_attention` on the scale layout):
+    dequantization is folded into the existing algebra instead of
+    widening the operands — k's scale multiplies the score COLUMNS
+    (``(q . k_int8[c]) * s_k[c]``) and v's scale folds into the softmax
+    weights before the ``p @ v`` matmul, so no dequantized [block_k, D]
+    tile is ever materialised.
 
     ``ring = (T, top)`` (a ring longer than its window, written at ``p %
     T``): slot ``s`` holds position ``top - (top - s) % T``, ``top`` being
     the last position written; a slot no position reached yet reads a
     negative one and is masked."""
+    heads = tuple(range(q.ndim - 2))  # the batch dims of both matmuls
+    last = q.ndim - 1
     s = jax.lax.dot_general(
-        q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
+        q, k.astype(q.dtype), (((last,), (last,)), (heads, heads)),
         preferred_element_type=jnp.float32,
-    )  # [rows, block_k]
+    )  # [(heads,) rows, block_k]
     if k_scale is not None:
         s = s * (k_scale * sm_scale)
     else:
         s = s * sm_scale
-    kv_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    kv_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, last)
     q_pos = pos if row_off is None else pos + row_off  # [rows, 1]
     if ring is not None:
         t, top = ring
@@ -94,20 +112,22 @@ def _softmax_block_update(q, k, v, k_start, pos, m_scr, l_scr, acc_scr, *,
         keep = keep & (kv_pos > q_pos - window)
     s = jnp.where(keep, s, NEG_BIG)
 
-    m_prev = m_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    col = (slice(None),) * last + (slice(0, 1),)  # the state's first lane
+    m_prev = m_scr[col]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=last, keepdims=True))
     p = jnp.where(s > NEG_BIG / 2, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
-    l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
+    l_new = l_scr[col] * corr + jnp.sum(p, axis=last, keepdims=True)
     pv_dtype = q.dtype
     if v_scale is not None:
         p = p * v_scale
-    acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-        p.astype(pv_dtype), v.astype(pv_dtype), (((1,), (0,)), ((), ())),
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+        p.astype(pv_dtype), v.astype(pv_dtype),
+        (((last,), (last - 1,)), (heads, heads)),
         preferred_element_type=jnp.float32,
     )
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
 
 def _row_offsets(rows: int, n_q: int):
@@ -120,26 +140,38 @@ def _row_offsets(rows: int, n_q: int):
         jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), n_q)
 
 
-def _head_row(scales, h):
-    """Row ``h`` of a ``[Hkv, block_k]`` f32 scale block as ``[1, block_k]``
-    (a masked sublane sum: one row OF a tile is not a slice Mosaic takes
-    at a traced index, and the block is a few KiB)."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, scales.shape, 0)
-    return jnp.sum(jnp.where(rows == h, scales, 0.0), axis=0, keepdims=True)
+def _head_rows(scales, first, heads: int):
+    """Rows ``first .. first + heads - 1`` of a ``[Hkv, block_k]`` f32 scale
+    block as ``[heads, 1, block_k]``, one row under each head's scores (a
+    masked sublane sum: rows OF a tile are neither a slice Mosaic takes at
+    a traced index nor a leading dim it reshapes to, and the block is a
+    few KiB)."""
+    pick = (first + jax.lax.broadcasted_iota(jnp.int32, (heads, 1, 1), 0)
+            == jax.lax.broadcasted_iota(
+                jnp.int32, (1, scales.shape[0], 1), 1))
+    return jnp.sum(jnp.where(pick, scales[None], 0.0), axis=1, keepdims=True)
 
 
 def _decode_stream_kernel(pos_ref, layer_ref, *refs,
-                          sm_scale: float, block_k: int, hkv: int,
-                          window: "int | None", n_blocks: int,
+                          sm_scale: float, block_k: int, heads: int,
+                          n_groups: int, window: "int | None", n_blocks: int,
                           quant: bool = False, n_q: int = 1,
                           by_row: bool = False, ring: bool = False):
-    """One grid cell per (batch, kv head): the WHOLE cache sweep runs in a
-    single cell as a fori_loop over kv blocks with double-buffered manual
-    DMA (compute on block i overlaps the HBM stream of block i+1).
+    """One grid cell per (batch row, group of ``heads`` kv heads; a row has
+    ``n_groups`` of them, one where VMEM lets every head in): the WHOLE
+    cache sweep of all the cell's heads runs in a single cell as a
+    fori_loop over kv blocks with double-buffered manual DMA (compute on
+    block i overlaps the HBM stream of block i+1), one descriptor a block
+    for all the heads, one batched matmul for their scores and one for
+    their weighted sums.  The body does not grow with the heads, the query
+    rows or the blocks: no Python loop over any of them.
 
-    The cell count is b*hkv regardless of T, so the kernel's time is the
-    max of the DMA stream (~cache bytes / HBM bandwidth) and the (tiny)
-    grouped-GQA matmuls.
+    The stream runs ACROSS cells: while a cell computes its last block the
+    first block of the next cell (its cursor and cache row are prefetched
+    scalars) is already on its way into the other buffer, so only the
+    call's first cell starts cold.  Which of the two buffers a cell starts
+    in is carried from cell to cell in SMEM (``par_ref``); the grid axis
+    must stay sequential ("arbitrary").
 
     ``k_hbm``/``v_hbm`` are the whole stacked caches ``[L, B, Hkv, T, D]``
     left in HBM; ``layer_ref`` (second prefetched scalar) picks the layer
@@ -148,8 +180,8 @@ def _decode_stream_kernel(pos_ref, layer_ref, *refs,
     ``quant``: two extra HBM inputs (per-token f32 scales ``[L, B, Hkv,
     T]``) and two extra scratch buffers ride the same double-buffered
     pipeline; the int8 cache blocks halve the DMA bytes.  A cell fetches
-    its batch row's ``[Hkv, block_k]`` scale block whole (one head's row
-    of it is a slice below the (8, 128) tile) and keeps its own row.
+    its batch row's ``[Hkv, block_k]`` scale block whole (a few heads' rows
+    of it are a slice below the (8, 128) tile) and keeps its own rows.
 
     ``by_row``: a third prefetched scalar array names the CACHE row each
     batch row reads (:func:`slot_attention`: the pieces of one prompt, all
@@ -163,83 +195,108 @@ def _decode_stream_kernel(pos_ref, layer_ref, *refs,
         row_ref, *refs = refs
     q_ref, k_hbm, v_hbm, *refs = refs
     if quant:
-        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems, m_scr,
-         l_scr, acc_scr) = refs
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems, par_ref,
+         m_scr, l_scr, acc_scr) = refs
     else:
         ks_hbm = vs_hbm = ks_buf = vs_buf = None
-        o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = refs
-    bh = pl.program_id(0)
-    b = bh // hkv
-    h = jax.lax.rem(bh, hkv)
+        o_ref, k_buf, v_buf, sems, par_ref, m_scr, l_scr, acc_scr = refs
+    cell = pl.program_id(0)
     layer = layer_ref[0]
-    pos = pos_ref[b]
-    hi = (pos + n_q - 1) // block_k  # last live block (queries span n_q)
-    if ring:
-        hi = jnp.minimum(hi, n_blocks - 1)
-    if by_row:
-        # A prompt's last piece is padded: its pad queries may lie past T.
-        hi = jnp.minimum(hi, n_blocks - 1)
-        b = row_ref[b]
-    if window is None or ring:
-        lo = jnp.int32(0)
-    else:
-        lo = jnp.maximum(pos - window + 1, 0) // block_k
 
-    def copies(i, slot):
+    def span(c):
+        """Cell ``c``'s cache row, first head, cursor and its first and
+        last live kv block.  Whatever the cursor, ``0 <= lo <= hi <
+        n_blocks``: every cell has a block to wait for, and the cell
+        before it one to fetch (``lax.div`` is a fraction of the scalar
+        code ``//`` lowers to, and rounds a negative cursor to block 0)."""
+        b = c if n_groups == 1 else jax.lax.div(c, n_groups)
+        h0 = 0 if n_groups == 1 else jax.lax.rem(c, n_groups) * heads
+        pos = pos_ref[b]
+        # The last live block: the queries span n_q positions.  (A ring is
+        # streamed whole; a prompt's last piece is padded, and its pad
+        # queries may lie past T.)
+        hi = jnp.minimum(jax.lax.div(pos + n_q - 1, block_k), n_blocks - 1)
+        if window is None or ring:
+            lo = 0
+        else:
+            lo = jnp.minimum(
+                jax.lax.div(jnp.maximum(pos - window + 1, 0), block_k), hi)
+        return (row_ref[b] if by_row else b), h0, pos, lo, hi
+
+    def copies(row, h0, i, slot):
         blk = pl.ds(i * block_k, block_k)
+        hs = pl.ds(h0, heads)
         cps = [
             pltpu.make_async_copy(
-                k_hbm.at[layer, b, h, blk], k_buf.at[slot], sems.at[slot, 0]),
+                k_hbm.at[layer, row, hs, blk], k_buf.at[slot],
+                sems.at[slot, 0]),
             pltpu.make_async_copy(
-                v_hbm.at[layer, b, h, blk], v_buf.at[slot], sems.at[slot, 1]),
+                v_hbm.at[layer, row, hs, blk], v_buf.at[slot],
+                sems.at[slot, 1]),
         ]
         if quant:
             cps.append(pltpu.make_async_copy(
-                ks_hbm.at[layer, b, :, blk], ks_buf.at[slot],
+                ks_hbm.at[layer, row, :, blk], ks_buf.at[slot],
                 sems.at[slot, 2]))
             cps.append(pltpu.make_async_copy(
-                vs_hbm.at[layer, b, :, blk], vs_buf.at[slot],
+                vs_hbm.at[layer, row, :, blk], vs_buf.at[slot],
                 sems.at[slot, 3]))
         return cps
 
-    m_scr[:] = jnp.full_like(m_scr, NEG_BIG)
-    l_scr[:] = jnp.zeros_like(l_scr)
-    acc_scr[:] = jnp.zeros_like(acc_scr)
-    for cp in copies(lo, 0):
-        cp.start()
-    q = q_ref[0]  # [rows, D] — the group's query heads (padded to tile)
+    row, h0, pos, lo, hi = span(cell)
+    # What follows this cell's last block in the stream: the next cell's
+    # first (the call's last cell names itself and fetches nothing).
+    last_cell = pl.num_programs(0) - 1
+    nrow, nh0, _, nlo, _ = span(jnp.minimum(cell + 1, last_cell))
+
+    @pl.when(cell == 0)
+    def _cold():
+        par_ref[0] = 0
+        for cp in copies(row, h0, lo, 0):
+            cp.start()
+
+    first = par_ref[0]  # the buffer this cell's first block is (put) in
+    m_scr[...] = jnp.full_like(m_scr, NEG_BIG)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    q = q_ref[0]  # [heads, rows, D]: each group's query heads (padded to tile)
 
     # STATIC trip count with liveness guards (not a dynamic-bound loop —
     # simpler Mosaic lowering): dead iterations run a few scalar ops; DMA,
     # waits, and compute all sit under pl.when, so only live blocks move
     # bytes — a windowed decode still streams ~window bytes however big T.
     def body(i, _):
-        live = (i >= lo) & (i <= hi)
-
-        @pl.when(live)
+        @pl.when((i >= lo) & (i <= hi))
         def _live():
-            slot = jax.lax.rem(i - lo, 2)
+            slot = jax.lax.rem(first + i - lo, 2)
+            more = i < hi  # of this cell; else the stream moves on
 
-            @pl.when(i + 1 <= hi)
+            @pl.when(more | (cell < last_cell))
             def _prefetch():
-                ns = jax.lax.rem(i + 1 - lo, 2)
-                for cp in copies(i + 1, ns):
+                for cp in copies(
+                        jnp.where(more, row, nrow),
+                        h0 if n_groups == 1 else jnp.where(more, h0, nh0),
+                        jnp.where(more, i + 1, nlo), 1 - slot):
                     cp.start()
 
-            for cp in copies(i, slot):
+            for cp in copies(row, h0, i, slot):
                 cp.wait()
             _softmax_block_update(
                 q, k_buf[slot], v_buf[slot], i * block_k, pos, m_scr, l_scr,
                 acc_scr, sm_scale=sm_scale, window=window,
-                k_scale=None if not quant else _head_row(ks_buf[slot], h),
-                v_scale=None if not quant else _head_row(vs_buf[slot], h),
-                row_off=_row_offsets(q.shape[0], n_q),
+                k_scale=(None if not quant
+                         else _head_rows(ks_buf[slot], h0, heads)),
+                v_scale=(None if not quant
+                         else _head_rows(vs_buf[slot], h0, heads)),
+                row_off=_row_offsets(q.shape[1], n_q),
                 ring=(n_blocks * block_k, pos + n_q - 1) if ring else None)
 
         return 0
 
     jax.lax.fori_loop(0, n_blocks, body, 0)
-    o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
+    par_ref[0] = jax.lax.rem(first + hi - lo + 1, 2)
+    o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :, :1], 1e-30)).astype(
+        o_ref.dtype)
 
 
 def _pick_block(t: int, block_k: int, quant: bool) -> "int | None":
@@ -256,6 +313,31 @@ def _pick_block(t: int, block_k: int, quant: bool) -> "int | None":
     return None
 
 
+# What a grid cell of :func:`decode_attention` may hold: the query rows of
+# all its heads (one kv block's scores are [heads, rows, block_k] float32,
+# 1 MiB at 512 x 512) and the bytes of one of its four k/v buffers.
+_CELL_ROWS = 512
+_CELL_BLOCK_BYTES = 1 << 20
+
+
+def _cell_shape(hkv: int, rows_q: int, t: int, pos_bytes: int, block_k: int,
+                quant: bool) -> "tuple[int, int]":
+    """The kv heads a grid cell takes and its kv block, from the shapes
+    alone: as many of the row's heads as keep the cell's query rows within
+    ``_CELL_ROWS`` (all of them at decode; one where a prompt's piece
+    brings 512 rows a head) and with them the largest block
+    (:func:`_pick_block`) of at most ``_CELL_BLOCK_BYTES`` (``pos_bytes``
+    one head's position of k), fewer heads where even the smallest block
+    of all of them is larger (a length that is one block)."""
+    for heads in range(hkv, 0, -1):
+        if hkv % heads or (heads > 1 and heads * rows_q > _CELL_ROWS):
+            continue
+        block = _pick_block(
+            t, min(block_k, _CELL_BLOCK_BYTES // (heads * pos_bytes)), quant)
+        if heads == 1 or heads * block * pos_bytes <= _CELL_BLOCK_BYTES:
+            return heads, block
+
+
 def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
                      block_k: int = 512, interpret=None, window=None,
                      k_scale=None, v_scale=None, rows=None,
@@ -267,8 +349,8 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
     q: [B, Hq, C, D] — C consecutive query positions per row (C=1 is
     plain single-token decode; C>1 is the speculative chunk verify:
     models/speculative.py packs C positions x n_rep grouped heads as the
-    rows of the SAME per-(batch, kv head) matmul, so the cache still
-    streams exactly once, narrow and int8-capable).  k_cache/v_cache:
+    rows of the SAME per-kv-head matmul, so the cache still streams
+    exactly once, narrow and int8-capable).  k_cache/v_cache:
     the scan-stacked caches ``[L, B, Hkv, T, D]`` with ``layer`` a scalar
     int (traced inside the layer scan: it reaches the kernel as a
     prefetched scalar and indexes HBM, so no layer is sliced out and the
@@ -291,10 +373,12 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
     given, matching the caches' int8 dtype.  They stay in that layout: a
     cell reads its batch row's ``[Hkv, block_k]`` block (Mosaic blocks
     and DMAs the last two dims in (8, 128) tiles unless a block spans the
-    whole dim, so one head's row of it is refused by the chip's compiler)
-    and keeps its head's row.
+    whole dim, so a few heads' rows of it are refused by the chip's
+    compiler) and keeps its heads' rows.
 
-    The kv block is chosen to divide T (:func:`_pick_block`), so nothing
+    A grid cell is a batch row's kv heads, all of them or a group
+    (:func:`_cell_shape`: what the shapes let into VMEM), and the kv block
+    is chosen to divide T (:func:`_pick_block`), so nothing
     is padded.  A length no block divides (not a multiple of 128; for
     bf16 up to 4096, of 8) costs what every length cost before: that
     layer is sliced out and padded, a copy of it a call.  Allocate
@@ -349,7 +433,6 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
     qg = q.reshape(b, hkv, n_rows, d)
     if rows_q != n_rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_q - n_rows), (0, 0)))
-    qf = qg.reshape(b * hkv, rows_q, d)
 
     if _pick_block(t, block_k, quant) is None:
         def one_padded(a):
@@ -361,41 +444,50 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
         k_cache, v_cache = one_padded(k_cache), one_padded(v_cache)
         scales = [one_padded(s) for s in scales]
         layer, t = 0, k_cache.shape[3]
-    block_k = _pick_block(t, block_k, quant)
+    heads, block_k = _cell_shape(hkv, rows_q, t, d * k_cache.dtype.itemsize,
+                                 block_k, quant)
+    n_groups = hkv // heads
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     by_row = () if rows is None else (jnp.asarray(rows, jnp.int32),)
-    q_spec = pl.BlockSpec((1, rows_q, d), lambda bh, *_: (bh, 0, 0))
+    q_spec = pl.BlockSpec(
+        (1, heads, rows_q, d),
+        lambda c, *_: (jax.lax.div(c, n_groups), jax.lax.rem(c, n_groups),
+                       0, 0))
     any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     quant_scratch = [pltpu.VMEM((2, hkv, block_k), jnp.float32)] * (
         2 * quant)
     out = pl.pallas_call(
         functools.partial(
             _decode_stream_kernel, sm_scale=sm_scale, block_k=block_k,
-            hkv=hkv, window=None if window is None else int(window),
+            heads=heads, n_groups=n_groups,
+            window=None if window is None else int(window),
             n_blocks=t // block_k, quant=quant, n_q=n_q,
             by_row=bool(by_row), ring=ring),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2 + len(by_row),
-            grid=(b * hkv,),
+            grid=(b * n_groups,),
             in_specs=[q_spec] + [any_spec] * (2 + 2 * quant),
             out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((2, block_k, d), k_cache.dtype),
-                pltpu.VMEM((2, block_k, d), v_cache.dtype),
+                pltpu.VMEM((2, heads, block_k, d), k_cache.dtype),
+                pltpu.VMEM((2, heads, block_k, d), v_cache.dtype),
             ] + quant_scratch + [
                 pltpu.SemaphoreType.DMA((2, 4 if quant else 2)),
-                pltpu.VMEM((rows_q, 128), jnp.float32),
-                pltpu.VMEM((rows_q, 128), jnp.float32),
-                pltpu.VMEM((rows_q, d), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((heads, rows_q, 128), jnp.float32),
+                pltpu.VMEM((heads, rows_q, 128), jnp.float32),
+                pltpu.VMEM((heads, rows_q, d), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b * hkv, rows_q, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows_q, d), q.dtype),
+        # Each cell starts the next one's stream: one core, in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=kernel_name,
-    )(pos_arr, layer_arr, *by_row, qf, k_cache, v_cache, *scales)
-    return out.reshape(b, hkv, rows_q, d)[:, :, :n_rows, :].reshape(
-        b, hq, n_q, d)
+    )(pos_arr, layer_arr, *by_row, qg, k_cache, v_cache, *scales)
+    return out[:, :, :n_rows, :].reshape(b, hq, n_q, d)
 
 
 def decode_attention_lax(q, k_cache, v_cache, pos, *, layer=None,

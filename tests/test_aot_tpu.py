@@ -112,9 +112,10 @@ def _ring_step(*, bwd=False, causal=True, t=2048):
 
 
 def _decode(hkv, *, c=1, int8=False, window=None, b=8, t=2048, layers=None,
-            hq=HQ, name="sw_decode_attn_stream", d=D):
+            hq=HQ, name="sw_decode_attn_stream", d=D, ring=False):
     """``layers``: the scan-stacked cache ``[layers, b, hkv, t, d]`` read
-    through a traced layer index, as the serving chunk reads it."""
+    through a traced layer index, as the serving chunk reads it.  ``ring``
+    (with ``window``): a ring longer than its window, read under masks."""
     from starway_tpu.ops.pallas_decode import decode_attention
 
     lead = () if layers is None else (layers,)
@@ -131,9 +132,23 @@ def _decode(hkv, *, c=1, int8=False, window=None, b=8, t=2048, layers=None,
         ks, vs = scales or (None, None)
         return decode_attention(q, k, v, pos, layer=layer, interpret=False,
                                 window=window, k_scale=ks, v_scale=vs,
-                                kernel_name=name)
+                                kernel_name=name, ring=ring)
 
     return fn, tuple(args)
+
+
+def _ingest(width, *, pieces=1, slots=24, t=2048, layers=16):
+    """``sw_ingest_attn``: a prompt's piece of ``width`` queries on its
+    request's row of mistral7b.chat_closed's stacked cache (512 rows a kv
+    head in tiles of 128 queries, the row named by an index)."""
+    from starway_tpu.ops.pallas_decode import slot_attention
+
+    cache = _s((layers, slots, HKV, t, D), BF16)
+    at = _s((pieces,), I32)
+    return (lambda q, k, v, pos, rows, layer: slot_attention(
+        q, k, v, pos, rows, layer=layer, interpret=False)), (
+            _s((pieces, HQ, width, D), BF16), cache, cache, at, at,
+            _s((), I32))
 
 
 def _kv_write(b, hkv, t, layers, d=D):
@@ -258,6 +273,20 @@ KERNELS = {
     "decode_bf16_padded": lambda: _decode(HKV, t=4104, layers=2),
     "decode_int8_padded": lambda: _decode(HKV, int8=True, t=2000, layers=2),
     "decode_bf16_mha": lambda: _decode(MHA),
+    # k-exaone.think_closed: 96 slots verify two positions a step, 64 query
+    # heads over 8 kv heads, on the window layers' masked rings of 256 =
+    # window 128 + slack and on the full layers' rows of 4,096.  (With
+    # decode_bf16_chat_closed, decode_longdoc_full / _ring and
+    # decode_answer_full: the six shapes of scripts/kernel_bench.py's
+    # ``decode_cells``.)
+    "decode_think_ring": lambda: _decode(
+        HKV, c=2, b=96, t=256, layers=6, hq=64, window=128, ring=True,
+        name="sw_decode_attn_ring"),
+    "decode_think_full": lambda: _decode(HKV, c=2, b=96, t=4096, layers=2,
+                                         hq=64),
+    # mistral7b's mixed chunk: a piece at each width the server ingests at.
+    "ingest_attn_128": lambda: _ingest(128),
+    "ingest_attn_256": lambda: _ingest(256),
     # Refused before PR 21: the [B*Hkv, T] scale operand was sliced one
     # row at a time, below the (8, 128) tile.
     "decode_int8": lambda: _decode(HKV, int8=True),
@@ -325,6 +354,47 @@ def test_kernel_compiles_for_v5e(topo, name):
     compiled = _compile(fn, *args,
                         sharding=SingleDeviceSharding(topo.devices[0]))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _kernel_module_text(fn, args):
+    """The Mosaic module of the one Pallas kernel ``fn`` calls, printed
+    without debug locations (lowered for the TPU; nothing is compiled)."""
+    import base64
+    import re
+
+    from jax._src import tpu_custom_call  # noqa: F401  (registers dialects)
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    (body,) = re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)', text)
+    ctx = ir.Context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        return ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+            enable_debug_info=False)
+
+
+@pytest.mark.parametrize("case", ["decode", "decode_c2_masked_ring",
+                                  "decode_int8"])
+def test_decode_kernel_body_does_not_grow_with_the_heads(case):
+    """What guards ``setup_s`` on the CPU: a warm start still traces and
+    lowers the kernel once a call site of every program, so its body must
+    not be unrolled over what a cell holds.  Eight kv heads a cell print a
+    module no more than 1.3 times that of two (PR 41's unrolled body was
+    refused for the seconds it cost every warm start: PERF.md section 6),
+    and sixteen blocks a row the same module as four."""
+    kw = {"decode": {}, "decode_int8": {"int8": True},
+          "decode_c2_masked_ring": dict(c=2, window=128, ring=True, t=256)
+          }[case]
+    size = lambda hkv, **over: len(_kernel_module_text(
+        *_decode(hkv, b=24, layers=4, hq=4 * hkv, **{**kw, **over})))
+    two, eight = size(2), size(8)
+    assert eight <= 1.3 * two, (two, eight)
+    if "t" not in kw:
+        assert abs(size(8, t=8192) - eight) <= 0.02 * eight
 
 
 # ----------------------------------------------------------- whole programs

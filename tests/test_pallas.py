@@ -329,6 +329,127 @@ def test_decode_attention_reads_stacked_cache_by_layer(int8, per_row, window,
                                   np.asarray(one, np.float32))
 
 
+# The grid of the decode kernel (a batch row's kv heads share a cell, and
+# a cell starts the NEXT cell's first block): name -> (Hq, Hkv, D, C, T,
+# int8, kind), ``kind`` one of None (plain rows), ("window", w), ("whole",)
+# (a ring of exactly one window: every cursor clamped to T - 1),
+# ("masked", w) (a ring longer than its window, absolute cursors) or
+# ("by_row", rows a head) (a prompt's piece on its request's slot).
+_DECODE_CELLS = {
+    "hkv1_c1": (4, 1, 128, 1, 384, False, None),
+    "hkv2_d256_c1": (16, 2, 256, 1, 384, False, None),
+    "hkv2_d256_c2_window": (16, 2, 256, 2, 384, False, ("window", 150)),
+    "hkv4_rep7_c1": (28, 4, 128, 1, 384, False, None),
+    "hkv4_rep7_whole_ring": (28, 4, 128, 1, 256, False, ("whole",)),
+    "hkv8_c1": (32, 8, 128, 1, 384, False, None),
+    "hkv8_c2": (64, 8, 128, 2, 384, False, None),
+    "hkv8_c2_masked_ring": (64, 8, 128, 2, 256, False, ("masked", 128)),
+    "hkv8_c4_window": (32, 8, 128, 4, 512, False, ("window", 200)),
+    "hkv8_c1_one_block_of_200": (32, 8, 128, 1, 200, False, None),
+    "hkv32_c1": (32, 32, 128, 1, 384, False, None),
+    "hkv32_c4_window": (32, 32, 128, 4, 384, False, ("window", 130)),
+    "int8_hkv1_c1": (4, 1, 128, 1, 384, True, None),
+    "int8_hkv2_d256_c2": (16, 2, 256, 2, 384, True, None),
+    "int8_hkv4_c4_window": (16, 4, 128, 4, 384, True, ("window", 150)),
+    "int8_hkv8_c1": (32, 8, 128, 1, 384, True, None),
+    "int8_hkv8_c2_masked_ring": (64, 8, 128, 2, 256, True, ("masked", 128)),
+    "int8_hkv8_whole_ring": (32, 8, 128, 1, 256, True, ("whole",)),
+    "int8_hkv32_c1": (32, 32, 128, 1, 384, True, None),
+    # 128 rows a head: four of the eight heads a cell, two cells a tile.
+    "by_row_128_rows_hkv8": (8, 8, 128, 128, 512, False, ("by_row", 128)),
+    # 512 rows a head (Mistral's 4 x 128): one head a cell.
+    "by_row_512_rows_hkv2": (8, 2, 128, 128, 512, False, ("by_row", 512)),
+    "by_row_512_rows_hkv2_int8": (8, 2, 128, 128, 512, True,
+                                  ("by_row", 512)),
+    "by_row_128_rows_hkv8_int8": (8, 8, 128, 128, 512, True,
+                                  ("by_row", 128)),
+}
+
+
+@pytest.mark.parametrize("name", list(_DECODE_CELLS))
+def test_decode_cells_match_lax(name):
+    """The kernel's grid against the lax twins, in interpret mode, over
+    the head counts, head sizes, query counts, cache kinds and cache
+    dtypes that reach it.  Every batch is ragged: its FIRST row is the
+    longest and its LAST the shortest (a cell fetches the first block of
+    the cell after it: it must never attend what was fetched for another
+    cell's row, head or window), with a cursor of 0, one either side of a
+    block edge and one that reaches T - 1.  Blocks of 128, so that rows
+    span one to four of them; the caches are stacked and the layer
+    traced."""
+    from starway_tpu.ops.pallas_decode import (
+        _cell_shape, decode_attention, decode_attention_lax, slot_attention,
+        slot_attention_lax)
+    from starway_tpu.ops.quantize import quantize_kv
+
+    hq, hkv, d, c, t, int8, kind = _DECODE_CELLS[name]
+    kind = kind or (None,)
+    L, layer = 2, jnp.int32(1)
+    by_row = kind[0] == "by_row"
+    n_rows = 5 if by_row else 4          # cache rows
+    b = 3 if by_row else n_rows          # batch rows
+    q, k, v = _rand(len(name), (b, hq, c, d), (L, n_rows, hkv, t, d),
+                    (L, n_rows, hkv, t, d), dtype=jnp.bfloat16)
+    kw = {}
+    if int8:
+        (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+        kw.update(k_scale=ks, v_scale=vs)
+    if by_row:
+        # Three pieces of 128 queries: the longest first (it reaches the
+        # cache's end), the shortest last, on rows that are not theirs.
+        assert hq // hkv * c == kind[1]
+        pos = jnp.asarray([t - c, 128, 0], jnp.int32)
+        rows = jnp.asarray([4, 0, 2], jnp.int32)
+        assert _cell_shape(hkv, kind[1], t, d * k.dtype.itemsize, 512,
+                           int8)[0] == 512 // kind[1]
+        got = jax.jit(lambda li: slot_attention(
+            q, k, v, pos, rows, layer=li, interpret=True, **kw))(layer)
+        want = slot_attention_lax(q, k, v, pos, rows, layer=layer, **kw)
+    else:
+        window = kind[1] if kind[0] in ("window", "masked") else None
+        masked = kind[0] == "masked"
+        top = (5 * t if masked else t) - c
+        pos = jnp.asarray([top, 127, 128 - c + 1, 0], jnp.int32)
+        if kind[0] == "whole":   # what cached_attention hands the kernel
+            pos = jnp.minimum(jnp.asarray([9 * t, t + 3, 127, 0]), t - 1)
+        got = jax.jit(lambda li: decode_attention(
+            q, k, v, pos, layer=li, window=window, ring=masked, block_k=128,
+            interpret=True, **kw))(layer)
+        want = decode_attention_lax(q, k, v, pos, layer=layer, window=window,
+                                    ring=masked, **kw)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("hkv,rows_q,t,pos_bytes,quant,want", [
+    (8, 8, 2048, 256, False, (8, 512)),      # mistral7b decode
+    (8, 16, 256, 256, False, (8, 256)),      # k-exaone's ring, two rows
+    (4, 8, 16384, 256, False, (4, 512)),     # smallthinker-21b's full rows
+    (2, 8, 4096, 512, False, (2, 512)),      # qwen3-next, heads of 256
+    (8, 512, 2048, 256, False, (1, 512)),    # mistral7b's piece: 512 rows
+    (8, 128, 2048, 256, False, (4, 512)),
+    (32, 8, 2048, 256, False, (32, 128)),    # MHA: the block gives way
+    (32, 8, 2048, 128, True, (32, 256)),
+    (8, 8, 4088, 256, False, (1, 4088)),     # one block of the whole row
+    (8, 8, 1000, 256, False, (4, 1000)),
+    (1, 1024, 512, 256, False, (1, 512)),
+])
+def test_cell_shape_table(hkv, rows_q, t, pos_bytes, quant, want):
+    """Heads a cell and kv block from the shapes alone: every head of the
+    row while their query rows stay within 512 and one of their blocks
+    within 1 MiB, the block giving way before the heads do."""
+    from starway_tpu.ops.pallas_decode import (_CELL_BLOCK_BYTES, _CELL_ROWS,
+                                               _cell_shape)
+
+    heads, block = _cell_shape(hkv, rows_q, t, pos_bytes, 512, quant)
+    assert (heads, block) == want
+    assert hkv % heads == 0 and t % block == 0
+    assert heads == 1 or (heads * rows_q <= _CELL_ROWS
+                          and heads * block * pos_bytes <= _CELL_BLOCK_BYTES)
+
+
 @pytest.mark.parametrize("n_new", [1, 4], ids=["c1", "c4"])
 @pytest.mark.parametrize("leaf", ["bf16", "int8", "scales"])
 def test_kv_write_in_place_matches_dynamic_update_slice(leaf, n_new):
