@@ -453,6 +453,56 @@ def bench_decode_cells(iters: int = 64, cells=None):
             "unit": "us", "detail": json.dumps(each)}
 
 
+# The gated delta rule's prefill at the two linear-state cells' shapes:
+# (value heads, key heads, head width, positions, one decay a head?).
+KDA_CHUNK_CELLS = {
+    **{f"reason_{s}": (32, 32, 128, s, False) for s in (512, 1024, 2048)},
+    **{f"answer_{s}": (32, 16, 128, s, True) for s in (512, 1024, 2048)},
+}
+
+
+def bench_kda_chunk(iters: int = 8, cells=None, sides=("kernel", "lax")):
+    """The whole ``ops.kda_chunk`` operation, one layer of one admission,
+    at the served cells' shapes (``KDA_CHUNK_CELLS``): the fused kernel
+    ``sw_kda_chunk`` against its lax twin, us a call and the GB/s of its
+    operands (q, k, v, the log-decays and beta in, the read-outs and the
+    state out), a row a shape and side."""
+    from starway_tpu.ops.pallas_kda import kda_chunk_kernel, kda_chunk_lax
+
+    run = {"kernel": kda_chunk_kernel, "lax": kda_chunk_lax}
+    each = {}
+    for name in cells or KDA_CHUNK_CELLS:
+        h, hk, d, s, by_head = KDA_CHUNK_CELLS[name]
+        ks = jax.random.split(jax.random.PRNGKey(0), 5)
+        q, k = (jax.random.normal(x, (1, s, hk, d), jnp.float32) * d**-0.5
+                for x in ks[:2])
+        v = jax.random.normal(ks[2], (1, s, h, d), jnp.float32)
+        g = -0.1 * jnp.abs(jax.random.normal(
+            ks[3], (1, s, h) if by_head else (1, s, h, d), jnp.float32))
+        beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, s, h)))
+        n_bytes = 4 * (q.size + k.size + 2 * v.size + g.size + beta.size
+                       + h * d * d)
+        for side in sides:
+            def op(q, k, v, g, beta, fn=run[side]):
+                o, state = fn(q, k, v, g, beta)
+                return o.at[0, 0, 0, 0].add(state[0, 0, 0, 0])   # both live
+
+            dt = _timeit(lambda *a, iters: _chain(op, *a, iters=iters),
+                         q, k, v, g, beta, iters=iters, target_s=0.2)
+            us = round(dt * 1e6, 1)
+            each[f"{name}_{side}"] = us
+            print(json.dumps({
+                "metric": f"kda_chunk_{name}_{side}_us", "value": us,
+                "unit": "us",
+                "detail": f"H={h} Hk={hk} d={d} S={s} "
+                          f"decay a {'head' if by_head else 'channel'}: "
+                          f"{us / (h * s // 64):.3f} us a (head, chunk), "
+                          f"{n_bytes / 1e6:.1f} MB of operands -> "
+                          f"{n_bytes / dt / 1e9:.1f} GB/s"}), flush=True)
+    return {"metric": "kda_chunk_us", "value": round(sum(each.values()), 1),
+            "unit": "us", "detail": json.dumps(each)}
+
+
 def bench_train_mfu(iters: int = 4, B: int = 8, S: int = 1024):
     """Tiny-Llama MFU (the r2 row; kept for continuity of the table)."""
     return _train_mfu_row(
@@ -950,6 +1000,7 @@ BENCHES = {
     "decode_paged": bench_decode_paged,
     "decode_shapes": bench_decode_shapes,
     "decode_cells": bench_decode_cells,
+    "kda_chunk": bench_kda_chunk,
     "train_mfu": bench_train_mfu,
     "train_mfu_large": bench_train_mfu_large,
     "serve": bench_serve,
@@ -991,6 +1042,7 @@ def main():
         heavy = ("serve", "serve_b8", "serve_ragged_b8", "serve_mistral",
                  "serve_int8_b8", "serve_w8_b1", "serve_continuous",
                  "train_mfu_large", "decode_shapes", "decode_cells",
+                 "kda_chunk",
                  "spec_verify",
                  "gemv_int8")
         names = [n for n in BENCHES

@@ -127,11 +127,12 @@ def _gates(x, z, kp, cfg):
     return g, beta, gate
 
 
-def _qkv(conved, cfg):
+def _qkv(conved, cfg, repeat: bool = True):
     """The convolution's outputs [B, S, conv_width] -> (q, k, v) [B, S, H,
     d] float32: SiLU, then q and k of unit length a head, q scaled, and
     where the key heads are fewer each repeated to the value heads that
-    read it."""
+    read it (not with ``repeat`` off: ``ops.kda_chunk`` reads a key head
+    for each of its value heads itself)."""
     la = cfg.linear
     y = jax.nn.silu(conved.astype(jnp.float32))
     kw = la.key_width
@@ -143,7 +144,7 @@ def _qkv(conved, cfg):
 
     q, k = unit(q) * la.head_dim**-0.5, unit(k)
     rep = la.n_heads // la.key_heads
-    if rep > 1:
+    if repeat and rep > 1:
         q, k = jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2)
     return q, k, v
 
@@ -175,16 +176,14 @@ def kda_prefill(x, kp, cfg, lengths=None):
     # Row b's last taps - 1 real inputs: padded[b, lengths[b] ..].
     tails = jax.vmap(lambda row, n: lax.dynamic_slice_in_dim(
         row, n, taps - 1, 0))(padded, lengths)
-    q, k, v = _qkv(conved, cfg)
+    q, k, v = _qkv(conved, cfg, repeat=False)
     g, beta, gate = _gates(x, z, kp, cfg)
     g = jnp.where(real[..., None, None] if g.ndim == 4 else real[..., None],
                   g, 0.0)
     beta = jnp.where(real[..., None], beta, 0.0)
-    heads = lambda a: jnp.moveaxis(a, 2, 1)                     # [B, H, S, ..]
     with jax.named_scope("sw_kda_chunk"):
-        o, state = kda_chunk(heads(q), heads(k), heads(v), heads(g),
-                             heads(beta))
-    out = _gated_out(jnp.moveaxis(o, 1, 2), gate, kp, cfg)
+        o, state = kda_chunk(q, k, v, g, beta)                  # [B, S, H, d]
+    out = _gated_out(o, gate, kp, cfg)
     return jnp.moveaxis(out, 2, 1), {"kda_state": state, "kda_conv": tails}
 
 

@@ -19,20 +19,59 @@ by
   the state before it, the pseudo-values ``u_i = beta_i (v_i - S'_i^T
   k_i)`` solve ``(I + A) U = beta V - beta K+ S0`` with ``A[i, j] = beta_i
   sum_d k_i k_j exp(G_i - G_j)`` for ``j < i`` (a unit lower-triangular
-  system, solved in blocks of rows: :func:`_solve_unit_lower`), the outputs are ``O = Q+
-  S0 + P U`` with ``P[i, j] = sum_d q_i k_j exp(G_i - G_j)`` for ``j <=
-  i``, and the chunk leaves ``S = diag(exp(G_C)) S0 + (K exp(G_C - G))^T
-  U`` behind (``K+``, ``Q+``: rows times ``exp(G)``).  Every exponent is a
-  decay BETWEEN two positions of the chunk, never above 0: nothing
-  overflows however strong the decay (the factored form ``exp(G_i)
-  exp(-G_j)`` does; :func:`_pairwise` says how the decays are taken; with
-  ONE decay a head they are a ``[C, C]`` matrix a head and ``A``, ``P``
-  plain matmuls times it: :func:`_pairwise_head`).  What is the same for
-  every chunk (``A``, ``P``, the
-  solve against ``beta V`` and ``beta K+``) is computed for all chunks in
-  plain lax; the part that carries ``S`` from chunk to chunk, three
-  matmuls and an update a chunk, is the kernel ``sw_kda_chunk``
-  (:func:`kda_chunk_carry`; twin :func:`kda_chunk_carry_lax`).  A position
+  system), the outputs are ``O = Q+ S0 + P U`` with ``P[i, j] = sum_d q_i
+  k_j exp(G_i - G_j)`` for ``j <= i``, and the chunk leaves ``S =
+  diag(exp(G_C)) S0 + (K exp(G_C - G))^T U`` behind (``K+``, ``Q+``: rows
+  times ``exp(G)``).  Every exponent is a decay BETWEEN two positions of
+  the chunk, never above 0: nothing overflows however strong the decay
+  (the factored form ``exp(G_i) exp(-G_j)`` does).
+
+  ONE kernel, ``sw_kda_chunk`` (:func:`kda_chunk_kernel`), walks the
+  chunks: a grid cell is a (row, head, chunk), the chunks in order, and
+  reads the chunk's q, k, v, log-decays and beta where the projections
+  left them (a head a 128-lane column block of ``[B, S, H * d]``; a key
+  head is read by each value head over it) and writes the chunk's outputs
+  the same way.  ``K+``, ``Q+``, ``K exp(G_C - G)``, the pseudo-values and
+  the state (VMEM scratch, written out after the last chunk) never exist
+  in HBM.  float32, every matmul at ``HIGHEST``.  What a cell is bound by
+  is the NUMBER of its matmuls (a float32 product at ``HIGHEST`` is six
+  passes that each latch a whole weight tile: 0.13-0.19 us however small
+  its operands, where XLA batches the same product over every cell of a
+  call for a fraction of that), so the work is split by what was measured
+  (PERF.md section 6, PR 43):
+
+  - a decay a CHANNEL builds everything in the cell.  ``G`` is a doubling
+    scan of sublane rolls.  ``A`` and ``P`` come level by level: the chunk
+    halves ``log2 C`` times; at a level every pair of sibling blocks
+    splits the decay between its second block's row i and its first
+    block's row j at the pair's middle, ``exp(G_i - G_mid) exp(G_mid -
+    G_j)``, both factors decays (sums of log-decays inside a block of
+    four, differences of ``G`` with the middle's above it), so the level
+    is ONE matmul over d_k of rows scaled by their own factor (``K_l [K_l
+    | Q_l]^T``: ``A^T`` and ``P^T`` side by side), masked to the sibling
+    pairs.  The levels' masks tile ``j < i`` exactly once; the diagonal of
+    ``P`` is ``q_i k_i``; no ``[SUB, SUB, d_k]`` block of exponentials is
+    built and no factor is above 1.  The solve (:func:`_solve_in_cell`)
+    goes in blocks of ``SUB`` rows: a block loses what the rows above it
+    give in one matmul and solves itself by forward substitution on the
+    VPU (fifteen multiply-subtracts of a row: exact, where the twin's
+    nilpotent product cancels); as nine chained ``[C, C]`` matmuls the
+    same solve was 1.4 of a cell's 3.0 us;
+  - a decay a HEAD takes ``A``, ``P`` and ``(I + A)^{-1}`` from lax
+    (:func:`_pairwise_head` and :func:`_solve_unit_lower` against the
+    identity: two matmuls over the key heads and a solve batched over
+    every chunk at once) as two ``[C, C]`` operands a cell: built in the
+    cell they made it 2.6 times slower than the lax form they replaced;
+  - both then run the carry: ``U = (I + A)^{-1} beta (V - K+ S0)``, ``O =
+    Q+ S0 + P U``, ``S = diag(exp(G_C)) S0 + (K exp(G_C - G))^T U``
+    (``[K+ ; Q+] S0`` one matmul).
+
+  :func:`kda_chunk_lax` is the twin, what runs off the chip and at shapes
+  the kernel does not tile: ``A`` and ``P`` for all chunks at once
+  (:func:`_pairwise`: exact ``[SUB, SUB, d_k]`` decays inside a sub-chunk,
+  a three-factor split at the borders between them; :func:`_pairwise_head`
+  for a decay a head), the blocked solve (:func:`_solve_unit_lower`) and a
+  scan that carries the state (:func:`kda_chunk_carry_lax`).  A position
   with ``g = 0`` and ``beta = 0`` leaves the state as it was: that is how
   a padded bucket's pads are told to stand still.
 """
@@ -152,7 +191,7 @@ def _chunked(x, chunk: int):
     return x.reshape(x.shape[:2] + (x.shape[2] // chunk, chunk) + x.shape[3:])
 
 
-SUB = 16   # rows of a sub-chunk: the blocks whose decays are taken pairwise
+SUB = 16   # rows of a sub-chunk: the solve's blocks, and the twin's exact decays
 
 
 def _pairwise(q, k, gc, beta):
@@ -201,18 +240,23 @@ def _pairwise(q, k, gc, beta):
 
 
 def _pairwise_head(q, k, gc, beta):
-    """:func:`_pairwise` where a head has ONE decay: gc ``[..., C]``.  The
-    decay between two positions is then a number, ``exp(G_i - G_j)`` a
+    """:func:`_pairwise` where a head has ONE decay: gc ``[..., H, C]``.
+    The decay between two positions is then a number, ``exp(G_i - G_j)`` a
     ``[C, C]`` matrix a head (every exponent at most 0), and ``A`` and
     ``P`` are ``K K^T`` and ``Q K^T`` times it: two matmuls over d_k, no
-    ``[C, C, d_k]`` array and no sub-chunks."""
+    ``[C, C, d_k]`` array and no sub-chunks.  q, k ``[..., Hk, C, d_k]``:
+    where the axis in front of C is shorter than gc's (key heads under
+    value heads) a key head's products serve each value head over it."""
     c = q.shape[-2]
+    rep = gc.shape[-2] // q.shape[-3]
     i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
     decay = jnp.exp(jnp.where(i >= j, gc[..., :, None] - gc[..., None, :],
                               -jnp.inf))
-    kk = jnp.einsum("...id,...jd->...ij", k * beta[..., None], k, precision=HI)
+    kk = jnp.einsum("...id,...jd->...ij", k, k, precision=HI)
     qk = jnp.einsum("...id,...jd->...ij", q, k, precision=HI)
-    return jnp.where(i > j, kk * decay, 0.0), qk * decay
+    if rep > 1:
+        kk, qk = jnp.repeat(kk, rep, axis=-3), jnp.repeat(qk, rep, axis=-3)
+    return (jnp.where(i > j, kk * decay, 0.0) * beta[..., None], qk * decay)
 
 
 def _solve_unit_lower(a, rhs):
@@ -247,7 +291,14 @@ def _solve_unit_lower(a, rhs):
 
 
 def kda_chunk_carry_lax(qp, w, ut, p, ktail, decay):
-    """:func:`kda_chunk_carry` in plain lax: a scan over the chunks."""
+    """The part of :func:`kda_chunk_lax` that goes from chunk to chunk, from
+    a zero state: a scan over the chunks.  Per (row, head, chunk): ``qp = Q
+    exp(G)`` and ``w = (I + A)^{-1} beta K exp(G)`` ``[C, d_k]``, ``ut = (I
+    + A)^{-1} beta V [C, d_v]``, ``p [C, C]``, ``ktail = K exp(G_C - G) [C,
+    d_k]``, ``decay = exp(G_C) [1, d_k]``; all ``[B, H, N, ...]`` float32.
+    In chunk order, ``U = ut - w S``; ``O = qp S + p U``; ``S = diag(decay)
+    S + ktail^T U``.  Returns ``(O [B, H, N, C, d_v], S [B, H, d_k,
+    d_v])``."""
     b, h, _n, _c, dk = qp.shape
     dv = ut.shape[-1]
 
@@ -265,26 +316,135 @@ def kda_chunk_carry_lax(qp, w, ut, p, ktail, decay):
     return jnp.moveaxis(o, 0, 2), s
 
 
-def _kda_chunk_kernel(qp_ref, w_ref, ut_ref, p_ref, kt_ref, d_ref, o_ref,
-                      st_ref, st_scr):
-    """One grid cell a (row, head, chunk), the chunks in order: the state
-    rides ``st_scr`` TRANSPOSED, ``[d_v, d_k]``, so that the decay a
-    channel of d_k is a row."""
+def _dot(a, b, dims=((1,), (0,))):
+    """A float32 matmul of a cell at ``HIGHEST``; ``dims``: the contracting
+    dimensions of the two operands."""
+    return lax.dot_general(a, b, (dims, ((), ())), precision=HI,
+                           preferred_element_type=jnp.float32)
+
+
+def _solve_in_cell(a, r, sub):
+    """``(I + a)^{-1} r`` for strictly lower ``a [C, C]`` in blocks of
+    ``sub`` rows, left-looking: a block's rows first lose what the rows
+    above the block give (ONE matmul of ``sub`` rows against the answer so
+    far; ``a`` masked to the columns left of the block, so what the
+    unfinished rows hold does not matter), then the block solves itself by
+    forward substitution on the VPU, row by row (a row that stands is
+    taken from the rows under it, ``a[i, j]`` times: ``a`` is zero on and
+    above its diagonal, so whole 8-row tiles are updated without a
+    mask)."""
+    c, width = r.shape
+    col = lax.broadcasted_iota(jnp.int32, (sub, c), 1)
+    blocks = []
+    for lo in range(0, c, sub):
+        rows = a[lo:lo + sub]
+        x = r[lo:lo + sub]
+        if lo:
+            sofar = jnp.concatenate(blocks + [r[lo:]], 0)
+            x = x - _dot(jnp.where(col < lo, rows, 0.0), sofar)
+        tiles = [x[t:t + 8] for t in range(0, sub, 8)]
+        for row in range(sub - 1):
+            t0 = row // 8
+            done = jnp.broadcast_to(tiles[t0][row % 8:row % 8 + 1], (8, width))
+            for t in range(t0, sub // 8):
+                tiles[t] = tiles[t] - jnp.broadcast_to(
+                    rows[8 * t:8 * t + 8, lo + row:lo + row + 1],
+                    (8, width)) * done
+        blocks.append(jnp.concatenate(tiles, 0))
+    return jnp.concatenate(blocks, 0)
+
+
+def _kda_chunk_kernel(*refs, by_head: bool, sub: int):
+    """One grid cell a (row, head, chunk), the chunks in order: from the
+    chunk's q, k, v, log-decays and beta to its outputs (see the module
+    docstring).  With a decay a head two more operands arrive, the chunk's
+    ``(I + A)^{-1}`` and ``P``.  The state rides ``st_scr`` TRANSPOSED,
+    ``[d_v, d_k]``, so that the decay a channel of d_k is a row.  Nothing
+    here loops over heads, rows of the batch or chunks; the loops are over
+    the ``log2 C`` levels of the pairwise decays and the steps of the
+    solve."""
+    if by_head:
+        (q_ref, k_ref, v_ref, g_ref, b_ref, inv_ref, p_ref, o_ref, st_ref,
+         st_scr) = refs
+    else:
+        q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, st_scr = refs
     n = pl.program_id(2)
 
     @pl.when(n == 0)
     def _start():
         st_scr[:] = jnp.zeros_like(st_scr)
 
-    def dot(a, b, dims):
-        return lax.dot_general(a, b, (dims, ((), ())), precision=HI,
-                               preferred_element_type=jnp.float32)
+    dot, nt = _dot, ((1,), (1,))                        # nt: a @ b^T
+    f32 = jnp.float32
+    q, k, v = (x[0].astype(f32) for x in (q_ref, k_ref, v_ref))
+    c, dk = q.shape
+    i = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    row = lax.broadcasted_iota(jnp.int32, (c, dk), 0)
+
+    def column(ref):     # a chunk's numbers arrive as a row: [1, C] -> [C, 1]
+        wide = jnp.broadcast_to(ref[0, 0, 0].astype(f32), (c, c))
+        return jnp.sum(jnp.where(i == j, wide, 0.0), 1, keepdims=True)
+
+    def back(x, far):                        # row i takes row i - far
+        return pltpu.roll(x, far % c, 0)
+
+    beta = column(b_ref)
+    g = (jnp.broadcast_to(column(g_ref), (c, dk)) if by_head
+         else g_ref[0].astype(f32))
+    gc, far = g, 1                           # G, the running sum, by doubling
+    while far < c:
+        gc = gc + jnp.where(row >= far, back(gc, far), 0.0)
+        far *= 2
+    grow, tail = jnp.exp(gc), jnp.exp(gc[c - 1:c] - gc)   # exp(G), exp(G_C - G)
+
+    def level(s):
+        """The side of level ``s``'s split (pairs of blocks of ``2^s``
+        rows) a row carries: a second block's row its decay since the
+        pair's middle, a first block's row the decay from itself to there.
+        Inside a block of four the sums of log-decays themselves; above,
+        differences of G with the middle's, one row a pair."""
+        b = 1 << s
+        if b == 1:
+            e = jnp.where((row & 1) == 1, g, 0.0)
+        elif b == 2:
+            at = row & 3
+            e = jnp.where(at == 0, back(g, -1), jnp.where(
+                at == 1, 0.0, jnp.where(at == 2, g, g + back(g, 1))))
+        else:
+            mid = jnp.concatenate([
+                jnp.broadcast_to(gc[m + b - 1:m + b], (2 * b, dk))
+                for m in range(0, c, 2 * b)], 0)
+            e = jnp.where(((row >> s) & 1) == 1, gc - mid, mid - gc)
+        return jnp.exp(e)
 
     st = st_scr[:]
-    u = ut_ref[0, 0, 0] - dot(w_ref[0, 0, 0], st, ((1,), (1,)))      # [C, d_v]
-    o_ref[0, 0, 0] = (dot(qp_ref[0, 0, 0], st, ((1,), (1,)))
-                      + dot(p_ref[0, 0, 0], u, ((1,), (0,))))
-    st = st * d_ref[0, 0, 0] + dot(u, kt_ref[0, 0, 0], ((0,), (0,)))
+    read = dot(jnp.concatenate([k, q], 0) * jnp.concatenate([grow, grow], 0),
+               st, nt)                       # [K+ ; Q+] S0: [2C, d_v]
+    r = beta * (v - read[:c])
+    if by_head:
+        # Every channel decays alike: A and P are two matmuls a chunk and
+        # the solve one more for ALL chunks at once, which XLA batches far
+        # better than a cell can chain them (kda_chunk_kernel says more).
+        p = p_ref[0, 0, 0]
+        u = dot(inv_ref[0, 0, 0], r)
+    else:
+        # A decay a channel: A^T and P^T level by level, a level ONE
+        # matmul over d_k of rows that each carry their side of the split
+        # at their pair's middle, masked to the sibling pairs.
+        j2 = lax.broadcasted_iota(jnp.int32, (c, 2 * c), 0)
+        i2 = lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1) & (c - 1)
+        both = jnp.zeros((c, 2 * c), f32)
+        for s in range(c.bit_length() - 2, -1, -1):
+            ws = level(s)
+            ks = k * ws
+            z = dot(ks, jnp.concatenate([ks, q * ws], 0), nt)    # [C, 2C]
+            both = jnp.where((i2 > j2) & ((i2 ^ j2) >> s == 1), z, both)
+        both = both.T                                            # [2C, C]
+        p = both[c:] + jnp.where(i == j, jnp.sum(q * k, 1, keepdims=True), 0.0)
+        u = _solve_in_cell(both[:c] * beta, r, sub)
+    o_ref[0] = read[c:] + dot(p, u)
+    st = st * grow[c - 1:c] + dot(u, k * tail, ((0,), (0,)))
     st_scr[:] = st
 
     @pl.when(n == pl.num_programs(2) - 1)
@@ -292,70 +452,94 @@ def _kda_chunk_kernel(qp_ref, w_ref, ut_ref, p_ref, kt_ref, d_ref, o_ref,
         st_ref[0, 0] = st
 
 
-def kda_chunk_carry_kernel(qp, w, ut, p, ktail, decay, *, interpret=None):
-    """:func:`kda_chunk_carry` as the Pallas kernel ``sw_kda_chunk``."""
-    b, h, n, c, dk = qp.shape
-    dv = ut.shape[-1]
+def _kernel_takes(dk: int, dv: int, chunk: int) -> bool:
+    """Shapes the fused kernel tiles: heads of whole lane blocks and a
+    chunk that halves down to whole sublane tiles."""
+    return (dk % 128 == 0 and dv % 128 == 0 and chunk % 8 == 0
+            and chunk & (chunk - 1) == 0)
+
+
+def kda_chunk_kernel(q, k, v, g, beta, *, chunk: int = 64, interpret=None):
+    """:func:`kda_chunk` as the Pallas kernel ``sw_kda_chunk``, S a whole
+    number of chunks.  q, k, v and a decay a channel are read where the
+    projections left them, a head a column block of ``[B, S, H * d]``, and
+    the output is written the same way; a key head is read by each of the
+    value heads over it.
+
+    With a decay a head the chunks' ``(I + A)^{-1}`` and ``P`` come from
+    lax (:func:`_pairwise_head`, :func:`_solve_unit_lower` against the
+    identity) as ``[C, C]`` operands: they are then two matmuls and a
+    solve batched over every (head, chunk) at once, 0.4 us a cell, where
+    a cell that chains them pays 0.13-0.19 us a float32 matmul at
+    ``HIGHEST`` (fourteen of them: measured 2.6 times the lax form's
+    whole operation).  With a decay a channel the pairwise part is what
+    XLA runs badly, and the cell builds everything."""
+    b, s, hk, dk = q.shape
+    h, dv = v.shape[2:]
+    by_head = g.ndim == 3
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    f32 = jnp.float32
+    rep, n = h // hk, s // chunk
+    sub = SUB if chunk % SUB == 0 else chunk
 
-    def spec(rows, width):
-        return pl.BlockSpec((1, 1, 1, rows, width),
-                            lambda i, j, m: (i, j, m, 0, 0))
+    def cols(width, index):     # a head's [C, width] of [B, S, heads * width]
+        return pl.BlockSpec((1, chunk, width), index)
 
+    def chunks(x):              # [B, S, H, ..] -> [B, N, H, C, ..]
+        x = x.astype(f32)
+        return jnp.moveaxis(x.reshape((b, n, chunk) + x.shape[2:]), 2, 3)
+
+    own = lambda i, j, m: (i, m, j)
+    key = lambda i, j, m: (i, m, j // rep)
+    by_chunk = lambda i, j, m: (i, m, j, 0, 0)
+    row_spec = pl.BlockSpec((1, 1, 1, 1, chunk), by_chunk)
+    beta = chunks(beta)                                  # [B, N, H, C]
+    operands = [q.reshape(b, s, hk * dk), k.reshape(b, s, hk * dk),
+                v.reshape(b, s, h * dv)]
+    specs = [cols(dk, key), cols(dk, key), cols(dv, own)]
+    if by_head:
+        g = chunks(g)
+        a, p = _pairwise_head(chunks(q), chunks(k), jnp.cumsum(g, -1), beta)
+        eye = jnp.broadcast_to(jnp.eye(chunk, dtype=f32), a.shape)
+        operands += [g[..., None, :], beta[..., None, :],
+                     _solve_unit_lower(a, eye), p]
+        specs += [row_spec, row_spec] + [pl.BlockSpec(
+            (1, 1, 1, chunk, chunk), by_chunk)] * 2
+    else:
+        operands += [g.reshape(b, s, h * dk), beta[..., None, :]]
+        specs += [cols(dk, own), row_spec]
     o, st = pl.pallas_call(
-        _kda_chunk_kernel,
+        functools.partial(_kda_chunk_kernel, by_head=by_head, sub=sub),
         grid=(b, h, n),
-        in_specs=[spec(c, dk), spec(c, dk), spec(c, dv), spec(c, c),
-                  spec(c, dk), spec(1, dk)],
-        out_specs=[spec(c, dv),
+        in_specs=specs,
+        out_specs=[cols(dv, own),
                    pl.BlockSpec((1, 1, dv, dk), lambda i, j, m: (i, j, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b, h, n, c, dv), jnp.float32),
-                   jax.ShapeDtypeStruct((b, h, dv, dk), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((b, s, h * dv), f32),
+                   jax.ShapeDtypeStruct((b, h, dv, dk), f32)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="sw_kda_chunk",
-    )(qp, w, ut, p, ktail, decay)
-    return o, jnp.swapaxes(st, -1, -2)
+    )(*operands)
+    return o.reshape(b, s, h, dv), jnp.swapaxes(st, -1, -2)
 
 
-def kda_chunk_carry(qp, w, ut, p, ktail, decay):
-    """The part of :func:`kda_chunk` that goes from chunk to chunk, from a
-    zero state.  Per (row, head, chunk): ``qp = Q exp(G)`` and ``w = (I +
-    A)^{-1} beta K exp(G)`` ``[C, d_k]``, ``ut = (I + A)^{-1} beta V [C,
-    d_v]``, ``p [C, C]``, ``ktail = K exp(G_C - G) [C, d_k]``, ``decay =
-    exp(G_C) [1, d_k]``; all ``[B, H, N, ...]`` float32.  In chunk order,
-    ``U = ut - w S``; ``O = qp S + p U``; ``S = diag(decay) S + ktail^T
-    U``.  Returns ``(O [B, H, N, C, d_v], S [B, H, d_k, d_v])``."""
-    c, dk = qp.shape[-2:]
-    if (dispatch.use_kernels() and dk % 128 == 0 and ut.shape[-1] % 128 == 0
-            and c % 8 == 0):
-        return kda_chunk_carry_kernel(qp, w, ut, p, ktail, decay)
-    return kda_chunk_carry_lax(qp, w, ut, p, ktail, decay)
-
-
-def kda_chunk(q, k, v, g, beta, *, chunk: int = 64):
-    """A whole prompt through the recurrence, chunk by chunk, from a zero
-    state, the operation.  q, k ``[B, H, S, d_k]``, v ``[B, H, S, d_v]``,
-    g ``[B, H, S, d_k]`` (a log-decay a channel) or ``[B, H, S]`` (one a
-    head), beta ``[B, H, S]``; a position with ``g = 0`` and ``beta = 0``
-    does not move the state (a bucket's pads).  Returns ``(o [B, H, S,
-    d_v] float32, state [B, H, d_k, d_v] float32)``: every position's
-    read-out and the state after the last.  S is padded to whole chunks
-    with such standing positions."""
+def kda_chunk_lax(q, k, v, g, beta, *, chunk: int = 64):
+    """:func:`kda_chunk` in plain lax, S a whole number of chunks: what
+    runs where Pallas does not, and what the kernel is tested against.
+    What is the same for every chunk (``A``, ``P``, the solve against
+    ``beta V`` and ``beta K+``) is computed for all chunks at once, and a
+    scan carries the state (:func:`kda_chunk_carry_lax`)."""
     f32 = jnp.float32
-    s = q.shape[2]
     by_head = g.ndim == 3
+    rep = v.shape[2] // q.shape[2]
+    if rep > 1:
+        q, k = jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2)
     if by_head:
-        g = g[..., None]          # [B, H, S, 1]: broadcasts against d_k
-    pad = -s % chunk
-    if pad:
-        q, k, v, g = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                      for x in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, 0), (0, pad)))
-    q, k, v, g, beta = (_chunked(x.astype(f32), chunk)
+        g = g[..., None]          # [B, S, H, 1]: broadcasts against d_k
+    q, k, v, g, beta = (_chunked(jnp.moveaxis(x.astype(f32), 2, 1), chunk)
                         for x in (q, k, v, g, beta))
     gc = jnp.cumsum(g, axis=-2)                          # [B, H, N, C, d_k]
     if by_head:
@@ -374,7 +558,29 @@ def kda_chunk(q, k, v, g, beta, *, chunk: int = 64):
     decay = jnp.exp(total)
     if by_head:   # the carry takes a decay a channel: every channel alike
         decay = jnp.broadcast_to(decay, total.shape[:-1] + k.shape[-1:])
-    o, state = kda_chunk_carry(q * grow, solved[..., dv:], solved[..., :dv],
-                               p, k * jnp.exp(total - gc), decay)
-    o = o.reshape(o.shape[:2] + (-1, dv))
-    return o[:, :, :s], state
+    o, state = kda_chunk_carry_lax(q * grow, solved[..., dv:], solved[..., :dv],
+                                   p, k * jnp.exp(total - gc), decay)
+    return jnp.moveaxis(o.reshape(o.shape[:2] + (-1, dv)), 1, 2), state
+
+
+def kda_chunk(q, k, v, g, beta, *, chunk: int = 64):
+    """A whole prompt through the recurrence, chunk by chunk, from a zero
+    state, the operation, in the projections' layout.  q, k ``[B, S, Hk,
+    d_k]`` (``Hk`` key heads, each read by ``H / Hk`` value heads), v ``[B,
+    S, H, d_v]``, g ``[B, S, H, d_k]`` (a log-decay a channel) or ``[B, S,
+    H]`` (one a head), beta ``[B, S, H]``; a position with ``g = 0`` and
+    ``beta = 0`` does not move the state (a bucket's pads).  Returns ``(o
+    [B, S, H, d_v] float32, state [B, H, d_k, d_v] float32)``: every
+    position's read-out and the state after the last.  S is padded to whole
+    chunks with such standing positions.  On a TPU the one kernel, elsewhere
+    and at shapes it does not tile the lax twin."""
+    s = q.shape[1]
+    pad = -s % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    run = (kda_chunk_kernel if dispatch.use_kernels() and _kernel_takes(
+        q.shape[-1], v.shape[-1], chunk) else kda_chunk_lax)
+    o, state = run(q, k, v, g, beta, chunk=chunk)
+    return o[:, :s], state

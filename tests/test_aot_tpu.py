@@ -241,14 +241,17 @@ def _kda_step(b=256, layers=6, h=32, d=128):
         _s((), I32))
 
 
-def _kda_chunk_carry(positions=1024, h=32, d=128, c=64):
-    from starway_tpu.ops.pallas_kda import kda_chunk_carry_kernel
+def _kda_chunk(positions=1024, h=32, hk=None, d=128, by_head=False):
+    """The fused prefill kernel on one admission's bucket: ``h`` value
+    heads over ``hk`` key heads in the projections' layout, a decay a
+    channel or (``by_head``) one a head."""
+    from starway_tpu.ops.pallas_kda import kda_chunk_kernel
 
-    n = positions // c
-    rows = _s((1, h, n, c, d), F32)
-    return (lambda *a: kda_chunk_carry_kernel(*a, interpret=False)), (
-        rows, rows, rows, _s((1, h, n, c, c), F32), rows,
-        _s((1, h, n, 1, d), F32))
+    keys = _s((1, positions, hk or h, d), F32)
+    return (lambda *a: kda_chunk_kernel(*a, interpret=False)), (
+        keys, keys, _s((1, positions, h, d), F32),
+        _s((1, positions, h) if by_head else (1, positions, h, d), F32),
+        _s((1, positions, h), F32))
 
 
 KERNELS = {
@@ -325,12 +328,13 @@ KERNELS = {
     "gmm_relu_admit": lambda: _gmm(86016, 128, 2560, 768, True, 64, "relu"),
     "gmm_down_longdoc": lambda: _gmm(288, 16, 768, 2560, False, 64),
     # kimi-linear.reason_closed: 256 slots' state of 32 heads x 128 x 128
-    # float32 in six stacked layers, by a traced index; a 1,024- and a
-    # 4,096-token admission's chunk-to-chunk carry; 256 x 8 pairs a decode
-    # step on 16 held experts of width 1024.
+    # float32 in six stacked layers, by a traced index; the smallest and
+    # the largest admission's whole chunked recurrence (a decay a channel)
+    # in one kernel; 256 x 8 pairs a decode step on 16 held experts of
+    # width 1024.
     "kda_step_reason": lambda: _kda_step(),
-    "kda_chunk_carry_1024": lambda: _kda_chunk_carry(1024),
-    "kda_chunk_carry_4096": lambda: _kda_chunk_carry(4096),
+    "kda_chunk_reason_128": lambda: _kda_chunk(128),
+    "kda_chunk_reason_4096": lambda: _kda_chunk(4096),
     "gmm_gated_reason": lambda: _gmm(2048, 16, 2304, 1024, True, 16),
     # qwen3-next.answer_closed: 16 query heads over 2 kv heads of 256 (8 a
     # kv head) in a 2,048-token admission's flash pass, the decode kernel
@@ -342,6 +346,10 @@ KERNELS = {
                                           d=256),
     "kv_write_answer": lambda: _kv_write(192, 2, 4096, 2, d=256),
     "kda_step_answer": lambda: _kda_step(b=192),
+    # ... and an admission's: 32 value heads read 16 key heads, one decay
+    # a head.
+    "kda_chunk_answer_256": lambda: _kda_chunk(256, hk=16, by_head=True),
+    "kda_chunk_answer_4096": lambda: _kda_chunk(4096, hk=16, by_head=True),
     "gmm_gated_answer": lambda: _gmm(1920, 16, 2048, 512, True, 128),
     "gmm_down_answer": lambda: _gmm(1920, 16, 512, 2048, False, 128),
     "gmm_gated_answer_admit": lambda: _gmm(20480, 128, 2048, 512, True, 128),
@@ -395,6 +403,21 @@ def test_decode_kernel_body_does_not_grow_with_the_heads(case):
     assert eight <= 1.3 * two, (two, eight)
     if "t" not in kw:
         assert abs(size(8, t=8192) - eight) <= 0.02 * eight
+
+
+@pytest.mark.parametrize("by_head", [False, True], ids=["a_channel", "a_head"])
+def test_kda_chunk_kernel_body_is_one_size_at_every_bucket_and_head_count(
+        by_head):
+    """The start-up budget (PERF.md section 7): the fused kernel's body
+    loops over the levels of its pairwise decays and the doublings of its
+    solve, constants of the algorithm, and over nothing a shape sets.  Its
+    printed module is the same at bucket 128 and at 4,096, for 2 heads and
+    for 32 (but for the digits of the operands' shapes)."""
+    size = lambda positions, h: len(_kernel_module_text(*_kda_chunk(
+        positions, h, hk=h // 2 if by_head else h, by_head=by_head)))
+    small = size(128, 2)
+    assert abs(size(4096, 2) - small) <= 0.01 * small
+    assert abs(size(128, 32) - small) <= 0.01 * small
 
 
 # ----------------------------------------------------------- whole programs
@@ -660,6 +683,40 @@ def test_admission_programs_at_the_cells_sizes_for_v5e(topo, monkeypatch, cell,
     assert [(o.shape, o.dtype) for o in seat.out_info] == [
         (a.shape, a.dtype) for a in state]
     assert seat.memory_analysis().argument_size_in_bytes < 4096
+
+
+@pytest.mark.parametrize("cell", ["kimi-linear", "qwen3-next"])
+def test_linear_layers_admit_in_one_kernel_a_layer_for_v5e(topo, monkeypatch,
+                                                           cell):
+    """A 1,024-token admission of the two linear-state cells: inside the
+    layer's scope ``sw_kda_chunk`` nothing loops (the parent walked the
+    chunks in a ``lax.map`` in front of its carry kernel) and no float32
+    array a position as wide as a head's q (``[.., 1024, 128]`` by 32
+    heads, head-major: the parent's ``moveaxis`` copies and its five
+    ``[B, H, N, C, d]`` operands) is made there.  With a decay a channel
+    the program holds no ``[.., C, C]`` float32 array either (``A``, ``P``
+    and the decays between two positions live and die in VMEM); with a
+    decay a head those two are what lax hands the kernel."""
+    import re
+
+    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.serving import _compiled_admit
+
+    _as_tpu(monkeypatch)
+    cfg, params, n_slots, max_len = _cell_model(cell)
+    cache = jax.eval_shape(lambda: init_cache(cfg, n_slots, max_len))
+    args = (params, cache, _s((1, 1024), I32), _s((), I32), _s((), I32),
+            jax.eval_shape(jax.random.PRNGKey, 0))
+    text = _compiled_admit(cfg, 1024, 0.0, None, None).lower(*_placed(
+        args, SingleDeviceSharding(topo.devices[0]))).compile().as_text()
+    scope = [line for line in text.splitlines() if "/sw_kda_chunk/" in line]
+    assert any('custom_call_target="tpu_custom_call"' in line
+               for line in scope)
+    assert [line for line in scope if " while(" in line] == []
+    assert [line for line in scope
+            if re.search(r"= f32\[1,32,(1024|16,64),128\]", line)] == []
+    square = re.findall(r"f32\[(?:\d+,)*64,64\]", text)
+    assert (square == []) == (cell == "kimi-linear"), square[:4]
 
 
 def test_two_cache_kinds_ride_the_decode_chunk_for_v5e(topo, monkeypatch):

@@ -403,18 +403,18 @@ def _recurrence(q, k, v, g, beta):
         s = s + k[..., None] * u[..., None, :]
         return s, jnp.einsum("bhkv,bhk->bhv", s, q)
 
-    b, h, _s, d = q.shape
+    b, _s, h, d = q.shape
     s, o = jax.lax.scan(token, jnp.zeros((b, h, d, v.shape[-1])), tuple(
-        jnp.moveaxis(x, 2, 0) for x in (q, k, v, g, beta)))
-    return jnp.moveaxis(o, 0, 2), s
+        jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1), s
 
 
 def _operands(s, d=16, b=2, h=3, decay=3.0, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    q, k, v = (jax.random.normal(x, (b, h, s, d)) for x in ks[:3])
+    q, k, v = (jax.random.normal(x, (b, s, h, d)) for x in ks[:3])
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    g = -jnp.abs(jax.random.normal(ks[3], (b, h, s))) * decay
-    return q, k, v, g, jax.nn.sigmoid(jax.random.normal(ks[4], (b, h, s)))
+    g = -jnp.abs(jax.random.normal(ks[3], (b, s, h))) * decay
+    return q, k, v, g, jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
 
 
 @pytest.mark.parametrize("length,chunk", [(1, 64), (63, 64), (64, 64),
@@ -461,10 +461,10 @@ def test_standing_positions_do_not_move_the_state():
 
     q, k, v, g, beta = _operands(70, seed=3)
     real = jnp.arange(70) < 41
-    _o, s = kda_chunk(q, k, v, jnp.where(real, g, 0.0),
-                      jnp.where(real, beta, 0.0))
-    _o, want = kda_chunk(q[:, :, :41], k[:, :, :41], v[:, :, :41],
-                         g[:, :, :41], beta[:, :, :41])
+    _o, s = kda_chunk(q, k, v, jnp.where(real[:, None], g, 0.0),
+                      jnp.where(real[:, None], beta, 0.0))
+    _o, want = kda_chunk(q[:, :41], k[:, :41], v[:, :41], g[:, :41],
+                         beta[:, :41])
     np.testing.assert_allclose(s, want, rtol=1e-5, atol=1e-5)
 
 
@@ -474,16 +474,16 @@ def test_the_decode_kernel_takes_a_heads_decay_as_a_channels():
     from starway_tpu.ops.pallas_kda import kda_step_kernel
 
     q, k, v, g, beta = _operands(4, h=4, seed=13)
-    state = jnp.zeros((2,) + q.shape[:2] + (16, 16))
+    state = jnp.zeros((2, 2, 4, 16, 16))
     outs = []
     for t in range(4):
         o, state = kda_step_kernel(
-            state, q[:, :, t], k[:, :, t], v[:, :, t],
-            jnp.broadcast_to(g[:, :, t, None], q.shape[:2] + (16,)),
-            beta[:, :, t], layer=jnp.int32(1), interpret=True)
+            state, q[:, t], k[:, t], v[:, t],
+            jnp.broadcast_to(g[:, t, :, None], (2, 4, 16)),
+            beta[:, t], layer=jnp.int32(1), interpret=True)
         outs.append(o)
     want_o, want_s = _recurrence(q, k, v, g, beta)
-    np.testing.assert_allclose(jnp.stack(outs, 2), want_o, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(jnp.stack(outs, 1), want_o, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(state[1], want_s, rtol=1e-5, atol=1e-5)
     assert not np.asarray(state[0]).any()
 

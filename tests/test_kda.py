@@ -344,18 +344,18 @@ def _recurrence(q, k, v, g, beta):
         s = s + k[..., None] * u[..., None, :]
         return s, jnp.einsum("bhkv,bhk->bhv", s, q)
 
-    b, h, _s, d = q.shape
+    b, _s, h, d = q.shape
     s, o = jax.lax.scan(token, jnp.zeros((b, h, d, v.shape[-1])), tuple(
-        jnp.moveaxis(x, 2, 0) for x in (q, k, v, g, beta)))
-    return jnp.moveaxis(o, 0, 2), s
+        jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1), s
 
 
 def _operands(s, d=16, b=2, h=3, decay=3.0, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    q, k, v = (jax.random.normal(x, (b, h, s, d)) for x in ks[:3])
+    q, k, v = (jax.random.normal(x, (b, s, h, d)) for x in ks[:3])
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    g = -jnp.abs(jax.random.normal(ks[3], (b, h, s, d))) * decay
-    return q, k, v, g, jax.nn.sigmoid(jax.random.normal(ks[4], (b, h, s)))
+    g = -jnp.abs(jax.random.normal(ks[3], (b, s, h, d))) * decay
+    return q, k, v, g, jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
 
 
 @pytest.mark.parametrize("length,chunk", [(1, 64), (63, 64), (64, 64),
@@ -390,10 +390,10 @@ def test_standing_positions_do_not_move_the_state():
 
     q, k, v, g, beta = _operands(70, seed=3)
     real = jnp.arange(70) < 41
-    _o, s = kda_chunk(q, k, v, jnp.where(real[:, None], g, 0.0),
-                      jnp.where(real, beta, 0.0))
-    _o, want = kda_chunk(q[:, :, :41], k[:, :, :41], v[:, :, :41],
-                         g[:, :, :41], beta[:, :, :41])
+    _o, s = kda_chunk(q, k, v, jnp.where(real[:, None, None], g, 0.0),
+                      jnp.where(real[:, None], beta, 0.0))
+    _o, want = kda_chunk(q[:, :41], k[:, :41], v[:, :41], g[:, :41],
+                         beta[:, :41])
     np.testing.assert_allclose(s, want, rtol=1e-5, atol=1e-5)
 
 
@@ -420,30 +420,129 @@ def test_kda_step_is_one_token_of_the_recurrence():
     from starway_tpu.ops.pallas_kda import kda_step_lax
 
     q, k, v, g, beta = _operands(5, seed=11)
-    state = jnp.zeros((1,) + q.shape[:2] + (16, 16))
+    state = jnp.zeros((1, 2, 3, 16, 16))
     outs = []
     for t in range(5):
-        o, state = kda_step_lax(state, q[:, :, t], k[:, :, t], v[:, :, t],
-                                g[:, :, t], beta[:, :, t], layer=0)
+        o, state = kda_step_lax(state, q[:, t], k[:, t], v[:, t], g[:, t],
+                                beta[:, t], layer=0)
         outs.append(o)
     want_o, want_s = _recurrence(q, k, v, g, beta)
-    np.testing.assert_allclose(jnp.stack(outs, 2), want_o, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(jnp.stack(outs, 1), want_o, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(state[0], want_s, rtol=1e-5, atol=1e-5)
 
 
-def test_kda_chunk_carry_kernel_matches_lax():
-    from starway_tpu.ops.pallas_kda import (kda_chunk_carry_kernel,
-                                            kda_chunk_carry_lax)
+def _wide_operands(s, by_head, kind="plain", d=128, seed=0):
+    """Operands at a width the fused kernel takes (a head a lane block of
+    128), two value heads over one key head where the decay is a head's:
+    ``plain``, ``overflow`` (a log-decay of -40 a token) or ``hard`` (beta
+    near 1 and a chunk's keys nearly alike: ``A`` is nearly all ones under
+    its diagonal, the solve's worst case)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    h, hk = 2, 1 if by_head else 2
+    q = jax.random.normal(ks[0], (1, s, hk, d)) * d**-0.5
+    k = jax.random.normal(ks[1], (1, s, hk, d))
+    v = jax.random.normal(ks[2], (1, s, h, d))
+    decay, shift = {"plain": (0.5, 0.0), "overflow": (40.0, 0.0),
+                    "hard": (0.01, 6.0)}[kind]
+    if kind == "hard":
+        k = jax.random.normal(ks[5], (1, 1, hk, d)) + 0.02 * k
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = -decay * jnp.abs(jax.random.normal(
+        ks[3], (1, s, h) if by_head else (1, s, h, d)))
+    return q, k, v, g, jax.nn.sigmoid(shift + jax.random.normal(ks[4], (1, s, h)))
 
-    ks = jax.random.split(jax.random.PRNGKey(1), 6)
-    shape = (2, 3, 4, 8, 16)                          # [B, H, N, C, d]
-    qp, w, ut, ktail = (jax.random.normal(x, shape) * 0.3 for x in ks[:4])
-    p = jax.random.normal(ks[4], (2, 3, 4, 8, 8)) * 0.3
-    decay = jnp.exp(-jnp.abs(jax.random.normal(ks[5], (2, 3, 4, 1, 16))))
-    want_o, want_s = kda_chunk_carry_lax(qp, w, ut, p, ktail, decay)
-    o, s = kda_chunk_carry_kernel(qp, w, ut, p, ktail, decay, interpret=True)
-    np.testing.assert_allclose(o, want_o, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(s, want_s, rtol=1e-5, atol=1e-5)
+
+def _on_both_sides(force_kernels, x):
+    """``kda_chunk(*x)`` on the fused kernel (interpreted) and on its lax
+    twin."""
+    from starway_tpu.ops import kda_chunk
+
+    force_kernels(True)
+    got = kda_chunk(*x)
+    force_kernels(False)
+    return got, kda_chunk(*x)
+
+
+DECAYS = pytest.mark.parametrize("by_head", [False, True],
+                                 ids=["a_channel", "a_head"])
+
+
+@DECAYS
+@pytest.mark.parametrize("length", [1, 63, 64, 65, 200])
+def test_fused_chunk_kernel_is_its_lax_twin(force_kernels, length, by_head):
+    """``sw_kda_chunk`` builds the pairwise decays, solves and carries in
+    one cell: the twin's outputs and state at lengths around a chunk's
+    edge (the rest of a chunk stands still)."""
+    (o, s), (want_o, want_s) = _on_both_sides(
+        force_kernels, _wide_operands(length, by_head, seed=length))
+    assert o.shape == want_o.shape == (1, length, 2, 128)
+    np.testing.assert_allclose(o, want_o, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(s, want_s, rtol=2e-4, atol=2e-4)
+
+
+@DECAYS
+def test_fused_chunk_kernel_survives_a_decay_that_would_overflow(
+        force_kernels, by_head):
+    """Every exponent the kernel takes is a sum of log-decays (``_sums``):
+    -40 a token overflows nothing, and the twin's numbers come out."""
+    x = _wide_operands(130, by_head, "overflow", seed=7)
+    (o, s), (want_o, want_s) = _on_both_sides(force_kernels, x)
+    assert np.isfinite(np.asarray(o)).all()
+    np.testing.assert_allclose(o, want_o, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(s, want_s, rtol=2e-4, atol=2e-4)
+
+
+@DECAYS
+def test_fused_chunk_kernel_solves_near_equal_keys_under_beta_near_one(
+        force_kernels, by_head):
+    """The solve's hard case: the kernel's block-diagonal inverses and
+    block substitution (finite products of [C, C] matmuls) stay as close
+    to the token-by-token recurrence as the twin's do."""
+    x = _wide_operands(128, by_head, "hard", seed=2)
+    (o, s), (twin_o, twin_s) = _on_both_sides(force_kernels, x)
+    q, k, v, g, beta = x
+    rep = v.shape[2] // q.shape[2]
+    want_o, want_s = _recurrence(
+        jnp.repeat(q, rep, 2), jnp.repeat(k, rep, 2), v,
+        jnp.broadcast_to(g[..., None], v.shape) if by_head else g, beta)
+    for got, twin, want in ((o, twin_o, want_o), (s, twin_s, want_s)):
+        np.testing.assert_allclose(got, twin, rtol=2e-3, atol=2e-3)
+        np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+
+
+@DECAYS
+def test_fused_chunk_kernel_leaves_the_unpadded_state_under_pads(
+        force_kernels, by_head):
+    """A bucket's pads (``g = 0``, ``beta = 0``) stand still inside the
+    kernel too: the state of the 41 real positions alone."""
+    from starway_tpu.ops import kda_chunk
+
+    q, k, v, g, beta = _wide_operands(128, by_head, seed=3)
+    real = jnp.arange(128)[None, :, None] < 41
+    force_kernels(True)
+    _o, s = kda_chunk(q, k, v, jnp.where(real if by_head else real[..., None],
+                                         g, 0.0), jnp.where(real, beta, 0.0))
+    _o, want = kda_chunk(q[:, :41], k[:, :41], v[:, :41], g[:, :41],
+                         beta[:, :41])
+    np.testing.assert_allclose(s, want, rtol=1e-5, atol=1e-5)
+
+
+def test_fused_chunk_kernel_keeps_to_shapes_it_tiles(force_kernels):
+    """A head narrower than a lane block, or a chunk that does not halve
+    down to sublane tiles, takes the lax twin whatever the decision says."""
+    from starway_tpu.ops import pallas_kda
+
+    assert pallas_kda._kernel_takes(128, 128, 64)
+    assert pallas_kda._kernel_takes(256, 128, 8)
+    assert not pallas_kda._kernel_takes(16, 128, 64)
+    assert not pallas_kda._kernel_takes(128, 128, 48)
+    assert not pallas_kda._kernel_takes(128, 128, 4)
+    force_kernels(True)
+    x = _operands(70, seed=5)           # d = 16
+    o, s = pallas_kda.kda_chunk(*x)
+    want_o, want_s = _recurrence(*x)
+    np.testing.assert_allclose(o, want_o, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(s, want_s, rtol=2e-4, atol=2e-4)
 
 
 def test_the_whole_model_on_the_kernels_side(runner, force_kernels):
