@@ -650,7 +650,8 @@ def check_numerics():
     # end-to-end greedy-output comparison would cascade from a single
     # benign near-tie and flap; the logit gap is the claim itself.
     from starway_tpu.models import LlamaConfig, init_params
-    from starway_tpu.models.generate import decode_step, init_cache
+    from starway_tpu.models.cache import init_cache
+    from starway_tpu.models.generate import decode_step
     from starway_tpu.models.llama import rope_tables
     from starway_tpu.models.speculative import chunk_decode_step
 
@@ -838,7 +839,8 @@ def bench_spec_verify(gamma=8, t=4096, iters: int = 16):
     import numpy as np
 
     from starway_tpu.models import LlamaConfig, chunk_decode_step, init_params
-    from starway_tpu.models.generate import decode_step, init_cache
+    from starway_tpu.models.cache import init_cache
+    from starway_tpu.models.generate import decode_step
     from starway_tpu.models.llama import rope_tables
 
     cfg = LlamaConfig.preset(

@@ -26,8 +26,8 @@ from .pp_llama import (
     shard_ppv_params,
 )
 from .beam import generate_beam
-from .generate import (generate, init_cache, init_rolling_cache, prefill,
-                       prefill_rolling)
+from .cache import init_cache, init_rolling_cache
+from .generate import generate, prefill, prefill_rolling
 from .paged import PagedSlotServer, init_paged_pool, paged_decode_step
 from .remote_serving import RemoteGenerateSession, RemoteSlotServer
 from .serving import SlotServer

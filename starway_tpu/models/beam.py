@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .cache import require_rows
 from .generate import NEG_BIG, decode_step, prefill
 from .llama import LlamaConfig, cfg_rope_tables
 
@@ -125,16 +126,7 @@ def generate_beam(params: dict, cfg: LlamaConfig, prompt,
         raise ValueError(f"beams must be >= 1, got {beams}")
     if beams > cfg.vocab_size:
         raise ValueError(f"beams={beams} exceeds the vocab ({cfg.vocab_size})")
-    if cfg.mtp:
-        raise ValueError("beam search scores one token a step; an MTP "
-                         "block's drafts are not wired into it (ROADMAP M5)")
-    if cfg.linear is not None:
-        raise ValueError("beam search reorders cache rows by position; a "
-                         "linear-attention layer's state (cfg.linear) has "
-                         "none and is not wired (ROADMAP M4)")
-    if cfg.sliding_window is not None or cfg.kinds is not None:
-        raise ValueError("beam search needs full caches; rolling-cache "
-                         "support is not wired")
+    require_rows(cfg, "reorder")  # each step re-gathers the K-way cache
     total = P + max_new_tokens
     if max_len is None:
         max_len = total

@@ -5,31 +5,10 @@ layer and attention masks by position, so one compiled step serves the whole
 generation (``lax.scan`` over steps; no retracing, no dynamic shapes -- the
 XLA-friendly decode loop).
 
-The cache layout is scan-stacked like the parameters: ``k/v
-[n_layers, B, Hkv, max_len, head_dim]``.  The stacked arrays ride the layer
-scan's CARRY (:func:`cached_layer_scan`): they are never a scan input or
-output and never sliced per layer, so one buffer serves the whole
-generation (donate the cache under jit).  On the chip the new entries are
-written in place by ``ops.cache_write`` and attention reads
-the stacked array through a layer index; the decode step moves no cache
-bytes but the ones attention reads.
-
-A RING is a cache of exactly one window's positions, written at ``pos %
-window`` and attended whole (``_write_cached`` / ``attend_cache`` with
-``ring=True``: the one implementation).  A model whose every layer has
-the window (``cfg.sliding_window``) may keep all its layers so
-(``init_rolling_cache``, ``decode_step(rolling=True)``); a model whose
-layers differ (``cfg.kinds``) keeps its window layers' rings under
-``k_ring`` / ``v_ring`` BESIDE its full layers' rows under ``k`` / ``v``,
-each stacked over the layers of its own kind (``init_cache``).
-
-A STATE is what a linear-attention layer keeps (``cfg.linear``,
-models/kda.py): ``kda_state`` / ``kda_conv``, a matrix a head and the
-convolutions' last inputs, with NO position axis.  Nothing is written at a
-cursor and nothing masks by one: a decode step moves the whole state on
-(``ops.kda_step``, in place), a prefill hands back the state after each
-row's own last token (:func:`prefill`'s ``logit_positions``), and whoever
-seats a request replaces the row's state whole.
+What the cache holds, and how it is written, attended and filled, is
+models/cache.py's; :func:`cached_layer_scan` is the one place here that
+names a cache leaf, because it is the layer body that PRODUCES a layer's
+new entries.
 """
 
 from __future__ import annotations
@@ -41,221 +20,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .cache import (_write_cached, attend_cache, attend_piece, cache_len,
+                    cache_spec, from_forward, init_rolling_cache,
+                    quantize_rows, ring_fold, ring_in_order,
+                    taken_for_rolling)
 from .llama import (LlamaConfig, apply_rope, cfg_rmsnorm, cfg_rope_tables,
                     embed_tokens, ffn_block, forward, gate_heads,
                     layer_segments, matmul_w, qkv_proj, scan_segment,
                     segment_kind)
-from ..ops import (cache_write, cached_attention, ingest_attention,
-                   latent_attention)
 from ..ops.attention import NEG_BIG, repeat_kv
-
-
-def init_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
-    """Decode cache: ``k/v [n_layers, B, Hkv, max_len, head_dim]``.
-
-    ``cfg.kv_quant == "int8"`` stores k/v as int8 plus per-token f32 scales
-    ``k_scale/v_scale [n_layers, B, Hkv, max_len]`` (ops/quantize.py) —
-    half the HBM bytes on the bandwidth-bound decode stream.  The scale
-    keys' presence IS the format marker every consumer dispatches on.
-
-    Latent attention (``cfg.latent``, models/mla.py) caches ONE row a token
-    for all heads: ``ckv [n_layers, B, 1, max_len, cache_width]`` (``kv_rank
-    + rope_dim`` values in whole lane tiles), the same five axes with a
-    single "head", so slot writes, padding and ``kv_write`` treat it as
-    they treat ``k``.
-
-    Layers of different kinds (``cfg.kinds``) keep TWO kinds of leaves:
-    ``k`` / ``v [full layers, B, Hkv, max_len, head_dim]`` and the window
-    layers' rings ``k_ring`` / ``v_ring [window layers, B, Hkv, window,
-    head_dim]``, each stacked over its own layers in model order.  Linear
-    layers (``cfg.linear``) add a third kind with NO position axis:
-    ``kda_state [linear layers, B, H, d, d]`` float32 and ``kda_conv
-    [linear layers, B, taps - 1, conv_width]`` (q, k and v side by side:
-    ``3*H*d``, less where the key heads are fewer); ``max_len`` then
-    sizes the attention layers alone, latent rows or grouped-query
-    ``k`` / ``v`` as the model has them.  A ring holds ``cfg.kinds.ring``
-    positions: the window and the slack a step of several positions needs.
-
-    An MTP block (``cfg.mtp``, models/mtp.py) keeps a full row of its own
-    a batch row, ``k_mtp`` / ``v_mtp [1, B, Hkv, max_len, head_dim]``,
-    beside the model's leaves.
-    """
-    full = cfg.kind_layers("full")
-    state = {}
-    if cfg.mtp:
-        state = {name: jnp.zeros(
-            (cfg.mtp, batch, cfg.n_kv_heads, max_len, cfg.head_dim),
-            cfg.compute_dtype) for name in ("k_mtp", "v_mtp")}
-    if cfg.linear is not None:
-        la, n = cfg.linear, cfg.kind_layers("linear")
-        state = {
-            "kda_state": jnp.zeros(
-                (n, batch, la.n_heads, la.head_dim, la.head_dim), jnp.float32),
-            "kda_conv": jnp.zeros((n, batch, la.conv - 1, la.conv_width),
-                                  cfg.compute_dtype)}
-    if cfg.latent is not None:
-        return {"ckv": jnp.zeros(
-            (full, batch, 1, max_len, cfg.latent.cache_width),
-            cfg.compute_dtype), **state}
-    hd = cfg.head_dim
-    if cfg.kinds is not None:
-        shapes = {"": (full, max_len)}
-        if cfg.kinds.window is not None:
-            shapes["_ring"] = (cfg.kind_layers("ring"), cfg.kinds.ring)
-        return {**{name + kind: jnp.zeros((n, batch, cfg.n_kv_heads, t, hd),
-                                          cfg.compute_dtype)
-                   for kind, (n, t) in shapes.items() for name in ("k", "v")},
-                **state}
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, hd)
-    if cfg.kv_quant == "int8":
-        return {
-            "k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.zeros(shape[:-1], jnp.float32),
-            "v_scale": jnp.zeros(shape[:-1], jnp.float32),
-        }
-    return {
-        "k": jnp.zeros(shape, cfg.compute_dtype),
-        "v": jnp.zeros(shape, cfg.compute_dtype),
-        **state,
-    }
-
-
-def init_rolling_cache(cfg: LlamaConfig, batch: int) -> dict:
-    """O(window) cache for sliding-window models: ``sliding_window`` slots
-    per layer, written modulo the window (see ``decode_step(rolling=True)``).
-    Generation length no longer bounds cache memory."""
-    if cfg.sliding_window is None:
-        raise ValueError("rolling caches require cfg.sliding_window")
-    return init_cache(cfg, batch, cfg.sliding_window)
-
-
-def cache_len(cache: dict) -> int:
-    """Positions a cache's rows hold: the T axis sits at index 3 of every
-    leaf that has one (the ring leaves of a ``cfg.kinds`` cache hold a
-    window's and a linear layer's state has none; this is the full
-    layers' length)."""
-    for name in ("k", "ckv"):
-        if name in cache:
-            return cache[name].shape[3]
-    return next(iter(cache.values())).shape[3]
-
-
-def is_state(name: str) -> bool:
-    """Whether a cache leaf is a linear layer's state: no position axis,
-    the whole of a row's entry is the request's (:func:`init_cache`)."""
-    return name.startswith("kda_")
-
-
-def ring_fold(a, lengths, window: int):
-    """A ring out of whole rows: ``a [L, B, Hkv, S(, D)]`` holds positions
-    ``0 .. S - 1`` of which row b's first ``lengths[b]`` are real; returns
-    ``[L, B, Hkv, window(, D)]`` whose slot ``s`` holds row b's LATEST real
-    position ``p`` with ``p % window == s``: the layout ``pos % window``
-    writes leave behind (``window``: the RING's length, which a
-    ``LayerKinds.slack`` makes longer than the attention window).  Slots
-    no real position reached yet hold junk that the decode steps overwrite
-    before the cursor lets them be read."""
-    last = jnp.asarray(lengths, jnp.int32).reshape(-1, 1) - 1       # [B, 1]
-    src = last - (last - jnp.arange(window, dtype=jnp.int32)[None, :]) % window
-    src = jnp.clip(src, 0, a.shape[3] - 1)                          # [B, W]
-    return jnp.take_along_axis(
-        a, src.reshape((1, -1, 1, window) + (1,) * (a.ndim - 4)), axis=3)
-
-
-def _ring_names(cache: dict) -> dict:
-    """The leaves that hold rings, by the plain name of each: a cache with
-    ``*_ring`` leaves keeps them there; else every leaf is one (a
-    whole-model rolling cache, ``init_rolling_cache``)."""
-    if "k_ring" in cache:
-        return {"k": "k_ring", "v": "v_ring"}
-    return {name: name for name in cache}
-
-
-def mtp_rows(cache: dict) -> dict:
-    """An MTP block's own rows as a cache of their own, under the plain
-    names (``k`` / ``v``): what :func:`cached_layer_scan` and
-    :func:`_write_cached` take."""
-    return {"k": cache["k_mtp"], "v": cache["v_mtp"]}
-
-
-def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig,
-                 ring: bool = False):
-    """``attend`` of :func:`cached_layer_scan` over a cache of either kind,
-    queries ``q [B, Hq, C, D]`` at ``pos[b] ..`` (C=1 is
-    single-token decode; C>1 the speculative chunk verify, whose entries
-    are already written: write-then-attend): grouped k/v
-    (``ops.cached_attention``, windowed and int8-aware), or the latent
-    rows of ``cfg.latent`` (absorbed queries in, ``P c_kv`` out;
-    ``ops.latent_attention``).  ``ring``: the layer's entries lie in a
-    ring (written at ``pos % T``).  A ring of exactly one window: its
-    warm slots ARE the window, so every slot up to the clamped cursor is
-    attended and nothing is masked again; cold slots (> pos) are masked by
-    the clamped position.  A ring LONGER than its window
-    (``LayerKinds.slack``): every slot is read under the mask of the
-    position it holds, ``i - window < j <= i`` for the query at ``i``."""
-    if "ckv" in cache:
-        return latent_attention(q, cache["ckv"], pos,
-                                rank=cfg.latent.kv_rank,
-                                sm_scale=cfg.latent.sm_scale, layer=layer)
-    if ring:
-        at = _ring_names(cache)
-        scales = {n: cache[at[n]] for n in ("k_scale", "v_scale") if n in at}
-        window = cfg.kinds.window if "k_ring" in cache else cfg.sliding_window
-        return cached_attention(
-            q, cache[at["k"]], cache[at["v"]], pos, layer=layer, ring=True,
-            window=None if cache[at["k"]].shape[3] == window else window,
-            **scales)
-    return cached_attention(q, cache["k"], cache["v"], pos, layer=layer,
-                            window=cfg.sliding_window,
-                            k_scale=cache.get("k_scale"),
-                            v_scale=cache.get("v_scale"))
-
-
-def _write_cached(cache: dict, new: dict, layer, pos, rows=None,
-                  count=None, ring: bool = False) -> dict:
-    """The C new positions of one layer into the stacked cache, every leaf
-    (k, v and, int8, their scales): ``cache[name][layer, rows[b], :,
-    pos[b] + c] = new[name][b, :, c]``.  ``new[name]``: [B, Hkv, C(, D)];
-    ``pos``: scalar or per-row [B]; ``rows`` (default ``arange(B)``): the
-    cache row each batch row owns — the paged pool passes page ids, with
-    ``pos`` the offsets inside them.  A start above ``T - C`` is clamped,
-    as ``lax.dynamic_update_slice`` does; with ``count`` ([B]) only each
-    row's first ``count[b]`` positions are written and nothing is clamped
-    (``ops.cache_write``).  ``ring``: the layer's entries lie in a ring
-    and ``pos`` is the ABSOLUTE position: position ``pos + c`` goes to
-    ``(pos + c) % T`` of the ring leaves, ``layer`` counting them (one
-    write a position: two of a chunk may lie at the ring's two ends).
-
-    The write itself is ``ops.cache_write``: on the chip in place, a tile
-    a row.  Either XLA form (a scatter, or ``dynamic_update_slice`` per
-    row) makes the chip's compiler re-lay the scan's carry for the write
-    and copy the whole stacked cache back for the kernel, every layer."""
-    B = next(iter(new.values())).shape[0]
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
-    rows = jnp.arange(B) if rows is None else rows
-    layer = jnp.asarray(layer, jnp.int32)
-    out = dict(cache)
-    if ring:
-        at = _ring_names(cache)
-        T = cache[at["k"]].shape[3]
-        groups = [(at["k"], at["v"])] + (
-            [(at["k_scale"], at["v_scale"])] if "k_scale" in at else [])
-        new = {at[name]: x for name, x in new.items()}
-        C = next(iter(new.values())).shape[2]
-        writes = [(new, lax.rem(pos, T))] if C == 1 else [
-            ({n: x[:, :, c:c + 1] for n, x in new.items()},
-             lax.rem(pos + c, T)) for c in range(C)]
-    else:
-        groups = ([("ckv",)] if "ckv" in cache else [("k", "v")] + [
-            ("k_scale", "v_scale")] * ("k_scale" in cache))
-        writes = [(new, pos)]
-    for new, pos in writes:
-        for names in groups:  # same-shaped leaves share one kernel call
-            out.update(zip(names, cache_write(
-                tuple(out[name] for name in names),
-                tuple(new[name] for name in names), layer, rows, pos, count)))
-    return out
 
 
 def decode_step(params: dict, cache: dict, token, pos, cfg: LlamaConfig,
@@ -283,11 +56,10 @@ def decode_step_counted(params: dict, cache: dict, token, pos,
     layers' rows (``init_cache``) and :func:`cached_layer_scan` says which
     a layer has: the same write and the same attention."""
     T = cache_len(cache)
-    if rolling:
-        if cfg.sliding_window is None or T != cfg.sliding_window:
-            raise ValueError(
-                f"rolling decode needs a cache of exactly sliding_window="
-                f"{cfg.sliding_window} slots, got {T}")
+    if rolling and not taken_for_rolling(cfg, T):
+        raise ValueError(
+            f"rolling decode needs a cache of exactly sliding_window="
+            f"{cfg.sliding_window} slots, got {T}")
     if rope is None:
         if rolling:
             # Absolute positions exceed the cache size; the caller knows the
@@ -344,7 +116,7 @@ def ingest_decode_step(params: dict, cache: dict, token, pos, piece,
     k/v cache without a window.  (An int8 cache's scale leaves go through
     the same lines, but its pieces would attend over quantized entries
     where a prefill reads them exact, so no server sends one here:
-    ``serving.SlotServer._ingest_widths``.)
+    ``cache.CacheSpec.piecewise``.)
 
     Returns ``(logits [B, V], cache, piece_logits [V], counts)``:
     ``piece_logits`` are the next-token logits of the piece's last valid
@@ -369,10 +141,8 @@ def ingest_decode_step(params: dict, cache: dict, token, pos, piece,
                              count=one(valid))
 
     def attend(q, cache, layer):
-        mine = ingest_attention(
-            jnp.swapaxes(q[B:], 0, 2), cache["k"], cache["v"], one(first),
-            one(slot), layer=layer, k_scale=cache.get("k_scale"),
-            v_scale=cache.get("v_scale"))
+        mine = attend_piece(jnp.swapaxes(q[B:], 0, 2), cache, one(first),
+                            one(slot), layer)
         return jnp.concatenate([attend_cache(q[:B], cache, pos, layer, cfg),
                                 jnp.swapaxes(mine, 0, 2)])
 
@@ -527,28 +297,10 @@ def prefill(params: dict, cfg: LlamaConfig, prompt,
         lengths=None if logit_positions is None else logit_positions + 1,
         return_hidden=return_hidden,
     )
-    state = {name: kv.pop(name) for name in list(kv) if is_state(name)}
-    cache = dict(kv)
-    if cfg.kv_quant == "int8":
-        from ..ops.quantize import quantize_kv
-
-        cache["k"], cache["k_scale"] = quantize_kv(kv["k"])
-        cache["v"], cache["v_scale"] = quantize_kv(kv["v"])
-    rings = {}
-    if cfg.kinds is not None:  # the window layers' whole rows -> rings
-        lengths = (jnp.full((B,), P, jnp.int32) if logit_positions is None
-                   else logit_positions + 1)
-        rings = {name: ring_fold(cache.pop(name), lengths, cfg.kinds.ring)
-                 for name in [n for n in cache if n.endswith("_ring")]}
-    pad = max_len - P
-    if pad:
-        # Every leaf's T axis sits at index 3 (the scale arrays only drop
-        # the trailing D dim) — same invariant the ring fold relies on.
-        cache = jax.tree_util.tree_map(
-            lambda a: jnp.pad(
-                a, ((0, 0),) * 3 + ((0, pad),) + ((0, 0),) * (a.ndim - 4)),
-            cache)
-    return (logits[:, 0], {**cache, **rings, **state}, *hidden)
+    lengths = (jnp.full((B,), P, jnp.int32) if logit_positions is None
+               else logit_positions + 1)
+    return (logits[:, 0],
+            from_forward(cache_spec(cfg, max_len), kv, lengths), *hidden)
 
 
 def prefill_rolling(params: dict, cfg: LlamaConfig, prompt, *,
@@ -642,7 +394,7 @@ def _compiled_prefill_chunk(cfg: LlamaConfig):
     W = cfg.sliding_window
     n_rep = cfg.n_heads // cfg.n_kv_heads
 
-    quant = cfg.kv_quant == "int8"
+    quant = cache_spec(cfg, W, rolling=True).int8
 
     def run_chunk(params, cache, tokens_c, c0, cos_c, sin_c):
         """One chunk through every layer; returns (h, new cache)."""
@@ -654,22 +406,15 @@ def _compiled_prefill_chunk(cfg: LlamaConfig):
         order = (c0 - W + jnp.arange(W)) % W
         h = embed_tokens(params, tokens_c, cfg)  # [B, Cc, D]
 
-        def chunk_attn(kc, vc, ksc, vsc):
-            """attn_fn for decoder_layer: past (the rolling cache, in
-            position order) + present (the chunk itself, causal) as two
-            mergeable online-softmax partials.  int8 caches dequantize the
-            gathered window up front — an O(window) transient per layer,
-            matching the path's O(chunk + window) memory contract."""
+        def chunk_attn(mine):
+            """attn_fn for decoder_layer: past (the layer's leaves of the
+            rolling cache, ``mine``, in position order) + present (the
+            chunk itself, causal) as two mergeable online-softmax partials.
+            int8 caches dequantize the gathered window up front — an
+            O(window) transient per layer, matching the path's O(chunk +
+            window) memory contract."""
             def attn(q, k, v):
-                kco = jnp.take(kc, order, axis=2)
-                vco = jnp.take(vc, order, axis=2)
-                if quant:
-                    from ..ops.quantize import dequantize_kv
-
-                    kco = dequantize_kv(kco, jnp.take(ksc, order, axis=2),
-                                        q.dtype)
-                    vco = dequantize_kv(vco, jnp.take(vsc, order, axis=2),
-                                        q.dtype)
+                kco, vco = ring_in_order(mine, order, q.dtype)
                 past = partial_attention(
                     q, repeat_kv(kco, n_rep), repeat_kv(vco, n_rep),
                     q_offset=c0, kv_offset=c0 - W, causal=True, window=W,
@@ -689,21 +434,12 @@ def _compiled_prefill_chunk(cfg: LlamaConfig):
         new = {name: [] for name in cache}
         for li in range(cfg.n_layers):
             lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
-            kc, vc = cache["k"][li], cache["v"][li]
-            ksc = cache["k_scale"][li] if quant else None
-            vsc = cache["v_scale"][li] if quant else None
+            mine = {name: a[li] for name, a in cache.items()}
             h, _aux, kv, _stats = decoder_layer(lp, h, cfg, cos_c, sin_c,
-                                                chunk_attn(kc, vc, ksc, vsc))
-            k, v = kv["k"], kv["v"]
-            if quant:
-                from ..ops.quantize import quantize_kv
-
-                k, k_s = quantize_kv(k)
-                v, v_s = quantize_kv(v)
-                new["k_scale"].append(ksc.at[:, :, slots].set(k_s))
-                new["v_scale"].append(vsc.at[:, :, slots].set(v_s))
-            new["k"].append(kc.at[:, :, slots, :].set(k))
-            new["v"].append(vc.at[:, :, slots, :].set(v))
+                                                chunk_attn(mine))
+            kv = quantize_rows(kv) if quant else kv
+            for name in cache:
+                new[name].append(mine[name].at[:, :, slots].set(kv[name]))
         return h, {name: jnp.stack(v) for name, v in new.items()}
 
     # The caller rebinds its cache to the returned one each chunk, so the

@@ -174,7 +174,7 @@ class LayerKinds:
     windows of a model are of one length: its cache then holds the full
     layers' rows of ``max_len`` beside the window layers' RINGS of that
     length and the linear layers' STATE, which has no position axis at all
-    (models/generate.py::init_cache).  ``slack``: positions a ring holds
+    (models/cache.py::init_cache).  ``slack``: positions a ring holds
     BEYOND its window.  0: the ring's warm slots ARE the window and a step
     writes one position.  A step that writes ``C`` positions before it
     knows which of them stay (a draft beside the pending token) needs
@@ -471,7 +471,7 @@ class LlamaConfig:
     def kind_layers(self, kind: str, upto: Optional[int] = None) -> int:
         """Layers of that cache kind among the first ``upto`` (default:
         all): a layer's index among those of its kind, which is where it
-        lies in that kind's stacked leaves (generate.init_cache)."""
+        lies in that kind's stacked leaves (cache.init_cache)."""
         upto = self.n_layers if upto is None else upto
         return sum(self.cache_kind(i) == kind for i in range(upto))
 

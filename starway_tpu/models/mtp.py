@@ -15,7 +15,7 @@ subtree ``params["mtp"]``: ``embed_norm`` / ``hidden_norm`` / ``final_norm
 [D]``, ``w_eh [2D, D]`` and ``layers``, a stacked segment of ONE layer with
 the leaves of the model's attention layers (``llama._init_block_params``).
 Its attention keeps a full row of its own a request, ``k_mtp`` / ``v_mtp``
-beside the model's cache leaves (``generate.init_cache``): row ``t`` is
+beside the model's cache leaves (``cache.init_cache``): row ``t`` is
 ``u_t``'s entry.
 
 Nothing here is a second model: the layer is
@@ -100,8 +100,8 @@ def mtp_chunk(params: dict, cfg: LlamaConfig, cache: dict, hidden,
     :func:`~starway_tpu.models.speculative.chunk_decode_step`).  ``hidden``
     / ``next_tokens`` as :func:`mtp_inputs` takes them.  Returns ``(m [B,
     C, D], cache)``."""
-    from .generate import (_write_cached, attend_cache, cached_layer_scan,
-                           mtp_rows)
+    from .cache import _write_cached, attend_cache, mtp_rows
+    from .generate import cached_layer_scan
 
     mcfg = mtp_config(cfg)
     u = mtp_inputs(params, cfg, hidden, next_tokens)

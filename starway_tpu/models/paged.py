@@ -45,11 +45,12 @@ from jax import lax
 
 from ..ops import paged_attention
 from ..ops.pallas_decode import kv_write_lax
-from .generate import _sample, _write_cached, cached_layer_scan, prefill
+from .cache import _write_cached, require_rows
+from .generate import _sample, cached_layer_scan, prefill
 from .llama import (LlamaConfig, cfg_rmsnorm, cfg_rope_tables, embed_tokens,
                     matmul_w)
-from .serving import (SlotServer, _bucket, _named_jit, _on_weights_mesh,
-                      make_chunk_scan_step)
+from .serving import (NoRoomYet, SlotServer, _bucket, _named_jit,
+                      _on_weights_mesh, make_chunk_scan_step)
 
 
 def init_paged_pool(cfg: LlamaConfig, n_pages: int, page: int) -> dict:
@@ -255,10 +256,11 @@ class PagedSlotServer(SlotServer):
     page``), not per-slot reservations, so short requests don't pay for
     ``max_len``, and pages recycle the moment a request finishes.
     A request whose prompt the pool cannot cover yet simply STAYS
-    QUEUED (step() catches the allocator's RuntimeError and retries once
-    in-flight work frees pages); lazy per-chunk growth exhausting the
-    pool mid-generation raises RuntimeError — preemption is not wired,
-    so size ``n_pages`` for the expected concurrency.
+    QUEUED (step() catches the allocator's ``NoRoomYet``, a RuntimeError,
+    and retries once in-flight work frees pages); lazy per-chunk growth
+    exhausting the pool mid-generation raises it out of step() —
+    preemption is not wired, so size ``n_pages`` for the expected
+    concurrency.
     """
 
     def __init__(self, params, cfg: LlamaConfig, *, n_slots: int = 4,
@@ -268,32 +270,8 @@ class PagedSlotServer(SlotServer):
                  top_p: Optional[float] = None,
                  eos_id: Optional[int] = None, seed: int = 0,
                  on_tokens=None):
-        if cfg.mtp:
-            raise NotImplementedError(
-                "the page pool's step writes one position a slot: an MTP "
-                "block's draft beside it, and the block's own row, need "
-                "pages that a rejection gives back; such a model serves "
-                "through the dense SlotServer (ROADMAP M5)")
-        if cfg.linear is not None:
-            raise NotImplementedError(
-                "the page pool holds rows a position: a linear-attention "
-                "layer's state (cfg.linear) is one matrix a request, with "
-                "nothing to page; such a model serves through the dense "
-                "SlotServer (ROADMAP M4: state in the pool)")
-        if cfg.sliding_window is not None or cfg.kinds is not None:
-            raise NotImplementedError(
-                "paged serving v1 is full-causal; sliding-window models "
-                "already serve in O(window) via the rolling SlotServer, "
-                "window layers beside full ones via the dense one "
-                "(ROADMAP M1: a pool with window pages)")
-        if cfg.kv_quant != "none":
-            raise NotImplementedError(
-                "int8 paged pools are not wired yet; use the dense "
-                "SlotServer for kv_quant='int8'")
-        if cfg.latent is not None:
-            raise NotImplementedError(
-                "the page pool has no latent page kind yet (ROADMAP M3); "
-                "latent-attention models serve through the dense SlotServer")
+        # The pool pages full rows of dense k / v, every layer alike.
+        require_rows(cfg, "page", NotImplementedError)
         if max_len % page:
             raise ValueError(f"page ({page}) must divide max_len "
                              f"({max_len})")
@@ -359,7 +337,7 @@ class PagedSlotServer(SlotServer):
         if n_needed > self.max_pages:
             n_needed = self.max_pages
         if n_needed > have and len(self._free) < n_needed - have:
-            raise RuntimeError(
+            raise NoRoomYet(
                 f"page pool exhausted: slot {slot} needs "
                 f"{n_needed - have} more page(s), {len(self._free)} free "
                 f"(n_pages={self.n_pages}); finish/cancel requests or "
@@ -385,7 +363,7 @@ class PagedSlotServer(SlotServer):
         plen = len(tokens)
         n_full = plen // self.page
         if len(self._free) < n_full:
-            raise RuntimeError(
+            raise NoRoomYet(
                 f"page pool exhausted: prefix needs {n_full} page(s), "
                 f"{len(self._free)} free")
         pids = [self._free.pop() for _ in range(n_full)]
@@ -449,7 +427,7 @@ class PagedSlotServer(SlotServer):
         need = -(-(plen + sb) // self.page)
         n_own = need - n_full
         if len(self._free) < n_own:
-            raise RuntimeError(
+            raise NoRoomYet(
                 f"page pool exhausted: prefixed admission needs {n_own} "
                 f"own page(s), {len(self._free)} free")
         row = self._tables[slot]

@@ -20,8 +20,8 @@ a FIXED, small set of compiled programs:
   before any read).  Host round-trips happen once per chunk, not once
   per token.
 * **Prompts ride the decode chunk** (dense k/v caches that hold k and v
-  as computed, no window; the one decision is
-  ``SlotServer._ingest_widths``).  A request that takes a
+  as computed, no window; the one decision is the kind's,
+  ``cache.CacheSpec.piecewise``).  A request that takes a
   free slot launches nothing: the host lays its prompt over the coming
   chunks' steps in pieces of ``W`` tokens (``INGEST_WIDTHS``, the smallest
   width that brings what waits in within a chunk: all of it, or the
@@ -82,9 +82,9 @@ the rope horizon.  A model whose layers differ in kind (``cfg.kinds``:
 window layers beside full ones) serves through the SAME dense programs
 with two kinds of cache leaves side by side: the full layers' rows of
 ``max_len`` and the window layers' rings of one window a slot
-(``generate.init_cache``); its bucketed admit programs fold each window
+(``cache.init_cache``); its bucketed admit programs fold each window
 layer's last ``window`` prompt positions into the slot's ring
-(``generate.ring_fold``).  MoE models serve when capacity is provably dropless
+(``cache.ring_fold``).  MoE models serve when capacity is provably dropless
 (``moe_capacity_factor >= n_experts``): expert capacity is shared
 batch-wide, so slot cohabitation could otherwise perturb routing — the
 same rule as ragged ``generate()``.  A model with linear-attention
@@ -110,9 +110,9 @@ from jax import lax
 
 from .. import perf
 from ..core import swtrace
-from .generate import (_filter_logits, _sample, cache_len,
-                       decode_step_counted, ingest_decode_step, init_cache,
-                       init_rolling_cache, is_state, prefill)
+from .cache import cache_len, served_spec, state_names
+from .generate import (_filter_logits, _sample, decode_step_counted,
+                       ingest_decode_step, prefill)
 from .llama import LlamaConfig, cfg_rope_tables, head_logits
 
 # ----------------------------------------------------------- the serve logs
@@ -224,6 +224,13 @@ def log_request(row: dict) -> None:
     _request_log.append(row)
 
 
+class NoRoomYet(RuntimeError):
+    """An admission found no room for its request YET (the page pool's
+    pages are out): raised before anything of the request was launched,
+    and the one error :meth:`SlotServer.step` keeps a request queued
+    for."""
+
+
 def _named_jit(fn, name: str, **jit_kwargs):
     """``jax.jit`` under a name of the program's own: the profiler's ``XLA
     Modules`` line then reads ``jit_<name>(...)`` instead of ``jit_run``, so
@@ -252,10 +259,10 @@ def _write_slot(cache, small, slot):
             cache[name], small[name].astype(cache[name].dtype),
             (0, slot) + (0,) * (cache[name].ndim - 2))
 
-    rows = {name: put(name) for name in cache if not is_state(name)}
+    state = state_names(cache)
+    rows = {name: put(name) for name in cache if name not in state}
     with jax.named_scope("sw_kda_seat"):
-        return {**rows,
-                **{name: put(name) for name in cache if is_state(name)}}
+        return {**rows, **{name: put(name) for name in state}}
 
 
 def _write_slot_and_sample(cache, small, logits, slot, key, temperature,
@@ -758,7 +765,7 @@ class SlotServer:
     tokens INCLUDE the terminating eos (when ``eos_id`` fires).
 
     WHICH PATH a request takes is decided from the cache this server
-    holds (:meth:`_ingest_widths`), never from a model's name.  Dense k/v
+    holds (``self.spec.piecewise``), never from a model's name.  Dense k/v
     leaves as computed (no int8 scales, no window): the request takes its
     slot at once and its prompt is ingested inside the decode chunks that
     follow, a piece a step, beside the slots that decode; no admit program
@@ -818,7 +825,11 @@ class SlotServer:
                 "on_logprobs hands out what a speculating server's accept "
                 "rule computed: it needs a configuration with an MTP block "
                 "(generate(return_logprobs=True) serves the others)")
-        self.rolling = cfg.sliding_window is not None
+        # What this server's cache holds (models/cache.py): the one
+        # description every decision below reads.  ``rolling``: per-slot
+        # rings of one window, a whole-model window's.
+        self.spec = served_spec(cfg, max_len)
+        self.rolling = self.spec.rolling
         if n_slots < 1 or chunk < 1:
             # Zero slots/chunk would make run() spin forever, not error.
             raise ValueError(f"need n_slots >= 1 and chunk >= 1, got "
@@ -855,11 +866,6 @@ class SlotServer:
         # not cache memory.  (_make_cache is a subclass hook: the paged
         # server allocates a shared page pool instead — models/paged.py.)
         self.cache = self._make_cache()
-        # Positions a window layer's ring holds, 0 with no ring leaves.
-        self._ring = (self.cache["k_ring"].shape[3]
-                      if "k_ring" in self.cache else 0)
-        # Whether it holds linear-attention state (no position axis).
-        self._state = any(is_state(name) for name in self.cache)
         # How a prompt enters that cache: () = by an admit program.
         self._widths = self._ingest_widths()
         self.token = jnp.zeros((n_slots,), jnp.int32)
@@ -927,34 +933,21 @@ class SlotServer:
 
     # ------------------------------------------------------------ intake
     def _make_cache(self):
-        return (init_rolling_cache(self.cfg, self.n_slots) if self.rolling
-                else init_cache(self.cfg, self.n_slots, self.max_len))
+        return self.spec.zeros(self.n_slots)
 
     def _ingest_widths(self) -> tuple:
-        """THE decision of how a prompt enters the cache, made once, from
-        the cache this server holds: a slot's row of dense k/v leaves of
-        ``max_len`` positions, holding k and v as computed, takes its
-        prompts piece by piece inside the decode chunk, at the widths
-        returned (those of ``INGEST_WIDTHS`` the plan can choose,
-        :meth:`_plan_ingest`: the last is the first at which the longest
-        prompt this cache holds comes in within one chunk).  Every other
-        kind keeps its admit programs and gets ``()``: a latent cache
-        (``ckv``), a linear layer's state beside latent or grouped-query
-        rows (``kda_state``: a piece would have to move the state on by W
-        tokens inside a decode step),
-        a rolling window, rings beside the full rows (``k_ring``:
-        a piece would have to attend over a ring its own later tokens
-        overwrite), an int8 cache (a piece attends over
-        what the cache holds, quantized there, where a prefill reads the
-        prompt's k/v exact: other tokens than ``generate()``'s), a model
-        with an MTP block (its admission runs the block over the prompt
-        and seats its row and the first draft) and a
-        subclass with a layout of its own (the page pool overrides this).
-        A ``prefix=`` request takes its admit program on every kind
-        (:meth:`_ingests`)."""
-        if (self.rolling or self._ring or self._state
-                or "k" not in self.cache or "k_scale" in self.cache
-                or self.cfg.mtp):
+        """The widths at which a prompt enters the cache piece by piece
+        inside the decode chunk; ``()``: by an admit program.  WHETHER a
+        cache takes pieces is its kind's to say
+        (:attr:`~starway_tpu.models.cache.CacheSpec.piecewise`, which
+        lists the kinds that keep their admit programs and why; a subclass
+        with a layout of its own, the page pool, overrides this); the
+        widths are the scheduler's: those of ``INGEST_WIDTHS`` the plan
+        can choose (:meth:`_plan_ingest`), the last being the first at
+        which the longest prompt this cache holds comes in within one
+        chunk.  A ``prefix=`` request takes its admit program on every
+        kind (:meth:`_ingests`)."""
+        if not self.spec.piecewise:
             return ()
         widths = []
         for w in INGEST_WIDTHS:
@@ -1008,11 +1001,11 @@ class SlotServer:
             raise ValueError("prefix caching copies the model's rows; an "
                              "MTP block's own row over the prefix would "
                              "have to be kept and copied too (ROADMAP M5)")
-        if self.rolling or self._ring:
+        if self.spec.ring:
             raise ValueError("prefix caching needs the dense slot cache; "
                              "rolling (sliding-window) slots rebuild their "
                              "window per request anyway")
-        if self._state:
+        if self.spec.state:
             raise ValueError("prefix caching copies cache rows by position; "
                              "a linear-attention layer's state has none: a "
                              "prefix would need a snapshot of the state at "
@@ -1338,32 +1331,22 @@ class SlotServer:
                                 0 if not self.buckets or self._ingests(prefix)
                                 else _bucket(len(prompt), self.buckets))
                         self._admit(free.pop(0), rid, prompt, max_new, prefix)
-                except RuntimeError:
+                except NoRoomYet:
                     # Transient resource exhaustion (the paged server's
                     # pool), raised before the request's admit program
                     # was launched: it STAYS QUEUED, where it was —
                     # in-flight work frees capacity and a later step
                     # admits it (the class docstring's "callers keep it
-                    # queued / retry" contract).
+                    # queued / retry" contract).  Any other error of an
+                    # admission is a fault and surfaces: kept queued, a
+                    # request that can never be admitted spins run().
                     self._pending.insert(at, (rid, prompt, max_new, prefix))
                     break
                 step["admits"] += 1
             step["queued"], step["live"] = (len(self._pending),
                                             len(self._slot_rid))
-            if self._ring or self._state:
-                # (a speculating step's verify attends from pos + 1 too)
-                at = 1 + self.cfg.mtp + self._pos_host[
-                    [s for s in self._slot_rid
-                     if self._live_host[s]]].astype(int)
-                if self._ring:
-                    step.update(
-                        kv_rows_full=int(at.sum()),
-                        kv_rows_window=int(np.minimum(at, self._ring).sum()))
-                else:   # state beside rows: latent, or grouped-query k / v
-                    rows = ("kv_rows_latent" if "ckv" in self.cache
-                            else "kv_rows_full")
-                    step.update({"state_slots": len(at),
-                                 rows: int(at.sum())})
+            step.update(self.spec.step_rows(self._pos_host[
+                [s for s in self._slot_rid if self._live_host[s]]]))
             # A slot the host knows dead already (a one-token request just
             # admitted) needs no chunk; one whose first token may be its
             # eos is found out after the chunk was queued, and rides it

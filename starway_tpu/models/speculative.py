@@ -40,8 +40,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .generate import (_filter_logits, _sample, _write_cached, attend_cache,
-                       cache_len, cached_layer_scan, prefill)
+from .cache import (_write_cached, attend_cache, require_chunk_rows,
+                    taken_for_rolling)
+from .generate import _filter_logits, _sample, cached_layer_scan, prefill
 from .llama import LlamaConfig, cfg_rope_tables, embed_tokens, head_logits
 
 
@@ -70,11 +71,11 @@ def chunk_decode_step(params, cache, tokens, pos, cfg: LlamaConfig, rope):
     refuses.  The whole-model rolling cache is not supported (speculative
     decoding targets the full-cache path) — a window-sized cache raises
     rather than silently writing absolute positions into a modular window.
-    The check is a shape heuristic (rolling and full caches share a
-    layout), so a FULL cache allocated with max_len exactly ==
-    sliding_window is rejected too; allocate max_len = window + C for
-    ingestion — positions past the window are masked out of attention
-    anyway, so the extra slots change nothing.
+    The check is a shape heuristic (``cache.taken_for_rolling``: rolling
+    and full caches share a layout), so a FULL cache allocated with
+    max_len exactly == sliding_window is rejected too; allocate max_len =
+    window + C for ingestion — positions past the window are masked out of
+    attention anyway, so the extra slots change nothing.
     """
     h, out, _ = chunk_decode_hidden(params, cache, tokens, pos, cfg, rope)
     return head_logits(h, params["final_norm"], params["lm_head"],
@@ -88,33 +89,7 @@ def chunk_decode_hidden(params, cache, tokens, pos, cfg: LlamaConfig, rope):
     What a server that drafts with an MTP block verifies with: the block
     reads ``h`` (models/mtp.py)."""
     B, C = tokens.shape
-    T_cache = cache_len(cache)
-    if "kda_state" in cache:
-        raise ValueError(
-            "chunk_decode_step does not support linear-attention layers "
-            "(cfg.linear): a state moved on by C tokens cannot be taken "
-            "back to where the accepted ones end (ROADMAP M4: snapshots)")
-    if "k_ring" in cache and (cache["k_ring"].shape[3]
-                              < cfg.kinds.window + C - 1):
-        raise ValueError(
-            f"chunk_decode_step needs rings of at least window + C - 1 = "
-            f"{cfg.kinds.window + C - 1} positions, got "
-            f"{cache['k_ring'].shape[3]}: a chunk written into a ring of "
-            f"one window overwrites entries its own earlier positions "
-            f"attend (LayerKinds.slack lengthens the rings)")
-    if cfg.sliding_window is not None and T_cache == cfg.sliding_window:
-        # Mirrors decode_step's rolling-cache shape check, inverted: a
-        # cache of exactly sliding_window slots is a rolling cache
-        # (init_rolling_cache), whose modular slots this absolute-position
-        # write-then-attend cannot address — dynamic_update_slice would
-        # clamp the write and the masks would lie.
-        raise ValueError(
-            f"chunk_decode_step does not support rolling caches: got a "
-            f"{T_cache}-slot cache == cfg.sliding_window, which is "
-            f"init_rolling_cache's layout; allocate a full cache "
-            f"(init_cache with max_len != sliding_window — positions past "
-            f"the window are masked anyway, so max_len = window + C costs "
-            f"nothing) for chunk verify / multi-token ingestion")
+    require_chunk_rows(cfg, cache, C)
     cos, sin = rope
     pos = jnp.asarray(pos, jnp.int32)
     pos_b = pos if pos.ndim == 1 else jnp.broadcast_to(pos, (B,))
@@ -553,7 +528,7 @@ def generate_speculative(params: dict, cfg: LlamaConfig, draft_params: dict,
     # Cache headroom: a macro step may write up to gamma - 1 positions
     # past the last kept token before the row's budget check stops it.
     max_len = P + max_new_tokens + gamma
-    if max_len == cfg.sliding_window:
+    if taken_for_rolling(cfg, max_len):
         # Dodge chunk_decode_step's rolling-cache shape heuristic (a FULL
         # cache of exactly window slots is indistinguishable from the
         # rolling layout); the extra slot is masked out of attention.
@@ -654,7 +629,7 @@ def generate_lookup(params: dict, cfg: LlamaConfig, prompt,
     # matching generate()'s regime for the same request (spec decode's
     # output-equivalence contract); the gamma headroom below is scratch.
     max_len = P + max_new_tokens + gamma
-    if max_len == cfg.sliding_window:
+    if taken_for_rolling(cfg, max_len):
         # Dodge chunk_decode_step's rolling-cache shape heuristic (a FULL
         # cache of exactly window slots is indistinguishable from the
         # rolling layout); the extra slot is masked out of attention.

@@ -24,6 +24,15 @@ def use_kernels() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def interpret() -> bool:
+    """A Pallas kernel's default ``interpret=``: off the chip a kernel
+    that IS called runs interpreted.  Not :func:`use_kernels`' question:
+    a test that forces the kernels on off the chip (``force_kernels``)
+    substitutes that decision and still gets them interpreted, by this
+    one."""
+    return jax.default_backend() != "tpu"
+
+
 def per_head_shard(kernel, sharded, replicated=(), *, axis: str = "tp",
                    head_dims=None, out_head_dims=None):
     """``kernel(*sharded, *replicated)``, run per shard of the head

@@ -661,7 +661,7 @@ def flash_partial(q, k, v, q_offset, kv_offset, *, causal: bool = True,
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret()
     b, hq, s, d = q.shape
     hkv = k.shape[1]
     n_rep = hq // hkv
@@ -751,7 +751,7 @@ def flash_partial_bwd(q, do, k, v, lse, delta, q_offset, kv_offset, *,
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret()
     b, hq, s, d = q.shape
     hkv = k.shape[1]
     kv_len = k.shape[2]
@@ -850,7 +850,7 @@ def flash_attention(
     if sm_scale is None:
         sm_scale = 1.0 / (q.shape[-1] ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret()
     cfg = _Cfg(
         causal=causal,
         sm_scale=float(sm_scale),

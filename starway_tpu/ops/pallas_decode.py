@@ -422,7 +422,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret()
 
     # Group query heads by their kv head: rows of the per-group matmul,
     # packed [n_rep, C] (row r = rep * C + ci — _row_offsets relies on
@@ -740,7 +740,7 @@ def mla_decode_attention(q, latent, pos, *, rank: int, sm_scale: float,
     b, h, n_q, w = q.shape
     t = latent.shape[3]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret()
     block_k = _pick_block(t, block_k, True)
     if block_k is None:
         return mla_decode_attention_lax(q, latent, pos, rank=rank,
@@ -893,7 +893,7 @@ def kv_write(caches, updates, layer, rows, pos, *, count=None,
     hkv, t = c0.shape[2:4]
     n, c = u0.shape[0], u0.shape[2]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret()
     # T is the second-minor (sublane) dim of k/v and the minor (lane) dim
     # of the scales.
     align = 128 if c0.ndim == 4 else 32 // c0.dtype.itemsize

@@ -54,7 +54,7 @@ def int8_matmul(x, wq, scale, *, block_f: "int | None" = None,
     if out_dtype is None:
         out_dtype = x.dtype
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret()
     f128 = _round_up(f, 128)
     if block_f is None:
         # ~4 MB of int8 weight block per buffer, lane-aligned.

@@ -101,7 +101,7 @@ def gmm(x, w, tile_expert, n_live, *, tile_m: int, w2=None, layer=None,
     if m % tile_m:
         raise ValueError(f"rows {m} are not whole tiles of {tile_m}")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret()
     tn = column_block(k, n, w.dtype.itemsize)
     n_col = n // tn
     n_live = jnp.asarray(n_live, jnp.int32).reshape(1)

@@ -138,7 +138,7 @@ def kda_step_kernel(state, q, k, v, g, beta, *, layer, interpret=None):
     """:func:`kda_step` as the Pallas kernel ``sw_kda_step``."""
     _layers, b, h, dk, dv = state.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret()
     hb = _head_block(h)
     f32 = jnp.float32
 
@@ -478,7 +478,7 @@ def kda_chunk_kernel(q, k, v, g, beta, *, chunk: int = 64, interpret=None):
     h, dv = v.shape[2:]
     by_head = g.ndim == 3
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret()
     f32 = jnp.float32
     rep, n = h // hk, s // chunk
     sub = SUB if chunk % SUB == 0 else chunk

@@ -132,7 +132,7 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, *, layer=None,
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret()
 
     n_rows = n_rep * n_q
     rows = _round_up(max(n_rows, 8), 8)

@@ -449,7 +449,7 @@ def _slot_state(n_slots=N_SLOTS):
 
 
 def _chunk_program(cfg, n_slots=N_SLOTS):
-    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.cache import init_cache
     from starway_tpu.models.serving import _compiled_chunk
 
     run = _compiled_chunk(cfg, n_slots, MAX_LEN, CHUNK, 0.0, None, None, None)
@@ -471,7 +471,7 @@ def _ingest_chunk_program(cfg, width, n_slots=N_SLOTS):
 
 
 def _admit_program(cfg, bucket=2048):
-    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.cache import init_cache
     from starway_tpu.models.serving import _compiled_admit
 
     run = _compiled_admit(cfg, bucket, 0.0, None, None)
@@ -563,7 +563,7 @@ def test_decode_chunk_moves_no_cache_for_v5e(topo, monkeypatch, kv):
                                 n_slots=CELL_SLOTS)
     text = compiled.as_text()
     assert "sw_kv_write" in text and "sw_decode_attn_stream" in text
-    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.cache import init_cache
 
     cache_bytes = sum(  # 3.22 GB bf16; 1.66 GB int8 with its f32 scales
         a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(
@@ -594,7 +594,7 @@ def test_ingest_chunk_moves_no_cache_for_v5e(topo, monkeypatch, width):
     text = compiled.as_text()
     assert all(name in text for name in (
         "sw_kv_write", "sw_decode_attn_stream", "sw_ingest_attn"))
-    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.cache import init_cache
 
     cache_bytes = sum(
         a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(
@@ -651,7 +651,7 @@ def test_admission_programs_at_the_cells_sizes_for_v5e(topo, monkeypatch, cell,
     cache."""
     import re
 
-    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.cache import init_cache
     from starway_tpu.models.serving import _compiled_admit, _seat
 
     _as_tpu(monkeypatch)
@@ -699,7 +699,7 @@ def test_linear_layers_admit_in_one_kernel_a_layer_for_v5e(topo, monkeypatch,
     decay a head those two are what lax hands the kernel."""
     import re
 
-    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.cache import init_cache
     from starway_tpu.models.serving import _compiled_admit
 
     _as_tpu(monkeypatch)
@@ -730,7 +730,7 @@ def test_two_cache_kinds_ride_the_decode_chunk_for_v5e(topo, monkeypatch):
     layer, which no CPU test shows)."""
     import re
 
-    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.cache import init_cache
     from starway_tpu.models.serving import _compiled_chunk
 
     _as_tpu(monkeypatch)
@@ -784,7 +784,7 @@ def test_draft_and_verify_chunk_moves_no_rows_or_rings_for_v5e(topo,
     from benchmark.harness import spec as S
     from benchmark.harness import weights_k_exaone as W
     from conftest import sorts_over
-    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.cache import init_cache
     from starway_tpu.models.serving import _compiled_chunk
 
     _as_tpu(monkeypatch)
@@ -838,7 +838,7 @@ def test_state_without_positions_rides_the_decode_chunk_for_v5e(topo,
     layer of it (a second copy of the state would be 3.2 GB)."""
     import re
 
-    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.cache import init_cache
     from starway_tpu.models.serving import _compiled_chunk
 
     _as_tpu(monkeypatch)
@@ -888,7 +888,7 @@ def test_state_beside_grouped_query_rows_rides_the_decode_chunk_for_v5e(
     of the rows 3.2)."""
     import re
 
-    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.cache import init_cache
     from starway_tpu.models.serving import _compiled_chunk
 
     _as_tpu(monkeypatch)

@@ -195,7 +195,7 @@ def test_prefill_then_decode_through_state_and_rows_matches_reference(
 
 
 def test_init_cache_sizes_the_attention_layers_alone(runner):
-    from starway_tpu.models.generate import cache_len, init_cache
+    from starway_tpu.models.cache import cache_len, init_cache
 
     cfg = runner.model_config(TINY)
     small, large = init_cache(cfg, 3, 32), init_cache(cfg, 3, 256)
@@ -362,7 +362,7 @@ def test_a_padded_admission_gives_the_unpadded_state_and_tails(runner, length):
                                   "param_specs", "mtp"])
 def test_paths_that_cannot_hold_a_state_refuse_it(runner, what):
     from starway_tpu.models import PagedSlotServer, SlotServer, generate_beam
-    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.cache import init_cache
     from starway_tpu.models.llama import cfg_rope_tables, param_specs
     from starway_tpu.models.speculative import chunk_decode_step
 

@@ -11,8 +11,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from starway_tpu.models import LlamaConfig, forward, init_params
-from starway_tpu.models.generate import (_filter_logits, decode_step,
-                                         generate, init_cache)
+from starway_tpu.models.cache import init_cache
+from starway_tpu.models.generate import _filter_logits, decode_step, generate
 from starway_tpu.ops.attention import NEG_BIG
 from starway_tpu.models.llama import rope_tables
 
@@ -388,7 +388,7 @@ def test_rolling_cache_matches_full_model():
     greedy generation equals the full re-forward oracle at every step
     (prompt longer AND shorter than the window), and rolling teacher
     forcing matches forward logits past the wrap point."""
-    from starway_tpu.models.generate import init_rolling_cache
+    from starway_tpu.models.cache import init_rolling_cache
 
     cfg = LlamaConfig.preset("debug", sliding_window=5)
     params = init_params(jax.random.PRNGKey(6), cfg)

@@ -143,7 +143,8 @@ def test_moe_ep_dispatches_with_all_to_all():
 def test_single_chip_decode_has_no_collectives_or_host_io(cfg):
     """The decode hot loop: zero collectives, zero host transfers —
     anything else would throttle the bandwidth-bound stream."""
-    from starway_tpu.models.generate import decode_step, init_cache
+    from starway_tpu.models.cache import init_cache
+    from starway_tpu.models.generate import decode_step
     from starway_tpu.models.llama import cfg_rope_tables
 
     params = _abstract_params(cfg)
@@ -180,8 +181,8 @@ def test_layer_scan_carries_the_cache(cfg, path):
     cannot share a buffer (PERF.md, PR 25: half a serving step's device
     time).  Read off the jaxpr, so it holds wherever the program is
     compiled; every path through ``cached_layer_scan`` is held to it."""
-    from starway_tpu.models.generate import (decode_step, init_cache,
-                                             init_rolling_cache)
+    from starway_tpu.models.cache import init_cache, init_rolling_cache
+    from starway_tpu.models.generate import decode_step
     from starway_tpu.models.llama import cfg_rope_tables
     from starway_tpu.models.paged import init_paged_pool, paged_decode_step
     from starway_tpu.models.speculative import chunk_decode_step
@@ -260,7 +261,7 @@ def _admission_programs(cfg):
     """{name: (program, abstract args, donated argument)} of every admit
     program a server can end an admission with, at debug widths."""
     from starway_tpu.models import paged, serving
-    from starway_tpu.models.generate import init_cache, init_rolling_cache
+    from starway_tpu.models.cache import init_cache, init_rolling_cache
 
     sampling = (0.0, None, None)
     s = jax.ShapeDtypeStruct

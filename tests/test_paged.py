@@ -187,6 +187,30 @@ def test_paged_server_pool_exhaustion_is_loud(cfg, params):
         srv.run()
 
 
+def test_only_no_room_yet_keeps_a_request_queued(cfg, params, monkeypatch):
+    """step() keeps a request queued for the pool's ``NoRoomYet`` alone.
+    Any other RuntimeError of an admission (a JaxRuntimeError is one) is a
+    fault and surfaces from step(): kept queued, as every RuntimeError once
+    was, it is admitted again at every step and run() never returns."""
+    from starway_tpu.models.serving import NoRoomYet
+
+    srv = PagedSlotServer(params, cfg, n_slots=2, max_len=64, page=16,
+                          n_pages=3, chunk=4)  # 2 usable pages
+    held = srv.submit(list(range(1, 30)), 1)
+    waits = srv.submit(list(range(1, 30)), 1)  # no page left: NoRoomYet
+    assert list(srv.step()) == [held]
+    assert [rid for rid, *_ in srv._pending] == [waits]
+
+    def fault(*args, **kwargs):
+        raise RuntimeError("the device fell over")
+
+    monkeypatch.setattr(srv, "_admit", fault)
+    with pytest.raises(RuntimeError, match="fell over") as err:
+        srv.step()
+    assert not isinstance(err.value, NoRoomYet)
+    assert not srv._pending and not srv._slot_rid  # nowhere twice
+
+
 def test_paged_server_refusals(cfg, params):
     with pytest.raises(NotImplementedError, match="rolling"):
         PagedSlotServer(params, LlamaConfig.preset("debug",

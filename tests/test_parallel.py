@@ -438,7 +438,7 @@ def test_per_head_shard_stacked_cache(kv, force_kernels):
     and both kernels agree with the unsharded run."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from starway_tpu.models.generate import _write_cached
+    from starway_tpu.models.cache import _write_cached
     from starway_tpu.ops import cached_attention
     from starway_tpu.ops.quantize import quantize_kv
 

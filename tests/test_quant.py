@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from starway_tpu.models import LlamaConfig, SlotServer, init_params
-from starway_tpu.models.generate import generate, init_cache
+from starway_tpu.models.cache import init_cache
+from starway_tpu.models.generate import generate
 from starway_tpu.ops.quantize import dequantize_kv, quantize_kv
 
 
@@ -143,7 +144,8 @@ def test_generate_int8_rolling(params):
     full-size windowed int8 cache step by step — both paths quantize the
     same post-RoPE k/v, so only the softmax's key-summation order differs.
     Then the compiled generate path runs past the wrap point."""
-    from starway_tpu.models.generate import decode_step, init_rolling_cache
+    from starway_tpu.models.cache import init_rolling_cache
+    from starway_tpu.models.generate import decode_step
     from starway_tpu.models.llama import rope_tables
 
     W = 5
@@ -173,8 +175,8 @@ def test_prefill_rolling_int8_tracks_stepwise(params):
     stepwise int8 decode (in-chunk attention is wide in the chunked path
     — the same choice the aligned prefill makes — so exact equality is
     not the contract; a <= 2-ulp int8 cache and close logits are)."""
-    from starway_tpu.models.generate import (decode_step, init_rolling_cache,
-                                             prefill_rolling)
+    from starway_tpu.models.cache import init_rolling_cache
+    from starway_tpu.models.generate import decode_step, prefill_rolling
     from starway_tpu.models.llama import rope_tables
 
     W, P = 6, 17

@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from starway_tpu.models import LlamaConfig, init_params
-from starway_tpu.models.generate import decode_step, generate, init_cache
+from starway_tpu.models.cache import init_cache
+from starway_tpu.models.generate import decode_step, generate
 from starway_tpu.models.llama import forward, rope_tables
 from starway_tpu.models.speculative import (chunk_decode_step,
                                             generate_speculative)
@@ -206,7 +207,7 @@ def test_chunk_decode_rejects_rolling_cache(params):
     """The PUBLIC chunk_decode_step entry raises on a rolling (window-
     sized) cache instead of silently clamping absolute-position writes
     into the modular window (ADVICE r3)."""
-    from starway_tpu.models.generate import init_rolling_cache
+    from starway_tpu.models.cache import init_rolling_cache
 
     cfg = LlamaConfig.preset("debug", sliding_window=8)
     cache = init_rolling_cache(cfg, 1)

@@ -210,7 +210,7 @@ def test_ragged_prefill_folds_each_rows_own_window(ref, runner):
 
 
 def test_ring_fold_keeps_the_last_window_at_its_residues():
-    from starway_tpu.models.generate import ring_fold
+    from starway_tpu.models.cache import ring_fold
 
     a = jnp.arange(2 * 20, dtype=jnp.float32).reshape(1, 2, 1, 20, 1)
     out = np.asarray(ring_fold(a, jnp.asarray([5, 19]), 8))[0, :, 0, :, 0]
@@ -291,7 +291,7 @@ def test_a_model_of_one_kind_logs_no_kv_rows():
 @pytest.mark.parametrize("what", ["prefix", "paged", "beam", "chunk_verify"])
 def test_paths_that_cannot_hold_rings_refuse_them(runner, what):
     from starway_tpu.models import PagedSlotServer, SlotServer, generate_beam
-    from starway_tpu.models.generate import init_cache
+    from starway_tpu.models.cache import init_cache
     from starway_tpu.models.llama import cfg_rope_tables
     from starway_tpu.models.speculative import chunk_decode_step
 
