@@ -12,7 +12,9 @@ compared: the compiled HLO with what names a source line taken out, and
 each Pallas kernel's Mosaic module printed without debug locations.
 "SAME" means the change left that cell's device programs as they were:
 what a PR that touches shared model code quotes for a cell whose spread
-sits at its gate.
+sits at its gate.  A configuration only ONE tree can build (the one a
+``model_config`` PR adds: ``phi4-mini-flash`` against its parent) is
+printed for that tree alone, "ONLY IN", and decides nothing.
 
 Beside each program, for both trees: the seconds its trace and lowering
 took (what a warm start, its compile cache hit, still pays for every
@@ -90,8 +92,14 @@ def _dump(root: str, configs: list) -> dict:
 
     out, spec = {}, S.load_spec()
     for name in configs:
-        config = S.load_config(spec, name)
-        runner = S.load_runner(config["runner"])
+        try:
+            config = S.load_config(spec, name)
+            runner = S.load_runner(config["runner"])
+            if config["runner"] != "serve":
+                runner.model_config(config)
+        except (SystemExit, Exception) as e:   # this tree cannot build it
+            print(f"{root}: no {name}: {e!r}"[:300], file=sys.stderr)
+            continue
         if config["runner"] == "serve":
             from benchmark.harness import weights as W
             cfg = runner.llama_config(config)
@@ -164,7 +172,15 @@ def main(argv) -> int:
         for tree in trees]
     digest = lambda text: hashlib.sha256(text.encode()).hexdigest()[:12]
     same = True
-    for program in dumps[0]:
+    for program in dict.fromkeys([*dumps[0], *dumps[1]]):
+        if program not in dumps[0] or program not in dumps[1]:
+            at = program in dumps[1]
+            hlo, kernels, seconds = dumps[at][program]
+            print(f"{program}: ONLY IN {trees[at]}: HLO {digest(hlo)}, "
+                  f"{len(kernels)} kernels\n    trace and lower "
+                  f"{seconds:.2f} s; kernel modules, chars: {_sizes(kernels)}"
+                  f", all {sum(map(len, kernels))}")
+            continue
         (hlo_a, ker_a, sec_a), (hlo_b, ker_b, sec_b) = (
             dumps[0][program], dumps[1][program])
         ok = hlo_a == hlo_b and ker_a == ker_b

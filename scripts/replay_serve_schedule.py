@@ -14,6 +14,8 @@ measured seeds' ``tok_s`` within 1% (12.7 +- 4.5 tokens/s high) and
 ``tpot_p95_ms`` within 0.5 ms.  ``kimi-linear.reason_closed`` (PR 32): a
 step's 19.9 ms do not depend on the cursors (the state is constant in
 length), the two latent layers' attention is 2.2 ms at 293k attended rows.
+``phi4-mini-flash.cot_closed`` (PR 46, ``--traffic cot_closed_c120
+--set-sizes 32``): one full layer's rows read eight times a step.
 Another cell needs its own numbers here.
 
     python scripts/replay_serve_schedule.py --set-sizes 16 32 --seeds 240
@@ -54,6 +56,16 @@ def _reason_step_s(cursors) -> float:
     return (19.9 + 2.2 * sum(p + 1 for p in cursors) / 293e3) / 1e3
 
 
+def _cot_step_s(cursors) -> float:
+    """One decode step (PR 46's traced run): 13.7 ms that do not depend on
+    the cursors (weights, nine Mamba states, the head), the ONE full
+    layer's rows read by eight layers (16.1 ms at 263k attended positions)
+    and the eight rings (2.68 ms at 49k)."""
+    full = sum(p + 1 for p in cursors)
+    ring = sum(min(p + 1, 512) for p in cursors)
+    return (13.7 + 16.1 * full / 263e3 + 2.68 * ring / 49152) / 1e3
+
+
 # traffic name -> (slots, seconds of each admit bucket, a decode step)
 CELLS = {
     "longdoc_closed_c72": (48, {
@@ -62,6 +74,10 @@ CELLS = {
     "reason_closed_c320": (256, {
         128: .0062, 256: .0096, 512: .0168, 1024: .0285, 2048: .0666,
         4096: .1401}, _reason_step_s),
+    # Buckets 3,072 and 4,096 as traced (PR 46); the others in proportion.
+    "cot_closed_c120": (96, {
+        1024: .0345, 2048: .0680, 3072: .1010, 4096: .1355, 6144: .2050},
+        _cot_step_s),
 }
 
 

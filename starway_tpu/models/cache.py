@@ -34,7 +34,13 @@ convolutions' last inputs, with NO position axis.  Nothing is written at a
 cursor and nothing masks by one: a decode step moves the whole state on
 (``ops.kda_step``, in place), a prefill hands back the state after each
 row's own last token (``generate.prefill``'s ``logit_positions``), and
-whoever seats a request replaces the row's state whole.
+whoever seats a request replaces the row's state whole.  A state-space
+layer (``cfg.ssm``, models/ssm.py) keeps the same kind under names of its
+own, ``ssm_state`` / ``ssm_conv``, and a model laid out in
+``LayerKinds.runs`` may keep state, rings AND full rows in one cache.  Its
+``"gmu"`` and ``"cross"`` layers keep NOTHING: a cross layer reads the
+rows of the full layer before it (``cfg.rows_layer``), so a row there has
+several readers a step and one writer.
 """
 
 from __future__ import annotations
@@ -68,8 +74,10 @@ class CacheSpec:
     positions a full row holds; ``ring``: the positions a ring holds (0:
     no ring) and ``rolling``: EVERY leaf is one (``length == ring``: the
     whole-model window), else the rings lie BESIDE full rows; ``state``:
-    linear layers' leaves with no position axis; ``mtp``: MTP blocks, each
-    with rows of its own; ``leaves``: the arrays, in the dict's order."""
+    linear or state-space layers' leaves with no position axis; ``mtp``:
+    MTP blocks, each with rows of its own; ``leaves``: the arrays, in the
+    dict's order; ``readers``: the layers that read ONE full layer's rows
+    a step (1, and more where cross layers read a full layer's)."""
     latent: bool
     int8: bool
     length: int
@@ -78,6 +86,7 @@ class CacheSpec:
     state: bool
     mtp: int
     leaves: tuple
+    readers: int = 1
 
     def zeros(self, batch: int) -> dict:
         """The cache itself, empty, of ``batch`` rows."""
@@ -110,15 +119,20 @@ class CacheSpec:
         Rings beside full rows: ``kv_rows_full``, and ``kv_rows_window``,
         a ring being read whole once warm.  State beside rows:
         ``state_slots``, and ``kv_rows_latent`` or ``kv_rows_full`` as the
-        rows are.  Every other kind adds nothing."""
+        rows are.  All three together where a cache holds all three, and
+        ``kv_full_readers`` where more layers than its own read a full
+        layer's rows.  Every other kind adds nothing."""
         at = 1 + self.mtp + np.asarray(pos).astype(int)
-        if self.ring and not self.rolling:
-            return {"kv_rows_full": int(at.sum()),
-                    "kv_rows_window": int(np.minimum(at, self.ring).sum())}
-        if self.state:
-            rows = "kv_rows_latent" if self.latent else "kv_rows_full"
-            return {"state_slots": len(at), rows: int(at.sum())}
-        return {}
+        rings = self.ring and not self.rolling
+        out = {"state_slots": len(at)} if self.state else {}
+        if rings or self.state:
+            out["kv_rows_latent" if self.latent else "kv_rows_full"] = int(
+                at.sum())
+        if rings:
+            out["kv_rows_window"] = int(np.minimum(at, self.ring).sum())
+        if self.readers > 1:
+            out["kv_full_readers"] = self.readers
+        return out
 
 
 def cache_spec(cfg: LlamaConfig, max_len: int,
@@ -160,8 +174,8 @@ def cache_spec(cfg: LlamaConfig, max_len: int,
         max_len = cfg.sliding_window
     dt, full = cfg.compute_dtype, cfg.kind_layers("full")
 
-    def pair(kind, layers, t, dtype=dt, d=(cfg.head_dim,)):
-        return [Leaf(name + kind, layers, (cfg.n_kv_heads, t) + d, dtype)
+    def pair(kind, layers, t, dtype=dt, d=(cfg.kv_cache_dim,)):
+        return [Leaf(name + kind, layers, (cfg.kv_cache_heads, t) + d, dtype)
                 for name in ("k", "v")]
 
     beside = pair("_mtp", cfg.mtp, max_len) if cfg.mtp else []
@@ -171,6 +185,10 @@ def cache_spec(cfg: LlamaConfig, max_len: int,
             Leaf("kda_state", n, (la.n_heads, la.head_dim, la.head_dim),
                  jnp.float32),
             Leaf("kda_conv", n, (la.conv - 1, la.conv_width), dt)]
+    if cfg.ssm is not None:
+        sp, n = cfg.ssm, cfg.kind_layers("ssm")
+        beside = [Leaf("ssm_state", n, (sp.d_state, sp.d_inner), jnp.float32),
+                  Leaf("ssm_conv", n, (sp.conv - 1, sp.d_inner), dt)]
     ring, int8 = cfg.sliding_window if rolling else 0, False
     if cfg.latent is not None:
         rows = [Leaf("ckv", full, (1, max_len, cfg.latent.cache_width), dt)]
@@ -188,8 +206,11 @@ def cache_spec(cfg: LlamaConfig, max_len: int,
         rows = pair("", full, max_len)
     return CacheSpec(
         latent=cfg.latent is not None, int8=int8, length=max_len, ring=ring,
-        rolling=rolling, state=cfg.linear is not None, mtp=cfg.mtp,
-        leaves=tuple(rows + beside))
+        rolling=rolling, state=(cfg.linear is not None
+                                or cfg.ssm is not None), mtp=cfg.mtp,
+        leaves=tuple(rows + beside),
+        readers=1 + sum(cfg.mixer(i) == "cross"
+                        for i in range(cfg.n_layers)))
 
 
 def served_spec(cfg: LlamaConfig, max_len: int) -> CacheSpec:
@@ -274,7 +295,8 @@ _REFUSED = {
 def require_rows(cfg: LlamaConfig, need: str, error=ValueError) -> None:
     """Raise ``error`` unless ``cfg``'s cache gives what ``need`` names
     (``_REFUSED``'s keys): full rows by position, every layer alike."""
-    keeps = {"mtp": cfg.mtp, "state": cfg.linear is not None,
+    keeps = {"mtp": cfg.mtp,
+             "state": cfg.linear is not None or cfg.ssm is not None,
              "window": (cfg.sliding_window is not None
                         or cfg.kinds is not None),
              "int8": cfg.kv_quant != "none", "latent": cfg.latent is not None}
@@ -326,9 +348,10 @@ def cache_len(cache: dict) -> int:
 
 
 def is_state(name: str) -> bool:
-    """Whether a cache leaf is a linear layer's state: no position axis,
-    the whole of a row's entry is the request's (:func:`cache_spec`)."""
-    return name.startswith("kda_")
+    """Whether a cache leaf is a linear or state-space layer's state: no
+    position axis, the whole of a row's entry is the request's
+    (:func:`cache_spec`)."""
+    return name.startswith(("kda_", "ssm_"))
 
 
 def ring_fold(a, lengths, window: int):
@@ -429,7 +452,10 @@ def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig,
     attended and nothing is masked again; cold slots (> pos) are masked by
     the clamped position.  A ring LONGER than its window
     (``LayerKinds.slack``): every slot is read under the mask of the
-    position it holds, ``i - window < j <= i`` for the query at ``i``."""
+    position it holds, ``i - window < j <= i`` for the query at ``i``.
+    ``layer`` indexes the LEAF that is read, which is not always the
+    attending layer's own: a cross layer, which keeps nothing, passes the
+    index of the full layer whose rows it reads (``cfg.rows_layer``)."""
     if "ckv" in cache:
         return latent_attention(q, cache["ckv"], pos,
                                 rank=cfg.latent.kv_rank,
@@ -441,11 +467,17 @@ def attend_cache(q, cache: dict, pos, layer, cfg: LlamaConfig,
         return cached_attention(
             q, cache[at["k"]], cache[at["v"]], pos, layer=layer, ring=True,
             window=None if cache[at["k"]].shape[3] == window else window,
-            **scales)
+            **scales, **_scale_of(cfg))
     return cached_attention(q, cache["k"], cache["v"], pos, layer=layer,
                             window=cfg.sliding_window,
                             k_scale=cache.get("k_scale"),
-                            v_scale=cache.get("v_scale"))
+                            v_scale=cache.get("v_scale"), **_scale_of(cfg))
+
+
+def _scale_of(cfg: LlamaConfig) -> dict:
+    """The scores' multiplier where it is not the cached head's own
+    ``D ** -0.5`` (``cfg.attn_scale``: a differential pair's)."""
+    return {} if cfg.attn_scale is None else {"sm_scale": cfg.attn_scale}
 
 
 def attend_piece(q, cache: dict, first, slot, layer):

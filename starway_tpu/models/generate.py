@@ -13,6 +13,7 @@ new entries.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional
 
@@ -25,9 +26,10 @@ from .cache import (_write_cached, attend_cache, attend_piece, cache_len,
                     quantize_rows, ring_fold, ring_in_order,
                     taken_for_rolling)
 from .llama import (LlamaConfig, apply_rope, cfg_rmsnorm, cfg_rope_tables,
-                    embed_tokens, ffn_block, forward, gate_heads,
-                    layer_segments, matmul_w, qkv_proj, scan_segment,
-                    segment_kind)
+                    diff_combine, diff_kv, diff_q, embed_tokens, ffn_block,
+                    forward, gate_heads, gated_memory, layer_segments,
+                    lm_head_matmul, matmul_w, mixer_out, model_logits,
+                    qkv_proj, scan_segment, segment_kind, segment_layers)
 from ..ops.attention import NEG_BIG, repeat_kv
 
 
@@ -88,7 +90,7 @@ def decode_step_counted(params: dict, cache: dict, token, pos,
     h, out, counts = cached_layer_scan(params, cache, h, cos_p, sin_p, cfg,
                                        write, attend)
     h = cfg_rmsnorm(h, params["final_norm"], cfg)
-    logits = matmul_w(h[:, 0, :], params["lm_head"]).astype(jnp.float32)
+    logits = lm_head_matmul(h[:, 0, :], params).astype(jnp.float32)
     return logits, out, counts
 
 
@@ -156,7 +158,7 @@ def ingest_decode_step(params: dict, cache: dict, token, pos, piece,
 
 
 def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
-                      write, attend):
+                      write, attend, *, first_layer: int = 0, mem=None):
     """The ONE per-layer body of every cached decode path — decode_step's
     C=1, the speculative chunk verify's C>1
     (models/speculative.py:chunk_decode_step), the paged pool's
@@ -199,7 +201,18 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
     MTP block's one full layer, models/mtp.py).  Returns ``(h [B, C, D], cache, counts)``: the pairs each held
     expert of each routed layer got, ``[routed layers, n_held]`` int32
     (None for a model with no routed layer).
+
+    A model laid out in ``LayerKinds.runs`` takes :func:`_mixer_scan`: one
+    scan body a run of whole periods, state, rings and full rows in one
+    carry, and beside them ``mem``, the last state-space layer's read-out
+    of the SAME token, for the gated memory units behind it.
+    ``first_layer`` / ``mem``: begin at that layer (the first of a run)
+    with that memory, which is how an admission runs the cross-decoder on
+    a prompt's last row alone (:func:`prefill`).
     """
+    if cfg.kinds is not None and cfg.kinds.runs:
+        return _mixer_scan(params, cache, h, cfg, write, attend,
+                           first_layer, mem)
     B, C = h.shape[0], h.shape[1]
     quant = "k_scale" in cache  # int8 cache (init_cache's format marker)
 
@@ -261,9 +274,122 @@ def cached_layer_scan(params, cache, h, cos_p, sin_p, cfg: LlamaConfig,
     return (*carry, jnp.concatenate(counts) if counts else None)
 
 
+def _mixer_scan(params, cache, h, cfg: LlamaConfig, write, attend,
+                first_layer: int = 0, mem=None):
+    """:func:`cached_layer_scan` of a model laid out in ``LayerKinds.runs``.
+    A state-space layer moves its state on itself (``ssm_decode``, C = 1)
+    and hands its read-out on as ``mem``; a gated memory unit multiplies
+    it and touches no leaf; a window or full layer writes its pair rows
+    and attends them; a cross layer writes nothing and attends the rows of
+    the full layer before it (``attend``'s ``layer`` is then THAT layer's
+    index, ``cfg.rows_layer``).  The layers behind the last one that keeps
+    anything run under the scope ``sw_cross_decoder``."""
+    from .ssm import ssm_decode
+
+    B, C = h.shape[0], h.shape[1]
+
+    def one(carry, lp, li, kind: str):
+        h, cache, mem = carry
+        x = cfg_rmsnorm(h, lp["attn_norm"], cfg)
+        if kind == "ssm":
+            if C != 1:
+                raise ValueError(
+                    "a state-space layer's state moves one token a step: "
+                    "it cannot verify or ingest a chunk of C > 1")
+            o, cache, mem = ssm_decode(x, lp["ssm"], cfg, cache, li)
+        elif kind == "gmu":
+            o = gated_memory(x, lp, mem)
+        else:
+            ring = {"ring": True} if kind == "window" else {}
+            if kind != "cross":
+                k, v = diff_kv(x, lp, cfg)
+                cache = write(cache, {"k": k, "v": v}, li, **ring)
+            o = diff_combine(attend(diff_q(x, lp, cfg), cache, li, **ring),
+                             lp, cfg)
+        return mixer_out(h, o, lp, cfg), cache, mem
+
+    def leaf_index(i: int) -> int:
+        kind = cfg.mixer(i)
+        if kind in ("cross", "full"):
+            return cfg.rows_layer(i)
+        return 0 if kind == "gmu" else cfg.kind_layers(cfg.cache_kind(i), i)
+
+    if mem is None:
+        wide = cfg.ssm.d_inner if cfg.ssm is not None else 0
+        mem = jnp.zeros((B, C, wide), cfg.compute_dtype)
+    carry = (h, dict(cache), mem)
+    keeps = [i for i in range(cfg.n_layers) if cfg.cache_kind(i) is not None]
+    for seg, first in layer_segments(params["layers"]):
+        if first < first_layer:
+            continue
+        kinds, p = cfg.kinds.mixers[first:first + len(seg)], len(seg)
+        index = tuple(jnp.asarray(
+            [leaf_index(first + r * p + j)
+             for r in range(segment_layers(seg) // p)], jnp.int32)
+            for j in range(p))
+
+        def period(carry, xs, kinds=kinds):
+            for kind, lp, li in zip(kinds, *xs):
+                carry = one(carry, lp, li, kind)
+            return carry, None
+
+        behind = first > keeps[-1]     # the layers that keep nothing
+        with (jax.named_scope("sw_cross_decoder") if behind
+              else contextlib.nullcontext()):
+            carry, _ = lax.scan(period, carry, (seg, index))
+    return carry[0], carry[1], None
+
+
+@functools.cache
+def early_exit_at(cfg: LlamaConfig) -> Optional[int]:
+    """Where an admission may leave the prompt's rows behind: the index of
+    the model's LAST layer that keeps anything, if that is a full layer in
+    a run of its own and every layer behind it a gated memory unit or a
+    cross layer (they write no cache, so an admission needs them at the
+    prompt's last row only); None for every other model."""
+    if cfg.kinds is None or "cross" not in cfg.kinds.mixers:
+        return None
+    kept = [i for i in range(cfg.n_layers) if cfg.cache_kind(i) is not None]
+    last = kept[-1]
+    alone = (last, 1, ("full",)) in cfg.kinds.segments()
+    return last if alone and last + 1 < cfg.n_layers else None
+
+
+def _prefill_early_exit(params, cfg: LlamaConfig, prompt, max_len: int,
+                        last, full_at: int):
+    """:func:`prefill` of a model whose layers behind ``full_at`` keep
+    nothing (:func:`early_exit_at`): the layers below it over every row;
+    layer ``full_at``'s k and v of every row and the rest of it for each
+    prompt's LAST row (``last [B]``); the layers behind it for that one
+    row, with the memory of that row, through the decode path's own body
+    over the rows just made.  The logits are the whole forward's."""
+    take = lambda a: jnp.take_along_axis(a, last[:, None, None], axis=1)
+    lengths = last + 1
+    h, mem, kv = forward(params, prompt, cfg, return_kv=True, lengths=lengths,
+                         stop_at=full_at)
+    seg = next(seg for seg, first in layer_segments(params["layers"])
+               if first == full_at)
+    lp = jax.tree_util.tree_map(lambda a: a[0], seg[0])
+    x = cfg_rmsnorm(h, lp["attn_norm"], cfg)
+    k, v = diff_kv(x, lp, cfg)
+    rows = {"k": k[None], "v": v[None]}        # a cache of this one layer
+
+    def attend(q, cache, layer, **_kind):
+        return attend_cache(q, cache, last, 0, cfg)
+
+    o = diff_combine(attend(diff_q(take(x), lp, cfg), rows, 0), lp, cfg)
+    h = mixer_out(take(h), o, lp, cfg)
+    h, _rows, _counts = cached_layer_scan(
+        params, rows, h, None, None, cfg, None, attend,
+        first_layer=full_at + 1, mem=take(mem))
+    return (model_logits(params, h, cfg)[:, 0], from_forward(
+        cache_spec(cfg, max_len), {**kv, **rows}, lengths))
+
+
 def prefill(params: dict, cfg: LlamaConfig, prompt,
             max_len: Optional[int] = None, attn_fn=None,
-            logit_positions=None, return_hidden: bool = False):
+            logit_positions=None, return_hidden: bool = False,
+            early_exit: bool = True):
     """One parallel forward pass over the whole prompt -> the decode state.
 
     Returns ``(next_logits [B, V], cache)`` where the cache holds the
@@ -284,13 +410,25 @@ def prefill(params: dict, cfg: LlamaConfig, prompt,
     linear layers (``cfg.linear``) come back as the STATE after each row's
     own last token, ``logit_positions + 1`` long: the positions behind it
     do not move the state and stay out of the convolutions' tails, so a
-    padded bucket leaves what the unpadded prompt leaves.
+    padded bucket leaves what the unpadded prompt leaves.  State-space
+    layers (``cfg.ssm``) likewise.
+
+    ``early_exit``: a model whose last layers keep nothing
+    (:func:`early_exit_at`) runs them for each row's last position only
+    (:func:`_prefill_early_exit`); off, every layer sees every row, and the
+    logits and every leaf are the same.
     """
     B, P = prompt.shape
     if max_len is None:
         max_len = P
     elif max_len < P:
         raise ValueError(f"max_len={max_len} is smaller than the prompt ({P})")
+    full_at = early_exit_at(cfg) if early_exit and not return_hidden else None
+    if full_at is not None and attn_fn is None:
+        last = (jnp.full((B,), P - 1, jnp.int32) if logit_positions is None
+                else jnp.asarray(logit_positions, jnp.int32))
+        return _prefill_early_exit(params, cfg, prompt, max_len, last,
+                                   full_at)
     logits, _aux, kv, *hidden = forward(
         params, prompt, cfg, attn_fn, return_aux=True, return_kv=True,
         last_only=logit_positions is None, logit_positions=logit_positions,

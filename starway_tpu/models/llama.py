@@ -22,6 +22,7 @@ scaled-down variants for tests and the graft entry.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import partial
 from typing import Callable, Optional
 
@@ -117,6 +118,27 @@ class LinearAttn:
 
 
 @dataclasses.dataclass(frozen=True)
+class StateSpace:
+    """Mixer kind: a selective state-space layer (Mamba-1, models/ssm.py).
+    ``d_inner`` channels behind a depthwise causal convolution of ``conv``
+    taps, each with ``d_state`` states under a diagonal transition that the
+    token chooses through a step ``dt`` of rank ``dt_rank``.  A layer of
+    this kind keeps no keys and no values: its state a request is one
+    ``[d_state, d_inner]`` float32 matrix and the convolution's last
+    ``conv - 1`` inputs, whatever the length."""
+    d_inner: int
+    d_state: int = 16
+    dt_rank: int = 160
+    conv: int = 4
+
+
+# The kinds a layer of a ``LayerKinds.runs`` model may be, and the leaves
+# of the cache each keeps (``LlamaConfig.cache_kind``).
+MIXERS = {"full": "full", "window": "ring", "linear": "linear",
+          "ssm": "ssm", "gmu": None, "cross": None}
+
+
+@dataclasses.dataclass(frozen=True)
 class RoutedFFN:
     """FFN kind: routed, dropless experts beside ``n_shared`` shared ones
     (models/moe.py::routed_ffn).  The router is ``n_experts`` wide and
@@ -181,11 +203,40 @@ class LayerKinds:
     ``slack >= C - 1``: the ring is then read under position masks, and
     neither the draft's write nor its rejection touches an entry a live
     query attends (DESIGN.md 9b).  A model whose every layer has the
-    same window is ``LlamaConfig.sliding_window``'s, not this group's."""
+    same window is ``LlamaConfig.sliding_window``'s, not this group's.
+
+    ``runs`` (:meth:`in_runs` builds it): the kinds are NOT one period over
+    the depth but RUNS OF PERIODS, ``((kinds of one period, repeats),
+    ...)`` with the kinds named (``MIXERS``): ``"full"`` / ``"window"`` /
+    ``"linear"`` as above, ``"ssm"`` (a state-space layer, ``cfg.ssm``:
+    models/ssm.py), ``"gmu"`` (a gated memory unit: it gates the LAST ssm
+    layer's scan output of the same token and keeps nothing) and
+    ``"cross"`` (attention that projects q only and reads the rows of the
+    last ``"full"`` layer before it; it keeps nothing either).  The three
+    tuples then hold one entry a LAYER, a run is one segment of the stacked
+    tree, scanned a whole period a body (``layer_segments``), and state,
+    rings and full rows may all lie in one cache."""
     windows: tuple
     rope: tuple
     linear: tuple = ()
     slack: int = 0
+    runs: tuple = ()
+
+    @classmethod
+    def in_runs(cls, runs, *, window: Optional[int] = None,
+                slack: int = 0) -> "LayerKinds":
+        """Kinds laid out as runs of periods: ``runs = ((kinds of one
+        period, repeats), ...)``; ``window``: the ``"window"`` layers'
+        one length.  No layer rotates (the one model laid out so has no
+        positional encoding; ``cfg.diff_attn`` refuses a rotation)."""
+        runs = tuple((tuple(period), int(reps)) for period, reps in runs)
+        kinds = [k for period, reps in runs for _ in range(reps)
+                 for k in period]
+        return cls(windows=tuple(window if k == "window" else None
+                                 for k in kinds),
+                   rope=(False,) * len(kinds),
+                   linear=tuple(k == "linear" for k in kinds), slack=slack,
+                   runs=runs)
 
     def __post_init__(self):
         object.__setattr__(self, "windows", tuple(self.windows))
@@ -199,7 +250,25 @@ class LayerKinds:
                 f"one linear flag a layer of the period, got {self.windows} "
                 f"/ {self.rope} / {self.linear}")
         sizes = {w for w in self.windows if w is not None}
-        if any(self.linear):
+        if self.runs:
+            kinds = self.mixers
+            first = {k: kinds.index(k) for k in set(kinds) & set(MIXERS)}
+            if (len(kinds) != len(self.windows) or set(kinds) - set(MIXERS)
+                    or any((k == "window") != (w is not None)
+                           or (k == "linear") != lin for k, w, lin in
+                           zip(kinds, self.windows, self.linear))
+                    or len(sizes) > 1 or "full" not in kinds
+                    or first.get("cross", len(kinds)) < first["full"]
+                    or first.get("gmu", len(kinds)) < first.get("ssm",
+                                                                 len(kinds))
+                    or ("gmu" in first and "ssm" not in first)):
+                raise ValueError(
+                    f"LayerKinds.runs names one kind a layer out of "
+                    f"{sorted(MIXERS)}, windows of ONE length, a full layer "
+                    f"before any cross layer and an ssm layer before any "
+                    f"gmu (LayerKinds.in_runs builds it), got {self.runs} "
+                    f"/ {self.windows} / {self.linear}")
+        elif any(self.linear):
             if sizes or all(self.linear):
                 raise ValueError(
                     f"LayerKinds with linear layers needs attention layers "
@@ -212,6 +281,21 @@ class LayerKinds:
         if self.slack < 0 or (self.slack and not sizes):
             raise ValueError(f"LayerKinds.slack lengthens rings: it needs "
                              f"window layers and >= 0, got {self.slack}")
+
+    @functools.cached_property
+    def mixers(self) -> tuple:
+        """The kind of every layer by name, of a model laid out in
+        ``runs`` (``()`` for a model on one period)."""
+        return tuple(k for period, reps in self.runs for _ in range(reps)
+                     for k in period)
+
+    def segments(self) -> list:
+        """``[(first layer, layers, kinds of one period)]`` of the runs."""
+        out, at = [], 0
+        for period, reps in self.runs:
+            out.append((at, reps * len(period), period))
+            at += reps * len(period)
+        return out
 
     @property
     def window(self) -> Optional[int]:
@@ -333,8 +417,55 @@ class LlamaConfig:
     # A SlotServer built on such a configuration drafts with it and
     # verifies inside every decode step.  0 or 1.
     mtp: int = 0
+    # The state-space layers' sizes (models/ssm.py): goes with
+    # ``kinds.runs`` naming ``"ssm"`` layers, and the other way.
+    ssm: Optional[StateSpace] = None
+    # The norm at every site ``cfg_rmsnorm`` serves: ``"rms"``, or
+    # ``"layer"``: LayerNorm with a gain and a bias, its weight leaf ``[2,
+    # D]`` (gain, then bias) wherever an RMSNorm's is ``[D]``.
+    norm: str = "rms"
+    # Differential attention (arXiv 2410.05258) over ADJACENT pairs of
+    # heads: two softmaxes a pair, subtracted under a scalar a layer, an
+    # RMSNorm over the pair's output (:func:`diff_q`, :func:`diff_kv`,
+    # :func:`diff_combine`).  The cache holds a kv PAIR as one head twice
+    # as wide (``kv_cache_heads`` / ``kv_cache_dim``).
+    diff_attn: bool = False
+    # The head IS the embedding table: no ``lm_head`` leaf, the logits
+    # contract over the table's ``D`` axis where it lies
+    # (:func:`lm_head_matmul`).
+    tied: bool = False
 
     def __post_init__(self):
+        has_ssm = self.kinds is not None and "ssm" in self.kinds.mixers
+        if has_ssm != (self.ssm is not None):
+            raise ValueError(
+                "ssm (the state-space layers' sizes) goes with kinds.runs "
+                "naming ssm layers (which they are), and the other way")
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"norm must be 'rms' or 'layer', got "
+                             f"{self.norm!r}")
+        if self.diff_attn and (
+                self.latent is not None or self.n_heads % 2
+                or self.n_kv_heads % 2 or self.kv_quant != "none"
+                or (self.n_heads // 2) % (self.n_kv_heads // 2)
+                or self.attn_gate or self.qk_norm or self.mtp
+                or self.kinds is None or not self.kinds.runs
+                or any(self.kinds.rope)):
+            raise ValueError(
+                "diff_attn pairs adjacent grouped-query heads of a model "
+                "laid out in kinds.runs: it needs even head counts, a "
+                "bf16/f32 cache and no rotation, latent attention, "
+                "attention gate, head norms or MTP block")
+        if self.kinds is not None and self.kinds.runs and (
+                len(self.kinds.mixers) != self.n_layers or self.routed
+                or self.n_experts or self.mtp or self.latent is not None
+                or not self.diff_attn or "linear" in self.kinds.mixers):
+            raise ValueError(
+                f"kinds.runs lays out {len(self.kinds.mixers)} layers of a "
+                f"dense model whose attention layers attend differentially "
+                f"(diff_attn), with no MTP block and no delta-rule layer "
+                f"(both are one more branch of the period's body, not "
+                f"written yet), got n_layers={self.n_layers}")
         if self.mtp not in (0, 1):
             raise ValueError(
                 f"mtp must be 0 or 1 (one draft a step; a second needs a "
@@ -451,6 +582,36 @@ class LlamaConfig:
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
+    @property
+    def kv_cache_heads(self) -> int:
+        """Heads a cached position of a grouped-query layer holds: under
+        ``diff_attn`` a PAIR of kv heads is one."""
+        return self.n_kv_heads // 2 if self.diff_attn else self.n_kv_heads
+
+    @property
+    def kv_cache_dim(self) -> int:
+        """Their width: a pair's two heads side by side."""
+        return self.head_dim * (2 if self.diff_attn else 1)
+
+    @property
+    def attn_scale(self) -> Optional[float]:
+        """The scores' multiplier where the kernels must be told: a pair's
+        128-wide row scores at its own heads' ``head_dim ** -0.5``."""
+        return self.head_dim ** -0.5 if self.diff_attn else None
+
+    def mixer(self, i: int) -> Optional[str]:
+        """The kind of layer ``i`` by name (``MIXERS``) in a model laid out
+        in ``kinds.runs``; None in every other model."""
+        if self.kinds is None or not self.kinds.runs:
+            return None
+        return self.kinds.mixers[i]
+
+    def rows_layer(self, i: int) -> int:
+        """Where in the full rows' leaves layer ``i`` reads: its own index
+        among the full layers, or for a ``"cross"`` layer (which keeps
+        nothing) that of the last full layer before it."""
+        return self.kind_layers("full", i) - (self.mixer(i) == "cross")
+
     def layer_kind(self, i: int) -> tuple:
         """``(window or None, rotates q/k, linear)`` of layer ``i``."""
         if self.kinds is None:
@@ -459,10 +620,14 @@ class LlamaConfig:
         return (self.kinds.windows[at], self.kinds.rope[at],
                 self.kinds.linear[at])
 
-    def cache_kind(self, i: int) -> str:
+    def cache_kind(self, i: int) -> Optional[str]:
         """Which leaves of the cache layer ``i`` keeps its state in:
-        ``"linear"`` (a matrix a head, no position axis), ``"ring"`` (one
-        window's positions) or ``"full"`` (a row a position)."""
+        ``"linear"`` (a matrix a head, no position axis), ``"ssm"`` (a
+        state-space layer's, likewise), ``"ring"`` (one window's
+        positions) or ``"full"`` (a row a position); None: the layer
+        keeps nothing (a gated memory unit, a cross layer)."""
+        if self.mixer(i) is not None:
+            return MIXERS[self.mixer(i)]
         window, _rope, linear = self.layer_kind(i)
         return ("linear" if linear else
                 "ring" if window is not None and self.kinds is not None
@@ -490,6 +655,10 @@ class LlamaConfig:
         """``[(first layer, layers, routed FFN?)]``: the homogeneous
         segments ``params["layers"]`` is stacked in: cut where the
         attention kind changes and behind the leading dense layers."""
+        if self.kinds is not None and self.kinds.runs:
+            # A run of whole periods is one segment, whatever its kinds.
+            return [(first, n, False)
+                    for first, n, _period in self.kinds.segments()]
         dense = (self.routed.first_dense if self.routed else self.n_layers)
         plan = []
         for first, n in self.kind_runs():
@@ -521,15 +690,27 @@ class LlamaConfig:
 # ------------------------------------------------------------------ params
 
 
+def segment_layers(seg) -> int:
+    """Layers one segment of the stacked tree holds: a dict's leading
+    axis, or for a run of whole periods (a TUPLE of stacked trees, one a
+    layer of the period: ``LayerKinds.runs``) that times the period."""
+    if isinstance(seg, (tuple, list)):
+        return len(seg) * segment_layers(seg[0])
+    return jax.tree_util.tree_leaves(seg)[0].shape[0]
+
+
 def layer_segments(layers) -> list:
     """``params["layers"]`` as ``[(stacked tree, index of its first
     layer)]``: one dict is a model of one segment, a tuple of dicts one
     segment each (a leading dense layer, then the expert layers; the runs
-    of full and of window layers of a ``cfg.kinds`` model)."""
+    of full and of window layers of a ``cfg.kinds`` model).  A model laid
+    out in ``LayerKinds.runs`` has a tuple of TUPLES: a run's segment is
+    one stacked tree a layer of its period, each ``[repeats, ...]``, and
+    one scan body walks a whole period."""
     out, at = [], 0
     for seg in (layers if isinstance(layers, (tuple, list)) else (layers,)):
         out.append((seg, at))
-        at += jax.tree_util.tree_leaves(seg)[0].shape[0]
+        at += segment_layers(seg)
     return out
 
 
@@ -576,9 +757,13 @@ def scan_segment(body, carry, seg, *xs):
 
 
 def _unit_gain(cfg: LlamaConfig, shape):
-    """A fresh norm's weight: the one that multiplies by one."""
-    return (jnp.zeros if cfg.norm_zero_centred else jnp.ones)(
+    """A fresh norm's weight: the one that multiplies by one (LayerNorm:
+    ``[..., 2, D]``, that gain over a zero bias)."""
+    gain = (jnp.zeros if cfg.norm_zero_centred else jnp.ones)(
         shape, cfg.compute_dtype)
+    if cfg.norm == "layer":
+        return jnp.stack([gain, jnp.zeros_like(gain)], axis=-2)
+    return gain
 
 
 def _init_block_params(key, cfg: LlamaConfig, plan=None) -> tuple:
@@ -657,6 +842,49 @@ def _init_block_params(key, cfg: LlamaConfig, plan=None) -> tuple:
             seg.update(mlp(ks[7], L, (), cfg.d_ff))
         return seg
 
+    def mixer_segment(k, L, kind: str, ids):
+        """``L`` stacked layers of one kind of a ``LayerKinds.runs`` model,
+        ``ids`` their indices in the model."""
+        ks = jax.random.split(k, 16)
+        seg = {"attn_norm": unit_gain((L, D)), "mlp_norm": unit_gain((L, D)),
+               **mlp(ks[7], L, (), cfg.d_ff)}
+        if kind == "ssm":
+            from .ssm import init_ssm_params
+
+            E = cfg.ssm.d_inner
+            seg["ssm"] = init_ssm_params(ks[0], L, cfg)
+            seg["wo"] = norm(ks[4], (L, E, D), E**-0.5)
+        elif kind == "gmu":
+            E = cfg.ssm.d_inner
+            seg["gmu_in"] = norm(ks[0], (L, D, E), D**-0.5)
+            seg["wo"] = norm(ks[4], (L, E, D), E**-0.5)
+        else:   # "full" / "window" / "cross": (differential) attention
+            hd, Hkv = cfg.head_dim, cfg.n_kv_heads
+            seg.update(wq=norm(ks[0], (L, D, H * hd), D**-0.5),
+                       bq=norm(ks[8], (L, H * hd), 0.02),
+                       wo=norm(ks[4], (L, H * hd, D), (H * hd)**-0.5),
+                       bo=norm(ks[9], (L, D), 0.02))
+            if kind != "cross":
+                seg.update(wk=norm(ks[1], (L, D, Hkv * hd), D**-0.5),
+                           wv=norm(ks[2], (L, D, Hkv * hd), D**-0.5),
+                           bk=norm(ks[10], (L, Hkv * hd), 0.02),
+                           bv=norm(ks[11], (L, Hkv * hd), 0.02))
+            if cfg.diff_attn:
+                seg.update(
+                    {n: norm(ks[12 + i], (L, hd), 0.1) for i, n in enumerate(
+                        ("lam_q1", "lam_k1", "lam_q2", "lam_k2"))},
+                    sub_norm=jnp.ones((L, 2 * hd), dt),
+                    lam0=diff_lambda_init(ids))
+        return seg
+
+    if cfg.kinds is not None and cfg.kinds.runs:
+        return tuple(
+            tuple(mixer_segment(
+                jax.random.fold_in(key, 131 * (first + j)), n // len(period),
+                kind, first + j + len(period) * jnp.arange(n // len(period)))
+                for j, kind in enumerate(period))
+            for first, n, period in cfg.kinds.segments())
+
     def key_of(first, routed):
         k = jax.random.fold_in(key, 31 + routed)   # as before cfg.kinds
         return k if cfg.kinds is None else jax.random.fold_in(k, first)
@@ -682,12 +910,15 @@ def init_params(key, cfg: LlamaConfig) -> dict:
     Hq, Hkv = cfg.n_heads, cfg.n_kv_heads
     if (cfg.latent is not None or cfg.routed is not None
             or cfg.kinds is not None or cfg.qk_norm or cfg.mtp
-            or cfg.attn_gate or cfg.norm_zero_centred):
+            or cfg.attn_gate or cfg.norm_zero_centred or cfg.tied
+            or cfg.norm != "rms"):
         segs = _init_block_params(jax.random.fold_in(key, 23), cfg)
         out = {"embed": norm(keys[0], (cfg.vocab_size, D), 0.02),
-               "layers": segs[0] if len(segs) == 1 else segs,
-               "final_norm": _unit_gain(cfg, (D,)),
-               "lm_head": norm(keys[8], (D, cfg.vocab_size), D**-0.5)}
+               "layers": (segs[0] if len(segs) == 1
+                          and not isinstance(segs[0], tuple) else segs),
+               "final_norm": _unit_gain(cfg, (D,))}
+        if not cfg.tied:   # tied: the table is the head (lm_head_matmul)
+            out["lm_head"] = norm(keys[8], (D, cfg.vocab_size), D**-0.5)
         if cfg.mtp:
             from .mtp import init_mtp_params
 
@@ -734,7 +965,8 @@ def param_specs(cfg: LlamaConfig) -> dict:
     """
     if (cfg.latent is not None or cfg.routed is not None
             or cfg.kinds is not None or cfg.qk_norm or cfg.mtp
-            or cfg.attn_gate or cfg.norm_zero_centred):
+            or cfg.attn_gate or cfg.norm_zero_centred or cfg.tied
+            or cfg.norm != "rms"):
         raise NotImplementedError(
             "latent attention, the routed FFN, attention kinds by layer "
             "(linear layers among them), head norms, the attention gate, "
@@ -821,9 +1053,22 @@ def rmsnorm(x, w, eps: float, zero_centred: bool = False):
     return (xf * scale).astype(x.dtype) * w
 
 
+def layernorm(x, w, eps: float):
+    """LayerNorm in float32: ``(x - mean) / std * w[0] + w[1]``, ``w [2,
+    D]`` the gain over the bias (``LlamaConfig.norm``)."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    scale = jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True) + eps)
+    wf = w.astype(jnp.float32)
+    return (xc * scale * wf[..., 0, :] + wf[..., 1, :]).astype(x.dtype)
+
+
 def cfg_rmsnorm(x, w, cfg: "LlamaConfig"):
-    """:func:`rmsnorm` keyed off a config: THE way model code norms (its
-    eps and whether its gains are zero-centred)."""
+    """The norm of every site, keyed off a config: THE way model code
+    norms (:func:`rmsnorm` with its eps and whether its gains are
+    zero-centred, or ``cfg.norm == "layer"``: :func:`layernorm`)."""
+    if cfg.norm == "layer":
+        return layernorm(x, w, cfg.norm_eps)
     return rmsnorm(x, w, cfg.norm_eps, cfg.norm_zero_centred)
 
 
@@ -969,6 +1214,24 @@ def head_logits(h, final_norm_w, lm_head_w, eps: float,
                     lm_head_w).astype(jnp.float32)
 
 
+def lm_head_matmul(x, params: dict):
+    """``x [..., D]`` times the output head: ``params["lm_head"] [D, V]``,
+    or where the tree has none (``cfg.tied``) the embedding table ``[V,
+    D]`` contracted over its ``D`` axis where it lies: ONE table in HBM,
+    no transposed copy."""
+    if "lm_head" in params:
+        return matmul_w(x, params["lm_head"])
+    return lax.dot_general(x, params["embed"],
+                           (((x.ndim - 1,), (1,)), ((), ())))
+
+
+def model_logits(params: dict, h, cfg: "LlamaConfig"):
+    """Model tail off a parameter tree: the final norm of ``cfg``'s kind
+    and the head (tied or not), f32 logits."""
+    return lm_head_matmul(cfg_rmsnorm(h, params["final_norm"], cfg),
+                          params).astype(jnp.float32)
+
+
 def token_ce(logits, targets):
     """Mean next-token cross-entropy of ``logits [..., V]`` against int ids
     ``targets [...]`` (same leading shape).
@@ -1019,6 +1282,8 @@ def resolve_attn_fn(cfg: LlamaConfig, attn_fn: Optional[Callable]) -> Callable:
                 "cfg.kinds gives each layer its own attention (a window or "
                 "none): attn_fn must be None")
         # forward binds each segment's window
+        if cfg.diff_attn:
+            return partial(self_attention, sm_scale=cfg.attn_scale)
         return (self_attention if cfg.latent is None else
                 partial(self_attention, sm_scale=cfg.latent.sm_scale))
     if attn_fn is None:
@@ -1110,6 +1375,86 @@ def gate_heads(o, gate):
         return o * gate
 
 
+# ------------------------------------------------- differential attention
+#
+# Head pair ``j`` has the queries ``q_2j, q_2j+1``; its kv pair ``g = j //
+# (pairs a kv pair)`` has the keys ``k_2g, k_2g+1`` and ONE value ``V_g =
+# [v_2g | v_2g+1]``, twice a head wide.  ``o_j = (softmax(q_2j k_2g^T s) -
+# lam softmax(q_2j+1 k_2g+1^T s)) V_g``, an RMSNorm over its ``2 hd`` values,
+# times ``1 - lam0``.  A lane tile holds 128 values and a head 64, so the
+# cache keeps a kv PAIR as one head ``K_g = [k_2g | k_2g+1]`` beside
+# ``V_g`` (no value stored twice) and the pair's queries go to the
+# attention kernels as two rows of the pair's width, ``[q_2j | 0]`` and ``[0
+# | q_2j+1]``: the zeros add exactly nothing to a score, so each row's
+# softmax is its own head's, over the whole ``V_g``.
+
+
+def diff_lambda_init(layer_ids):
+    """``lam0`` of the layers ``layer_ids`` (0-based): ``0.8 - 0.6 exp(-0.3
+    l)``, a leaf of the layer's tree so that it rides the layer scan."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer_ids, jnp.float32))
+
+
+def diff_q(x, lp, cfg: "LlamaConfig"):
+    """x [B, S, D] -> the pairs' query rows ``[B, H, S, 2 hd]``: row ``2j``
+    is ``[q_2j | 0]``, row ``2j + 1`` is ``[0 | q_2j+1]``."""
+    B, S = x.shape[:2]
+    H, hd = cfg.n_heads, cfg.head_dim
+    q = matmul_w(x, lp["wq"]) + lp["bq"]
+    side = jnp.eye(2, dtype=q.dtype)[:, :, None]             # [2, 2, 1]
+    q = q.reshape(B, S, H // 2, 2, 1, hd) * side
+    return q.reshape(B, S, H, 2 * hd).transpose(0, 2, 1, 3)
+
+
+def diff_kv(x, lp, cfg: "LlamaConfig"):
+    """x [B, S, D] -> ``(K, V) [B, Hkv / 2, S, 2 hd]``: what the cache holds
+    of these positions, adjacent kv heads side by side."""
+    B, S = x.shape[:2]
+    pairs, wide = cfg.kv_cache_heads, cfg.kv_cache_dim
+    return tuple(
+        (matmul_w(x, lp[w]) + lp[b]).reshape(B, S, pairs, wide)
+        .transpose(0, 2, 1, 3) for w, b in (("wk", "bk"), ("wv", "bv")))
+
+
+def diff_combine(o, lp, cfg: "LlamaConfig"):
+    """The two rows' attention outputs of every pair ``o [B, H, S, 2 hd]``
+    -> ``[B, S, H / 2 * 2 hd]``: subtracted under the layer's ``lam``,
+    normed over the pair's width, times ``1 - lam0``; float32."""
+    with jax.named_scope("sw_diff_attn"):
+        f32 = jnp.float32
+        B, H, S, wide = o.shape
+        o = o.astype(f32).reshape(B, H // 2, 2, S, wide)
+        dot = lambda a, b: jnp.sum(lp[a].astype(f32) * lp[b].astype(f32))
+        lam0 = lp["lam0"].astype(f32)
+        lam = (jnp.exp(dot("lam_q1", "lam_k1"))
+               - jnp.exp(dot("lam_q2", "lam_k2")) + lam0)
+        d = o[:, :, 0] - lam * o[:, :, 1]
+        d = d * jax.lax.rsqrt(jnp.mean(d * d, -1, keepdims=True) + cfg.norm_eps)
+        d = d * lp["sub_norm"].astype(f32) * (1.0 - lam0)
+        return d.transpose(0, 2, 1, 3).reshape(B, S, -1).astype(
+            cfg.compute_dtype)
+
+
+def gated_memory(x, lp, mem):
+    """A gated memory unit's mixer on the normed ``x [B, S, D]``: ``SiLU(x
+    W_g) * mem`` before ``wo``, ``mem [B, S, E]`` the last state-space
+    layer's scan output of the SAME tokens.  It keeps nothing."""
+    with jax.named_scope("sw_gmu"):
+        g = jax.nn.silu(matmul_w(x, lp["gmu_in"]).astype(jnp.float32))
+        return (g * mem.astype(jnp.float32)).astype(x.dtype)
+
+
+def mixer_out(h, o, lp, cfg: "LlamaConfig"):
+    """The rest of a dense block behind its mixer's output ``o [B, S, wo's
+    rows]``: ``wo`` (and ``bo`` where the tree has one) into the residual,
+    then the gated MLP.  What the cached paths of a ``LayerKinds.runs``
+    model share (``generate._mixer_scan``, an admission's one-row pass)."""
+    h = h + matmul_w(o, lp["wo"])
+    if "bo" in lp:
+        h = h + lp["bo"]
+    return h + ffn_block(cfg_rmsnorm(h, lp["mlp_norm"], cfg), lp, cfg)[0]
+
+
 def ffn_block(x, lp, cfg: "LlamaConfig", moe_fn: Optional[Callable] = None,
               attn_in=None):
     """The FFN of one block on the normed ``x [B, S, D]``, by the kind its
@@ -1159,7 +1504,7 @@ def ffn_block(x, lp, cfg: "LlamaConfig", moe_fn: Optional[Callable] = None,
 
 def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
                   attn_fn: Callable, moe_fn: Optional[Callable] = None,
-                  lengths=None):
+                  lengths=None, mem=None, rows=None):
     """One pre-norm decoder block on ``h [B, S, D]`` with layer params
     ``lp`` (one slice of a stacked segment); ``cos``/``sin`` None: a layer
     that does not rotate q and k (NoPE).  Returns
@@ -1170,6 +1515,11 @@ def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
     its state after each row's first ``lengths[b]`` positions, default
     all S: models/kda.py), stats the capacity MoE's router-health dict
     when ``moe_fn`` returns one (``with_stats=True`` builders), else None.
+    A state-space layer (``ssm`` in its tree: models/ssm.py) hands back
+    ``ssm_state`` / ``ssm_conv`` and, under ``mem``, its scan's output at
+    every position: what the gated memory units behind it multiply
+    (``mem``, in); a layer with no ``wk`` (``"cross"``) attends ``rows``,
+    the ``(k, v)`` of the full layer before it, and hands back nothing.
     Shared by the scan forward, and the pipeline-parallel stage body
     (models/pp_llama.py)."""
     B, S, _ = h.shape
@@ -1203,7 +1553,13 @@ def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
 
     def post(h, o, lp, attn_in, gate=None):
         o = gate_heads(o.transpose(0, 2, 1, 3).reshape(B, S, -1), gate)
+        return mixed(h, o, lp, attn_in)
+
+    def mixed(h, o, lp, attn_in=None):
+        """``o [B, S, wo's rows]``: the mixer's output before ``wo``."""
         h = h + matmul_w(o, lp["wo"])
+        if "bo" in lp:
+            h = h + lp["bo"]
         y, aux, stats = ffn_block(cfg_rmsnorm(h, lp["mlp_norm"], cfg),
                                   lp, cfg, moe_fn, attn_in)
         if "routed" in lp:
@@ -1238,6 +1594,21 @@ def decoder_layer(lp, h, cfg: LlamaConfig, cos, sin,
         o, kv = kda_prefill(x, lp["kda"], cfg, lengths)
         h, aux, stats = post(h, o, lp, None)
         return h, aux, kv, stats
+    if "ssm" in lp or "gmu_in" in lp or cfg.diff_attn:
+        x = cfg_rmsnorm(h, lp["attn_norm"], cfg)
+        if "ssm" in lp:
+            from .ssm import ssm_prefill
+
+            o, kv = ssm_prefill(x, lp["ssm"], cfg, lengths)
+        elif "gmu_in" in lp:
+            o, kv = gated_memory(x, lp, mem), {}
+        else:
+            k, v = diff_kv(x, lp, cfg) if "wk" in lp else rows
+            o = checkpoint_name(attn_fn(diff_q(x, lp, cfg), k, v), "attn_out")
+            o, kv = diff_combine(o, lp, cfg), (
+                {"k": k, "v": v} if "wk" in lp else {})
+        h, aux, stats = mixed(h, o, lp)
+        return h, aux, kv, stats
     q, k, v, kv, attn_in, gate = pre(h, lp)
     o = attn_fn(q, k, v)  # [B, H, S, Dh]
     # Tag kept for user-supplied whole-model remat policies; the flash
@@ -1252,7 +1623,7 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
             moe_fn: Optional[Callable] = None, return_kv: bool = False,
             last_only: bool = False, logit_positions=None,
             return_moe_stats: bool = False, lengths=None,
-            return_hidden: bool = False):
+            return_hidden: bool = False, stop_at: Optional[int] = None):
     """Next-token logits ``[B, S, V]`` for token ids ``[B, S]``.
 
     ``return_kv`` additionally returns what the cache holds of every
@@ -1277,6 +1648,11 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
     positions of each right-padded row are real, for the layers whose state
     is not a row a position (``cfg.linear``): a pad must not move it.
     Attention layers need no telling, a pad lies behind every real query.
+
+    ``stop_at`` (a model laid out in ``LayerKinds.runs``): stop in front of
+    the run that begins at that layer and return ``(h [B, S, D], mem [B, S,
+    E], kv)`` as they stand there, no logits: the layers an admission runs
+    over every row of the prompt (``generate.prefill``).
 
     ``attn_fn(q, k, v) -> out`` takes q ``[B, Hq, S, Dh]`` and *grouped*
     kv ``[B, Hkv, S, Dh]`` (impls expand GQA heads internally); defaults to
@@ -1325,12 +1701,61 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
 
         return _remat_wrap(layer, cfg)
 
+    def period_of(kinds: tuple):
+        """The scan body of a run of whole periods (``LayerKinds.runs``):
+        one layer of each of ``kinds`` in turn.  Beside ``h`` the carry
+        holds what crosses layers: ``mem``, the last state-space layer's
+        scan output, for the gated memory units, and ``rows``, the last
+        full layer's ``(k, v)``, for the cross layers."""
+        def period(carry, lps):
+            h, aux, mem, rows = carry
+            kvs = []
+            for kind, lp in zip(kinds, lps):
+                attend = (partial(attn_fn, window=cfg.kinds.window)
+                          if kind == "window" else attn_fn)
+                h, layer_aux, kv, _stats = decoder_layer(
+                    lp, h, cfg, None, None, attend, moe_fn=moe_fn,
+                    lengths=lengths, mem=mem, rows=rows)
+                aux = aux + layer_aux
+                mem = kv.pop("mem", mem)
+                if kind == "full":
+                    rows = (kv["k"], kv["v"])
+                elif kind == "window":
+                    kv = {name + "_ring": x for name, x in kv.items()}
+                kvs.append(kv if return_kv else None)
+            return (h, aux, mem, rows), (tuple(kvs), None)
+
+        return _remat_wrap(period, cfg)
+
     carry = (h, jnp.zeros((), jnp.float32))
     outs = []
+    runs = cfg.kinds is not None and bool(cfg.kinds.runs)
+    if runs:
+        if return_moe_stats or not cfg.scan_layers:
+            raise ValueError("a model laid out in LayerKinds.runs is dense "
+                             "and scans its layers")
+        dt = cfg.compute_dtype
+        wide = (cfg.kv_cache_heads, S, cfg.kv_cache_dim)
+        carry += (jnp.zeros((B, S, cfg.ssm.d_inner if cfg.ssm else 0), dt),
+                  (jnp.zeros((B,) + wide, dt),) * 2)
     # One scan a segment: the segments differ in their trees (a leading
     # dense layer before the expert layers) or in their attention kind
     # (cfg.kinds); the body reads a layer's kind off its leaves.
     for seg, first in layer_segments(params["layers"]):
+        if runs and first == stop_at:
+            break
+        if runs:
+            kinds = cfg.kinds.mixers[first:first + len(seg)]
+            carry, (kvs, _none) = lax.scan(period_of(kinds), carry, seg)
+            # Layers of one cache kind in model order: a period's in turn.
+            merged: dict = {}
+            for kv in kvs if return_kv else ():
+                for name, x in kv.items():
+                    merged.setdefault(name, []).append(x)
+            outs.append(({name: xs[0] if len(xs) == 1 else jnp.stack(
+                xs, 1).reshape((-1,) + xs[0].shape[1:])
+                for name, xs in merged.items()}, None))
+            continue
         window, rope, _linear = segment_kind(cfg, seg, first)
         body = layer_of(window, rope)
         if cfg.scan_layers:
@@ -1347,7 +1772,7 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
         if return_kv and cfg.kinds is not None and window is not None:
             ys = ({name + "_ring": x for name, x in ys[0].items()}, ys[1])
         outs.append(ys)
-    h, aux = carry
+    h, aux = carry[:2]
     hidden = h
     by_name: dict = {}
     for kv, _stats in outs if return_kv else ():
@@ -1355,6 +1780,8 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
             by_name.setdefault(name, []).append(x)
     kv = {name: xs[0] if len(xs) == 1 else jnp.concatenate(xs)
           for name, xs in by_name.items()}
+    if stop_at is not None:
+        return h, carry[2], kv
     stats = [st for _kv, st in outs if st is not None]
     moe_stats = (None if not stats else stats[0] if len(stats) == 1 else
                  jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs),
@@ -1363,8 +1790,7 @@ def forward(params: dict, tokens, cfg: LlamaConfig,
         h = h[:, -1:]
     elif logit_positions is not None:
         h = jnp.take_along_axis(h, logit_positions[:, None, None], axis=1)
-    logits = head_logits(h, params["final_norm"], params["lm_head"],
-                         cfg.norm_eps, cfg.norm_zero_centred)
+    logits = model_logits(params, h, cfg)
     out = (logits,)
     if return_aux:
         out += (aux,)

@@ -112,7 +112,7 @@ from .. import perf
 from ..core import swtrace
 from .cache import cache_len, served_spec, state_names
 from .generate import (_filter_logits, _sample, decode_step_counted,
-                       ingest_decode_step, prefill)
+                       early_exit_at, ingest_decode_step, prefill)
 from .llama import LlamaConfig, cfg_rope_tables, head_logits
 
 # ----------------------------------------------------------- the serve logs
@@ -207,7 +207,16 @@ def step_log() -> list:
     two a step), and its ``kv_rows_full`` / ``kv_rows_window`` count what
     a step's TWO query positions read: ``pos + 2`` in a full row,
     ``min(pos + 2, ring)`` in a ring of ``window + slack`` positions,
-    which is read whole once warm."""
+    which is read whole once warm.  A server whose cache holds state,
+    rings AND full rows (``LayerKinds.runs``) adds all three:
+    ``state_slots``, ``kv_rows_full`` and ``kv_rows_window``; where cross
+    layers read a full layer's rows it adds ``kv_full_readers`` (the
+    layers that read ONE full layer's rows a step, its own among them)
+    and, in a step that admits, ``admit_rows_self`` / ``admit_rows_cross``:
+    the rows of the prompts' buckets that the layers which keep something
+    and the layers behind them which keep nothing processed in its admit
+    programs (the latter one a prompt where the admission exits early:
+    ``generate.early_exit_at``)."""
     return [dict(row) for row in list(_step_log)]
 
 
@@ -1141,6 +1150,14 @@ class SlotServer:
             pb = _bucket(len(prompt), self.buckets)
             padded = np.zeros((1, pb), np.int32)
             padded[0, :len(prompt)] = prompt
+            if self.spec.readers > 1:
+                # Rows the layers that keep something, and those behind
+                # them that keep nothing, saw of this prompt's bucket.
+                exits = early_exit_at(self.cfg) is not None
+                step = self._step
+                step["admit_rows_self"] = step.get("admit_rows_self", 0) + pb
+                step["admit_rows_cross"] = (step.get("admit_rows_cross", 0)
+                                            + (1 if exits else pb))
             admit = _compiled_admit(self.cfg, pb, *self.sampling)
             self.cache, tok = admit(
                 self.params, self.cache, jnp.asarray(padded),

@@ -14,7 +14,8 @@ function alone decides what runs (ops/dispatch.py): ``self_attention``,
 ``cached_attention``, ``ingest_attention``, ``latent_attention``,
 ``cache_write``, ``paged_attention``, ``grouped_matmul``,
 ``quantized_matmul``, the linear-attention recurrence ``kda_step`` /
-``kda_chunk`` and the ring step ``ring_step`` / ``ring_step_bwd``.  Each lives in the file that holds
+``kda_chunk``, the state-space recurrence ``ssm_step`` / ``ssm_scan`` and
+the ring step ``ring_step`` / ``ring_step_bwd``.  Each lives in the file that holds
 its kernel, beside its ``*_lax`` twin.
 """
 
@@ -33,6 +34,7 @@ from .pallas_gemv import quantized_matmul
 from .pallas_gmm import GATE_ACTS, grouped_matmul
 from .pallas_kda import kda_chunk, kda_step
 from .pallas_paged import paged_attention
+from .pallas_ssm import ssm_scan, ssm_step
 from .quantize import quantize_params
 
 __all__ = ["ring_shift", "all_to_all", "all_gather", "psum",
@@ -40,5 +42,6 @@ __all__ = ["ring_shift", "all_to_all", "all_gather", "psum",
            "self_attention", "cached_attention", "ingest_attention",
            "latent_attention",
            "cache_write", "paged_attention", "grouped_matmul", "GATE_ACTS",
-           "quantized_matmul", "kda_step", "kda_chunk", "ring_step",
+           "quantized_matmul", "kda_step", "kda_chunk", "ssm_step",
+           "ssm_scan", "ring_step",
            "ring_step_bwd"]

@@ -492,7 +492,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, layer=None, sm_scale=None,
 
 def decode_attention_lax(q, k_cache, v_cache, pos, *, layer=None,
                          window=None, k_scale=None, v_scale=None,
-                         ring: bool = False):
+                         ring: bool = False, sm_scale=None):
     """:func:`decode_attention` in plain lax (softmax in f32): what runs
     where Pallas does not, and what the kernel is tested against.  It
     slices the layer out, dequantizes an int8 cache up front and expands
@@ -509,7 +509,7 @@ def decode_attention_lax(q, k_cache, v_cache, pos, *, layer=None,
     k = repeat_kv(k_cache, n_rep)
     v = repeat_kv(v_cache, n_rep)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
-    s = s / (q.shape[-1] ** 0.5)
+    s = s / (q.shape[-1] ** 0.5) if sm_scale is None else s * sm_scale
     kv_pos = jnp.arange(k.shape[2])[None, None, None, :]
     qp = (jnp.asarray(pos).reshape(-1)[:, None, None, None]
           + jnp.arange(q.shape[2])[None, None, :, None])
@@ -527,7 +527,8 @@ def decode_attention_lax(q, k_cache, v_cache, pos, *, layer=None,
 
 
 def cached_attention(q, k_cache, v_cache, pos, *, layer=None, window=None,
-                     k_scale=None, v_scale=None, ring: bool = False):
+                     k_scale=None, v_scale=None, ring: bool = False,
+                     sm_scale=None):
     """Decode attention over a grouped k/v cache, the operation: the
     arguments of :func:`decode_attention`.  On a TPU the kernel streams
     the grouped cache once where it lies (an ``n_rep``-fold saving of HBM
@@ -545,7 +546,8 @@ def cached_attention(q, k_cache, v_cache, pos, *, layer=None, window=None,
     under the mask of the position it holds (:func:`decode_attention`'s
     ``ring``), and ``C`` query positions may have been written.  The
     same kernel either way, which a trace then calls
-    ``sw_decode_attn_ring``."""
+    ``sw_decode_attn_ring``.  ``sm_scale``: the scores' multiplier where
+    it is not ``D ** -0.5``."""
     masked = ring and window is not None
     if masked and not window < k_cache.shape[-2]:
         raise ValueError(f"a masked ring is longer than its window, got "
@@ -560,7 +562,8 @@ def cached_attention(q, k_cache, v_cache, pos, *, layer=None, window=None,
     if not dispatch.use_kernels():
         return decode_attention_lax(q, k_cache, v_cache, pos, layer=layer,
                                     window=window, k_scale=k_scale,
-                                    v_scale=v_scale, ring=masked)
+                                    v_scale=v_scale, ring=masked,
+                                    sm_scale=sm_scale)
     if layer is None:  # one layer's caches: a stack of one
         k_cache, v_cache, k_scale, v_scale = (
             None if a is None else a[None]
@@ -573,7 +576,8 @@ def cached_attention(q, k_cache, v_cache, pos, *, layer=None, window=None,
         ks, vs = rest[:-2] or (None, None)
         return decode_attention(q, k, v, rest[-2], layer=rest[-1],
                                 window=window, k_scale=ks, v_scale=vs,
-                                kernel_name=name, ring=masked)
+                                kernel_name=name, ring=masked,
+                                sm_scale=sm_scale)
 
     # Heads (dim 1 of q, dim 2 of the stacked caches and scales) shard
     # alike; pos (a scalar, or one cursor per batch row) and the layer

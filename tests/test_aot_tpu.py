@@ -241,6 +241,25 @@ def _kda_step(b=256, layers=6, h=32, d=128):
         _s((), I32))
 
 
+def _ssm_step(b=96, layers=9, e=5120, n=16):
+    from starway_tpu.ops.pallas_ssm import ssm_step_kernel
+
+    return (lambda st, dt, x, bm, cm, a, d, layer: ssm_step_kernel(
+        st, dt, x, bm, cm, a, d, layer=layer, interpret=False)), (
+        _s((layers, b, n, e), F32), _s((b, e), F32), _s((b, e), F32),
+        _s((b, n), F32), _s((b, n), F32), _s((n, e), F32), _s((e,), F32),
+        _s((), I32))
+
+
+def _ssm_scan(positions=1024, e=5120, n=16):
+    """One admission's bucket through the state-space recurrence."""
+    from starway_tpu.ops.pallas_ssm import ssm_scan_kernel
+
+    seq, col = _s((1, positions, e), F32), _s((1, positions, n), F32)
+    return (lambda *a: ssm_scan_kernel(*a, interpret=False)), (
+        seq, seq, col, col, _s((n, e), F32), _s((e,), F32))
+
+
 def _kda_chunk(positions=1024, h=32, hk=None, d=128, by_head=False):
     """The fused prefill kernel on one admission's bucket: ``h`` value
     heads over ``hk`` key heads in the projections' layout, a decay a
@@ -353,6 +372,21 @@ KERNELS = {
     "gmm_gated_answer": lambda: _gmm(1920, 16, 2048, 512, True, 128),
     "gmm_down_answer": lambda: _gmm(1920, 16, 512, 2048, False, 128),
     "gmm_gated_answer_admit": lambda: _gmm(20480, 128, 2048, 512, True, 128),
+    # phi4-mini-flash.cot_closed: 96 slots' Mamba state of 16 x 5,120
+    # float32 in nine stacked layers, by a traced index; the smallest and
+    # the largest admission's recurrence; 40 query rows over 10 PAIRS of
+    # 64-wide kv heads (128 wide in the cache) in a 6,144-token admission's
+    # flash pass, the decode kernel over the one full layer's rows of
+    # 8,192 and the eight rings of 512, and their writes.
+    "ssm_step_cot": lambda: _ssm_step(),
+    "ssm_scan_cot_1024": lambda: _ssm_scan(1024),
+    "ssm_scan_cot_6144": lambda: _ssm_scan(6144),
+    "flash_fwd_cot": lambda: _flash(10, s=6144, hq=40),
+    "decode_cot_full": lambda: _decode(10, b=96, t=8192, layers=1, hq=40),
+    "decode_cot_ring": lambda: _decode(10, b=96, t=512, layers=8, hq=40,
+                                       name="sw_decode_attn_ring"),
+    "kv_write_cot_full": lambda: _kv_write(96, 10, 8192, 1),
+    "kv_write_cot_ring": lambda: _kv_write(96, 10, 512, 8),
 }
 
 
@@ -924,6 +958,130 @@ def test_state_beside_grouped_query_rows_rides_the_decode_chunk_for_v5e(
                       "parameter", "get-tuple-element", "bitcast",
                       "custom-call"}]
     assert moved == []
+
+
+def _moved(text, shapes):
+    """Instructions of a compiled program's text that copy, slice or
+    scatter an array of one of ``shapes`` (each the tail of a leaf's shape
+    from the slot axis on), whole or one layer of it."""
+    import re
+
+    moved = []
+    for shape in shapes:
+        shaped = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[(?:\d+,)?"
+                            + re.escape(shape) + r"\S* ([\w\-]+)\(")
+        moved += [(mm.group(1), mm.group(2))
+                  for mm in map(shaped.match, text.splitlines())
+                  if mm and mm.group(2) not in {
+                      "parameter", "get-tuple-element", "bitcast",
+                      "custom-call"}]
+    return moved
+
+
+def test_state_rings_and_rows_ride_the_decode_chunk_for_v5e(topo, monkeypatch):
+    """phi4-mini-flash.cot_closed's decode chunk at the cell's shapes, the
+    WHOLE model (32 layers, the 200,064-row tied table): the 9 Mamba
+    layers' state, the 8 window layers' rings of 512 and the ONE full
+    layer's rows of 8,192 ride the scans' carries; the state is moved in
+    place by ``sw_ssm_step``, rings and rows are written by ``sw_kv_write``
+    and read by one decode kernel under two names (the rows by eight
+    layers: layer 17 and the seven cross layers, which keep nothing); no
+    instruction copies an array of the state's, the rings' or the rows'
+    shape, whole or one layer of it; the table is there ONCE (the head
+    contracts over its ``D`` axis: a transposed copy would be 1.02 GB, and
+    the arguments are the weights' and the cache's bytes and nothing
+    else); and the 32 layers are at most FIVE loops with the chunk's own
+    scan over its steps (a run of whole periods one body), not 32."""
+    import re
+
+    from starway_tpu.models.cache import init_cache
+    from starway_tpu.models.serving import _compiled_chunk
+
+    _as_tpu(monkeypatch)
+    cfg, params, n_slots, max_len = _cell_model("phi4-mini-flash")
+    assert max_len == 8192 and "lm_head" not in params
+    cache = jax.eval_shape(lambda: init_cache(cfg, n_slots, max_len))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (1, n_slots, 10, 8192, 128), "v": (1, n_slots, 10, 8192, 128),
+        "k_ring": (8, n_slots, 10, 512, 128),
+        "v_ring": (8, n_slots, 10, 512, 128),
+        "ssm_state": (9, n_slots, 16, 5120),
+        "ssm_conv": (9, n_slots, 3, 5120)}
+    run = _compiled_chunk(cfg, n_slots, max_len, CHUNK, 0.0, None, None, None)
+    compiled = run.lower(*_placed(
+        (params, cache, *_slot_state(n_slots)),
+        SingleDeviceSharding(topo.devices[0]))).compile()
+    text = compiled.as_text()
+    for name in ("sw_ssm_step", "sw_decode_attn_stream",
+                 "sw_decode_attn_ring", "sw_kv_write"):
+        assert name in text, name
+    size = lambda t: sum(a.size * a.dtype.itemsize
+                         for a in jax.tree_util.tree_leaves(t))
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == size(cache)
+    assert m.temp_size_in_bytes < size(cache["k_ring"]) / 2    # 0.4 GB of 1.0
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes) < 15.75 * 2**30
+    # one table: the arguments are the tree, the cache and five vectors
+    assert m.argument_size_in_bytes < size(params) + size(cache) + 2**20
+    assert not re.search(r"= bf16\[(200064,2560|2560,200064)\]\S* "
+                         r"(copy|transpose)\(", text)
+    assert _moved(text, (f"{n_slots},16,5120]", f"{n_slots},10,512,128]",
+                         f"{n_slots},10,8192,128]")) == []
+    assert len(re.findall(r" while\(", text)) <= 5
+
+
+@pytest.mark.parametrize("bucket", [1024, 6144])
+def test_early_exit_admit_at_the_cells_size_for_v5e(topo, monkeypatch, bucket):
+    """phi4-mini-flash.cot_closed's admissions, the smallest and the
+    largest bucket, into the cell's cache: layers 0-16 over the bucket
+    (``sw_ssm_scan`` a Mamba layer, the flash kernel a window layer), layer
+    17's k / v over the bucket and its attention, like layers 18-31, for
+    the prompt's last row through the DECODE kernel; the state is seated
+    whole, no leaf of the cache is copied, the table is there once, and
+    the 32 layers are at most five loops."""
+    import re
+
+    from starway_tpu.models.cache import init_cache
+    from starway_tpu.models.serving import _compiled_admit
+
+    _as_tpu(monkeypatch)
+    cfg, params, n_slots, max_len = _cell_model("phi4-mini-flash")
+    cache = jax.eval_shape(lambda: init_cache(cfg, n_slots, max_len))
+    run = _compiled_admit(cfg, bucket, 0.0, None, None)
+    compiled = run.lower(*_placed(
+        (params, cache, _s((1, bucket), I32), _s((), I32), _s((), I32),
+         jax.eval_shape(jax.random.PRNGKey, 0)),
+        SingleDeviceSharding(topo.devices[0]))).compile()
+    text = compiled.as_text()
+    for name in ("sw_ssm_scan", "sw_flash_fwd", "sw_decode_attn_stream"):
+        assert name in text, name
+    size = lambda t: sum(a.size * a.dtype.itemsize
+                         for a in jax.tree_util.tree_leaves(t))
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == size(cache)
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes) < 15.75 * 2**30
+    assert not re.search(r"= bf16\[(200064,2560|2560,200064)\]\S* "
+                         r"(copy|transpose)\(", text)
+    # the slot's entries are seated by in-place dynamic-update-slices
+    assert [op for _name, op in _moved(text, (
+        f"{n_slots},16,5120]", f"{n_slots},10,512,128]",
+        f"{n_slots},10,8192,128]")) if op == "copy"] == []
+    assert len(re.findall(r" while\(", text)) <= 5
+
+
+@pytest.mark.parametrize("which", ["step", "scan"])
+def test_ssm_kernel_bodies_do_not_grow_with_what_they_walk(which):
+    """What guards ``setup_s`` on the CPU (PERF.md section 7's start-up
+    rule): rows and tokens are loops of the kernel's body, not unrolled
+    into it, so 96 slots print the module of 8 and a 6,144-token bucket
+    the module of 1,024."""
+    if which == "step":
+        size = lambda b: len(_kernel_module_text(*_ssm_step(b=b)))
+        small, large = size(8), size(96)
+    else:
+        size = lambda s: len(_kernel_module_text(*_ssm_scan(s)))
+        small, large = size(1024), size(6144)
+    assert abs(large - small) <= 0.02 * small, (small, large)
 
 
 @pytest.mark.slow

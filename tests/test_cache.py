@@ -20,6 +20,7 @@ from tests.test_gdn import TINY as GDN
 from tests.test_kda import TINY as KDA
 from tests.test_mla_moe import TINY as MLA
 from tests.test_mtp_serving import tiny_cfg
+from tests.test_sambay import TINY as SAMBAY
 from tests.test_window_moe import TINY as WINDOW
 
 B, T = 3, 40
@@ -84,6 +85,18 @@ KINDS = {
         _kv(2, 2, T, 8) + _kv(6, 2, 8, 8, kind="_ring")
         + _kv(1, 2, T, 8, kind="_mtp"), dict(ring=8, mtp=1),
         dict(kv_rows_full=46, kv_rows_window=23)),
+    # state, rings AND rows in one cache (PR 46: no parent to hold it to;
+    # the leaves as DESIGN.md 9b's table has them): a pair of 8-wide kv
+    # heads is one 16-wide head, the states lie [N, E], and the two gated
+    # memory units and two cross layers own no leaf.
+    "state_rings_rows": (
+        _served("serve_ssm_yoco", SAMBAY), False,
+        _kv(1, 2, T, 16) + _kv(2, 2, 8, 16, kind="_ring")
+        + [("ssm_state", (3, B, 8, 128), F32),
+           ("ssm_conv", (3, B, 3, 128), F32)],
+        dict(ring=8, state=True),
+        dict(state_slots=4, kv_rows_full=42, kv_rows_window=21,
+             kv_full_readers=3)),
 }
 FACTS = dict(latent=False, int8=False, length=T, ring=0, rolling=False,
              state=False, mtp=0)
