@@ -503,6 +503,91 @@ def bench_kda_chunk(iters: int = 8, cells=None, sides=("kernel", "lax")):
             "unit": "us", "detail": json.dumps(each)}
 
 
+# The grouped matmul's two calls of one routed layer's decode step at the
+# five routed cells' shapes: name -> (pairs a call: slots x rows a slot x
+# top_k, published experts, held experts, d_model, expert width, skew).
+# ``skew``: the held experts' popularity is softmax(skew * normal) over a
+# seed, set so that the busiest expert over 64 seeds gets what the cell's
+# ledger reads as the chunk's largest (``expert_load_max_over_mean.agent``).
+GMM_CELLS = {
+    "agent": (128 * 8, 384, 12, 7168, 2048, 0.25),
+    "longdoc": (48 * 6, 64, 64, 2560, 768, 0.15),
+    "reason": (256 * 8, 256, 16, 2304, 1024, 1.25),
+    "think": (96 * 2 * 8, 128, 8, 6144, 2048, 0.15),
+    "answer": (192 * 10, 512, 128, 2048, 512, 0.5),
+}
+# ``think``'s pairs laid by hand: the same 96 on 8 tiles and on 11.
+GMM_LAYOUTS = {
+    "think_even": ("think", (12,) * 8),
+    "think_three_full": ("think", (22, 22, 20, 6, 7, 6, 7, 6)),
+}
+
+
+def bench_gmm_cells(iters: int = 16, cells=None, seeds=(0, 1, 2)):
+    """``sw_moe_gmm`` alone, the gated call and the down call of one routed
+    layer's decode step, at the five routed cells' shapes (``GMM_CELLS``)
+    with the pairs an expert drawn from a seeded skew, and at the layouts
+    laid by hand (``GMM_LAYOUTS``): us the two calls, the live row tiles a
+    touched expert, and GB/s of the touched experts' weights read ONCE
+    (the yardstick of ``sw_moe_gmm_roofline_share``)."""
+    import numpy as np
+
+    from starway_tpu.models.moe import group_rows, row_tile
+    from starway_tpu.ops.pallas_gmm import gmm
+
+    layouts = {}
+    for name in cells or [*GMM_CELLS, *GMM_LAYOUTS]:
+        if name in GMM_LAYOUTS:
+            layouts[name] = GMM_LAYOUTS[name]
+            continue
+        m, published, g, _, _, skew = GMM_CELLS[name]
+        for seed in seeds:
+            rng = np.random.default_rng(seed)
+            p = np.exp(skew * rng.standard_normal(g))
+            layouts[f"{name}_s{seed}"] = (
+                name, rng.multinomial(m * g // published, p / p.sum()))
+    each, held = {}, {}
+    for label, (cell, sizes) in sorted(   # a cell's layouts together
+            layouts.items(), key=lambda kv: list(GMM_CELLS).index(kv[1][0])):
+        m, _, g, k, f, _ = GMM_CELLS[cell]
+        sizes, tile = np.asarray(sizes), row_tile(m)
+        local = np.full((m,), g, np.int32)   # pairs held elsewhere
+        local[:sizes.sum()] = np.repeat(np.arange(g), sizes)
+        src, _, tile_expert, n_live, _ = group_rows(jnp.asarray(local), g,
+                                                    tile)
+        if cell not in held:   # one cell's operands at a time on the chip
+            held.clear()
+            ks = jax.random.split(jax.random.PRNGKey(0), 4)
+            held[cell] = (
+                jax.random.normal(ks[0], (src.shape[0], k), jnp.bfloat16),
+                *(jax.random.normal(key, shape, jnp.bfloat16) * 0.02
+                  for key, shape in zip(ks[1:], ((g, k, f), (g, k, f),
+                                                 (g, f, k)))))
+        x, w_gate, w_up, w_down = held[cell]
+
+        def layer(x, w_gate, w_up, w_down, tile_expert, n_live, tile=tile):
+            hidden = gmm(x, w_gate, tile_expert, n_live, tile_m=tile,
+                         w2=w_up)
+            return gmm(hidden, w_down, tile_expert, n_live, tile_m=tile)
+
+        dt = _timeit(lambda *a, iters: _chain(layer, *a, iters=iters),
+                     x, w_gate, w_up, w_down, tile_expert, n_live,
+                     iters=iters, target_s=0.2)
+        touched = int((sizes > 0).sum())
+        n_bytes = touched * 3 * k * f * 2
+        us = round(dt * 1e6, 1)
+        each[label] = us
+        print(json.dumps({
+            "metric": f"gmm_cell_{label}_us", "value": us, "unit": "us",
+            "detail": f"G={g} K={k} F={f} tile={tile} rows={x.shape[0]} "
+                      f"pairs={sizes.tolist()}: {int(n_live)} live tiles, "
+                      f"{int(n_live) / touched:.2f} a touched expert, "
+                      f"{n_bytes / 1e6:.1f} MB of touched weights -> "
+                      f"{n_bytes / dt / 1e9:.0f} GB/s"}), flush=True)
+    return {"metric": "gmm_cells_us", "value": round(sum(each.values()), 1),
+            "unit": "us", "detail": json.dumps(each)}
+
+
 def bench_train_mfu(iters: int = 4, B: int = 8, S: int = 1024):
     """Tiny-Llama MFU (the r2 row; kept for continuity of the table)."""
     return _train_mfu_row(
@@ -1003,6 +1088,7 @@ BENCHES = {
     "decode_shapes": bench_decode_shapes,
     "decode_cells": bench_decode_cells,
     "kda_chunk": bench_kda_chunk,
+    "gmm_cells": bench_gmm_cells,
     "train_mfu": bench_train_mfu,
     "train_mfu_large": bench_train_mfu_large,
     "serve": bench_serve,
@@ -1044,7 +1130,7 @@ def main():
         heavy = ("serve", "serve_b8", "serve_ragged_b8", "serve_mistral",
                  "serve_int8_b8", "serve_w8_b1", "serve_continuous",
                  "train_mfu_large", "decode_shapes", "decode_cells",
-                 "kda_chunk",
+                 "kda_chunk", "gmm_cells",
                  "spec_verify",
                  "gemv_int8")
         names = [n for n in BENCHES

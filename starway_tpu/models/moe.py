@@ -375,6 +375,13 @@ def softmax_route(xt, router_w, top_k: int, scale: float):
     return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1) * scale
 
 
+def row_tile(n_pairs: int) -> int:
+    """Rows of a row tile for a call of ``n_pairs`` (token, choice) pairs:
+    a decode step's few pairs an expert want small tiles, a prompt's
+    hundreds the MXU's height."""
+    return 16 if n_pairs <= 4096 else 128
+
+
 def group_rows(local, n_held: int, tile_m: int):
     """The grouped matmul's row layout for ``local [M]`` (each pair's
     expert as an index into the held ones; anything outside ``[0,
@@ -421,9 +428,7 @@ def routed_experts(xt, experts, gates, w, first: int, act: str = "silu"):
     k = experts.shape[1]
     layer = w.get("layer")
     g = w["w_gate"].shape[0 if layer is None else 1]
-    # Row tiles: a decode step's few pairs an expert want small tiles, a
-    # prompt's hundreds the MXU's height.
-    tile_m = 16 if t * k <= 4096 else 128
+    tile_m = row_tile(t * k)
     src, row, tile_expert, n_live, sizes = group_rows(
         experts.reshape(-1) - first, g, tile_m)
     x_rows = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)])[

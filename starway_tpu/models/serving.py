@@ -114,6 +114,7 @@ from .cache import cache_len, served_spec, state_names
 from .generate import (_filter_logits, _sample, decode_step_counted,
                        early_exit_at, ingest_decode_step, prefill)
 from .llama import LlamaConfig, cfg_rope_tables, head_logits
+from .moe import row_tile
 
 # ----------------------------------------------------------- the serve logs
 #
@@ -184,9 +185,14 @@ def step_log() -> list:
     routed FFN (``cfg.routed``) adds, for the chunk's decode steps and
     every slot's row in them: ``moe_assign`` ((token, choice) pairs that
     landed on held experts, all routed layers), ``moe_touched`` (held
-    experts with at least one pair, mean over layers and steps) and
+    experts with at least one pair, mean over layers and steps),
+    ``moe_tiles`` (the live row tiles a routed layer's grouped matmul
+    walks, ``ceil(pairs / tile)`` summed over the held experts, the tile
+    :func:`~starway_tpu.models.moe.row_tile`'s for the step's rows; mean
+    over layers and steps: over ``moe_touched``, the row tiles that share
+    one read of an expert's weights) and
     ``moe_max`` (the most pairs one expert got in one layer of one step);
-    other models' rows carry none of the three.  A server whose cache
+    other models' rows carry none of the four.  A server whose cache
     holds rings beside full rows (``cfg.kinds``) adds ``kv_rows_full`` and
     ``kv_rows_window``: the cache positions the chunk's FIRST decode step
     attends in one full layer (``pos + 1``) and in one window layer
@@ -220,11 +226,13 @@ def step_log() -> list:
     return [dict(row) for row in list(_step_log)]
 
 
-def _moe_fields(pairs: np.ndarray) -> dict:
+def _moe_fields(pairs: np.ndarray, tile: int) -> dict:
     """A routed model's :func:`step_log` fields from the chunk's pair
-    counts ``[steps, routed layers, held experts]``."""
+    counts ``[steps, routed layers, held experts]`` and the rows of the
+    row tiles its calls lay them in."""
     return {"moe_assign": int(pairs.sum()),
             "moe_touched": float((pairs > 0).sum(-1).mean()),
+            "moe_tiles": float((-(-pairs // tile)).sum(-1).mean()),
             "moe_max": int(pairs.max())}
 
 
@@ -1379,7 +1387,12 @@ class SlotServer:
                 step["wait_s"] = span.seconds
                 self._live_host, self._pos_host = np.array(live), np.array(pos)
                 if pairs is not None:  # a routed model's
-                    step.update(_moe_fields(pairs))
+                    # The rows of one call: each slot's (a verified draft
+                    # beside it), and a mixed chunk's piece.
+                    rows = (self.n_slots * (1 + self.cfg.mtp)
+                            + step["ingest_width"])
+                    step.update(_moe_fields(pairs, row_tile(
+                        rows * self.cfg.routed.top_k)))
                 if spec is not None:  # a speculating server's
                     step.update(spec_drafted=int(mask[:, 0].sum()),
                                 spec_accepted=int(spec["accepted"].sum()),

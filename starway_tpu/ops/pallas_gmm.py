@@ -3,13 +3,26 @@
 The rows of ``x [M, K]`` are (token, choice) pairs laid out expert by
 expert, each expert's rows padded up to whole row tiles, so that a row
 tile belongs to exactly ONE expert (models/moe.py::group_rows builds the
-layout).  The kernel walks ``(row tile, column block)``; a row tile's
-expert reaches it as a prefetched scalar and picks the weight block in the
-DMA's source address, so only the experts that got a token are read, once
-a row tile, and nothing is padded to a capacity.  Row tiles past the last
-live one repeat the last live tile's block indices and skip their body:
-they move no bytes and do no work, so a step's cost follows the pairs that
-really landed here, not the worst case the static shape allows.
+layout).  A row tile's expert reaches the kernel as a prefetched scalar and
+picks the weight block in the DMA's source address, so only the experts
+that got a token are read and nothing is padded to a capacity.
+
+The kernel walks a RUN at a time: the live row tiles of one expert, which
+lie together.  Inside a run the column block is the outer index and the
+run's tiles the inner one (:func:`tile_walk` lays the walk out; a grid
+step reads its expert, tile and column block as prefetched scalars), so
+the weight block ``(expert, column block)`` keeps its index over the run's
+tiles and the pipeline, which copies a block only where its index changed,
+fetches it ONCE a call however many tiles the expert fills.  (Row tile
+outer, a 3 MB weight block was fetched again for each 16-row tile of its
+expert wherever a matrix has several column blocks: PERF.md, PR 47.)  A
+run of several tiles pays with its ``x`` tiles, fetched once a column
+block: a fifteenth of the weight block they ride under.  A run of one tile
+holds its ``x`` tile over its columns, and with one column block the walk
+is the tiles' own order.  Steps past the last live tile repeat the last
+live step's block indices and skip their body: they move no bytes and do
+no work, so a step's cost follows the pairs that really landed here, not
+the worst case the static shape allows.
 
 Two forms under one name (``sw_moe_gmm``): ``x @ w[e]`` and, with a second
 weight, the gated pair ``act(x @ w[e]) * (x @ w2[e])`` of a gated expert's
@@ -37,14 +50,14 @@ _BLOCK_BYTES = 4 << 20   # one weight block [K, tn]
 GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
-def _gmm_kernel(tile_expert_ref, n_live_ref, layer_ref, x_ref, *refs,
-                gated: bool, act: str):
+def _gmm_kernel(step_expert_ref, step_tile_ref, step_col_ref, n_live_ref,
+                layer_ref, x_ref, *refs, gated: bool, act: str, n_col: int):
     if gated:
         w_ref, w2_ref, o_ref = refs
     else:
         w_ref, o_ref = refs
 
-    @pl.when(pl.program_id(0) < n_live_ref[0])
+    @pl.when(pl.program_id(0) < n_live_ref[0] * n_col)
     def _body():
         x = x_ref[...]
         y = jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
@@ -61,6 +74,30 @@ def column_block(k: int, n: int, itemsize: int) -> int:
     fits = [c for c in range(128, n + 1, 128)
             if n % c == 0 and k * c * itemsize <= _BLOCK_BYTES]
     return max(fits) if fits else n
+
+
+def tile_walk(tile_expert, n_live, n_col: int):
+    """The kernel's walk, ``(step_expert, step_tile, step_col)``, each
+    ``[tiles * n_col]`` int32: the expert, row tile and column block whose
+    blocks a grid step holds.  A RUN is the live tiles of one expert
+    (``tile_expert`` does not decrease over them, so they lie together);
+    its ``length * n_col`` steps start where its first tile's would, tile
+    by tile, and go column block by column block over all its tiles.  A
+    step past the last live tile is the last live step."""
+    tiles = jnp.arange(tile_expert.shape[0], dtype=jnp.int32)
+    live = tiles < n_live
+    same = live[None, :] & (tile_expert[None, :] == tile_expert[:, None])
+    # A tile's run (a dead tile is alone), said at each of its n_col steps.
+    first, length, expert = (jnp.repeat(a.astype(jnp.int32), n_col) for a in (
+        jnp.where(live, jnp.argmax(same, axis=1), tiles),
+        jnp.where(live, jnp.sum(same, axis=1), 1), tile_expert))
+    steps = jnp.arange(first.shape[0], dtype=jnp.int32)
+    at = steps - first * n_col
+    on = steps < n_live * n_col
+    last = jnp.maximum(n_live - 1, 0)
+    return (jnp.where(on, expert, tile_expert[last]),
+            jnp.where(on, first + at % length, last),
+            jnp.where(on, at // length, n_col - 1))
 
 
 def gmm_lax(x, w, tile_expert, n_live, tile_m: int, w2=None, layer=None,
@@ -104,44 +141,37 @@ def gmm(x, w, tile_expert, n_live, *, tile_m: int, w2=None, layer=None,
         interpret = dispatch.interpret()
     tn = column_block(k, n, w.dtype.itemsize)
     n_col = n // tn
-    n_live = jnp.asarray(n_live, jnp.int32).reshape(1)
+    n_live = jnp.asarray(n_live, jnp.int32).reshape(())
 
-    def live(i, j, n_live_ref):
-        """(row tile, column block) whose blocks step (i, j) holds: its
-        own while live, the last live step's after."""
-        on = i < n_live_ref[0]
-        last = jnp.maximum(n_live_ref[0] - 1, 0)
-        return jnp.where(on, i, last), jnp.where(on, j, n_col - 1)
+    def x_index(s, expert, tile, col, nl, la):
+        return (tile[s], 0)
 
-    def x_index(i, j, te, nl, la):
-        return (live(i, j, nl)[0], 0)
+    def w_index(s, expert, tile, col, nl, la):
+        return (la[0], expert[s], 0, col[s])
 
-    def w_index(i, j, te, nl, la):
-        ii, jj = live(i, j, nl)
-        return (la[0], te[ii], 0, jj)
-
-    def o_index(i, j, te, nl, la):
-        return live(i, j, nl)
+    def o_index(s, expert, tile, col, nl, la):
+        return (tile[s], col[s])
 
     w_spec = pl.BlockSpec((None, None, k, tn), w_index)
     weights = (w,) if w2 is None else (w, w2)
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, gated=w2 is not None, act=act),
+        functools.partial(_gmm_kernel, gated=w2 is not None, act=act,
+                          n_col=n_col),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(m // tile_m, n_col),
+            num_scalar_prefetch=5,
+            grid=(m // tile_m * n_col,),
             in_specs=[pl.BlockSpec((tile_m, k), x_index)]
             + [w_spec] * len(weights),
             out_specs=pl.BlockSpec((tile_m, tn), o_index),
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="sw_moe_gmm",
-    )(jnp.asarray(tile_expert, jnp.int32), n_live,
-      jnp.asarray(layer, jnp.int32).reshape(1), x, *weights)
+    )(*tile_walk(jnp.asarray(tile_expert, jnp.int32), n_live, n_col),
+      n_live.reshape(1), jnp.asarray(layer, jnp.int32).reshape(1), x, *weights)
 
 
 def grouped_matmul(x, w, tile_expert, n_live, *, tile_m: int, w2=None,

@@ -372,6 +372,11 @@ KERNELS = {
     "gmm_gated_answer": lambda: _gmm(1920, 16, 2048, 512, True, 128),
     "gmm_down_answer": lambda: _gmm(1920, 16, 512, 2048, False, 128),
     "gmm_gated_answer_admit": lambda: _gmm(20480, 128, 2048, 512, True, 128),
+    # k-exaone.think_closed: 96 slots x 2 verified rows x 8 choices a decode
+    # step on 8 held experts of width 2,048 under 6,144: 12 pairs an expert
+    # on average, so runs of two 16-row tiles, over 8 and 6 column blocks.
+    "gmm_gated_think": lambda: _gmm(1536, 16, 6144, 2048, True, 8),
+    "gmm_down_think": lambda: _gmm(1536, 16, 2048, 6144, False, 8),
     # phi4-mini-flash.cot_closed: 96 slots' Mamba state of 16 x 5,120
     # float32 in nine stacked layers, by a traced index; the smallest and
     # the largest admission's recurrence; 40 query rows over 10 PAIRS of

@@ -117,6 +117,8 @@ def test_slot_server_ragged_slots_match_reference(ref, runner):
     decoded = [r for r in rows if "moe_assign" in r]
     assert decoded and all(
         0 < r["moe_touched"] <= 16 and 0 < r["moe_max"] <= 3
+        # at most 3 pairs an expert: a touched expert fills one 16-row tile
+        and r["moe_tiles"] == r["moe_touched"]
         # every pair lands on a held expert when all are held: 3 slots x
         # 4 choices x 2 routed layers x 4 steps
         and r["moe_assign"] == 3 * 4 * 2 * 4 for r in decoded)
@@ -138,6 +140,35 @@ def test_a_latent_step_queues_its_programs_and_fetches_twice(runner, k,
     rids, done = check_step_queues_then_fetches(srv, requests, k, monkeypatch)
     assert [len(done[r]) for r in rids] == [requests[i % 3][1]
                                             for i in range(k)]
+
+
+@pytest.mark.parametrize("rows, value", [
+    # two chunks inside the window, one after it
+    ([{"t0": 0.2, "moe_touched": 8.0, "moe_tiles": 10.0},
+      {"t0": 0.6, "moe_touched": 7.0, "moe_tiles": 8.0},
+      {"t0": 1.5, "moe_touched": 8.0, "moe_tiles": 16.0}], 18.0 / 15.0),
+    # a program from before the counter (the parent), and a dense one
+    ([{"t0": 0.2, "moe_touched": 8.0}], None),
+    ([{"t0": 0.2}], None),
+])
+def test_moe_tiles_reader(rows, value, monkeypatch):
+    """``moe_tiles_per_expert.agent`` as ``benchmark/run.py`` reads it."""
+    from benchmark.harness import spec as S
+    from starway_tpu.models import serving
+
+    metric = "moe_tiles_per_expert.agent"
+    spec = S.load_spec()
+    entry = next(m for m in spec["per_layer"] if m["name"] == metric)
+    assert (entry["source"], entry["layer"], entry["moves"]) == (
+        "program_counter", "model", "tpot_p95_ms")
+    touched = next(m for m in spec["per_layer"]
+                   if m["name"] == "experts_touched.agent")
+    assert entry["workloads"] == touched["workloads"]   # the routed cells
+    monkeypatch.setattr(serving, "step_log", lambda: rows)
+    for cell in entry["workloads"]:   # as run.py reads a cell's line
+        assert metric in {m["name"] for m in S.per_layer_for(spec, cell)}
+    got = S.load_reader(metric).read({"window": (0.0, 1.0), "config": {}})
+    assert got == (value if value is None else pytest.approx(value))
 
 
 def test_dense_model_step_log_has_no_moe_fields():
@@ -282,15 +313,39 @@ def test_mla_decode_kernel_matches_lax():
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("gated,act", [(False, "silu"), (True, "silu"),
-                                       (True, "relu")])
-def test_gmm_kernel_matches_lax(gated, act):
+def _pairs_of(rng, sizes, strangers: int):
+    """``local`` for a grouped matmul test: ``sizes[e]`` pairs on held
+    expert ``e`` and ``strangers`` on experts not held, shuffled."""
+    g = len(sizes)
+    return jnp.asarray(rng.permutation(np.concatenate(
+        [np.repeat(np.arange(g), sizes),
+         rng.choice([-3, -1, g, g + 1], strangers)])), jnp.int32)
+
+
+# ``cols``: column blocks of the weight; ``sizes``: pairs an expert (None:
+# drawn, two tiles an expert or so).  Every served kimi-k2 / k-exaone /
+# kimi-linear call has several column blocks, and an expert of several
+# tiles is where the walk goes column block outer.
+@pytest.mark.parametrize("gated,act,cols,sizes", [
+    (False, "silu", 1, None), (True, "silu", 1, None), (True, "relu", 1, None),
+    (False, "silu", 2, (20, 0, 3, 9, 1)), (True, "silu", 2, (20, 0, 3, 9, 1)),
+    (True, "silu", 8, (7, 24, 0, 0, 17)), (False, "silu", 8, (0, 0, 0, 0, 0)),
+])
+def test_gmm_kernel_matches_lax(gated, act, cols, sizes, monkeypatch):
     from starway_tpu.models.moe import group_rows
+    from starway_tpu.ops import pallas_gmm
     from starway_tpu.ops.pallas_gmm import gmm, gmm_lax
 
     rng = np.random.default_rng(13)
-    G, K, N, tm = 5, 64, 256, 8
-    local = jnp.asarray(rng.integers(-3, G + 2, 90), jnp.int32)  # some not held
+    G, K, tm = 5, 64, 8
+    N = 256 if cols == 1 else 128 * cols
+    if cols > 1:   # a block of 128 columns is all that fits
+        monkeypatch.setattr(pallas_gmm, "_BLOCK_BYTES", K * 128 * 4)
+    assert N // pallas_gmm.column_block(K, N, 4) == cols
+    if sizes is None:
+        local = jnp.asarray(rng.integers(-3, G + 2, 90), jnp.int32)
+    else:
+        local = _pairs_of(rng, sizes, 11)
     src, row, tile_expert, n_live, sizes = group_rows(local, G, tm)
     assert int(sizes.sum()) == int(((local >= 0) & (local < G)).sum())
     x = jnp.asarray(rng.normal(size=(src.shape[0], K)), jnp.float32)
@@ -309,6 +364,57 @@ def test_gmm_kernel_matches_lax(gated, act):
     held = np.asarray((local >= 0) & (local < G))
     assert (e_of_row[np.asarray(row)[held]] == np.asarray(local)[held]).all()
     assert (np.asarray(row)[~held] == src.shape[0]).all()
+
+
+@pytest.mark.parametrize("cols", [1, 2, 8])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_gmm_walk_reads_an_expert_once(cols, seed):
+    """The grid step -> (row tile, column block) map over layouts with
+    experts of 0, 1, 2 and 6 tiles and dead tiles behind: every block of
+    the output once, every weight block fetched once a call."""
+    from starway_tpu.models.moe import group_rows
+    from starway_tpu.ops.pallas_gmm import tile_walk
+
+    rng = np.random.default_rng(seed)
+    tm = 8
+    sizes = rng.permutation([0, 0, 3, 8, 9, 16, 41, 48, 1])
+    local = _pairs_of(rng, sizes, 29)
+    _, _, tile_expert, n_live, _ = group_rows(local, len(sizes), tm)
+    tile_expert, n_live = np.asarray(tile_expert), int(n_live)
+    n_tiles = tile_expert.shape[0]
+    assert n_live == 1 + 1 + 2 + 2 + 6 + 6 + 1 and n_tiles > n_live + 3
+    expert, tile, col = (np.asarray(a) for a in tile_walk(
+        jnp.asarray(tile_expert), jnp.int32(n_live), cols))
+    assert expert.shape == (n_tiles * cols,)
+    assert (expert == tile_expert[tile]).all()
+    steps = n_live * cols
+    # Every (live tile, column block) exactly once, in the live steps.
+    assert sorted(zip(tile[:steps], col[:steps])) == [
+        (i, j) for i in range(n_live) for j in range(cols)]
+    # A dead step repeats the last live step's indices: nothing moves.
+    assert (tile[steps:] == tile[steps - 1]).all()
+    assert (col[steps:] == col[steps - 1]).all()
+    assert (tile[steps - 1], col[steps - 1]) == (n_live - 1, cols - 1)
+    # The weight block (expert, column block) changes once a touched
+    # expert and column block over the whole grid, its first fetch counted.
+    block = np.stack([expert, col], 1)
+    fetches = 1 + int((block[1:] != block[:-1]).any(1).sum())
+    assert fetches == int((sizes > 0).sum()) * cols
+    # A run of one tile walks as it always did: its x tile held, its
+    # columns in order; so does everything where there is one column block.
+    live_experts = tile_expert[:n_live]
+    alone = np.isin(live_experts, [e for e in range(len(sizes))
+                                   if 0 < sizes[e] <= tm])
+    for s in range(steps):
+        if cols == 1 or alone[s // cols]:
+            assert (tile[s], col[s]) == (s // cols, s % cols)
+    # A run of several tiles: column block outer, its tiles inner.
+    six = int(np.flatnonzero(sizes == 48)[0])
+    run = np.flatnonzero(tile_expert[tile[:steps]] == six)
+    r0 = int(np.flatnonzero(live_experts == six)[0])
+    assert (run == np.arange(r0 * cols, (r0 + 6) * cols)).all()
+    assert (col[run] == np.repeat(np.arange(cols), 6)).all()
+    assert (tile[run] == r0 + np.tile(np.arange(6), cols)).all()
 
 
 def test_routed_experts_pallas_path_matches_lax(runner, force_kernels):
