@@ -412,15 +412,85 @@ DECODE_CELLS = {
 }
 
 
+# The latent decode attention calls of the two latent cells (one layer's
+# call; every head shares the slot's one row a position, 640 values
+# allocated for the 576 attended): name -> (B, heads, T, first and last
+# cursor, skew).  Cursor i of B is ``first + (last - first) * (i / B) **
+# skew``: a skew above 1 crowds the cursors low, to the mean the cell's
+# traffic has (about 1,650 in agent_closed, 1,145 in reason_closed).  The
+# ``_1blk`` / ``_8blk`` rows put EVERY cursor in its first / its eighth
+# block of 512: their difference over seven is a block's steady state,
+# and what a one-block cell costs beyond that is the cell's own start.
+LATENT_RANK, LATENT_ROPE, LATENT_WIDTH = 512, 64, 640
+LATENT_CELLS = {
+    "agent_latent": (128, 64, 5120, 183, 5000, 2.28),
+    "reason_latent": (256, 32, 6144, 91, 4900, 3.56),
+    "agent_latent_1blk": (128, 64, 5120, 511, 511, 1.0),
+    "agent_latent_8blk": (128, 64, 5120, 4095, 4095, 1.0),
+    "reason_latent_1blk": (256, 32, 6144, 511, 511, 1.0),
+    "reason_latent_8blk": (256, 32, 6144, 4095, 4095, 1.0),
+}
+
+
+def _bench_latent_cells(iters: int, cells) -> dict:
+    """``sw_mla_decode_attn`` alone at ``LATENT_CELLS``: us a call, us a
+    cell (a slot) and GB/s of the 576-value rows attended."""
+    from starway_tpu.ops.pallas_decode import latent_attention
+
+    each = {}
+    for name in cells:
+        b, h, t, first, last, skew = LATENT_CELLS[name]
+        kq, kc = jax.random.split(jax.random.PRNGKey(0))
+        q = jax.random.normal(kq, (b, h, 1, LATENT_WIDTH), jnp.bfloat16)
+        latent = jax.random.normal(kc, (1, b, 1, t, LATENT_WIDTH),
+                                   jnp.bfloat16)
+        # The span's cursors, in a scrambled order of rows.
+        at = (jnp.arange(b) * 7919 % b) / b
+        pos = (first + (last - first) * at ** skew).astype(jnp.int32)
+
+        def calls(q, latent, pos, iters):
+            # Dependent calls: each one's cursors wait for the one before
+            # (by a zero XLA does not fold), so nothing but the kernel and
+            # B scalar adds is timed.
+            def body(_, out):
+                wait = (out[0, 0, 0, 0] * 0).astype(jnp.int32)
+                return latent_attention(q, latent, pos + wait,
+                                        rank=LATENT_RANK, sm_scale=0.1)
+
+            out = lax.fori_loop(
+                0, iters, body, jnp.zeros((b, h, 1, LATENT_RANK), q.dtype))
+            return out[0, 0, 0, 0].astype(jnp.float32)
+
+        dt = _timeit(calls, q, latent, pos, iters=iters)
+        n_bytes = int(jnp.sum(pos + 1)) * (LATENT_RANK + LATENT_ROPE) * 2
+        blocks = float(jnp.mean(pos // 512 + 1))
+        us = each[name] = round(dt * 1e6, 1)
+        print(json.dumps({
+            "metric": f"decode_cell_{name}_us", "value": us, "unit": "us",
+            "detail": f"B={b} H={h} T={t} row {LATENT_WIDTH} / rank "
+                      f"{LATENT_RANK}, cursors {int(pos.min())}-"
+                      f"{int(pos.max())} mean {int(pos.mean())} "
+                      f"({blocks:.2f} blocks of 512 a cell): "
+                      f"{dt * 1e6 / b:.3f} us a cell, {n_bytes / 1e6:.1f} MB "
+                      f"attended -> {n_bytes / dt / 1e9:.0f} GB/s"}),
+            flush=True)
+    return each
+
+
 def bench_decode_cells(iters: int = 64, cells=None):
-    """The decode attention kernel alone at the served cells' own shapes
-    (``DECODE_CELLS``): us a call and GB/s of the k/v it attends, one row
-    a shape and their sum: the yardstick a kernel PR starts from (PR 41's
-    builder made it and lost it; PERF.md section 6)."""
+    """The decode attention kernels alone at the served cells' own shapes:
+    the grouped-query kernel's (``DECODE_CELLS``: us a call and GB/s of the
+    k/v it attends, one row a shape and their sum: the yardstick a kernel
+    PR starts from; PR 41's builder made it and lost it, PERF.md section 6)
+    and then the latent kernel's (``LATENT_CELLS``, in ``detail`` beside
+    the others and not in the sum, which stays comparable with the
+    records)."""
     from starway_tpu.ops.pallas_decode import cached_attention
 
     total, each = 0.0, {}
-    for name in cells or DECODE_CELLS:
+    latent = [c for c in cells or LATENT_CELLS if c in LATENT_CELLS]
+    cells = [c for c in cells or DECODE_CELLS if c in DECODE_CELLS]
+    for name in cells:
         b, hq, hkv, d, c, t, window, ring, first, last = DECODE_CELLS[name]
         kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
         q = jax.random.normal(kq, (b, hq, c, d), jnp.bfloat16)
@@ -449,6 +519,7 @@ def bench_decode_cells(iters: int = 64, cells=None):
                       f"{int(pos.mean())}: {us / (b * hkv):.3f} us a (slot, "
                       f"kv head), {n_bytes / 1e6:.1f} MB attended -> "
                       f"{n_bytes / dt / 1e9:.0f} GB/s"}), flush=True)
+    each.update(_bench_latent_cells(iters, latent))
     return {"metric": "decode_cells_us", "value": round(total, 1),
             "unit": "us", "detail": json.dumps(each)}
 
@@ -1113,6 +1184,9 @@ def main():
                     help="comma list of benches, 'all', or 'check' "
                          "(on-chip numerics vs the lax oracles)")
     ap.add_argument("--iters", type=int, default=None)
+    ap.add_argument("--cells", default=None,
+                    help="comma list: only these rows of a bench that takes "
+                         "cells (decode_cells, kda_chunk, gmm_cells)")
     args = ap.parse_args()
     from starway_tpu.utils.chip import enable_compile_cache, require_accelerator
 
@@ -1146,6 +1220,8 @@ def main():
                 emit(row)
             continue
         kw = {"iters": args.iters} if args.iters else {}
+        if args.cells:
+            kw["cells"] = args.cells.split(",")
         try:
             row = BENCHES[name](**kw)
         except Exception as e:  # report the row, finish the rest, exit 1

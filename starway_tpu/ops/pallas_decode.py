@@ -31,6 +31,13 @@ was deleted in PR 28.  One cell a (batch row, kv head), each starting
 cold, paid about 1.1 us a cell until PR 42: 768 cells a call where 96
 slots have 8 kv heads.)
 
+The latent cache's kernel (``sw_mla_decode_attn``, :func:`mla_decode_attention`)
+is the same kernel body and so the same chain (PR 48; until then it was a
+body of its own, one cell a slot, each cell starting cold and waiting for
+its whole first block): ONE operand streams, the slot's one row a position
+that every head shares, and a block is used twice, whole as the keys and
+its first ``rank`` columns as the values.
+
 Same online-softmax algebra as ops/pallas_attention.py; layouts follow
 models/generate.py: ``q [B, Hq, 1, D]``, caches ``[B, Hkv, T, D]`` — or the
 whole scan-stacked cache ``[L, B, Hkv, T, D]`` with a traced ``layer``.
@@ -156,7 +163,8 @@ def _decode_stream_kernel(pos_ref, layer_ref, *refs,
                           sm_scale: float, block_k: int, heads: int,
                           n_groups: int, window: "int | None", n_blocks: int,
                           quant: bool = False, n_q: int = 1,
-                          by_row: bool = False, ring: bool = False):
+                          by_row: bool = False, ring: bool = False,
+                          rank: "int | None" = None):
     """One grid cell per (batch row, group of ``heads`` kv heads; a row has
     ``n_groups`` of them, one where VMEM lets every head in): the WHOLE
     cache sweep of all the cell's heads runs in a single cell as a
@@ -173,9 +181,10 @@ def _decode_stream_kernel(pos_ref, layer_ref, *refs,
     in is carried from cell to cell in SMEM (``par_ref``); the grid axis
     must stay sequential ("arbitrary").
 
-    ``k_hbm``/``v_hbm`` are the whole stacked caches ``[L, B, Hkv, T, D]``
-    left in HBM; ``layer_ref`` (second prefetched scalar) picks the layer
-    in the DMA's source address, so no layer is ever sliced out.
+    What streams (``src``: k and v) are the whole stacked caches ``[L, B,
+    Hkv, T, D]`` left in HBM; ``layer_ref`` (second prefetched scalar)
+    picks the layer in the DMA's source address, so no layer is ever
+    sliced out.
 
     ``quant``: two extra HBM inputs (per-token f32 scales ``[L, B, Hkv,
     T]``) and two extra scratch buffers ride the same double-buffered
@@ -190,16 +199,20 @@ def _decode_stream_kernel(pos_ref, layer_ref, *refs,
     ``ring`` (with ``window``): the cache is a ring of ``T > window``
     positions written at ``p % T`` and ``pos`` is absolute: every warm
     block is streamed and each slot masked by the position it holds.
+
+    ``rank`` (the latent cache, :func:`mla_decode_attention`): ONE operand
+    ``[L, B, 1, T, W]`` streams, as ``k`` does and with no ``v`` beside it;
+    a cell's "head" is the row all the heads share, their absorbed queries
+    are the rows of its matmuls, and a block's first ``rank`` columns are
+    its values.
     """
     if by_row:
         row_ref, *refs = refs
-    q_ref, k_hbm, v_hbm, *refs = refs
-    if quant:
-        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems, par_ref,
-         m_scr, l_scr, acc_scr) = refs
-    else:
-        ks_hbm = vs_hbm = ks_buf = vs_buf = None
-        o_ref, k_buf, v_buf, sems, par_ref, m_scr, l_scr, acc_scr = refs
+    q_ref, *refs = refs
+    # What streams: k and v, then their scales; the latent rows alone.
+    n_src = 1 if rank is not None else 4 if quant else 2
+    src, o_ref, bufs = refs[:n_src], refs[n_src], refs[n_src + 1:2 * n_src + 1]
+    sems, par_ref, m_scr, l_scr, acc_scr = refs[2 * n_src + 1:]
     cell = pl.program_id(0)
     layer = layer_ref[0]
 
@@ -225,23 +238,12 @@ def _decode_stream_kernel(pos_ref, layer_ref, *refs,
 
     def copies(row, h0, i, slot):
         blk = pl.ds(i * block_k, block_k)
-        hs = pl.ds(h0, heads)
-        cps = [
+        hs = pl.ds(h0, heads)  # of k and v; a scale block comes whole
+        return [
             pltpu.make_async_copy(
-                k_hbm.at[layer, row, hs, blk], k_buf.at[slot],
-                sems.at[slot, 0]),
-            pltpu.make_async_copy(
-                v_hbm.at[layer, row, hs, blk], v_buf.at[slot],
-                sems.at[slot, 1]),
-        ]
-        if quant:
-            cps.append(pltpu.make_async_copy(
-                ks_hbm.at[layer, row, :, blk], ks_buf.at[slot],
-                sems.at[slot, 2]))
-            cps.append(pltpu.make_async_copy(
-                vs_hbm.at[layer, row, :, blk], vs_buf.at[slot],
-                sems.at[slot, 3]))
-        return cps
+                hbm.at[layer, row, hs if a < 2 else slice(None), blk],
+                buf.at[slot], sems.at[slot, a])
+            for a, (hbm, buf) in enumerate(zip(src, bufs))]
 
     row, h0, pos, lo, hi = span(cell)
     # What follows this cell's last block in the stream: the next cell's
@@ -281,13 +283,15 @@ def _decode_stream_kernel(pos_ref, layer_ref, *refs,
 
             for cp in copies(row, h0, i, slot):
                 cp.wait()
+            k = bufs[0][slot]
             _softmax_block_update(
-                q, k_buf[slot], v_buf[slot], i * block_k, pos, m_scr, l_scr,
+                q, k, k[..., :rank] if rank is not None else bufs[1][slot],
+                i * block_k, pos, m_scr, l_scr,
                 acc_scr, sm_scale=sm_scale, window=window,
                 k_scale=(None if not quant
-                         else _head_rows(ks_buf[slot], h0, heads)),
+                         else _head_rows(bufs[2][slot], h0, heads)),
                 v_scale=(None if not quant
-                         else _head_rows(vs_buf[slot], h0, heads)),
+                         else _head_rows(bufs[3][slot], h0, heads)),
                 row_off=_row_offsets(q.shape[1], n_q),
                 ring=(n_blocks * block_k, pos + n_q - 1) if ring else None)
 
@@ -669,51 +673,6 @@ def ingest_attention(q, k_cache, v_cache, pos, rows, *, layer, k_scale=None,
 # ------------------------------------------------- latent (MLA) attention
 
 
-def _mla_stream_kernel(pos_ref, layer_ref, q_ref, c_hbm, o_ref, c_buf, sems,
-                       m_scr, l_scr, acc_scr, *, sm_scale: float,
-                       block_k: int, n_blocks: int, rank: int, n_q: int):
-    """One grid cell per batch row: the row's latent entries stream through
-    VMEM once (double-buffered, as :func:`_decode_stream_kernel`) and each
-    block is used twice: whole as the keys of all the heads' absorbed
-    queries, and its first ``rank`` columns as their values."""
-    b = pl.program_id(0)
-    layer = layer_ref[0]
-    pos = pos_ref[b]
-    hi = (pos + n_q - 1) // block_k
-
-    def copy(i, slot):
-        return pltpu.make_async_copy(
-            c_hbm.at[layer, b, 0, pl.ds(i * block_k, block_k)],
-            c_buf.at[slot], sems.at[slot])
-
-    m_scr[:] = jnp.full_like(m_scr, NEG_BIG)
-    l_scr[:] = jnp.zeros_like(l_scr)
-    acc_scr[:] = jnp.zeros_like(acc_scr)
-    copy(0, 0).start()
-    q = q_ref[0]
-
-    def body(i, _):
-        @pl.when(i <= hi)
-        def _live():
-            slot = jax.lax.rem(i, 2)
-
-            @pl.when(i + 1 <= hi)
-            def _prefetch():
-                copy(i + 1, jax.lax.rem(i + 1, 2)).start()
-
-            copy(i, slot).wait()
-            c = c_buf[slot]
-            _softmax_block_update(
-                q, c, c[:, :rank], i * block_k, pos, m_scr, l_scr, acc_scr,
-                sm_scale=sm_scale, window=None,
-                row_off=_row_offsets(q.shape[0], n_q))
-
-        return 0
-
-    jax.lax.fori_loop(0, n_blocks, body, 0)
-    o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
-
-
 def mla_decode_attention_lax(q, latent, pos, *, rank: int, sm_scale: float,
                              layer=0):
     """:func:`mla_decode_attention` in plain lax (softmax in f32): what runs
@@ -740,7 +699,19 @@ def mla_decode_attention(q, latent, pos, *, rank: int, sm_scale: float,
     caller applies ``W_UV``).  The H x C queries of a row are the rows of
     one matmul against each block, so the cache is read once for all heads
     and once for both uses.  T must be a multiple of 128
-    (:func:`_pick_block`); other lengths take the lax form."""
+    (:func:`_pick_block`); other lengths take the lax form.
+
+    The kernel is :func:`_decode_stream_kernel` over one operand, one grid
+    cell a slot, and its stream is that kernel's chain: a slot's last
+    block computes while the NEXT slot's first is on its way into the
+    other buffer, the buffer a slot starts in rides from cell to cell in
+    SMEM, and only the call's first cell starts cold (before PR 48 every
+    cell did, and waited for its whole first block: 1.1 us of the 4 to 5
+    a cell took at the served cursors, PERF.md section 6).  Hence the sequential ("arbitrary") grid axis: a cell
+    waits for a copy the cell before it started.  And hence the clamp of a
+    slot's last block to the cache (``span``): every cell must have a
+    block the one before it can fetch, also a frozen slot whose cursor
+    stands at ``T``."""
     b, h, n_q, w = q.shape
     t = latent.shape[3]
     if interpret is None:
@@ -752,28 +723,34 @@ def mla_decode_attention(q, latent, pos, *, rank: int, sm_scale: float,
     rows = h * n_q  # row r = head * C + ci (_row_offsets' layout)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     out = pl.pallas_call(
-        functools.partial(_mla_stream_kernel, sm_scale=float(sm_scale),
-                          block_k=block_k, n_blocks=t // block_k, rank=rank,
-                          n_q=n_q),
+        functools.partial(
+            _decode_stream_kernel, sm_scale=float(sm_scale), block_k=block_k,
+            heads=1, n_groups=1, window=None, n_blocks=t // block_k, n_q=n_q,
+            rank=rank),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b,),
-            in_specs=[pl.BlockSpec((1, rows, w), lambda i, *_: (i, 0, 0)),
+            in_specs=[pl.BlockSpec((1, 1, rows, w), lambda i, *_: (i, 0, 0, 0)),
                       pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
-            out_specs=pl.BlockSpec((1, rows, rank), lambda i, *_: (i, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, rows, rank),
+                                   lambda i, *_: (i, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, block_k, w), latent.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, rank), jnp.float32),
+                pltpu.VMEM((2, 1, block_k, w), latent.dtype),
+                pltpu.SemaphoreType.DMA((2, 1)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((1, rows, 128), jnp.float32),
+                pltpu.VMEM((1, rows, 128), jnp.float32),
+                pltpu.VMEM((1, rows, rank), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, rows, rank), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, rows, rank), q.dtype),
+        # Each cell starts the next one's stream: one core, in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="sw_mla_decode_attn",
     )(pos_arr, jnp.asarray(layer, jnp.int32).reshape(1),
-      q.reshape(b, rows, w), latent)
+      q.reshape(b, 1, rows, w), latent)
     return out.reshape(b, h, n_q, rank)
 
 

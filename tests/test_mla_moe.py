@@ -295,22 +295,40 @@ def test_shares_add_up_to_the_uncut_layer(model, share_of, top_k):
     np.testing.assert_allclose(total_prog + shared, want, rtol=1e-4, atol=1e-4)
 
 
-def test_mla_decode_kernel_matches_lax():
+# Blocks of 128.  The stream runs from cell to cell (a row's last block
+# computes while the next row's first is fetched, and which of the two
+# buffers a row starts in is handed on), so the cases are ORDERS of
+# cursors: ``c`` query positions a row, ``layer`` of a stack of two.
+@pytest.mark.parametrize("t,pos,c,layer", [
+    (256, (0, 129, 255), 1, 1),
+    (256, (0, 129, 254), 2, 1),
+    # Uneven rows: the first position, either side of a block's edge, the
+    # last position.
+    (512, (0, 127, 128, 511, 300), 1, 1),
+    # A row of one block, then a row of many, and the reverse: the parity
+    # handed over is odd after one and even after the other.
+    (512, (5, 500, 7), 1, 1),
+    (512, (500, 5, 400, 3, 130), 1, 0),
+    (512, (300,), 1, 1),          # a first cell that is also the last
+    (512, (0,), 1, 0),
+    (512, (100, 512, 40), 1, 1),  # a frozen slot's cursor at max_len: clamped
+    (512, (512, 512), 1, 0),
+    (512, (510, 0, 127, 254), 2, 0),
+], ids=lambda v: "-".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_mla_decode_kernel_matches_lax(t, pos, c, layer):
     from starway_tpu.ops.pallas_decode import (mla_decode_attention,
                                                mla_decode_attention_lax)
 
     k = jax.random.split(jax.random.PRNGKey(11), 2)
-    L, B, H, T, r, w = 2, 3, 8, 256, 128, 160
+    L, B, H, T, r, w = 2, len(pos), 8, t, 128, 160
     latent = jax.random.normal(k[0], (L, B, 1, T, w), jnp.float32)
-    pos = jnp.asarray([0, 129, 255], jnp.int32)
-    for c in (1, 2):
-        q = jax.random.normal(k[1], (B, H, c, w), jnp.float32)
-        p = jnp.minimum(pos, T - c)
-        got = mla_decode_attention(q, latent, p, rank=r, sm_scale=0.11,
-                                   layer=1, block_k=128, interpret=True)
-        want = mla_decode_attention_lax(q, latent, p, rank=r, sm_scale=0.11,
-                                        layer=1)
-        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    q = jax.random.normal(k[1], (B, H, c, w), jnp.float32)
+    pos = jnp.asarray(pos, jnp.int32)
+    got = mla_decode_attention(q, latent, pos, rank=r, sm_scale=0.11,
+                               layer=layer, block_k=128, interpret=True)
+    want = mla_decode_attention_lax(q, latent, pos, rank=r, sm_scale=0.11,
+                                    layer=layer)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
 def _pairs_of(rng, sizes, strangers: int):
